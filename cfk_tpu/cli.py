@@ -1451,7 +1451,7 @@ def build_parser() -> argparse.ArgumentParser:
         "--platform",
         choices=["default", "cpu", "tpu"],
         default="default",
-        help="force the JAX platform (overrides environment registration)",
+        help="force the JAX platform (same effect as JAX_PLATFORMS)",
     )
     sub = p.add_subparsers(dest="command", required=True)
 
@@ -1622,9 +1622,10 @@ def build_parser() -> argparse.ArgumentParser:
     t.add_argument(
         "--compile-cache-dir", default=None, metavar="DIR",
         help="persistent jax compilation cache (ISSUE 13): compiled "
-        "programs are reused across process restarts, keyed per device "
-        "fingerprint inside DIR — a warm cache removes the cold-start "
-        "compile cost the time_to_first_step/batch columns measure",
+        "programs are reused across process restarts — a warm cache "
+        "removes the cold-start compile cost the time_to_first_step/batch "
+        "columns measure.  Default: .jax_compile_cache at the checkout "
+        "root; JAX_COMPILATION_CACHE_DIR, where set, wins over DIR",
     )
     t.add_argument(
         "--health-check-every", type=int, default=None, metavar="N",
@@ -1792,10 +1793,11 @@ def build_parser() -> argparse.ArgumentParser:
     sv.add_argument("--loadgen-requests", type=int, default=256)
     sv.add_argument("--seed", type=int, default=0)
     sv.add_argument("--compile-cache-dir", default=None, metavar="DIR",
-                    help="persistent jax compilation cache keyed per "
-                    "device fingerprint (ISSUE 13) — a restarted server "
-                    "replays its prewarmed serve programs instead of "
-                    "recompiling the batch-bucket set")
+                    help="persistent jax compilation cache (ISSUE 13) — a "
+                    "restarted server replays its prewarmed serve programs "
+                    "instead of recompiling the batch-bucket set (default "
+                    ".jax_compile_cache at the checkout root; "
+                    "JAX_COMPILATION_CACHE_DIR wins over DIR)")
     sv.add_argument("--metrics-port", type=int, default=None,
                     help="serve GET /metrics (Prometheus text) on this "
                     "port while the server runs (0 = ephemeral)")
@@ -1929,9 +1931,10 @@ def build_parser() -> argparse.ArgumentParser:
                     "pair with --compile-cache-dir so a warm restart "
                     "skips the compiles too)")
     st.add_argument("--compile-cache-dir", default=None, metavar="DIR",
-                    help="persistent jax compilation cache keyed per "
-                    "device fingerprint — removes the cold-process "
-                    "re-compile cost of the fold-in/retrain programs")
+                    help="persistent jax compilation cache — removes the "
+                    "cold-process re-compile cost of the fold-in/retrain "
+                    "programs (default .jax_compile_cache at the checkout "
+                    "root; JAX_COMPILATION_CACHE_DIR wins over DIR)")
     st.add_argument("--metrics-port", type=int, default=None,
                     help="serve GET /metrics (Prometheus text) on this "
                     "port while the stream runs (0 = ephemeral)")
@@ -2051,8 +2054,6 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if args.platform != "default":
-        # Must go through jax.config (some environments force-register a
-        # platform and override the JAX_PLATFORMS env var).
         import jax
 
         jax.config.update("jax_platforms", args.platform)

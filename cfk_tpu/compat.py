@@ -1,107 +1,54 @@
-"""JAX version compatibility shims.
+"""The jax seams every sharded call site shares, and the kernels' XLA twins.
 
-The framework targets the current JAX API (top-level ``jax.shard_map`` with
-``check_vma``, ``jax.typeof`` + varying-mesh-axes types, ``lax.pvary`` /
-``lax.pcast``), but must also run on older installs (0.4.x) where none of
-those exist: there the vma system is absent entirely, so the correct
-degradation is "no vma marking at all" — collectives still place correctly,
-we just lose the static checker.  Every call site goes through this module
-instead of sniffing ``hasattr`` locally, so the support matrix lives in one
-file.
+``shard_map``/``typeof_vma``/``to_varying`` are thin names over the installed
+jax (0.9: top-level ``jax.shard_map`` with ``check_vma``, ``jax.typeof``,
+``lax.pcast``) so the ring bodies and kernels spell them one way.  The
+``emulate_*`` functions are the plain-XLA twins of the Pallas kernels: the
+route interpret-mode (CPU) runs take, and the references the kernel tests
+compare against.
 """
 
 from __future__ import annotations
-
-import functools
-import inspect
 
 import jax
 from jax import lax
 
 
-@functools.lru_cache(maxsize=1)
-def _shard_map_fn():
-    try:  # jax >= 0.6 exposes shard_map at top level
-        return jax.shard_map
-    except AttributeError:  # pragma: no cover - version-dependent
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm
-
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_check_kwarg() -> str | None:
-    """Name of shard_map's static-checker toggle on this JAX.
-
-    ``check_vma`` on current JAX, ``check_rep`` on 0.4.x-era shard_map,
-    None if the signature is opaque (pass nothing and take the default).
-    """
-    try:
-        params = inspect.signature(_shard_map_fn()).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        return None
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None  # pragma: no cover - exotic builds
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check=True):
-    """``jax.shard_map`` across JAX versions.
-
-    ``check`` maps onto whichever static replication/vma checker this JAX
-    has (``check_vma`` today, ``check_rep`` historically).  On 0.4.x the
-    rep checker predates several collectives/ops we emit inside the ring
-    bodies (``optimization_barrier`` has no rep rule there), so ``check``
-    is only honored when True is known to work — callers that must disable
-    it still can.
-    """
-    kw = {}
-    name = _shard_map_check_kwarg()
-    if name is not None:
-        kw[name] = check if name == "check_vma" else False
-    return _shard_map_fn()(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
-
-
-def has_vma_system() -> bool:
-    """True when this JAX has the typed varying-mesh-axes system (and the
-    pallas toolchain that goes with it).  Old installs (0.4.x) predate it;
-    their pallas HLO interpreter is also orders of magnitude slower on the
-    grouped-Gram kernels, so callers use this to prefer the XLA emulation
-    there."""
-    return hasattr(jax, "typeof")
+    """``jax.shard_map`` with the vma checker toggled by ``check``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def typeof_vma(x):
-    """``jax.typeof(x).vma`` where the vma system exists, else None."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
+    """``jax.typeof(x).vma`` (None for values jax cannot type)."""
     try:
-        return getattr(typeof(x), "vma", None)
+        return getattr(jax.typeof(x), "vma", None)
     except TypeError:  # pragma: no cover - non-typeable values
         return None
 
 
 def to_varying(x, axis):
-    """Mark x device-varying over ``axis``.
+    """Mark x device-varying over ``axis``."""
+    return lax.pcast(x, axis, to="varying")
 
-    ``pcast`` on jax >= 0.9, ``pvary`` before; identity on installs that
-    predate the vma system (nothing to mark — carries typecheck unmarked).
+
+def match_varying(z, ref):
+    """Give constant ``z`` the same device-varying axes as traced ``ref``.
+
+    Inside ``shard_map`` (with vma checking) a scan carry initialized from
+    constants must be explicitly pcast to the mesh axes the body's data is
+    varying over; outside shard_map this is the identity.
     """
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis)
-    return x
+    have = typeof_vma(z) or ()
+    missing = tuple(a for a in (typeof_vma(ref) or ()) if a not in have)
+    return to_varying(z, missing) if missing else z
 
 
 def emulate_in_kernel_gather(table, nb, wt, ct):
     """XLA twin of the gather-fused Gram kernels' in-kernel row fetch —
-    the interpret/old-jax route, so CPU CI exercises the same code shape
-    the Mosaic DMA gather runs.
+    the interpret route, so CPU CI exercises the same code shape the
+    Mosaic DMA gather runs.
 
     The Mosaic kernels (``ops.pallas.gram_kernel`` ``*_gather_pallas``)
     keep the RAW fixed table in HBM/ANY memory, DMA each tile's indexed
@@ -120,11 +67,8 @@ def emulate_in_kernel_gather(table, nb, wt, ct):
 
     k = table.shape[-1]
     zrow = jnp.zeros((1, k), table.dtype)
-    try:  # mark the zero row varying like the table under shard_map
-        vma = jax.typeof(table).vma
-    except (AttributeError, TypeError):
-        vma = None
-    if vma:
+    vma = typeof_vma(table)
+    if vma:  # mark the zero row varying like the table under shard_map
         zrow = to_varying(zrow, tuple(vma))
     fz = jnp.concatenate([table, zrow])
     g = fz[nb].astype(ct)
@@ -135,7 +79,7 @@ def emulate_in_kernel_gather(table, nb, wt, ct):
 
 def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
                         tile_m, row_offset=0):
-    """XLA twin of the serving score+top-K kernel — the interpret/old-jax
+    """XLA twin of the serving score+top-K kernel — the sharded-interpret
     route, so CPU CI exercises the same code shape the Mosaic kernel runs.
 
     Scans the SAME per-tile fold the kernel body runs
@@ -158,31 +102,38 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
     tbl = table.reshape(nt, tile_m, -1)
     sc = (None if scale is None
           else scale.reshape(nt, tile_m, 1).astype(jnp.float32))
-    carry0 = (
-        jnp.full((b, k_top), -jnp.inf, jnp.float32),
-        jnp.full((b, k_top), -1, jnp.int32),
+    # slot-major, like the kernel's block: one exclusion slot = one row
+    seen = None if seen_tiles is None else jnp.swapaxes(seen_tiles, 1, 2)
+    carry0 = jax.tree.map(
+        lambda z: match_varying(z, table),
+        (jnp.full((k_top, b), -jnp.inf, jnp.float32),
+         jnp.full((k_top, b), -1, jnp.int32)),
     )
 
     off = jnp.asarray(row_offset, jnp.int32)
 
     def step(carry, i):
         idx = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+        seen_i = None if seen is None else idx(seen)
         v, ids = _score_tile_fold(
             carry[0], carry[1], u, idx(tbl),
             None if sc is None else idx(sc),
-            None if seen_tiles is None else idx(seen_tiles),
+            None if seen is None else (
+                lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
+            ),
+            0 if seen is None else seen.shape[1],
             off + i * tile_m,
             num_movies=num_movies, k_top=k_top,
         )
         return (v, ids), None
 
     (vals, ids), _ = lax.scan(step, carry0, jnp.arange(nt, dtype=jnp.int32))
-    return vals, ids
+    return vals.T, ids.T
 
 
 def emulate_fused_gram_solve(a, b, reg, *, reg_mode, lam, lseg):
-    """XLA twin of the fused Gram+solve epilogue — the interpret/old-jax
-    route, so CPU CI exercises the same code shape the Mosaic kernel runs.
+    """XLA twin of the fused Gram+solve epilogue — the interpret route, so
+    CPU CI exercises the same code shape the Mosaic kernel runs.
 
     Given the chunk's emulated (A [S, k, k], b [S, k]) normal-equation
     sums, return exactly what ``gram_solve_tiles_pallas`` returns:

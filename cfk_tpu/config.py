@@ -94,63 +94,55 @@ def apply_overlap_xla_flags(config: "ALSConfig") -> None:
     set_async_collective_permute(config.async_collective_permute)
 
 
-def enable_compile_cache(cache_dir: str | None) -> str | None:
-    """Wire jax's persistent compilation cache at ``cache_dir`` (the
-    ``ALSConfig.compile_cache_dir`` / ``--compile-cache-dir`` seam,
-    ISSUE 13).  Returns the resolved per-device directory, or None when
-    disabled/unsupported.
+# The persistent compilation cache when nothing else names one: ONE fixed,
+# git-ignored directory at the root of the checkout.  Fixed because the path
+# is part of jax's cache key discipline in practice — a directory that moves
+# (a temp name, a pid, a timestamp) never hits — and named without asking a
+# backend anything, so it exists before the first device is touched.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache",
+)
 
-    Key discipline: the cache lives in a SUBDIRECTORY keyed by the
-    device fingerprint (``plan.DeviceSpec.fingerprint()`` — backend,
-    device kind, device count: the same key the autotune cache trusts
-    measured winners by), so one shared tree never replays an
-    executable compiled for different hardware.  The thresholds are
-    lowered to cache every program — the fold-in/serve bucket programs
-    this exists for compile in milliseconds each but number dozens per
-    cold process (the PR 6 re-trace bound, paid again as re-COMPILE on
-    every restart).
 
-    Must run BEFORE the first compile to cover it (trainer/session/
-    engine entries call this; jax ignores dir changes for programs
-    already compiled).  Idempotent; failures (an old jax without the
-    config knobs, an unwritable path) degrade to a no-op with a warning
-    rather than failing training."""
-    if not cache_dir:
-        return None
-    import os as _os
-    import warnings as _warnings
+def enable_compile_cache(cache_dir: str | None = None) -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
 
-    try:
-        import jax as _jax
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself uses it and
+    nothing here names another directory — ``cache_dir`` (the
+    ``ALSConfig.compile_cache_dir`` / ``--compile-cache-dir`` seam)
+    included: the environment owns the location, because only a directory
+    the machine was started with survives from one run to the next.
+    Otherwise the cache lives at ``cache_dir``, else at
+    ``DEFAULT_COMPILE_CACHE_DIR``.  jax's own keying (program, compile
+    options, backend and device kind) keeps executables for different
+    hardware apart inside one directory.
 
-        from cfk_tpu.plan.spec import DeviceSpec
+    The thresholds are lowered to cache every program — the fold-in/serve
+    bucket programs this exists for compile in milliseconds each but number
+    dozens per cold process.  Must run BEFORE the first compile to cover it
+    (trainer/session/engine entries call this; jax ignores dir changes for
+    programs already compiled).  Idempotent.  A directory that cannot be
+    created or written raises: a silently cold cache costs every later run
+    its compile time."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-        sub = _os.path.join(
-            cache_dir, DeviceSpec.detect().fingerprint().replace(":", "_")
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = env_dir or cache_dir or DEFAULT_COMPILE_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    if not os.access(path, os.W_OK | os.X_OK):
+        raise PermissionError(
+            f"compile cache directory {path!r} is not writable"
         )
-        _os.makedirs(sub, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", sub)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                           0.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        try:
-            # jax latches "no cache" on the first compile that ran
-            # without a dir; reset so the next compile re-initializes
-            # against the directory just configured (measured on 0.4.37:
-            # without this, a dir set after any compile is ignored with
-            # "cache is disabled/not initialized").
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass  # newer jax may not need (or expose) the reset
-        return sub
-    except Exception as e:  # pragma: no cover - jax/filesystem specific
-        _warnings.warn(
-            f"persistent compilation cache disabled ({e}); training "
-            "continues with cold compiles"
-        )
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not env_dir and jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax latches "no cache" on the first compile that ran without a
+        # dir; reset so the next compile initializes against this one.
+        cc.reset_cache()
+    return path
 
 
 @dataclasses.dataclass(frozen=True)
@@ -436,13 +428,12 @@ class ALSConfig:
     # bitwise the fully-staged ones); only staged PCIe bytes change.
     hot_rows: int | None = None
     # --- warm-start compile caching (ISSUE 13) --------------------------
-    # Directory for jax's persistent compilation cache.  None disables
-    # (today's behavior).  A path is keyed per device fingerprint (the
-    # autotune cache's discipline — a winner compiled on one backend
-    # must not collide with another's), so one tree serves mixed fleets;
-    # trainers/serving/streaming apply it at entry via
-    # enable_compile_cache(), BEFORE their first compile.  Cold-process
-    # time-to-first-step/batch is what it buys; trace counts are
+    # Directory for jax's persistent compilation cache; trainers/serving/
+    # streaming apply it at entry via enable_compile_cache(), BEFORE their
+    # first compile.  None = the fixed in-checkout default
+    # (DEFAULT_COMPILE_CACHE_DIR).  Where JAX_COMPILATION_CACHE_DIR is set
+    # the environment wins and this field is not consulted.  Cold-process
+    # time-to-first-step/batch is what the cache buys; trace counts are
     # unchanged (tracing is jax-side — the cache removes the XLA compile
     # behind each trace).
     compile_cache_dir: str | None = None

@@ -788,6 +788,7 @@ def build_tiled_blocks(
     accum_max_entities: int = 1 << 16,
     ring: bool = False,
     dense_stream: bool = False,
+    accum_chunk_elems: int | None = None,
 ) -> TiledBlocks:
     """Pad entity runs to tiles and pack into chunks (one mode per side).
 
@@ -799,7 +800,9 @@ def build_tiled_blocks(
     (``_build_dense_stream`` — the measured explicit-ALS default at
     scale; iALS runs it too via the weighted channels, but measured
     slower than the padded stream at the ML-25M rank-128 target, see
-    BASELINE.md round-4 notes).
+    BASELINE.md round-4 notes).  ``accum_chunk_elems`` overrides
+    ``chunk_elems`` on a side that resolves to accum mode (the measured
+    knees differ: 64k stream chunks, 256k accum chunks at Netflix shape).
     """
     if dense_stream and not ring:
         e_l = _round_up(num_solve_entities, num_shards) // num_shards
@@ -864,6 +867,8 @@ def build_tiled_blocks(
         minlength=num_solve_entities,
     ).astype(np.float32)
 
+    if mode == "accum" and accum_chunk_elems is not None:
+        chunk_elems = accum_chunk_elems
     cap = max(t, ((chunk_elems or (1 << 20)) // t) * t)
     nt = cap // t
 
@@ -1504,6 +1509,8 @@ class Dataset:
         dense_stream: bool = False,
         ring_warn: bool = True,
         tile_rows: int = 128,
+        accum_chunk_elems: int | None = None,
+        slice_rows: int = TILED_SLICE_ROWS_DEFAULT,
     ) -> "Dataset":
         """``ring`` (tiled layout): False/True build both halves for the
         all_gather/ring exchange; a ``(movie_ring, user_ring)`` tuple sets
@@ -1522,7 +1529,10 @@ class Dataset:
         per-shard solve entities fit ``accum_max_entities`` — e.g. the
         movie half at Netflix shape) keeps the accum layout by design, and
         ring halves carry the accum machinery too, so ``ring=True`` +
-        ``dense_stream=True`` leaves no half for the flag and warns."""
+        ``dense_stream=True`` leaves no half for the flag and warns.
+        ``accum_chunk_elems`` (tiled layout) sizes the chunks of a half
+        that runs in accum mode; ``chunk_elems`` sizes the rest.
+        ``slice_rows`` is the accum halves' gather-slice height."""
         movie_map, m_dense = index_entities(coo.movie_raw)
         user_map, u_dense = index_entities(coo.user_raw)
         if layout == "bucketed":
@@ -1558,6 +1568,8 @@ class Dataset:
                 accum_max_entities=accum_max_entities,
                 dense_stream=dense_stream,
                 tile_rows=tile_rows,
+                accum_chunk_elems=accum_chunk_elems,
+                slice_rows=slice_rows,
             )
         elif layout == "padded":
             build = functools.partial(

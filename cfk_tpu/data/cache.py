@@ -299,40 +299,12 @@ def cached_scale_dataset(
             return ds
     t0 = time.time()
     coo = synthetic_netflix_coo(users, movies, nnz, seed=seed)
-    if layout == "tiled":
-        from cfk_tpu.data.blocks import (
-            RatingsCOO,
-            build_tiled_blocks,
-            index_entities,
-        )
-
-        movie_map, m_dense = index_entities(coo.movie_raw)
-        user_map, u_dense = index_entities(coo.user_raw)
-        mb = build_tiled_blocks(
-            m_dense, u_dense, coo.rating,
-            movie_map.num_entities, user_map.num_entities,
-            tile_rows=tile_rows,
-            chunk_elems=(chunk_elems if accum_chunk_elems is None
-                         else accum_chunk_elems),
-            slice_rows=slice_rows,
-        )
-        ub = build_tiled_blocks(
-            u_dense, m_dense, coo.rating,
-            user_map.num_entities, movie_map.num_entities,
-            tile_rows=tile_rows, chunk_elems=chunk_elems,
-            slice_rows=slice_rows, dense_stream=dense_stream,
-        )
-        ds = Dataset(
-            movie_map=movie_map, user_map=user_map,
-            movie_blocks=mb, user_blocks=ub,
-            coo_dense=RatingsCOO(
-                movie_raw=m_dense.astype(np.int64),
-                user_raw=u_dense.astype(np.int64),
-                rating=coo.rating.astype(np.float32),
-            ),
-        )
-    else:
-        ds = Dataset.from_coo(coo, layout=layout, chunk_elems=chunk_elems)
+    tiled_kw = dict(
+        tile_rows=tile_rows, slice_rows=slice_rows,
+        accum_chunk_elems=accum_chunk_elems, dense_stream=dense_stream,
+    ) if layout == "tiled" else {}
+    ds = Dataset.from_coo(coo, layout=layout, chunk_elems=chunk_elems,
+                          **tiled_kw)
     log(f"# dataset built in {time.time()-t0:.1f}s", flush=True)
     os.makedirs(root, exist_ok=True)
     ds.save(path, build_key=key)

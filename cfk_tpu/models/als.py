@@ -157,22 +157,25 @@ def _bucketed_device_setup(dataset: Dataset):
     return mblocks, ublocks, u_stats, layout_kw
 
 
-def _tiled_to_device(blocks: TiledBlocks, weighted: bool = False
-                     ) -> dict[str, jax.Array]:
+def _tiled_host_arrays(blocks: TiledBlocks, weighted: bool = False
+                       ) -> dict[str, np.ndarray]:
+    """The host arrays one tiled half-step reads, keyed as the device dict
+    (``_tiled_to_device`` uploads exactly these; ``chip_smoke.py`` derives
+    the step program's avals from them)."""
     if blocks.mode == "dstream":
         # Window metadata rides in tile_meta; upload only what the model's
         # kernel reads — the weighted channels (tile-aligned weight +
         # stream-aligned rating_dense, ~1 GB at full Netflix) only for
         # iALS, never for the unit-weight explicit path.
         d = {
-            "neighbor_idx": jnp.asarray(blocks.neighbor_idx),
-            "rating": jnp.asarray(blocks.rating),
-            "tile_meta": jnp.asarray(blocks.tile_meta),
-            "chunk_entity": jnp.asarray(blocks.chunk_entity),
-            "chunk_count": jnp.asarray(blocks.chunk_count),
-            "carry_in": jnp.asarray(blocks.carry_in),
-            "last_seg": jnp.asarray(blocks.last_seg),
-            "count": jnp.asarray(blocks.count),
+            "neighbor_idx": blocks.neighbor_idx,
+            "rating": blocks.rating,
+            "tile_meta": blocks.tile_meta,
+            "chunk_entity": blocks.chunk_entity,
+            "chunk_count": blocks.chunk_count,
+            "carry_in": blocks.carry_in,
+            "last_seg": blocks.last_seg,
+            "count": blocks.count,
         }
         if weighted:
             if not blocks.weight.size or blocks.rating_dense is None:
@@ -180,26 +183,44 @@ def _tiled_to_device(blocks: TiledBlocks, weighted: bool = False
                     "these dense-stream blocks predate the weighted "
                     "channels — rebuild the dataset (delete its cache)"
                 )
-            d["weight"] = jnp.asarray(blocks.weight)
-            d["rating_dense"] = jnp.asarray(blocks.rating_dense)
+            d["weight"] = blocks.weight
+            d["rating_dense"] = blocks.rating_dense
         return d
     return {
-        "neighbor_idx": jnp.asarray(blocks.neighbor_idx),
-        "rating": jnp.asarray(blocks.rating),
-        "weight": jnp.asarray(blocks.weight),
-        "tile_seg": jnp.asarray(blocks.tile_seg),
-        "chunk_base": jnp.asarray(blocks.chunk_base),
-        "chunk_entity": jnp.asarray(blocks.chunk_entity),
-        "chunk_count": jnp.asarray(blocks.chunk_count),
-        "carry_in": jnp.asarray(blocks.carry_in),
-        "last_seg": jnp.asarray(blocks.last_seg),
-        "slice_starts": jnp.asarray(blocks.slice_starts),
-        "count": jnp.asarray(blocks.count),
+        "neighbor_idx": blocks.neighbor_idx,
+        "rating": blocks.rating,
+        "weight": blocks.weight,
+        "tile_seg": blocks.tile_seg,
+        "chunk_base": blocks.chunk_base,
+        "chunk_entity": blocks.chunk_entity,
+        "chunk_count": blocks.chunk_count,
+        "carry_in": blocks.carry_in,
+        "last_seg": blocks.last_seg,
+        "slice_starts": blocks.slice_starts,
+        "count": blocks.count,
     }
 
 
+def _tiled_to_device(blocks: TiledBlocks, weighted: bool = False
+                     ) -> dict[str, jax.Array]:
+    return {name: jnp.asarray(x)
+            for name, x in _tiled_host_arrays(blocks, weighted).items()}
+
+
+def _tiled_layout_kw(dataset: Dataset) -> dict:
+    """The tiled layout's static step kwargs; statics carry
+    ("tiled", mode, ...)."""
+    mb, ub = dataset.movie_blocks, dataset.user_blocks
+    return dict(
+        m_chunks=("tiled", mb.mode) + mb.statics,
+        u_chunks=("tiled", ub.mode) + ub.statics,
+        m_entities=mb.padded_entities,
+        u_entities=ub.padded_entities,
+    )
+
+
 def _tiled_device_setup(dataset: Dataset, weighted: bool = False):
-    """Single-device tiled-layout setup; statics carry ("tiled", mode, ...).
+    """Single-device tiled-layout setup.
 
     ``weighted=True`` (the iALS trainer) stages the dense-stream weighted
     channels too."""
@@ -209,14 +230,33 @@ def _tiled_device_setup(dataset: Dataset, weighted: bool = False):
         "rating_sum": jnp.asarray(ub.rating_sum),
         "count": jnp.asarray(ub.count),
     }
-    layout_kw = dict(
-        m_chunks=("tiled", mb.mode) + mb.statics,
-        u_chunks=("tiled", ub.mode) + ub.statics,
-        m_entities=mb.padded_entities,
-        u_entities=ub.padded_entities,
-    )
     return (_tiled_to_device(mb, weighted), _tiled_to_device(ub, weighted),
-            u_stats, layout_kw)
+            u_stats, _tiled_layout_kw(dataset))
+
+
+def _train_loop_statics(config: ALSConfig, knobs: dict, *, solve_chunk,
+                        health) -> dict:
+    """``_train_loop``'s static arguments for a config under its resolved
+    plan knobs — ``train_als`` calls the loop with exactly these, and
+    ``chip_smoke.py`` compiles the same program from avals with them."""
+    return dict(
+        rank=config.rank,
+        num_iterations=config.num_iterations,
+        lam=config.lam,
+        solve_chunk=solve_chunk,
+        dtype=config.dtype,
+        solver=knobs["solver"],
+        algorithm=config.algorithm,
+        block_size=config.block_size,
+        sweeps=config.sweeps,
+        overlap=knobs["overlap"],
+        fused_epilogue=knobs["fused_epilogue"],
+        in_kernel_gather=knobs["in_kernel_gather"],
+        reg_solve_algo=knobs["reg_solve_algo"],
+        table_dtype=knobs["table_dtype"],
+        health_every=None if health is None else health.every,
+        health_norm_limit=0.0 if health is None else health.norm_limit,
+    )
 
 
 def _segment_device_setup(dataset: Dataset):
@@ -628,28 +668,9 @@ def train_als(
         with metrics.phase("train"), \
                 span("train/fused_loop", iters=config.num_iterations):
             out = _train_loop(
-                key,
-                mblocks,
-                ublocks,
-                u_stats,
-                rank=config.rank,
-                num_iterations=config.num_iterations,
-                lam=config.lam,
-                solve_chunk=solve_chunk,
-                dtype=config.dtype,
-                solver=knobs["solver"],
-                algorithm=config.algorithm,
-                block_size=config.block_size,
-                sweeps=config.sweeps,
-                overlap=knobs["overlap"],
-                fused_epilogue=knobs["fused_epilogue"],
-                in_kernel_gather=knobs["in_kernel_gather"],
-                reg_solve_algo=knobs["reg_solve_algo"],
-                table_dtype=knobs["table_dtype"],
-                health_every=None if health is None else health.every,
-                health_norm_limit=(
-                    0.0 if health is None else health.norm_limit
-                ),
+                key, mblocks, ublocks, u_stats,
+                **_train_loop_statics(config, knobs, solve_chunk=solve_chunk,
+                                      health=health),
                 **layout_kw,
             )
             u, m = out[0], out[1]

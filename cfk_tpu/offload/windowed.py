@@ -869,10 +869,11 @@ def ring_windowed_half_step(
     nt = cap // t
     local = rplan.local_entities
     backend = default_tiled_gram_backend()
+    stage_name = _stage_dtype(fixed_store.dtype, table_dtype)
     gather = resolve_gather_mode(
         in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        table_dtype=stage_name,
     )
-    stage_name = _stage_dtype(fixed_store.dtype, table_dtype)
     int8 = stage_name == "int8"
     schedule = rplan.schedule(visits)
     own = stager is None
@@ -2506,7 +2507,7 @@ def _bucket_window_impl(tbl, scale, nb, rt, mk, gram, *, shape, lam, alpha,
         rows = ni.shape[0]
         modes = bport.resolve_bucket_modes(
             fused_epilogue, in_kernel_gather, solver, rows, width, k,
-            None, reg_solve_algo,
+            None, reg_solve_algo, table_dtype=tbl.dtype,
         )
         if modes is None:
             a_obs, b = gather_gram_implicit(view, ni, alpha * rt_c, mk_c)

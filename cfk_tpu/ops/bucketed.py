@@ -66,23 +66,25 @@ def bucket_port_supported(rows: int, width: int, k: int) -> bool:
     classes keep the legacy XLA schedule — same math, the measured-slow
     path — never a compile failure.
     """
-    from cfk_tpu.ops.pallas.gram_kernel import in_kernel_gather_supported
+    from cfk_tpu.ops.pallas.gram_kernel import gather_prefetch_fits
 
     if width < 16 or width % 16:
         return False
-    return in_kernel_gather_supported(width, 3, width)
+    return gather_prefetch_fits(width, 3)
 
 
 def _sub_rows(rows: int, width: int, k: int, fused: bool,
               algo: str | None) -> int:
     """Rows per kernel call: the largest power-of-two piece whose
-    flattened entry count passes the SMEM gate (and whose segment count
-    passes the fused epilogue's scratch gate when fused).  The bucket is
+    flattened entry count passes the SMEM gate and whose segment count
+    passes the fused epilogue's scratch gate (fused) or the split Gram
+    kernels' resident-output cap (the same 96 MB ``ops.tiled`` falls back
+    on).  The bucket is
     row-padded to a multiple and lax.map'd — each entity is wholly inside
     its own row, so pieces need no cross-piece accumulation."""
     from cfk_tpu.ops.pallas.gram_kernel import (
         fused_gram_solve_supported,
-        in_kernel_gather_supported,
+        gather_prefetch_fits,
     )
 
     sub = 1
@@ -90,17 +92,20 @@ def _sub_rows(rows: int, width: int, k: int, fused: bool,
         nxt = sub * 2
         if nxt > rows:
             break
-        if not in_kernel_gather_supported(nxt * width, nxt + 2, width):
+        if not gather_prefetch_fits(nxt * width, nxt + 2):
             break
         if fused and not fused_gram_solve_supported(nxt, k, algo):
             break
+        if not fused and 2 * nxt * k * (k + 1) * 4 > (96 << 20):
+            break  # the split kernels' resident (A, b) output cap
         sub = nxt
     return sub
 
 
 def resolve_bucket_modes(fused_epilogue, in_kernel_gather, solver,
                          rows: int, width: int, k: int, lam,
-                         algo: str | None) -> tuple[bool, str] | None:
+                         algo: str | None, *,
+                         table_dtype) -> tuple[bool, str] | None:
     """Static gating of the ported bucket piece.
 
     Returns (fused, gather) — ``None`` keeps the legacy XLA schedule.
@@ -123,6 +128,7 @@ def resolve_bucket_modes(fused_epilogue, in_kernel_gather, solver,
         return None
     gather = resolve_gather_mode(
         in_kernel_gather, "pallas", "full", width, 3, width, 2, k,
+        table_dtype=table_dtype,
     )
     lam_f = resolve_fused_chunk_lam(
         fused_epilogue, solver, k, 1, "pallas",
@@ -231,7 +237,12 @@ def bucket_gram_solve(
         reg_s = reg.reshape(n_pieces, sub)
     else:
         reg_s = jnp.broadcast_to(reg, (n_pieces,) + reg.shape)
-    x = lax.map(piece, (nb_s, wt_s, rt_s, reg_s))
+    # The barrier keeps each piece's kernels out of XLA's output fusions:
+    # fused with the loop's stacked-output update, a Mosaic call is held to
+    # the default 16 MiB scoped-VMEM limit instead of its own
+    # ``vmem_limit_bytes``, and the v5e compiler then refuses it.
+    x = lax.map(lambda args: lax.optimization_barrier(piece(args)),
+                (nb_s, wt_s, rt_s, reg_s))
     return x.reshape(n_pieces * sub, k)[:rows]
 
 

@@ -47,16 +47,34 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from cfk_tpu.compat import has_vma_system, typeof_vma
+from cfk_tpu.compat import typeof_vma
+from cfk_tpu.ops.pallas.interpret import resolve_interpret
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific extensions; absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _SOLVE_LANES = 128  # lane width of the fused epilogue's solve tiles — the
 # same 128-system batching the standalone solve kernels use
+
+
+def _dense_window_bytes(block_rows: int, group_rows: int, k: int) -> int:
+    """VMEM of the dense kernels' double-buffered input windows: the
+    [BG, k] stream block, lane-padded to 128 and charged 4 B/element
+    whatever its dtype (a ceiling for bf16), plus the [1, m·T] b row.
+    A rank-64 float32 stream really occupies the padded 32 MiB at
+    BG = 32k — budgeted at k lanes, the v5e compiler refused the fused
+    kernel by 13 MiB (on the chip, PR 21)."""
+    return 2 * (block_rows * max(k, 128) * 4 + group_rows * 4)
+
+
+def _walk_stack_bytes(m: int, k: int) -> int:
+    """VMEM the unrolled walk keeps on Mosaic's stack: a group's m tile Grams
+    are all issued before the walk (``_tile_grams``), each a lane-padded
+    [k, k] plus a [1, k] that occupies a whole (8, 128) tile, and the walk's
+    running partials double them.  Negligible at the tiled layout's m = 64
+    (~5 MB at k = 64); the bucketed port's m = 4096 // width reaches 256,
+    where the v5e compiler allocated 12 MiB more than a budget without this
+    term."""
+    return 2 * m * (k * max(k, 128) + 8 * 128) * 4
 
 
 def _tile_grams(g_ref, rt_ref, *, m, t, k, precision, row_off=None):
@@ -351,17 +369,14 @@ def gram_tiles_dense_pallas(
     if c % bg != 0 or bg < t:
         raise ValueError(f"stream length {c} not a multiple of block_rows "
                          f"{bg} >= tile_rows {t}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         # Vectorized emulation (CPU tests, shard_map interpret — same vma
         # rationale as gram_tiles_pallas): zeros for absent rows.
         return _emulate_gram_dense(
             g, rt, meta, num_segments=num_segments, tile_rows=t,
             num_tiles=nt, num_groups=ng, block_rows=bg, carry=carry,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
 
     vma = typeof_vma(g)
     mk = (lambda s, d: jax.ShapeDtypeStruct(s, d, vma=vma)) if vma else (
@@ -392,13 +407,10 @@ def gram_tiles_dense_pallas(
         jax.lax.Precision.HIGHEST if g.dtype == jnp.float32 else None
     )
     out_bytes = num_segments * k * (k + 1) * 4
-    # Mosaic budgets input windows at 4 B/elem even for bf16 (measured in
-    # the compile-OOM dump), and the resident output at 2× its bytes.
-    in_bytes = 2 * (bg * k * 4 + m * t * 4)
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    # The resident output is budgeted at 2× its bytes; the windows as
+    # _dense_window_bytes counts them.
+    in_bytes = _dense_window_bytes(bg, m * t, k)
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(2 * out_bytes + in_bytes + (10 << 20),
                              124 << 20)
     )}
@@ -414,7 +426,7 @@ def gram_tiles_dense_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(meta, g, rt.reshape(1, nt * t), *carry_ops)
     return a, b[:, 0, :]
@@ -467,18 +479,16 @@ def gram_tiles_pallas(
     nt = c // t
     if seg.shape != (nt,):
         raise ValueError(f"seg shape {seg.shape} != ({nt},)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret and (typeof_vma(g) or not has_vma_system()):
+    interpret = resolve_interpret(interpret)
+    if interpret is True and typeof_vma(g):
         # Under shard_map with vma checking, the pallas HLO interpreter's
         # grid loop slices varying operands with unvarying grid counters
         # and fails the vma match.  Mosaic compilation is unaffected (the
         # indexing lives inside the kernel binary), so only CPU-interpret
         # sharded runs (tests, dryrun_multichip) take this branch: the
         # same math via segment-sum, zeros for absent rows (a superset of
-        # the kernel's unspecified-rows contract).  Old-jax installs
-        # (no vma system) take it too: their HLO interpreter predates
-        # this kernel's patterns and runs orders of magnitude slower.
+        # the kernel's unspecified-rows contract).  Outside shard_map this
+        # kernel's interpret route is its own body.
         return _emulate_gram_tiles(
             g, rt, seg, num_segments=num_segments, tile_rows=tile_rows,
             carry=carry,
@@ -495,8 +505,6 @@ def gram_tiles_pallas(
         mk((num_segments, k, k), jnp.float32),
         mk((num_segments, 1, k), jnp.float32),
     )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     fac_spec = pl.BlockSpec((m * t, k), lambda i, seg: (i, 0))
     carry_specs = [] if carry is None else [
         pl.BlockSpec((k, k), lambda i, seg: (0, 0)),
@@ -527,11 +535,9 @@ def gram_tiles_pallas(
         # 16 MB scoped allowance is far too small for S ≈ 2.5k segments).
         out_bytes = num_segments * k * (k + 1) * 4
         in_bytes = 2 * (m * t * (k + 1) * 4)
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        kwargs["compiler_params"] = params(
-            vmem_limit_bytes=min(2 * out_bytes + 4 * in_bytes + (12 << 20),
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(2 * out_bytes + 4 * in_bytes
+                                 + _walk_stack_bytes(m, k) + (12 << 20),
                                  110 << 20)
         )
     carry_ops = [] if carry is None else [
@@ -546,7 +552,7 @@ def gram_tiles_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(seg, g, rt.reshape(1, c), *carry_ops)
     return a, b[:, 0, :]
@@ -554,7 +560,7 @@ def gram_tiles_pallas(
 
 def _emulate_gram_tiles(g, rt, seg, *, num_segments, tile_rows, carry):
     """XLA segment-sum emulation of the grouped-Gram kernel (interpret /
-    shard_map-vma / old-jax routes): zeros for absent rows — a superset of
+    shard_map-vma routes): zeros for absent rows — a superset of
     the kernel's unspecified-rows contract."""
     k = g.shape[-1]
     prec = (jax.lax.Precision.HIGHEST if g.dtype == jnp.float32 else None)
@@ -615,13 +621,22 @@ def _emulate_gram_dense(g, rt, meta, *, num_segments, tile_rows, num_tiles,
 
 
 def _fused_scratch_bytes(s_pad: int, k: int) -> int:
-    """VMEM bytes of the fused epilogue's resident state: the (A, b)
-    scratch plus the elimination's [k, k, 128]-class temporaries (budgeted
-    at the worst case — LU's three scratch buffers plus the in-register
-    transposed tile).  ONE formula, shared by the support gate below and
-    the pallas_call budget (``_fused_call_pieces``), so the two can never
-    drift into a gate that admits a shape the compiler then rejects."""
-    return (s_pad * k * (k + 1) + 4 * k * k * _SOLVE_LANES) * 4
+    """VMEM bytes of the fused epilogue's resident state, as Mosaic lays it
+    out: the A scratch; the b scratch, whose [1, k] rows each occupy a whole
+    (8, 128) tile; the solved-rows output block, double-buffered and
+    lane-padded; and EIGHT [k, k, 128] float32 temporaries for the
+    elimination — LU's scratch buffers (one such block) and the ~seven the
+    unrolled elimination keeps on Mosaic's stack.  Fitted to the v5e
+    compiler's own accounting (its out-of-VMEM reports) at three shapes with
+    32k-row dense blocks: k = 128, S = 384 allocated 104.19 MiB with bf16
+    input windows and 120.59 MiB with f32 ones (this formula + windows:
+    105.9 / 121.9); k = 64, S = 2688 allocated 87.41 MiB (87.1).  ONE
+    formula, shared by the support gate below and the pallas_call budget
+    (``_fused_call_pieces``), so the two can never drift into a gate that
+    admits a shape the compiler then rejects."""
+    tile = 8 * 128
+    return 4 * (s_pad * k * k + s_pad * tile + 2 * s_pad * max(k, 128)
+                + 8 * k * k * _SOLVE_LANES)
 
 
 def fused_gram_solve_supported(num_segments: int, k: int,
@@ -632,13 +647,18 @@ def fused_gram_solve_supported(num_segments: int, k: int,
     (LU 128 / GJ 64 — past it the dispatcher's cholesky/Schur backends are
     needed, which only exist as separate passes; ``algo`` threads the
     caller's elimination choice, None/'auto' = the process default), and
-    the lane-padded (A, b) scratch (``_fused_scratch_bytes`` — same
-    formula the compile budget uses) must leave VMEM headroom for the
-    double-buffered input blocks under the ~124 MB scoped ceiling.  The
-    72 MB gate reserves ≥ 50 MB for inputs (the gate cannot see the
-    chunk's block size, so it is conservative: a refused shape takes the
-    split path — same math, one extra round-trip — never a Mosaic compile
-    failure).
+    the lane-padded (A, b) scratch plus the elimination's temporaries
+    (``_fused_scratch_bytes`` — same formula the compile budget uses) must
+    leave VMEM headroom for the double-buffered input blocks under the
+    ~124 MB scoped ceiling.  The 72 MB gate reserves ≥ 50 MB for inputs
+    (the gate cannot see the chunk's block size, so it is conservative: a
+    refused shape takes the split path — same math, one extra round-trip —
+    never a Mosaic compile failure).  At rank 128 the temporaries alone
+    are 64 MiB, so the gate refuses every rank-128 chunk: with 32k-row
+    dense blocks the v5e compiler ran out of VMEM, and rank 128 takes the
+    split Gram kernel + standalone LU-128 solve — the route the chip
+    record was taken on.  At rank 64 it refuses chunks of more than ~2.4k
+    segments (short rows: many entities per chunk).
     """
     from cfk_tpu.ops.pallas.solve_kernel import _fused_reg_rank_cap
 
@@ -818,7 +838,7 @@ def gram_solve_tiles_pallas(
     (~2·Ec·k² f32 of pure HBM traffic per chunk) that PR 1's prefetch
     pipelines left as the exposed hot path.
 
-    Off-TPU (interpret) and on old-jax installs this routes to the
+    Off-TPU (interpret) this routes to the
     XLA-emulation twin (``cfk_tpu.compat.emulate_fused_gram_solve``): the
     same segment-sum Gram the split path emulates plus the interpret-mode
     fused reg+solve kernel — bit-identical to running split with
@@ -829,8 +849,6 @@ def gram_solve_tiles_pallas(
     from cfk_tpu.ops.pallas.solve_kernel import resolve_reg_solve_algo
 
     algo = resolve_reg_solve_algo(algo)
-    if algo == "lu" and pltpu is None:  # pragma: no cover - non-TPU build
-        algo = "gj"
     return _gram_solve_tiles_pallas(
         g, rt, seg, reg, lseg, num_segments=num_segments,
         tile_rows=tile_rows, group_tiles=group_tiles, reg_mode=reg_mode,
@@ -855,11 +873,10 @@ def _gram_solve_tiles_pallas(
     if seg.shape != (nt,):
         raise ValueError(f"seg shape {seg.shape} != ({nt},)")
     _check_reg_shape(reg, reg_mode, num_segments, k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
-        # The XLA-emulation twin (compat.py): CPU CI and old-jax installs
-        # exercise the same fused code shape without Mosaic.
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
+        # The XLA-emulation twin (compat.py): CPU CI exercises the same
+        # fused code shape without Mosaic.
         from cfk_tpu.compat import emulate_fused_gram_solve
 
         a, b = _emulate_gram_tiles(
@@ -868,8 +885,6 @@ def _gram_solve_tiles_pallas(
         return emulate_fused_gram_solve(
             a, b, reg, reg_mode=reg_mode, lam=lam, lseg=lseg,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     m = group_tiles
     while nt % m != 0:  # grid must tile exactly; m=1 always divides
         m //= 2
@@ -895,11 +910,9 @@ def _gram_solve_tiles_pallas(
         jax.lax.Precision.HIGHEST if g.dtype == jnp.float32 else None
     )
     in_bytes = 2 * (m * t * (k + 1) * 4)
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
-        vmem_limit_bytes=min(scratch_bytes + 4 * in_bytes + (12 << 20),
+    kwargs = {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(scratch_bytes + 4 * in_bytes
+                             + _walk_stack_bytes(m, k) + (12 << 20),
                              124 << 20)
     )}
     x, cao, cbo = pl.pallas_call(
@@ -911,7 +924,7 @@ def _gram_solve_tiles_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(seg_plus, g, rt.reshape(1, c), reg_op, *carry_ops)
     return x[:num_segments], cao, cbo[0]
@@ -942,8 +955,6 @@ def gram_solve_tiles_dense_pallas(
     from cfk_tpu.ops.pallas.solve_kernel import resolve_reg_solve_algo
 
     algo = resolve_reg_solve_algo(algo)
-    if algo == "lu" and pltpu is None:  # pragma: no cover - non-TPU build
-        algo = "gj"
     return _gram_solve_tiles_dense_pallas(
         g, rt, meta, reg, lseg, num_segments=num_segments,
         tile_rows=tile_rows, num_tiles=num_tiles, num_groups=num_groups,
@@ -975,9 +986,8 @@ def _gram_solve_tiles_dense_pallas(
         raise ValueError(f"stream length {c} not a multiple of block_rows "
                          f"{bg} >= tile_rows {t}")
     _check_reg_shape(reg, reg_mode, num_segments, k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         from cfk_tpu.compat import emulate_fused_gram_solve
 
         a, b = _emulate_gram_dense(
@@ -987,8 +997,6 @@ def _gram_solve_tiles_dense_pallas(
         return emulate_fused_gram_solve(
             a, b, reg, reg_mode=reg_mode, lam=lam, lseg=lseg,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     s_pad = -(-num_segments // _SOLVE_LANES) * _SOLVE_LANES
     vma = typeof_vma(g)
     (reg_op, reg_spec, carry_ops, carry_specs, out_shape, out_specs,
@@ -1011,11 +1019,8 @@ def _gram_solve_tiles_dense_pallas(
     precision = (
         jax.lax.Precision.HIGHEST if g.dtype == jnp.float32 else None
     )
-    in_bytes = 2 * (bg * k * 4 + m * t * 4)
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    in_bytes = _dense_window_bytes(bg, m * t, k)
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(scratch_bytes + in_bytes + (10 << 20),
                              124 << 20)
     )}
@@ -1027,7 +1032,7 @@ def _gram_solve_tiles_dense_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(meta_plus, g, rt.reshape(1, nt * t), reg_op, *carry_ops)
     return x[:num_segments], cao, cbo[0]
@@ -1049,8 +1054,9 @@ def _gram_solve_tiles_dense_pallas(
 # last real row for the DMA and the per-entry premultiply ``wt`` (the 0/1
 # validity mask for unit weights, √aw·mask for iALS) zeroes padding rows —
 # the [F+1, k] zero-row copy of the table is never built.  Dense-stream
-# padding needs no mask at all: pad slots sit outside every [lo, hi)
-# window, so the existing one-operand window mask annihilates them.
+# padding needs the mask too: a run's 16-row alignment pads sit INSIDE its
+# [lo, hi) window, so the dense wrappers always pass a weight stream — the
+# caller's, times ``nb < F`` (``_dense_gather_weight``).
 # Failure-mode caveat (same class the walk's arithmetic select accepts —
 # see _walk_tiles): clamped-row × 0.0 is exactly 0 only for FINITE table
 # rows; a diverged table (Inf/NaN rows) turns padding slots into NaN via
@@ -1063,7 +1069,7 @@ def _gram_solve_tiles_dense_pallas(
 #
 # Index convention (all gather variants): ``nb == table.shape[0]`` is the
 # virtual zero row; the clamp + wt/window masking makes its contribution
-# exactly 0.  Off-TPU and on old-jax installs the wrappers route to
+# exactly 0.  Off-TPU the wrappers route to
 # ``compat.emulate_in_kernel_gather`` + the existing emulation twins,
 # which run the numerically identical append-zero-row + gather + multiply
 # the XLA-gather path runs — fused-gather vs XLA-gather factors are
@@ -1073,43 +1079,51 @@ def _gram_solve_tiles_dense_pallas(
 
 # Scalar-prefetch budget for the gather variants: the whole index chunk
 # (plus seg/meta words) lives in SMEM.  512 KiB admits the production 64k-
-# entry chunks (64k indices + ~20k meta words ≈ 336 KiB); past it the
-# resolver keeps the XLA-gather path.  Needs on-TPU validation against the
-# real SMEM ceiling (ROADMAP) — a too-large cap fails at Mosaic compile
-# time, never silently.
+# entry chunks (64k indices + ~3k meta words ≈ 268 KiB, which the chip's
+# compiler accepts — tests/test_chip_compile.py); past it the resolver keeps
+# the XLA-gather path.
 _GATHER_SMEM_BYTES_CAP = 512 << 10
 
 
+def gather_prefetch_fits(entries: int, meta_words: int) -> bool:
+    """Does the scalar prefetch (indices + seg/meta + lseg) fit SMEM?"""
+    return (entries + meta_words + 1) * 4 <= _GATHER_SMEM_BYTES_CAP
+
+
 def in_kernel_gather_supported(entries: int, meta_words: int, tile_rows: int,
-                               block_rows: int | None = None) -> bool:
+                               block_rows: int | None = None, *,
+                               k: int, table_dtype,
+                               lowered: bool = True) -> bool:
     """Can the gather-fused kernels handle this chunk shape?
 
-    Gates: the scalar prefetch (indices + seg/meta + lseg) must fit the
-    SMEM budget, and tile/block row counts must be 16-aligned — the
-    double-buffered gather scratch is addressed at ``slot·rows + i·t``
-    dynamic offsets, which Mosaic's sublane slicing only lowers at
-    (16, 128)-tile alignment.  A refused shape keeps the XLA-gather path
-    (same math, the materialized stream) — never a compile failure.
+    Gates, each a refusal of the chip's compiler when crossed
+    (tests/test_chip_compile.py compiles both sides for a v5e):
+
+    - the row DMA (``_gather_dma``) copies ONE table row per descriptor,
+      and Mosaic lowers a one-row slice only of a 32-bit table whose rows
+      are whole 128-lane tiles: float32 at rank 64 fails with "Slice shape
+      along dimension 1 must be aligned to tiling (128), but is 64",
+      bfloat16 and int8 at any rank with "Slice shape along dimension 0
+      must be aligned to tiling (8), but is 1";
+    - the scalar prefetch must fit the SMEM budget;
+    - tile/block row counts must be 16-aligned — the double-buffered
+      gather scratch is addressed at ``slot·rows + i·t`` dynamic offsets,
+      which Mosaic's sublane slicing only lowers at (16, 128)-tile
+      alignment.
+
+    A refused shape keeps the XLA-gather path (same math, the materialized
+    stream feeding the non-gather Pallas kernels) — never a compile
+    failure.  ``lowered=False`` asks for the wrappers' interpret route,
+    where no DMA is lowered (the XLA twin runs, at any rank and dtype) and
+    only the shape gates apply, as they always did off the chip.
     """
+    if lowered and (jnp.dtype(table_dtype) != jnp.float32 or k % 128):
+        return False
     if tile_rows % 16:
         return False
     if block_rows is not None and block_rows % 16:
         return False
-    return (entries + meta_words + 1) * 4 <= _GATHER_SMEM_BYTES_CAP
-
-
-def _any_memory_space():
-    """The compiler-placed (HBM-resident for big operands) memory space
-    across pallas versions — where the gather variants keep the full
-    fixed table."""
-    if pltpu is not None:
-        ms = getattr(pltpu, "ANY", None)
-        if ms is None:
-            tms = getattr(pltpu, "TPUMemorySpace", None)
-            ms = getattr(tms, "ANY", None) if tms is not None else None
-        if ms is not None:
-            return ms
-    return getattr(pl, "ANY", None)  # pragma: no cover - exotic builds
+    return gather_prefetch_fits(entries, meta_words)
 
 
 def _gather_dma(table_ref, g_buf, sem, sc_ref, nb_base, row0, rows, slot,
@@ -1288,13 +1302,14 @@ def _gram_solve_gather_groups_kernel(sc_ref, table_ref, *refs, m, t, k, nt,
 
 
 def _gram_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng, nt, bg,
-                              f_rows, precision, with_carry, weighted,
+                              f_rows, precision, with_carry,
                               int8_table=False):
     """Gather-fused twin of ``_gram_dense_kernel``: the [BG, k] stream
-    block is row-DMA'd by index instead of streamed.  Dense padding slots
-    need no premultiply mask — they sit outside every [lo, hi) window, so
-    the windowed walk's one-operand mask annihilates whatever the clamped
-    DMA fetched.  Scalar layout: meta [NG+4·NT] ‖ nb [C]."""
+    block is row-DMA'd by index instead of streamed, then premultiplied by
+    the stream-aligned ``wt`` — which carries the padding mask: a run's
+    16-row alignment pads sit INSIDE its [lo, hi) window, and the clamped
+    DMA fetched a real row for them.  Scalar layout: meta [NG+4·NT] ‖
+    nb [C]."""
     refs = list(refs)
     g_buf, sem, dq_buf = _pop_gather_scratch(refs, int8_table)
     a_ref, b_ref = refs[-2:]
@@ -1303,8 +1318,7 @@ def _gram_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng, nt, bg,
     if with_carry:
         carry = tuple(refs[-3:])
         del refs[-3:]
-    rt_ref = refs[0]
-    wt_ref = refs[1] if weighted else None
+    rt_ref, wt_ref = refs[0], refs[1]
     gi = pl.program_id(0)
     base = gi * m
     meta_words = ng + 4 * nt
@@ -1313,8 +1327,7 @@ def _gram_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng, nt, bg,
         ng=pl.num_programs(0), f_rows=f_rows,
         group_row0=lambda g: sc_ref[g] * bg,
     )
-    if weighted:
-        _premultiply_rows(g_buf, off, bg, wt_ref, out_buf=dq_buf)
+    _premultiply_rows(g_buf, off, bg, wt_ref, out_buf=dq_buf)
     a_all, b_all = _tile_grams_dense(
         sc_ref, dq_buf if int8_table else g_buf, rt_ref, m=m, t=t, k=k,
         base=base, ng=ng, nt=nt,
@@ -1326,8 +1339,8 @@ def _gram_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng, nt, bg,
 
 def _gram_solve_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng,
                                     nt, bg, s_pad, f_rows, precision,
-                                    with_carry, weighted, reg_mode, lam,
-                                    algo, int8_table=False):
+                                    with_carry, reg_mode, lam, algo,
+                                    int8_table=False):
     """Gather-fused twin of ``_gram_solve_dense_kernel``.  Scalar layout:
     meta [NG+4·NT] ‖ lseg ‖ nb [C]."""
     refs = list(refs)
@@ -1345,9 +1358,7 @@ def _gram_solve_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng,
     if with_carry:
         carry = tuple(refs[-3:])
         del refs[-3:]
-    rt_ref = refs[0]
-    wt_ref = refs[1] if weighted else None
-    reg_ref = refs[2] if weighted else refs[1]
+    rt_ref, wt_ref, reg_ref = refs[0], refs[1], refs[2]
     gi = pl.program_id(0)
     base = gi * m
     meta_words = ng + 4 * nt
@@ -1356,8 +1367,7 @@ def _gram_solve_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng,
         gi=gi, ng=pl.num_programs(0), f_rows=f_rows,
         group_row0=lambda g: sc_ref[g] * bg,
     )
-    if weighted:
-        _premultiply_rows(g_buf, off, bg, wt_ref, out_buf=dq_buf)
+    _premultiply_rows(g_buf, off, bg, wt_ref, out_buf=dq_buf)
     a_all, b_all = _tile_grams_dense(
         sc_ref, dq_buf if int8_table else g_buf, rt_ref, m=m, t=t, k=k,
         base=base, ng=ng, nt=nt,
@@ -1375,22 +1385,33 @@ def _gram_solve_gather_dense_kernel(sc_ref, table_ref, *refs, m, t, k, ng,
         )
 
 
-def _int8_gather_pieces(table, rows, k, weighted=True):
+def _int8_gather_pieces(table, rows, k):
     """int8-quantized-table extras for the gather wrappers (``ops.quant``):
     the f32 dequant compute scratch (appended LAST in the scratch list —
     the convention ``_pop_gather_scratch`` reverses) and its VMEM bytes.
-    int8 rows REQUIRE a weight stream — the per-row dequant scale rides it
-    (folded upstream by ``quant.fold_scale``, which is also what makes the
-    single premultiply the dequantize) — so an unweighted int8 call is
-    refused rather than silently accumulating raw quantized codes."""
+    The per-row dequant scale rides the weight stream (folded upstream by
+    ``quant.fold_scale``, which is also what makes the single premultiply
+    the dequantize)."""
     if table.dtype != jnp.int8:
         return False, [], 0
-    if not weighted:
+    return True, [pltpu.VMEM((2 * rows, k), jnp.float32)], 2 * rows * k * 4
+
+
+def _dense_gather_weight(table, nb, wt):
+    """The dense gather kernels' stream-aligned premultiply: the caller's
+    ``wt`` (√aw for iALS, the folded dequant scale for int8 tables; None =
+    unit weights) times the padding mask.  The mask is what zeroes a run's
+    alignment pads — they index the virtual zero row F, the row DMA clamps
+    them onto a real row, and they sit inside the tile windows.  An int8
+    table without ``wt`` is refused: its dequant scale travels only there,
+    and raw codes would be accumulated as numbers."""
+    if wt is None and table.dtype == jnp.int8:
         raise ValueError(
             "int8 gather tables need a weight stream (quant.fold_scale "
             "folds the per-row dequant scale into wt); got wt=None"
         )
-    return True, [pltpu.VMEM((2 * rows, k), jnp.float32)], 2 * rows * k * 4
+    mask = (nb < table.shape[0]).astype(jnp.float32)
+    return mask if wt is None else wt.astype(jnp.float32) * mask
 
 
 def _gather_precision(table):
@@ -1404,7 +1425,7 @@ def _gather_precision(table):
 
 
 def _emulate_gather(table, nb, wt):
-    """The wrappers' interpret/old-jax gather: the XLA twin of the DMA
+    """The wrappers' interpret-route gather: the XLA twin of the DMA
     fetch + in-register premultiply (``compat.emulate_in_kernel_gather``),
     at the factor compute dtype the materialized-stream path uses (f32
     for int8 tables — the dequant scratch dtype)."""
@@ -1447,15 +1468,12 @@ def gram_tiles_gather_pallas(
     nt = c // t
     if seg.shape != (nt,):
         raise ValueError(f"seg shape {seg.shape} != ({nt},)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         return _emulate_gram_tiles(
             _emulate_gather(table, nb, wt), rt, seg,
             num_segments=num_segments, tile_rows=t, carry=carry,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     m = group_tiles
     while nt % m != 0:
         m //= 2
@@ -1479,7 +1497,7 @@ def gram_tiles_gather_pallas(
         num_scalar_prefetch=1,
         grid=(nt // m,),
         in_specs=[
-            pl.BlockSpec(memory_space=_any_memory_space()),  # table
+            pl.BlockSpec(memory_space=pl.ANY),  # table
             pl.BlockSpec((1, rows), lambda i, sc: (0, i)),   # rt
             pl.BlockSpec((1, rows), lambda i, sc: (0, i)),   # wt
         ] + carry_specs,
@@ -1495,12 +1513,10 @@ def gram_tiles_gather_pallas(
     precision = _gather_precision(table)
     out_bytes = num_segments * k * (k + 1) * 4
     g_bytes = 2 * rows * k * table.dtype.itemsize + dq_bytes
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(2 * out_bytes + g_bytes + 4 * rows * 8
-                             + (12 << 20), 124 << 20)
+                             + _walk_stack_bytes(m, k) + (12 << 20),
+                             124 << 20)
     )}
     carry_ops = [] if carry is None else [
         carry[0].astype(jnp.float32),
@@ -1516,7 +1532,7 @@ def gram_tiles_gather_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(scalar, table, rt.reshape(1, c).astype(jnp.float32),
       wt.reshape(1, c).astype(jnp.float32), *carry_ops)
@@ -1547,8 +1563,6 @@ def gram_solve_tiles_gather_pallas(
     from cfk_tpu.ops.pallas.solve_kernel import resolve_reg_solve_algo
 
     algo = resolve_reg_solve_algo(algo)
-    if algo == "lu" and pltpu is None:  # pragma: no cover - non-TPU build
-        algo = "gj"
     return _gram_solve_tiles_gather_pallas(
         table, nb, wt, rt, seg, reg, lseg, num_segments=num_segments,
         tile_rows=tile_rows, group_tiles=group_tiles, reg_mode=reg_mode,
@@ -1574,9 +1588,8 @@ def _gram_solve_tiles_gather_pallas(
     if seg.shape != (nt,):
         raise ValueError(f"seg shape {seg.shape} != ({nt},)")
     _check_reg_shape(reg, reg_mode, num_segments, k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         from cfk_tpu.compat import emulate_fused_gram_solve
 
         a, b = _emulate_gram_tiles(
@@ -1586,8 +1599,6 @@ def _gram_solve_tiles_gather_pallas(
         return emulate_fused_gram_solve(
             a, b, reg, reg_mode=reg_mode, lam=lam, lseg=lseg,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     m = group_tiles
     while nt % m != 0:
         m //= 2
@@ -1612,7 +1623,7 @@ def _gram_solve_tiles_gather_pallas(
         num_scalar_prefetch=1,
         grid=(nt // m,),
         in_specs=[
-            pl.BlockSpec(memory_space=_any_memory_space()),  # table
+            pl.BlockSpec(memory_space=pl.ANY),  # table
             pl.BlockSpec((1, rows), lambda i, sc: (0, i)),   # rt
             pl.BlockSpec((1, rows), lambda i, sc: (0, i)),   # wt
             reg_spec,
@@ -1622,12 +1633,10 @@ def _gram_solve_tiles_gather_pallas(
     )
     precision = _gather_precision(table)
     g_bytes = 2 * rows * k * table.dtype.itemsize + dq_bytes
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(scratch_bytes + g_bytes + 4 * rows * 8
-                             + (12 << 20), 124 << 20)
+                             + _walk_stack_bytes(m, k) + (12 << 20),
+                             124 << 20)
     )}
     x, cao, cbo = pl.pallas_call(
         functools.partial(
@@ -1638,7 +1647,7 @@ def _gram_solve_tiles_gather_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(scalar, table, rt.reshape(1, c).astype(jnp.float32),
       wt.reshape(1, c).astype(jnp.float32), reg_op, *carry_ops)
@@ -1667,9 +1676,8 @@ def gram_tiles_dense_gather_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """Gather-fused ``gram_tiles_dense_pallas``: the dense [C, k] stream
     is never materialized — each grid step row-DMAs its [BG, k] block by
-    index.  Unit-weight callers pass ``wt=None``: dense padding slots sit
-    outside every window, so the walk's one-operand mask annihilates the
-    clamped rows without a premultiply."""
+    index.  Unit-weight callers pass ``wt=None``; the kernel still
+    premultiplies by the padding mask (``_dense_gather_weight``)."""
     c = nb.shape[0]
     k = table.shape[-1]
     t = tile_rows
@@ -1684,20 +1692,16 @@ def gram_tiles_dense_gather_pallas(
     if c % bg != 0 or bg < t:
         raise ValueError(f"stream length {c} not a multiple of block_rows "
                          f"{bg} >= tile_rows {t}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         return _emulate_gram_dense(
             _emulate_gather(table, nb, wt), rt, meta,
             num_segments=num_segments, tile_rows=t, num_tiles=nt,
             num_groups=ng, block_rows=bg, carry=carry,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     f_rows = table.shape[0]
-    weighted = wt is not None
-    int8_table, dq_scratch, dq_bytes = _int8_gather_pieces(
-        table, bg, k, weighted=weighted)
+    wt = _dense_gather_weight(table, nb, wt)
+    int8_table, dq_scratch, dq_bytes = _int8_gather_pieces(table, bg, k)
     vma = typeof_vma(table)
     mk = (lambda s, d: jax.ShapeDtypeStruct(s, d, vma=vma)) if vma else (
         lambda s, d: jax.ShapeDtypeStruct(s, d)
@@ -1711,15 +1715,14 @@ def gram_tiles_dense_gather_pallas(
         pl.BlockSpec((1, k), lambda i, sc: (0, 0)),
         pl.BlockSpec((1, 1), lambda i, sc: (0, 0)),
     ]
-    wt_specs = ([pl.BlockSpec((1, bg), lambda i, sc: (0, sc[i]))]
-                if weighted else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(ng,),
         in_specs=[
-            pl.BlockSpec(memory_space=_any_memory_space()),  # table
+            pl.BlockSpec(memory_space=pl.ANY),  # table
             pl.BlockSpec((1, m * t), lambda i, sc: (0, i)),  # rt
-        ] + wt_specs + carry_specs,
+            pl.BlockSpec((1, bg), lambda i, sc: (0, sc[i])),  # wt
+        ] + carry_specs,
         out_specs=[
             pl.BlockSpec((num_segments, k, k), lambda i, sc: (0, 0, 0)),
             pl.BlockSpec((num_segments, 1, k), lambda i, sc: (0, 0, 0)),
@@ -1732,10 +1735,7 @@ def gram_tiles_dense_gather_pallas(
     precision = _gather_precision(table)
     out_bytes = num_segments * k * (k + 1) * 4
     g_bytes = 2 * bg * k * table.dtype.itemsize + dq_bytes
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(2 * out_bytes + g_bytes + 4 * bg * 8
                              + (10 << 20), 124 << 20)
     )}
@@ -1744,20 +1744,18 @@ def gram_tiles_dense_gather_pallas(
         carry[1].reshape(1, k).astype(jnp.float32),
         carry[2].reshape(1, 1).astype(jnp.float32),
     ]
-    wt_ops = ([wt.reshape(1, c).astype(jnp.float32)] if weighted else [])
     scalar = jnp.concatenate([meta.astype(jnp.int32), nb.astype(jnp.int32)])
     a, b = pl.pallas_call(
         functools.partial(
             _gram_gather_dense_kernel, m=m, t=t, k=k, ng=ng, nt=nt, bg=bg,
             f_rows=f_rows, precision=precision,
-            with_carry=carry is not None, weighted=weighted,
-            int8_table=int8_table,
+            with_carry=carry is not None, int8_table=int8_table,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
-    )(scalar, table, rt.reshape(1, nt * t), *wt_ops, *carry_ops)
+    )(scalar, table, rt.reshape(1, nt * t), wt.reshape(1, c), *carry_ops)
     return a, b[:, 0, :]
 
 
@@ -1786,8 +1784,6 @@ def gram_solve_tiles_dense_gather_pallas(
     from cfk_tpu.ops.pallas.solve_kernel import resolve_reg_solve_algo
 
     algo = resolve_reg_solve_algo(algo)
-    if algo == "lu" and pltpu is None:  # pragma: no cover - non-TPU build
-        algo = "gj"
     return _gram_solve_tiles_dense_gather_pallas(
         table, nb, wt, rt, meta, reg, lseg, num_segments=num_segments,
         tile_rows=tile_rows, num_tiles=num_tiles, num_groups=num_groups,
@@ -1821,9 +1817,8 @@ def _gram_solve_tiles_dense_gather_pallas(
         raise ValueError(f"stream length {c} not a multiple of block_rows "
                          f"{bg} >= tile_rows {t}")
     _check_reg_shape(reg, reg_mode, num_segments, k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret or not has_vma_system():
+    interpret = resolve_interpret(interpret)
+    if interpret is True:
         from cfk_tpu.compat import emulate_fused_gram_solve
 
         a, b = _emulate_gram_dense(
@@ -1834,12 +1829,9 @@ def _gram_solve_tiles_dense_gather_pallas(
         return emulate_fused_gram_solve(
             a, b, reg, reg_mode=reg_mode, lam=lam, lseg=lseg,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     f_rows = table.shape[0]
-    weighted = wt is not None
-    int8_table, dq_scratch, dq_bytes = _int8_gather_pieces(
-        table, bg, k, weighted=weighted)
+    wt = _dense_gather_weight(table, nb, wt)
+    int8_table, dq_scratch, dq_bytes = _int8_gather_pieces(table, bg, k)
     s_pad = -(-num_segments // _SOLVE_LANES) * _SOLVE_LANES
     vma = typeof_vma(table)
     (reg_op, reg_spec, carry_ops, carry_specs, out_shape, out_specs,
@@ -1849,8 +1841,6 @@ def _gram_solve_tiles_dense_gather_pallas(
         pltpu.VMEM((2 * bg, k), table.dtype),
         pltpu.SemaphoreType.DMA((2,)),
     ] + dq_scratch
-    wt_specs = ([pl.BlockSpec((1, bg), lambda i, sc: (0, sc[i]))]
-                if weighted else [])
     scalar = jnp.concatenate([
         meta.astype(jnp.int32),
         jnp.asarray(lseg, jnp.int32).reshape(1),
@@ -1860,34 +1850,33 @@ def _gram_solve_tiles_dense_gather_pallas(
         num_scalar_prefetch=1,
         grid=(ng,),
         in_specs=[
-            pl.BlockSpec(memory_space=_any_memory_space()),  # table
+            pl.BlockSpec(memory_space=pl.ANY),  # table
             pl.BlockSpec((1, m * t), lambda i, sc: (0, i)),  # rt
-        ] + wt_specs + [reg_spec] + carry_specs,
+            pl.BlockSpec((1, bg), lambda i, sc: (0, sc[i])),  # wt
+            reg_spec,
+        ] + carry_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
     precision = _gather_precision(table)
     g_bytes = 2 * bg * k * table.dtype.itemsize + dq_bytes
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    kwargs = {"compiler_params": params(
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(scratch_bytes + g_bytes + 4 * bg * 8
                              + (10 << 20), 124 << 20)
     )}
-    wt_ops = ([wt.reshape(1, c).astype(jnp.float32)] if weighted else [])
     x, cao, cbo = pl.pallas_call(
         functools.partial(
             _gram_solve_gather_dense_kernel, m=m, t=t, k=k, ng=ng, nt=nt,
             bg=bg, s_pad=s_pad, f_rows=f_rows, precision=precision,
-            with_carry=carry is not None, weighted=weighted,
-            reg_mode=reg_mode, lam=lam, algo=algo, int8_table=int8_table,
+            with_carry=carry is not None, reg_mode=reg_mode, lam=lam,
+            algo=algo, int8_table=int8_table,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
-    )(scalar, table, rt.reshape(1, nt * t), *wt_ops, reg_op, *carry_ops)
+    )(scalar, table, rt.reshape(1, nt * t), wt.reshape(1, c), reg_op,
+      *carry_ops)
     return x[:num_segments], cao, cbo[0]
 
 
@@ -1940,7 +1929,7 @@ def gather_rows_pallas(
     zero row realized by clamp + the ``wt`` mask (``wt=None`` skips the
     multiply — callers whose padding is annihilated downstream).
 
-    Off-TPU / old-jax / refused shapes route through the bit-identical
+    Off-TPU and refused shapes route through the bit-identical
     XLA twin (``compat.emulate_in_kernel_gather``), so CPU CI pins the
     same numbers the Mosaic DMA path produces on hardware."""
     from cfk_tpu.ops.solve import _gram_compute_dtype
@@ -1959,16 +1948,14 @@ def gather_rows_pallas(
     if out_dtype is None:
         out_dtype, _ = _gram_compute_dtype(table)
     out_dtype = jnp.dtype(out_dtype)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bg = block_rows or min(c, 1024)
     while bg > 16 and c % bg:
         bg //= 2
-    supported = (
-        not interpret and has_vma_system() and pltpu is not None
-        and c % bg == 0 and bg % 16 == 0
-        and in_kernel_gather_supported(c, 0, 16)
-    )
+    supported = interpret is not True and c % bg == 0 and bg % 16 == 0
+    if supported and interpret is False:
+        supported = in_kernel_gather_supported(
+            c, 0, 16, k=k, table_dtype=table.dtype)
     if not supported:
         from cfk_tpu.compat import emulate_in_kernel_gather
 
@@ -1985,19 +1972,16 @@ def gather_rows_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(c // bg,),
-        in_specs=[pl.BlockSpec(memory_space=_any_memory_space())] + wt_specs,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + wt_specs,
         out_specs=[pl.BlockSpec((bg, k), lambda i, sc: (i, 0))],
         scratch_shapes=[
             pltpu.VMEM((2 * bg, k), table.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ] + ([pltpu.VMEM((2 * bg, k), out_dtype)] if sep_buf else []),
     )
-    params = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
     g_bytes = 2 * bg * k * (table.dtype.itemsize
                             + (out_dtype.itemsize if sep_buf else 0))
-    kwargs = {"compiler_params": params(
+    kwargs = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=min(g_bytes + 2 * bg * k * out_dtype.itemsize
                              + 4 * bg * 8 + (8 << 20), 124 << 20)
     )}
@@ -2009,7 +1993,7 @@ def gather_rows_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=(mk((c, k), out_dtype),),
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(nb.astype(jnp.int32), table, *wt_ops)
     return out

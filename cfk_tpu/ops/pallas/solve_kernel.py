@@ -36,15 +36,9 @@ import jax
 import jax.numpy as jnp
 
 from cfk_tpu.compat import typeof_vma
+from cfk_tpu.ops.pallas.interpret import resolve_interpret
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 # VMEM budget cap: the kernel keeps [k, k, _LANES] float32 blocks live
@@ -277,11 +271,8 @@ def resolve_reg_solve_algo(algo: str | None) -> str:
 def _fused_reg_rank_cap(algo: str | None = None) -> int:
     """Largest rank the fused reg+solve path handles with the given (or
     default) algorithm — what the dispatchers in ``ops.solve`` route on."""
-    return (
-        LU_MAX_RANK
-        if resolve_reg_solve_algo(algo) == "lu" and pltpu is not None
-        else PALLAS_MAX_RANK
-    )
+    return (LU_MAX_RANK if resolve_reg_solve_algo(algo) == "lu"
+            else PALLAS_MAX_RANK)
 
 
 def gauss_solve_reg_pallas(
@@ -309,8 +300,6 @@ def gauss_solve_reg_pallas(
     of silently reusing the previously traced kernel.
     """
     algo = resolve_reg_solve_algo(algo)
-    if algo == "lu" and pltpu is None:  # pragma: no cover - non-TPU build
-        algo = "gj"
     return _gauss_solve_reg_pallas(
         a, b, reg, reg_mode=reg_mode, lam=lam, interpret=interpret,
         algo=algo,
@@ -347,8 +336,7 @@ def _gauss_solve_reg_pallas(
             raise ValueError(f"matrix reg shape {reg.shape} != ({k},{k})")
     else:
         raise ValueError(f"unknown reg_mode {reg_mode!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile = _LANES
     if interpret:
         # The HLO interpreter needs exact block tiling; compiled Mosaic
@@ -368,7 +356,7 @@ def _gauss_solve_reg_pallas(
         e_pad = e
         a_p, b_p = a, b
         r_p = reg[None, :] if reg_mode == "diag" else reg
-    mem = {"memory_space": _VMEM} if _VMEM is not None and not interpret else {}
+    mem = {} if interpret else {"memory_space": pltpu.VMEM}
     r_spec = (
         pl.BlockSpec((1, tile), lambda i: (0, i), **mem)
         if reg_mode == "diag"
@@ -381,15 +369,12 @@ def _gauss_solve_reg_pallas(
         else jax.ShapeDtypeStruct((e_pad, k), jnp.float32)
     )
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         # The batch-first input block + its in-kernel batch-last transpose
         # both sit in VMEM through the unrolled elimination (~20 MB at
         # k=64, ~4× that at k=128); the default 16 MB scoped allowance is
         # far short.
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        kwargs["compiler_params"] = params(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=(40 if k <= 64 else 100) * 1024 * 1024
         )
     if algo == "lu":
@@ -417,7 +402,7 @@ def _gauss_solve_reg_pallas(
             r_spec,
         ],
         out_specs=pl.BlockSpec((tile, k), lambda i: (i, 0), **mem),
-        interpret=interpret,
+        interpret=bool(interpret),
         **kwargs,
     )(a_p, b_p, r_p)
     return x[:e]
@@ -439,8 +424,7 @@ def _lane_padded_inputs(a, b, b_pad_axis, interpret):
     """
     k = a.shape[0]
     e = a.shape[2]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile = _LANES
     e_pad = ((e + tile - 1) // tile) * tile
     a_p = _pad_to(a, e_pad, axis=2)
@@ -459,7 +443,7 @@ def _solve_call(kernel, a_p, b_p, b_block, out_struct, tile, interpret,
     raise."""
     k = a_p.shape[0]
     e_pad = a_p.shape[2]
-    mem = {"memory_space": _VMEM} if _VMEM is not None and not interpret else {}
+    mem = {} if interpret else {"memory_space": pltpu.VMEM}
     nb = len(b_block)
     b_map = (lambda i: (0, 0, i)) if nb == 3 else (lambda i: (0, i))
     specs = dict(
@@ -476,16 +460,14 @@ def _solve_call(kernel, a_p, b_p, b_block, out_struct, tile, interpret,
     else:
         out_shape = jax.ShapeDtypeStruct(shape, dtype)
     kwargs = {}
-    if vmem_limit is not None and pltpu is not None and not interpret:
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        kwargs["compiler_params"] = params(vmem_limit_bytes=vmem_limit)
+    if vmem_limit is not None and not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit)
     return pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid=(e_pad // tile,),
-        interpret=interpret,
+        interpret=bool(interpret),
         **specs,
         **kwargs,
     )(a_p, b_p)

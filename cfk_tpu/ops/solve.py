@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from cfk_tpu.compat import match_varying as _match_varying
+
 
 def _gram_compute_dtype(fixed_factors):
     """(compute dtype, einsum precision) for Gram/RHS contractions.
@@ -284,7 +286,7 @@ def ials_half_step_bucketed(
         rows, width = ni.shape
         modes = bport.resolve_bucket_modes(
             fused_epilogue, in_kernel_gather, solver, rows, width, k,
-            None, reg_solve_algo,
+            None, reg_solve_algo, table_dtype=data.dtype,
         )
         if modes is None:
             a_obs, b = gather_gram_implicit(view, ni, alpha * rt, mk)
@@ -620,24 +622,6 @@ def _segment_gram_flat(
     return a, b
 
 
-def _match_varying(z, ref):
-    """Give constant ``z`` the same device-varying axes as traced ``ref``.
-
-    Inside ``shard_map`` (with vma checking) a scan carry initialized from
-    constants must be explicitly pcast/pvary'd to the mesh axes the body's
-    data is varying over; outside shard_map this is the identity.
-    """
-    try:
-        vma = jax.typeof(ref).vma
-    except (AttributeError, TypeError):
-        return z
-    if not vma:
-        return z
-    if hasattr(lax, "pcast"):
-        return lax.pcast(z, tuple(vma), to="varying")
-    return lax.pvary(z, tuple(vma))
-
-
 def _segment_scan(fixed_factors, per_chunk_gram, solve_rows, arrays, statics,
                   local_entities):
     """The chunk scan both segment half-steps share.
@@ -876,7 +860,7 @@ def als_half_step_bucketed(
         rows, width = ni.shape
         modes = bport.resolve_bucket_modes(
             fused_epilogue, in_kernel_gather, solver, rows, width, k,
-            lam, reg_solve_algo,
+            lam, reg_solve_algo, table_dtype=data.dtype,
         )
         if modes is None:
             return _solve_chunk(view, lam, ni, rt, mk, cnt, solver,

@@ -79,7 +79,7 @@ def resolve_in_kernel_gather(in_kernel_gather) -> bool:
 
 def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
                         meta_words, tile_rows, num_segments, k,
-                        block_rows=None) -> str:
+                        block_rows=None, *, table_dtype) -> str:
     """Static gating of the in-kernel gather — ``"fused"`` or ``"xla"``.
 
     The logic lives in ``cfk_tpu.plan.registry`` now (ISSUE 9): ONE
@@ -92,7 +92,8 @@ def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
     from cfk_tpu.plan.registry import resolve_gather_mode as _resolve
 
     return _resolve(in_kernel_gather, backend, stage, entries, meta_words,
-                    tile_rows, num_segments, k, block_rows)
+                    tile_rows, num_segments, k, block_rows,
+                    table_dtype=table_dtype)
 
 
 def default_tiled_gram_backend() -> str:
@@ -318,6 +319,42 @@ def resolve_fused_chunk_lam(fused_epilogue, solver, k, num_segments,
 
     return _resolve(fused_epilogue, solver, k, num_segments, backend, lam,
                     implicit, algo)
+
+
+def resolve_tiled_route(mode, statics, k, lam, *, table_dtype, solver,
+                        implicit=False, fused_epilogue=None,
+                        in_kernel_gather=None, reg_solve_algo=None,
+                        gram_backend=None, stage="full"):
+    """(gather mode, fused-epilogue λ or None) of one tiled half-step: what
+    the static gates resolve the knobs to for this mode's chunk statics.
+
+    The ONE place a mode's statics are turned into the gates' arguments
+    (scalar-prefetch words, segment count, block rows) — the three chunk
+    bodies route by it, and ``chip_smoke.py`` prints and pins it.
+    ``table_dtype`` is the dtype of the table the kernels would read
+    (after quantization).  Accum mode has no per-chunk epilogue to fuse
+    (its (A, b) lives in HBM across chunks), so its λ is always None."""
+    backend = gram_backend or default_tiled_gram_backend()
+    if mode == "dstream":
+        nc, cap, e_c, t, nt, ng, bg = statics
+        meta_words, block_rows = ng + 4 * nt + 1, bg
+    elif mode == "stream":
+        nc, cap, e_c, t = statics
+        meta_words, block_rows = cap // t + 1, None
+    else:
+        nc, cap, t, h, e_c = statics
+        meta_words, block_rows = cap // t, None
+    gather = resolve_gather_mode(
+        in_kernel_gather, backend, stage, cap, meta_words, t, e_c + 1, k,
+        block_rows, table_dtype=table_dtype,
+    )
+    fused_lam = None
+    if mode != "accum" and stage == "full":
+        fused_lam = resolve_fused_chunk_lam(
+            fused_epilogue, solver, k, e_c + 1, backend, lam, implicit,
+            reg_solve_algo,
+        )
+    return gather, fused_lam
 
 
 def quantize_tiled_operand(fixed_factors, blk, chunks, table_dtype):
@@ -591,14 +628,11 @@ def als_half_step_tiled(
     # padding zero row is ALSO the dequantize — the unit-weight shortcut
     # (which skips that multiply on the XLA route) must not fire.
     unit = implicit_reg is None and fixed_factors.dtype != jnp.int8
-    fused_lam = (
-        resolve_fused_chunk_lam(
-            fused_epilogue, solver, k, e_c + 1, backend, lam,
-            implicit_reg is not None, reg_solve_algo,
-        ) if stage == "full" else None
-    )
-    gather = resolve_gather_mode(
-        in_kernel_gather, backend, stage, cap, nt + 1, t, e_c + 1, k,
+    gather, fused_lam = resolve_tiled_route(
+        "stream", statics, k, lam, table_dtype=fixed_factors.dtype,
+        solver=solver, implicit=implicit_reg is not None,
+        fused_epilogue=fused_epilogue, in_kernel_gather=in_kernel_gather,
+        reg_solve_algo=reg_solve_algo, gram_backend=backend, stage=stage,
     )
     chunks = (
         neighbor_idx.reshape(nc, cap), rating.reshape(nc, cap),
@@ -812,15 +846,11 @@ def als_half_step_tiled_dense(
     overlap = resolve_overlap(overlap)
     nc, cap, e_c, t, nt, ng, bg = statics
     k = fixed_factors.shape[-1]
-    fused_lam = (
-        resolve_fused_chunk_lam(
-            fused_epilogue, solver, k, e_c + 1, backend, lam,
-            implicit_reg is not None, reg_solve_algo,
-        ) if stage == "full" else None
-    )
-    gather = resolve_gather_mode(
-        in_kernel_gather, backend, stage, cap, ng + 4 * nt + 1, t,
-        e_c + 1, k, block_rows=bg,
+    gather, fused_lam = resolve_tiled_route(
+        "dstream", statics, k, lam, table_dtype=fixed_factors.dtype,
+        solver=solver, implicit=implicit_reg is not None,
+        fused_epilogue=fused_epilogue, in_kernel_gather=in_kernel_gather,
+        reg_solve_algo=reg_solve_algo, gram_backend=backend, stage=stage,
     )
     ct, _ = _gram_compute_dtype(fixed_factors)
     if gather != "fused" or stage != "full":
@@ -1063,8 +1093,10 @@ def als_half_step_tiled_accum(
     # weight channel, so the weighted multiply must run (see the stream
     # body / quantize_tiled_operand).
     unit = implicit_reg is None and fixed_factors.dtype != jnp.int8
-    gather = resolve_gather_mode(
-        in_kernel_gather, backend, stage, cap, nt, t, e_c + 1, k,
+    gather, _ = resolve_tiled_route(
+        "accum", statics, k, lam, table_dtype=fixed_factors.dtype,
+        solver=solver, in_kernel_gather=in_kernel_gather,
+        gram_backend=backend, stage=stage,
     )
     chunks = (
         neighbor_idx.reshape(nc, cap), rating.reshape(nc, cap),

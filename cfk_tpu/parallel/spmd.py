@@ -32,7 +32,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cfk_tpu.compat import shard_map as _compat_shard_map, to_varying
+from cfk_tpu.compat import (
+    shard_map as _compat_shard_map,
+    to_varying as _to_varying,
+)
 from cfk_tpu.config import ALSConfig
 from cfk_tpu.data.blocks import (
     BucketedBlocks,
@@ -57,8 +60,6 @@ from cfk_tpu.ops.solve import (
 )
 from cfk_tpu.parallel.mesh import AXIS, shard_rows, to_host
 
-
-_to_varying = to_varying  # compat: pcast / pvary / identity by jax version
 
 
 def half_step_allgather(
@@ -569,10 +570,11 @@ def half_step_tiled_ring_hier(
     _, _, nc, cap, t, h, e_c = chunks
     nt = cap // t
     k = fixed_local.shape[-1]
+    data, scale = quant.quantize_table(fixed_local, table_dtype)
     gather = resolve_gather_mode(
         in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        table_dtype=data.dtype,
     )
-    data, scale = quant.quantize_table(fixed_local, table_dtype)
     tbl0 = (data,) if scale is None else (data, scale)
     int8 = scale is not None
     my = lax.axis_index(AXIS)
@@ -734,9 +736,6 @@ def half_step_tiled_ring(
     s = num_shards
     nt = cap // t
     k = fixed_local.shape[-1]
-    gather = resolve_gather_mode(
-        in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
-    )
     # Quantize the ROTATING payload once, before the ring (ops.quant):
     # every ppermute then moves the bf16 block — or the (int8 codes,
     # f32 per-row scales) pair, a quarter of the bytes — and every Gram
@@ -744,6 +743,10 @@ def half_step_tiled_ring(
     # (indices are local to whichever block this shard currently holds),
     # folded into the weight channel per chunk — the canonical order.
     data, scale = quant.quantize_table(fixed_local, table_dtype)
+    gather = resolve_gather_mode(
+        in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        table_dtype=data.dtype,
+    )
     tbl0 = (data,) if scale is None else (data, scale)
     int8 = scale is not None
     my = lax.axis_index(AXIS)
@@ -1570,10 +1573,16 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
         cat_v = lax.all_gather(v, AXIS, axis=1, tiled=True)
         cat_i = lax.all_gather(ids, AXIS, axis=1, tiled=True)
         mv, pos = lax.top_k(cat_v, k_top)
-        return mv, jnp.take_along_axis(cat_i, pos, axis=1)
+        return mv[None], jnp.take_along_axis(cat_i, pos, axis=1)[None]
 
-    return jax.jit(_compat_shard_map(
+    # Every shard merges the same all_gather'd candidates, so the merged
+    # selections are equal on all of them — which jax's typing cannot say
+    # (``lax.all_gather`` is varying → varying, and nothing public casts
+    # varying → invariant).  So they come back stacked over the mesh axis,
+    # typed as what they are to the checker, and the first is the answer.
+    sharded = _compat_shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(AXIS), P(AXIS), P(AXIS)),
-        out_specs=(P(), P()),
-    ))
+        out_specs=(P(AXIS), P(AXIS)),
+    )
+    return jax.jit(lambda *ops: tuple(x[0] for x in sharded(*ops)))

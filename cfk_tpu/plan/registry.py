@@ -180,13 +180,15 @@ def _register_builtins() -> None:
         return load
 
     def _gather_gate(entries=None, meta_words=None, tile_rows=None,
-                     block_rows=None, **_):
+                     block_rows=None, k=None, table_dtype=None,
+                     lowered=True, **_):
         from cfk_tpu.ops.pallas.gram_kernel import in_kernel_gather_supported
 
         if entries is None:
             return True
-        return in_kernel_gather_supported(entries, meta_words, tile_rows,
-                                          block_rows)
+        return in_kernel_gather_supported(
+            entries, meta_words, tile_rows, block_rows, k=k,
+            table_dtype=table_dtype, lowered=lowered)
 
     def _fused_gate(num_segments=None, k=None, algo=None, **_):
         from cfk_tpu.ops.pallas.gram_kernel import fused_gram_solve_supported
@@ -290,18 +292,26 @@ _register_builtins()
 
 def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
                         meta_words, tile_rows, num_segments, k,
-                        block_rows=None) -> str:
+                        block_rows=None, *, table_dtype) -> str:
     """Static gating of the in-kernel gather: ``"fused"`` (the kernel DMAs
     the indexed rows itself) or ``"xla"`` (the materialized-stream
-    schedule).  Gates: the knob, the pallas Gram backend (the XLA A/B
-    backend has no kernel to gather inside), ``mosaic_tpu`` registry
-    availability (a forced-unavailable backend reroutes the next trace to
-    the emulation schedule), production stage only (the decompose probes
-    time the XLA gather as its own phase), the kernels' SMEM/alignment
-    support gate, and the same resident-output VMEM cap the split kernels
-    fall back on.  A refused shape keeps the XLA-gather path — same math
-    via the same emulation twins, so the two modes stay bit-identical
-    (tests/test_in_kernel_gather.py)."""
+    schedule).  ``table_dtype`` is the dtype of the table the kernel would
+    DMA from (after quantization).  Gates: the knob, the pallas Gram
+    backend (the XLA A/B backend has no kernel to gather inside),
+    ``mosaic_tpu`` registry availability (a forced-unavailable backend
+    reroutes the next trace to the emulation schedule), production stage
+    only (the decompose probes time the XLA gather as its own phase), the
+    kernels' rank/dtype/SMEM/alignment support gate
+    (``in_kernel_gather_supported`` — what the chip's compiler accepts),
+    and the same resident-output VMEM cap the split kernels fall back on.
+    The rank/dtype term is Mosaic's row-DMA limit, so it binds where
+    Mosaic lowers the kernel — where ``interpret=None`` resolves to False,
+    a TPU backend.  Anywhere else "fused" is the gather wrappers' XLA
+    twin, which has no such limit: the CPU tests go on covering the
+    gather-fused schedule at toy ranks, and the windowed and sharded
+    drivers keep taking the same route as each other there.  A refused
+    shape keeps the XLA-gather path (same math; kernel vs twin agree to
+    float32 round-off — tests/test_in_kernel_gather.py)."""
     if stage != "full" or backend != "pallas":
         return "xla"
     if not REGISTRY.backend_available("mosaic_tpu"):
@@ -312,9 +322,12 @@ def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
         return "xla"
     if 2 * num_segments * k * (k + 1) * 4 > (96 << 20):
         return "xla"  # mirrors _entity_gram_chunk's resident-output cap
+    from cfk_tpu.ops.pallas.interpret import resolve_interpret
+
     gate = REGISTRY.get("gram_gather", "mosaic_tpu").supported
     if not gate(entries=entries, meta_words=meta_words, tile_rows=tile_rows,
-                block_rows=block_rows):
+                block_rows=block_rows, k=k, table_dtype=table_dtype,
+                lowered=resolve_interpret(None) is False):
         return "xla"
     return "fused"
 

@@ -204,9 +204,16 @@ def _feasible(shape: ProblemShape, device: DeviceSpec, cand: dict,
         tr = shape.tile_rows
         entries = min(cand["chunk_elems"], 2 * shape.nnz)
         gate = _registry.REGISTRY.get("gram_gather", "mosaic_tpu").supported
+        # table_dtype "float32" is the identity: the kernel would DMA
+        # from the table at the factors' storage dtype.  Mosaic lowers
+        # that DMA only on a TPU; elsewhere the plan runs the XLA twin.
+        dma_dtype = (shape.dtype if cand["table_dtype"] == "float32"
+                     else cand["table_dtype"])
         if not gate(entries=entries, meta_words=entries // max(tr, 1) + 2,
-                    tile_rows=tr, block_rows=None):
-            return "chunk shape refused by the gather SMEM/alignment gate"
+                    tile_rows=tr, block_rows=None, k=shape.rank,
+                    table_dtype=dma_dtype, lowered=device.kind == "tpu"):
+            return ("chunk shape refused by the gather "
+                    "rank/dtype/SMEM/alignment gate")
     if cand["solver"] == "pallas":
         from cfk_tpu.ops.pallas import PALLAS_MAX_RANK
 

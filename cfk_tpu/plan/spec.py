@@ -185,28 +185,27 @@ class ProblemShape:
         return tag
 
 
-# TPU v5e reference numbers (utils.roofline's measured/spec constants).
-_V5E = dict(hbm_bytes=16 * 1024**3, hbm_bytes_per_s=819e9,
-            peak_flops=197e12, gather_rows_per_s=600e6)
-
-
 @dataclasses.dataclass(frozen=True)
 class DeviceSpec:
     """What the cost model knows about the hardware.
 
-    ``kind="cpu"`` carries NOMINAL numbers: off-TPU the model is used only
-    to RANK candidate plans (CI, the plan CLI), never as an absolute
-    latency claim — the ratios (gather is row-slot-bound, fusion saves the
-    A-batch round trip, quantization shrinks the scan) are what transfer.
+    Build one with ``detect()`` (the running backend) or ``nominal()``; both
+    take a TPU's numbers from the ONE peaks table
+    (``utils.roofline.DEVICE_PEAKS``, keyed by ``device_kind``) and refuse a
+    TPU that is not in it.  ``kind="cpu"`` carries NOMINAL numbers: off-TPU
+    the model is used only to RANK candidate plans (CI, the plan CLI), never
+    as an absolute latency claim — the ratios (gather is row-slot-bound,
+    fusion saves the A-batch round trip, quantization shrinks the scan) are
+    what transfer.
     """
 
     kind: str  # "tpu" | "cpu" | "gpu"
     name: str = ""
     num_devices: int = 1
-    hbm_bytes: float = _V5E["hbm_bytes"]
-    hbm_bytes_per_s: float = _V5E["hbm_bytes_per_s"]
-    peak_flops: float = _V5E["peak_flops"]
-    gather_rows_per_s: float = _V5E["gather_rows_per_s"]
+    hbm_bytes: float = dataclasses.field(kw_only=True)
+    hbm_bytes_per_s: float = dataclasses.field(kw_only=True)
+    peak_flops: float = dataclasses.field(kw_only=True)
+    gather_rows_per_s: float = dataclasses.field(kw_only=True)
     vmem_bytes: int = 96 << 20  # the gram kernels' resident-output cap
     smem_bytes: int = 512 << 10  # _GATHER_SMEM_BYTES_CAP
     # Fabric tiers the offload/hier-exchange terms price (ISSUE 11).
@@ -234,23 +233,32 @@ class DeviceSpec:
     @classmethod
     def nominal(cls, kind: str, name: str = "", num_devices: int = 1,
                 ) -> "DeviceSpec":
-        """A spec for ``kind`` with the reference numbers: v5e for
-        ``"tpu"``, the nominal byte-bound host numbers otherwise."""
-        extra = {} if kind == "tpu" else dict(cls._CPU)
-        return cls(kind=kind, name=name or kind,
-                   num_devices=num_devices, **extra)
+        """A spec for ``kind`` with reference numbers: for ``"tpu"`` the
+        peaks-table row of ``name`` (a ``device_kind`` or an alias such as
+        ``"v5e"``; a TPU with no row raises), for anything else the nominal
+        byte-bound host numbers."""
+        if kind != "tpu":
+            return cls(kind=kind, name=name or kind,
+                       num_devices=num_devices, **cls._CPU)
+        from cfk_tpu.utils.roofline import device_peaks
+
+        pk = device_peaks(name)
+        return cls(
+            kind=kind, name=name, num_devices=num_devices,
+            hbm_bytes=pk.hbm_bytes, hbm_bytes_per_s=pk.hbm_bytes_per_s,
+            peak_flops=pk.peak_bf16_flops,
+            gather_rows_per_s=pk.gather_rows_per_s,
+        )
 
     @classmethod
     def detect(cls) -> "DeviceSpec":
         """The current jax backend, as a spec (see ``nominal``)."""
         import jax
 
-        backend = jax.default_backend()
-        dev = jax.devices()[0]
+        devices = jax.devices()
         return cls.nominal(
-            backend,
-            name=getattr(dev, "device_kind", backend),
-            num_devices=len(jax.devices()),
+            jax.default_backend(), name=devices[0].device_kind,
+            num_devices=len(devices),
         )
 
     def fingerprint(self) -> str:

@@ -92,8 +92,12 @@ class ServeEngine:
         max_stale_fraction: float = 0.25,
         metrics=None,  # telemetry.Metrics — recall/bytes-scanned gauges
     ) -> None:
+        from cfk_tpu.config import enable_compile_cache
         from cfk_tpu.ops.quant import resolve_table_dtype
 
+        # Before the first compile: a restarted server replays its serve
+        # programs from the persistent cache.
+        enable_compile_cache()
         # Opt-in plan consumption (cfk_tpu.plan): when a plan is given its
         # serve knobs (batch quantum, movie tile rows, retrieval mode +
         # index size, and — unless passed explicitly — the table dtype)
@@ -602,10 +606,10 @@ class ServeEngine:
         when given — pass a workload sample so the seen-rectangle widths
         it produces match live traffic — else the first users of the
         table; results are discarded, and the jit cache keys on shapes
-        only, so bit-exactness is untouched).  With
-        ``ALSConfig.compile_cache_dir`` wired, the XLA compile behind
-        each new trace is also served from the persistent cache — a warm
-        restart pays neither.  Returns
+        only, so bit-exactness is untouched).  The XLA compile behind
+        each new trace is also served from the persistent cache
+        (``config.enable_compile_cache``) — a warm restart pays neither.
+        Returns
         ``{"programs", "new_traces", "prewarm_s"}``; a later batch whose
         (padded size, seen width) bucket was covered here traces
         nothing, which ``tests/test_staging.py`` pins.  In two_stage

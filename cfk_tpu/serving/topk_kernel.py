@@ -11,32 +11,34 @@ block on the MXU, and folds it into a running per-user K-selection carried
 in VMEM — so the only thing that ever reaches HBM is the [B, K] result.
 No [B, num_movies] score matrix exists anywhere, on-chip or off.
 
-Per grid step (one movie tile):
+Per grid step (one movie tile), everything MOVIE-MAJOR — [T, B] scores,
+[K, B] carry — so that every dynamic index is a single-sublane ref row and
+every reduction runs along sublanes, the forms Mosaic lowers:
 
-- score block  S = U · tileᵀ on the MXU (f32 accumulation; an int8 tile is
+- score block  S = tile · Uᵀ on the MXU (f32 accumulation; an int8 tile is
   dequantized in-register by its per-row scale — the same canonical
   dequant placement as the Gram kernels, ``ops.quant``),
-- padding mask: global column ≥ ``num_movies`` → −inf (the table is padded
+- padding mask: global row ≥ ``num_movies`` → −inf (the table is padded
   to a tile multiple),
 - exclusion mask: already-rated items are −inf'd in-register from the
   batch's per-user CSR slice, re-bucketed per tile on the host
   (``build_seen_tiles``: ``seen[b]``'s movie rows, already sorted, split
-  at tile boundaries into a [NT, B, W] rectangle of in-tile columns — W is
+  at tile boundaries into a [NT, B, W] rectangle of in-tile rows — W is
   the pow2-bucketed max per-(user, tile) seen count, so the kernel's mask
-  pass is W comparisons against the tile's column iota, not a [B, S×T]
-  blow-up),
-- K-selection merge: the tile's masked scores are concatenated onto the
-  [B, K] carry and one ``lax.top_k`` re-selects — equal scores resolve to
-  the earlier tile (carry first), making tie order deterministic.
+  pass is W compares of one [1, B] slot row against the tile's row iota,
+  not a [B, S×T] blow-up),
+- K-selection merge: K rounds of "largest remaining value, earliest
+  position" over [carry ‖ tile] — equal scores resolve to the carry, then
+  to the lower slot/row, making tie order deterministic (``lax.top_k``
+  has no Mosaic lowering; neither has ``dynamic_slice`` on values).
 
 The merge step (``_score_tile_fold``) is ONE function shared by the Mosaic
-kernel body and the XLA emulation twin (``compat.emulate_topk_scores``
-scans it over the same tiles), so the two routes are bit-identical on the
-interpret path — the same twin discipline as the Gram kernels.  On real
-hardware the open questions are whether the [B, K+T] top_k lowers
-efficiently in Mosaic or the K-selection carry should spill to a VMEM
-scratch merge-sort, and the score tile's MXU utilization at small B — both
-recorded in the ROADMAP on-TPU backlog.
+kernel body and the XLA twin (``compat.emulate_topk_scores`` scans it over
+the same tiles), so the two routes are bit-identical on the interpret path
+— the same twin discipline as the Gram kernels.  The kernel compiles for
+the v5e at f32/bf16/int8 (``tests/test_chip_compile.py``) and matches the
+twin on the chip (``tests/test_pallas_tpu.py``); how fast the K selection
+rounds are there is not measured (ROADMAP S7).
 """
 
 from __future__ import annotations
@@ -47,19 +49,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from cfk_tpu.compat import has_vma_system, typeof_vma
+from cfk_tpu.compat import match_varying, typeof_vma
+from cfk_tpu.ops.pallas.interpret import resolve_interpret
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific extensions; absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 import numpy as np
 
 # Exclusion-mask compare chunk: W seen slots are checked against the tile's
-# column iota in slices of this many slots, bounding both the trace length
-# and the [B, chunk, T] boolean intermediate (≤ ~1 MB at the default tile).
+# row iota this many slots per loop trip (unrolled by hand), and W is padded
+# to a multiple of it.
 _SEEN_CHUNK = 16
 
 
@@ -81,17 +80,30 @@ def serve_compute_dtype(table_dtype):
     return jnp.float32, lax.Precision.HIGHEST
 
 
-def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen, tile_base,
-                     *, num_movies, k_top):
+def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
+                     tile_base, *, num_movies, k_top):
     """Fold one movie tile into the running top-K carry.
 
     The ONE copy of the per-tile math — the Mosaic kernel body and the XLA
-    emulation twin both call exactly this, which is what makes the two
-    routes bit-identical on the interpret path.
+    twin both call exactly this.  Everything is MOVIE-MAJOR ([T, B] scores,
+    [K, B] carry): per-slot exclusion rows and per-round selections are
+    then single-sublane ref rows broadcast down the tile, and every
+    reduction runs along sublanes — the only forms of dynamic indexing and
+    reduction this selection needs that Mosaic lowers (``lax.top_k``,
+    ``dynamic_slice`` on values and lane-offset slices do not).
 
-    carry_v [B, K] f32, carry_i [B, K] int32 (−1 empty), u [B, k],
-    tile [T, k] (f32/bf16/int8), scale [T, 1] f32 or None, seen [B, W]
-    int32 in-tile columns (T = padding), tile_base scalar int32.
+    carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty), u [B, k],
+    tile [T, k] (f32/bf16/int8), scale [T, 1] f32 or None,
+    ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j
+    (T = padding) for j < ``seen_width`` (None = no exclusion),
+    tile_base scalar int32.
+
+    Selection is K rounds of "largest remaining value, earliest position":
+    a carry slot beats a tile row at equal score and lower slots/rows beat
+    higher ones — the stable order of a top-k over [carry ‖ tile].  Tile
+    rows are consumed by overwriting them with −inf; carry slots by a
+    ``taken`` mask, so that −inf ties (fewer than K candidates) walk the
+    empty carry slots in order and the tail ids stay −1.
     """
     t = tile.shape[0]
     b = u.shape[0]
@@ -103,31 +115,60 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen, tile_base,
     else:
         tile_f = tile.astype(ct)
     scores = jax.lax.dot_general(
-        u.astype(ct), tile_f,
+        tile_f, u.astype(ct),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=prec,
-    )  # [B, T]
-    col = lax.broadcasted_iota(jnp.int32, (1, t), 1)  # [1, T] in-tile column
-    gid = tile_base + col  # [1, T] global movie row
+    )  # [T, B]
+    row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile movie row
     neg = jnp.float32(-jnp.inf)
-    scores = jnp.where(gid < num_movies, scores, neg)
-    if seen is not None:
-        w = seen.shape[1]
+    scores = jnp.where(tile_base + row < num_movies, scores, neg)
+    if seen_row is not None:
+        def mask_chunk(c, sc):
+            # _SEEN_CHUNK slots per trip, unrolled by hand: Mosaic's loop
+            # lowering takes unroll=1 or a full unroll only
+            for j in range(_SEEN_CHUNK):
+                sc = jnp.where(row == seen_row(c * _SEEN_CHUNK + j), neg, sc)
+            return sc
 
-        def mask_chunk(j, sc):
-            chunk = lax.dynamic_slice(seen, (0, j * _SEEN_CHUNK),
-                                      (b, _SEEN_CHUNK))  # [B, C]
-            hit = (chunk[:, :, None] == col[None, :, :]).any(axis=1)
-            return jnp.where(hit, neg, sc)
+        scores = lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk,
+                               scores)
+    slot = lax.broadcasted_iota(jnp.int32, (k_top, b), 0)
 
-        scores = lax.fori_loop(0, w // _SEEN_CHUNK, mask_chunk, scores)
-    cat_v = jnp.concatenate([carry_v, scores], axis=1)  # [B, K+T]
-    cat_i = jnp.concatenate(
-        [carry_i, jnp.broadcast_to(gid, (b, t))], axis=1
+    def select(j, state):
+        sc, taken, out_v, out_i = state
+        free = taken == 0
+        mc = jnp.max(jnp.where(free, carry_v, neg), axis=0, keepdims=True)
+        ms = jnp.max(sc, axis=0, keepdims=True)  # [1, B]
+        pos_c = jnp.min(jnp.where(free & (carry_v == mc), slot, k_top),
+                        axis=0, keepdims=True)
+        pos_s = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
+        from_carry = mc >= ms
+        hit_c = from_carry & (slot == pos_c)
+        id_c = jnp.sum(jnp.where(slot == pos_c, carry_i, 0), axis=0,
+                       keepdims=True)
+        new_v = jnp.where(from_carry, mc, ms)
+        new_i = jnp.where(from_carry, id_c, tile_base + pos_s)
+        sc = jnp.where(jnp.logical_not(from_carry) & (row == pos_s), neg, sc)
+        taken = jnp.where(hit_c, 1, taken)
+        out_v = jnp.where(slot == j, new_v, out_v)
+        out_i = jnp.where(slot == j, new_i, out_i)
+        return sc, taken, out_v, out_i
+
+    # Every output slot is written once in K rounds, so the loop state
+    # starts from constants, not from the carry: inside a compiled kernel
+    # under shard_map a value read from an OUTPUT ref keeps the out_shape's
+    # vma while everything computed from it has none (jax 0.9.0), and a
+    # carry seeded with one could not typecheck.  Under shard_map's own
+    # tracing (the twin's sharded route) the state varies over the mesh
+    # like the tile's scores do.
+    init = jax.tree.map(
+        lambda z: match_varying(z, scores),
+        (jnp.zeros((k_top, b), jnp.int32),
+         jnp.full((k_top, b), neg, jnp.float32),
+         jnp.full((k_top, b), -1, jnp.int32)),
     )
-    new_v, pos = lax.top_k(cat_v, k_top)
-    new_i = jnp.take_along_axis(cat_i, pos, axis=1)
+    _, _, new_v, new_i = lax.fori_loop(0, k_top, select, (scores, *init))
     return new_v, new_i
 
 
@@ -180,8 +221,8 @@ def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
 
 
 def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
-                 with_scale, with_seen):
-    """Grid step i: fold movie tile i into the resident [B, K] carry.
+                 seen_width, with_scale):
+    """Grid step i: fold movie tile i into the resident [K, B] carry.
 
     The outputs are the carry (constant-index resident blocks, the Gram
     kernels' accumulation idiom): step 0 initializes them, every step
@@ -192,19 +233,20 @@ def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
     """
     refs = list(refs)
     scale_ref = refs.pop(0) if with_scale else None
-    seen_ref = refs.pop(0) if with_seen else None
+    seen_ref = refs.pop(0) if seen_width else None
     vals_ref, ids_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        vals_ref[...] = jnp.full((b, k_top), -jnp.inf, jnp.float32)
-        ids_ref[...] = jnp.full((b, k_top), -1, jnp.int32)
+        vals_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
+        ids_ref[...] = jnp.full((k_top, b), -1, jnp.int32)
 
     new_v, new_i = _score_tile_fold(
         vals_ref[...], ids_ref[...], u_ref[...], tbl_ref[...],
         scale_ref[...] if scale_ref is not None else None,
-        seen_ref[0] if seen_ref is not None else None,
+        (lambda j: seen_ref[0, pl.ds(j, 1), :]) if seen_width else None,
+        seen_width,
         off_ref[0] + i * t,
         num_movies=num_movies, k_top=k_top,
     )
@@ -259,19 +301,17 @@ def topk_scores_pallas(
             "per-row scale required exactly when the table is int8 "
             "(ops.quant.quantize_table provides it)"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret and (typeof_vma(u) or not has_vma_system()):
-        # Same routing rule as the Gram kernels: sharded-interpret and
-        # old-jax runs take the bit-exact XLA twin.
+    interpret = resolve_interpret(interpret)
+    if interpret and (typeof_vma(u) or typeof_vma(table)):
+        # Same routing rule as the Gram kernels: sharded-interpret runs
+        # take the bit-exact XLA twin (the table is the operand that
+        # varies over the mesh; the batch is replicated).
         from cfk_tpu.compat import emulate_topk_scores
 
         return emulate_topk_scores(
             u, table, scale, seen_tiles, k_top=k_top,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
-    if pltpu is None:  # pragma: no cover - non-TPU pallas build
-        raise RuntimeError("pallas TPU extensions unavailable")
     in_specs = [
         pl.BlockSpec((b, k), lambda i, off: (0, 0)),  # u: resident
         pl.BlockSpec((tile_m, k), lambda i, off: (i, 0)),  # table: streamed
@@ -280,24 +320,26 @@ def topk_scores_pallas(
     if scale is not None:
         in_specs.append(pl.BlockSpec((tile_m, 1), lambda i, off: (i, 0)))
         ops.append(scale.reshape(m_pad, 1).astype(jnp.float32))
+    seen_width = 0
     if seen_tiles is not None:
-        w = seen_tiles.shape[2]
-        in_specs.append(pl.BlockSpec((1, b, w), lambda i, off: (i, 0, 0)))
-        ops.append(seen_tiles)
+        seen_width = seen_tiles.shape[2]
+        # slot-major for the kernel: one exclusion slot = one [1, B] row
+        in_specs.append(
+            pl.BlockSpec((1, seen_width, b), lambda i, off: (i, 0, 0))
+        )
+        ops.append(jnp.swapaxes(seen_tiles, 1, 2))
     kwargs = {}
     if not interpret:
         # resident carry (2× for Mosaic's output double-buffer) + one
-        # streamed tile double-buffered + the seen rectangle + headroom
+        # streamed tile double-buffered + the seen rectangle + the [T, B]
+        # score block and its selection temporaries + headroom
         out_bytes = 2 * b * k_top * 8
         tile_bytes = 2 * tile_m * (k + 1) * 4
-        seen_bytes = (0 if seen_tiles is None
-                      else 2 * b * seen_tiles.shape[2] * 4)
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        kwargs["compiler_params"] = params(
+        seen_bytes = 2 * b * seen_width * 4
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=min(
-                2 * out_bytes + 2 * tile_bytes + seen_bytes + (16 << 20),
+                2 * out_bytes + 2 * tile_bytes + seen_bytes
+                + 8 * tile_m * b * 4 + (16 << 20),
                 110 << 20,
             )
         )
@@ -306,23 +348,25 @@ def topk_scores_pallas(
         grid=(nt,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((b, k_top), lambda i, off: (0, 0)),
-            pl.BlockSpec((b, k_top), lambda i, off: (0, 0)),
+            pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
+            pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
         ],
     )
     off = jnp.asarray(row_offset, jnp.int32).reshape(1)
+    # under shard_map the selection varies over the mesh like the table
+    # slice it was scored from (as in ops.pallas.gram_kernel)
+    vma = typeof_vma(table)
+    mk = (lambda s, d: jax.ShapeDtypeStruct(s, d, vma=vma)) if vma else (
+        lambda s, d: jax.ShapeDtypeStruct(s, d)
+    )
     vals, ids = pl.pallas_call(
         functools.partial(
             _topk_kernel, t=tile_m, k_top=k_top, num_movies=num_movies,
-            b=b, with_scale=scale is not None,
-            with_seen=seen_tiles is not None,
+            b=b, seen_width=seen_width, with_scale=scale is not None,
         ),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k_top), jnp.float32),
-            jax.ShapeDtypeStruct((b, k_top), jnp.int32),
-        ),
-        interpret=interpret,
+        out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32)),
+        interpret=bool(interpret),
         **kwargs,
     )(off, *ops)
-    return vals, ids
+    return vals.T, ids.T

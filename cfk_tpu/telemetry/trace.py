@@ -25,9 +25,9 @@ Design constraints (the sentinel discipline, ISSUE 3's ≤2% budget):
   token for spans whose begin and end live on different code paths (or
   different threads); they bypass the per-thread nesting stack.
 
-Span naming: callers pass the FULL taxonomy path (``train/iter/half_step/
+Span naming: callers pass the FULL span-name path (``train/iter/half_step/
 window_stage``) — explicit at the call site, zero path-joining overhead
-in the hot path.  The taxonomy is documented in ARCHITECTURE.md
+in the hot path.  The naming scheme is documented in ARCHITECTURE.md
 ("Telemetry").
 """
 

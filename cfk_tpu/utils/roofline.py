@@ -26,18 +26,60 @@ from __future__ import annotations
 
 import dataclasses
 
-# TPU v5e (v5 lite) single chip, from the public spec sheet.
-V5E_PEAK_BF16_FLOPS = 197e12  # per second
-V5E_HBM_BYTES_PER_S = 819e9
-V5E_HBM_BYTES = 16 * 1024**3
-# Measured on this chip (r3 gather micro-bench + in-scan profile): XLA's
-# row-gather engine sustains ~600M rows/s on ≤34 MB tables REGARDLESS of
-# row width (64-col bf16 and 128-col rows time identically) — the gather
-# is row-slot-bound, not byte-bound.  ALS is two gathers per rating per
-# iteration, which makes THIS the binding resource at full Netflix scale,
-# not HBM bandwidth: the row-gather floor (~0.36 s/iter) sits 6.7× above
-# the naive HBM roofline (54 ms).
-V5E_GATHER_ROWS_PER_S = 600e6
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """One chip's peaks, as the roofline and the cost model charge them."""
+
+    peak_bf16_flops: float  # per second
+    hbm_bytes_per_s: float
+    hbm_bytes: int
+    gather_rows_per_s: float  # XLA row-gather engine, rows per second
+    source: str
+
+
+# The ONE peaks table, keyed by ``jax.devices()[0].device_kind``.  A device
+# that is not here is an error (``device_peaks``), never a default: an MFU or
+# a roofline share over another chip's peak is not a number.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        peak_bf16_flops=197e12,
+        hbm_bytes_per_s=819e9,
+        hbm_bytes=16 * 1024**3,
+        # XLA's row-gather engine sustained ~600M rows/s on ≤34 MB tables
+        # REGARDLESS of row width (64-col bf16 and 128-col rows timed
+        # identically) — row-slot-bound, not byte-bound.  ALS is two
+        # gathers per rating per iteration, which makes THIS the binding
+        # resource at full Netflix scale: the row-gather floor (~0.36
+        # s/iter) sits 6.7× above the naive HBM roofline (54 ms).
+        gather_rows_per_s=600e6,
+        source=(
+            "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+            "16 GB HBM at 819 GB/s per chip; gather rate measured on one "
+            "chip (BASELINE.md round 3, 2026-08-01, earlier toolchain, "
+            "not re-measured)"
+        ),
+    ),
+}
+# Names people type for a table row (``cfk_tpu plan --device v5e``).
+DEVICE_KIND_ALIASES = {"v5e": "TPU v5 lite"}
+
+
+def _peaks_or_none(device_kind: str) -> DevicePeaks | None:
+    return DEVICE_PEAKS.get(DEVICE_KIND_ALIASES.get(device_kind, device_kind))
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The table row for ``device_kind`` (or an alias of it); raises for a
+    device without published peaks."""
+    pk = _peaks_or_none(device_kind)
+    if pk is None:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)} — add a row to "
+            "cfk_tpu.utils.roofline.DEVICE_PEAKS with its source before "
+            "reporting an efficiency against it"
+        )
+    return pk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,17 +94,14 @@ class IterationCost:
     def achieved_tflops(self, seconds: float) -> float:
         return self.model_flops / seconds / 1e12
 
-    def mfu(self, seconds: float, peak_flops: float = V5E_PEAK_BF16_FLOPS) -> float:
+    def mfu(self, seconds: float, peak_flops: float) -> float:
         return self.model_flops / seconds / peak_flops
 
-    def hbm_bound_s(self, bandwidth: float = V5E_HBM_BYTES_PER_S) -> float:
+    def hbm_bound_s(self, bandwidth: float) -> float:
         """Naive roofline floor: minimum HBM traffic over peak bandwidth."""
         return self.min_hbm_bytes / bandwidth
 
-    def gather_bound_s(
-        self, rows_per_s: float = V5E_GATHER_ROWS_PER_S,
-        bandwidth: float = V5E_HBM_BYTES_PER_S,
-    ) -> float:
+    def gather_bound_s(self, rows_per_s: float, bandwidth: float) -> float:
         """Gather floor: the binding resource for ALS on this chip.
 
         Every rating needs its neighbor's factor row on each side every
@@ -85,25 +124,51 @@ class IterationCost:
 FULL_NETFLIX_NNZ = 100_480_507
 
 
+_NOT_MEASURED = "not measured: no published peaks for device_kind {!r}"
+
+
+def this_device_kind() -> str:
+    """``device_kind`` of the device this process computes on."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
 def roofline_row(cost: IterationCost, s_per_iter: float,
-                 table_dtype: str | None = None) -> dict:
-    """The efficiency fields every recorded benchmark row carries.
+                 table_dtype: str | None = None, *,
+                 device_kind: str) -> dict:
+    """The model-cost and efficiency fields every recorded benchmark row
+    carries.  The counts (model FLOPs, minimum bytes) hold on any device;
+    the efficiencies are taken against the peaks of ``device_kind`` and, on
+    a device without published peaks (the CPU backend the tests run on),
+    left out with a ``"roofline": "not measured…"`` note — a CPU timing
+    over a TPU's peak is not a slower version of that number.
 
     One definition so bench.py's rows and scripts/perf_lab.py can never
     drift on which metrics exist or how they're computed.  ``table_dtype``
     records the gather-table quantization the run used (None → float32
     pre-quantization semantics are NOT implied — pass what the run ran)."""
     row = {
+        "device_kind": device_kind,
         "model_tflops_per_iter": round(cost.model_flops / 1e12, 4),
-        "achieved_tflops": round(cost.achieved_tflops(s_per_iter), 4),
-        "mfu": round(cost.mfu(s_per_iter), 5),
         "min_hbm_gb_per_iter": round(cost.min_hbm_bytes / 1e9, 3),
-        "hbm_roofline_s": round(cost.hbm_bound_s(), 4),
-        "vs_hbm_roofline": round(s_per_iter / cost.hbm_bound_s(), 2),
-        "gather_roofline_s": round(cost.gather_bound_s(), 4),
-        "vs_gather_roofline": round(s_per_iter / cost.gather_bound_s(), 2),
         "gather_gb_per_iter": round(cost.gather_bytes / 1e9, 3),
     }
+    pk = _peaks_or_none(device_kind)
+    if pk is None:
+        row["roofline"] = _NOT_MEASURED.format(device_kind)
+    else:
+        hbm_s = cost.hbm_bound_s(pk.hbm_bytes_per_s)
+        gather_s = cost.gather_bound_s(pk.gather_rows_per_s,
+                                       pk.hbm_bytes_per_s)
+        row.update({
+            "achieved_tflops": round(cost.achieved_tflops(s_per_iter), 4),
+            "mfu": round(cost.mfu(s_per_iter, pk.peak_bf16_flops), 5),
+            "hbm_roofline_s": round(hbm_s, 4),
+            "vs_hbm_roofline": round(s_per_iter / hbm_s, 2),
+            "gather_roofline_s": round(gather_s, 4),
+            "vs_gather_roofline": round(s_per_iter / gather_s, 2),
+        })
     if table_dtype is not None:
         row["table_dtype"] = table_dtype
     return row
@@ -154,14 +219,13 @@ class ServeBatchCost:
     model_flops: float  # 2·B·M_pad·k score MACs (the merge is negligible)
     hbm_bytes: float  # table scan + batch in + [B, K] out
 
-    def flops_bound_s(self, peak=V5E_PEAK_BF16_FLOPS) -> float:
+    def flops_bound_s(self, peak: float) -> float:
         return self.model_flops / peak
 
-    def bytes_bound_s(self, bandwidth=V5E_HBM_BYTES_PER_S) -> float:
+    def bytes_bound_s(self, bandwidth: float) -> float:
         return self.hbm_bytes / bandwidth
 
-    def batch_bound_s(self, peak=V5E_PEAK_BF16_FLOPS,
-                      bandwidth=V5E_HBM_BYTES_PER_S) -> float:
+    def batch_bound_s(self, peak: float, bandwidth: float) -> float:
         """The floor is max(compute, bytes): at serving batch sizes the
         table scan dominates (B ≪ M), so the roofline QPS is essentially
         batch · bandwidth / table_bytes — bigger batches and smaller
@@ -232,12 +296,13 @@ def serve_batch_cost(num_movies: int, rank: int, batch: int, k_top: int,
 
 
 def serve_roofline_row(cost: ServeBatchCost, s_per_batch: float,
-                       table_dtype: str | None = None) -> dict:
-    """The efficiency fields every ``bench.py --serve`` row carries — one
-    definition shared with ``perf_lab --serve`` (the same no-drift rule as
-    ``roofline_row``)."""
-    floor = cost.batch_bound_s()
+                       table_dtype: str | None = None, *,
+                       device_kind: str) -> dict:
+    """The model-cost and efficiency fields every ``bench.py --serve`` row
+    carries — one definition shared with ``perf_lab --serve`` (the same
+    no-drift rule, and the same no-peaks rule, as ``roofline_row``)."""
     row = {
+        "device_kind": device_kind,
         "serve_batch_tflops": round(cost.model_flops / 1e12, 6),
         "serve_batch_mb": round(cost.hbm_bytes / 1e6, 3),
         # The EXECUTED mode's per-batch HBM traffic (ISSUE 16): for exact
@@ -245,9 +310,14 @@ def serve_roofline_row(cost: ServeBatchCost, s_per_batch: float,
         # builds the cost from the MEASURED shortlist union, so the byte
         # column is what the batch actually moved, not the model's guess.
         "bytes_scanned_per_batch": round(cost.hbm_bytes),
-        "serve_roofline_s": round(floor, 6),
-        "vs_roofline": round(s_per_batch / floor, 2),
     }
+    pk = _peaks_or_none(device_kind)
+    if pk is None:
+        row["roofline"] = _NOT_MEASURED.format(device_kind)
+    else:
+        floor = cost.batch_bound_s(pk.peak_bf16_flops, pk.hbm_bytes_per_s)
+        row["serve_roofline_s"] = round(floor, 6)
+        row["vs_roofline"] = round(s_per_batch / floor, 2)
     if table_dtype is not None:
         row["table_dtype"] = table_dtype
     return row

@@ -8,8 +8,7 @@ on CPU:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/sharded_training.py
 
-(If the environment force-registers a TPU platform, the in-process override
-below handles CPU forcing — pass --cpu.)
+(``--cpu`` does the platform half of that in-process.)
 
 Multi-host (one process per host over DCN) uses the same code path after
 ``cfk_tpu.parallel.mesh.initialize_distributed()`` +

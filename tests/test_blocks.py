@@ -109,3 +109,45 @@ def test_counts_match_bincount(rng):
     np.testing.assert_array_equal(
         ds.movie_blocks.count.sum() , coo.num_ratings
     )
+
+
+def test_tiled_accum_chunk_elems_sizes_only_the_accum_half():
+    """``accum_chunk_elems`` (the measured knees differ: 64k stream / 256k
+    accum chunks at Netflix shape) applies to the half that resolves to
+    accum mode; ``chunk_elems`` still sizes the streamed half."""
+    from cfk_tpu.data.synthetic import synthetic_netflix_coo
+
+    coo = synthetic_netflix_coo(3_000, 200, 40_000, seed=2)
+    kw = dict(layout="tiled", tile_rows=16, accum_max_entities=1_000,
+              chunk_elems=2_048)
+    ds = Dataset.from_coo(coo, accum_chunk_elems=8_192, **kw)
+    assert ds.movie_blocks.mode == "accum" and ds.user_blocks.mode == "stream"
+    assert ds.movie_blocks.chunk_cap == 8_192
+    assert ds.user_blocks.chunk_cap == 2_048
+    # unset: one knob sizes both, as before
+    same = Dataset.from_coo(coo, **kw)
+    assert same.movie_blocks.chunk_cap == same.user_blocks.chunk_cap == 2_048
+    np.testing.assert_array_equal(same.user_blocks.neighbor_idx,
+                                  ds.user_blocks.neighbor_idx)
+
+
+def test_cached_scale_dataset_builds_through_from_coo(tmp_path):
+    """The measurement tools' build-or-load path (perf_lab, bench.py) is
+    ``Dataset.from_coo`` and nothing else: same blocks, same knobs."""
+    from cfk_tpu.data.cache import cached_scale_dataset
+    from cfk_tpu.data.synthetic import synthetic_netflix_coo
+
+    shape = dict(users=3_000, movies=200, nnz=40_000)
+    kw = dict(tile_rows=16, slice_rows=1_024, accum_chunk_elems=8_192,
+              dense_stream=True)
+    got = cached_scale_dataset(
+        **shape, seed=2, layout="tiled", chunk_elems=2_048,
+        cache_root=str(tmp_path), log=lambda *a, **k: None, **kw)
+    want = Dataset.from_coo(
+        synthetic_netflix_coo(3_000, 200, 40_000, seed=2), layout="tiled",
+        chunk_elems=2_048, **kw)
+    for side in ("movie_blocks", "user_blocks"):
+        g, w = getattr(got, side), getattr(want, side)
+        assert (g.mode, g.statics) == (w.mode, w.statics)
+        np.testing.assert_array_equal(g.neighbor_idx, w.neighbor_idx)
+    assert got.movie_blocks.slice_rows == 1_024

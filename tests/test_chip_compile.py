@@ -1,0 +1,360 @@
+"""Ask the chip's compiler, without the chip: every Pallas route the default
+settings pick on a TPU, compiled for a described v5e at the real widths.
+
+Interpret mode cannot see what Mosaic refuses (slices off the tiling, VMEM
+over the scoped limit, primitives with no lowering), so these compile the
+kernels — and the half-steps that call them — with ``interpret`` resolved the
+way a TPU backend resolves it, for ``v5e:2x2`` described through
+``jax.experimental.topologies`` (no device attached; nothing runs).  Shapes
+are the Netflix-Prize deployment's: the statics ``Dataset.from_coo(
+layout="tiled", dense_stream=True, chunk_elems=65_536,
+accum_chunk_elems=262_144)`` produces at 480,189 × 17,770 × 100,480,507
+(measured once, PR 21), and the ML-25M serve table (59,392 × 128, B=64, K=10).
+
+A compile that passes is not a chip run; ``chip_smoke.py`` is.  Each test
+asserts ``tpu_custom_call`` in the compiled module so an XLA twin cannot pass
+for the kernel, and each gate is checked from both sides: what it admits
+compiles, what the compiler refuses it does not admit.
+
+One file, on purpose: only one process at a time may load libtpu, so the
+topology is described inside a module-scoped fixture — never at import — and
+every compile runs in the test's own process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+f32, bf16, i8, i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+USERS, MOVIES = 480_189, 17_770
+# user half, dense stream: (NC, C, Ec, T, NT, NG, BG)
+U_NC, U_C, U_EC, T, U_NT, U_NG, U_BG = 1589, 65_536, 333, 128, 704, 11, 32_768
+U_SEG, U_META = U_EC + 1, U_NG + 4 * U_NT
+# movie half, accum: (NC, C, T, H, Ec)
+M_NC, M_C, M_H, M_EC = 403, 262_144, 131_072, 430
+M_NT, M_SEG = M_C // T, M_EC + 1
+LAM = 0.05
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plug-in raises: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` → a ShapeDtypeStruct placed on one described
+    v5e chip.  The persistent compile cache is off around the module: an
+    entry written for a described device cannot be read back without one,
+    and the next compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer the code that asks ``jax.default_backend()`` (``interpret=
+    None``, ``solver="auto"``) onto its TPU branch — this process's default
+    backend is the CPU, the compile target is not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described chip; the module must hold a
+    Mosaic kernel."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _carry(chip, k):
+    return chip((k, k), f32), chip((k,), f32), chip((), f32)
+
+
+# -- solve kernels -----------------------------------------------------------
+
+def test_solve_rank64(chip):
+    from cfk_tpu.ops.pallas import solve_kernel as sk
+
+    e, k = 2560, 64
+    _compile(lambda a, b: sk.gauss_solve_pallas(a, b, interpret=False),
+             chip((k, k, e), f32), chip((k, e), f32))
+    for algo in ("lu", "gj"):
+        _compile(
+            lambda a, b, r: sk.gauss_solve_reg_pallas(
+                a, b, r, reg_mode="diag", lam=LAM, interpret=False,
+                algo=algo),
+            chip((e + 1, k, k), f32), chip((e + 1, k), f32),
+            chip((e + 1,), f32))
+
+
+@pytest.mark.slow  # the unrolled LU-128 elimination compiles in ~2 min
+def test_solve_rank128_lu(chip):
+    from cfk_tpu.ops.pallas import solve_kernel as sk
+
+    e, k = 2561, 128
+    _compile(
+        lambda a, b, r: sk.gauss_solve_reg_pallas(
+            a, b, r, reg_mode="diag", lam=LAM, interpret=False, algo="lu"),
+        chip((e, k, k), f32), chip((e, k), f32), chip((e,), f32))
+
+
+# -- Gram kernels, rank 64 bf16 (the headline configuration) -----------------
+
+def test_gram_tiles_rank64_bf16(chip):
+    from cfk_tpu.ops.pallas import gram_kernel as gk
+
+    k = 64
+    _compile(
+        lambda g, rt, seg: gk.gram_tiles_pallas(
+            g, rt, seg, num_segments=M_SEG, tile_rows=T, interpret=False),
+        chip((M_C, k), bf16), chip((M_C,), f32), chip((M_NT,), i32))
+
+
+def test_gram_tiles_dense_rank64_bf16(chip):
+    from cfk_tpu.ops.pallas import gram_kernel as gk
+
+    k = 64
+    _compile(
+        lambda g, rt, meta, ca, cb, ci: gk.gram_tiles_dense_pallas(
+            g, rt, meta, num_segments=U_SEG, tile_rows=T, num_tiles=U_NT,
+            num_groups=U_NG, block_rows=U_BG, interpret=False,
+            carry=(ca, cb, ci)),
+        chip((U_C, k), bf16), chip((U_NT * T,), f32), chip((U_META,), i32),
+        *_carry(chip, k))
+
+
+# -- the gates, from both sides ----------------------------------------------
+
+def _dense_gather(chip, k, dtype):
+    """Compile the dense gather kernel at the user half's statics for a
+    table of ``dtype``."""
+    from cfk_tpu.ops.pallas import gram_kernel as gk
+
+    # int8 rows need the scale-carrying wt stream
+    wt = [chip((U_C,), f32)] if dtype == i8 else []
+
+    def fn(tbl, nb, rt, meta, ca, cb, ci, *wt):
+        return gk.gram_tiles_dense_gather_pallas(
+            tbl, nb, wt[0] if wt else None, rt, meta, num_segments=U_SEG,
+            tile_rows=T, num_tiles=U_NT, num_groups=U_NG, block_rows=U_BG,
+            interpret=False, carry=(ca, cb, ci))
+
+    return _compile(fn, chip((MOVIES, k), dtype), chip((U_C,), i32),
+                    chip((U_NT * T,), f32), chip((U_META,), i32),
+                    *_carry(chip, k), *wt)
+
+
+def test_gather_gate_admits_what_compiles(chip):
+    from cfk_tpu.ops.pallas.gram_kernel import in_kernel_gather_supported
+
+    gate = lambda k, dt: in_kernel_gather_supported(
+        U_C, U_META + 1, T, U_BG, k=k, table_dtype=dt)
+    assert gate(128, f32)
+    _dense_gather(chip, 128, f32)
+    # what Mosaic refuses (one-row DMA slices off the tiling) is not admitted
+    for k, dt in ((64, f32), (64, bf16), (128, bf16), (64, i8), (128, i8)):
+        assert not gate(k, dt), (k, dt)
+    # the accum half's 256k-entry chunks overflow the SMEM prefetch budget
+    assert not in_kernel_gather_supported(
+        M_C, M_NT, T, k=128, table_dtype=f32)
+
+
+@pytest.mark.parametrize("k,dtype", [(64, f32), (128, bf16)])
+def test_gather_gate_refusals_are_the_compilers(chip, k, dtype):
+    """The refused shapes still fail to compile — when one starts to pass,
+    the gate can admit it."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _dense_gather(chip, k, dtype)
+
+
+def test_fused_epilogue_gate_admits_what_compiles(chip):
+    from cfk_tpu.ops.pallas import gram_kernel as gk
+
+    k = 64
+    assert gk.fused_gram_solve_supported(U_SEG, k)
+    _compile(
+        lambda g, rt, meta, reg, lseg, ca, cb, ci:
+        gk.gram_solve_tiles_dense_pallas(
+            g, rt, meta, reg, lseg, num_segments=U_SEG, tile_rows=T,
+            num_tiles=U_NT, num_groups=U_NG, block_rows=U_BG,
+            reg_mode="diag", lam=LAM, interpret=False, carry=(ca, cb, ci)),
+        chip((U_C, k), bf16), chip((U_NT * T,), f32), chip((U_META,), i32),
+        chip((U_SEG,), f32), chip((), i32), *_carry(chip, k))
+    # rank 128: the v5e compiler ran out of VMEM on the elimination's stack
+    # (104 / 121 MiB with bf16 / f32 windows) — never admitted
+    for segs in (1, U_SEG, 2561):
+        assert not gk.fused_gram_solve_supported(segs, 128)
+
+
+def test_mode_resolvers_route_the_defaults(as_tpu):
+    """What default knobs resolve to at the deployment's statics — the
+    routes the half-step compiles below must contain."""
+    from cfk_tpu.ops.solve import _resolve_solver
+    from cfk_tpu.ops.tiled import resolve_tiled_route
+
+    assert _resolve_solver("auto") == "pallas"
+    user = ("dstream", (U_NC, U_C, U_EC, T, U_NT, U_NG, U_BG))
+    movie = ("accum", (M_NC, M_C, T, M_H, M_EC))
+    route = lambda half, k, dt: resolve_tiled_route(
+        *half, k, LAM, table_dtype=dt, solver="auto")
+    assert route(user, 64, bf16) == ("xla", LAM)
+    assert route(user, 64, i8) == ("xla", LAM)
+    assert route(user, 128, f32) == ("fused", None)
+    for k, dt in ((64, bf16), (128, f32)):  # 256k chunks overflow SMEM
+        assert route(movie, k, dt) == ("xla", None)
+
+
+# -- whole half-steps through the default routes -----------------------------
+
+def _user_blocks(chip):
+    return dict(
+        neighbor_idx=chip((U_NC * U_C,), i32),
+        rating=chip((U_NC * U_NT * T,), f32),
+        tile_meta=chip((U_NC * U_META,), i32),
+        chunk_entity=chip((U_NC * U_EC,), i32),
+        chunk_count=chip((U_NC * U_EC,), i32),
+        carry_in=chip((U_NC,), f32), last_seg=chip((U_NC,), i32),
+        count=chip((USERS,), i32),
+    ), ("tiled", "dstream", U_NC, U_C, U_EC, T, U_NT, U_NG, U_BG)
+
+
+def _movie_blocks(chip):
+    n = M_NC * M_C
+    return dict(
+        neighbor_idx=chip((n,), i32), rating=chip((n,), f32),
+        weight=chip((n,), f32), tile_seg=chip((M_NC * M_NT,), i32),
+        chunk_base=chip((M_NC,), i32),
+        chunk_entity=chip((M_NC * M_EC,), i32),
+        chunk_count=chip((M_NC * M_EC,), i32),
+        carry_in=chip((M_NC,), f32), last_seg=chip((M_NC,), i32),
+        slice_starts=chip((5,), i32), count=chip((MOVIES,), i32),
+    ), ("tiled", "accum", M_NC, M_C, T, M_H, M_EC)
+
+
+def _half_step(chip, side, k, dtype, table_dtype):
+    from cfk_tpu.ops.tiled import tiled_half_step
+
+    if side == "user":
+        (blk, chunks), fixed_rows, ents = _user_blocks(chip), MOVIES, USERS
+    else:
+        (blk, chunks), fixed_rows, ents = _movie_blocks(chip), USERS, MOVIES
+    return _compile(
+        lambda fixed, b: tiled_half_step(
+            fixed, b, chunks, ents, LAM, solver="auto",
+            table_dtype=table_dtype),
+        chip((fixed_rows, k), dtype), blk)
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("side", ["user", "movie"])
+def test_half_step_rank64_bf16(chip, as_tpu, side, table_dtype):
+    """The headline route (XLA gather + fused epilogue on the user half,
+    Gram kernel + one LU-64 solve on the movie half), plain and with the
+    int8 gather table."""
+    compiled = _half_step(chip, side, 64, bf16, table_dtype)
+    # fits one v5e chip (16 GiB) with the other half's blocks resident too
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 12 << 30
+
+
+def test_half_step_rank64_f32_user(chip, as_tpu):
+    """float32 storage at rank 64: inside the half-step program the stream
+    block reaches the fused kernel lane-padded (twice the window bytes a
+    standalone compile of the kernel sees), and the chip's compiler refused
+    it by 13 MiB until ``_dense_window_bytes`` counted the padding — found
+    on the chip by ``chip_smoke.py --multichip``'s one-device reference."""
+    _half_step(chip, "user", 64, f32, "float32")
+
+
+@pytest.mark.slow  # LU-128 compiles in ~2 min per program
+@pytest.mark.parametrize("side", ["user", "movie"])
+def test_half_step_rank128_f32(chip, as_tpu, side):
+    """In-kernel gather (user half) + split epilogue with the standalone
+    LU-128 solve."""
+    _half_step(chip, side, 128, f32, "float32")
+
+
+# -- the bucketed port (iALS++ / bucketed ALS) -------------------------------
+
+@pytest.mark.parametrize("rows,width", [(4096, 16), (1024, 128)])
+def test_bucket_port_piece_rank64_bf16(chip, as_tpu, rows, width):
+    """One width class through ``ops.bucketed`` as the defaults route it at
+    rank 64 bf16: the tile kernels with ``tile_rows = width`` and up to 256
+    tiles per group, ``lax.map``'d over several pieces.  Two refusals were
+    repaired here: XLA fused the kernel into the loop's output update and
+    held it to the default 16 MiB VMEM limit (``optimization_barrier``),
+    and 256 unrolled tile Grams overran the kernel's own budget
+    (``_walk_stack_bytes``)."""
+    from cfk_tpu.ops import bucketed as bp
+
+    k, f_rows = 64, 59_047
+    fused, gather = bp.resolve_bucket_modes(
+        None, None, "auto", rows, width, k, LAM, None, table_dtype=bf16)
+    assert (fused, gather) == (True, "xla")
+    assert bp._sub_rows(rows, width, k, fused, None) < rows  # several pieces
+    _compile(
+        lambda table, nb, wt, rt, cnt: bp.bucket_gram_solve(
+            table, None, nb, wt, rt, cnt, lam=LAM, reg_mode="diag",
+            solver="auto", fused=fused, gather=gather, algo=None),
+        chip((f_rows, k), bf16), chip((rows, width), i32),
+        chip((rows, width), f32), chip((rows, width), f32),
+        chip((rows,), f32))
+
+
+# -- serving -----------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [f32, bf16, i8])
+def test_serve_scorer_ml25m_width(chip, dtype):
+    from cfk_tpu.serving.topk_kernel import topk_scores_pallas
+
+    m_pad, k, b, k_top, tile_m, w = 59_392, 128, 64, 10, 512, 64
+    scale = [chip((m_pad,), f32)] if dtype == i8 else []
+
+    def fn(u, tbl, seen, *sc):
+        return topk_scores_pallas(
+            u, tbl, sc[0] if sc else None, seen, k_top=k_top,
+            num_movies=59_047, tile_m=tile_m, interpret=False)
+
+    _compile(fn, chip((b, k), f32), chip((m_pad, k), dtype),
+             chip((m_pad // tile_m, b, w), i32), *scale)
+
+
+def test_serve_sharded_four_devices(topo, chip, as_tpu):
+    """Item-axis sharded serving as one program over the described 2×2
+    mesh, shard_map's vma check ON: the kernel's outputs carry the table's
+    vma, and the merged selections come back stacked over the mesh axis."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cfk_tpu.parallel import spmd
+    from cfk_tpu.parallel.mesh import AXIS
+
+    mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
+    m, m_pad, k, b, k_top, tile_m, w = 59_047, 59_392, 128, 64, 10, 512, 16
+    nt = m_pad // tile_m
+    fn = spmd._serve_topk_sharded_fn(
+        mesh, m_pad // 4, False, True, k_top, m, tile_m)
+    on = lambda shape, dt, spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, spec))
+    text = fn.lower(
+        on((b, k), f32, P()), on((m_pad, k), bf16, P(AXIS)),
+        on((m_pad,), f32, P(AXIS)), on((nt, b, w), i32, P(AXIS)),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text

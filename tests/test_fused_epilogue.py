@@ -41,7 +41,7 @@ def _half(blocks, fixed, lam, fused, **kw):
     ))
 
 
-def test_stream_fused_matches_split_bitexact(synth):
+def test_stream_fused_matches_split(synth):
     d = synth.coo_dense
     rng = np.random.default_rng(0)
     M = jnp.asarray(rng.standard_normal((400, 8)).astype(np.float32))
@@ -52,7 +52,14 @@ def test_stream_fused_matches_split_bitexact(synth):
     assert ub.mode == "stream"
     fused = _half(ub, M, 0.05, True)
     split = _half(ub, M, 0.05, False)
-    np.testing.assert_array_equal(fused, split)
+    # tile_rows=8 refuses the in-kernel gather, so the split side calls
+    # gram_tiles_pallas — on the CPU the kernel BODY under the Pallas
+    # interpreter — while the fused wrapper runs its XLA twin (einsum +
+    # segment-sum).  Same products, different float32 summation order: a
+    # few ulp on (A, b), 3.6e-7 absolute observed in the solved rank-8
+    # rows on jax 0.9.0.  The bit-exact leg (twin vs the XLA split
+    # schedule) is the next test.
+    np.testing.assert_allclose(fused, split, rtol=1e-5, atol=1e-5)
 
 
 def test_stream_fused_matches_xla_split_bitexact(synth):

@@ -5,11 +5,26 @@ materialized [C, k] gathered stream.
 
 Equivalence contract pinned here: on the interpret/XLA-emulation route
 the fused gather runs the numerically identical append-zero-row + gather
-+ premultiply the XLA-gather path runs (``compat.emulate_in_kernel_gather``),
-so fused-gather and XLA-gather factors are BIT-IDENTICAL — for the
-kernel wrappers (padding rows, bf16 and f32 tables, the weighted √aw
-premultiply, carries) and for the stream/dense/accum/ring half-step
++ premultiply the XLA-gather path runs (``compat.emulate_in_kernel_gather``)
+— for the kernel wrappers (padding rows, bf16 and f32 tables, the weighted
+√aw premultiply, carries) and for the stream/dense/accum/ring half-step
 bodies, overlap on and off, with the support-gate fallbacks exercised.
+
+What "identical" means depends on what the two sides run on the CPU:
+
+- same program two ways — the gather wrappers' XLA twin against another
+  twin (the fused-epilogue and dense wrappers always take theirs), or one
+  route with the knob resolving the same way: BIT-IDENTICAL
+  (``assert_array_equal``);
+- twin against kernel body — the knob-off side of a split-epilogue stream
+  or accum half-step calls ``gram_tiles_pallas``, whose interpret route
+  outside shard_map is the kernel body under the Pallas interpreter, while
+  the knob-on side is the gather wrapper's twin.  The body sums each
+  16-row tile with one MXU-shaped dot and walks tiles in order; the twin
+  is an einsum + segment-sum that XLA:CPU is free to reassociate.  Same
+  products, different float32 summation order: a few ulp on (A, b)
+  (≤ 4.6e-7 relative observed on jax 0.9.0), amplified by the ridge
+  system's conditioning once solved.  ``_ULP`` / ``_SOLVED`` below.
 """
 
 import dataclasses
@@ -34,6 +49,11 @@ from cfk_tpu.ops.pallas.gram_kernel import (
 from cfk_tpu.ops.tiled import ials_tiled_half_step, tiled_half_step
 
 
+# kernel body vs XLA twin (module docstring): float32 summation order only.
+_ULP = dict(rtol=2e-6, atol=0)  # (A, b) sums; 4.6e-7 relative observed
+_SOLVED = dict(rtol=1e-5, atol=1e-5)  # solved rank-8 factors; 7.2e-7 abs seen
+
+
 @pytest.fixture(scope="module")
 def synth():
     coo = synthetic_netflix_coo(3000, 400, 60_000, seed=1)
@@ -56,21 +76,34 @@ def _kernel_inputs(rng, *, f=37, k=8, t=16, nt=12, s=5, dtype=np.float32):
 
 @pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
 def test_kernel_gather_matches_materialized_stream(dtype):
-    """Unit-weight contract: gather-fused (A, b) == the split kernel fed
-    the materialized zero-row-appended stream, bit-exact, f32 AND bf16
-    tables, padding rows contributing exact zeros."""
+    """Unit-weight contract: gather-fused (A, b) == the split Gram fed the
+    materialized zero-row-appended stream, f32 AND bf16 tables, padding
+    rows contributing exact zeros.  Twin vs twin is bit-exact; twin vs the
+    split kernel's body is float32 summation order apart (``_ULP``)."""
+    from cfk_tpu.ops.pallas.gram_kernel import _emulate_gram_tiles
+
     rng = np.random.default_rng(0)
     dt = jnp.bfloat16 if dtype == "bfloat16" else np.float32
     table, nb, mask, rt, seg = _kernel_inputs(rng)
     table = table.astype(dt)
     fz = jnp.concatenate([table, jnp.zeros((1, 8), table.dtype)])
     g = fz[nb]  # the materialized stream the XLA schedule builds
-    a_ref, b_ref = gram_tiles_pallas(g, rt, seg, num_segments=5,
-                                     tile_rows=16)
     a, b = gram_tiles_gather_pallas(table, nb, mask, rt, seg,
                                     num_segments=5, tile_rows=16)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_ref))
-    np.testing.assert_array_equal(np.asarray(b), np.asarray(b_ref))
+    a_twin, b_twin = _emulate_gram_tiles(g, rt, seg, num_segments=5,
+                                         tile_rows=16, carry=None)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_twin))
+    np.testing.assert_array_equal(np.asarray(b), np.asarray(b_twin))
+    a_ref, b_ref = gram_tiles_pallas(g, rt, seg, num_segments=5,
+                                     tile_rows=16)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(a_ref), **_ULP)
+    # b: the body feeds the b coefficient to the MXU in the stream dtype
+    # (``r_i.astype(g_i.dtype)``), the twin keeps it float32 — on a bf16
+    # stream that is 2⁻⁹ relative rounding per term (2.5e-2 observed on
+    # sums that cancel), not summation order.
+    b_tol = _ULP if dtype == np.float32 else dict(
+        rtol=0, atol=1e-2 * float(np.abs(np.asarray(b_ref)).max()))
+    np.testing.assert_allclose(np.asarray(b), np.asarray(b_ref), **b_tol)
 
 
 def test_kernel_gather_weighted_premultiply():
@@ -123,14 +156,26 @@ def test_kernel_gather_fused_solve_with_carry():
 
 
 def test_support_gate():
-    """SMEM budget and tile/block alignment gates; refused shapes keep
-    the XLA-gather path (exercised end-to-end below via tile_rows=8)."""
-    assert in_kernel_gather_supported(65_536, 20_480, 128)
-    assert not in_kernel_gather_supported(65_536, 20_480, 8)  # tile align
+    """Rank/dtype (what Mosaic's one-row DMA lowers — tests/
+    test_chip_compile.py holds the gate to the chip's compiler), SMEM
+    budget and tile/block alignment gates; refused shapes keep the
+    XLA-gather path (exercised end-to-end below)."""
+    f32 = dict(k=128, table_dtype=jnp.float32)
+    assert in_kernel_gather_supported(65_536, 20_480, 128, **f32)
+    assert not in_kernel_gather_supported(65_536, 20_480, 8, **f32)  # tile
     assert not in_kernel_gather_supported(
-        65_536, 20_480, 128, block_rows=24
+        65_536, 20_480, 128, block_rows=24, **f32
     )  # block align
-    assert not in_kernel_gather_supported(1 << 21, 0, 128)  # SMEM budget
+    assert not in_kernel_gather_supported(1 << 21, 0, 128, **f32)  # SMEM
+    for k, dt in ((64, jnp.float32), (128, jnp.bfloat16), (128, jnp.int8)):
+        assert not in_kernel_gather_supported(
+            65_536, 20_480, 128, k=k, table_dtype=dt), (k, dt)
+        # ... where Mosaic lowers the DMA; the interpret route's twin has
+        # no such limit, only the shape gates
+        assert in_kernel_gather_supported(
+            65_536, 20_480, 128, k=k, table_dtype=dt, lowered=False)
+    assert not in_kernel_gather_supported(
+        65_536, 20_480, 8, lowered=False, **f32)
 
 
 def _half(blocks, fixed, lam, ikg, weighted=False, **kw):
@@ -174,9 +219,11 @@ def test_dense_stream_fused_gather_matches_xla_bitexact(synth, overlap):
 
 
 @pytest.mark.parametrize("overlap", [True, False])
-def test_accum_fused_gather_matches_xla_bitexact(synth, overlap):
+def test_accum_fused_gather_matches_xla(synth, overlap):
     """Accum mode rebases slice-local indices to absolute table rows and
-    skips the hoisted window stack entirely — factors stay bit-exact."""
+    skips the hoisted window stack entirely.  Accum has no fused epilogue,
+    so knob-off runs the split kernel's body against knob-on's twin:
+    ``_SOLVED``, not bits."""
     d = synth.coo_dense
     rng = np.random.default_rng(4)
     U = jnp.asarray(rng.standard_normal((3000, 8)).astype(np.float32))
@@ -187,7 +234,7 @@ def test_accum_fused_gather_matches_xla_bitexact(synth, overlap):
     assert mb.mode == "accum"
     on = _half(mb, U, 0.05, True, overlap=overlap)
     off = _half(mb, U, 0.05, False, overlap=overlap)
-    np.testing.assert_array_equal(on, off)
+    np.testing.assert_allclose(on, off, **_SOLVED)
 
 
 @pytest.mark.parametrize("dense", [False, True])
@@ -231,7 +278,8 @@ def test_unaligned_tiles_fall_back_to_xla_gather(synth):
 
 def test_gather_with_split_epilogue(synth):
     """The fused gather composes with fused_epilogue=False (gather-fused
-    Gram, split HBM solve) — still bit-exact vs the all-XLA schedule."""
+    Gram, split HBM solve).  Knob-off is the split kernel's body, knob-on
+    the gather twin: ``_SOLVED`` vs the all-XLA-gather schedule."""
     d = synth.coo_dense
     rng = np.random.default_rng(6)
     M = jnp.asarray(rng.standard_normal((400, 8)).astype(np.float32))
@@ -241,13 +289,16 @@ def test_gather_with_split_epilogue(synth):
     )
     on = _half(ub, M, 0.05, True, fused_epilogue=False)
     off = _half(ub, M, 0.05, False, fused_epilogue=False)
-    np.testing.assert_array_equal(on, off)
+    np.testing.assert_allclose(on, off, **_SOLVED)
 
 
 def test_rank_above_solve_cap_keeps_gather(synth):
     """rank > the fused elimination's cap: the fused SOLVE falls back to
-    the split schedule while the fused GATHER stays active — still
-    bit-identical to the all-XLA schedule."""
+    the split schedule while the fused GATHER stays active.  The split
+    schedule's knob-off side is the kernel body (twin vs body), and at
+    rank 136 every system has far more unknowns than the ~20 ratings
+    behind it, so only the λ·n ridge conditions it: the few-ulp (A, b)
+    difference reaches 1.3e-5 absolute in the solved rows — 1e-4."""
     from cfk_tpu.ops.pallas.solve_kernel import LU_MAX_RANK
 
     d = synth.coo_dense
@@ -260,7 +311,7 @@ def test_rank_above_solve_cap_keeps_gather(synth):
     )
     on = _half(ub, M, 0.05, True)
     off = _half(ub, M, 0.05, False)
-    np.testing.assert_array_equal(on, off)
+    np.testing.assert_allclose(on, off, rtol=1e-4, atol=1e-4)
 
 
 def test_trainer_gather_matches_xla_bitexact(synth):

@@ -318,7 +318,7 @@ def test_plan_resolves_oversized_to_host_window():
         plan,
     )
 
-    dev = DeviceSpec.nominal("tpu")
+    dev = DeviceSpec.nominal("tpu", name="v5e")
     big = ProblemShape(num_users=10_000_000, num_movies=1_000_000,
                        nnz=1_000_000_000, rank=128)
     ep, prov = plan(big, dev)

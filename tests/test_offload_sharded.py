@@ -404,7 +404,7 @@ def test_shape_fits_device_threads_num_shards():
     shape1 = ProblemShape(num_users=10_000_000, num_movies=1_000_000,
                           nnz=1_000_000_000, rank=128)
     shape4 = dataclasses.replace(shape1, num_shards=4)
-    dev = DeviceSpec.nominal("tpu")
+    dev = DeviceSpec.nominal("tpu", name="v5e")
     assert not _budget.shape_fits_device(shape1, dev)
     assert _budget.shape_fits_device(shape4, dev)
 
@@ -420,7 +420,7 @@ def test_sharded_oversized_resolves_host_window_with_exchange():
         plan,
     )
 
-    dev = DeviceSpec.nominal("tpu")
+    dev = DeviceSpec.nominal("tpu", name="v5e")
     big = ProblemShape(num_users=40_000_000, num_movies=1_000_000,
                        nnz=2_000_000_000, rank=128, num_shards=4)
     ep, prov = plan(big, dev)

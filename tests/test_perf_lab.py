@@ -56,10 +56,13 @@ def test_json_row_contract(tmp_path, monkeypatch, capsys):
     row = perf_lab.run_lab(_args())
     out = capsys.readouterr().out.strip().splitlines()
     assert json.loads(out[-1]) == row  # last stdout line IS the row
-    for key in ("s_per_iter_min", "s_per_iter_median", "mfu",
-                "hbm_roofline_s", "gather_roofline_s", "vs_gather_roofline",
+    for key in ("s_per_iter_min", "s_per_iter_median", "device_kind",
+                "model_tflops_per_iter", "min_hbm_gb_per_iter",
                 "layout", "rank", "iters_per_call"):
         assert key in row, key
+    # this run is on the CPU backend: no efficiency against a TPU's peaks
+    assert "not measured" in row["roofline"]
+    assert "mfu" not in row and "vs_gather_roofline" not in row
     assert row["s_per_iter_min"] >= 0
     assert row["s_per_iter_min"] <= row["s_per_iter_median"]
     assert row["layout"] == "segment"
@@ -405,10 +408,10 @@ def test_serve_axis_row(tmp_path, monkeypatch, capsys):
     assert row["answered"] == 24
     assert row["qps"] > 0
     assert row["serve_k"] == 3
-    assert row["vs_roofline"] > 0
+    assert "not measured" in row["roofline"]  # CPU backend: no peaks
     assert row["batches"] >= 1
     for key in ("p50_ms", "p99_ms", "batch_s", "capacity_qps",
-                "serve_roofline_s"):
+                "serve_batch_mb"):
         assert row[key] >= 0, key
     assert row["p50_ms"] <= row["p99_ms"]
     # every serve row now carries the ISSUE 16 A/B columns
@@ -439,4 +442,4 @@ def test_serve_axis_two_stage_row(tmp_path, monkeypatch, capsys):
     assert 0 < row["shortlist_rows"] <= row["movies"]
     assert 0.0 <= row["recall_at_k"] <= 1.0
     assert row["bytes_scanned_per_batch"] > 0
-    assert row["vs_roofline"] > 0
+    assert "vs_roofline" not in row  # CPU backend: no peaks

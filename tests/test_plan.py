@@ -110,9 +110,18 @@ def test_cost_model_orderings():
     base, _ = plan(sh, TPU, PlanConstraints(layout="tiled"))
     c = lambda ep: plan_cost(sh, TPU, ep).seconds
     flip = lambda **kw: dataclasses.replace(base, **kw)
-    assert c(flip(in_kernel_gather=False)) > c(base)
     assert c(flip(fused_epilogue=False)) > c(base)
     assert c(flip(reg_solve_algo="gj")) >= c(base)
+    # The in-kernel gather is feasible only where Mosaic lowers its row
+    # DMA: float32 tables at a multiple of 128 lanes (rank 128 here, where
+    # the fused epilogue in turn is refused).
+    assert not base.in_kernel_gather
+    sh128 = _shape(rank=128)
+    base128, _ = plan(sh128, TPU, PlanConstraints(layout="tiled"))
+    assert base128.in_kernel_gather and not base128.fused_epilogue
+    c128 = lambda ep: plan_cost(sh128, TPU, ep).seconds
+    assert c128(dataclasses.replace(base128, in_kernel_gather=False)) \
+        > c128(base128)
     # Quantized tables can only shrink the estimate.
     assert c(flip(table_dtype="int8")) <= c(base)
     # On the byte-bound CPU spec int8 is STRICTLY cheaper (resolve both
@@ -395,7 +404,7 @@ def test_autotune_stale_fingerprint_invalidates(tmp_path, monkeypatch):
     autotune(sh, TPU, PlanConstraints(layout="tiled"), cache_path=path,
              measure=m)
     # Different device fingerprint → miss, re-measures.
-    other = DeviceSpec(kind="tpu", name="v6e")
+    other = dataclasses.replace(TPU, name="v6e")
     m2 = _fake_measure({"float32": 0.2})
     _, prov = autotune(sh, other, PlanConstraints(layout="tiled"),
                        cache_path=path, measure=m2)
@@ -495,14 +504,15 @@ def test_registry_slots_resolve_loaders():
 
 def test_forced_outage_reroutes_resolvers_and_bumps_generation():
     gen0 = REGISTRY.generation()
-    args = (None, "pallas", "full", 512, 34, 16, 33, 8)
-    assert resolve_gather_mode(*args) == "fused"
+    args = (None, "pallas", "full", 512, 34, 16, 33, 128)
+    kw = dict(table_dtype="float32")
+    assert resolve_gather_mode(*args, **kw) == "fused"
     assert resolve_fused_chunk_lam(None, "pallas", 8, 33, "pallas", 0.05,
                                    False) == 0.05
     with REGISTRY.unavailable("mosaic_tpu"):
         assert REGISTRY.generation() == gen0 + 1
         assert not REGISTRY.backend_available("mosaic_tpu")
-        assert resolve_gather_mode(*args) == "xla"
+        assert resolve_gather_mode(*args, **kw) == "xla"
         assert resolve_fused_chunk_lam(None, "pallas", 8, 33, "pallas",
                                        0.05, False) is None
         # The resolver lands every slot on the emulation floor.

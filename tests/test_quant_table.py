@@ -140,8 +140,15 @@ def test_roofline_table_bytes():
                              table_dtype="bfloat16")
     assert c_b.gather_bytes == c_f.gather_bytes / 2
     assert c_b.gather_rows == c_f.gather_rows
-    row = roofline_row(c_b, 1.0, table_dtype="bfloat16")
+    row = roofline_row(c_b, 1.0, table_dtype="bfloat16",
+                       device_kind="TPU v5 lite")
     assert row["table_dtype"] == "bfloat16"
+    assert row["vs_gather_roofline"] > 0 and "roofline" not in row
+    # a device without published peaks gets the counts and a note, never
+    # another chip's peak
+    cpu = roofline_row(c_b, 1.0, device_kind="cpu")
+    assert "not measured" in cpu["roofline"] and "mfu" not in cpu
+    assert cpu["gather_gb_per_iter"] == row["gather_gb_per_iter"]
     # layout-aware rows: bucketed counts padded cells, sweeps multiply
     c_r = als_iteration_cost(10**7, 10**5, 10**4, 128, gather_rows=3.1e7,
                              sweeps=2)

@@ -13,8 +13,9 @@ Four groups:
 - prewarm: ``ServeEngine.prewarm`` / ``StreamSession.prewarm`` trace the
   pow2 bucket set up front, pinned by ZERO new traces on the first real
   batch afterwards;
-- ``enable_compile_cache``: the persistent-cache dir is keyed per device
-  fingerprint and populated by a compile.
+- ``enable_compile_cache``: the persistent-cache directory rules (the
+  environment's directory wins, else one fixed directory), populated by a
+  compile.
 """
 
 import threading
@@ -304,26 +305,54 @@ def test_stream_session_prewarm_skips_tiled_layout(tmp_path):
 # --- compile cache -----------------------------------------------------------
 
 
-def test_enable_compile_cache_keys_per_device(tmp_path):
+def test_enable_compile_cache_directory_rules(tmp_path, monkeypatch):
+    """The environment's directory wins and is never overridden in code;
+    without it the flag/config directory, else ONE fixed in-checkout
+    default — no per-device sub-directory, no temp name; an unusable path
+    raises."""
     import os
 
-    from cfk_tpu.config import enable_compile_cache
-    from cfk_tpu.plan.spec import DeviceSpec
+    from cfk_tpu import config as cfg
 
-    assert enable_compile_cache(None) is None
-    sub = enable_compile_cache(str(tmp_path))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cfg, "DEFAULT_COMPILE_CACHE_DIR",
+                        str(tmp_path / "default"))
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_enable_compilation_cache", True)  # conftest: off
     try:
-        fp = DeviceSpec.detect().fingerprint().replace(":", "_")
-        assert sub == os.path.join(str(tmp_path), fp)
-        assert os.path.isdir(sub)
+        assert cfg.enable_compile_cache() == str(tmp_path / "default")
+        assert cfg.enable_compile_cache() == str(tmp_path / "default")
+        # ... and it is in use, on whatever backend this is (the CPU here)
+        assert jax.config.jax_compilation_cache_dir == str(
+            tmp_path / "default")
+        assert os.path.isdir(tmp_path / "default")
+        sub = cfg.enable_compile_cache(str(tmp_path / "flag"))
+        assert sub == str(tmp_path / "flag") and os.path.isdir(sub)
+        assert jax.config.jax_compilation_cache_dir == sub
 
-        # a fresh compile lands in the per-device cache directory
+        # a fresh compile lands directly in that directory
         @jax.jit
         def f(x):
             return (x * 2.0 + 1.0).sum()
 
         f(jax.numpy.arange(1333.0)).block_until_ready()
         assert any("-cache" in name for name in os.listdir(sub))
+
+        # with the variable set, nothing here names another directory
+        env_dir = str(tmp_path / "env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert cfg.enable_compile_cache(str(tmp_path / "other")) == env_dir
+        assert jax.config.jax_compilation_cache_dir == sub  # untouched
+        assert not os.path.exists(tmp_path / "other")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+
+        # a path that cannot be used is an error, not a warning
+        blocker = tmp_path / "file"
+        blocker.write_text("")
+        with pytest.raises(OSError):
+            cfg.enable_compile_cache(str(blocker / "sub"))
     finally:
         # restore: later tests must not inherit the cache dir
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_enable_compilation_cache", was[1])

@@ -277,7 +277,9 @@ def test_roofline_two_stage_variant():
     assert meas.hbm_bytes == pytest.approx(
         1024 * int8_row + 2048 * (int8_row + 4.0)
         + ex.hbm_bytes - m * int8_row, rel=0.05)
-    row = serve_roofline_row(ts, 1.0, table_dtype="int8")
+    row = serve_roofline_row(ts, 1.0, table_dtype="int8",
+                             device_kind="cpu")
+    assert "not measured" in row["roofline"] and "vs_roofline" not in row
     assert row["bytes_scanned_per_batch"] == round(ts.hbm_bytes)
     with pytest.raises(ValueError):
         serve_batch_cost(m, r, b, k, serve_mode="two_stage", clusters=0)
