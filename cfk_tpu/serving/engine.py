@@ -414,7 +414,7 @@ class ServeEngine:
         if not 1 <= k <= self.num_movies:
             raise ValueError(f"k must be in [1, {self.num_movies}], got {k}")
         b = _pow2_ceil(n, self.batch_quantum)
-        with span("serve/batch/assemble", n=n, b=b):
+        with span("serve/batch/assemble", n=n, b=b) as sp:
             with self._lock:
                 table, scale = self._table
                 cluster = self._cluster
@@ -432,6 +432,7 @@ class ServeEngine:
                     [indptr, np.full(b - n, indptr[-1], np.int64)]
                 )
                 seen_pad = (movies, indptr_pad)
+                sp.set(seen_cells=len(movies))
         if (self.serve_mode == "two_stage" and not force_exact
                 and not self._two_stage_disabled):
             out = self._topk_two_stage(cluster, u, n, b, k, seen_pad)
@@ -443,26 +444,42 @@ class ServeEngine:
         seen_tiles = None
         if seen_pad is not None:
             movies, indptr_pad = seen_pad
-            seen_tiles = jnp.asarray(build_seen_tiles(
-                movies, indptr_pad, np.arange(b),
-                num_movies=self.num_movies,
-                tile_m=self.tile_m,
-                num_tiles=self.table_rows // self.tile_m,
-            ))
+            with span("serve/batch/seen_tiles") as sp:
+                seen_tiles = build_seen_tiles(
+                    movies, indptr_pad, np.arange(b),
+                    num_movies=self.num_movies,
+                    tile_m=self.tile_m,
+                    num_tiles=self.table_rows // self.tile_m,
+                )
+                _set_rectangle(sp, seen_tiles)
+        # the calls that hand the batch to the runtime; they may return
+        # before the bytes have landed, and the fetch below then waits
+        # for the transfer as well as for the scorer
+        with span("serve/batch/upload") as sp:
+            nbytes = u.nbytes
+            if seen_tiles is not None:
+                nbytes += seen_tiles.nbytes
+                seen_tiles = jnp.asarray(seen_tiles)
+            u = jnp.asarray(u)
+            sp.set(bytes=nbytes)
         with span("serve/batch/compute", n=n, b=b, k=k):
-            if self.mesh is not None:
-                from cfk_tpu.parallel.spmd import serve_topk_sharded
+            with span("serve/batch/compute/dispatch"):
+                if self.mesh is not None:
+                    from cfk_tpu.parallel.spmd import serve_topk_sharded
 
-                vals, ids = serve_topk_sharded(
-                    self.mesh, jnp.asarray(u), table, scale, seen_tiles,
-                    k_top=k, num_movies=self.num_movies, tile_m=self.tile_m,
-                )
-            else:
-                vals, ids = _topk_jit_fn()(
-                    jnp.asarray(u), table, scale, seen_tiles,
-                    k_top=k, num_movies=self.num_movies, tile_m=self.tile_m,
-                )
-            vals, ids = np.asarray(vals)[:n], np.asarray(ids)[:n]
+                    vals, ids = serve_topk_sharded(
+                        self.mesh, u, table, scale, seen_tiles, k_top=k,
+                        num_movies=self.num_movies, tile_m=self.tile_m,
+                    )
+                else:
+                    vals, ids = _topk_jit_fn()(
+                        u, table, scale, seen_tiles, k_top=k,
+                        num_movies=self.num_movies, tile_m=self.tile_m,
+                    )
+            with span("serve/batch/compute/fetch") as sp:
+                vals, ids = np.asarray(vals), np.asarray(ids)
+                sp.set(bytes=vals.nbytes + ids.nbytes)
+            vals, ids = vals[:n], ids[:n]
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids
 
@@ -510,10 +527,14 @@ class ServeEngine:
             seen_tiles = None
             if seen_pad is not None:
                 movies, indptr_pad = seen_pad
-                seen_tiles = jnp.asarray(shortlist_seen_tiles(
-                    index, shortlist, movies, indptr_pad, b,
-                    tile_m=self.tile_m,
-                ))
+                with span("serve/batch/seen_tiles") as sp:
+                    seen_tiles = shortlist_seen_tiles(
+                        index, shortlist, movies, indptr_pad, b,
+                        tile_m=self.tile_m,
+                    )
+                    _set_rectangle(sp, seen_tiles)
+                with span("serve/batch/upload", bytes=seen_tiles.nbytes):
+                    seen_tiles = jnp.asarray(seen_tiles)
         with span("serve/rescore", n=n, b=b, k=k, rows=shortlist.rows,
                   rows_padded=shortlist.rows_padded):
             vals, ids = rescore_jit_fn()(
@@ -658,6 +679,13 @@ class ServeEngine:
                 "new_traces": trace_count() - before,
                 "prewarm_s": round(_time.time() - t0, 4),
             }
+
+
+def _set_rectangle(sp, tiles: np.ndarray) -> None:
+    """The seen-tile rectangle's size on its ``serve/batch/seen_tiles``
+    span: [tiles, b, width] int32 cells."""
+    nt, b, width = tiles.shape
+    sp.set(tiles=nt, b=b, width=width, bytes=tiles.nbytes)
 
 
 # Trace counter (ISSUE 13): bumped once per TRACE of the serve program

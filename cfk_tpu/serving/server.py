@@ -24,7 +24,7 @@ import time
 import numpy as np
 
 from cfk_tpu.serving.topk_kernel import _pow2_ceil
-from cfk_tpu.telemetry import record_event, span
+from cfk_tpu.telemetry import get_tracer, record_event, span
 from cfk_tpu.transport.serdes import (
     ScoreRequest,
     ScoreResponse,
@@ -172,6 +172,14 @@ class RecommendServer:
             self._cursors[p] += got
         return out
 
+    def _pending(self) -> int:
+        """Requests produced to this server's partitions and not yet
+        polled: the backlog that waits for the next batch."""
+        return sum(
+            self.transport.end_offset(self.requests_topic, p) - cursor
+            for p, cursor in self._cursors.items()
+        )
+
     def _stamp(self) -> tuple[int, int]:
         """(epoch, staleness) for this batch's response stamps."""
         epoch = int(getattr(self.engine, "epoch", 0))
@@ -188,7 +196,19 @@ class RecommendServer:
         answered (0 = nothing pending).  Requests shed by admission
         control are answered too — with an explicit RETRIABLE rejection,
         never a silent drop — and count toward the return value."""
-        reqs = self._poll_requests()
+        # The spans of one batch share its ordinal: this poll feeds batch
+        # ``batches + 1``.  An empty poll writes no event — an idle server
+        # polls every millisecond.
+        batch = self.batches + 1
+        with span("serve/poll", batch=batch) as sp:
+            malformed = self.malformed_requests
+            reqs = self._poll_requests()
+            if not reqs:
+                sp.drop()
+            elif get_tracer() is not None:
+                sp.set(requests=len(reqs),
+                       malformed=self.malformed_requests - malformed,
+                       pending_after=self._pending())
         # a fuzzed frame can decode into a request whose reply_partition
         # doesn't exist — unanswerable (there is no partition to refuse
         # it on), so it is counted and dropped BEFORE admission rather
@@ -210,8 +230,8 @@ class RecommendServer:
             reqs, shed = self.admission.admit(reqs)
         t_batch = time.perf_counter()
         epoch, staleness = self._stamp()
-        with self.metrics.phase("serve_batch"), \
-                span("serve/batch", requests=len(reqs), shed=len(shed)):
+        with span("serve/batch", requests=len(reqs), shed=len(shed),
+                  batch=batch):
             # Refuse out-of-range rows per REQUEST (an error response),
             # never per batch — one bad query must not poison its
             # co-batched neighbors.
@@ -222,7 +242,6 @@ class RecommendServer:
                     ok = (0 <= r.user < self.engine.num_users
                           and 1 <= r.k <= self.engine.num_movies)
                     (valid if ok else errors).append(r)
-            responses: list[tuple[int, ScoreResponse]] = []
             if valid:
                 k_pad = _pow2_ceil(
                     max(r.k for r in valid),
@@ -230,9 +249,12 @@ class RecommendServer:
                 )
                 k_pad = min(k_pad, self.engine.num_movies)
                 rows = np.asarray([r.user for r in valid], np.int64)
-                # engine.topk opens the serve/batch/assemble + compute
-                # spans — the kernel side of this batch's timeline
+                # engine.topk opens the assemble, seen_tiles, upload and
+                # compute spans — the kernel side of this batch's timeline
                 scores, ids = self.engine.topk(rows, k_pad)
+            # respond: response objects, encode, produce, flush
+            with span("serve/batch/respond") as sp:
+                responses: list[tuple[int, ScoreResponse]] = []
                 for i, r in enumerate(valid):
                     responses.append((r.reply_partition, ScoreResponse(
                         req_id=r.req_id,
@@ -240,36 +262,39 @@ class RecommendServer:
                         scores=scores[i, : r.k],
                         epoch=epoch, staleness=staleness,
                     )))
-            for r in errors:
-                responses.append((r.reply_partition, ScoreResponse(
-                    req_id=r.req_id,
-                    movie_rows=np.zeros(0, np.int32),
-                    scores=np.zeros(0, np.float32),
-                    error=(f"user row {r.user} out of range "
-                           f"[0, {self.engine.num_users}) or k {r.k} "
-                           f"outside [1, {self.engine.num_movies}]"),
-                    epoch=epoch, staleness=staleness,
-                )))
-            for r in shed:
-                # Explicit retriable rejection: the client backs off and
-                # re-sends; the request is ANSWERED, not dropped.
-                responses.append((r.reply_partition, ScoreResponse(
-                    req_id=r.req_id,
-                    movie_rows=np.zeros(0, np.int32),
-                    scores=np.zeros(0, np.float32),
-                    error="overloaded: admission queue depth exceeded",
-                    retriable=True, epoch=epoch, staleness=staleness,
-                )))
-            with span("serve/batch/respond", responses=len(responses)):
+                for r in errors:
+                    responses.append((r.reply_partition, ScoreResponse(
+                        req_id=r.req_id,
+                        movie_rows=np.zeros(0, np.int32),
+                        scores=np.zeros(0, np.float32),
+                        error=(f"user row {r.user} out of range "
+                               f"[0, {self.engine.num_users}) or k {r.k} "
+                               f"outside [1, {self.engine.num_movies}]"),
+                        epoch=epoch, staleness=staleness,
+                    )))
+                for r in shed:
+                    # Explicit retriable rejection: the client backs off
+                    # and re-sends; the request is ANSWERED, not dropped.
+                    responses.append((r.reply_partition, ScoreResponse(
+                        req_id=r.req_id,
+                        movie_rows=np.zeros(0, np.int32),
+                        scores=np.zeros(0, np.float32),
+                        error="overloaded: admission queue depth exceeded",
+                        retriable=True, epoch=epoch, staleness=staleness,
+                    )))
+                produced = 0
                 for part, resp in responses:
+                    value = encode_score_response(resp)
+                    produced += len(value)
                     self.transport.produce(
                         self.responses_topic,
                         key=int(resp.req_id % (1 << 31)),
-                        value=encode_score_response(resp), partition=part,
+                        value=value, partition=part,
                     )
                 flush = getattr(self.transport, "flush", None)
                 if flush is not None:
                     flush()
+                sp.set(responses=len(responses), bytes=produced)
         # Responses durable → commit the read cursors (failover handoff).
         self.committed_cursors.update(self._cursors)
         self.requests_served += len(reqs)

@@ -41,11 +41,8 @@ from cfk_tpu.telemetry.recorder import (
 )
 from cfk_tpu.telemetry.trace import (
     Tracer,
-    begin_span,
     configure,
-    end_span,
     get_tracer,
-    instant,
     shutdown,
     span,
     stage_overlap_from_events,
@@ -60,14 +57,11 @@ __all__ = [
     "MetricsHTTPServer",
     "MetricsRegistry",
     "Tracer",
-    "begin_span",
     "configure",
     "dump_flight",
-    "end_span",
     "get_recorder",
     "get_tracer",
     "install_crash_hooks",
-    "instant",
     "prometheus_text",
     "record_event",
     "sanitize_metric_name",

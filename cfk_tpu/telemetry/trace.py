@@ -21,9 +21,15 @@ Design constraints (the sentinel discipline, ISSUE 3's ≤2% budget):
 - **Thread-aware.**  Every event records its OS thread; staging-pool
   worker spans carry the (shard, window) ids their task staged, so pool
   overlap is *visible* in the trace instead of inferred from counters.
-- **Async edges.**  ``begin()``/``end()`` return/consume an explicit
-  token for spans whose begin and end live on different code paths (or
-  different threads); they bypass the per-thread nesting stack.
+- **Attributes at the boundary.**  ``with span(...) as sp`` hands back
+  the open span: ``sp.set(bytes=...)`` adds what is only known once the
+  work is done, ``sp.drop()`` writes no event (an empty poll).  Both are
+  no-ops on the null span.
+- **One clock pair.**  A ``Tracer`` samples ``(perf_counter_ns,
+  time_ns)`` once, at construction.  ``events()`` stays on
+  ``perf_counter`` microseconds; the Chrome export is on the unix epoch
+  through that pair (``Tracer.to_unix_ns``), the clock a ``jax.profiler``
+  trace's ``profile_start_time`` is on, so the two files line up.
 
 Span naming: callers pass the FULL span-name path (``train/iter/half_step/
 window_stage``) — explicit at the call site, zero path-joining overhead
@@ -49,26 +55,19 @@ class _NullSpan:
     __slots__ = ()
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
-
-
-class SpanToken:
-    """An open span's identity for the explicit begin/end (async) API."""
-
-    __slots__ = ("name", "attrs", "t0_us", "tid", "closed")
-
-    def __init__(self, name: str, attrs: dict, t0_us: int, tid: int) -> None:
-        self.name = name
-        self.attrs = attrs
-        self.t0_us = t0_us
-        self.tid = tid
-        self.closed = False
 
 
 class _SpanCM:
@@ -85,8 +84,18 @@ class _SpanCM:
         self._t0 = time.perf_counter_ns()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attributes known only at the span's far boundary."""
+        self._attrs.update(attrs)
+
+    def drop(self) -> None:
+        """Write no event for this span."""
+        self._tracer = None
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter_ns()
+        if self._tracer is None:
+            return False
         if exc_type is not None:
             # annotate, never swallow — a span that died mid-fault is
             # exactly the event a flight-recorder reader wants labelled
@@ -120,73 +129,34 @@ class Tracer:
         self._lock = threading.Lock()
         self._thread_names: dict[int, str] = {}
         self.dropped = 0
-        self.begin_count = 0
-        self.end_count = 0
+        # the one reading of both clocks: perf_counter (what every event is
+        # stamped with) against the unix epoch (what the export is on)
+        self.clock_pair_ns = (time.perf_counter_ns(), time.time_ns())
+
+    def to_unix_ns(self, ts_us: int) -> int:
+        """An event's ``ts`` (perf_counter microseconds) on the unix epoch."""
+        perf_ns, unix_ns = self.clock_pair_ns
+        return unix_ns + (int(ts_us) * 1000 - perf_ns)
 
     # -- recording -----------------------------------------------------------
 
-    def _append(self, event: dict) -> None:
-        """One locked append with the cap + thread-name bookkeeping —
-        shared by complete spans and instant markers so the drop
-        accounting can never diverge between them."""
+    def _emit(self, name: str, ts_us: int, dur_us: int, tid: int,
+              attrs: dict) -> None:
+        """One locked append with the cap + thread-name bookkeeping."""
+        event = {
+            "name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
+            "pid": os.getpid(), "tid": tid, "args": attrs,
+        }
         with self._lock:
             if len(self._events) >= MAX_EVENTS:
                 self.dropped += 1
                 return
-            tid = event["tid"]
             if tid not in self._thread_names:
                 self._thread_names[tid] = threading.current_thread().name
             self._events.append(event)
 
-    def _emit(self, name: str, ts_us: int, dur_us: int, tid: int,
-              attrs: dict) -> None:
-        self._append({
-            "name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
-            "pid": os.getpid(), "tid": tid, "args": attrs,
-        })
-
     def span(self, name: str, **attrs) -> _SpanCM:
         return _SpanCM(self, name, attrs)
-
-    def begin(self, name: str, **attrs) -> SpanToken:
-        """Open an async-edge span (end may happen on another thread)."""
-        tid = threading.get_ident()
-        with self._lock:
-            self.begin_count += 1
-            # Register the BEGIN thread's name now: end() may run on a
-            # different thread, and the event lands on this tid's row —
-            # deferring the mapping would mislabel it with the closer's
-            # name if no other span emits from this thread first.
-            if tid not in self._thread_names:
-                self._thread_names[tid] = threading.current_thread().name
-        return SpanToken(name, attrs, time.perf_counter_ns() // 1000, tid)
-
-    def end(self, token: SpanToken, **extra) -> None:
-        """Close an async-edge span; extra attrs merge over begin's.
-        Idempotent, including against concurrent double-ends (the
-        check-and-set happens under the tracer lock)."""
-        with self._lock:
-            if token.closed:
-                return
-            token.closed = True
-            self.end_count += 1
-        t1 = time.perf_counter_ns() // 1000
-        attrs = dict(token.attrs, **extra) if extra else token.attrs
-        # attributed to the BEGINNING thread's row (the async span's
-        # home); the closing thread is recorded for forensics
-        if threading.get_ident() != token.tid:
-            attrs = dict(attrs, end_thread=threading.current_thread().name)
-        self._emit(token.name, token.t0_us, max(t1 - token.t0_us, 0),
-                   token.tid, attrs)
-
-    def instant(self, name: str, **attrs) -> None:
-        """A zero-duration marker event."""
-        self._append({
-            "name": name, "ph": "i", "s": "t",
-            "ts": time.perf_counter_ns() // 1000,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": attrs,
-        })
 
     # -- export --------------------------------------------------------------
 
@@ -200,8 +170,13 @@ class Tracer:
             self.dropped = 0
 
     def chrome_trace(self) -> dict:
-        """The Chrome-trace JSON object (Perfetto / chrome://tracing)."""
-        events = self.events()
+        """The Chrome-trace JSON object (Perfetto / chrome://tracing), its
+        ``ts`` in microseconds of the unix epoch; ``metadata`` carries the
+        clock pair the conversion went through."""
+        # a whole number of microseconds: every ts moves by the same
+        # amount, so spans that nest in events() nest in the export
+        shift_us = self.to_unix_ns(0) // 1000
+        events = [dict(e, ts=e["ts"] + shift_us) for e in self.events()]
         with self._lock:
             names = dict(self._thread_names)
         meta = [
@@ -211,7 +186,12 @@ class Tracer:
             }
             for tid, tname in sorted(names.items())
         ]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+        perf_ns, unix_ns = self.clock_pair_ns
+        return {
+            "traceEvents": meta + events, "displayTimeUnit": "ms",
+            "metadata": {"ts_epoch": "unix", "clock_perf_counter_ns": perf_ns,
+                         "clock_unix_ns": unix_ns},
+        }
 
     def write(self, path: str | None = None) -> str | None:
         """Atomically write the Chrome trace; returns the path (None when
@@ -263,25 +243,6 @@ def span(name: str, **attrs):
     if t is None:
         return _NULL_SPAN
     return t.span(name, **attrs)
-
-
-def begin_span(name: str, **attrs) -> SpanToken | None:
-    t = _TRACER
-    if t is None:
-        return None
-    return t.begin(name, **attrs)
-
-
-def end_span(token: SpanToken | None, **extra) -> None:
-    t = _TRACER
-    if t is not None and token is not None:
-        t.end(token, **extra)
-
-
-def instant(name: str, **attrs) -> None:
-    t = _TRACER
-    if t is not None:
-        t.instant(name, **attrs)
 
 
 # -- analysis helpers --------------------------------------------------------
