@@ -1505,7 +1505,7 @@ def serve_topk_sharded(
     u,  # [B, k] user-factor batch (replicated)
     table,  # [M_pad, k] item table, M_pad a multiple of shards·tile_m
     scale,  # [M_pad] f32 int8 per-row scales, or None
-    seen_tiles,  # [NT, B, W] int32 (serving.topk_kernel.build_seen_tiles)
+    seen_tiles,  # [NT, B, W] int32 (serving.topk_kernel.scatter_seen_cells)
     *,
     k_top: int,
     num_movies: int,

@@ -69,6 +69,8 @@ from cfk_tpu.serving.server import (
 )
 from cfk_tpu.serving.topk_kernel import (
     build_seen_tiles,
+    group_seen_cells,
+    scatter_seen_cells,
     topk_scores_pallas,
 )
 
@@ -102,5 +104,7 @@ __all__ = [
     "SnapshotStore",
     "table_crc",
     "build_seen_tiles",
+    "group_seen_cells",
+    "scatter_seen_cells",
     "topk_scores_pallas",
 ]

@@ -17,9 +17,16 @@ user rows" and "[B, K] ids+scores":
   commit's (user, movie) cells append to the overlay so a just-rated
   movie disappears from that user's recommendations at the next request,
 - pow2 request-batch bucketing: batches pad to a power of two (and the
-  seen rectangle width is pow2 from ``build_seen_tiles``), so live
+  seen rectangle width is pow2 from ``group_seen_cells``), so live
   traffic converges onto a handful of compiled programs instead of
   re-tracing per batch — the same trick PR 6 used for fold-in shapes,
+- the exclusion rectangle built on the device: the host groups the
+  batch's seen cells at tile boundaries (a few thousand entries) and
+  hands over that list in pieces whose size depends on the padded batch
+  size alone; a scatter program fills and writes the [NT, B, W] rectangle
+  where the scorer reads it.  A batch with more cells than one piece runs
+  the same scatter program again on top, so ``prewarm``'s batch-size
+  ladder closes the program set whatever the data,
 - two-stage clustered retrieval (ISSUE 16, ``serve_mode="two_stage"``):
   a k-means index over the item factors (``serving.cluster``), rebuilt
   ATOMICALLY on every table swap, probed by a centroid stage
@@ -44,7 +51,10 @@ import numpy as np
 
 from cfk_tpu.serving.topk_kernel import (
     _pow2_ceil,
-    build_seen_tiles,
+    chunk_seen_cells,
+    group_seen_cells,
+    scatter_seen_cells,
+    seen_cell_capacity,
     topk_scores_pallas,
 )
 from cfk_tpu.telemetry import dump_flight, record_event, span
@@ -398,6 +408,12 @@ class ServeEngine:
         ``force_exact`` skips the two-stage candidate path for this one
         batch (same table, same masks, same jitted exact program) — the
         dense oracle the recall@K measurements score against."""
+        return self._topk(user_rows, k, exclude_seen, force_exact)
+
+    def _topk(self, user_rows, k, exclude_seen, force_exact,
+              min_seen_chunks=1):
+        """``topk``; ``prewarm`` asks for the seen cells in two pieces at
+        least, which runs every program a batch over the capacity runs."""
         import jax.numpy as jnp
 
         user_rows = np.asarray(user_rows, dtype=np.int64)
@@ -435,35 +451,37 @@ class ServeEngine:
                 sp.set(seen_cells=len(movies))
         if (self.serve_mode == "two_stage" and not force_exact
                 and not self._two_stage_disabled):
-            out = self._topk_two_stage(cluster, u, n, b, k, seen_pad)
+            out = self._topk_two_stage(cluster, u, n, b, k, seen_pad,
+                                       min_seen_chunks)
             if out is not None:
                 return out
             # a detected fault fell through: the exact path below IS the
             # un-disableable fallback — same table, same jitted program
             # as serve_mode="exact", so the degraded answer is bit-exact
-        seen_tiles = None
+        seen = shape = None
         if seen_pad is not None:
             movies, indptr_pad = seen_pad
             with span("serve/batch/seen_tiles") as sp:
-                seen_tiles = build_seen_tiles(
+                cells, shape = group_seen_cells(
                     movies, indptr_pad, np.arange(b),
                     num_movies=self.num_movies,
                     tile_m=self.tile_m,
                     num_tiles=self.table_rows // self.tile_m,
                 )
-                _set_rectangle(sp, seen_tiles)
+                seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
         # the calls that hand the batch to the runtime; they may return
         # before the bytes have landed, and the fetch below then waits
         # for the transfer as well as for the scorer
         with span("serve/batch/upload") as sp:
             nbytes = u.nbytes
-            if seen_tiles is not None:
-                nbytes += seen_tiles.nbytes
-                seen_tiles = jnp.asarray(seen_tiles)
+            if seen is not None:
+                nbytes += sum(c.nbytes for c in seen)
+                seen = [jnp.asarray(c) for c in seen]
             u = jnp.asarray(u)
             sp.set(bytes=nbytes)
         with span("serve/batch/compute", n=n, b=b, k=k):
             with span("serve/batch/compute/dispatch"):
+                seen_tiles = self._seen_tiles(seen, shape)
                 if self.mesh is not None:
                     from cfk_tpu.parallel.spmd import serve_topk_sharded
 
@@ -483,7 +501,21 @@ class ServeEngine:
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids
 
-    def _topk_two_stage(self, cluster, u, n, b, k, seen_pad):
+    def _seen_tiles(self, chunks, shape):
+        """The [NT, B, W] exclusion rectangle on the device, from the
+        uploaded pieces of the batch's cell list (``_seen_chunks``; None
+        = no exclusion): one run of the scatter program per piece, the
+        first onto a fresh all-padding rectangle.  Every caller — exact,
+        item-sharded, two-stage rescore — gets its rectangle here."""
+        seen_tiles = None
+        for cells in chunks or ():
+            seen_tiles = _seen_tiles_jit_fn()(
+                cells, seen_tiles, shape=shape, tile_m=self.tile_m,
+            )
+        return seen_tiles
+
+    def _topk_two_stage(self, cluster, u, n, b, k, seen_pad,
+                        min_seen_chunks=1):
         """One two-stage batch: centroid probe → batch-union shortlist →
         exact rescore.  Returns ``(vals, ids)`` sliced to ``n``, or None
         after recording a fault — the caller then takes the exact scan."""
@@ -494,7 +526,7 @@ class ServeEngine:
             coarse_jit_fn,
             map_shortlist_ids,
             rescore_jit_fn,
-            shortlist_seen_tiles,
+            shortlist_seen_cells,
         )
 
         if cluster is None:
@@ -524,19 +556,21 @@ class ServeEngine:
                 index, np.asarray(cids)[:n].ravel(),
                 tile_m=self.tile_m, min_rows=k,
             )
-            seen_tiles = None
+            seen = shape = None
             if seen_pad is not None:
                 movies, indptr_pad = seen_pad
                 with span("serve/batch/seen_tiles") as sp:
-                    seen_tiles = shortlist_seen_tiles(
+                    cells, shape = shortlist_seen_cells(
                         index, shortlist, movies, indptr_pad, b,
                         tile_m=self.tile_m,
                     )
-                    _set_rectangle(sp, seen_tiles)
-                with span("serve/batch/upload", bytes=seen_tiles.nbytes):
-                    seen_tiles = jnp.asarray(seen_tiles)
+                    seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
+                with span("serve/batch/upload",
+                          bytes=sum(c.nbytes for c in seen)):
+                    seen = [jnp.asarray(c) for c in seen]
         with span("serve/rescore", n=n, b=b, k=k, rows=shortlist.rows,
                   rows_padded=shortlist.rows_padded):
+            seen_tiles = self._seen_tiles(seen, shape)
             vals, ids = rescore_jit_fn()(
                 jnp.asarray(u), jnp.asarray(shortlist.indices), ctable,
                 cscale, seen_tiles, np.int32(shortlist.offset),
@@ -660,7 +694,7 @@ class ServeEngine:
                 # sample still traces the intended batch size
                 if take.size < b:
                     take = np.resize(take, b)
-                self.topk(take, k, exclude_seen=exclude_seen)
+                self._topk(take, k, exclude_seen, False, min_seen_chunks=2)
                 programs += 1
                 if self.serve_mode == "two_stage" and rows.size > b:
                     # a second, disjoint sample per rung: the shortlist
@@ -671,7 +705,8 @@ class ServeEngine:
                     alt = rows[b:2 * b]
                     if alt.size < b:
                         alt = np.resize(alt, b)
-                    self.topk(alt, k, exclude_seen=exclude_seen)
+                    self._topk(alt, k, exclude_seen, False,
+                               min_seen_chunks=2)
                 b *= 2
             self.prewarmed = True  # the /readyz gate flips here
             return {
@@ -681,11 +716,19 @@ class ServeEngine:
             }
 
 
-def _set_rectangle(sp, tiles: np.ndarray) -> None:
-    """The seen-tile rectangle's size on its ``serve/batch/seen_tiles``
-    span: [tiles, b, width] int32 cells."""
-    nt, b, width = tiles.shape
-    sp.set(tiles=nt, b=b, width=width, bytes=tiles.nbytes)
+def _seen_chunks(sp, cells: np.ndarray, shape, min_chunks: int):
+    """The pieces of one batch's cell list, for ``ServeEngine._seen_tiles``
+    once uploaded.  Its ``serve/batch/seen_tiles`` span says what the
+    device will build ([tiles, b, width] int32), what the host built for
+    it (``bytes``), the real ``cells`` among them and how many scatter
+    programs (``chunks`` of ``capacity``) carry them."""
+    nt, b, width = shape
+    capacity = seen_cell_capacity(b)
+    chunks = chunk_seen_cells(cells, capacity, nt, min_chunks)
+    sp.set(tiles=nt, b=b, width=width, cells=cells.shape[1],
+           capacity=capacity, chunks=len(chunks),
+           bytes=sum(c.nbytes for c in chunks))
+    return chunks
 
 
 # Trace counter (ISSUE 13): bumped once per TRACE of the serve program
@@ -698,8 +741,9 @@ _TRACES = [0]
 
 def trace_count() -> int:
     """Traces of the single-device serve programs this process — the
-    exact scan plus (ISSUE 16) the two-stage coarse/rescore stages, so
-    the prewarm contract covers whichever mode the plan picked."""
+    exact scan and the seen-rectangle scatter, plus (ISSUE 16) the
+    two-stage coarse/rescore stages, so the prewarm contract covers
+    whichever mode the plan picked."""
     from cfk_tpu.serving import twostage
 
     return _TRACES[0] + twostage.trace_count()
@@ -710,6 +754,24 @@ def _topk_call(u, table, scale, seen_tiles, *, k_top, num_movies, tile_m):
     return topk_scores_pallas(
         u, table, scale, seen_tiles, k_top=k_top, num_movies=num_movies,
         tile_m=tile_m,
+    )
+
+
+def _seen_tiles_call(cells, seen_tiles, *, shape, tile_m):
+    _TRACES[0] += 1
+    return scatter_seen_cells(cells, seen_tiles, shape=shape, tile_m=tile_m)
+
+
+@functools.lru_cache(maxsize=1)
+def _seen_tiles_jit_fn():
+    """Jitted seen-rectangle scatter: per (B, W) bucket one program that
+    starts a rectangle and one that adds to the rectangle it is given
+    (donated, so no second 299 MB lives beside it)."""
+    import jax
+
+    return jax.jit(
+        _seen_tiles_call, static_argnames=("shape", "tile_m"),
+        donate_argnames=("seen_tiles",),
     )
 
 

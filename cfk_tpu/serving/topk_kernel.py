@@ -20,13 +20,16 @@ every reduction runs along sublanes, the forms Mosaic lowers:
   dequant placement as the Gram kernels, ``ops.quant``),
 - padding mask: global row ≥ ``num_movies`` → −inf (the table is padded
   to a tile multiple),
-- exclusion mask: already-rated items are −inf'd in-register from the
-  batch's per-user CSR slice, re-bucketed per tile on the host
-  (``build_seen_tiles``: ``seen[b]``'s movie rows, already sorted, split
-  at tile boundaries into a [NT, B, W] rectangle of in-tile rows — W is
-  the pow2-bucketed max per-(user, tile) seen count, so the kernel's mask
-  pass is W compares of one [1, B] slot row against the tile's row iota,
-  not a [B, S×T] blow-up),
+- exclusion mask: already-rated items are −inf'd in-register from a
+  [NT, B, W] rectangle of in-tile rows — ``seen[b]``'s movie rows, already
+  sorted, split at tile boundaries; W is the pow2-bucketed max per-(user,
+  tile) seen count, so the kernel's mask pass is W compares of one [1, B]
+  slot row against the tile's row iota, not a [B, S×T] blow-up.  The host
+  only groups the batch's cells (``group_seen_cells``: a few thousand
+  (tile, slot, position, in-tile row) columns); the rectangle itself —
+  299 MB at 18,262 tiles × 256 slots × 16 — is filled and scattered on the
+  device (``scatter_seen_cells``), and ``build_seen_tiles`` is the same
+  rectangle in numpy, the tests' oracle,
 - K-selection merge: K rounds of "largest remaining value, earliest
   position" over [carry ‖ tile] — equal scores resolve to the carry, then
   to the lower slot/row, making tie order deterministic (``lax.top_k``
@@ -172,20 +175,23 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     return new_v, new_i
 
 
-def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
+def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
                      tile_m, num_tiles: int | None = None,
                      min_width: int = _SEEN_CHUNK):
-    """[NT, B, W] per-tile exclusion rectangle from a per-user CSR.
+    """The batch's exclusion cells grouped at tile boundaries: ``(cells
+    [4, n] int32, (NT, B, W))`` — the host's whole share of the rectangle.
 
     ``seen_movies``/``seen_indptr`` is the CSR of already-rated movie rows
     by user row (movie rows sorted ascending within each user — the
     ``StreamState.neighbors`` / ``eval.ranking`` convention);
-    ``batch_rows`` [B] selects the batch.  Entry [t, b, w] is the w-th
-    in-tile column of batch user b's seen movies inside movie tile t,
-    padded with ``tile_m`` (which no in-tile column equals).  W is the
-    pow2-bucketed max per-(user, tile) count — pow2 so the rectangle
-    shape, which is jit-static in the kernel, converges onto a handful of
-    compiled programs under live traffic (the PR 6 fold-in trick).
+    ``batch_rows`` [B] selects the batch.  Column j of ``cells`` says that
+    rectangle entry ``[cells[0, j], cells[1, j], cells[2, j]]`` (movie
+    tile, batch slot, position within that slot's seen movies inside the
+    tile) holds in-tile column ``cells[3, j]``; movie rows at or past
+    ``num_movies`` are dropped.  W is the pow2-bucketed max per-(slot,
+    tile) count — pow2 so the rectangle shape, which is jit-static,
+    converges onto a handful of compiled programs under live traffic (the
+    PR 6 fold-in trick).
     """
     nt = -(-num_movies // tile_m) if num_tiles is None else num_tiles
     b = len(batch_rows)
@@ -202,7 +208,6 @@ def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
     keep = mv < num_movies
     rows, mv = rows[keep], mv[keep]
     tile_of = mv // tile_m
-    local = (mv % tile_m).astype(np.int32)
     # mv is sorted within each row, so (row, tile) groups are contiguous;
     # position within group = running index − group start.
     key = rows * nt + tile_of
@@ -214,9 +219,63 @@ def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
     else:
         pos = np.zeros(0, np.int64)
         width = 0
-    w = _pow2_ceil(max(width, 1), min_width)
-    out = np.full((nt, b, w), tile_m, dtype=np.int32)
-    out[tile_of, rows, pos] = local
+    cells = np.stack([tile_of, rows, pos, mv % tile_m]).astype(np.int32)
+    return cells, (nt, b, _pow2_ceil(max(width, 1), min_width))
+
+
+def seen_cell_capacity(batch: int) -> int:
+    """Cells one scatter program takes: a function of the padded batch
+    size alone, so the batch-size ladder ``ServeEngine.prewarm`` walks
+    covers every cell-list shape the data can produce."""
+    return batch * _SEEN_CHUNK
+
+
+def chunk_seen_cells(cells, capacity: int, num_tiles: int,
+                     min_chunks: int = 1):
+    """``cells`` [4, n] cut into [4, capacity] pieces, at least
+    ``min_chunks`` of them.  The fill columns name tile ``num_tiles`` —
+    out of range, so the scatter drops them."""
+    n = cells.shape[1]
+    chunks = []
+    for i in range(max(-(-n // capacity), min_chunks)):
+        chunk = np.zeros((4, capacity), np.int32)
+        chunk[0] = num_tiles
+        piece = cells[:, i * capacity:(i + 1) * capacity]
+        chunk[:, :piece.shape[1]] = piece
+        chunks.append(chunk)
+    return chunks
+
+
+def scatter_seen_cells(cells, seen_tiles=None, *, shape, tile_m):
+    """The [NT, B, W] exclusion rectangle, built where the kernel reads it.
+
+    ``cells`` is one [4, capacity] piece of ``chunk_seen_cells``.  Entry
+    [t, b, w] is the w-th in-tile column of batch slot b's seen movies
+    inside movie tile t, padded with ``tile_m`` (which no in-tile column
+    equals).  ``seen_tiles`` None starts from the all-padding rectangle;
+    a rectangle that earlier pieces were scattered into takes this one on
+    top.  No ``indices_are_sorted`` / ``unique_indices``: the chip's
+    compiler folds the three indices into one and every dropped column
+    into the same out-of-range value, which is neither, and with the hints
+    the v5e wrote a wrong rectangle (PERF.md, PR 25)."""
+    if seen_tiles is None:
+        seen_tiles = jnp.full(shape, tile_m, jnp.int32)
+    return seen_tiles.at[cells[0], cells[1], cells[2]].set(
+        cells[3], mode="drop")
+
+
+def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
+                     tile_m, num_tiles: int | None = None,
+                     min_width: int = _SEEN_CHUNK):
+    """``scatter_seen_cells``'s rectangle in numpy, whole, on the host: the
+    oracle the tests hold the device-built one to (at the serve cell's size
+    it is 299 MB per batch, which is why nothing serves from it)."""
+    cells, shape = group_seen_cells(
+        seen_movies, seen_indptr, batch_rows, num_movies=num_movies,
+        tile_m=tile_m, num_tiles=num_tiles, min_width=min_width,
+    )
+    out = np.full(shape, tile_m, dtype=np.int32)
+    out[cells[0], cells[1], cells[2]] = cells[3]
     return out
 
 
@@ -258,7 +317,7 @@ def topk_scores_pallas(
     u: jax.Array,  # [B, k] user-factor batch (f32 or bf16)
     table: jax.Array,  # [M_pad, k] item table (f32 / bf16 / int8 codes)
     scale: jax.Array | None,  # [M_pad] f32 per-row int8 scales, else None
-    seen_tiles: jax.Array | None,  # [NT, B, W] int32 (build_seen_tiles)
+    seen_tiles: jax.Array | None,  # [NT, B, W] int32 (scatter_seen_cells)
     *,
     k_top: int,
     num_movies: int,
@@ -294,7 +353,7 @@ def topk_scores_pallas(
     if seen_tiles is not None and seen_tiles.shape[2] % _SEEN_CHUNK != 0:
         raise ValueError(
             f"seen_tiles width {seen_tiles.shape[2]} must be a multiple of "
-            f"{_SEEN_CHUNK} (build_seen_tiles pads it)"
+            f"{_SEEN_CHUNK} (group_seen_cells pads it)"
         )
     if (scale is None) != (table.dtype != jnp.int8):
         raise ValueError(

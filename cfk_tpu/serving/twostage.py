@@ -42,7 +42,7 @@ import numpy as np
 from cfk_tpu.serving.cluster import ClusterIndex
 from cfk_tpu.serving.topk_kernel import (
     _pow2_ceil,
-    build_seen_tiles,
+    group_seen_cells,
     serve_compute_dtype,
     topk_scores_pallas,
 )
@@ -203,7 +203,7 @@ def shortlist_seen(index: ClusterIndex, shortlist: Shortlist,
     """Remap a batch seen-CSR (GLOBAL movie rows, sorted per user) to
     SHORTLIST-LOCAL positions, dropping entries outside the shortlist (an
     unselected seen item is not a candidate, so it needs no mask).  Local
-    positions are re-sorted per user — ``build_seen_tiles``'s contract."""
+    positions are re-sorted per user — ``group_seen_cells``'s contract."""
     movies = np.asarray(seen_movies, np.int64)
     indptr = np.asarray(seen_indptr, np.int64)
     if movies.size:
@@ -230,15 +230,16 @@ def shortlist_seen(index: ClusterIndex, shortlist: Shortlist,
     return out_movies, out_indptr
 
 
-def shortlist_seen_tiles(index: ClusterIndex, shortlist: Shortlist,
+def shortlist_seen_cells(index: ClusterIndex, shortlist: Shortlist,
                          seen_movies, seen_indptr, batch: int, *,
                          tile_m: int):
-    """[NT_local, B, W] exclusion rectangle in shortlist coordinates —
-    ``build_seen_tiles`` over the remapped CSR (W pow2-bucketed as ever)."""
+    """The [NT_local, B, W] exclusion rectangle's cells and shape in
+    shortlist coordinates — ``group_seen_cells`` over the remapped CSR (W
+    pow2-bucketed as ever)."""
     movies_l, indptr_l = shortlist_seen(
         index, shortlist, seen_movies, seen_indptr
     )
-    return build_seen_tiles(
+    return group_seen_cells(
         movies_l, indptr_l, np.arange(batch),
         num_movies=max(shortlist.rows, 1), tile_m=tile_m,
         num_tiles=shortlist.rows_padded // tile_m,

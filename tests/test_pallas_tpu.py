@@ -388,3 +388,81 @@ def test_topk_compiled_matches_twin(table_dtype):
         assert (i_c[row] >= 0).all() and (i_c[row] < m).all()
     # ids agree except where two candidates are within the tolerance
     assert (i_c == i_t).mean() > 0.95
+
+
+def _seen_problem(rng, users, movies, longest):
+    seen = [np.sort(rng.choice(movies, size=int(rng.integers(0, longest)),
+                               replace=False)).astype(np.int32)
+            for _ in range(users)]
+    indptr = np.zeros(users + 1, np.int64)
+    indptr[1:] = np.cumsum([s.size for s in seen])
+    return np.concatenate(seen), indptr
+
+
+@pytest.mark.parametrize("capacity", [None, 300])
+def test_seen_rectangle_built_on_the_chip_equals_host_oracle(capacity):
+    """The scatter the chip's compiler makes of ``scatter_seen_cells``
+    (dropped fill columns, the sorted and unique hints, a donated
+    rectangle for the second piece on) against numpy's, bit for bit."""
+    from cfk_tpu.serving.engine import _seen_tiles_jit_fn
+    from cfk_tpu.serving.topk_kernel import (
+        build_seen_tiles,
+        chunk_seen_cells,
+        group_seen_cells,
+        seen_cell_capacity,
+    )
+
+    rng = np.random.default_rng(11)
+    m, tile, b = 200_000, 512, 64
+    movies, indptr = _seen_problem(rng, b, m + 5_000, 40)
+    rows = rng.integers(0, b, size=b)  # users repeat within the batch
+    kw = dict(num_movies=m, tile_m=tile)
+    want = build_seen_tiles(movies, indptr, rows, **kw)
+    cells, shape = group_seen_cells(movies, indptr, rows, **kw)
+    chunks = chunk_seen_cells(cells, capacity or seen_cell_capacity(b),
+                              shape[0], 2)
+    assert len(chunks) == (2 if capacity is None else
+                           -(-cells.shape[1] // capacity)) >= 2
+    got = None
+    for chunk in chunks:
+        got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
+                                   tile_m=tile)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("caller", ["exact", "one_device_mesh", "two_stage"])
+def test_serve_callers_same_answers_from_the_chip_built_rectangle(
+        caller, monkeypatch):
+    """Each caller of the device-built rectangle — the one-device scan, the
+    item-sharded scan (a mesh of this one chip), the two-stage rescore —
+    answers as it did over a rectangle built on the host and uploaded
+    whole: ids and scores bit for bit, one piece or several."""
+    from cfk_tpu.parallel.mesh import make_mesh
+    from cfk_tpu.serving import engine as engine_mod
+    from tests.test_serving import host_built_seen_tiles
+
+    rng = np.random.default_rng(5)
+    users, m, rank, tile = 300, 20_000, 128, 512
+    uf = rng.standard_normal((users, rank)).astype(np.float32)
+    mf = rng.standard_normal((m, rank)).astype(np.float32)
+    movies, indptr = _seen_problem(rng, users, m, 60)
+    eng = engine_mod.ServeEngine(
+        uf, mf, num_users=users, num_movies=m, seen_movies=movies,
+        seen_indptr=indptr, tile_m=tile,
+        mesh=make_mesh(1) if caller == "one_device_mesh" else None,
+        serve_mode="two_stage" if caller == "two_stage" else "exact",
+    )
+    rows = rng.integers(0, users, size=50)
+    for capacity in (engine_mod.seen_cell_capacity, lambda b: 512):
+        with monkeypatch.context() as mp:
+            mp.setattr(engine_mod, "seen_cell_capacity", capacity)
+            vals, ids = eng.topk(rows, 10)
+            mp.setattr(engine_mod.ServeEngine, "_seen_tiles",
+                       host_built_seen_tiles)
+            want_vals, want_ids = eng.topk(rows, 10)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(vals, want_vals)
+    for row, got in zip(rows, ids):
+        assert (got >= 0).all() and (got < m).all()
+        mine = movies[indptr[row]: indptr[row + 1]]
+        assert not set(got.tolist()) & set(mine.tolist())

@@ -74,14 +74,14 @@ def _by_name(events):
 
 
 def test_one_step_emits_each_span_once(tracer, monkeypatch):
-    rectangles = []
-    real = engine_mod.build_seen_tiles
+    grouped = []
+    real = engine_mod.group_seen_cells
 
     def recording(*a, **kw):
-        rectangles.append(real(*a, **kw))
-        return rectangles[-1]
+        grouped.append(real(*a, **kw))
+        return grouped[-1]
 
-    monkeypatch.setattr(engine_mod, "build_seen_tiles", recording)
+    monkeypatch.setattr(engine_mod, "group_seen_cells", recording)
     # seven produced, four polled: three wait for the next batch
     server, _ = _served(_engine(), range(7), max_batch=4)
     assert server.step() == 4
@@ -96,11 +96,17 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     assert args["serve/poll"]["malformed"] == 0
     assert args["serve/poll"]["pending_after"] == 3
     assert args["serve/batch/assemble"]["seen_cells"] == 4 * 3
-    (rect,) = rectangles
+    # the rectangle the device builds, and what the host built for it: the
+    # batch's twelve cells in one [4, capacity] int32 piece
+    ((cells, shape),) = grouped
+    assert shape == (3, 4, 16) and cells.shape == (4, 12)
+    capacity = engine_mod.seen_cell_capacity(4)
     assert args["serve/batch/seen_tiles"] == {
-        "tiles": rect.shape[0], "b": rect.shape[1], "width": rect.shape[2],
-        "bytes": rect.nbytes}
-    assert args["serve/batch/upload"]["bytes"] == rect.nbytes + 4 * RANK * 4
+        "tiles": 3, "b": 4, "width": 16, "cells": 12, "capacity": capacity,
+        "chunks": 1, "bytes": 4 * capacity * 4}
+    # handed over: that piece and the [b, rank] float32 user batch
+    assert args["serve/batch/upload"]["bytes"] == (4 * capacity * 4
+                                                   + 4 * RANK * 4)
     # [b, k_pad] float32 scores and int32 ids come back
     assert args["serve/batch/compute/fetch"]["bytes"] == 2 * 4 * 8 * 4
     assert args["serve/batch/respond"]["responses"] == 4
@@ -226,7 +232,7 @@ def test_batch_and_compute_bracket_the_same_calls(tracer, monkeypatch):
     import jax.numpy as jnp
 
     tiles_s, upload_s, dispatch_s = 0.05, 0.03, 0.02
-    real_tiles, real_asarray = engine_mod.build_seen_tiles, jnp.asarray
+    real_tiles, real_asarray = engine_mod.group_seen_cells, jnp.asarray
     real_jit = engine_mod._topk_jit_fn()
 
     def slow_tiles(*a, **kw):
@@ -234,7 +240,7 @@ def test_batch_and_compute_bracket_the_same_calls(tracer, monkeypatch):
         return real_tiles(*a, **kw)
 
     def slow_asarray(x, *a, **kw):
-        if isinstance(x, np.ndarray) and x.ndim == 3:  # the rectangle
+        if isinstance(x, np.ndarray) and x.dtype == np.int32:  # the cells
             time.sleep(upload_s)
         return real_asarray(x, *a, **kw)
 
@@ -245,7 +251,7 @@ def test_batch_and_compute_bracket_the_same_calls(tracer, monkeypatch):
     eng = _engine()
     eng.topk(np.arange(4), 8)  # compile outside the spans under test
     tracer.clear()
-    monkeypatch.setattr(engine_mod, "build_seen_tiles", slow_tiles)
+    monkeypatch.setattr(engine_mod, "group_seen_cells", slow_tiles)
     monkeypatch.setattr(jnp, "asarray", slow_asarray)
     monkeypatch.setattr(engine_mod, "_topk_jit_fn", lambda: slow_scorer)
     server, _ = _served(eng, range(4))

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from cfk_tpu.compat import emulate_topk_scores
 from cfk_tpu.serving.topk_kernel import (
     build_seen_tiles,
+    chunk_seen_cells,
+    group_seen_cells,
     topk_scores_pallas,
 )
 
@@ -224,6 +226,176 @@ def test_build_seen_tiles_brute_force(rng):
                           if t * tile <= x < (t + 1) * tile)
             got = sorted(x for x in st[t, b].tolist() if x != tile)
             assert got == want, (t, b)
+
+
+def _csr(lists):
+    indptr = np.zeros(len(lists) + 1, np.int64)
+    indptr[1:] = np.cumsum([len(x) for x in lists])
+    movies = (np.concatenate([np.asarray(x, np.int32) for x in lists])
+              if indptr[-1] else np.zeros(0, np.int32))
+    return movies, indptr
+
+
+def _seen_case(name):
+    """(seen lists by user row, batch rows, num_movies, tile_m, capacity,
+    pieces expected, width expected) of one case of the device build."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    pick = lambda m, n: np.sort(rng.choice(m, size=n, replace=False))
+    if name == "random":
+        lists = [pick(77, int(rng.integers(0, 30))) for _ in range(8)]
+        return lists, np.arange(8), 77, 16, 128, None, 16
+    if name == "all_empty":
+        return [[]] * 8, np.arange(8), 50, 16, 128, 1, 16
+    if name == "one_user_many_times":
+        lists = [pick(200, 40), pick(200, 3), []]
+        rows = np.array([0] * 13 + [1, 2, 0])
+        return lists, rows, 200, 16, 16 * 16, 3, 16
+    if name == "cells_past_num_movies":
+        # rows 50..63 lie in the padded table, 64.. past it: all dropped
+        lists = [np.array([3, 49, 50, 63, 64, 900]), np.array([50, 51]),
+                 np.array([0, 48])] + [[]] * 5
+        return lists, np.arange(8), 50, 16, 128, 1, 16
+    if name == "pad_slots_empty":
+        # the engine's padding: slots past n select the empty last list
+        lists = [pick(50, 9), pick(50, 4), pick(50, 7), []]
+        return lists, np.array([0, 1, 2, 3, 3, 3, 3, 3]), 50, 16, 128, 1, 16
+    if name == "over_capacity":
+        lists = [pick(300, 25) for _ in range(8)]
+        return lists, np.arange(8), 300, 16, 64, 4, 16
+    if name == "exactly_capacity":
+        lists = [pick(300, 8) for _ in range(8)]
+        return lists, np.arange(8), 300, 16, 64, 1, 16
+    if name == "wide":
+        # 40 and 70 seen items inside one 128-row tile: W 64 and 128
+        lists = [pick(128, 40), 128 + pick(128, 70), pick(256, 5), []]
+        return lists, np.arange(4), 256, 128, 64, 2, 128
+    raise KeyError(name)
+
+
+SEEN_CASES = ("random", "all_empty", "one_user_many_times",
+              "cells_past_num_movies", "pad_slots_empty", "over_capacity",
+              "exactly_capacity", "wide")
+
+
+@pytest.mark.parametrize("name", SEEN_CASES)
+def test_device_built_rectangle_bit_equals_host_oracle(name):
+    # the serve path's rectangle: host grouping, pieces of a fixed
+    # capacity, the engine's jitted scatter run once per piece
+    from cfk_tpu.serving.engine import _seen_tiles_jit_fn
+
+    lists, rows, m, tile, capacity, pieces, width = _seen_case(name)
+    movies, indptr = _csr(lists)
+    kw = dict(num_movies=m, tile_m=tile, num_tiles=-(-m // tile) + 1)
+    want = build_seen_tiles(movies, indptr, rows, **kw)
+    cells, shape = group_seen_cells(movies, indptr, rows, **kw)
+    assert shape == want.shape == (kw["num_tiles"], len(rows), width)
+    assert cells.shape[1] == int((want != tile).sum())
+    chunks = chunk_seen_cells(cells, capacity, shape[0])
+    if pieces is not None:
+        assert len(chunks) == pieces
+    got = None
+    for chunk in chunks:
+        assert chunk.shape == (4, capacity) and chunk.dtype == np.int32
+        got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
+                                   tile_m=tile)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("name", SEEN_CASES)
+def test_engine_answers_equal_dense_oracle_through_device_rectangle(
+        name, monkeypatch):
+    from cfk_tpu.serving import engine as engine_mod
+
+    lists, rows, m, tile, capacity, pieces, _ = _seen_case(name)
+    rng = np.random.default_rng(7)
+    uf = rng.standard_normal((len(lists), 8)).astype(np.float32)
+    mf = rng.standard_normal((m, 8)).astype(np.float32)
+    movies, indptr = _csr(lists)
+    eng = engine_mod.ServeEngine(
+        uf, mf, num_users=len(lists), num_movies=m, seen_movies=movies,
+        seen_indptr=indptr, tile_m=tile, batch_quantum=4,
+    )
+    monkeypatch.setattr(engine_mod, "seen_cell_capacity", lambda b: capacity)
+    seen_chunks = []
+    real = engine_mod._seen_chunks
+    monkeypatch.setattr(
+        engine_mod, "_seen_chunks",
+        lambda *a: seen_chunks.append(real(*a)) or seen_chunks[-1])
+    k = 6
+    vals, ids = eng.topk(rows, k)
+    if pieces is not None:
+        assert len(seen_chunks[0]) == pieces
+    seen = [np.asarray(lists[r], np.int64) for r in rows]
+    seen = [x[x < m] for x in seen]
+    ov, oi = _dense_oracle(uf[rows], mf, seen, k)
+    np.testing.assert_array_equal(ids, oi)
+    np.testing.assert_allclose(vals, ov, rtol=0, atol=_DOT_ATOL)
+    # and bit for bit what the kernel gives over the host-built rectangle
+    b = engine_mod._pow2_ceil(len(rows), 4)
+    m2, i2 = _csr([lists[r] for r in rows] + [[]] * (b - len(rows)))
+    st = build_seen_tiles(m2, i2, np.arange(b), num_movies=m, tile_m=tile,
+                          num_tiles=eng.table_rows // tile)
+    u = np.zeros((b, 8), np.float32)
+    u[: len(rows)] = uf[rows]
+    kv, ki = topk_scores_pallas(
+        jnp.asarray(u), eng._table[0], None, jnp.asarray(st), k_top=k,
+        num_movies=m, tile_m=tile,
+    )
+    np.testing.assert_array_equal(vals, np.asarray(kv)[: len(rows)])
+    np.testing.assert_array_equal(ids, np.asarray(ki)[: len(rows)])
+
+
+def host_built_seen_tiles(engine, chunks, shape):
+    """``ServeEngine._seen_tiles`` as the serve path had it before the
+    device built the rectangle: numpy fills it, the whole of it is
+    uploaded."""
+    if chunks is None:
+        return None
+    rect = np.full(shape, engine.tile_m, np.int32)
+    for cells in map(np.asarray, chunks):
+        cells = cells[:, cells[0] < shape[0]]
+        rect[cells[0], cells[1], cells[2]] = cells[3]
+    return jnp.asarray(rect)
+
+
+@pytest.mark.parametrize("caller", ["exact", "item_sharded", "two_stage"])
+def test_every_caller_serves_from_the_device_built_rectangle(
+        caller, rng, monkeypatch):
+    # one grouping, one device builder: the one-device scan, the sharded
+    # scan and the two-stage rescore give, bit for bit, the answers they
+    # give over a rectangle the host built from the same pieces — also for
+    # a batch in three pieces
+    from cfk_tpu.parallel.mesh import make_mesh
+    from cfk_tpu.serving import engine as engine_mod
+
+    users, movies = 24, 300
+    uf = rng.standard_normal((users, 8)).astype(np.float32)
+    mf = rng.standard_normal((movies, 8)).astype(np.float32)
+    movies_csr, indptr = _csr([
+        np.sort(rng.choice(movies, size=int(rng.integers(0, 40)),
+                           replace=False)) for _ in range(users)])
+    eng = engine_mod.ServeEngine(
+        uf, mf, num_users=users, num_movies=movies, seen_movies=movies_csr,
+        seen_indptr=indptr, tile_m=16, batch_quantum=8,
+        mesh=make_mesh(2) if caller == "item_sharded" else None,
+        serve_mode="two_stage" if caller == "two_stage" else "exact",
+        clusters=8, probe_clusters=4,
+    )
+    rows = rng.integers(0, users, size=13)
+    for capacity in (engine_mod.seen_cell_capacity,
+                     lambda b: -(-int(np.diff(indptr)[rows].sum()) // 3)):
+        with monkeypatch.context() as mp:
+            mp.setattr(engine_mod, "seen_cell_capacity", capacity)
+            vals, ids = eng.topk(rows, 5)
+            mp.setattr(engine_mod.ServeEngine, "_seen_tiles",
+                       host_built_seen_tiles)
+            want_vals, want_ids = eng.topk(rows, 5)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(vals, want_vals)
+    for row, got in zip(rows, ids):
+        mine = movies_csr[indptr[row]: indptr[row + 1]]
+        assert not set(got.tolist()) & set(mine.tolist())
 
 
 def _tiny_model(seed=0):

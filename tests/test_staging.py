@@ -237,6 +237,51 @@ def test_serve_engine_prewarm_pins_zero_new_traces():
     assert eng.trace_count - before == 1
 
 
+def test_serve_engine_prewarm_closes_the_seen_scatter_programs(monkeypatch):
+    # the exclusion rectangle's programs are keyed by the padded batch size
+    # (the cell-list capacity is a function of it alone), so the ladder
+    # covers a batch of every rung AND a batch whose cells overflow one
+    # piece, twice over, whatever seen lists the sample happened to hold
+    from cfk_tpu.serving import engine as engine_mod
+
+    rng = np.random.default_rng(0)
+    users, movies = 40, 60
+    lists = [np.sort(rng.choice(movies, int(rng.integers(0, 5)),
+                                replace=False)) for _ in range(users)]
+    lists[7] = np.arange(movies)[::2]  # 30 cells: over a third of a piece
+    indptr = np.zeros(users + 1, np.int64)
+    indptr[1:] = np.cumsum([x.size for x in lists])
+    eng = engine_mod.ServeEngine(
+        rng.standard_normal((users, 8)).astype(np.float32),
+        rng.standard_normal((movies, 8)).astype(np.float32),
+        num_users=users, num_movies=movies,
+        seen_movies=np.concatenate(lists).astype(np.int32),
+        seen_indptr=indptr, tile_m=16, batch_quantum=4,
+    )
+    # the sample holds none of the heavy user's rows
+    warm = eng.prewarm(3, max_batch=16, user_rows=np.arange(20, 36))
+    assert warm["programs"] == 3  # buckets 4, 8, 16
+    # per bucket at most: the scorer, the scatter that starts a rectangle
+    # and the one that adds to it (fewer where an earlier test of this
+    # process traced the same shapes: the counter is process-wide)
+    assert warm["new_traces"] <= 9
+    before = eng.trace_count
+    chunks = []
+    real = engine_mod._seen_chunks
+    monkeypatch.setattr(
+        engine_mod, "_seen_chunks",
+        lambda *a: chunks.append(real(*a)) or chunks[-1])
+    for n in (3, 7, 13):  # a batch of every rung
+        eng.topk(np.arange(n), 3)
+    # 4 x 30 cells against a capacity of 64, 8 x 30 against 128, 16 x 30
+    # against 256, 3 x 30 against 64: two pieces each
+    for n in (4, 8, 16, 3):
+        vals, ids = eng.topk(np.full(n, 7), 3)
+        assert not set(ids.ravel().tolist()) & set(lists[7].tolist())
+    assert [len(c) for c in chunks] == [1, 1, 1, 2, 2, 2, 2]
+    assert eng.trace_count - before == 0
+
+
 def test_stream_session_prewarm_pins_zero_new_traces(tmp_path):
     from cfk_tpu.config import ALSConfig
     from cfk_tpu.data.blocks import Dataset
