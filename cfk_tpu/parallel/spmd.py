@@ -1505,7 +1505,7 @@ def serve_topk_sharded(
     u,  # [B, k] user-factor batch (replicated)
     table,  # [M_pad, k] item table, M_pad a multiple of shards·tile_m
     scale,  # [M_pad] f32 int8 per-row scales, or None
-    seen_tiles,  # [NT, B, W] int32 (serving.topk_kernel.scatter_seen_cells)
+    seen_tiles,  # [NT, B, W] int32 (serve_seen_tiles_sharded), or None
     *,
     k_top: int,
     num_movies: int,
@@ -1521,6 +1521,8 @@ def serve_topk_sharded(
     per-shard [B, K] selections — [B, shards·K] — feeds a final
     ``lax.top_k`` merge.  No dense score block ever crosses a shard
     boundary; the exchange is O(B·shards·K), independent of num_movies.
+    The program takes the operands there are and no others: no scales
+    without an int8 table, no rectangle without exclusion.
 
     Bit-equality with the single-shard kernel holds by construction:
     per-element score dots are identical (same k-order contraction), and
@@ -1536,21 +1538,16 @@ def serve_topk_sharded(
             f"table rows {m_pad} not divisible by shards×tile_m "
             f"({shards}×{tile_m}); pad with serving.engine.pad_table"
         )
-    nt = m_pad // tile_m
-    if nt % shards != 0:  # pragma: no cover - implied by the check above
-        raise ValueError(f"{nt} tiles not divisible by {shards} shards")
-
-    # int8 scales / seen rectangles shard with the table rows / tiles; a
-    # zero placeholder keeps the spec arity fixed when absent.
-    sc_op = (jnp.zeros((m_pad,), jnp.float32) if scale is None
-             else scale.astype(jnp.float32))
-    seen_op = (jnp.zeros((nt, u.shape[0], 1), jnp.int32)
-               if seen_tiles is None else seen_tiles)
     fn = _serve_topk_sharded_fn(
         mesh, m_pad // shards, scale is not None, seen_tiles is not None,
         k_top, num_movies, tile_m,
     )
-    return fn(u, table, sc_op, seen_op)
+    ops = [u, table]
+    if scale is not None:
+        ops.append(scale.astype(jnp.float32))
+    if seen_tiles is not None:
+        ops.append(seen_tiles)
+    return fn(*ops)
 
 
 @functools.lru_cache(maxsize=64)
@@ -1559,17 +1556,25 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
     """Jitted shard_map for one (mesh, shapes-class, K) serving config —
     cached so a live server's request stream reuses compiled programs
     instead of re-tracing the shard_map per call (the engine's pow2
-    bucketing keeps the distinct key count small)."""
+    bucketing keeps the distinct key count small).  Operands: ``u``, the
+    table, then the scales and the rectangle where the key says so."""
+    from cfk_tpu.serving.engine import note_trace
     from cfk_tpu.serving.topk_kernel import topk_scores_pallas
 
-    def shard_fn(u_rep, tbl, sc, seen):
+    def shard_fn(u_rep, tbl, *rest):
+        rest = list(rest)
+        sc = rest.pop(0) if has_scale else None
+        seen = rest.pop(0) if has_seen else None
         off = lax.axis_index(AXIS).astype(jnp.int32) * rows_per_shard
-        v, ids = topk_scores_pallas(
-            u_rep, tbl, sc if has_scale else None,
-            seen if has_seen else None,
-            k_top=k_top, num_movies=num_movies, tile_m=tile_m,
-            row_offset=off,
-        )
+        # the scope names the scorer's custom call in a device trace
+        # (``_topk_shard_call.<n>``): without it the call would read
+        # ``shard_map.<n>``, beside the one-device entry's ``_topk_call``
+        with jax.named_scope("_topk_shard_call"):
+            v, ids = topk_scores_pallas(
+                u_rep, tbl, sc, seen,
+                k_top=k_top, num_movies=num_movies, tile_m=tile_m,
+                row_offset=off,
+            )
         cat_v = lax.all_gather(v, AXIS, axis=1, tiled=True)
         cat_i = lax.all_gather(ids, AXIS, axis=1, tiled=True)
         mv, pos = lax.top_k(cat_v, k_top)
@@ -1582,7 +1587,64 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
     # typed as what they are to the checker, and the first is the answer.
     sharded = _compat_shard_map(
         shard_fn, mesh=mesh,
-        in_specs=(P(), P(AXIS), P(AXIS), P(AXIS)),
+        in_specs=(P(), P(AXIS)) + (P(AXIS),) * (has_scale + has_seen),
         out_specs=(P(AXIS), P(AXIS)),
     )
-    return jax.jit(lambda *ops: tuple(x[0] for x in sharded(*ops)))
+
+    def _topk_shard_call(*ops):
+        note_trace()
+        return tuple(x[0] for x in sharded(*ops))
+
+    return jax.jit(_topk_shard_call)
+
+
+def serve_seen_tiles_sharded(mesh: Mesh, cells, seen_tiles, *, shape,
+                             tile_m: int):
+    """The [NT, B, W] exclusion rectangle of ``serve_topk_sharded``, each
+    shard's NT / shards tiles built on the chip that scans them: ``cells``
+    (one replicated [4, capacity] piece of ``chunk_seen_cells``) goes to
+    every chip, and none ever holds the other chips' slices.  ``seen_tiles``
+    None starts the rectangle; one that earlier pieces went into is donated
+    and takes this piece on top."""
+    fn = _serve_seen_tiles_sharded_fn(mesh, tuple(shape), tile_m,
+                                      seen_tiles is None)
+    return fn(cells) if seen_tiles is None else fn(cells, seen_tiles)
+
+
+@functools.lru_cache(maxsize=64)
+def _serve_seen_tiles_sharded_fn(mesh, shape, tile_m, fresh):
+    from cfk_tpu.serving.engine import note_trace
+    from cfk_tpu.serving.topk_kernel import scatter_seen_cells
+
+    nt, b, width = shape
+    shards = mesh.devices.size
+    if nt % shards != 0:
+        raise ValueError(f"{nt} tiles not divisible by {shards} shards")
+    per = nt // shards
+
+    def shard_fn(cells, *rect):
+        # the tile index rebased by the shard's first tile; another
+        # shard's cell goes past the slice, where the scatter drops it —
+        # never below zero, where ``.at[]`` would wrap round
+        tile = cells[0] - lax.axis_index(AXIS).astype(jnp.int32) * per
+        tile = jnp.where((tile >= 0) & (tile < per), tile, per)
+        local = jnp.concatenate(
+            [tile[None], _match_varying(cells[1:], tile)])
+        start = (rect[0] if rect else
+                 _match_varying(jnp.full((per, b, width), tile_m, jnp.int32),
+                               tile))
+        return scatter_seen_cells(local, start, shape=(per, b, width),
+                                  tile_m=tile_m)
+
+    sharded = _compat_shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(P(),) if fresh else (P(), P(AXIS)),
+        out_specs=P(AXIS),
+    )
+
+    def _seen_tiles_shard_call(*ops):
+        note_trace()
+        return sharded(*ops)
+
+    return jax.jit(_seen_tiles_shard_call,
+                   donate_argnums=() if fresh else (1,))

@@ -5,7 +5,14 @@ user rows" and "[B, K] ids+scores":
 
 - the item factor table, padded to the kernel's tile grid, quantized per
   ``ALSConfig.table_dtype`` (``ops.quant``) and kept device-resident (it
-  is read every request; re-uploading 30 MB per query would dominate),
+  is read every request; re-uploading 30 MB per query would dominate):
+  on one device, or — ``shards=`` / ``mesh=`` — row-sharded over a mesh,
+  staged and uploaded one shard at a time, so that a catalogue past one
+  chip's memory is never whole on any device.  Each chip then scans its
+  slice and builds its own slice of the exclusion rectangle from the
+  batch's replicated cell list; ``u`` and that list are all a batch sends
+  to every chip, and one all_gather of the [B, K] selections merges them
+  (``parallel.spmd.serve_topk_sharded``),
 - the user factor source: a base snapshot taken at attach time plus a
   HOT-ROW OVERLAY — the factor rows most recently re-solved by streaming
   fold-in commits.  ``StreamSession`` publishes every commit through
@@ -92,7 +99,8 @@ class ServeEngine:
         table_dtype: str | None = None,
         tile_m: int = 512,
         batch_quantum: int = 8,
-        mesh=None,
+        mesh=None,  # the caller's own; or
+        shards: int | None = None,  # a mesh over the first `shards` devices
         plan=None,  # cfk_tpu.plan.ExecutionPlan (serve knobs)
         plan_provenance=None,
         serve_mode: str | None = None,  # "exact" | "two_stage"
@@ -148,6 +156,12 @@ class ServeEngine:
         self.table_dtype = resolve_table_dtype(table_dtype)
         self.tile_m = int(tile_m)
         self.batch_quantum = int(batch_quantum)
+        if shards is not None:
+            if mesh is not None:
+                raise ValueError("pass one of mesh/shards, not both")
+            from cfk_tpu.parallel.mesh import make_mesh
+
+            mesh = make_mesh(int(shards))
         self.mesh = mesh
         self._shards = 1 if mesh is None else int(mesh.devices.size)
         self._lock = threading.RLock()
@@ -230,14 +244,17 @@ class ServeEngine:
 
         from cfk_tpu.ops.quant import quantize_table
 
-        padded = pad_table(
-            movie_factors_host.astype(np.float32), self.tile_m, self._shards
-        )
-        data, scale = quantize_table(jnp.asarray(padded), self.table_dtype)
-        # one atomic reference swap: a batch in flight keeps the table it
-        # captured; the next batch sees the new one
-        self._table = (jax.device_put(data),
-                       None if scale is None else jax.device_put(scale))
+        if self.mesh is not None:
+            self._table = self._upload_sharded(movie_factors_host)
+        else:
+            padded = pad_table(movie_factors_host.astype(np.float32),
+                               self.tile_m)
+            data, scale = quantize_table(jnp.asarray(padded),
+                                         self.table_dtype)
+            # one atomic reference swap: a batch in flight keeps the table
+            # it captured; the next batch sees the new one
+            self._table = (jax.device_put(data),
+                           None if scale is None else jax.device_put(scale))
         if self.serve_mode == "two_stage":
             # Rebuild the cluster index with every swap (re-cluster ONLY
             # here — fold-in deltas update rows in place).  Built off to
@@ -270,6 +287,51 @@ class ServeEngine:
             # after any fault-driven degradation (the recovery half of the
             # chaos contract)
             self._two_stage_disabled = False
+
+    def _upload_sharded(self, host: np.ndarray):
+        """(data, scale) row-sharded over the mesh, shard by shard: shard
+        s's rows go from the caller's array straight to chip s and are
+        quantized there, the four transfers in flight together.  Only a
+        shard that is not a whole contiguous slice of that array (the
+        last, zero-padded to the tile grid) goes through a staging copy,
+        and it has landed before another is made.  No device and no host
+        buffer holds the padded table whole."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from cfk_tpu.ops.quant import quantize_table
+        from cfk_tpu.parallel.mesh import AXIS
+
+        shards, rank = self._shards, host.shape[1]
+        quantum = self.tile_m * shards
+        per = -(-host.shape[0] // quantum) * self.tile_m
+        host = np.asarray(host, np.float32)
+        datas, scales = [], []
+        with span("serve/engine/table_upload", shards=shards,
+                  rows_per_shard=per) as sp:
+            for s, device in enumerate(self.mesh.devices.flat):
+                rows = host[s * per:(s + 1) * per]
+                staged = rows.shape[0] < per or not rows.flags.c_contiguous
+                if staged:
+                    stage = np.zeros((per, rank), np.float32)
+                    stage[:rows.shape[0]] = rows
+                    rows = stage
+                # committed to chip s, so the quantization runs there too
+                data, scale = quantize_table(jax.device_put(rows, device),
+                                             self.table_dtype)
+                if staged:  # at most one staging copy at a time
+                    jax.block_until_ready(data)
+                datas.append(data)
+                scales.append(scale)
+            jax.block_until_ready(datas)
+            sharding = NamedSharding(self.mesh, P(AXIS))
+            data = jax.make_array_from_single_device_arrays(
+                (per * shards, rank), sharding, datas)
+            scale = None if scales[0] is None else (
+                jax.make_array_from_single_device_arrays(
+                    (per * shards,), sharding, scales))
+            sp.set(bytes=data.nbytes + (0 if scale is None else scale.nbytes))
+        return data, scale
 
     @property
     def table_rows(self) -> int:
@@ -340,9 +402,10 @@ class ServeEngine:
         qd, qs = quantize_table(jnp.asarray(f), self.table_dtype)
         with self._lock:
             data, scale = self._table
-            data = data.at[rows].set(qd.astype(data.dtype))
+            set_rows = _set_rows_fn(data.sharding)
+            data = set_rows(data, rows, qd.astype(data.dtype))
             if scale is not None:
-                scale = scale.at[rows].set(qs)
+                scale = set_rows(scale, rows, qs)
             self._table = (data, scale)
             if self._cluster is not None:
                 index, ctable, cscale, qc, qcs = self._cluster
@@ -414,6 +477,7 @@ class ServeEngine:
               min_seen_chunks=1):
         """``topk``; ``prewarm`` asks for the seen cells in two pieces at
         least, which runs every program a batch over the capacity runs."""
+        import jax
         import jax.numpy as jnp
 
         user_rows = np.asarray(user_rows, dtype=np.int64)
@@ -469,6 +533,11 @@ class ServeEngine:
                     num_tiles=self.table_rows // self.tile_m,
                 )
                 seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
+                if self.mesh is not None:
+                    # how many of the cells each chip keeps for its slice
+                    sp.set(shard_cells=np.bincount(
+                        cells[0] // (shape[0] // self._shards),
+                        minlength=self._shards).tolist())
         # the calls that hand the batch to the runtime; they may return
         # before the bytes have landed, and the fetch below then waits
         # for the transfer as well as for the scorer
@@ -476,15 +545,26 @@ class ServeEngine:
             nbytes = u.nbytes
             if seen is not None:
                 nbytes += sum(c.nbytes for c in seen)
-                seen = [jnp.asarray(c) for c in seen]
-            u = jnp.asarray(u)
+            if self.mesh is None:
+                put = jnp.asarray
+            else:
+                # u and the cell pieces go to every chip, from the host
+                put = functools.partial(
+                    jax.device_put, device=_replicated(self.mesh))
+                sp.set(shards=self._shards,
+                       replicated_bytes=nbytes * self._shards)
+            if seen is not None:
+                seen = [put(c) for c in seen]
+            u = put(u)
             sp.set(bytes=nbytes)
-        with span("serve/batch/compute", n=n, b=b, k=k):
+        with span("serve/batch/compute", n=n, b=b, k=k) as sp:
             with span("serve/batch/compute/dispatch"):
-                seen_tiles = self._seen_tiles(seen, shape)
+                seen_tiles = self._seen_tiles(seen, shape, self.mesh)
                 if self.mesh is not None:
                     from cfk_tpu.parallel.spmd import serve_topk_sharded
 
+                    sp.set(shards=self._shards,
+                           merge_candidates=self._shards * k)
                     vals, ids = serve_topk_sharded(
                         self.mesh, u, table, scale, seen_tiles, k_top=k,
                         num_movies=self.num_movies, tile_m=self.tile_m,
@@ -501,17 +581,24 @@ class ServeEngine:
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids
 
-    def _seen_tiles(self, chunks, shape):
+    def _seen_tiles(self, chunks, shape, mesh=None):
         """The [NT, B, W] exclusion rectangle on the device, from the
         uploaded pieces of the batch's cell list (``_seen_chunks``; None
         = no exclusion): one run of the scatter program per piece, the
         first onto a fresh all-padding rectangle.  Every caller — exact,
-        item-sharded, two-stage rescore — gets its rectangle here."""
+        item-sharded, two-stage rescore — gets its rectangle here, from
+        the one ``scatter_seen_cells``; over a mesh each chip builds the
+        tiles it scans (``parallel.spmd.serve_seen_tiles_sharded``)."""
+        if mesh is None:
+            build = _seen_tiles_jit_fn()
+        else:
+            from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
+
+            build = functools.partial(serve_seen_tiles_sharded, mesh)
         seen_tiles = None
         for cells in chunks or ():
-            seen_tiles = _seen_tiles_jit_fn()(
-                cells, seen_tiles, shape=shape, tile_m=self.tile_m,
-            )
+            seen_tiles = build(cells, seen_tiles, shape=shape,
+                               tile_m=self.tile_m)
         return seen_tiles
 
     def _topk_two_stage(self, cluster, u, n, b, k, seen_pad,
@@ -740,13 +827,19 @@ _TRACES = [0]
 
 
 def trace_count() -> int:
-    """Traces of the single-device serve programs this process — the
-    exact scan and the seen-rectangle scatter, plus (ISSUE 16) the
-    two-stage coarse/rescore stages, so the prewarm contract covers
-    whichever mode the plan picked."""
+    """Traces of the serve programs this process — the exact scan and the
+    seen-rectangle scatter, on one device or as shard programs over a
+    mesh, plus (ISSUE 16) the two-stage coarse/rescore stages, so the
+    prewarm contract covers whichever mode the plan picked."""
     from cfk_tpu.serving import twostage
 
     return _TRACES[0] + twostage.trace_count()
+
+
+def note_trace() -> None:
+    """One more trace of a serve program that lives outside this module
+    (``parallel.spmd``'s shard programs)."""
+    _TRACES[0] += 1
 
 
 def _topk_call(u, table, scale, seen_tiles, *, k_top, num_movies, tile_m):
@@ -786,6 +879,23 @@ def _topk_jit_fn():
     )
 
 
+@functools.lru_cache(maxsize=8)
+def _set_rows_fn(sharding):
+    """Jitted row update that leaves the table placed as it was: a
+    row-sharded table stays row-sharded."""
+    import jax
+
+    return jax.jit(lambda data, rows, vals: data.at[rows].set(vals),
+                   out_shardings=sharding)
+
+
+@functools.lru_cache(maxsize=8)
+def _replicated(mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return NamedSharding(mesh, P())
+
+
 def plan_for_serving(num_users: int, num_movies: int, rank: int, *,
                      k_top: int = 100, table_dtype: str | None = None,
                      serve_mode: str | None = None,
@@ -813,7 +923,7 @@ def plan_for_serving(num_users: int, num_movies: int, rank: int, *,
 
 
 def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
-                      mesh=None, batch_quantum=8, plan=None,
+                      mesh=None, shards=None, batch_quantum=8, plan=None,
                       plan_provenance=None, serve_mode=None, clusters=None,
                       probe_clusters=None, metrics=None) -> ServeEngine:
     """Build an engine from an ``ALSModel`` (+ optional dataset/index whose
@@ -842,7 +952,7 @@ def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
         np.asarray(u), np.asarray(m),
         num_users=model.num_users, num_movies=model.num_movies,
         seen_movies=seen_movies, seen_indptr=seen_indptr,
-        table_dtype=table_dtype, tile_m=tile_m, mesh=mesh,
+        table_dtype=table_dtype, tile_m=tile_m, mesh=mesh, shards=shards,
         batch_quantum=batch_quantum, plan=plan,
         plan_provenance=plan_provenance, serve_mode=serve_mode,
         clusters=clusters, probe_clusters=probe_clusters, metrics=metrics,

@@ -573,15 +573,24 @@ def multichip(args, dev: Device) -> None:
         check(bool(np.isfinite(preds).all()) and delta <= MULTICHIP_TOL,
               f"{exchange}: within {MULTICHIP_TOL} of the one-device result")
 
-    # Item-axis sharded serving (serve_topk_sharded: each shard scans its
-    # table slice, one all_gather of the [B, K] selections, a final merge)
-    # against the one-device engine over the same factors.
+    # Item-axis sharded serving (ServeEngine(shards=4): the table goes up a
+    # shard at a time, each chip scans its slice and builds its slice of the
+    # exclusion rectangle, one all_gather of the [B, K] selections, a final
+    # merge) against the one-device engine over the same factors.
     from cfk_tpu.serving import engine_from_model
 
     rows = np.random.default_rng(args.seed + 3).choice(
         ref_model.num_users, size=64, replace=False)
     v1, i1 = engine_from_model(ref_model, ds1).topk(rows, 10)
-    v4, i4 = engine_from_model(ref_model, ds1, mesh=mesh).topk(rows, 10)
+    eng4 = engine_from_model(ref_model, ds1, shards=4)
+    table = eng4._table[0]
+    parts = table.addressable_shards
+    check([s.device for s in parts] == list(devices)
+          and {s.data.shape[0] for s in parts} == {table.shape[0] // 4},
+          f"sharded serving: item table [{table.shape[0]}, {table.shape[1]}] "
+          f"in 4 shards of {table.shape[0] // 4} rows, one on each device, "
+          "not whole on device 0")
+    v4, i4 = eng4.topk(rows, 10)
     same = float((i1 == i4).mean())
     say(f"  sharded serving: 64 users, K=10 — {same:.3f} of ids identical, "
         f"largest score difference {np.abs(v1 - v4).max():.2e} "

@@ -336,10 +336,16 @@ def test_serve_scorer_ml25m_width(chip, dtype):
              chip((m_pad // tile_m, b, w), i32), *scale)
 
 
-def test_serve_sharded_four_devices(topo, chip, as_tpu):
+@pytest.mark.parametrize("m,k_top,b,dtype", [
+    (59_047, 10, 64, bf16),  # the ML-25M table
+    (48_190_000, 16, 256, f32),  # Amazon-2023: 24.7 GB, 6.17 GB a chip
+])
+def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
     """Item-axis sharded serving as one program over the described 2×2
     mesh, shard_map's vma check ON: the kernel's outputs carry the table's
-    vma, and the merged selections come back stacked over the mesh axis."""
+    vma, and the merged selections come back stacked over the mesh axis.
+    The scorer's custom call carries the shard entry's name, the rectangle
+    is built slice by slice, and no chip is handed more than its shard."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -347,14 +353,27 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu):
     from cfk_tpu.parallel.mesh import AXIS
 
     mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
-    m, m_pad, k, b, k_top, tile_m, w = 59_047, 59_392, 128, 64, 10, 512, 16
-    nt = m_pad // tile_m
-    fn = spmd._serve_topk_sharded_fn(
-        mesh, m_pad // 4, False, True, k_top, m, tile_m)
+    k, tile_m, w = 128, 512, 16
+    per = -(-m // (4 * tile_m)) * tile_m
+    m_pad, nt = 4 * per, 4 * per // tile_m
     on = lambda shape, dt, spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(mesh, spec))
-    text = fn.lower(
-        on((b, k), f32, P()), on((m_pad, k), bf16, P(AXIS)),
-        on((m_pad,), f32, P(AXIS)), on((nt, b, w), i32, P(AXIS)),
-    ).compile().as_text()
+    fn = spmd._serve_topk_sharded_fn(mesh, per, False, True, k_top, m, tile_m)
+    scorer = fn.lower(
+        on((b, k), f32, P()), on((m_pad, k), dtype, P(AXIS)),
+        on((nt, b, w), i32, P(AXIS)),
+    ).compile()
+    text = scorer.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
+    assert "%_topk_shard_call." in text
+    shard_bytes = per * k * jnp.dtype(dtype).itemsize + nt // 4 * b * w * 4
+    # (a narrow batch's rectangle is padded to whole 128-lane registers)
+    assert scorer.memory_analysis().argument_size_in_bytes < 1.5 * shard_bytes
+    for fresh in (True, False):
+        build = spmd._serve_seen_tiles_sharded_fn(
+            mesh, (nt, b, w), tile_m, fresh)
+        ops = [on((4, 16 * b), i32, P())]
+        if not fresh:
+            ops.append(on((nt, b, w), i32, P(AXIS)))
+        mem = build.lower(*ops).compile().memory_analysis()
+        assert mem.output_size_in_bytes < 1.5 * nt // 4 * max(b, 128) * w * 4

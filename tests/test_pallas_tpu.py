@@ -466,3 +466,59 @@ def test_serve_callers_same_answers_from_the_chip_built_rectangle(
         assert (got >= 0).all() and (got < m).all()
         mine = movies[indptr[row]: indptr[row + 1]]
         assert not set(got.tolist()) & set(mine.tolist())
+
+
+@pytest.mark.parametrize("capacity", [None, 512])
+def test_four_chip_sharded_engine_equals_one_device(capacity, monkeypatch):
+    """``ServeEngine(shards=4)`` on four real chips, at a size one chip
+    holds too: the table lies a quarter on each chip, each chip's slice of
+    the exclusion rectangle is the host oracle's, and ids and scores are the
+    one-device engine's bit for bit, the cell list in one piece or several."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four chips (the chip tool's --chips 4)")
+    from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
+    from cfk_tpu.serving import engine as engine_mod
+    from cfk_tpu.serving.topk_kernel import (
+        build_seen_tiles,
+        chunk_seen_cells,
+        group_seen_cells,
+        seen_cell_capacity,
+    )
+
+    rng = np.random.default_rng(23)
+    users, m, rank, tile, b = 300, 201_000, 128, 512, 64
+    uf = rng.standard_normal((users, rank)).astype(np.float32)
+    mf = rng.standard_normal((m, rank)).astype(np.float32)
+    movies, indptr = _seen_problem(rng, users, m, 60)
+    kw = dict(num_users=users, num_movies=m, seen_movies=movies,
+              seen_indptr=indptr, tile_m=tile)
+    one = engine_mod.ServeEngine(uf, mf, **kw)
+    four = engine_mod.ServeEngine(uf, mf, shards=4, **kw)
+    table = four._table[0]
+    assert [s.device for s in table.addressable_shards] == jax.devices()[:4]
+    assert {s.data.shape[0] for s in table.addressable_shards} == {
+        four.table_rows // 4}
+
+    rows = rng.integers(0, users, size=b)
+    nt = four.table_rows // tile
+    skw = dict(num_movies=m, tile_m=tile, num_tiles=nt)
+    want = build_seen_tiles(movies, indptr, rows, **skw)
+    cells, shape = group_seen_cells(movies, indptr, rows, **skw)
+    got = None
+    for chunk in chunk_seen_cells(cells, capacity or seen_cell_capacity(b),
+                                  nt, 2):
+        got = serve_seen_tiles_sharded(
+            four.mesh, jax.device_put(chunk, engine_mod._replicated(four.mesh)),
+            got, shape=shape, tile_m=tile)
+    for shard in got.addressable_shards:
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      want[shard.index])
+
+    if capacity is not None:
+        monkeypatch.setattr(engine_mod, "seen_cell_capacity",
+                            lambda b: capacity)
+    for take in (rows, rows[:50], rows[:5]):
+        want_vals, want_ids = one.topk(take, 10)
+        vals, ids = four.topk(take, 10)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(vals, want_vals)

@@ -1,0 +1,278 @@
+"""A catalogue row-sharded over a mesh (ISSUE 26): ``ServeEngine(shards=n)``
+on the 8-device CPU mesh at toy size — answers against a numpy reference
+that imports nothing of the program and against the one-device engine bit
+for bit, where the table and the exclusion rectangle live, what the spans
+say, and the table's life under ``load_state`` / ``apply_movie_deltas``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cfk_tpu import telemetry
+from cfk_tpu.serving import engine as engine_mod
+from cfk_tpu.serving.topk_kernel import (
+    build_seen_tiles,
+    chunk_seen_cells,
+    group_seen_cells,
+)
+from tests.serve_reference import exact_topk_blocks, topk_gaps
+
+RANK, TILE = 8, 16
+SHARDS = (1, 2, 4)
+
+
+def _csr(lists):
+    indptr = np.zeros(len(lists) + 1, np.int64)
+    indptr[1:] = np.cumsum([len(x) for x in lists])
+    movies = (np.concatenate([np.asarray(x, np.int32) for x in lists])
+              if indptr[-1] else np.zeros(0, np.int32))
+    return movies, indptr
+
+
+def _problem(case):
+    """(user factors, item factors, seen lists, batch rows, K) of a case."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    pick = lambda lo, hi, n: lo + np.sort(rng.choice(hi - lo, n, replace=False))
+    users = 24
+    if case == "ragged":  # 1,000 rows: not a multiple of shards x tile
+        m, k = 1000, 7
+        lists = [pick(0, m, int(rng.integers(0, 40))) for _ in range(users)]
+    elif case == "exact_multiple":  # 1,024 = 4 x 16 x 16
+        m, k = 1024, 5
+        lists = [pick(0, m, int(rng.integers(0, 30))) for _ in range(users)]
+    elif case == "seen_in_one_shard":
+        # every seen item of every user lies in the second of four shards
+        m, k = 1000, 7
+        lists = [pick(256, 512, int(rng.integers(1, 60)))
+                 for _ in range(users)]
+    elif case == "k_over_a_shard":
+        # 60 rows over four shards: 16 a shard, most of them seen, K = 20
+        m, k = 60, 20
+        lists = [pick(0, 32, 25) for _ in range(users)]
+    elif case == "last_shard_all_padding":
+        # 130 rows pad to 4 x 48: the last shard holds no real row
+        m, k = 130, 6
+        lists = [pick(0, m, int(rng.integers(0, 20))) for _ in range(users)]
+    else:
+        raise KeyError(case)
+    uf = rng.standard_normal((users, RANK)).astype(np.float32)
+    mf = rng.standard_normal((m, RANK)).astype(np.float32)
+    rows = rng.integers(0, users, size=13)
+    return uf, mf, lists, rows, k
+
+
+CASES = ("ragged", "exact_multiple", "seen_in_one_shard", "k_over_a_shard",
+         "last_shard_all_padding")
+
+
+def _engine(uf, mf, lists, **kw):
+    movies, indptr = _csr(lists)
+    kw.setdefault("tile_m", TILE)
+    return engine_mod.ServeEngine(
+        uf, mf, num_users=uf.shape[0], num_movies=mf.shape[0],
+        seen_movies=movies, seen_indptr=indptr, batch_quantum=8, **kw)
+
+
+@pytest.mark.parametrize("pieces", ["one_piece", "several_pieces"])
+@pytest.mark.parametrize("shards", SHARDS)
+@pytest.mark.parametrize("case", CASES)
+def test_sharded_answers_equal_reference_and_one_device(
+        case, shards, pieces, monkeypatch):
+    uf, mf, lists, rows, k = _problem(case)
+    if pieces == "several_pieces":
+        cells = int(sum(len(lists[r]) for r in rows))
+        monkeypatch.setattr(engine_mod, "seen_cell_capacity",
+                            lambda b: max(-(-cells // 3), 1))
+    want_vals, want_ids = _engine(uf, mf, lists).topk(rows, k)
+    vals, ids = _engine(uf, mf, lists, shards=shards).topk(rows, k)
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(vals, want_vals)
+    best, best_ids, at = exact_topk_blocks(
+        uf[rows], mf, [lists[r] for r in rows], k, ids, block=97)
+    np.testing.assert_array_equal(ids, best_ids)
+    rank_gap, score_err = topk_gaps(vals, best, at)
+    assert rank_gap == 0.0 and score_err <= 2e-6
+    for row, got in zip(rows, ids):
+        assert not set(got.tolist()) & set(np.asarray(lists[row]).tolist())
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("shards", SHARDS)
+def test_table_is_placed_shard_by_shard_never_whole(shards, table_dtype,
+                                                    monkeypatch):
+    uf, mf, lists, rows, k = _problem("ragged")
+    put = []
+    real_put = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, *a, **kw: put.append(np.shape(x)) or real_put(x, *a, **kw))
+    before = {id(a) for a in jax.live_arrays()}
+    eng = _engine(uf, mf, lists, shards=shards, table_dtype=table_dtype)
+    per = -(-mf.shape[0] // (shards * TILE)) * TILE
+    assert eng.table_rows == per * shards
+    for part in eng._table:
+        if part is None:
+            continue
+        assert part.shape[0] == per * shards
+        assert [s.data.shape[0] for s in part.addressable_shards] == (
+            [per] * shards)
+        assert [s.device for s in part.addressable_shards] == list(
+            eng.mesh.devices.flat)
+    # what went up went up a shard at a time ...
+    assert [s for s in put if len(s) == 2] == [(per, RANK)] * shards
+    # ... and no array the engine made holds the table's rows on one device
+    for a in jax.live_arrays():
+        if id(a) not in before and a.ndim and a.shape[0] >= per * shards:
+            assert len(a.sharding.device_set) == shards, (a.shape, a.sharding)
+    want_vals, want_ids = _engine(uf, mf, lists,
+                                  table_dtype=table_dtype).topk(rows, k)
+    vals, ids = eng.topk(rows, k)
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(vals, want_vals)
+
+
+@pytest.mark.parametrize("pieces", [1, 3])
+@pytest.mark.parametrize("shards", SHARDS)
+def test_each_chip_builds_its_slice_of_the_rectangle(shards, pieces):
+    from cfk_tpu.parallel.mesh import make_mesh
+    from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
+
+    _, mf, lists, rows, _ = _problem("ragged")
+    movies, indptr = _csr([lists[r] for r in rows] + [[]] * 3)
+    nt = -(-mf.shape[0] // (shards * TILE)) * shards
+    kw = dict(num_movies=mf.shape[0], tile_m=TILE, num_tiles=nt)
+    want = build_seen_tiles(movies, indptr, np.arange(16), **kw)
+    cells, shape = group_seen_cells(movies, indptr, np.arange(16), **kw)
+    mesh = make_mesh(shards)
+    got = None
+    for chunk in chunk_seen_cells(cells, -(-cells.shape[1] // pieces), nt):
+        got = serve_seen_tiles_sharded(mesh, jnp.asarray(chunk), got,
+                                       shape=shape, tile_m=TILE)
+    assert got.shape == want.shape
+    assert len(got.addressable_shards) == shards
+    for shard, device in zip(got.addressable_shards, mesh.devices.flat):
+        assert shard.device == device
+        assert shard.data.shape == (nt // shards,) + want.shape[1:]
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      want[shard.index])
+
+
+def test_a_cell_of_an_earlier_shard_is_dropped_not_wrapped_round():
+    """Rebased below zero a tile index would wrap round in ``.at[]`` and
+    land in the shard's last tiles."""
+    from cfk_tpu.parallel.mesh import make_mesh
+    from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
+
+    cells = np.array([[0], [2], [0], [5]], np.int32)  # tile 0 only
+    got = serve_seen_tiles_sharded(make_mesh(4), jnp.asarray(cells), None,
+                                   shape=(8, 4, 16), tile_m=TILE)
+    want = np.full((8, 4, 16), TILE, np.int32)
+    want[0, 2, 0] = 5
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("shards", SHARDS)
+def test_table_stays_sharded_through_deltas_and_load_state(shards):
+    uf, mf, lists, rows, k = _problem("ragged")
+    rng = np.random.default_rng(9)
+    one, eng = _engine(uf, mf, lists), _engine(uf, mf, lists, shards=shards)
+    placed = eng._table[0].sharding
+    delta_rows = np.array([0, 255, 256, 999, 4000, -1])
+    delta = rng.standard_normal((6, RANK)).astype(np.float32)
+    for e in (one, eng):
+        assert e.apply_movie_deltas(delta_rows, delta) == 4
+    assert eng._table[0].sharding == placed
+    for a, b in zip(one.topk(rows, k), eng.topk(rows, k)):
+        np.testing.assert_array_equal(a, b)
+    uf2 = rng.standard_normal(uf.shape).astype(np.float32)
+    mf2 = rng.standard_normal(mf.shape).astype(np.float32)
+    for e in (one, eng):
+        e.load_state(uf2, mf2, epoch=3)
+    assert eng._table[0].sharding == placed and eng.table_swaps == 1
+    assert len(eng._table[0].addressable_shards) == shards
+    for a, b in zip(one.topk(rows, k), eng.topk(rows, k)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_shards_and_mesh_are_one_choice():
+    from cfk_tpu.parallel.mesh import make_mesh
+
+    uf, mf, lists, *_ = _problem("ragged")
+    with pytest.raises(ValueError, match="mesh/shards"):
+        _engine(uf, mf, lists, shards=2, mesh=make_mesh(2))
+    with pytest.raises(ValueError, match="devices"):
+        _engine(uf, mf, lists, shards=64)
+    assert _engine(uf, mf, lists).mesh is None
+
+
+@pytest.mark.parametrize("table_dtype,operands", [("float32", 3), ("int8", 4)])
+def test_shard_program_takes_the_operands_there_are(table_dtype, operands):
+    """No scales placeholder: a float table's shard program has ``u``, the
+    table and the rectangle; an int8 table's has its scales too."""
+    from cfk_tpu.parallel import spmd
+
+    uf, mf, lists, rows, k = _problem("ragged")
+    eng = _engine(uf, mf, lists, shards=2, table_dtype=table_dtype)
+    seen = []
+    real = spmd._serve_topk_sharded_fn
+
+    def spy(*key):
+        fn = real(*key)
+        return lambda *ops: seen.append(len(ops)) or fn(*ops)
+
+    spmd_fn = spmd._serve_topk_sharded_fn
+    try:
+        spmd._serve_topk_sharded_fn = spy
+        eng.topk(rows, k)
+        eng.topk(rows, k, exclude_seen=False)
+    finally:
+        spmd._serve_topk_sharded_fn = spmd_fn
+    assert seen == [operands, operands - 1]
+
+
+def test_prewarm_counts_the_shard_programs_and_closes_the_set():
+    uf, mf, lists, rows, k = _problem("ragged")
+    eng = _engine(uf, mf, lists, shards=4, tile_m=32)  # shapes of its own
+    warm = eng.prewarm(8, max_batch=16)
+    # per rung: the scorer, the slice build, the build onto a donated slice
+    assert warm["programs"] == 2 and warm["new_traces"] == 6
+    before = engine_mod.trace_count()
+    eng.topk(rows, 8)
+    eng.topk(rows[:5], 8)
+    assert engine_mod.trace_count() == before
+
+
+def test_spans_of_a_sharded_engine():
+    uf, mf, lists, rows, k = _problem("ragged")
+    tracer = telemetry.configure()
+    try:
+        eng = _engine(uf, mf, lists, shards=4)
+        eng.topk(rows, k)
+        events = {e["name"]: e for e in tracer.events() if e.get("ph") == "X"}
+    finally:
+        telemetry.shutdown(write=False)
+    up = events["serve/engine/table_upload"]["args"]
+    assert up == {"shards": 4, "rows_per_shard": 256,
+                  "bytes": 1024 * RANK * 4}
+    cells = int(sum(len(lists[r]) for r in rows))
+    seen = events["serve/batch/seen_tiles"]["args"]
+    assert seen["cells"] == cells == sum(seen["shard_cells"])
+    assert len(seen["shard_cells"]) == 4
+    batch = events["serve/batch/upload"]["args"]
+    assert batch["shards"] == 4
+    assert batch["bytes"] == 16 * RANK * 4 + 4 * 16 * 16 * 4
+    assert batch["replicated_bytes"] == 4 * batch["bytes"]
+    compute = events["serve/batch/compute"]["args"]
+    assert compute["shards"] == 4 and compute["merge_candidates"] == 4 * k
+    # a one-device engine's spans carry none of it
+    tracer = telemetry.configure()
+    try:
+        _engine(uf, mf, lists).topk(rows, k)
+        events = {e["name"]: e for e in tracer.events() if e.get("ph") == "X"}
+    finally:
+        telemetry.shutdown(write=False)
+    assert "serve/engine/table_upload" not in events
+    assert "shards" not in events["serve/batch/upload"]["args"]
+    assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
+    assert set(events["serve/batch/compute"]["args"]) == {"n", "b", "k"}

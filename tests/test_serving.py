@@ -346,7 +346,7 @@ def test_engine_answers_equal_dense_oracle_through_device_rectangle(
     np.testing.assert_array_equal(ids, np.asarray(ki)[: len(rows)])
 
 
-def host_built_seen_tiles(engine, chunks, shape):
+def host_built_seen_tiles(engine, chunks, shape, mesh=None):
     """``ServeEngine._seen_tiles`` as the serve path had it before the
     device built the rectangle: numpy fills it, the whole of it is
     uploaded."""
