@@ -80,17 +80,30 @@ def emulate_in_kernel_gather(table, nb, wt, ct):
 def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
                         tile_m, row_offset=0):
     """XLA twin of the serving score+top-K kernel — the sharded-interpret
-    route, so CPU CI exercises the same code shape the Mosaic kernel runs.
+    route, so CPU CI exercises the same code shape the Mosaic kernel runs:
+    ``emulate_topk_counted`` without the counts, as
+    ``serving.topk_kernel.topk_scores_pallas`` is of its counted form."""
+    return emulate_topk_counted(
+        u, table, scale, seen_tiles, k_top=k_top, num_movies=num_movies,
+        tile_m=tile_m, row_offset=row_offset,
+    )[:2]
+
+
+def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
+                         tile_m, row_offset=0):
+    """XLA twin of ``serving.topk_kernel.topk_scores_counted``: (scores,
+    movie rows, [selection rounds, tiles that ran one]).
 
     Scans the SAME per-tile fold the kernel body runs
     (``serving.topk_kernel._score_tile_fold`` — one shared function, the
     same twin discipline as the Gram kernels) over the same movie tiles in
-    the same order, carrying the same [B, K] selection — so kernel and
-    twin are BIT-IDENTICAL on this route (``tests/test_serving.py`` pins
-    it).  Crucially the scan's per-step block is [B, tile_m]: no
-    [B, num_movies] score matrix is ever materialized here either (the
-    emulation-path memory check in the tests compiles this and bounds its
-    temp memory below B·M·4 bytes).
+    the same order, carrying the same sorted [K, B] selection and gating
+    each tile's selection rounds on the carry's K-th score as the kernel
+    does — so kernel and twin are BIT-IDENTICAL on this route, counts
+    included (``tests/test_serving.py`` pins it).  Crucially the scan's
+    per-step block is [B, tile_m]: no [B, num_movies] score matrix is ever
+    materialized here either (the emulation-path memory check in the tests
+    compiles this and bounds its temp memory below B·M·4 bytes).
     """
     import jax.numpy as jnp
 
@@ -107,7 +120,8 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
     carry0 = jax.tree.map(
         lambda z: match_varying(z, table),
         (jnp.full((k_top, b), -jnp.inf, jnp.float32),
-         jnp.full((k_top, b), -1, jnp.int32)),
+         jnp.full((k_top, b), -1, jnp.int32),
+         jnp.zeros(2, jnp.int32)),
     )
 
     off = jnp.asarray(row_offset, jnp.int32)
@@ -115,7 +129,7 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
     def step(carry, i):
         idx = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
         seen_i = None if seen is None else idx(seen)
-        v, ids = _score_tile_fold(
+        v, ids, rounds = _score_tile_fold(
             carry[0], carry[1], u, idx(tbl),
             None if sc is None else idx(sc),
             None if seen is None else (
@@ -125,10 +139,12 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
             off + i * tile_m,
             num_movies=num_movies, k_top=k_top,
         )
-        return (v, ids), None
+        counts = carry[2] + jnp.stack([rounds, (rounds > 0).astype(jnp.int32)])
+        return (v, ids, counts), None
 
-    (vals, ids), _ = lax.scan(step, carry0, jnp.arange(nt, dtype=jnp.int32))
-    return vals.T, ids.T
+    (vals, ids, counts), _ = lax.scan(
+        step, carry0, jnp.arange(nt, dtype=jnp.int32))
+    return vals.T, ids.T, counts
 
 
 def emulate_fused_gram_solve(a, b, reg, *, reg_mode, lam, lseg):

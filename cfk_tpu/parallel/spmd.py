@@ -1511,7 +1511,9 @@ def serve_topk_sharded(
     num_movies: int,
     tile_m: int = 512,
 ):
-    """Item-axis sharded score+top-K: (scores [B, K], movie rows [B, K]).
+    """Item-axis sharded score+top-K: (scores [B, K], movie rows [B, K],
+    counts [shards, 2] — each shard's selection rounds and the tiles that
+    ran any, ``topk_scores_counted``'s, left on their chips unsummed).
 
     The serving analog of the half-steps' exchange, with the direction
     reversed: the ITEM table is row-sharded over the mesh, the [B, k]
@@ -1559,7 +1561,7 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
     bucketing keeps the distinct key count small).  Operands: ``u``, the
     table, then the scales and the rectangle where the key says so."""
     from cfk_tpu.serving.engine import note_trace
-    from cfk_tpu.serving.topk_kernel import topk_scores_pallas
+    from cfk_tpu.serving.topk_kernel import topk_scores_counted
 
     def shard_fn(u_rep, tbl, *rest):
         rest = list(rest)
@@ -1570,7 +1572,7 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
         # (``_topk_shard_call.<n>``): without it the call would read
         # ``shard_map.<n>``, beside the one-device entry's ``_topk_call``
         with jax.named_scope("_topk_shard_call"):
-            v, ids = topk_scores_pallas(
+            v, ids, counts = topk_scores_counted(
                 u_rep, tbl, sc, seen,
                 k_top=k_top, num_movies=num_movies, tile_m=tile_m,
                 row_offset=off,
@@ -1578,7 +1580,8 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
         cat_v = lax.all_gather(v, AXIS, axis=1, tiled=True)
         cat_i = lax.all_gather(ids, AXIS, axis=1, tiled=True)
         mv, pos = lax.top_k(cat_v, k_top)
-        return mv[None], jnp.take_along_axis(cat_i, pos, axis=1)[None]
+        return (mv[None], jnp.take_along_axis(cat_i, pos, axis=1)[None],
+                counts[None])
 
     # Every shard merges the same all_gather'd candidates, so the merged
     # selections are equal on all of them — which jax's typing cannot say
@@ -1588,12 +1591,13 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
     sharded = _compat_shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(AXIS)) + (P(AXIS),) * (has_scale + has_seen),
-        out_specs=(P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS), P(AXIS), P(AXIS)),
     )
 
     def _topk_shard_call(*ops):
         note_trace()
-        return tuple(x[0] for x in sharded(*ops))
+        mv, ids, counts = sharded(*ops)
+        return mv[0], ids[0], counts
 
     return jax.jit(_topk_shard_call)
 

@@ -62,7 +62,7 @@ from cfk_tpu.serving.topk_kernel import (
     group_seen_cells,
     scatter_seen_cells,
     seen_cell_capacity,
-    topk_scores_pallas,
+    topk_scores_counted,
 )
 from cfk_tpu.telemetry import dump_flight, record_event, span
 
@@ -565,18 +565,24 @@ class ServeEngine:
 
                     sp.set(shards=self._shards,
                            merge_candidates=self._shards * k)
-                    vals, ids = serve_topk_sharded(
+                    vals, ids, counts = serve_topk_sharded(
                         self.mesh, u, table, scale, seen_tiles, k_top=k,
                         num_movies=self.num_movies, tile_m=self.tile_m,
                     )
                 else:
-                    vals, ids = _topk_jit_fn()(
+                    vals, ids, counts = _topk_jit_fn()(
                         u, table, scale, seen_tiles, k_top=k,
                         num_movies=self.num_movies, tile_m=self.tile_m,
                     )
-            with span("serve/batch/compute/fetch") as sp:
+            with span("serve/batch/compute/fetch") as fetch:
                 vals, ids = np.asarray(vals), np.asarray(ids)
-                sp.set(bytes=vals.nbytes + ids.nbytes)
+                # a [2] row a shard: the host adds them, no collective
+                counts = np.asarray(counts).reshape(-1, 2).sum(axis=0)
+                fetch.set(bytes=vals.nbytes + ids.nbytes)
+            # what the gated selection cost this batch, over every tile
+            # scanned (all shards'): rounds run, tiles that ran any
+            sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
+                   tiles=self.table_rows // self.tile_m)
             vals, ids = vals[:n], ids[:n]
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids
@@ -844,7 +850,7 @@ def note_trace() -> None:
 
 def _topk_call(u, table, scale, seen_tiles, *, k_top, num_movies, tile_m):
     _TRACES[0] += 1
-    return topk_scores_pallas(
+    return topk_scores_counted(
         u, table, scale, seen_tiles, k_top=k_top, num_movies=num_movies,
         tile_m=tile_m,
     )

@@ -30,18 +30,29 @@ every reduction runs along sublanes, the forms Mosaic lowers:
   299 MB at 18,262 tiles × 256 slots × 16 — is filled and scattered on the
   device (``scatter_seen_cells``), and ``build_seen_tiles`` is the same
   rectangle in numpy, the tests' oracle,
-- K-selection merge: K rounds of "largest remaining value, earliest
-  position" over [carry ‖ tile] — equal scores resolve to the carry, then
-  to the lower slot/row, making tie order deterministic (``lax.top_k``
-  has no Mosaic lowering; neither has ``dynamic_slice`` on values).
+- K-selection merge, gated: the [K, B] carry is kept sorted (descending;
+  equal scores by ascending row; empty slots at the tail), so its last
+  row is each user's K-th score.  One pass takes the tile's per-user
+  maximum, and only while some user's maximum is strictly above its K-th
+  score does a selection round run: "largest remaining value, earliest
+  position" inserted into the sorted carry by a one-sublane shift.  Equal
+  scores resolve to the carry, then to the lower row, making tie order
+  deterministic — the stable order of a top-k over [carry ‖ tile]
+  (``lax.top_k`` has no Mosaic lowering; neither has ``dynamic_slice`` on
+  values).  A stream in no particular order changes a user's top-K about
+  K/i times in tile i, so most tiles cost the gating pass and no round;
+  the kernel counts the rounds it ran and the tiles that ran any
+  (``topk_scores_counted``; ``ServeEngine.topk`` puts them on its compute
+  span).
 
 The merge step (``_score_tile_fold``) is ONE function shared by the Mosaic
-kernel body and the XLA twin (``compat.emulate_topk_scores`` scans it over
-the same tiles), so the two routes are bit-identical on the interpret path
-— the same twin discipline as the Gram kernels.  The kernel compiles for
-the v5e at f32/bf16/int8 (``tests/test_chip_compile.py``) and matches the
-twin on the chip (``tests/test_pallas_tpu.py``); how fast the K selection
-rounds are there is not measured (ROADMAP S7).
+kernel body and the XLA twin (``compat.emulate_topk_counted`` scans it over
+the same tiles), so the two routes are bit-identical on the interpret path,
+counts included — the same twin discipline as the Gram kernels.  The
+kernel compiles for the v5e at f32/bf16/int8 and under the 2×2 shard_map
+(``tests/test_chip_compile.py``) and matches the twin on the chip
+(``tests/test_pallas_tpu.py``); what it costs there is in PERF.md
+(sections 5 and 6, PR 27).
 """
 
 from __future__ import annotations
@@ -85,7 +96,9 @@ def serve_compute_dtype(table_dtype):
 
 def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
                      tile_base, *, num_movies, k_top):
-    """Fold one movie tile into the running top-K carry.
+    """Fold one movie tile into the running top-K carry: ``(carry_v,
+    carry_i, rounds)``, ``rounds`` the int32 count of selection rounds this
+    tile needed (0 for most tiles).
 
     The ONE copy of the per-tile math — the Mosaic kernel body and the XLA
     twin both call exactly this.  Everything is MOVIE-MAJOR ([T, B] scores,
@@ -101,12 +114,20 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     (T = padding) for j < ``seen_width`` (None = no exclusion),
     tile_base scalar int32.
 
-    Selection is K rounds of "largest remaining value, earliest position":
-    a carry slot beats a tile row at equal score and lower slots/rows beat
-    higher ones — the stable order of a top-k over [carry ‖ tile].  Tile
-    rows are consumed by overwriting them with −inf; carry slots by a
-    ``taken`` mask, so that −inf ties (fewer than K candidates) walk the
-    empty carry slots in order and the tail ids stay −1.
+    The carry is SORTED: scores descending, equal scores by ascending
+    global row, empty slots (−inf / −1) at the tail — so its last row is
+    each user's K-th score, and a tile row can enter a user's top-K only
+    by beating it.  After the masks one pass takes the tile's per-user
+    maximum; while any user's maximum is STRICTLY above its K-th score, a
+    selection round inserts that maximum (earliest row holding it) into
+    the sorted carry behind every value ≥ it — a shift by one sublane —
+    drops the last slot, and consumes the tile row by overwriting it with
+    −inf.  At equal score the carry (an earlier row) stays ahead and a
+    −inf row never enters, which is the stable order of a top-k over
+    [carry ‖ tile] and keeps the −1 tail when fewer than K candidates
+    exist.  The number of rounds is decided by the data: a tile no row of
+    which can enter costs the one gating pass, the worst case (scores
+    ascending along the table) min(K, T) rounds.
     """
     t = tile.shape[0]
     b = u.shape[0]
@@ -136,43 +157,38 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
 
         scores = lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk,
                                scores)
-    slot = lax.broadcasted_iota(jnp.int32, (k_top, b), 0)
+    first = lax.broadcasted_iota(jnp.int32, (k_top, b), 0) == 0
 
-    def select(j, state):
-        sc, taken, out_v, out_i = state
-        free = taken == 0
-        mc = jnp.max(jnp.where(free, carry_v, neg), axis=0, keepdims=True)
-        ms = jnp.max(sc, axis=0, keepdims=True)  # [1, B]
-        pos_c = jnp.min(jnp.where(free & (carry_v == mc), slot, k_top),
-                        axis=0, keepdims=True)
-        pos_s = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
-        from_carry = mc >= ms
-        hit_c = from_carry & (slot == pos_c)
-        id_c = jnp.sum(jnp.where(slot == pos_c, carry_i, 0), axis=0,
-                       keepdims=True)
-        new_v = jnp.where(from_carry, mc, ms)
-        new_i = jnp.where(from_carry, id_c, tile_base + pos_s)
-        sc = jnp.where(jnp.logical_not(from_carry) & (row == pos_s), neg, sc)
-        taken = jnp.where(hit_c, 1, taken)
-        out_v = jnp.where(slot == j, new_v, out_v)
-        out_i = jnp.where(slot == j, new_i, out_i)
-        return sc, taken, out_v, out_i
+    def tile_max(sc):
+        return jnp.max(sc, axis=0, keepdims=True)  # [1, B]
 
-    # Every output slot is written once in K rounds, so the loop state
-    # starts from constants, not from the carry: inside a compiled kernel
-    # under shard_map a value read from an OUTPUT ref keeps the out_shape's
-    # vma while everything computed from it has none (jax 0.9.0), and a
-    # carry seeded with one could not typecheck.  Under shard_map's own
-    # tracing (the twin's sharded route) the state varies over the mesh
-    # like the tile's scores do.
-    init = jax.tree.map(
-        lambda z: match_varying(z, scores),
-        (jnp.zeros((k_top, b), jnp.int32),
-         jnp.full((k_top, b), neg, jnp.float32),
-         jnp.full((k_top, b), -1, jnp.int32)),
-    )
-    _, _, new_v, new_i = lax.fori_loop(0, k_top, select, (scores, *init))
-    return new_v, new_i
+    def entrant(state):
+        _, cv, _, ms, _ = state
+        return jnp.max((ms > cv[k_top - 1:]).astype(jnp.int32)) > 0
+
+    def select(state):
+        sc, cv, ci, ms, rounds = state
+        pos = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
+        # Slots whose value is >= the entrant stay; the entrant lands in
+        # the first slot that is not, and the rest move one sublane down.
+        # A user with nothing to enter (ms <= its K-th score) keeps every
+        # slot, so it needs no mask of its own — and its consumed tile row
+        # could not have entered later either (the K-th score only rises).
+        stay = cv >= ms
+        up_v, up_i = pltpu.roll(cv, 1, 0), pltpu.roll(ci, 1, 0)
+        here = first | (up_v >= ms)
+        cv = jnp.where(stay, cv, jnp.where(here, ms, up_v))
+        ci = jnp.where(stay, ci, jnp.where(here, tile_base + pos, up_i))
+        sc = jnp.where(row == pos, neg, sc)
+        return sc, cv, ci, tile_max(sc), rounds + 1
+
+    # Under shard_map's own tracing (the twin's sharded route) the loop
+    # state varies over the mesh like the tile's scores do.
+    rounds = match_varying(jnp.int32(0), scores)
+    _, carry_v, carry_i, _, rounds = lax.while_loop(
+        entrant, select,
+        (scores, carry_v, carry_i, tile_max(scores), rounds))
+    return carry_v, carry_i, rounds
 
 
 def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
@@ -283,34 +299,50 @@ def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
                  seen_width, with_scale):
     """Grid step i: fold movie tile i into the resident [K, B] carry.
 
-    The outputs are the carry (constant-index resident blocks, the Gram
-    kernels' accumulation idiom): step 0 initializes them, every step
-    merges its tile, the final state IS the result.  ``off_ref`` (scalar-
+    The carry is two VMEM scratch blocks: step 0 initializes them, every
+    step merges its tile, the last step copies the final state to the
+    (constant-index, resident) output blocks.  ``off_ref`` (scalar-
     prefetched, [1] int32) is the shard's global row offset — 0 on a
     single device; under item-axis sharding each shard's tile i covers
-    global movie rows [off + i·T, off + (i+1)·T).
+    global movie rows [off + i·T, off + (i+1)·T).  ``counts_ref`` (SMEM,
+    [2] int32) accumulates the selection rounds run and the tiles that
+    ran at least one.
     """
     refs = list(refs)
     scale_ref = refs.pop(0) if with_scale else None
     seen_ref = refs.pop(0) if seen_width else None
-    vals_ref, ids_ref = refs
+    vals_ref, ids_ref, counts_ref, cv_ref, ci_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        vals_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
-        ids_ref[...] = jnp.full((k_top, b), -1, jnp.int32)
+        cv_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
+        ci_ref[...] = jnp.full((k_top, b), -1, jnp.int32)
+        counts_ref[0] = 0
+        counts_ref[1] = 0
 
-    new_v, new_i = _score_tile_fold(
-        vals_ref[...], ids_ref[...], u_ref[...], tbl_ref[...],
+    # The carry lives in scratch, not in the output blocks: the selection
+    # loop's state starts from it, and inside a compiled kernel under
+    # shard_map a value read from an OUTPUT ref keeps the out_shape's vma
+    # while everything computed from it has none (jax 0.9.0) — a loop
+    # seeded with one could not typecheck.
+    new_v, new_i, rounds = _score_tile_fold(
+        cv_ref[...], ci_ref[...], u_ref[...], tbl_ref[...],
         scale_ref[...] if scale_ref is not None else None,
         (lambda j: seen_ref[0, pl.ds(j, 1), :]) if seen_width else None,
         seen_width,
         off_ref[0] + i * t,
         num_movies=num_movies, k_top=k_top,
     )
-    vals_ref[...] = new_v
-    ids_ref[...] = new_i
+    cv_ref[...] = new_v
+    ci_ref[...] = new_i
+    counts_ref[0] += rounds
+    counts_ref[1] += (rounds > 0).astype(jnp.int32)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        vals_ref[...] = new_v
+        ids_ref[...] = new_i
 
 
 def topk_scores_pallas(
@@ -336,6 +368,18 @@ def topk_scores_pallas(
     path (``parallel.spmd.serve_topk_sharded``) passes each shard's base
     row; ids come back global and ``num_movies`` stays the GLOBAL count.
     """
+    return topk_scores_counted(
+        u, table, scale, seen_tiles, k_top=k_top, num_movies=num_movies,
+        tile_m=tile_m, row_offset=row_offset, interpret=interpret,
+    )[:2]
+
+
+def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
+                        tile_m=512, row_offset=0, interpret=None):
+    """``topk_scores_pallas`` and what the selection cost: ``(scores, movie
+    rows, counts)``, counts [2] int32 = selection rounds run over the
+    table's tiles, and tiles that ran at least one (of ``M_pad / tile_m``).
+    What ``ServeEngine.topk`` puts on its compute span."""
     b, k = u.shape
     m_pad = table.shape[0]
     if m_pad % tile_m != 0:
@@ -365,9 +409,9 @@ def topk_scores_pallas(
         # Same routing rule as the Gram kernels: sharded-interpret runs
         # take the bit-exact XLA twin (the table is the operand that
         # varies over the mesh; the batch is replicated).
-        from cfk_tpu.compat import emulate_topk_scores
+        from cfk_tpu.compat import emulate_topk_counted
 
-        return emulate_topk_scores(
+        return emulate_topk_counted(
             u, table, scale, seen_tiles, k_top=k_top,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
@@ -389,9 +433,10 @@ def topk_scores_pallas(
         ops.append(jnp.swapaxes(seen_tiles, 1, 2))
     kwargs = {}
     if not interpret:
-        # resident carry (2× for Mosaic's output double-buffer) + one
-        # streamed tile double-buffered + the seen rectangle + the [T, B]
-        # score block and its selection temporaries + headroom
+        # the [K, B] result (2× for Mosaic's output double-buffer; the
+        # scratch carry is as large again) + one streamed tile
+        # double-buffered + the seen rectangle + the [T, B] score block
+        # and its selection temporaries + headroom
         out_bytes = 2 * b * k_top * 8
         tile_bytes = 2 * tile_m * (k + 1) * 4
         seen_bytes = 2 * b * seen_width * 4
@@ -409,7 +454,10 @@ def topk_scores_pallas(
         out_specs=[
             pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
             pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
+        scratch_shapes=[pltpu.VMEM((k_top, b), jnp.float32),
+                        pltpu.VMEM((k_top, b), jnp.int32)],
     )
     off = jnp.asarray(row_offset, jnp.int32).reshape(1)
     # under shard_map the selection varies over the mesh like the table
@@ -418,14 +466,15 @@ def topk_scores_pallas(
     mk = (lambda s, d: jax.ShapeDtypeStruct(s, d, vma=vma)) if vma else (
         lambda s, d: jax.ShapeDtypeStruct(s, d)
     )
-    vals, ids = pl.pallas_call(
+    vals, ids, counts = pl.pallas_call(
         functools.partial(
             _topk_kernel, t=tile_m, k_top=k_top, num_movies=num_movies,
             b=b, seen_width=seen_width, with_scale=scale is not None,
         ),
         grid_spec=grid_spec,
-        out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32)),
+        out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32),
+                   mk((2,), jnp.int32)),
         interpret=bool(interpret),
         **kwargs,
     )(off, *ops)
-    return vals.T, ids.T
+    return vals.T, ids.T, counts

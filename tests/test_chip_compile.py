@@ -320,20 +320,37 @@ def test_bucket_port_piece_rank64_bf16(chip, as_tpu, rows, width):
 
 # -- serving -----------------------------------------------------------------
 
-@pytest.mark.parametrize("dtype", [f32, bf16, i8])
-def test_serve_scorer_ml25m_width(chip, dtype):
-    from cfk_tpu.serving.topk_kernel import topk_scores_pallas
+def _compile_scorer(chip, dtype, b, k_top, w):
+    """The one-device scorer over the ML-25M table (59,047 × 128)."""
+    from cfk_tpu.serving.topk_kernel import topk_scores_counted
 
-    m_pad, k, b, k_top, tile_m, w = 59_392, 128, 64, 10, 512, 64
+    m_pad, k, tile_m = 59_392, 128, 512
     scale = [chip((m_pad,), f32)] if dtype == i8 else []
 
     def fn(u, tbl, seen, *sc):
-        return topk_scores_pallas(
+        return topk_scores_counted(
             u, tbl, sc[0] if sc else None, seen, k_top=k_top,
             num_movies=59_047, tile_m=tile_m, interpret=False)
 
     _compile(fn, chip((b, k), f32), chip((m_pad, k), dtype),
              chip((m_pad // tile_m, b, w), i32), *scale)
+
+
+@pytest.mark.parametrize("dtype", [f32, bf16, i8])
+def test_serve_scorer_ml25m_width(chip, dtype):
+    _compile_scorer(chip, dtype, b=64, k_top=10, w=64)
+
+
+@pytest.mark.parametrize("b,k_top", [
+    (256, 16),  # a full batch of the serve cells (K = 10 padded to 16)
+    (64, 128),  # the engine's default K = 100, padded
+    (8, 1),  # one slot: the shift is the identity
+])
+def test_serve_scorer_gated_selection_shapes(chip, b, k_top):
+    """The gated fold's loop — a ``while`` on a scalar reduced from a
+    vector compare, the one-sublane roll of the [K, B] carry, the SMEM
+    counts — at the carry heights and batch widths the server asks for."""
+    _compile_scorer(chip, f32, b=b, k_top=k_top, w=16)
 
 
 @pytest.mark.parametrize("m,k_top,b,dtype", [

@@ -349,25 +349,43 @@ def test_dense_gather_f32_rank128_compiled():
     np.testing.assert_array_equal(np.asarray(rows_c), np.asarray(rows_i))
 
 
+@pytest.mark.parametrize("order", ["random", "ascending", "equal"])
 @pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
-def test_topk_compiled_matches_twin(table_dtype):
-    """The serve scorer compiled (movie-major fold, K rounds of max
-    selection) against its XLA twin: the same ids wherever scores are not
-    within round-off of each other, the same scores to MXU tolerance."""
-    from cfk_tpu.compat import emulate_topk_scores
+def test_topk_compiled_matches_twin(table_dtype, order):
+    """The serve scorer compiled (movie-major fold, selection rounds gated
+    on the carry's K-th score) against its XLA twin: the same ids wherever
+    scores are not within round-off of each other, the same scores to MXU
+    tolerance.  ``ascending`` makes every tile enter every user's top-K
+    (the most rounds the gate can ask for) with scores that are exact in
+    a float table, so compiled and twin agree to the bit; ``equal``
+    makes every score of a user the same, so the ids are its K lowest
+    unseen rows whatever the precision."""
+    from cfk_tpu.compat import emulate_topk_counted
     from cfk_tpu.ops.quant import quantize_table
     from cfk_tpu.serving.topk_kernel import (
         build_seen_tiles,
-        topk_scores_pallas,
+        topk_scores_counted,
     )
 
     rng = np.random.default_rng(3)
     m, k, b, k_top, tile = 2_000, 128, 64, 10, 512
     m_pad = -(-m // tile) * tile
     tbl = np.zeros((m_pad, k), np.float32)
-    tbl[:m] = rng.standard_normal((m, k)).astype(np.float32)
+    u = rng.standard_normal((b, k)).astype(np.float32)
+    if order == "random":
+        tbl[:m] = rng.standard_normal((m, k)).astype(np.float32)
+    elif order == "ascending":
+        # row r scores 2^e_b · (r + 1): two small integers a row, one
+        # power of two a user — products and the one sum are exact
+        tbl[:m, 0] = np.arange(1, m + 1) // 64
+        tbl[:m, 1] = np.arange(1, m + 1) % 64
+        u = np.zeros((b, k), np.float32)
+        u[:, 1] = 2.0 ** rng.integers(-3, 4, b)
+        u[:, 0] = 64 * u[:, 1]
+    else:
+        tbl[:m] = rng.standard_normal(k).astype(np.float32)
     data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
-    u = jnp.asarray(rng.standard_normal((b, k)).astype(np.float32))
+    u = jnp.asarray(u)
     seen = [np.sort(rng.choice(m, size=int(rng.integers(0, 40)),
                                replace=False)).astype(np.int32)
             for _ in range(b)]
@@ -377,8 +395,9 @@ def test_topk_compiled_matches_twin(table_dtype):
         np.concatenate(seen), indptr, np.arange(b), num_movies=m,
         tile_m=tile))
     kw = dict(k_top=k_top, num_movies=m, tile_m=tile)
-    v_c, i_c = topk_scores_pallas(u, data, scale, st, interpret=False, **kw)
-    v_t, i_t = emulate_topk_scores(u, data, scale, st, **kw)
+    v_c, i_c, n_c = topk_scores_counted(u, data, scale, st, interpret=False,
+                                        **kw)
+    v_t, i_t, n_t = emulate_topk_counted(u, data, scale, st, **kw)
     v_c, i_c, v_t, i_t = map(np.asarray, (v_c, i_c, v_t, i_t))
     tol = 2e-2 if table_dtype == "bfloat16" else 2e-3
     np.testing.assert_allclose(v_c, v_t, rtol=tol, atol=tol)
@@ -386,8 +405,28 @@ def test_topk_compiled_matches_twin(table_dtype):
     for row in range(b):
         assert not set(i_c[row].tolist()) & set(seen[row].tolist())
         assert (i_c[row] >= 0).all() and (i_c[row] < m).all()
-    # ids agree except where two candidates are within the tolerance
-    assert (i_c == i_t).mean() > 0.95
+    if order == "random":
+        # ids agree except where two candidates are within the tolerance
+        assert (i_c == i_t).mean() > 0.95
+        return
+    unseen = [np.setdiff1d(np.arange(m), s) for s in seen]
+    if order == "ascending":
+        # every tile enters: K rounds each (the last has 464 real rows)
+        assert np.asarray(n_c).tolist() == [k_top * 4, 4]
+        assert np.asarray(n_t).tolist() == [k_top * 4, 4]
+        if table_dtype == "int8":
+            # the codes round: neither exact sums nor the strict order hold
+            assert (i_c == i_t).mean() > 0.95
+            return
+        want = np.stack([x[::-1][:k_top] for x in unseen])
+        np.testing.assert_array_equal(i_c, want)
+        np.testing.assert_array_equal(v_c, v_t)
+    else:
+        want = np.stack([x[:k_top] for x in unseen])
+        np.testing.assert_array_equal(i_c, want)
+        assert np.asarray(n_c).tolist() == [k_top, 1]
+    np.testing.assert_array_equal(i_c, i_t)
+    assert np.asarray(n_c).tolist() == np.asarray(n_t).tolist()
 
 
 def _seen_problem(rng, users, movies, longest):

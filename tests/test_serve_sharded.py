@@ -265,6 +265,10 @@ def test_spans_of_a_sharded_engine():
     assert batch["replicated_bytes"] == 4 * batch["bytes"]
     compute = events["serve/batch/compute"]["args"]
     assert compute["shards"] == 4 and compute["merge_candidates"] == 4 * k
+    # the gated selection's counts, added up over the four shards' tiles
+    assert compute["tiles"] == 1024 // eng.tile_m
+    assert 4 <= compute["select_tiles"] <= compute["tiles"]
+    assert compute["select_tiles"] <= compute["select_rounds"]
     # a one-device engine's spans carry none of it
     tracer = telemetry.configure()
     try:
@@ -275,4 +279,5 @@ def test_spans_of_a_sharded_engine():
     assert "serve/engine/table_upload" not in events
     assert "shards" not in events["serve/batch/upload"]["args"]
     assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
-    assert set(events["serve/batch/compute"]["args"]) == {"n", "b", "k"}
+    assert set(events["serve/batch/compute"]["args"]) == {
+        "n", "b", "k", "select_rounds", "select_tiles", "tiles"}

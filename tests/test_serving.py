@@ -179,6 +179,134 @@ def test_row_offset_split_merges_to_whole(rng):
     np.testing.assert_array_equal(np.asarray(mi), np.asarray(i))
 
 
+# -- the gated selection on the orders that stress the gate ------------------
+#
+# Every score is ONE exact product: the table's only non-zero column holds a
+# small integer g(row) (exact in bfloat16; an int8 row dequantizes to one f32
+# value, which the oracle reads back), the user's entry in that column is a
+# power of two.  So the numpy oracle — a stable sort by score descending, row
+# ascending — gives the answer to the bit in every table dtype, and nothing
+# of the fold is used to compute it.
+
+_GATE_M, _GATE_TILE, _GATE_RANK = 70, 16, 16  # 5 tiles, the last 6 real rows
+GATE_ORDERS = ("ascending", "descending", "equal", "few", "seen_entrants",
+               "last_tile")
+
+
+def _gate_problem(order, b, k_top):
+    """(u, table, seen lists) of one adversarial order."""
+    m, tile = _GATE_M, _GATE_TILE
+    rng = np.random.default_rng(b * 131 + k_top)
+    rows = np.arange(m)
+    g = {"ascending": rows + 1.0,  # every tile enters every top-K
+         "descending": m - rows + 0.0,  # only the first tile does
+         "equal": np.ones(m),  # ties: the K lowest unseen rows
+         "few": rows % 7 + 1.0,
+         "seen_entrants": rows + 1.0,
+         # one tile of winners early on, then nothing until the last two
+         # real rows of the padded last tile
+         "last_tile": np.where(rows >= m - 2, 150.0 + rows,
+                               np.where(rows < tile, 60.0 - rows, 1.0)),
+         }[order]
+    m_pad = -(-m // tile) * tile
+    tbl = np.zeros((m_pad, _GATE_RANK), np.float32)
+    tbl[:m, 3] = g
+    tbl[m:, 3] = 200.0  # padding rows would win if the mask let them
+    u = rng.standard_normal((b, _GATE_RANK)).astype(np.float32)
+    u[:, 3] = 2.0 ** rng.integers(-2, 3, b)
+    if order == "few":
+        # user i keeps i % (K + 1) candidates: from none to exactly K
+        seen = [np.sort(rng.permutation(m)[i % (k_top + 1):])
+                for i in range(b)]
+    elif order == "seen_entrants":
+        # the best rows of every tile are seen cells, and a few more
+        best = rows[rows % tile >= tile - 3]
+        seen = [np.union1d(best, rng.choice(m, size=i % 5, replace=False))
+                for i in range(b)]
+    else:
+        seen = [np.sort(rng.choice(m, size=int(rng.integers(0, 6)),
+                                   replace=False)) for _ in range(b)]
+    return u, tbl, [x.astype(np.int32) for x in seen]
+
+
+def _gate_oracle(u, deq, seen, k_top):
+    """Stable sort by score descending, row ascending; −inf / −1 where a
+    user has fewer than K candidates."""
+    m = _GATE_M
+    vals = np.full((len(seen), k_top), -np.inf, np.float32)
+    ids = np.full((len(seen), k_top), -1, np.int32)
+    for i, s in enumerate(seen):
+        sc = u[i, 3] * deq[:m, 3]  # exact: power of two × one value
+        cand = np.setdiff1d(np.arange(m), s)
+        order = cand[np.lexsort((cand, -sc[cand]))][:k_top]
+        vals[i, :order.size], ids[i, :order.size] = sc[order], order
+    return vals, ids
+
+
+@functools.lru_cache(maxsize=None)
+def _gate_programs(table_dtype, b, k_top):
+    """(kernel on the interpret path, twin), one compile per shape class."""
+    from cfk_tpu.compat import emulate_topk_counted
+    from cfk_tpu.serving.topk_kernel import topk_scores_counted
+
+    kw = dict(k_top=k_top, num_movies=_GATE_M, tile_m=_GATE_TILE)
+    return (jax.jit(functools.partial(topk_scores_counted, **kw)),
+            jax.jit(functools.partial(emulate_topk_counted, **kw)))
+
+
+def _gate_run(order, table_dtype, b, k_top, exclude=True):
+    from cfk_tpu.ops.quant import dequantize_table, quantize_table
+
+    u, tbl, seen = _gate_problem(order, b, k_top)
+    if not exclude:
+        seen = [np.zeros(0, np.int32)] * b
+    data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
+    deq = np.asarray(dequantize_table(data, scale), np.float32)
+    indptr = np.zeros(b + 1, np.int64)
+    indptr[1:] = np.cumsum([x.size for x in seen])
+    st = jnp.asarray(build_seen_tiles(
+        np.concatenate(seen), indptr, np.arange(b), num_movies=_GATE_M,
+        tile_m=_GATE_TILE))
+    assert st.shape == (5, b, 16)  # one compiled shape per (dtype, B, K)
+    outs = [tuple(map(np.asarray, fn(jnp.asarray(u), data, scale, st)))
+            for fn in _gate_programs(table_dtype, b, k_top)]
+    return outs, _gate_oracle(u, deq, seen, k_top)
+
+
+@pytest.mark.parametrize("k_top", [1, 10, 16])
+@pytest.mark.parametrize("b", [8, 64, 256])
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("order", GATE_ORDERS)
+def test_gated_fold_equals_stable_sort_oracle(order, table_dtype, b, k_top):
+    """Kernel (interpret path) and twin against the numpy oracle, to the
+    bit: values, ids and their order."""
+    (kernel, twin), (want_v, want_i) = _gate_run(order, table_dtype, b, k_top)
+    for got_v, got_i, _ in (kernel, twin):
+        np.testing.assert_array_equal(got_i, want_i)
+        np.testing.assert_array_equal(got_v, want_v)
+    np.testing.assert_array_equal(kernel[2], twin[2])
+    if order == "few":  # the −1 tail sits behind the candidates there are
+        have = np.arange(b) % (k_top + 1)
+        np.testing.assert_array_equal(
+            kernel[1] >= 0, np.arange(k_top)[None, :] < have[:, None])
+
+
+@pytest.mark.parametrize("k_top", [1, 10, 16, 24])
+@pytest.mark.parametrize("order", ["ascending", "descending", "equal"])
+def test_selection_counts_are_what_the_order_implies(order, k_top):
+    """[rounds run, tiles that ran any] with no exclusion: ascending scores
+    make every tile replace the whole carry (min(K, real rows) rounds
+    each), descending or equal ones only fill it (K rounds over the first
+    ceil(K / T) tiles, then every gate stays shut) — and kernel and twin
+    count alike."""
+    (kernel, twin), _ = _gate_run(order, "float32", 8, k_top, exclude=False)
+    real = [16, 16, 16, 16, 6]  # rows of each tile below num_movies
+    want = ([sum(min(k_top, r) for r in real), 5] if order == "ascending"
+            else [k_top, -(-k_top // 16)])  # the tiles that fill the carry
+    assert kernel[2].tolist() == want
+    assert twin[2].tolist() == want
+
+
 @pytest.mark.parametrize("shards", [2, 4])
 def test_sharded_serve_equals_single_shard(rng, shards):
     from cfk_tpu.parallel.mesh import make_mesh
@@ -201,7 +329,7 @@ def test_sharded_serve_equals_single_shard(rng, shards):
     u, tbl = jnp.asarray(u), jnp.asarray(tbl)
     kw = dict(k_top=7, num_movies=m, tile_m=tile)
     v1, i1 = topk_scores_pallas(u, tbl, None, st, **kw)
-    v2, i2 = serve_topk_sharded(make_mesh(shards), u, tbl, None, st, **kw)
+    v2, i2, _ = serve_topk_sharded(make_mesh(shards), u, tbl, None, st, **kw)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
