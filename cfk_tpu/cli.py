@@ -60,7 +60,7 @@ def _parse_tcp_url(url: str, topic_optional: bool = False) -> tuple[str, int, st
     return host, int(port_s), topic or (None if topic_optional else RATINGS_TOPIC)
 
 
-AUTO_LAYOUT_TILED_NNZ = 2_000_000  # above this, tiled wins (BASELINE.md)
+AUTO_LAYOUT_TILED_NNZ = 2_000_000  # a guess no chip run has tested (PERF.md §8)
 
 
 def _resolve_auto_layout(coo, algorithm="als", solve_chunk=None) -> str:
@@ -70,7 +70,7 @@ def _resolve_auto_layout(coo, algorithm="als", solve_chunk=None) -> str:
     rest of the invocation: an explicit (deprecated) --solve-chunk only
     means anything on the padded layout, and the subspace optimizers
     (als++/ials++) need padded/bucketed — bucketed is their at-scale
-    layout (what bench.py's subspace path uses)."""
+    layout."""
     if solve_chunk is not None:
         return "padded"
     big = coo.num_ratings >= AUTO_LAYOUT_TILED_NNZ
@@ -832,7 +832,7 @@ def _serve(args) -> int:
     - without --broker, runs the built-in open-loop load generator
       against an in-memory log (--loadgen-qps/--loadgen-requests) and
       prints the measured QPS/p50/p99 row — the self-contained smoke
-      (the recorded-at-scale numbers live in ``bench.py --serve``).
+      (the chip's numbers are the benchmark's: BENCHMARK.json, PERF.md).
 
     ``--metrics-port`` makes the server answer ``GET /metrics``
     (Prometheus text) while it serves; ``--trace-dir`` writes the host
@@ -1400,8 +1400,8 @@ def _plan_cmd(args) -> int:
     if args.autotune:
         if args.serve:
             raise ValueError(
-                "--autotune measures the training iteration; warm the "
-                "serve cache with perf_lab --serve --plan autotune"
+                "--autotune measures the training iteration; it has no "
+                "serve measurement"
             )
         from cfk_tpu.plan.autotune import measure_with_training
 
@@ -1599,8 +1599,8 @@ def build_parser() -> argparse.ArgumentParser:
         "'pool' (= 'auto', the default) overlaps every shard's window "
         "staging — store gather, host quantize, checksum, device_put — "
         "on a bounded thread pool across shards and windows; 'serial' "
-        "pins the one-thread double buffer (the bench.py --staging-ab "
-        "baseline).  Factors are crc-identical across the knob",
+        "pins the one-thread double buffer (the A/B baseline).  "
+        "Factors are crc-identical across the knob",
     )
     t.add_argument(
         "--hot-rows", type=int, default=None, metavar="F",

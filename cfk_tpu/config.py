@@ -207,7 +207,7 @@ class ALSConfig:
     # current block's Gram consumes it) and chunk scans prefetch chunk c+1's
     # neighbor-factor gather while chunk c solves (cfk_tpu.ops.pipeline).
     # False pins the serial reference schedule (each phase drains before
-    # the next starts) — the measurement baseline of bench.py --overlap-ab.
+    # the next starts) — the A/B baseline.
     # Factors are bit-identical either way (tests/test_overlap.py).
     overlap: bool = True
     # Fused Gram+solve epilogue: solve each chunk's normal equations INSIDE
@@ -221,8 +221,8 @@ class ALSConfig:
     # automatic fallback to the split schedule otherwise).  False pins the
     # split Gram→HBM→solve schedule in the tiled chunk scans (factors
     # bit-exact either way — the split chunk solve keeps the one-pass
-    # reg+solve kernel, so only the round-trip toggles; the bench.py
-    # --fused-ab baseline) and gates the accum/ring paths' final fused
+    # reg+solve kernel, so only the round-trip toggles; the A/B
+    # baseline) and gates the accum/ring paths' final fused
     # reg+solve pass.  The knob does not reach the segment/bucketed/
     # padded half-steps, whose solves follow the process default
     # (ops.solve.default_fused_epilogue) only.
@@ -239,8 +239,8 @@ class ALSConfig:
     # backend + the kernels' SMEM/alignment gates, with automatic fallback
     # to the XLA-gather path otherwise — interpret/old-jax runs use the
     # emulation twin either way).  False pins the XLA-gather schedule (the
-    # bench.py --gather-ab baseline).  Factors are bit-identical across
-    # the knob (tests/test_in_kernel_gather.py).
+    # A/B baseline).  Factors are bit-identical across the knob
+    # (tests/test_in_kernel_gather.py).
     in_kernel_gather: bool | None = None
     # HBM gather-table dtype (cfk_tpu.ops.quant; approximate-computing MF,
     # arXiv 1808.03843): the RAW fixed-side table each half-iteration
@@ -260,8 +260,8 @@ class ALSConfig:
     # Elimination algorithm of the fused reg+solve kernels: "lu" (reverse
     # no-pivot LU, rank cap 128) or "gj" (Gauss-Jordan, cap 64); "auto"
     # defers to the process default (ops.pallas.solve_kernel.
-    # default_reg_solve_algo — the CFK_REG_SOLVE_ALGO env var / perf_lab
-    # --reg-solve-algo patch point).  This is a real threaded parameter
+    # default_reg_solve_algo — the CFK_REG_SOLVE_ALGO env var's patch
+    # point).  This is a real threaded parameter
     # (a jit-static on every half-step), which is how the recovery
     # ladder's GJ rung flips it now (cfk_tpu.resilience.policy) — it used
     # to ride the env var.
@@ -371,7 +371,7 @@ class ALSConfig:
     #   "pinned"   — no optimization: pins + legacy process defaults (the
     #                pre-planner behavior, still recorded as a plan).
     #   "autotune" — consult the measured-winner cache (warmed offline by
-    #                `cfk_tpu plan --autotune` / perf_lab); model fallback
+    #                `cfk_tpu plan --autotune`); model fallback
     #                with cache=miss provenance when cold.  Trainers never
     #                measure inline.
     plan: Literal["model", "pinned", "autotune"] = "model"
@@ -401,7 +401,7 @@ class ALSConfig:
     #                   d's compute (the ALX per-shard transfer pipeline's
     #                   host half; the default, like PR 1's overlap).
     #   "serial"      — the PR 10/11 single-thread double buffer (the
-    #                   measurement baseline of bench.py --staging-ab).
+    #                   A/B baseline).
     # Factors are crc-identical across the knob (the staging order never
     # changes the consumption order — tests/test_offload_sharded.py).
     staging: Literal["auto", "pool", "serial"] = "auto"

@@ -745,7 +745,7 @@ class TiledBlocks:
         worst-chunk padding — empty [lo, hi) windows).  Measured 0.113 at
         the flagship full-Netflix 64k config; the kernel walk's cost is
         per-slot, so this bounds the recoverable walk time (VERDICT r4
-        #6 — see BASELINE.md round-5 for why the residual is kept)."""
+        #6; the pre-ledger round 5 bounded it at ~4.6 ms of 627)."""
         if self.mode != "dstream" or self.num_tiles == 0:
             return 0.0
         ng, nt = self.num_groups, self.num_tiles
@@ -770,8 +770,8 @@ class TiledBlocks:
 
 
 TILED_SLICE_ROWS_DEFAULT = 1 << 17  # ≤34 MB bf16 rank-64 slice: the
-# measured fast-gather regime (BASELINE.md); perf_lab keys caches on
-# deviations from this same constant
+# fast-gather regime measured before the ledger (PERF.md §8);
+# ``data/cache.py`` keys caches on deviations from this same constant
 
 
 def build_tiled_blocks(
@@ -800,7 +800,7 @@ def build_tiled_blocks(
     (``_build_dense_stream`` — the measured explicit-ALS default at
     scale; iALS runs it too via the weighted channels, but measured
     slower than the padded stream at the ML-25M rank-128 target, see
-    BASELINE.md round-4 notes).  ``accum_chunk_elems`` overrides
+    PERF.md §8, round 4).  ``accum_chunk_elems`` overrides
     ``chunk_elems`` on a side that resolves to accum mode (the measured
     knees differ: 64k stream chunks, 256k accum chunks at Netflix shape).
     """
@@ -839,7 +839,7 @@ def build_tiled_blocks(
         # sub-stream whose neighbors live in the block it currently holds.
         # Forces accum machinery: entities recur across slices, and the
         # per-entity accumulator [E_local+1, k, k+1] must fit HBM — the
-        # ring's memory economics on TPU (see PARITY.md / BASELINE.md).
+        # ring's memory economics on TPU (see PARITY.md).
         mode = "accum"
         n_slices = num_shards
         # f_pad = _round_up(num_fixed, num_shards) above, so this divides.
@@ -1172,7 +1172,7 @@ def _build_dense_stream(
     The padded stream layout (``mode="stream"``) rounds every entity's run
     up to a multiple of T gather slots — measured 26% wasted rows on the
     full-Netflix user half, directly on the binding resource (XLA's row
-    gather engine is row-slot-bound at ~600M rows/s, BASELINE.md).  Here
+    gather engine is row-slot-bound at ~600M rows/s, PERF.md §8).  Here
     runs are padded only to 16 rows (bf16 sublane-tile alignment,
     ~3.4%), packed
     back-to-back, and tiles become [T]-row WINDOWS into the dense stream:
@@ -1522,7 +1522,7 @@ class Dataset:
         ring more).  At Netflix shape that is ring movie-half (rotate
         480k-user blocks instead of all_gathering 61 MB) + all_gather
         user-half (whose ring accumulator would be ~1 GB), the optimum
-        the exchange comparison identifies (BASELINE.md).
+        the pre-ledger exchange comparison identified.
 
         ``dense_stream`` (tiled layout) upgrades each STREAM-mode half to
         the unpadded dense layout; a half that runs in accum mode (its

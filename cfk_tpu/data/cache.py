@@ -256,11 +256,9 @@ def cached_scale_dataset(
 ) -> Dataset:
     """Build-or-load a synthetic Netflix-shaped dataset, disk-cached.
 
-    The shared steady-state measurement path of ``scripts/perf_lab.py``
-    and ``bench.py``'s headline rows: at full-corpus shapes the host-side
-    block build costs minutes while being fully deterministic for the
-    key below, so both tools key the same cache (tag format unchanged
-    from perf_lab round 2 — existing caches keep hitting).
+    What ``chip_smoke.py`` builds through: at full-corpus shapes the
+    host-side block build costs minutes while being fully deterministic
+    for the key below.
     """
     import time
 

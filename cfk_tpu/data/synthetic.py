@@ -1,7 +1,7 @@
 """Synthetic Netflix-Prize-shaped rating data for scale benchmarking.
 
 The environment has no network egress, so the full Netflix Prize /
-MovieLens-25M files of BASELINE.md cannot be downloaded; throughput at that
+MovieLens-25M files cannot be downloaded; throughput at that
 scale is instead measured on synthetic data with the same statistical shape:
 Zipf-distributed entity popularity (the reference datasets' degree
 distributions are power-law — the property that stresses the block layouts)

@@ -46,7 +46,7 @@ def mse_rmse_heldout(
     movie never appeared in training (no dense index) are dropped — their
     factors don't exist.  Streams factor-space dot products like
     ``mse_rmse_from_model``.  Used by the planted-factor quality
-    validation (bench.py --planted, tests/test_planted.py).
+    validation (tests/test_planted.py).
     """
     u, m = model.host_factors()
     um, mm = dataset.user_map, dataset.movie_map

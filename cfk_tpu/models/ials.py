@@ -6,7 +6,7 @@ c = 1 + α·r, preferences 1 at observed cells.  The global Gram YᵀY is
 computed once per half-iteration — locally per shard and ``psum``'d over the
 mesh (a [k,k] collective, the cheapest message in the whole framework).
 
-This is the BASELINE.md "MovieLens-25M implicit, rank 128" family.  The
+This is the "MovieLens-25M implicit, rank 128" family.  The
 reference has no implicit model; capability parity plus one — but the
 transport/ingest/checkpoint plumbing is shared with the explicit path.
 """
@@ -277,7 +277,7 @@ def _check_nonnegative_strengths(dataset: Dataset) -> None:
     r = dataset.coo_dense.rating
     if not r.size:
         return
-    mn = float(np.min(r))  # once — the second np.min re-scanned 100M rows
+    mn = float(np.min(r))
     if mn < 0:
         raise ValueError(
             "iALS requires non-negative interaction strengths "

@@ -41,7 +41,7 @@ staging checksum, a chaos ``StagingCrash``, anything) propagates out of
 the not-yet-started tasks and drains the running ones, so a recovery
 rollback never races a worker still reading the pre-rollback store.
 
-Accounting (the bench/perf_lab staging columns):
+Accounting (the windowed drivers' ``offload_*`` gauges):
 
 - ``stage_busy_s``   — summed wall seconds workers (or the serial caller)
   spent inside staging tasks;

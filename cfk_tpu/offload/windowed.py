@@ -871,7 +871,7 @@ def ring_windowed_half_step(
     backend = default_tiled_gram_backend()
     stage_name = _stage_dtype(fixed_store.dtype, table_dtype)
     gather = resolve_gather_mode(
-        in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        in_kernel_gather, backend, cap, nt, t, e_c + 1, k,
         table_dtype=stage_name,
     )
     int8 = stage_name == "int8"
@@ -2805,9 +2805,16 @@ def train_ials_host_window(
     windowed width-class half-steps (ISSUE 19's tentpole driver).
 
     Same math, init, and iteration order as ``models.ials.train_ials`` on
-    the same bucketed blocks — bit-exact at f32 defaults and pinned per
-    knob by ``tests/test_offload_ials.py`` (table dtype, hot cache,
-    window size, shard count).  Per half-iteration:
+    the same bucketed blocks — bit-exact against the resident trainer's
+    stepped loop (one program per half-step, as here), and against its
+    fused ``fori_loop`` wherever XLA compiles the sweep the same way
+    inside the one big program: pinned per knob by
+    ``tests/test_offload_ials.py`` (table dtype, hot cache, window size)
+    at ``block_size < rank``.  With full-rank blocks (``block_size ==
+    rank``) the fused loop sums in another order and the two agree to
+    float32 round-off only (4.8e-7 absolute after two toy iterations:
+    ``test_windowed_vs_resident_at_full_rank_blocks``).  Per
+    half-iteration:
 
         gram  = windowed_store_gram(fixed store)   # streamed YᵀY
         solve = width-class windows through the resident bucket pieces

@@ -1,7 +1,7 @@
 """Bucketed-layout kernel port — the PR 2/4 machinery on the width classes.
 
-BENCH_r05 measured the bucketed layout as the worst remaining roofline gap
-(`ialspp_ml25m` 9.94× `vs_gather_roofline`): its half-steps still ran the
+The pre-ledger record (PERF.md §8) had the bucketed layout as the worst
+remaining roofline gap: its half-steps still ran the
 original XLA schedule — materialized `fixed[nb]` gather, whole-rectangle
 Gram einsum, separate batched solve — while the tiled layout got in-kernel
 DMA gathers (PR 4) and the fused Gram+solve epilogue (PR 2).
@@ -19,14 +19,19 @@ class (the ISSUE's "per-width-class grids") the bucket walk then calls
   - ``gram_tiles_gather_pallas`` + the one-pass reg+solve kernel (split
     epilogue), or the same pair fed by an XLA-materialized stream
     (gather=xla) — the A/B axes toggle exactly what they toggle in tiled
-    land, and factors are bit-identical across both knobs because every
-    route runs the canonical ``g = table[nb]·wt`` + per-tile Gram ops
-    (CPU CI pins this through the kernels' XLA emulation twins).
+    land, and every route runs the canonical ``g = table[nb]·wt`` +
+    per-tile Gram ops.  On the CPU the routes that take a kernel's XLA
+    twin (gather=fused under either epilogue) agree to the bit; gather=xla
+    with the split epilogue feeds the stream to ``gram_tiles_pallas``,
+    whose CPU route is the kernel body under the Pallas interpreter
+    (``ops/pallas/interpret.py``), and differs from them by float32
+    summation order (7.2e-7 absolute per half-step at toy size:
+    ``tests/test_quant_table.py``).
 
 One-tile-per-entity also means the emulation twin's per-tile einsum
-``ntk,ntl->nkl`` IS the legacy whole-rectangle ``epk,epl->ekl`` — so the
-ported f32 explicit path is bit-identical to the pre-port bucketed path on
-the emulation route, not merely close.  The implicit (iALS) port uses the
+``ntk,ntl->nkl`` is the legacy whole-rectangle ``epk,epl->ekl`` contraction
+(equal to the bit on XLA:CPU where measured; two programs all the same, so
+the tests hold them to round-off).  The implicit (iALS) port uses the
 tiled layout's sqrt reparameterization (one gs = √aw·f stream instead of
 the asymmetric (c−1)-premultiplied pair), which changes last-bit rounding
 vs the legacy formulation — the same accepted trade the tiled iALS path
@@ -127,7 +132,7 @@ def resolve_bucket_modes(fused_epilogue, in_kernel_gather, solver,
     if not bucket_port_supported(rows, width, k):
         return None
     gather = resolve_gather_mode(
-        in_kernel_gather, "pallas", "full", width, 3, width, 2, k,
+        in_kernel_gather, "pallas", width, 3, width, 2, k,
         table_dtype=table_dtype,
     )
     lam_f = resolve_fused_chunk_lam(

@@ -1044,8 +1044,8 @@ def _gram_solve_tiles_dense_pallas(
 # Every half-iteration above consumes a PRE-GATHERED [C, k] stream: XLA
 # materializes fz[nb] in HBM and the kernel reads it straight back — the
 # same write+readback shape the fused epilogue removed for the [Ec, k, k]
-# A-batches, and the dominant measured roofline gap (BENCH_r05
-# vs_gather_roofline 1.88–9.94×).  The ``*_gather_pallas`` variants retire
+# A-batches, and the largest roofline gap of the pre-ledger record
+# (PERF.md §8).  The ``*_gather_pallas`` variants retire
 # that stream: the RAW fixed factor table stays in HBM/ANY memory, each
 # tile's neighbor indices ride the scalar prefetch, and the kernel DMAs
 # the indexed rows straight into a double-buffered VMEM block (group g+1's

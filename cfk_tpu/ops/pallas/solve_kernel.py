@@ -207,9 +207,8 @@ def default_reg_solve_algo() -> str:
     identically in the production chunk scan (the kernel is
     issue-rate-bound, not FLOP-bound); LU is the default because it
     extends the fused path to k=128 — one direct solve instead of the
-    blocked Schur composition.  gj kept for A/B measurement (`perf_lab
-    --reg-solve-algo` or the ``CFK_REG_SOLVE_ALGO`` env var, which also
-    flips every bench.py path).
+    blocked Schur composition.  gj is kept for the recovery ladder's
+    rung and for A/B measurement.
 
     This is only the DEFAULT: callers that thread an explicit algorithm
     (``ALSConfig.reg_solve_algo`` → the half-step dispatchers → the
@@ -225,8 +224,8 @@ def default_reg_solve_algo() -> str:
     The ``CFK_REG_SOLVE_ALGO`` env var is a DEPRECATED alias (ISSUE 9):
     the process default is a plan concern now — pin it with
     ``ALSConfig.reg_solve_algo`` / a ``PlanConstraints(reg_solve_algo=)``
-    pin / ``perf_lab --reg-solve-algo``.  A set env var still wins (so
-    old scripts keep working) but warns ONCE per process."""
+    pin.  A set env var still wins (so old scripts keep working) but
+    warns ONCE per process."""
     import os
 
     algo = os.environ.get("CFK_REG_SOLVE_ALGO")
@@ -244,9 +243,8 @@ def default_reg_solve_algo() -> str:
         warnings.warn(
             "CFK_REG_SOLVE_ALGO is deprecated: pin the elimination "
             "algorithm through the execution planner instead "
-            "(ALSConfig.reg_solve_algo, a PlanConstraints pin, or "
-            "perf_lab --reg-solve-algo); the env var still wins this "
-            "process but will be removed",
+            "(ALSConfig.reg_solve_algo or a PlanConstraints pin); the "
+            "env var still wins this process but will be removed",
             DeprecationWarning,
             stacklevel=2,
         )
@@ -260,7 +258,7 @@ def resolve_reg_solve_algo(algo: str | None) -> str:
     """The threaded elimination algorithm if given, else the process
     default.  ``None`` and ``"auto"`` both defer (``"auto"`` is the
     ``ALSConfig.reg_solve_algo`` spelling of "no opinion", so configs
-    stay env-var/perf_lab patchable by default)."""
+    stay env-var patchable by default)."""
     if algo is None or algo == "auto":
         return default_reg_solve_algo()
     if algo not in ("lu", "gj"):

@@ -29,9 +29,8 @@ from jax import lax
 def default_overlap() -> bool:
     """Process-wide default for comm/compute overlap (the production mode).
 
-    Patchable for A/B measurement (``scripts/perf_lab.py --overlap off``)
-    the same way the gram/solve backends are; per-call ``overlap=`` and
-    ``ALSConfig.overlap`` override it explicitly."""
+    A patch point no tool patches any more (ROADMAP D13); per-call
+    ``overlap=`` and ``ALSConfig.overlap`` override it explicitly."""
     return True
 
 

@@ -320,7 +320,7 @@ def _blocked_spd_solve_pallas(a: jax.Array, b: jax.Array) -> jax.Array:
     matmuls — MXU work — so rank 128 costs two lane-vectorized solves plus
     GEMMs instead of XLA's latency-bound 128×128 cholesky custom calls
     (measured: full-Netflix rank-128 drops from 15.8 to well under the
-    12 s/iter bar; see BASELINE.md).
+    12 s/iter bar; pre-ledger record, PERF.md §8).
     """
     from cfk_tpu.ops.pallas import (
         PALLAS_MAX_RANK,
@@ -403,10 +403,10 @@ def default_fused_epilogue() -> bool:
     gram_solve_tiles_pallas``) and the fused reg+solve dispatch below.
     True = fuse wherever the backend/rank gates allow — the production
     mode (the split path's per-chunk [Ec, k, k] A-batch write + readback
-    is pure HBM traffic the fusion removes).  Patchable for A/B
-    measurement (``scripts/perf_lab.py --fused off``, ``bench.py
-    --fused-ab``) exactly like ``ops.pipeline.default_overlap``; per-call
-    ``fused=`` and ``ALSConfig.fused_epilogue`` override it explicitly."""
+    is pure HBM traffic the fusion removes).  A patch point no tool
+    patches any more (ROADMAP D13), like ``ops.pipeline.default_overlap``;
+    per-call ``fused=`` and ``ALSConfig.fused_epilogue`` override it
+    explicitly."""
     return True
 
 
@@ -431,8 +431,8 @@ def regularized_solve(
     (``gauss_solve_reg_pallas``) — the separate diagonal-add pass re-wrote
     the whole Gram batch through HBM every chunk (round-3 profile).
     ``fused=False`` (or the process default off) pins the split
-    ridge-add + dispatch schedule — the measurement baseline of
-    ``bench.py --fused-ab``.  ``algo`` threads the fused elimination
+    ridge-add + dispatch schedule — the A/B baseline.
+    ``algo`` threads the fused elimination
     choice ('lu'/'gj'; None/'auto' = the process default) — the knob the
     recovery ladder's GJ rung flips (``ALSConfig.reg_solve_algo``).
     """
@@ -842,8 +842,8 @@ def als_half_step_bucketed(
     Width classes that pass the port gates (``ops.bucketed``) run the
     tiled gather kernels — in-kernel row DMA (``in_kernel_gather``) and
     the in-VMEM ridge+solve epilogue (``fused_epilogue``), one tile per
-    entity, so the ported f32 path is bit-identical to this legacy
-    schedule on the emulation route.  Refused classes (width < 16, SMEM
+    entity: the same contraction as this legacy schedule, equal to float32
+    round-off (``ops.bucketed``).  Refused classes (width < 16, SMEM
     overflow) keep the legacy gather + einsum + solve batch.  Rows absent
     from every bucket (zero ratings) stay exactly 0, matching the padded
     path's λ·I-floor solve of an all-zero system.  ``chunk_rows`` streams

@@ -9,8 +9,8 @@ per entity per epoch (O(nnz·k² + E·k³)), sweep over coordinate blocks of
 size b, solving a b×b subsystem per entity per block
 (O(nnz·k + nnz·k·b + E·k·b²) per sweep).  At rank 128 with b=32 this is the
 difference between a 2M-FLOP and a 130K-FLOP solve per entity, and the Gram
-work drops by k/b — the big-k regime (the BASELINE.md MovieLens-25M rank-128
-target) is exactly where it pays.
+work drops by k/b — the big-k regime (MovieLens-25M at rank 128 and
+beyond) is exactly where it pays.
 
 Math (implicit objective, Hu et al. 2008, preferences 1, confidence
 c = 1 + α·r, unobserved weight 1):

@@ -1,6 +1,6 @@
 """Tile-padded Gram half-steps — the MXU-native segment layout.
 
-Why this exists (measured on a real v5e, see BASELINE.md roofline notes):
+Why this exists (measured on a v5e before the ledger, PERF.md §8):
 the flat segment layout's grouped ragged matmul (``lax.ragged_dot_general``)
 runs the per-entity Gram accumulation ~15× below what the MXU can do, and
 XLA's row gather falls off a cliff (4×) once the fixed factor table exceeds
@@ -59,14 +59,11 @@ def default_in_kernel_gather() -> bool:
     """Process-wide default for the in-kernel neighbor gather: fuse the
     per-chunk neighbor-factor gather into the Pallas Gram kernels (the
     ``*_gather_pallas`` variants DMA the indexed table rows straight into
-    VMEM), retiring the materialized [C, k] gathered stream — the largest
-    measured roofline gap in BENCH_r05 (``vs_gather_roofline``
-    1.88–9.94×).  True = gather in-kernel wherever the gates allow
-    (``resolve_gather_mode``).  Patchable for A/B measurement
-    (``scripts/perf_lab.py --gather xla``, ``bench.py --gather-ab``)
-    exactly like ``default_tiled_gram_backend``; per-call
-    ``in_kernel_gather=`` and ``ALSConfig.in_kernel_gather`` override it
-    explicitly."""
+    VMEM), retiring the materialized [C, k] gathered stream.  True =
+    gather in-kernel wherever the gates allow (``resolve_gather_mode``).
+    A patch point no tool patches any more (ROADMAP D13), like
+    ``default_tiled_gram_backend``; per-call ``in_kernel_gather=`` and
+    ``ALSConfig.in_kernel_gather`` override it explicitly."""
     return True
 
 
@@ -77,9 +74,9 @@ def resolve_in_kernel_gather(in_kernel_gather) -> bool:
     return bool(in_kernel_gather)
 
 
-def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
-                        meta_words, tile_rows, num_segments, k,
-                        block_rows=None, *, table_dtype) -> str:
+def resolve_gather_mode(in_kernel_gather, backend, entries, meta_words,
+                        tile_rows, num_segments, k, block_rows=None, *,
+                        table_dtype) -> str:
     """Static gating of the in-kernel gather — ``"fused"`` or ``"xla"``.
 
     The logic lives in ``cfk_tpu.plan.registry`` now (ISSUE 9): ONE
@@ -91,7 +88,7 @@ def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
     existing call site and test import working."""
     from cfk_tpu.plan.registry import resolve_gather_mode as _resolve
 
-    return _resolve(in_kernel_gather, backend, stage, entries, meta_words,
+    return _resolve(in_kernel_gather, backend, entries, meta_words,
                     tile_rows, num_segments, k, block_rows,
                     table_dtype=table_dtype)
 
@@ -113,7 +110,7 @@ def default_tiled_gram_backend() -> str:
 
 def _entity_gram_chunk(
     fixed_slice, nb, wt, rt, seg, tile_rows, num_segments, backend,
-    unit_weights=False, zero_appended=False, carry=None, stage="full",
+    unit_weights=False, zero_appended=False, carry=None,
     pregathered=None, gather="xla",
 ):
     """One chunk's per-entity Gram/RHS: (A [num_segments, k, k], b [.., k]).
@@ -147,7 +144,7 @@ def _entity_gram_chunk(
     the in-body gather.
 
     ``gather="fused"`` (gated upstream by ``resolve_gather_mode``;
-    stage="full" + pallas backend only) retires the materialized stream
+    pallas backend only) retires the materialized stream
     entirely: ``fixed_slice`` must then be the RAW table (no zero row)
     and ``nb`` indexes it with ``table_rows`` as the virtual zero row;
     the kernel DMAs the rows itself and applies ``wt`` in-register —
@@ -165,14 +162,7 @@ def _entity_gram_chunk(
             fixed_slice, nb, wt, rt, seg, num_segments=num_segments,
             tile_rows=tile_rows, carry=carry,
         )
-    ct, prec = _gram_compute_dtype(fixed_slice)
-    if stage == "gather":
-        # Measurement probe (``tiled_half_step(stage=...)``): stop after
-        # the gather (+ the fused √aw multiply where weighted) and fold
-        # everything into a scalar so nothing is dead-code eliminated —
-        # the full-array reduce is negligible next to the row-slot-bound
-        # gather it sinks.
-        return jnp.sum(g.astype(jnp.float32)), None
+    _, prec = _gram_compute_dtype(fixed_slice)
     if backend == "pallas" and 2 * num_segments * k * (k + 1) * 4 > (96 << 20):
         # The kernel keeps the whole (A, b) chunk output resident in VMEM
         # (double-buffered); past ~96 MB it cannot compile.  Dense shapes
@@ -247,7 +237,7 @@ def _gathered_stream(fixed_slice, nb, wt, unit_weights, zero_appended,
     if not unit_weights:
         # Sqrt-weighted single stream (see _entity_gram_chunk): the
         # multiply fuses into the producing gather, and everything
-        # downstream — kernel operands, probes, both backends — sees one
+        # downstream — kernel operands, both backends — sees one
         # stream, exactly like the unit path.
         g = g * wt.astype(ct)[:, None]
     return g
@@ -324,7 +314,7 @@ def resolve_fused_chunk_lam(fused_epilogue, solver, k, num_segments,
 def resolve_tiled_route(mode, statics, k, lam, *, table_dtype, solver,
                         implicit=False, fused_epilogue=None,
                         in_kernel_gather=None, reg_solve_algo=None,
-                        gram_backend=None, stage="full"):
+                        gram_backend=None):
     """(gather mode, fused-epilogue λ or None) of one tiled half-step: what
     the static gates resolve the knobs to for this mode's chunk statics.
 
@@ -345,11 +335,11 @@ def resolve_tiled_route(mode, statics, k, lam, *, table_dtype, solver,
         nc, cap, t, h, e_c = statics
         meta_words, block_rows = cap // t, None
     gather = resolve_gather_mode(
-        in_kernel_gather, backend, stage, cap, meta_words, t, e_c + 1, k,
+        in_kernel_gather, backend, cap, meta_words, t, e_c + 1, k,
         block_rows, table_dtype=table_dtype,
     )
     fused_lam = None
-    if mode != "accum" and stage == "full":
+    if mode != "accum":
         fused_lam = resolve_fused_chunk_lam(
             fused_epilogue, solver, k, e_c + 1, backend, lam, implicit,
             reg_solve_algo,
@@ -408,7 +398,7 @@ def quantize_tiled_operand(fixed_factors, blk, chunks, table_dtype):
 
 def tiled_half_step(
     fixed_factors, blk, chunks, local_entities, lam, *,
-    solver="cholesky", implicit_reg=None, stage="full", overlap=None,
+    solver="cholesky", implicit_reg=None, overlap=None,
     fused_epilogue=None, in_kernel_gather=None, reg_solve_algo=None,
     table_dtype=None, return_chunk_rows=False,
 ):
@@ -416,15 +406,6 @@ def tiled_half_step(
 
     ``chunks`` is the static tuple ``("tiled", mode, *statics)`` the layout
     setup emits; ``blk`` the device-array dict of ``TiledBlocks`` fields.
-
-    ``stage`` (static; measurement hook for ``scripts/decompose.py``) stops
-    the half-step after a prefix of its pipeline and returns a [1, 1] f32
-    sink instead of factors, so each term of an iteration can be timed as
-    the LITERAL production ops (VERDICT r4 #4): ``"gather"`` = the per-chunk
-    neighbor-factor gather (incl. the weighted premultiply where the
-    production path pays it), ``"gram"`` = gather + the fused Gram kernel
-    with carry threading, ``"accum"`` (accum mode only) = everything but
-    the final solve.  ``"full"`` (default) is the unchanged production path.
 
     ``table_dtype`` quantizes the gather operand for this half-step
     (``ops.quant``; the solved factors keep the storage dtype): bf16
@@ -451,7 +432,7 @@ def tiled_half_step(
             blk["tile_seg"], blk["chunk_base"], blk["chunk_entity"],
             blk["count"], local_entities, lam,
             statics=st, solver=solver, implicit_reg=implicit_reg,
-            stage=stage, overlap=overlap, fused_epilogue=fused_epilogue,
+            overlap=overlap, fused_epilogue=fused_epilogue,
             in_kernel_gather=in_kernel_gather, reg_solve_algo=reg_solve_algo,
         )
     if mode == "dstream":
@@ -460,7 +441,7 @@ def tiled_half_step(
             blk["tile_meta"], blk["chunk_entity"], blk["chunk_count"],
             blk["carry_in"], blk["last_seg"], local_entities, lam,
             statics=st, solver=solver, implicit_reg=implicit_reg,
-            aweight_dense=blk.get("aweight_dense"), stage=stage,
+            aweight_dense=blk.get("aweight_dense"),
             overlap=overlap, fused_epilogue=fused_epilogue,
             in_kernel_gather=in_kernel_gather, reg_solve_algo=reg_solve_algo,
         )
@@ -468,7 +449,7 @@ def tiled_half_step(
         fixed_factors, blk["neighbor_idx"], blk["rating"], blk["weight"],
         blk["tile_seg"], blk["chunk_entity"], blk["chunk_count"],
         blk["carry_in"], blk["last_seg"], local_entities, lam,
-        statics=st, solver=solver, implicit_reg=implicit_reg, stage=stage,
+        statics=st, solver=solver, implicit_reg=implicit_reg,
         overlap=overlap, fused_epilogue=fused_epilogue,
         in_kernel_gather=in_kernel_gather, reg_solve_algo=reg_solve_algo,
         return_chunk_rows=return_chunk_rows,
@@ -481,7 +462,7 @@ _SQRT_WEIGHT_EPS = 1e-12  # clamp for α·r = 0 entries: their A-term becomes
 
 def ials_tiled_half_step(
     fixed_factors, blk, chunks, local_entities, lam, alpha, *,
-    gram=None, solver="cholesky", stage="full", overlap=None,
+    gram=None, solver="cholesky", overlap=None,
     fused_epilogue=None, in_kernel_gather=None, reg_solve_algo=None,
     table_dtype=None,
 ):
@@ -542,7 +523,7 @@ def ials_tiled_half_step(
             alpha * blk["rating_dense"], _SQRT_WEIGHT_EPS))
         return tiled_half_step(
             fixed_factors, blk, chunks, local_entities, lam,
-            solver=solver, implicit_reg=reg, stage=stage, overlap=overlap,
+            solver=solver, implicit_reg=reg, overlap=overlap,
             fused_epilogue=fused_epilogue,
             in_kernel_gather=in_kernel_gather, reg_solve_algo=reg_solve_algo,
             table_dtype=table_dtype,
@@ -556,7 +537,7 @@ def ials_tiled_half_step(
     blk["rating"], blk["weight"] = rt_scaled, aw_tile * blk["weight"]
     return tiled_half_step(
         fixed_factors, blk, chunks, local_entities, lam,
-        solver=solver, implicit_reg=reg, stage=stage, overlap=overlap,
+        solver=solver, implicit_reg=reg, overlap=overlap,
         fused_epilogue=fused_epilogue,
         in_kernel_gather=in_kernel_gather, reg_solve_algo=reg_solve_algo,
         table_dtype=table_dtype,
@@ -580,7 +561,6 @@ def als_half_step_tiled(
     solver: str = "cholesky",
     implicit_reg: jax.Array | None = None,  # [k,k] YᵀY+λI (iALS); None = ALS-WR
     gram_backend: str | None = None,
-    stage: str = "full",
     overlap: bool | None = None,
     fused_epilogue: bool | None = None,
     in_kernel_gather: bool | None = None,
@@ -632,7 +612,7 @@ def als_half_step_tiled(
         "stream", statics, k, lam, table_dtype=fixed_factors.dtype,
         solver=solver, implicit=implicit_reg is not None,
         fused_epilogue=fused_epilogue, in_kernel_gather=in_kernel_gather,
-        reg_solve_algo=reg_solve_algo, gram_backend=backend, stage=stage,
+        reg_solve_algo=reg_solve_algo, gram_backend=backend,
     )
     chunks = (
         neighbor_idx.reshape(nc, cap), rating.reshape(nc, cap),
@@ -641,44 +621,14 @@ def als_half_step_tiled(
         carry_in.reshape(nc), last_seg.reshape(nc),
     )
 
-    if stage != "full":
-        if stage not in ("gather", "gram"):
-            raise ValueError(f"stream mode has no stage {stage!r}")
-
-        def probe(carry, chunk):
-            acc, a0, b0 = carry
-            nb_c, rt_c, wt_c, ts_c, ent_c, cnt_c, cin_c, lseg_c = chunk
-            if stage == "gather":
-                s, _ = _entity_gram_chunk(
-                    fixed_factors, nb_c, wt_c, rt_c, ts_c, t, e_c + 1,
-                    backend, unit_weights=unit,
-                    stage="gather",
-                )
-                return (acc + s, a0, b0), None
-            a, b = _entity_gram_chunk(
-                fixed_factors, nb_c, wt_c, rt_c, ts_c, t, e_c + 1, backend,
-                unit_weights=unit, carry=(a0, b0, cin_c),
-            )
-            a1 = lax.dynamic_index_in_dim(a, lseg_c, 0, keepdims=False)
-            b1 = lax.dynamic_index_in_dim(b, lseg_c, 0, keepdims=False)
-            return (acc + a[0, 0, 0] + b[0, 0], a1, b1), None
-
-        init = jax.tree.map(
-            lambda z: _match_varying(z, neighbor_idx),
-            (jnp.zeros((), jnp.float32), jnp.zeros((k, k), jnp.float32),
-             jnp.zeros((k,), jnp.float32)),
-        )
-        (acc, _, _), _ = lax.scan(probe, init, chunks)
-        return acc.reshape(1, 1)
-
     def solve_chunk_rows(a, b, cnt_c):
         # The whole batch is solved including the trash row — solving it
         # beats slicing it away, which copied the batch again.  fused=True
         # pins the reg+solve FUSION (one kernel pass, the pre-existing
         # default): the fused_epilogue A/B toggles only the Gram→HBM→solve
         # round-trip, so split and fused chunk factors stay bit-exact and
-        # a patched process default (perf_lab --fused off) cannot swap the
-        # elimination algorithm under the baseline.
+        # a patched process default cannot swap the elimination algorithm
+        # under the baseline.
         if implicit_reg is None:
             return regularized_solve(a, b, _chunk_reg(cnt_c, None), lam,
                                      solver, fused=True,
@@ -817,7 +767,6 @@ def als_half_step_tiled_dense(
     implicit_reg: jax.Array | None = None,
     gram_backend: str | None = None,
     aweight_dense: jax.Array | None = None,  # [NC·C] per-entry A-weights
-    stage: str = "full",
     overlap: bool | None = None,
     fused_epilogue: bool | None = None,
     in_kernel_gather: bool | None = None,
@@ -850,10 +799,10 @@ def als_half_step_tiled_dense(
         "dstream", statics, k, lam, table_dtype=fixed_factors.dtype,
         solver=solver, implicit=implicit_reg is not None,
         fused_epilogue=fused_epilogue, in_kernel_gather=in_kernel_gather,
-        reg_solve_algo=reg_solve_algo, gram_backend=backend, stage=stage,
+        reg_solve_algo=reg_solve_algo, gram_backend=backend,
     )
     ct, _ = _gram_compute_dtype(fixed_factors)
-    if gather != "fused" or stage != "full":
+    if gather != "fused":
         # The zero-row-appended table only exists for the XLA-gather
         # schedule; the in-kernel gather realizes the zero row in-register
         # (clamp + window mask) and never builds this copy.
@@ -872,35 +821,6 @@ def als_half_step_tiled_dense(
     # scale stream, quantize_tiled_operand) — not only under implicit_reg.
     if aweight_dense is not None:
         chunks = chunks + (aweight_dense.reshape(nc, cap),)
-
-    if stage != "full":
-        if stage not in ("gather", "gram"):
-            raise ValueError(f"dstream mode has no stage {stage!r}")
-
-        def probe(carry, chunk):
-            acc, a0, b0 = carry
-            nb_c, rt_c, meta_c, lseg_c, cin_c, cnt_c = chunk[:6]
-            g = fz[nb_c].astype(ct)
-            if aweight_dense is not None:  # sqrt-weighted single stream
-                g = g * chunk[6].astype(ct)[:, None]
-            if stage == "gather":
-                return (acc + jnp.sum(g.astype(jnp.float32)), a0, b0), None
-            a, b = gram_tiles_dense_pallas_dispatch(
-                g, rt_c, meta_c, num_segments=e_c + 1, tile_rows=t,
-                num_tiles=nt, num_groups=ng, block_rows=bg,
-                carry=(a0, b0, cin_c), backend=backend,
-            )
-            a1 = lax.dynamic_index_in_dim(a, lseg_c, 0, keepdims=False)
-            b1 = lax.dynamic_index_in_dim(b, lseg_c, 0, keepdims=False)
-            return (acc + a[0, 0, 0] + b[0, 0], a1, b1), None
-
-        init = jax.tree.map(
-            lambda z: _match_varying(z, neighbor_idx),
-            (jnp.zeros((), jnp.float32), jnp.zeros((k, k), jnp.float32),
-             jnp.zeros((k,), jnp.float32)),
-        )
-        (acc, _, _), _ = lax.scan(probe, init, chunks)
-        return acc.reshape(1, 1)
 
     def gram_solve(carry, g, x, nb_c=None):
         # ``g`` is the gathered stream on the XLA-gather schedule; with
@@ -1047,7 +967,6 @@ def als_half_step_tiled_accum(
     solver: str = "cholesky",
     implicit_reg: jax.Array | None = None,
     gram_backend: str | None = None,
-    stage: str = "full",
     overlap: bool | None = None,
     fused_epilogue: bool | None = None,
     in_kernel_gather: bool | None = None,
@@ -1076,7 +995,7 @@ def als_half_step_tiled_accum(
     gather is issued before chunk c's Gram + accumulator scatter-add.
 
     ``in_kernel_gather`` (default on where legal) retires accum mode's
-    whole window machinery for the production stage: slice-local indices
+    whole window machinery: slice-local indices
     are rebased to ABSOLUTE table rows (a cheap [C] int32 map — the
     clamped window base comes along as data) and the gather-fused kernel
     DMAs the rows straight from the full table, so neither the hoisted
@@ -1096,7 +1015,7 @@ def als_half_step_tiled_accum(
     gather, _ = resolve_tiled_route(
         "accum", statics, k, lam, table_dtype=fixed_factors.dtype,
         solver=solver, in_kernel_gather=in_kernel_gather,
-        gram_backend=backend, stage=stage,
+        gram_backend=backend,
     )
     chunks = (
         neighbor_idx.reshape(nc, cap), rating.reshape(nc, cap),
@@ -1126,7 +1045,7 @@ def als_half_step_tiled_accum(
     # error (> _GZ_HOISTED_BUDGET_BYTES), degrade to the per-chunk
     # dynamic_slice + concat path instead of OOMing: same math, pays the
     # in-body slice copy the hoist was measured to save (~25 ms/iter).
-    # The in-kernel gather (gather == "fused", production stage) never
+    # The in-kernel gather (gather == "fused") never
     # builds the windows at all — absolute indices go straight to the
     # kernel's DMA, which has no operand-size gather cliff to dodge.
     gz_bytes = n_slices * (h + 1) * k * fixed_factors.dtype.itemsize
@@ -1171,43 +1090,6 @@ def als_half_step_tiled_accum(
                 lax.dynamic_slice_in_dim(fixed_factors, base_c, h), zrow
             ])
         return fixed_slice
-
-    if stage == "gather":
-        def probe(acc, chunk):
-            nb_c, rt_c, wt_c, ts_c, base_c, ent_c = chunk
-            s, _ = _entity_gram_chunk(
-                select_window(base_c), nb_c, wt_c, rt_c, ts_c, t, e_c + 1,
-                backend, unit_weights=unit,
-                zero_appended=True, stage="gather",
-            )
-            return acc + s, None
-
-        init = _match_varying(jnp.zeros((), jnp.float32), neighbor_idx)
-        acc, _ = lax.scan(probe, init, chunks)
-        return acc.reshape(1, 1)
-    if stage == "gram":
-        def probe(acc, chunk):
-            nb_c, rt_c, wt_c, ts_c, base_c, ent_c = chunk
-            a, b = _entity_gram_chunk(
-                select_window(base_c), nb_c, wt_c, rt_c, ts_c, t, e_c + 1,
-                backend, unit_weights=unit,
-                zero_appended=True,
-            )
-            # Sink a row the pallas kernel is GUARANTEED to have written:
-            # the owner of the chunk's first tile (ts_c[0] — the accum
-            # analog of the stream probe's lseg-indexed a1/b1).  Row 0 is
-            # unwritten garbage in all-trash padding chunks, and garbage
-            # NaN would poison the probe accumulator (ADVICE r5).
-            s0 = ts_c[0]
-            a1 = lax.dynamic_index_in_dim(a, s0, 0, keepdims=False)
-            b1 = lax.dynamic_index_in_dim(b, s0, 0, keepdims=False)
-            return acc + a1[0, 0] + b1[0], None
-
-        init = _match_varying(jnp.zeros((), jnp.float32), neighbor_idx)
-        acc, _ = lax.scan(probe, init, chunks)
-        return acc.reshape(1, 1)
-    if stage not in ("accum", "full"):
-        raise ValueError(f"accum mode has no stage {stage!r}")
 
     def accumulate(carry, a, b, ent_c):
         # Rank rows owning no tile are unwritten garbage under the pallas
@@ -1282,8 +1164,6 @@ def als_half_step_tiled_accum(
         )
     else:
         (acc_a, acc_b), _ = lax.scan(body, init, chunks)
-    if stage == "accum":  # everything but the final solve
-        return (acc_a[0, 0, 0] + acc_b[0, 0]).reshape(1, 1)
     # Accum mode's (A, b) lives in HBM ACROSS chunks by design (entities
     # recur across table slices), so there is no per-chunk VMEM residency
     # to solve inside; the fused knob here gates the one fused reg+solve

@@ -118,7 +118,7 @@ def _ring_rotate(blk, perm, compute, *, overlap):
     scheduling can run the ICI transfer under the compute.  With
     ``overlap=False`` an ``optimization_barrier`` pins the serial reference
     schedule (compute fully drains, then the transfer starts) — the A/B
-    ``bench.py --overlap-ab`` measures.  Returns (compute result, next
+    baseline.  Returns (compute result, next
     block); both orders run identical ops on identical values, so factors
     are bit-equal either way (``tests/test_overlap.py``)."""
     permute = lambda b: jax.tree.map(
@@ -572,7 +572,7 @@ def half_step_tiled_ring_hier(
     k = fixed_local.shape[-1]
     data, scale = quant.quantize_table(fixed_local, table_dtype)
     gather = resolve_gather_mode(
-        in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        in_kernel_gather, backend, cap, nt, t, e_c + 1, k,
         table_dtype=data.dtype,
     )
     tbl0 = (data,) if scale is None else (data, scale)
@@ -744,7 +744,7 @@ def half_step_tiled_ring(
     # folded into the weight channel per chunk — the canonical order.
     data, scale = quant.quantize_table(fixed_local, table_dtype)
     gather = resolve_gather_mode(
-        in_kernel_gather, backend, "full", cap, nt, t, e_c + 1, k,
+        in_kernel_gather, backend, cap, nt, t, e_c + 1, k,
         table_dtype=data.dtype,
     )
     tbl0 = (data,) if scale is None else (data, scale)
@@ -798,7 +798,7 @@ def half_step_tiled_ring(
     )
     # Like accum mode, the ring's accumulator lives across steps in HBM;
     # the fused knob gates the final fused reg+solve vs the split
-    # ridge-add + dispatch (bench.py --fused-ab measures the pair).
+    # ridge-add + dispatch.
     x = regularized_solve(
         acc_a[:local_entities], acc_b[:local_entities],
         blk["count"], lam, solver, fused=fused_epilogue,

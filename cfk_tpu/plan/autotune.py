@@ -15,8 +15,7 @@ model-estimated and measured cost plus hit/miss so a regression is
 attributable to the decision.
 
 Measurement is always opt-in: trainers consult the cache but never
-measure (warm it offline with ``cfk_tpu plan --autotune`` or
-``perf_lab --plan autotune``).
+measure (warm it offline with ``cfk_tpu plan --autotune``).
 """
 
 from __future__ import annotations

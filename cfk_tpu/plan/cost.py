@@ -42,7 +42,7 @@ _GATHER_PAD_FACTOR = {
 # slow — the model must never pick it on a cpu/gpu device.
 _OFFCHIP_PALLAS_SOLVER_PENALTY = 50.0
 # XLA's batched-Cholesky custom calls measured ~1.7× the fused pallas
-# solve end-to-end on TPU (BASELINE round 2).
+# solve end-to-end on TPU (pre-ledger round 2; PERF.md §8).
 _TPU_CHOLESKY_PENALTY = 1.7
 
 

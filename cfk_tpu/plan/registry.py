@@ -290,17 +290,16 @@ _register_builtins()
 # -- central mode resolution (the logic ops.tiled/ops.bucketed/both spmd
 # -- ring half-steps used to carry copies of) ------------------------------
 
-def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
-                        meta_words, tile_rows, num_segments, k,
-                        block_rows=None, *, table_dtype) -> str:
+def resolve_gather_mode(in_kernel_gather, backend, entries, meta_words,
+                        tile_rows, num_segments, k, block_rows=None, *,
+                        table_dtype) -> str:
     """Static gating of the in-kernel gather: ``"fused"`` (the kernel DMAs
     the indexed rows itself) or ``"xla"`` (the materialized-stream
     schedule).  ``table_dtype`` is the dtype of the table the kernel would
     DMA from (after quantization).  Gates: the knob, the pallas Gram
     backend (the XLA A/B backend has no kernel to gather inside),
     ``mosaic_tpu`` registry availability (a forced-unavailable backend
-    reroutes the next trace to the emulation schedule), production stage
-    only (the decompose probes time the XLA gather as its own phase), the
+    reroutes the next trace to the emulation schedule), the
     kernels' rank/dtype/SMEM/alignment support gate
     (``in_kernel_gather_supported`` — what the chip's compiler accepts),
     and the same resident-output VMEM cap the split kernels fall back on.
@@ -312,7 +311,7 @@ def resolve_gather_mode(in_kernel_gather, backend, stage, entries,
     drivers keep taking the same route as each other there.  A refused
     shape keeps the XLA-gather path (same math; kernel vs twin agree to
     float32 round-off — tests/test_in_kernel_gather.py)."""
-    if stage != "full" or backend != "pallas":
+    if backend != "pallas":
         return "xla"
     if not REGISTRY.backend_available("mosaic_tpu"):
         return "xla"

@@ -673,8 +673,8 @@ def plan_for_config(config, *, num_users: int, num_movies: int, nnz: int,
     """The trainer entry: shape from the dataset's counts, pins from the
     config's explicit knobs, mode from ``config.plan``.  Trainer-side
     autotune NEVER measures (that belongs offline — ``cfk_tpu plan
-    --autotune`` / ``perf_lab --plan autotune``); it consults the cache
-    and falls back to the model on a miss, recording hit/miss."""
+    --autotune``); it consults the cache and falls back to the model on a
+    miss, recording hit/miss."""
     shape = shape_for_config(
         config, num_users=num_users, num_movies=num_movies, nnz=nnz,
         implicit=implicit, gather_rows=gather_rows,

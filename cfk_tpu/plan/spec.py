@@ -19,8 +19,8 @@ Bit-exactness contract: an ``ALSConfig``'s concrete knobs become PINNED
 constraints (``constraints_from_config``), and ``ExecutionPlan.
 half_step_kwargs`` threads the config's own sentinel (``None``/``"auto"``)
 for every knob the config left deferred — so the default-config path routes
-through exactly the same downstream resolution (process defaults, perf_lab
-patch points, jit cache keys) as before the planner existed, and is
+through exactly the same downstream resolution (process defaults, jit
+cache keys) as before the planner existed, and is
 bit-identical by construction.  The plan's *resolved* concrete choices are
 what provenance records and what the cost model priced.
 """
@@ -435,8 +435,8 @@ class ExecutionPlan:
         returns the config's own sentinel (``None``/``"auto"``) rather
         than the resolved concrete value: the downstream half-steps then
         resolve through the same process defaults as before the planner,
-        so jit cache keys, perf_lab patch points, and bit-exactness are
-        untouched.  The resolved value is still visible in ``knob_dict``
+        so jit cache keys and bit-exactness are untouched (ROADMAP D13:
+        nothing patches those defaults any more).  The resolved value is still visible in ``knob_dict``
         and in the provenance record.  A PINNED knob threads concrete.
         """
         pin = self.pinned

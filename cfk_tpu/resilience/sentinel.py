@@ -5,9 +5,7 @@ factor row poisons every Gram that row touches on the next half-iteration,
 so by the time the final RMSE is computed the whole model is garbage.  The
 probes here are O(E·k) reductions — two ``isfinite`` all-reduces and two
 max-row-norm watchdogs over U/M — against the iteration's O(nnz·k + E·k²)
-solve work, so they are effectively free (< 2% s/iter measured at the
-bench dense-stream config with ``health_check_every=1``; ``scripts/
-perf_lab.py --health`` records the axis).
+solve work (what they cost on the chip is not measured).
 
 Two consumption modes, one probe:
 

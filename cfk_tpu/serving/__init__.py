@@ -9,7 +9,7 @@ serve_topk_sharded``); a request server coalesces queries from the
 transport log into pow2-bucketed batches (``server``) over a live-updating
 ``ServeEngine`` whose hot-user factor cache re-serves streaming fold-in
 commits (``engine``); and an open-loop generator measures QPS/p50/p99
-honestly (``loadgen``; ``bench.py --serve`` for the recorded rows).
+honestly (``loadgen``; PERF.md for what the chip measured).
 
 Two-stage clustered retrieval (ISSUE 16 / ROADMAP item 4) breaks the
 O(users × catalog) scan floor: a seeded k-means over the item factors

@@ -74,8 +74,8 @@ def warm_serve_programs(client, server, pool, k: int, max_batch: int) -> None:
     """Compile the serve path's batch-size program variants before a
     measured run: every pow2 coalesced size up to ``max_batch``, plus
     ``max_batch`` itself (a non-pow2 cap still pads to its own pow2
-    bucket).  The ONE copy used by bench.py --serve, perf_lab --serve and
-    the CLI loadgen mode.  Seen-rectangle widths (W) are data-dependent
+    bucket).  Used by the CLI loadgen mode (the benchmark has its own
+    copy: ROADMAP D11).  Seen-rectangle widths (W) are data-dependent
     per batch, so a first-seen W can still trace mid-run — warming with
     the hottest pool rows makes the common widths resident."""
     pool = np.asarray(pool, np.int64)

@@ -14,7 +14,7 @@ Batching is the throughput lever, exactly as it was for the reference's
 Kafka producer and PR 6's fold-in micro-batches: under open-loop load the
 natural batch size self-tunes — a busy server finds more requests pending
 per poll, amortizing the per-batch dispatch over more queries, which is
-what makes the QPS-vs-latency trade measurable (``bench.py --serve``).
+what makes the QPS-vs-latency trade measurable (PERF.md §4).
 """
 
 from __future__ import annotations

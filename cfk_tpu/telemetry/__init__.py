@@ -15,10 +15,10 @@ Three instruments, one package (ISSUE 14):
   Prometheus-text ``/metrics`` endpoint the request server and
   ``cfk_tpu stream`` serve.
 
-Telemetry-off is bit-identical and within the ≤2% overhead budget by the
-sentinel discipline: nothing here ever touches device values, span/record
-calls are no-ops (one global read) when nothing is configured, and
-``chaos_lab telemetry_overhead`` + ``perf_lab --telemetry`` pin it.
+Telemetry-off is bit-identical by the sentinel discipline: nothing here
+ever touches device values, and span/record calls are no-ops (one global
+read) when nothing is configured (``tests/test_telemetry.py``; tracer on
+against off on the chip: PERF.md section 6, PR 24).
 """
 
 from cfk_tpu.telemetry.export import (

@@ -4,8 +4,8 @@ The reference has no notion of compute efficiency — its hot loop is a
 per-entity EJML solve (``processors/MFeatureCalculator.java:85-99``) and its
 only telemetry is wall-clock milliseconds.  On TPU the honest yardstick is
 the hardware: model FLOPs per iteration over the chip's peak (MFU), and the
-minimum HBM traffic over measured bandwidth (roofline).  These numbers are
-printed by ``bench.py`` so every recorded benchmark carries its efficiency.
+minimum HBM traffic over measured bandwidth (roofline).  The planner's cost
+model prices from these counts (``plan/cost.py``, ``plan/spec.py``).
 
 Conventions
 -----------
@@ -55,8 +55,8 @@ DEVICE_PEAKS: dict[str, DevicePeaks] = {
         source=(
             "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
             "16 GB HBM at 819 GB/s per chip; gather rate measured on one "
-            "chip (BASELINE.md round 3, 2026-08-01, earlier toolchain, "
-            "not re-measured)"
+            "chip (pre-ledger round 3, 2026-08-01, earlier toolchain, "
+            "not re-measured: PERF.md section 8)"
         ),
     ),
 }
@@ -144,8 +144,8 @@ def roofline_row(cost: IterationCost, s_per_iter: float,
     left out with a ``"roofline": "not measured…"`` note — a CPU timing
     over a TPU's peak is not a slower version of that number.
 
-    One definition so bench.py's rows and scripts/perf_lab.py can never
-    drift on which metrics exist or how they're computed.  ``table_dtype``
+    Its callers were the deleted measurement scripts (ROADMAP D11).
+    ``table_dtype``
     records the gather-table quantization the run used (None → float32
     pre-quantization semantics are NOT implied — pass what the run ran)."""
     row = {
@@ -194,10 +194,8 @@ def bucketed_gather_rows(movie_blocks, user_blocks) -> float:
     """Honest gather-row count for the bucketed layout: every PADDED cell
     of every width class fetches a row (padding slots gather the clamped /
     zero row like any other — the engine charges the slot), so the floor
-    is Σ rows·width per class per side, not 2·nnz.  BENCH_r05's bucketed
-    rows were computed at 2·nnz, which understated the floor by the
-    padding ratio (~1.3–2× on power-law data) — part of why
-    ``ialspp_ml25m`` read as 9.94× its roofline."""
+    is Σ rows·width per class per side, not 2·nnz, which understates the
+    floor by the padding ratio (~1.3–2× on power-law data)."""
     return float(movie_blocks.padded_cells + user_blocks.padded_cells)
 
 
@@ -298,9 +296,9 @@ def serve_batch_cost(num_movies: int, rank: int, batch: int, k_top: int,
 def serve_roofline_row(cost: ServeBatchCost, s_per_batch: float,
                        table_dtype: str | None = None, *,
                        device_kind: str) -> dict:
-    """The model-cost and efficiency fields every ``bench.py --serve`` row
-    carries — one definition shared with ``perf_lab --serve`` (the same
-    no-drift rule, and the same no-peaks rule, as ``roofline_row``)."""
+    """The model-cost and efficiency fields of a serve batch (the same
+    no-peaks rule as ``roofline_row``; its callers were the deleted
+    measurement scripts: ROADMAP D11)."""
     row = {
         "device_kind": device_kind,
         "serve_batch_tflops": round(cost.model_flops / 1e12, 6),

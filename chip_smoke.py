@@ -34,8 +34,8 @@ import time
 
 import numpy as np
 
-# The deployment (README "Measured on one TPU v5e chip"; bench.py
-# full_rank64_row) and, for --multichip, a cut of it to 1/10 on every axis
+# The deployment (the Netflix Prize shape) and, for --multichip, a cut of
+# it to 1/10 on every axis
 # (row lengths kept: ~209 ratings per user, ~5.7k per movie): that phase
 # checks what exists only across chips (mesh, collectives, sharded state),
 # not speed.

@@ -1,6 +1,6 @@
 """Golden end-to-end test: tiny dataset at the reference's published config
 (k=5, 7 iterations, λ=0.05) must reach MSE ≤ 0.27 — the reference reports
-0.265 / RMSE 0.515 (README.md:207-211, BASELINE.md)."""
+0.265 / RMSE 0.515 (the reference's README.md:207-211)."""
 
 import numpy as np
 

@@ -132,7 +132,7 @@ def test_tiled_accum_chunk_elems_sizes_only_the_accum_half():
 
 
 def test_cached_scale_dataset_builds_through_from_coo(tmp_path):
-    """The measurement tools' build-or-load path (perf_lab, bench.py) is
+    """The build-or-load path ``chip_smoke.py`` builds through is
     ``Dataset.from_coo`` and nothing else: same blocks, same knobs."""
     from cfk_tpu.data.cache import cached_scale_dataset
     from cfk_tpu.data.synthetic import synthetic_netflix_coo
@@ -151,3 +151,23 @@ def test_cached_scale_dataset_builds_through_from_coo(tmp_path):
         assert (g.mode, g.statics) == (w.mode, w.statics)
         np.testing.assert_array_equal(g.neighbor_idx, w.neighbor_idx)
     assert got.movie_blocks.slice_rows == 1_024
+
+
+
+def test_cached_scale_dataset_second_call_loads_what_the_first_built(tmp_path):
+    """A first call builds and says nothing of a hit; the same arguments
+    again load the cache (and say so) and give back the same ratings."""
+    from cfk_tpu.data.cache import cached_scale_dataset
+
+    said = []
+    kw = dict(users=300, movies=80, nnz=2_000, seed=0, layout="tiled",
+              chunk_elems=1_024, tile_rows=16, cache_root=str(tmp_path),
+              log=lambda *a, **k: said.append(" ".join(map(str, a))))
+    built = cached_scale_dataset(**kw)
+    assert not any("cache hit" in line for line in said)
+    loaded = cached_scale_dataset(**kw)
+    assert any("cache hit" in line for line in said)
+    np.testing.assert_array_equal(built.coo_dense.rating,
+                                  loaded.coo_dense.rating)
+    np.testing.assert_array_equal(built.user_blocks.neighbor_idx,
+                                  loaded.user_blocks.neighbor_idx)

@@ -79,7 +79,7 @@ def test_auto_layout_resolution(capsys, monkeypatch):
 
 @pytest.mark.reference_data
 def test_train_survives_unmaterializable_dense_preds(capsys, tmp_path, monkeypatch):
-    """At BASELINE scales the dense U·Mᵀ cannot exist; training must still
+    """At Netflix-Prize scales the dense U·Mᵀ cannot exist; training must still
     finish, report factored train MSE, and only skip the CSV dump."""
     from cfk_tpu.models.als import ALSModel
 

@@ -265,6 +265,30 @@ def test_train_als_routes_host_window_tier(stream_ds):
         )
 
 
+def test_windowed_driver_exports_its_meters(stream_ds):
+    # What the driver reports of a run, read off its own gauges: windows
+    # on both sides, sublane-aligned window rows, the bytes staged (of
+    # them the table's share, all cold with no hot partition), the plan's
+    # pinned host bytes, and the time to the first full iteration.
+    from cfk_tpu.utils.metrics import Metrics
+
+    cfg = ALSConfig(rank=8, lam=0.05, num_iterations=2, layout="tiled",
+                    solver="cholesky")
+    metrics = Metrics()
+    train_als_host_window(stream_ds, cfg, metrics=metrics,
+                          chunks_per_window=2, hot_rows=0)
+    g = metrics.gauges
+    assert g["offload_windows_m"] >= 1 and g["offload_windows_u"] >= 1
+    assert g["offload_chunks_per_window"] == 2
+    assert g["offload_window_rows_m"] >= 8
+    assert g["offload_window_rows_m"] % 8 == 0
+    assert g["offload_staged_mb"] > 0
+    assert 0 < g["offload_staged_cold_mb"] <= g["offload_staged_mb"]
+    assert g.get("offload_hot_rows", 0) == 0
+    assert g["offload_plan_held_mb"] > 0
+    assert g["time_to_first_step_s"] > 0
+
+
 def test_window_integrity_trip_recovers_bit_exact(stream_ds):
     # A torn window (finite, WRONG bytes) is caught by the staging
     # checksum BEFORE any kernel consumes it; rollback + one-shot replay

@@ -219,10 +219,12 @@ def test_hot_on_off_resident_crc_identical(shards, exchange, table_dtype):
     m = Metrics()
     auto = _crc(train_als_host_window(ds, cfg, chunks_per_window=2,
                                       metrics=m))
+    m_pin = Metrics()
     pinned = _crc(train_als_host_window(ds, cfg, chunks_per_window=2,
-                                        hot_rows=10))
+                                        hot_rows=10, metrics=m_pin))
     assert off == auto == pinned
     assert m.gauges.get("offload_hot_rows", 0) > 0  # auto really cached
+    assert 0 < m_pin.gauges["offload_hot_rows"] <= 10  # a pin is a cap
     if shards == 1 and exchange == "all_gather":
         assert off == _crc(train_als(ds, cfg))
 

@@ -123,6 +123,55 @@ def test_windowed_bit_exact_across_window_sizes(ds, resident):
         assert _crc(model) == want, f"chunks_per_window={cpw}"
 
 
+# Full-rank blocks (block_size == rank) are where windowed and resident part
+# in the last bits.  The windowed driver compiles each width-class window as
+# its own program, and so does the resident trainer's STEPPED loop (a
+# checkpoint manager forces it): those agree to the bit.  The resident
+# FUSED loop is one XLA:CPU program over both halves and every iteration,
+# and there the compiler orders the sweep's float32 sums differently (the
+# block's column slice is the whole row, so the slices fold away).  After
+# this shape's two iterations, on factors up to 3.6 in magnitude: 4.8e-7
+# absolute on the user side, 1.5e-8 on the movie side (measured, jax
+# 0.9.0; 2.6e-6 after three iterations).  The bound is 8 x the larger.
+_FULL_RANK_BLOCK_ATOL = 4e-6
+
+
+@pytest.mark.parametrize("resident_loop", ["fused", "stepped"])
+def test_windowed_vs_resident_at_full_rank_blocks(tmp_path, resident_loop):
+    """iALS++ at 120 x 40 x 900, rank 4 = block_size, chunk_elems 512, two
+    iterations, two chunks a window (where ROADMAP D0's fifth red test
+    failed): the windowed driver is deterministic to the bit and
+    matches either resident loop to ``_FULL_RANK_BLOCK_ATOL``."""
+    from cfk_tpu.data.synth import synth_coo
+    from cfk_tpu.transport.checkpoint import CheckpointManager
+
+    ds = Dataset.from_coo(synth_coo(120, 40, 900, seed=0),
+                          layout="bucketed", chunk_elems=512)
+    cfg = _cfg(block_size=4, solver="cholesky")
+    manager = (CheckpointManager(str(tmp_path))
+               if resident_loop == "stepped" else None)
+    want = train_ials(ds, cfg, checkpoint_manager=manager)
+    win_cfg = _cfg(block_size=4, solver="cholesky",
+                   offload_tier="host_window")
+    metrics = Metrics()
+    got = train_ials_host_window(ds, win_cfg, metrics=metrics,
+                                 chunks_per_window=2)
+    again = train_ials_host_window(ds, win_cfg, metrics=Metrics(),
+                                   chunks_per_window=2)
+    assert _crc(got) == _crc(again)  # one program twice
+    for side in ("user_factors", "movie_factors"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(got, side), np.float32),
+            np.asarray(getattr(want, side), np.float32),
+            rtol=0, atol=_FULL_RANK_BLOCK_ATOL,
+        )
+    assert metrics.gauges.get("offload_windows_m", 0) >= 1
+    assert metrics.gauges.get("offload_windows_u", 0) >= 1
+    assert metrics.gauges.get("offload_staged_mb", 0) > 0
+    assert metrics.gauges.get("offload_gram_staged_mb", 0) > 0
+    assert metrics.gauges.get("offload_gram_reserved_mb", 0) > 0
+
+
 @pytest.mark.slow
 def test_windowed_plain_ials_algorithm_bit_exact(ds, resident):
     """algorithm='als' (full-rank sweeps, no subspace blocks) rides the

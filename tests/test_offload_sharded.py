@@ -512,6 +512,37 @@ def test_pooled_staging_crc_identity_fast_representatives(corpus,
     assert a == b
 
 
+def test_staging_modes_share_programs_and_meter_the_engine():
+    # Serial then pooled staging of one 2-shard workload (a shape of this
+    # test's own, so the first arm is cold): the second arm traces no
+    # window program (staging decides who copies, not what runs), serial
+    # staging hides nothing (it stages on the consuming thread), and the
+    # pool reports its depth and busy time.
+    from cfk_tpu.data.synth import synth_coo
+    from cfk_tpu.utils.metrics import Metrics
+
+    ds = Dataset.from_coo(synth_coo(200, 60, 1500, seed=0), num_shards=2,
+                          layout="tiled", tile_rows=16, chunk_elems=512,
+                          accum_max_entities=0)
+    cfg = ALSConfig(rank=8, lam=0.05, num_iterations=2, num_shards=2,
+                    layout="tiled", solver="cholesky")
+    serial, pool = Metrics(), Metrics()
+    a = train_als_host_window(ds, cfg, metrics=serial, chunks_per_window=2,
+                              staging="serial")
+    b = train_als_host_window(ds, cfg, metrics=pool, chunks_per_window=2,
+                              staging="pool")
+    assert _crc(a) == _crc(b)
+    assert serial.notes["offload_staging"] == "serial"
+    assert pool.notes["offload_staging"] == "pool"
+    assert serial.gauges["offload_trace_count"] >= 1
+    assert pool.gauges["offload_trace_count"] == 0
+    assert serial.gauges["offload_stage_hidden_frac"] == 0.0
+    assert serial.gauges.get("offload_pool_depth") is None
+    assert pool.gauges["offload_pool_depth"] >= 1
+    assert pool.gauges["offload_stage_busy_s"] >= 0
+    assert pool.gauges["time_to_first_step_s"] > 0
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("shards,exchange,ici", [
     (1, "all_gather", None),

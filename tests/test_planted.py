@@ -1,6 +1,6 @@
 """Planted-factor quality validation (VERDICT r1 item #6).
 
-The BASELINE RMSE bars need the real Netflix corpus, which this environment
+The reference's RMSE bars need the real Netflix corpus, which this environment
 cannot fetch (no egress).  Proxy: generate ratings from KNOWN low-rank
 factors + Gaussian noise and assert the production at-scale pipeline
 (tiled layout, bf16 factor storage, per-entity solves) recovers them —
@@ -9,9 +9,7 @@ every (user, movie) pair seen in training (Zipf-hot pairs collide), which
 skews them cold — the conservative direction.  Calibration at this shape:
 converged recovery reaches ≈1.50σ (finite-data estimation error over the
 cold held-out pairs); an undertrained/broken pipeline sits at the
-zero-predictor level ≈5.5σ, so the 1.7σ bound discriminates sharply.  The full-Netflix-shape run
-of the same validation is ``bench.py --scale --full --planted`` (recorded
-in BASELINE.md).
+zero-predictor level ≈5.5σ, so the 1.7σ bound discriminates sharply.
 """
 
 import dataclasses

@@ -258,6 +258,26 @@ def test_session_foldin_rmse_parity_with_batch_solve(ds, cfg, base, tmp_path):
     )
 
 
+def test_session_meters_every_stage_of_a_batch(ds, cfg, base, tmp_path):
+    """A drained stream leaves the session's own accounting behind: the
+    batches committed, the fresh updates absorbed, and a wall-clock phase
+    for each stage of the batch loop (stage, solve, health probe, commit),
+    through the asynchronous checkpoint writer."""
+    from cfk_tpu.utils.metrics import Metrics
+
+    broker = InMemoryBroker()
+    _produce_stream(broker, ds, n=48, parts=1)
+    metrics = Metrics()
+    sess, _ = _run(
+        ds, cfg, broker, CheckpointManager(str(tmp_path), async_write=True),
+        base=base, batch_records=16, metrics=metrics,
+    )
+    assert sess.stream_step == 3
+    assert 0 < metrics.counters["updates_fresh"] <= 48
+    for phase in ("stage", "foldin_solve", "health_check", "commit"):
+        assert metrics.phases[phase] >= 0, phase
+
+
 # --- delivery-fault / crash bit-exactness ------------------------------------
 
 

@@ -558,6 +558,48 @@ def test_windowed_overlap_gauge_agrees_with_spans(tracer):
     assert abs(from_spans - gauge) <= 0.05, (from_spans, gauge)
 
 
+def test_tracer_on_trains_the_same_factors(tmp_path):
+    # Spans observe from the host only: a windowed run under the tracer
+    # ends on the factors of the same run without it, the written Chrome
+    # trace holds the iteration and staging spans, and shutdown leaves no
+    # tracer behind for whoever trains next.
+    import zlib
+
+    from cfk_tpu.config import ALSConfig
+    from cfk_tpu.data.blocks import Dataset
+    from cfk_tpu.data.synth import synth_coo
+    from cfk_tpu.offload.windowed import train_als_host_window
+
+    ds = Dataset.from_coo(
+        synth_coo(200, 60, 1500, seed=0), layout="tiled", chunk_elems=512,
+        tile_rows=16, accum_max_entities=0,
+    )
+    cfg = ALSConfig(rank=8, lam=0.05, num_iterations=2, seed=0,
+                    layout="tiled", solver="cholesky",
+                    offload_tier="host_window")
+
+    def crc():
+        model = train_als_host_window(ds, cfg, chunks_per_window=2)
+        return zlib.crc32(
+            np.asarray(model.user_factors, np.float32).tobytes())
+
+    assert telemetry.get_tracer() is None
+    off = crc()
+    tracer = telemetry.configure(trace_dir=str(tmp_path / "trace"))
+    try:
+        on = crc()
+        assert len(tracer.events()) > 0
+    finally:
+        path = telemetry.shutdown(write=True)
+    assert on == off
+    with open(path) as f:
+        names = {e["name"] for e in json.load(f)["traceEvents"]
+                 if e.get("ph") == "X"}
+    assert "train/iter" in names
+    assert any(n.endswith("window_stage") for n in names)
+    assert telemetry.get_tracer() is None
+
+
 def test_staging_error_leaves_flight_dump(recorder, tmp_path):
     from cfk_tpu.offload.staging import WindowStager
 

@@ -240,6 +240,46 @@ def test_prewarm_zero_new_traces_in_two_stage_mode(rng):
     assert trace_count() - before == 0
 
 
+def test_two_stage_engine_through_the_request_loop(rng):
+    # The clustered candidate -> exact-rescore path behind the whole
+    # request loop (transport log, RecommendServer coalescing, open-loop
+    # client): every request answered from the two-stage scan, whose
+    # shortlist is a real cut of the catalogue and whose answers overlap
+    # the exact scan's.
+    from cfk_tpu.serving import (
+        RecommendServer,
+        ServeClient,
+        ensure_serve_topics,
+        run_open_loop,
+        zipf_user_rows,
+    )
+    from cfk_tpu.transport import InMemoryBroker
+
+    uf, mf = _clustered(rng)
+    _, sm, si = _seen(rng)
+    eng = _engine(uf, mf, seen=(sm, si))
+    broker = InMemoryBroker()
+    ensure_serve_topics(broker)
+    server = RecommendServer(eng, broker, max_batch=8)
+    client = ServeClient(broker)
+    client.ask([0], 3, server=server)  # warm
+    rep = run_open_loop(
+        client, rate_qps=2000.0, num_requests=24,
+        user_rows=zipf_user_rows(USERS, 24, seed=2), k=3, server=server,
+        drive_server=True,
+    )
+    assert rep.as_row()["answered"] == 24 and rep.batches >= 1
+    scan = dict(eng.last_scan)
+    assert scan["serve_mode"] == "two_stage"
+    assert scan["clusters"] == 16 and scan["probe_clusters"] >= 1
+    assert 0 < scan["shortlist_rows"] <= MOVIES
+    assert scan["bytes_scanned_per_batch"] > 0
+    rows = zipf_user_rows(USERS, 8, seed=1)
+    _, ids = eng.topk(rows, 3)
+    _, oracle = eng.topk(rows, 3, force_exact=True)
+    assert 0.0 < float(recall_at_k(ids, oracle)) <= 1.0
+
+
 def test_default_params_meet_recall_floor():
     from cfk_tpu.plan.cost import SERVE_MIN_RECALL, estimated_recall
 
