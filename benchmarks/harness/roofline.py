@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import dataclasses
 
+from benchmarks.harness import stats
+
 
 @dataclasses.dataclass(frozen=True)
 class Peaks:
@@ -57,3 +59,20 @@ def topk_cost(table_rows: int, rank: int, batch: int, k_top: int,
         bytes=(float(table_rows) * rank * table_bytes_per_cell
                + batch * rank * 4.0 + batch * k_top * 8.0),
     )
+
+
+def serve_batch_floor_s(window: dict, config: dict, pk: Peaks):
+    """The least seconds one chip could take over one batch of a serve
+    window: ``topk_cost`` of the rows the device holds (the whole padded
+    table, or ``table_rows // shards`` of it over a mesh, where the chips
+    scan their slices at once) at the bucket the window's median batch was
+    padded to.  Nothing where the window made no batch."""
+    sizes = window.get("batch_sizes")
+    if not sizes:
+        return None
+    batch = max(8, 1 << (int(stats.median(sizes)) - 1).bit_length())
+    return topk_cost(
+        window["table_rows"] // window.get("shards", 1), config["rank"],
+        batch, window["k_pad"],
+        {"float32": 4, "bfloat16": 2, "int8": 1}[config["table_dtype"]]
+    ).floor_s(pk)

@@ -26,6 +26,7 @@ import argparse
 import contextlib
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
@@ -275,6 +276,11 @@ def main(argv=None, *, require_tpu: bool = True,
         correct &= ok
         ctx.say(f"check {name}: {value:.6g} against limit {limit:g} -> "
                 f"{'ok' if ok else 'FAILED'}  [{why}]")
+    # each number compared beside its limit, last in the result's line and
+    # last on standard error: all the driver keeps of a run that is not correct
+    compared = {name: {"value": float(value) if math.isfinite(value)
+                       else str(value), "limit": float(limit)}
+                for name, value, limit, _ in checks}
 
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": peak}
@@ -300,7 +306,12 @@ def main(argv=None, *, require_tpu: bool = True,
             if reports(m, cell["name"]):
                 metrics[m["name"]] = {"value": float(values[m["name"]]),
                                       "unit": m["unit"]}
+    result["checks"] = compared
     print(json.dumps(result), flush=True)
+    for name, c in compared.items():
+        print(f"check {name}: {c['value']} against limit {c['limit']:g}",
+              file=sys.stderr)
+    print(f"correct: {correct}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -113,6 +113,28 @@ def test_every_cell_reports_enough_and_finds_its_files(manifest):
             assert callable(reader.read)
 
 
+def test_a_share_of_a_peak_lists_every_cell_that_reports_what_it_moves(manifest):
+    """What PR 29 met on the chip: a claim in a cell needs every share of a
+    roofline or of a peak that moves the cell's end-to-end metric reported
+    there, and one scan read under two names could be reported in no cell at
+    once.  So each such metric lists every cell that reports the metric it
+    moves; a PR that adds a cell adds its name to these lists."""
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    shares = [m for m in manifest["per_layer"]
+              if "roofline" in m["name"] or "mfu" in m["name"]]
+    assert shares
+    for m in shares:
+        cells = {w["name"] for w in manifest["workloads"]
+                 if run.reports(e2e[m["moves"]], w["name"])}
+        assert set(m.get("workloads", cells)) == cells, m["name"]
+    # the whole step's share stands beside each kernel's roofline
+    for m in shares:
+        if "roofline" in m["name"]:
+            assert any("mfu" in o["name"] and o["moves"] == m["moves"]
+                       and o.get("workloads") == m.get("workloads")
+                       for o in shares), m["name"]
+
+
 def test_files_under_paths_are_named_from_name_characters(manifest):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for p in manifest["paths"]:

@@ -24,14 +24,28 @@ def drive(capsys, cell, *, trace=0, seed=3_000_000_017, manifest=MANIFEST,
     rc = run.main(["--workload", cell, "--seed", str(seed), "--seconds",
                    str(seconds), "--trace", str(trace), "--manifest", manifest],
                   require_tpu=False)
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     assert rc == 0
-    return json.loads(out.strip().splitlines()[-1]), out
+    res = json.loads(out.strip().splitlines()[-1])
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines on standard error, whatever the run found
+    assert list(res)[-1] == "checks" and len(res["checks"]) >= 5
+    last = err.strip().splitlines()[-len(res["checks"]) - 1:]
+    assert last[-1] == f"correct: {res['correct']}"
+    for line, (name, c) in zip(last, res["checks"].items()):
+        assert set(c) == {"value", "limit"}
+        assert line == (f"check {name}: {c['value']} against limit "
+                        f"{c['limit']:g}")
+    return res, out
 
 
 def test_last_line_schema_and_correct(capsys):
     res, out = drive(capsys, "toy-serve.serve")
-    assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(res) == {"correct", "attempted", "failed", "metrics", "device",
+                        "checks"}
+    assert list(res["checks"]) == ["compiles_in_window", "failed_requests",
+                                   "invalid_id_sets", "rank_gap", "score_err"]
+    assert all(c["value"] <= c["limit"] for c in res["checks"].values())
     assert res["correct"] is True and res["failed"] == 0 and res["attempted"] > 0
     assert set(res["metrics"]) == {"serve_req_per_s", "setup_s"}
     # under its capacity the server answers what is offered (200 req/s)
@@ -47,7 +61,7 @@ def test_last_line_schema_and_correct(capsys):
 def test_traced_run_reports_the_per_layer_metrics(capsys):
     res, _ = drive(capsys, "toy-serve.serve", trace=1)
     assert set(res) == {"correct", "attempted", "failed", "metrics", "device",
-                        "breakdown"}
+                        "breakdown", "checks"}
     assert set(res["metrics"]) == {"setup_data_s", "serve_batch_size.toy"}
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}

@@ -145,6 +145,31 @@ def test_a_program_that_cannot_shard_is_refused_before_any_data(
     assert not any(l.startswith("{") for l in capsys.readouterr().out.splitlines())
 
 
+@pytest.mark.parametrize("manifest, cell, sharded", [
+    (MANIFEST, CELL, True),
+    (os.path.join(os.path.dirname(TOY), "toy", "BENCHMARK.json"),
+     "toy-serve.serve", False)], ids=["toy_x4", "toy"])
+def test_the_sweep_tool_loads_the_runner_the_mix_names(
+        capsys, manifest, cell, sharded):
+    """One tool for every serve cell: a probe, then two shares of what it
+    completed, each a window of the mix's own runner."""
+    sweep = run.load_module(os.path.join(run.HERE, "tools", "sweep.py"),
+                            "sweep_under_test")
+    assert sweep.main(["--cpu", "--manifest", manifest, "--workload", cell,
+                       "--seconds", "1", "--probe", "2000",
+                       "--rates", "0.5,1.0"]) == 0
+    out = capsys.readouterr().out
+    assert ("in 4 shards of 768" in out) is sharded  # the sharded runner's line
+    rows = [json.loads(l.split(" ", 1)[1]) for l in out.splitlines()
+            if l.startswith("SWEEP_ROW ")]
+    assert [r["pass"] for r in rows] == ["probe", 0, 0]
+    capacity = rows[0]["answered_in_window_per_s"]
+    assert 0 < capacity <= 2000
+    assert [r["offered_req_per_s"] for r in rows[1:]] == [
+        round(capacity * s / 10) * 10.0 for s in (0.5, 1.0)]
+    assert all(r["new_traces"] == 0 for r in rows)
+
+
 # -- the blockwise generator and reference against the whole-array ones -------
 
 def test_seen_lists_in_blocks_past_24_bits_of_items():
@@ -240,19 +265,57 @@ def test_shard_readers_on_a_made_up_trace():
     assert _reader("serve_merge_ms").read(ctx, "serve_merge_ms.x4") == (
         pytest.approx(5.0))
     assert _reader("serve_seen_build_ms").read(ctx, "x") == pytest.approx(3.0)
-    # 12,047,872 x 128 x 4 B + the batch in + the selection out at 819 GB/s
+    # 12,047,872 x 128 x 4 B + the batch in + the selection out at 819 GB/s:
+    # a chip scans its slice, not the table
     floor = (12_047_872 * 512 + 256 * 512 + 256 * 16 * 8) / 819e9
-    assert _reader("topk_shard_roofline").read(ctx, "x") == pytest.approx(
+    assert _reader("topk_roofline").read(ctx, "x") == pytest.approx(
         100 * floor / 0.205)
     assert 3.6 < 100 * floor / 0.205 < 3.7
 
 
-def test_shard_readers_say_nothing_of_a_one_device_program():
+def _one_device_ctx():
     trace = xplane.DeviceTrace(
         modules=[[(0.0, 0.17, "jit__topk_call(3)")]],
         ops=[(0.0, 0.16, "_topk_call.1", True)], mark=0.0,
         profile_start_unix_ns=0)
-    ctx = _Ctx(trace, batch_sizes=[256], table_rows=9_350_144, k_pad=16)
+    return _Ctx(trace, batch_sizes=[256], table_rows=9_350_144, k_pad=16)
+
+
+def test_the_roofline_reader_prices_the_whole_table_on_one_device():
+    """The same reader, the one-device entry's name, no ``shards`` in the
+    window: the share of a whole-table scan, not nothing."""
+    floor = (9_350_144 * 512 + 256 * 512 + 256 * 16 * 8) / 819e9
+    assert _reader("topk_roofline").read(_one_device_ctx(), "x") == (
+        pytest.approx(100 * floor / 0.16))
+    assert 3.6 < 100 * floor / 0.16 < 3.7
+    # neither name in the trace: nothing, never 0
+    none = xplane.DeviceTrace(modules=[[(0.0, 0.17, "jit_other(3)")]],
+                              ops=[(0.0, 0.16, "fusion.1", False)], mark=0.0,
+                              profile_start_unix_ns=0)
+    ctx = _Ctx(none, batch_sizes=[256], table_rows=9_350_144, k_pad=16)
+    assert _reader("topk_roofline").read(ctx, "x") is None
+
+
+@pytest.mark.parametrize("shards, rows, period_ms", [
+    (None, 9_350_144, 46.8), (4, 4 * 12_047_872, 57.7)])
+def test_the_whole_steps_share_is_the_floor_over_the_batch_period(
+        shards, rows, period_ms):
+    starts = [1_000 + n * period_ms * 1e3 for n in range(4)]
+    ctx = _Ctx(None, batch_sizes=[256] * 4, table_rows=rows, k_pad=16,
+               **({"shards": shards} if shards else {}))
+    ctx.program_spans = [
+        {"name": "serve/batch", "ph": "X", "ts": t, "dur": 40_000.0,
+         "args": {"batch": n}} for n, t in enumerate(starts)]
+    floor = ((rows // (shards or 1)) * 512 + 256 * 512 + 256 * 16 * 8) / 819e9
+    got = _reader("serve_step_mfu").read(ctx, "serve_step_mfu.saturate")
+    assert got == pytest.approx(100 * floor / (period_ms * 1e-3))
+    assert 12 < got < 14
+    ctx.program_spans = ctx.program_spans[:1]  # one batch has no period
+    assert _reader("serve_step_mfu").read(ctx, "x") is None
+
+
+def test_shard_readers_say_nothing_of_a_one_device_program():
+    ctx = _one_device_ctx()
     for family in ("serve_merge_ms", "serve_seen_build_ms",
-                   "topk_shard_roofline", "setup_table_upload_s"):
+                   "setup_table_upload_s"):
         assert _reader(family).read(ctx, family) is None

@@ -78,9 +78,12 @@ def per_batch(spans, by_batch) -> list:
 def lineup(ctx, runner, seconds: float) -> dict:
     """One window under the profiler, the tracer's Chrome trace written into
     the profiler's directory, the two lined up by their unix clocks alone."""
-    from benchmarks.harness import xplane
+    import re
+
+    from benchmarks.harness import shard_trace, xplane
     from cfk_tpu import telemetry
 
+    scorer = re.compile(shard_trace.SCORER)
     trace_dir = os.path.join(ctx.cache_dir, "trace", "stages-lineup")
     with run.traced(ctx, trace_dir):
         runner.window(seconds)
@@ -97,7 +100,7 @@ def lineup(ctx, runner, seconds: float) -> dict:
     start_ns = trace.profile_start_unix_ns
     kernels = [(start_ns + a * 1e9, start_ns + b * 1e9)
                for a, b, name, custom in trace.ops
-               if custom and name.startswith("_topk_call")]
+               if custom and scorer.search(name)]
     inside, lead_ms, tail_ms = 0, [], []
     for a, b in kernels:
         for lo, hi in compute:
@@ -143,8 +146,9 @@ def main() -> int:
     ns = argparse.Namespace(seed=args.seed, trace=0,
                             seconds=max(args.seconds, args.lineup_seconds))
     ctx = run.Ctx(ns, cell, config, traffic, cache_dir)
-    runner = run.load_module(run.find(search, "runners", "serve.py"),
-                             "bench_runner_serve").make(ctx)
+    runner = run.load_module(
+        run.find(search, "runners", traffic["runner"] + ".py"),
+        "bench_runner_" + traffic["runner"]).make(ctx)
     reader = run.load_module(
         run.find(search, "layer_metrics", "serve_span_ms.py"),
         "bench_metric_serve_span_ms")
