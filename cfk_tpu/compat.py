@@ -92,22 +92,25 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
 def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
                          tile_m, row_offset=0):
     """XLA twin of ``serving.topk_kernel.topk_scores_counted``: (scores,
-    movie rows, [selection rounds, tiles that ran one]).
+    movie rows, [selection rounds, tiles that ran one, exclusion chunks,
+    tiles that ran one]).
 
     Scans the SAME per-tile fold the kernel body runs
     (``serving.topk_kernel._score_tile_fold`` — one shared function, the
     same twin discipline as the Gram kernels) over the same movie tiles in
-    the same order, carrying the same sorted [K, B] selection and gating
-    each tile's selection rounds on the carry's K-th score as the kernel
-    does — so kernel and twin are BIT-IDENTICAL on this route, counts
-    included (``tests/test_serving.py`` pins it).  Crucially the scan's
+    the same order, carrying the same sorted [K, B] selection, gating
+    each tile's selection rounds on the carry's K-th score and taking the
+    fold's branch with or without the masks by the same per-tile scalar
+    (``SeenTiles.hits``) as the kernel does — so kernel and twin are
+    BIT-IDENTICAL on this route, counts included (``tests/test_serving.py``
+    pins it).  Crucially the scan's
     per-step block is [B, tile_m]: no [B, num_movies] score matrix is ever
     materialized here either (the emulation-path memory check in the tests
     compiles this and bounds its temp memory below B·M·4 bytes).
     """
     import jax.numpy as jnp
 
-    from cfk_tpu.serving.topk_kernel import _score_tile_fold
+    from cfk_tpu.serving import topk_kernel
 
     b = u.shape[0]
     m_pad = table.shape[0]
@@ -115,13 +118,20 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     tbl = table.reshape(nt, tile_m, -1)
     sc = (None if scale is None
           else scale.reshape(nt, tile_m, 1).astype(jnp.float32))
-    # slot-major, like the kernel's block: one exclusion slot = one row
-    seen = None if seen_tiles is None else jnp.swapaxes(seen_tiles, 1, 2)
+    seen_tiles = topk_kernel.as_seen_tiles(seen_tiles, tile_m)
+    if seen_tiles is None:
+        seen = hits = None
+        width = 0
+    else:
+        # slot-major, like the kernel's block: one exclusion slot = one
+        # row; the hits are the kernel's second scalar-prefetch operand
+        seen, hits = jnp.swapaxes(seen_tiles.slots, 1, 2), seen_tiles.hits
+        width = seen.shape[1]
     carry0 = jax.tree.map(
         lambda z: match_varying(z, table),
         (jnp.full((k_top, b), -jnp.inf, jnp.float32),
          jnp.full((k_top, b), -1, jnp.int32),
-         jnp.zeros(2, jnp.int32)),
+         jnp.zeros(4, jnp.int32)),
     )
 
     off = jnp.asarray(row_offset, jnp.int32)
@@ -129,18 +139,16 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     def step(carry, i):
         idx = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
         seen_i = None if seen is None else idx(seen)
-        v, ids, rounds = _score_tile_fold(
-            carry[0], carry[1], u, idx(tbl),
-            None if sc is None else idx(sc),
+        v, ids, counts = topk_kernel._score_tile_fold(
+            lambda: (carry[0], carry[1], u, idx(tbl),
+                     None if sc is None else idx(sc)),
             None if seen is None else (
                 lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
             ),
-            0 if seen is None else seen.shape[1],
-            off + i * tile_m,
-            num_movies=num_movies, k_top=k_top,
+            width, None if seen is None else idx(hits), off + i * tile_m,
+            tile_m=tile_m, num_movies=num_movies, k_top=k_top,
         )
-        counts = carry[2] + jnp.stack([rounds, (rounds > 0).astype(jnp.int32)])
-        return (v, ids, counts), None
+        return (v, ids, carry[2] + jnp.stack(counts)), None
 
     (vals, ids, counts), _ = lax.scan(
         step, carry0, jnp.arange(nt, dtype=jnp.int32))

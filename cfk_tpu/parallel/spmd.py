@@ -1505,15 +1505,16 @@ def serve_topk_sharded(
     u,  # [B, k] user-factor batch (replicated)
     table,  # [M_pad, k] item table, M_pad a multiple of shards·tile_m
     scale,  # [M_pad] f32 int8 per-row scales, or None
-    seen_tiles,  # [NT, B, W] int32 (serve_seen_tiles_sharded), or None
+    seen_tiles,  # SeenTiles (serve_seen_tiles_sharded), or None
     *,
     k_top: int,
     num_movies: int,
     tile_m: int = 512,
 ):
     """Item-axis sharded score+top-K: (scores [B, K], movie rows [B, K],
-    counts [shards, 2] — each shard's selection rounds and the tiles that
-    ran any, ``topk_scores_counted``'s, left on their chips unsummed).
+    counts [shards, 4] — each shard's selection rounds and exclusion chunks
+    and the tiles that ran any of either, ``topk_scores_counted``'s, left
+    on their chips unsummed).
 
     The serving analog of the half-steps' exchange, with the direction
     reversed: the ITEM table is row-sharded over the mesh, the [B, k]
@@ -1604,12 +1605,13 @@ def _serve_topk_sharded_fn(mesh, rows_per_shard, has_scale, has_seen,
 
 def serve_seen_tiles_sharded(mesh: Mesh, cells, seen_tiles, *, shape,
                              tile_m: int):
-    """The [NT, B, W] exclusion rectangle of ``serve_topk_sharded``, each
-    shard's NT / shards tiles built on the chip that scans them: ``cells``
-    (one replicated [4, capacity] piece of ``chunk_seen_cells``) goes to
-    every chip, and none ever holds the other chips' slices.  ``seen_tiles``
-    None starts the rectangle; one that earlier pieces went into is donated
-    and takes this piece on top."""
+    """The [NT, B, W] exclusion rectangle of ``serve_topk_sharded`` and its
+    [NT] hits (a ``SeenTiles``), each shard's NT / shards tiles built on
+    the chip that scans them: ``cells`` (one replicated [4, capacity] piece
+    of ``chunk_seen_cells``) goes to every chip, and none ever holds the
+    other chips' slices.  ``seen_tiles`` None starts the rectangle; one
+    that earlier pieces went into is donated and takes this piece on
+    top."""
     fn = _serve_seen_tiles_sharded_fn(mesh, tuple(shape), tile_m,
                                       seen_tiles is None)
     return fn(cells) if seen_tiles is None else fn(cells, seen_tiles)
@@ -1618,7 +1620,7 @@ def serve_seen_tiles_sharded(mesh: Mesh, cells, seen_tiles, *, shape,
 @functools.lru_cache(maxsize=64)
 def _serve_seen_tiles_sharded_fn(mesh, shape, tile_m, fresh):
     from cfk_tpu.serving.engine import note_trace
-    from cfk_tpu.serving.topk_kernel import scatter_seen_cells
+    from cfk_tpu.serving.topk_kernel import SeenTiles, scatter_seen_cells
 
     nt, b, width = shape
     shards = mesh.devices.size
@@ -1634,9 +1636,10 @@ def _serve_seen_tiles_sharded_fn(mesh, shape, tile_m, fresh):
         tile = jnp.where((tile >= 0) & (tile < per), tile, per)
         local = jnp.concatenate(
             [tile[None], _match_varying(cells[1:], tile)])
-        start = (rect[0] if rect else
-                 _match_varying(jnp.full((per, b, width), tile_m, jnp.int32),
-                               tile))
+        start = rect[0] if rect else SeenTiles(
+            _match_varying(jnp.full((per, b, width), tile_m, jnp.int32),
+                           tile),
+            _match_varying(jnp.zeros(per, jnp.int32), tile))
         return scatter_seen_cells(local, start, shape=(per, b, width),
                                   tile_m=tile_m)
 

@@ -576,22 +576,25 @@ class ServeEngine:
                     )
             with span("serve/batch/compute/fetch") as fetch:
                 vals, ids = np.asarray(vals), np.asarray(ids)
-                # a [2] row a shard: the host adds them, no collective
-                counts = np.asarray(counts).reshape(-1, 2).sum(axis=0)
+                # a [4] row a shard: the host adds them, no collective
+                counts = np.asarray(counts).reshape(-1, 4).sum(axis=0)
                 fetch.set(bytes=vals.nbytes + ids.nbytes)
-            # what the gated selection cost this batch, over every tile
-            # scanned (all shards'): rounds run, tiles that ran any
+            # what the data made this batch cost, over every tile scanned
+            # (all shards'): selection rounds run and tiles that ran any,
+            # exclusion chunks run and tiles that ran any
             sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
+                   seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
                    tiles=self.table_rows // self.tile_m)
             vals, ids = vals[:n], ids[:n]
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids
 
     def _seen_tiles(self, chunks, shape, mesh=None):
-        """The [NT, B, W] exclusion rectangle on the device, from the
-        uploaded pieces of the batch's cell list (``_seen_chunks``; None
-        = no exclusion): one run of the scatter program per piece, the
-        first onto a fresh all-padding rectangle.  Every caller — exact,
+        """The [NT, B, W] exclusion rectangle on the device and which of
+        its tiles hold a cell (a ``SeenTiles``), from the uploaded pieces
+        of the batch's cell list (``_seen_chunks``; None = no exclusion):
+        one run of the scatter program per piece, the first onto a fresh
+        all-padding rectangle.  Every caller — exact,
         item-sharded, two-stage rescore — gets its rectangle here, from
         the one ``scatter_seen_cells``; over a mesh each chip builds the
         tiles it scans (``parallel.spmd.serve_seen_tiles_sharded``)."""

@@ -23,13 +23,21 @@ every reduction runs along sublanes, the forms Mosaic lowers:
 - exclusion mask: already-rated items are −inf'd in-register from a
   [NT, B, W] rectangle of in-tile rows — ``seen[b]``'s movie rows, already
   sorted, split at tile boundaries; W is the pow2-bucketed max per-(user,
-  tile) seen count, so the kernel's mask pass is W compares of one [1, B]
-  slot row against the tile's row iota, not a [B, S×T] blow-up.  The host
-  only groups the batch's cells (``group_seen_cells``: a few thousand
-  (tile, slot, position, in-tile row) columns); the rectangle itself —
-  299 MB at 18,262 tiles × 256 slots × 16 — is filled and scattered on the
-  device (``scatter_seen_cells``), and ``build_seen_tiles`` is the same
-  rectangle in numpy, the tests' oracle,
+  tile) seen count, so the mask pass is W compares of one [1, B] slot row
+  against the tile's row iota, not a [B, S×T] blow-up.  The host only
+  groups the batch's cells (``group_seen_cells``: a few thousand (tile,
+  slot, position, in-tile row) columns); the rectangle itself — 299 MB at
+  18,262 tiles × 256 slots × 16 — is filled and scattered on the device
+  (``scatter_seen_cells``), and ``build_seen_tiles`` is the same rectangle
+  in numpy, the tests' oracle,
+- both masks only on the tiles that need them: a batch's few thousand
+  cells touch under a tenth of 18,262 tiles, and only the last tile
+  reaches past ``num_movies``.  Every other tile's slots are all padding,
+  which no row equals, so it takes the fold's branch without the masks
+  and loses nothing.  The branch is on a scalar known before the tile is
+  scored: ``SeenTiles.hits``, one int32 a tile, made from the cell list
+  beside the rectangle and scalar-prefetched into SMEM beside the row
+  offset,
 - K-selection merge, gated: the [K, B] carry is kept sorted (descending;
   equal scores by ascending row; empty slots at the tail), so its last
   row is each user's K-th score.  One pass takes the tile's per-user
@@ -41,9 +49,9 @@ every reduction runs along sublanes, the forms Mosaic lowers:
   (``lax.top_k`` has no Mosaic lowering; neither has ``dynamic_slice`` on
   values).  A stream in no particular order changes a user's top-K about
   K/i times in tile i, so most tiles cost the gating pass and no round;
-  the kernel counts the rounds it ran and the tiles that ran any
-  (``topk_scores_counted``; ``ServeEngine.topk`` puts them on its compute
-  span).
+  the kernel counts the rounds it ran and the tiles that ran any, and
+  likewise the exclusion chunks (``topk_scores_counted``;
+  ``ServeEngine.topk`` puts them on its compute span).
 
 The merge step (``_score_tile_fold``) is ONE function shared by the Mosaic
 kernel body and the XLA twin (``compat.emulate_topk_counted`` scans it over
@@ -52,12 +60,13 @@ counts included — the same twin discipline as the Gram kernels.  The
 kernel compiles for the v5e at f32/bf16/int8 and under the 2×2 shard_map
 (``tests/test_chip_compile.py``) and matches the twin on the chip
 (``tests/test_pallas_tpu.py``); what it costs there is in PERF.md
-(sections 5 and 6, PR 27).
+(sections 5 and 6, PRs 27 and 31).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -94,11 +103,13 @@ def serve_compute_dtype(table_dtype):
     return jnp.float32, lax.Precision.HIGHEST
 
 
-def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
-                     tile_base, *, num_movies, k_top):
+def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
+                     tile_m, num_movies, k_top):
     """Fold one movie tile into the running top-K carry: ``(carry_v,
-    carry_i, rounds)``, ``rounds`` the int32 count of selection rounds this
-    tile needed (0 for most tiles).
+    carry_i, counts)``, ``counts`` four int32 scalars — the selection
+    rounds this tile needed (0 for most tiles) and whether it ran any, the
+    exclusion chunks of ``_SEEN_CHUNK`` slots it ran (its whole width, or
+    none) and whether it ran any: what ``topk_scores_counted`` adds up.
 
     The ONE copy of the per-tile math — the Mosaic kernel body and the XLA
     twin both call exactly this.  Everything is MOVIE-MAJOR ([T, B] scores,
@@ -108,11 +119,26 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     reduction this selection needs that Mosaic lowers (``lax.top_k``,
     ``dynamic_slice`` on values and lane-offset slices do not).
 
-    carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty), u [B, k],
-    tile [T, k] (f32/bf16/int8), scale [T, 1] f32 or None,
-    ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j
-    (T = padding) for j < ``seen_width`` (None = no exclusion),
-    tile_base scalar int32.
+    ``read()`` → (carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty),
+    u [B, k], tile [T, k] (f32/bf16/int8), scale [T, 1] f32 or None),
+    ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j < the
+    static ``seen_width`` (T = padding), ``seen_hit`` scalar int32 =
+    whether any of this tile's slots holds a cell (``SeenTiles.hits``) —
+    both None without exclusion — tile_base scalar int32, T = ``tile_m``.
+
+    With exclusion the fold is two branches of one ``lax.cond`` on a scalar
+    known before the tile is scored: a tile that holds a cell or reaches
+    past ``num_movies`` (the table's last) runs both masks, every other
+    tile — nine in ten of a serve cell's — runs neither, and loses
+    nothing: its slot rows are all T, which no row equals, and none of its
+    rows is padding.  Each branch is the whole fold, matmul to selection,
+    and reads its operands itself (``read``), so the masks stay fused with
+    the score block as they are without the gate and only the [K, B] carry
+    crosses the branch.  On the v5e that is what the gate has to be: a
+    trip count on the mask loops, or a branch around the masks alone,
+    leaves the [T, B] block in VMEM between matmul and masks and costs
+    more than the masks do, and operands read ahead of the branch are
+    spilled across it (PERF.md section 6, PR 31).
 
     The carry is SORTED: scores descending, equal scores by ascending
     global row, empty slots (−inf / −1) at the tail — so its last row is
@@ -129,66 +155,84 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     which can enter costs the one gating pass, the worst case (scores
     ascending along the table) min(K, T) rounds.
     """
-    t = tile.shape[0]
-    b = u.shape[0]
-    ct, prec = serve_compute_dtype(tile.dtype)
-    if tile.dtype == jnp.int8:
-        # canonical dequant placement (ops.quant): codes → f32 × per-row
-        # scale, before the single matmul
-        tile_f = tile.astype(jnp.float32) * scale
+    t = tile_m
+    hit = jnp.int32(0) if seen_row is None else seen_hit
+
+    def fold(masked):
+        carry_v, carry_i, u, tile, scale = read()
+        b = u.shape[0]
+        ct, prec = serve_compute_dtype(tile.dtype)
+        if tile.dtype == jnp.int8:
+            # canonical dequant placement (ops.quant): codes → f32 ×
+            # per-row scale, before the single matmul
+            tile_f = tile.astype(jnp.float32) * scale
+        else:
+            tile_f = tile.astype(ct)
+        scores = jax.lax.dot_general(
+            tile_f, u.astype(ct),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec,
+        )  # [T, B]
+        row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile row
+        neg = jnp.float32(-jnp.inf)
+        if masked:
+            scores = jnp.where(tile_base + row < num_movies, scores, neg)
+        if masked and seen_row is not None:
+            def mask_chunk(c, sc):
+                # _SEEN_CHUNK slots per trip, unrolled by hand: Mosaic's
+                # loop lowering takes unroll=1 or a full unroll only
+                for j in range(_SEEN_CHUNK):
+                    sc = jnp.where(row == seen_row(c * _SEEN_CHUNK + j),
+                                   neg, sc)
+                return sc
+
+            scores = lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk,
+                                   scores)
+        first = lax.broadcasted_iota(jnp.int32, (k_top, b), 0) == 0
+
+        def tile_max(sc):
+            return jnp.max(sc, axis=0, keepdims=True)  # [1, B]
+
+        def entrant(state):
+            _, cv, _, ms, _ = state
+            return jnp.max((ms > cv[k_top - 1:]).astype(jnp.int32)) > 0
+
+        def select(state):
+            sc, cv, ci, ms, rounds = state
+            pos = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
+            # Slots whose value is >= the entrant stay; the entrant lands
+            # in the first slot that is not, and the rest move one sublane
+            # down.  A user with nothing to enter (ms <= its K-th score)
+            # keeps every slot, so it needs no mask of its own — and its
+            # consumed tile row could not have entered later either (the
+            # K-th score only rises).
+            stay = cv >= ms
+            up_v, up_i = pltpu.roll(cv, 1, 0), pltpu.roll(ci, 1, 0)
+            here = first | (up_v >= ms)
+            cv = jnp.where(stay, cv, jnp.where(here, ms, up_v))
+            ci = jnp.where(stay, ci, jnp.where(here, tile_base + pos, up_i))
+            sc = jnp.where(row == pos, neg, sc)
+            return sc, cv, ci, tile_max(sc), rounds + 1
+
+        # Under shard_map's own tracing (the twin's sharded route) the
+        # loop state varies over the mesh like the tile's scores do.
+        rounds = match_varying(jnp.int32(0), scores)
+        _, carry_v, carry_i, _, rounds = lax.while_loop(
+            entrant, select,
+            (scores, carry_v, carry_i, tile_max(scores), rounds))
+        return carry_v, carry_i, rounds
+
+    if seen_row is None:
+        # nothing to skip but the padding compare: not worth a branch
+        carry_v, carry_i, rounds = fold(True)
     else:
-        tile_f = tile.astype(ct)
-    scores = jax.lax.dot_general(
-        tile_f, u.astype(ct),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=prec,
-    )  # [T, B]
-    row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile movie row
-    neg = jnp.float32(-jnp.inf)
-    scores = jnp.where(tile_base + row < num_movies, scores, neg)
-    if seen_row is not None:
-        def mask_chunk(c, sc):
-            # _SEEN_CHUNK slots per trip, unrolled by hand: Mosaic's loop
-            # lowering takes unroll=1 or a full unroll only
-            for j in range(_SEEN_CHUNK):
-                sc = jnp.where(row == seen_row(c * _SEEN_CHUNK + j), neg, sc)
-            return sc
-
-        scores = lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk,
-                               scores)
-    first = lax.broadcasted_iota(jnp.int32, (k_top, b), 0) == 0
-
-    def tile_max(sc):
-        return jnp.max(sc, axis=0, keepdims=True)  # [1, B]
-
-    def entrant(state):
-        _, cv, _, ms, _ = state
-        return jnp.max((ms > cv[k_top - 1:]).astype(jnp.int32)) > 0
-
-    def select(state):
-        sc, cv, ci, ms, rounds = state
-        pos = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
-        # Slots whose value is >= the entrant stay; the entrant lands in
-        # the first slot that is not, and the rest move one sublane down.
-        # A user with nothing to enter (ms <= its K-th score) keeps every
-        # slot, so it needs no mask of its own — and its consumed tile row
-        # could not have entered later either (the K-th score only rises).
-        stay = cv >= ms
-        up_v, up_i = pltpu.roll(cv, 1, 0), pltpu.roll(ci, 1, 0)
-        here = first | (up_v >= ms)
-        cv = jnp.where(stay, cv, jnp.where(here, ms, up_v))
-        ci = jnp.where(stay, ci, jnp.where(here, tile_base + pos, up_i))
-        sc = jnp.where(row == pos, neg, sc)
-        return sc, cv, ci, tile_max(sc), rounds + 1
-
-    # Under shard_map's own tracing (the twin's sharded route) the loop
-    # state varies over the mesh like the tile's scores do.
-    rounds = match_varying(jnp.int32(0), scores)
-    _, carry_v, carry_i, _, rounds = lax.while_loop(
-        entrant, select,
-        (scores, carry_v, carry_i, tile_max(scores), rounds))
-    return carry_v, carry_i, rounds
+        carry_v, carry_i, rounds = lax.cond(
+            (hit > 0) | (tile_base + t > num_movies),
+            lambda: fold(True), lambda: fold(False))
+    counts = (rounds, (rounds > 0).astype(jnp.int32),
+              hit * (seen_width // _SEEN_CHUNK), hit)
+    return carry_v, carry_i, counts
 
 
 def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
@@ -262,22 +306,40 @@ def chunk_seen_cells(cells, capacity: int, num_tiles: int,
     return chunks
 
 
-def scatter_seen_cells(cells, seen_tiles=None, *, shape, tile_m):
-    """The [NT, B, W] exclusion rectangle, built where the kernel reads it.
+class SeenTiles(NamedTuple):
+    """The exclusion rectangle as the device holds it: ``slots`` [NT, B, W]
+    int32 in-tile rows (``tile_m`` = padding) and ``hits`` [NT] int32, 1
+    for a tile any of whose slots holds a cell — the scalar the scorer's
+    fold branches on, made where the rectangle is made
+    (``scatter_seen_cells``) so that the scorer need not read 299 MB again
+    to find it."""
 
-    ``cells`` is one [4, capacity] piece of ``chunk_seen_cells``.  Entry
+    slots: jax.Array
+    hits: jax.Array
+
+
+def scatter_seen_cells(cells, seen_tiles=None, *, shape, tile_m):
+    """The [NT, B, W] exclusion rectangle, built where the kernel reads it,
+    and which tiles of it hold a cell: a ``SeenTiles``.
+
+    ``cells`` is one [4, capacity] piece of ``chunk_seen_cells``.  Slot
     [t, b, w] is the w-th in-tile column of batch slot b's seen movies
     inside movie tile t, padded with ``tile_m`` (which no in-tile column
-    equals).  ``seen_tiles`` None starts from the all-padding rectangle;
-    a rectangle that earlier pieces were scattered into takes this one on
-    top.  No ``indices_are_sorted`` / ``unique_indices``: the chip's
-    compiler folds the three indices into one and every dropped column
-    into the same out-of-range value, which is neither, and with the hints
-    the v5e wrote a wrong rectangle (PERF.md, PR 25)."""
+    equals); hit t is 1 where some piece named tile t.  ``seen_tiles``
+    None starts from the all-padding rectangle; one that earlier pieces
+    were scattered into takes this one on top.  No ``indices_are_sorted``
+    / ``unique_indices``: the chip's compiler folds the three indices into
+    one and every dropped column into the same out-of-range value, which
+    is neither, and with the hints the v5e wrote a wrong rectangle
+    (PERF.md, PR 25).  Both scatters drop the same columns (those whose
+    tile is out of range), so no slot is written in a tile without a hit."""
     if seen_tiles is None:
-        seen_tiles = jnp.full(shape, tile_m, jnp.int32)
-    return seen_tiles.at[cells[0], cells[1], cells[2]].set(
-        cells[3], mode="drop")
+        seen_tiles = SeenTiles(jnp.full(shape, tile_m, jnp.int32),
+                               jnp.zeros(shape[:1], jnp.int32))
+    slots, hits = seen_tiles
+    return SeenTiles(
+        slots.at[cells[0], cells[1], cells[2]].set(cells[3], mode="drop"),
+        hits.at[cells[0]].max(1, mode="drop"))
 
 
 def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
@@ -295,8 +357,23 @@ def build_seen_tiles(seen_movies, seen_indptr, batch_rows, *, num_movies,
     return out
 
 
-def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
-                 seen_width, with_scale):
+def as_seen_tiles(seen_tiles, tile_m):
+    """``seen_tiles`` as the scorer takes it — None, a ``SeenTiles``, or a
+    bare [NT, B, W] rectangle — as a ``SeenTiles`` (or None).  A bare
+    rectangle gets its hits by being read: 1 for a tile that holds anything
+    but the ``tile_m`` padding, so they can never miss a cell.  That is
+    what a caller without the cell list pays (the tests' numpy-built
+    rectangles; ~1 ms a batch on the v5e at the serve cell's 299 MB, which
+    is why the server's hits come from ``scatter_seen_cells``)."""
+    if seen_tiles is None or isinstance(seen_tiles, SeenTiles):
+        return seen_tiles
+    return SeenTiles(
+        seen_tiles,
+        jnp.any(seen_tiles != tile_m, axis=(1, 2)).astype(jnp.int32))
+
+
+def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
+                 with_scale):
     """Grid step i: fold movie tile i into the resident [K, B] carry.
 
     The carry is two VMEM scratch blocks: step 0 initializes them, every
@@ -304,13 +381,19 @@ def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
     (constant-index, resident) output blocks.  ``off_ref`` (scalar-
     prefetched, [1] int32) is the shard's global row offset — 0 on a
     single device; under item-axis sharding each shard's tile i covers
-    global movie rows [off + i·T, off + (i+1)·T).  ``counts_ref`` (SMEM,
-    [2] int32) accumulates the selection rounds run and the tiles that
-    ran at least one.
+    global movie rows [off + i·T, off + (i+1)·T).  With exclusion a second
+    scalar-prefetched operand follows it, ``hits_ref`` ([NT] int32,
+    ``SeenTiles.hits``): whether tile i holds a cell to mask.
+    ``counts_ref`` (SMEM, [4] int32) accumulates the selection rounds run,
+    the tiles that ran at least one, the exclusion chunks run
+    (``_SEEN_CHUNK`` slots each, the rectangle's whole width on a tile
+    that is hit) and the tiles that ran them.
     """
     refs = list(refs)
+    hits_ref = refs.pop(0) if with_seen else None
+    u_ref, tbl_ref = refs.pop(0), refs.pop(0)
     scale_ref = refs.pop(0) if with_scale else None
-    seen_ref = refs.pop(0) if seen_width else None
+    seen_ref = refs.pop(0) if with_seen else None
     vals_ref, ids_ref, counts_ref, cv_ref, ci_ref = refs
     i = pl.program_id(0)
 
@@ -318,26 +401,26 @@ def _topk_kernel(off_ref, u_ref, tbl_ref, *refs, t, k_top, num_movies, b,
     def _():
         cv_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
         ci_ref[...] = jnp.full((k_top, b), -1, jnp.int32)
-        counts_ref[0] = 0
-        counts_ref[1] = 0
+        for j in range(4):
+            counts_ref[j] = 0
 
     # The carry lives in scratch, not in the output blocks: the selection
     # loop's state starts from it, and inside a compiled kernel under
     # shard_map a value read from an OUTPUT ref keeps the out_shape's vma
     # while everything computed from it has none (jax 0.9.0) — a loop
     # seeded with one could not typecheck.
-    new_v, new_i, rounds = _score_tile_fold(
-        cv_ref[...], ci_ref[...], u_ref[...], tbl_ref[...],
-        scale_ref[...] if scale_ref is not None else None,
-        (lambda j: seen_ref[0, pl.ds(j, 1), :]) if seen_width else None,
-        seen_width,
-        off_ref[0] + i * t,
-        num_movies=num_movies, k_top=k_top,
+    new_v, new_i, counts = _score_tile_fold(
+        lambda: (cv_ref[...], ci_ref[...], u_ref[...], tbl_ref[...],
+                 scale_ref[...] if scale_ref is not None else None),
+        (lambda j: seen_ref[0, pl.ds(j, 1), :]) if with_seen else None,
+        seen_ref.shape[1] if with_seen else 0,
+        hits_ref[i] if with_seen else None, off_ref[0] + i * t,
+        tile_m=t, num_movies=num_movies, k_top=k_top,
     )
     cv_ref[...] = new_v
     ci_ref[...] = new_i
-    counts_ref[0] += rounds
-    counts_ref[1] += (rounds > 0).astype(jnp.int32)
+    for j, n in enumerate(counts):
+        counts_ref[j] += n
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -349,7 +432,7 @@ def topk_scores_pallas(
     u: jax.Array,  # [B, k] user-factor batch (f32 or bf16)
     table: jax.Array,  # [M_pad, k] item table (f32 / bf16 / int8 codes)
     scale: jax.Array | None,  # [M_pad] f32 per-row int8 scales, else None
-    seen_tiles: jax.Array | None,  # [NT, B, W] int32 (scatter_seen_cells)
+    seen_tiles,  # SeenTiles (scatter_seen_cells), a bare [NT, B, W], or None
     *,
     k_top: int,
     num_movies: int,
@@ -376,10 +459,14 @@ def topk_scores_pallas(
 
 def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
                         tile_m=512, row_offset=0, interpret=None):
-    """``topk_scores_pallas`` and what the selection cost: ``(scores, movie
-    rows, counts)``, counts [2] int32 = selection rounds run over the
-    table's tiles, and tiles that ran at least one (of ``M_pad / tile_m``).
-    What ``ServeEngine.topk`` puts on its compute span."""
+    """``topk_scores_pallas`` and what the data made it cost: ``(scores,
+    movie rows, counts)``, counts [4] int32 = selection rounds run over the
+    table's tiles, tiles that ran at least one (of ``M_pad / tile_m``),
+    exclusion chunks run (``_SEEN_CHUNK`` compares each: the rectangle's
+    width on every tile that holds a cell) and tiles that ran them.  What
+    ``ServeEngine.topk`` puts on its compute span.  ``seen_tiles`` is a
+    ``SeenTiles`` (``scatter_seen_cells``) or a bare [NT, B, W] rectangle
+    (``as_seen_tiles``)."""
     b, k = u.shape
     m_pad = table.shape[0]
     if m_pad % tile_m != 0:
@@ -390,15 +477,19 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     if not 1 <= k_top:
         raise ValueError(f"k_top must be >= 1, got {k_top}")
     nt = m_pad // tile_m
-    if seen_tiles is not None and seen_tiles.shape[:2] != (nt, b):
-        raise ValueError(
-            f"seen_tiles shape {seen_tiles.shape} != ({nt}, {b}, W)"
-        )
-    if seen_tiles is not None and seen_tiles.shape[2] % _SEEN_CHUNK != 0:
-        raise ValueError(
-            f"seen_tiles width {seen_tiles.shape[2]} must be a multiple of "
-            f"{_SEEN_CHUNK} (group_seen_cells pads it)"
-        )
+    seen_tiles = as_seen_tiles(seen_tiles, tile_m)
+    if seen_tiles is not None:
+        slots, hits = seen_tiles
+        if slots.shape[:2] != (nt, b) or hits.shape != (nt,):
+            raise ValueError(
+                f"seen_tiles shapes {slots.shape}, {hits.shape} != "
+                f"({nt}, {b}, W), ({nt},)"
+            )
+        if slots.shape[2] % _SEEN_CHUNK != 0:
+            raise ValueError(
+                f"seen_tiles width {slots.shape[2]} must be a multiple of "
+                f"{_SEEN_CHUNK} (group_seen_cells pads it)"
+            )
     if (scale is None) != (table.dtype != jnp.int8):
         raise ValueError(
             "per-row scale required exactly when the table is int8 "
@@ -415,22 +506,26 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             u, table, scale, seen_tiles, k_top=k_top,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
+    # index maps take the grid step and then the scalar-prefetch refs: the
+    # row offset and, with exclusion, the tiles' hits
     in_specs = [
-        pl.BlockSpec((b, k), lambda i, off: (0, 0)),  # u: resident
-        pl.BlockSpec((tile_m, k), lambda i, off: (i, 0)),  # table: streamed
+        pl.BlockSpec((b, k), lambda i, *_: (0, 0)),  # u: resident
+        pl.BlockSpec((tile_m, k), lambda i, *_: (i, 0)),  # table: streamed
     ]
+    prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
     ops = [u, table]
     if scale is not None:
-        in_specs.append(pl.BlockSpec((tile_m, 1), lambda i, off: (i, 0)))
+        in_specs.append(pl.BlockSpec((tile_m, 1), lambda i, *_: (i, 0)))
         ops.append(scale.reshape(m_pad, 1).astype(jnp.float32))
     seen_width = 0
     if seen_tiles is not None:
-        seen_width = seen_tiles.shape[2]
+        seen_width = slots.shape[2]
+        prefetch.append(hits)
         # slot-major for the kernel: one exclusion slot = one [1, B] row
         in_specs.append(
-            pl.BlockSpec((1, seen_width, b), lambda i, off: (i, 0, 0))
+            pl.BlockSpec((1, seen_width, b), lambda i, *_: (i, 0, 0))
         )
-        ops.append(jnp.swapaxes(seen_tiles, 1, 2))
+        ops.append(jnp.swapaxes(slots, 1, 2))
     kwargs = {}
     if not interpret:
         # the [K, B] result (2× for Mosaic's output double-buffer; the
@@ -448,18 +543,17 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             )
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         grid=(nt,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
-            pl.BlockSpec((k_top, b), lambda i, off: (0, 0)),
+            pl.BlockSpec((k_top, b), lambda i, *_: (0, 0)),
+            pl.BlockSpec((k_top, b), lambda i, *_: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[pltpu.VMEM((k_top, b), jnp.float32),
                         pltpu.VMEM((k_top, b), jnp.int32)],
     )
-    off = jnp.asarray(row_offset, jnp.int32).reshape(1)
     # under shard_map the selection varies over the mesh like the table
     # slice it was scored from (as in ops.pallas.gram_kernel)
     vma = typeof_vma(table)
@@ -469,12 +563,13 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     vals, ids, counts = pl.pallas_call(
         functools.partial(
             _topk_kernel, t=tile_m, k_top=k_top, num_movies=num_movies,
-            b=b, seen_width=seen_width, with_scale=scale is not None,
+            b=b, with_seen=seen_tiles is not None,
+            with_scale=scale is not None,
         ),
         grid_spec=grid_spec,
         out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32),
-                   mk((2,), jnp.int32)),
+                   mk((4,), jnp.int32)),
         interpret=bool(interpret),
         **kwargs,
-    )(off, *ops)
+    )(*prefetch, *ops)
     return vals.T, ids.T, counts

@@ -320,20 +320,24 @@ def test_bucket_port_piece_rank64_bf16(chip, as_tpu, rows, width):
 
 # -- serving -----------------------------------------------------------------
 
-def _compile_scorer(chip, dtype, b, k_top, w):
-    """The one-device scorer over the ML-25M table (59,047 × 128)."""
-    from cfk_tpu.serving.topk_kernel import topk_scores_counted
+def _compile_scorer(chip, dtype, b, k_top, w, m=59_047):
+    """The one-device scorer, by default over the ML-25M table (59,047 ×
+    128)."""
+    from cfk_tpu.serving.topk_kernel import SeenTiles, topk_scores_counted
 
-    m_pad, k, tile_m = 59_392, 128, 512
+    k, tile_m = 128, 512
+    m_pad = -(-m // tile_m) * tile_m
+    nt = m_pad // tile_m
     scale = [chip((m_pad,), f32)] if dtype == i8 else []
 
     def fn(u, tbl, seen, *sc):
         return topk_scores_counted(
             u, tbl, sc[0] if sc else None, seen, k_top=k_top,
-            num_movies=59_047, tile_m=tile_m, interpret=False)
+            num_movies=m, tile_m=tile_m, interpret=False)
 
-    _compile(fn, chip((b, k), f32), chip((m_pad, k), dtype),
-             chip((m_pad // tile_m, b, w), i32), *scale)
+    return _compile(fn, chip((b, k), f32), chip((m_pad, k), dtype),
+                    SeenTiles(chip((nt, b, w), i32), chip((nt,), i32)),
+                    *scale)
 
 
 @pytest.mark.parametrize("dtype", [f32, bf16, i8])
@@ -353,6 +357,17 @@ def test_serve_scorer_gated_selection_shapes(chip, b, k_top):
     _compile_scorer(chip, f32, b=b, k_top=k_top, w=16)
 
 
+def test_serve_scorer_amazon14_cell_shape(chip):
+    """The one-chip serve cell's own call: 18,262 tiles × 256 rows × 16
+    slots, so the tiles' hits ride in as a second scalar-prefetch operand
+    of 18,262 words of SMEM (the four-chip cell's 23,531 a shard:
+    ``test_serve_sharded_four_devices``), and the fold's two branches —
+    the whole fold with the masks and without — compile side by side."""
+    text = _compile_scorer(chip, f32, b=256, k_top=16, w=16,
+                           m=9_350_000).as_text()
+    assert "s32[18262]" in text
+
+
 @pytest.mark.parametrize("m,k_top,b,dtype", [
     (59_047, 10, 64, bf16),  # the ML-25M table
     (48_190_000, 16, 256, f32),  # Amazon-2023: 24.7 GB, 6.17 GB a chip
@@ -368,6 +383,7 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
 
     from cfk_tpu.parallel import spmd
     from cfk_tpu.parallel.mesh import AXIS
+    from cfk_tpu.serving.topk_kernel import SeenTiles
 
     mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
     k, tile_m, w = 128, 512, 16
@@ -376,9 +392,9 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
     on = lambda shape, dt, spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(mesh, spec))
     fn = spmd._serve_topk_sharded_fn(mesh, per, False, True, k_top, m, tile_m)
+    seen = SeenTiles(on((nt, b, w), i32, P(AXIS)), on((nt,), i32, P(AXIS)))
     scorer = fn.lower(
-        on((b, k), f32, P()), on((m_pad, k), dtype, P(AXIS)),
-        on((nt, b, w), i32, P(AXIS)),
+        on((b, k), f32, P()), on((m_pad, k), dtype, P(AXIS)), seen,
     ).compile()
     text = scorer.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
@@ -391,6 +407,6 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
             mesh, (nt, b, w), tile_m, fresh)
         ops = [on((4, 16 * b), i32, P())]
         if not fresh:
-            ops.append(on((nt, b, w), i32, P(AXIS)))
+            ops.append(seen)
         mem = build.lower(*ops).compile().memory_analysis()
         assert mem.output_size_in_bytes < 1.5 * nt // 4 * max(b, 128) * w * 4

@@ -349,14 +349,17 @@ def test_dense_gather_f32_rank128_compiled():
     np.testing.assert_array_equal(np.asarray(rows_c), np.asarray(rows_i))
 
 
+@pytest.mark.parametrize("cells", ["none", "one_tile", "every_tile"])
 @pytest.mark.parametrize("order", ["random", "ascending", "equal"])
 @pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
-def test_topk_compiled_matches_twin(table_dtype, order):
+def test_topk_compiled_matches_twin(table_dtype, order, cells):
     """The serve scorer compiled (movie-major fold, selection rounds gated
-    on the carry's K-th score) against its XLA twin: the same ids wherever
-    scores are not within round-off of each other, the same scores to MXU
-    tolerance.  ``ascending`` makes every tile enter every user's top-K
-    (the most rounds the gate can ask for) with scores that are exact in
+    on the carry's K-th score, the masks on the tiles that hold a cell or
+    cross ``num_movies``: ``cells`` puts the seen rows nowhere, in the
+    third of the four tiles, or anywhere) against its XLA twin: the same
+    ids wherever scores are not within round-off of each other, the same
+    scores to MXU tolerance.  ``ascending`` makes every tile enter every
+    user's top-K (the most rounds the gate can ask for) with scores exact in
     a float table, so compiled and twin agree to the bit; ``equal``
     makes every score of a user the same, so the ids are its K lowest
     unseen rows whatever the precision."""
@@ -386,19 +389,28 @@ def test_topk_compiled_matches_twin(table_dtype, order):
         tbl[:m] = rng.standard_normal(k).astype(np.float32)
     data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
     u = jnp.asarray(u)
-    seen = [np.sort(rng.choice(m, size=int(rng.integers(0, 40)),
-                               replace=False)).astype(np.int32)
-            for _ in range(b)]
+    lo, hi = {"none": (0, m), "one_tile": (2 * tile, 3 * tile),
+              "every_tile": (0, m)}[cells]
+    seen = [lo + np.sort(rng.choice(
+        hi - lo, size=0 if cells == "none" else int(rng.integers(0, 40)),
+        replace=False)).astype(np.int32) for _ in range(b)]
     indptr = np.zeros(b + 1, np.int64)
     indptr[1:] = np.cumsum([s.size for s in seen])
     st = jnp.asarray(build_seen_tiles(
         np.concatenate(seen), indptr, np.arange(b), num_movies=m,
         tile_m=tile))
+    # a tile a user has rated into runs the rectangle's width, 16 slots a
+    # chunk; the others none
+    hit = {"none": 0, "one_tile": 1, "every_tile": 4}[cells]
+    assert hit == len(np.unique(np.concatenate(seen) // tile))
+    chunks = [hit * (st.shape[2] // 16), hit]
     kw = dict(k_top=k_top, num_movies=m, tile_m=tile)
     v_c, i_c, n_c = topk_scores_counted(u, data, scale, st, interpret=False,
                                         **kw)
     v_t, i_t, n_t = emulate_topk_counted(u, data, scale, st, **kw)
     v_c, i_c, v_t, i_t = map(np.asarray, (v_c, i_c, v_t, i_t))
+    assert np.asarray(n_c)[2:].tolist() == chunks
+    assert np.asarray(n_t)[2:].tolist() == chunks
     tol = 2e-2 if table_dtype == "bfloat16" else 2e-3
     np.testing.assert_allclose(v_c, v_t, rtol=tol, atol=tol)
     assert (np.diff(v_c, axis=1) <= 0).all()  # descending
@@ -412,8 +424,8 @@ def test_topk_compiled_matches_twin(table_dtype, order):
     unseen = [np.setdiff1d(np.arange(m), s) for s in seen]
     if order == "ascending":
         # every tile enters: K rounds each (the last has 464 real rows)
-        assert np.asarray(n_c).tolist() == [k_top * 4, 4]
-        assert np.asarray(n_t).tolist() == [k_top * 4, 4]
+        assert np.asarray(n_c)[:2].tolist() == [k_top * 4, 4]
+        assert np.asarray(n_t)[:2].tolist() == [k_top * 4, 4]
         if table_dtype == "int8":
             # the codes round: neither exact sums nor the strict order hold
             assert (i_c == i_t).mean() > 0.95
@@ -424,7 +436,7 @@ def test_topk_compiled_matches_twin(table_dtype, order):
     else:
         want = np.stack([x[:k_top] for x in unseen])
         np.testing.assert_array_equal(i_c, want)
-        assert np.asarray(n_c).tolist() == [k_top, 1]
+        assert np.asarray(n_c)[:2].tolist() == [k_top, 1]
     np.testing.assert_array_equal(i_c, i_t)
     assert np.asarray(n_c).tolist() == np.asarray(n_t).tolist()
 
@@ -466,7 +478,9 @@ def test_seen_rectangle_built_on_the_chip_equals_host_oracle(capacity):
     for chunk in chunks:
         got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
                                    tile_m=tile)
-    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got.slots), want)
+    np.testing.assert_array_equal(np.asarray(got.hits),
+                                  (want != tile).any(axis=(1, 2)))
 
 
 @pytest.mark.parametrize("caller", ["exact", "one_device_mesh", "two_stage"])
@@ -549,9 +563,11 @@ def test_four_chip_sharded_engine_equals_one_device(capacity, monkeypatch):
         got = serve_seen_tiles_sharded(
             four.mesh, jax.device_put(chunk, engine_mod._replicated(four.mesh)),
             got, shape=shape, tile_m=tile)
-    for shard in got.addressable_shards:
-        np.testing.assert_array_equal(np.asarray(shard.data),
-                                      want[shard.index])
+    for part, oracle in ((got.slots, want),
+                         (got.hits, (want != tile).any(axis=(1, 2)))):
+        for shard in part.addressable_shards:
+            np.testing.assert_array_equal(np.asarray(shard.data),
+                                          oracle[shard.index])
 
     if capacity is not None:
         monkeypatch.setattr(engine_mod, "seen_cell_capacity",

@@ -149,13 +149,16 @@ def test_each_chip_builds_its_slice_of_the_rectangle(shards, pieces):
     for chunk in chunk_seen_cells(cells, -(-cells.shape[1] // pieces), nt):
         got = serve_seen_tiles_sharded(mesh, jnp.asarray(chunk), got,
                                        shape=shape, tile_m=TILE)
-    assert got.shape == want.shape
-    assert len(got.addressable_shards) == shards
-    for shard, device in zip(got.addressable_shards, mesh.devices.flat):
-        assert shard.device == device
-        assert shard.data.shape == (nt // shards,) + want.shape[1:]
-        np.testing.assert_array_equal(np.asarray(shard.data),
-                                      want[shard.index])
+    assert got.slots.shape == want.shape and got.hits.shape == (nt,)
+    # the rectangle and its hits (a tile that holds a cell) lie together
+    for part, oracle in ((got.slots, want),
+                         (got.hits, (want != TILE).any(axis=(1, 2)))):
+        assert len(part.addressable_shards) == shards
+        for shard, device in zip(part.addressable_shards, mesh.devices.flat):
+            assert shard.device == device
+            assert shard.data.shape == (nt // shards,) + oracle.shape[1:]
+            np.testing.assert_array_equal(np.asarray(shard.data),
+                                          oracle[shard.index])
 
 
 def test_a_cell_of_an_earlier_shard_is_dropped_not_wrapped_round():
@@ -169,7 +172,8 @@ def test_a_cell_of_an_earlier_shard_is_dropped_not_wrapped_round():
                                    shape=(8, 4, 16), tile_m=TILE)
     want = np.full((8, 4, 16), TILE, np.int32)
     want[0, 2, 0] = 5
-    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got.slots), want)
+    np.testing.assert_array_equal(np.asarray(got.hits), [1] + [0] * 7)
 
 
 @pytest.mark.parametrize("shards", SHARDS)
@@ -269,6 +273,9 @@ def test_spans_of_a_sharded_engine():
     assert compute["tiles"] == 1024 // eng.tile_m
     assert 4 <= compute["select_tiles"] <= compute["tiles"]
     assert compute["select_tiles"] <= compute["select_rounds"]
+    # and the gated exclusion's: chunks run, tiles that ran any
+    assert 1 <= compute["seen_hit_tiles"] <= compute["tiles"]
+    assert compute["seen_hit_tiles"] <= compute["seen_chunks"]
     # a one-device engine's spans carry none of it
     tracer = telemetry.configure()
     try:
@@ -280,4 +287,38 @@ def test_spans_of_a_sharded_engine():
     assert "shards" not in events["serve/batch/upload"]["args"]
     assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
     assert set(events["serve/batch/compute"]["args"]) == {
-        "n", "b", "k", "select_rounds", "select_tiles", "tiles"}
+        "n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
+        "seen_hit_tiles", "tiles"}
+    assert {x: events["serve/batch/compute"]["args"][x]
+            for x in ("seen_chunks", "seen_hit_tiles")} == {
+        x: compute[x] for x in ("seen_chunks", "seen_hit_tiles")}
+
+
+@pytest.mark.parametrize("shards", SHARDS)
+def test_exclusion_chunks_of_cells_in_one_shard(shards):
+    """Every cell lies in the second of four shards: the other shards run
+    no exclusion chunk at all, the answers are the one-device engine's to
+    the bit, and the span's counts, added up over the shards, are what the
+    cell lists imply (a tile a batch row has rated into runs the
+    rectangle's whole width, 16 slots a chunk)."""
+    uf, mf, lists, rows, k = _problem("seen_in_one_shard")
+    want_vals, want_ids = _engine(uf, mf, lists).topk(rows, k)
+    tracer = telemetry.configure()
+    try:
+        vals, ids = _engine(uf, mf, lists, shards=shards).topk(rows, k)
+        compute = next(e["args"] for e in tracer.events()
+                       if e["name"] == "serve/batch/compute")
+    finally:
+        telemetry.shutdown(write=False)
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(vals, want_vals)
+    hit = np.zeros(1024 // TILE, bool)
+    most = 0
+    for r in rows:
+        n = np.bincount(np.asarray(lists[r]) // TILE, minlength=hit.size)
+        hit |= n > 0
+        most = max(most, int(n.max()))
+    assert not hit[:256 // TILE].any() and not hit[512 // TILE:].any()
+    width = max(16, 1 << (most - 1).bit_length())
+    assert compute["seen_hit_tiles"] == hit.sum() > 0
+    assert compute["seen_chunks"] == hit.sum() * (width // 16)

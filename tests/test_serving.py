@@ -229,10 +229,9 @@ def _gate_problem(order, b, k_top):
     return u, tbl, [x.astype(np.int32) for x in seen]
 
 
-def _gate_oracle(u, deq, seen, k_top):
+def _gate_oracle(u, deq, seen, k_top, m=_GATE_M):
     """Stable sort by score descending, row ascending; −inf / −1 where a
     user has fewer than K candidates."""
-    m = _GATE_M
     vals = np.full((len(seen), k_top), -np.inf, np.float32)
     ids = np.full((len(seen), k_top), -1, np.int32)
     for i, s in enumerate(seen):
@@ -244,12 +243,13 @@ def _gate_oracle(u, deq, seen, k_top):
 
 
 @functools.lru_cache(maxsize=None)
-def _gate_programs(table_dtype, b, k_top):
-    """(kernel on the interpret path, twin), one compile per shape class."""
+def _counted_programs(k_top, num_movies, tile_m):
+    """(kernel on the interpret path, twin), jitted: one compile per shape
+    class of the operands."""
     from cfk_tpu.compat import emulate_topk_counted
     from cfk_tpu.serving.topk_kernel import topk_scores_counted
 
-    kw = dict(k_top=k_top, num_movies=_GATE_M, tile_m=_GATE_TILE)
+    kw = dict(k_top=k_top, num_movies=num_movies, tile_m=tile_m)
     return (jax.jit(functools.partial(topk_scores_counted, **kw)),
             jax.jit(functools.partial(emulate_topk_counted, **kw)))
 
@@ -269,7 +269,7 @@ def _gate_run(order, table_dtype, b, k_top, exclude=True):
         tile_m=_GATE_TILE))
     assert st.shape == (5, b, 16)  # one compiled shape per (dtype, B, K)
     outs = [tuple(map(np.asarray, fn(jnp.asarray(u), data, scale, st)))
-            for fn in _gate_programs(table_dtype, b, k_top)]
+            for fn in _counted_programs(k_top, _GATE_M, _GATE_TILE)]
     return outs, _gate_oracle(u, deq, seen, k_top)
 
 
@@ -294,17 +294,123 @@ def test_gated_fold_equals_stable_sort_oracle(order, table_dtype, b, k_top):
 @pytest.mark.parametrize("k_top", [1, 10, 16, 24])
 @pytest.mark.parametrize("order", ["ascending", "descending", "equal"])
 def test_selection_counts_are_what_the_order_implies(order, k_top):
-    """[rounds run, tiles that ran any] with no exclusion: ascending scores
-    make every tile replace the whole carry (min(K, real rows) rounds
-    each), descending or equal ones only fill it (K rounds over the first
-    ceil(K / T) tiles, then every gate stays shut) — and kernel and twin
-    count alike."""
+    """[rounds run, tiles that ran any, exclusion chunks run, tiles that
+    ran any] with no cell in the rectangle: ascending scores make every
+    tile replace the whole carry (min(K, real rows) rounds each),
+    descending or equal ones only fill it (K rounds over the first
+    ceil(K / T) tiles, then every gate stays shut), no tile runs an
+    exclusion chunk — and kernel and twin count alike."""
     (kernel, twin), _ = _gate_run(order, "float32", 8, k_top, exclude=False)
     real = [16, 16, 16, 16, 6]  # rows of each tile below num_movies
     want = ([sum(min(k_top, r) for r in real), 5] if order == "ascending"
             else [k_top, -(-k_top // 16)])  # the tiles that fill the carry
-    assert kernel[2].tolist() == want
-    assert twin[2].tolist() == want
+    assert kernel[2].tolist() == want + [0, 0]
+    assert twin[2].tolist() == want + [0, 0]
+
+
+# -- the gated masks: a tile runs the exclusion chunks it holds --------------
+
+_MASK_TILE, _MASK_NT, _MASK_B, _MASK_K = 512, 20, 8, 10
+_MASK_M = _MASK_NT * _MASK_TILE - 300  # the last tile holds 212 real rows
+_MASK_WIDTH = 32  # two chunks of slots in every case: one compiled shape
+MASK_CASES = ("no_cell", "every_tile", "one_tile_in_ten", "two_chunks",
+              "padded_last_tile", "rows_0_and_511")
+
+
+def _mask_seen(case):
+    """Seen lists (global rows, sorted) of the ``_MASK_B`` batch rows."""
+    t, b = _MASK_TILE, _MASK_B
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if case == "no_cell":
+        lists = [[] for _ in range(b)]
+    elif case == "every_tile":
+        lists = [[j * t + (37 * i + 11 * j) % 212 for j in range(_MASK_NT)]
+                 for i in range(b)]
+    elif case == "one_tile_in_ten":
+        lists = [[3 * t + int(x) for x in rng.choice(t, 3, replace=False)]
+                 + [13 * t + int(x) for x in rng.choice(t, i % 3, False)]
+                 for i in range(b)]
+        lists[1] += [13 * t + 7]
+    elif case == "two_chunks":
+        # row 0 has 20 cells in tile 5: the second chunk of 16 slots holds
+        # cells there, and nowhere else
+        lists = [[5 * t + int(x) for x in rng.choice(t, 20, replace=False)]]
+        lists += [[1 * t + i, 5 * t + 2 * i, 9 * t + 3 * i][:i % 4]
+                  for i in range(1, b)]
+    elif case == "padded_last_tile":
+        lists = [[19 * t + int(x) for x in rng.choice(212, i, replace=False)]
+                 for i in range(b)]
+    elif case == "rows_0_and_511":
+        lists = [[4 * t, 4 * t + 511], [0, 511], [511], [4 * t]]
+        lists += [[] for _ in range(b - 4)]
+    else:
+        raise KeyError(case)
+    return [np.sort(np.asarray(x, np.int32)) for x in lists]
+
+
+def _mask_counts_implied(seen):
+    """[exclusion chunks run, tiles that ran them] by the cell lists: a
+    tile any batch row has rated into runs the rectangle's whole width."""
+    hit = np.zeros((_MASK_NT,), bool)
+    for s in seen:
+        hit[s // _MASK_TILE] = True
+    return [int(hit.sum()) * (_MASK_WIDTH // 16), int(hit.sum())]
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("case", MASK_CASES)
+def test_gated_masks_equal_oracle_and_full_width_run(case, table_dtype):
+    """Kernel (interpret path) and twin, masking only the tiles that hold
+    a cell or cross ``num_movies``, against the numpy stable-sort oracle
+    and against a run told that every tile holds one — to the bit — with
+    the hits made from the cell list (``scatter_seen_cells``, the
+    server's) and by reading a bare rectangle; the four counts are what
+    the cell lists imply."""
+    from cfk_tpu.ops.quant import dequantize_table, quantize_table
+    from cfk_tpu.serving.topk_kernel import SeenTiles, scatter_seen_cells
+
+    seen = _mask_seen(case)
+    rng = np.random.default_rng(5)
+    m, m_pad = _MASK_M, _MASK_NT * _MASK_TILE
+    g = rng.permutation(m) % 251 + 1.0
+    # a seen row would top its user's list if the mask let it through
+    g[np.concatenate(seen).astype(np.int64)] = 300.0
+    tbl = np.zeros((m_pad, _GATE_RANK), np.float32)
+    tbl[:m, 3] = g
+    tbl[m:, 3] = 400.0  # and so would a padding row
+    u = rng.standard_normal((_MASK_B, _GATE_RANK)).astype(np.float32)
+    u[:, 3] = 2.0 ** rng.integers(-2, 3, _MASK_B)
+    data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
+    deq = np.asarray(dequantize_table(data, scale), np.float32)
+    movies, indptr = _csr(seen)
+    kw = dict(num_movies=m, tile_m=_MASK_TILE, min_width=_MASK_WIDTH)
+    bare = jnp.asarray(build_seen_tiles(
+        movies, indptr, np.arange(_MASK_B), **kw))
+    cells, shape = group_seen_cells(movies, indptr, np.arange(_MASK_B), **kw)
+    assert shape == bare.shape == (_MASK_NT, _MASK_B, _MASK_WIDTH)
+    built = None
+    for piece in chunk_seen_cells(cells, 64, _MASK_NT):
+        built = scatter_seen_cells(jnp.asarray(piece), built, shape=shape,
+                                   tile_m=_MASK_TILE)
+    np.testing.assert_array_equal(built.slots, bare)
+    every_tile = SeenTiles(bare, jnp.ones(_MASK_NT, jnp.int32))
+    want_v, want_i = _gate_oracle(u, deq, seen, _MASK_K, m=m)
+    runs = {name: [tuple(map(np.asarray, fn(jnp.asarray(u), data, scale, st)))
+                   for fn in _counted_programs(_MASK_K, _MASK_M, _MASK_TILE)]
+            for name, st in (("cell_list", built), ("bare", bare),
+                             ("every_tile", every_tile))}
+    for kernel, twin in runs.values():
+        for got_v, got_i, _ in (kernel, twin):
+            np.testing.assert_array_equal(got_i, want_i)
+            np.testing.assert_array_equal(got_v, want_v)
+        np.testing.assert_array_equal(kernel[2], twin[2])
+    counts = {name: kernel[2].tolist() for name, (kernel, _) in runs.items()}
+    assert counts["cell_list"][2:] == _mask_counts_implied(seen)
+    assert counts["bare"] == counts["cell_list"]
+    assert counts["every_tile"][2:] == [
+        _MASK_NT * (_MASK_WIDTH // 16), _MASK_NT]
+    # the same rounds on the same scores: only the masks differ
+    assert counts["every_tile"][:2] == counts["cell_list"][:2]
 
 
 @pytest.mark.parametrize("shards", [2, 4])
@@ -426,8 +532,11 @@ def test_device_built_rectangle_bit_equals_host_oracle(name):
         assert chunk.shape == (4, capacity) and chunk.dtype == np.int32
         got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
                                    tile_m=tile)
-    assert got.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(got), want)
+    assert got.slots.dtype == got.hits.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got.slots), want)
+    # a tile is hit where the rectangle holds a cell, and nowhere else
+    np.testing.assert_array_equal(np.asarray(got.hits),
+                                  (want != tile).any(axis=(1, 2)))
 
 
 @pytest.mark.parametrize("name", SEEN_CASES)

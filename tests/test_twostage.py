@@ -182,6 +182,39 @@ def test_force_exact_bit_identical_to_exact_engine(rng):
     np.testing.assert_array_equal(ti, ei)
 
 
+@pytest.mark.parametrize("rows", [65, 129, 191, 192])
+def test_rescore_masks_its_tail_and_the_tiles_that_hold_a_cell(rng, rows):
+    """The rescore over a gathered shortlist of ``rows`` of 192 padded
+    rows against numpy: the padding mask runs only on the tiles the tail
+    reaches (it starts at ``row_offset + rows``, not at a tile boundary),
+    the exclusion mask only on the middle tile, the one that holds cells —
+    and the answers are those of masking everything everywhere."""
+    from cfk_tpu.serving.topk_kernel import build_seen_tiles
+    from cfk_tpu.serving.twostage import _rescore_call
+
+    b, k_top, tile = 8, 10, 64
+    u = rng.standard_normal((b, RANK)).astype(np.float32)
+    table = rng.standard_normal((MOVIES, RANK)).astype(np.float32)
+    indices = rng.permutation(MOVIES)[:192].astype(np.int32)
+    scores = u @ table[indices[:rows]].T
+    # each user has seen the best rows of the middle tile it would get
+    seen = [64 + np.sort(np.argsort(-scores[i, 64:min(rows, 128)])[:i % 4])
+            for i in range(b)]
+    indptr = np.zeros(b + 1, np.int64)
+    indptr[1:] = np.cumsum([x.size for x in seen])
+    st = build_seen_tiles(np.concatenate(seen).astype(np.int32), indptr,
+                          np.arange(b), num_movies=192, tile_m=tile)
+    vals, ids = _rescore_call(
+        jnp.asarray(u), jnp.asarray(indices), jnp.asarray(table), None,
+        jnp.asarray(st), np.int32(192 - rows), k_top=k_top, tile_m=tile)
+    for i, x in enumerate(seen):
+        scores[i, x] = -np.inf
+    want = np.argsort(-scores, axis=1, kind="stable")[:, :k_top]
+    np.testing.assert_array_equal(np.asarray(ids) - (192 - rows), want)
+    np.testing.assert_allclose(
+        np.asarray(vals), np.take_along_axis(scores, want, 1), atol=1e-5)
+
+
 # -- fold-in deltas / fault fallback / prewarm ------------------------------
 
 def test_movie_delta_lands_in_cluster_row(rng):
