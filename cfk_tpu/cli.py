@@ -1760,8 +1760,13 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["float32", "bfloat16", "int8"],
                     default="float32",
                     help="item-table quantization (ops.quant): bf16 "
-                    "halves the per-batch table scan, int8+scale "
-                    "quarters it")
+                    "halves the bytes the table holds in HBM and the "
+                    "scorer scans per batch, int8 + a float32 scale a "
+                    "row quarters them (and is quantized on the host, "
+                    "uploaded as codes); that is bytes, not time: the "
+                    "float32 scorer is bound by the MXU, and answers "
+                    "are exact against the dequantized table (PERF.md "
+                    "has the times)")
     sv.add_argument("--tile-m", type=int, default=2048,
                     help="movie-axis tile rows streamed through VMEM")
     sv.add_argument("--serve-mode", choices=["exact", "two_stage"],

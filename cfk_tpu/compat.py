@@ -116,8 +116,10 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     m_pad = table.shape[0]
     nt = m_pad // tile_m
     tbl = table.reshape(nt, tile_m, -1)
+    # one dense [tile_m] row a tile, a column only inside the step: a
+    # trailing axis of 1 is padded to 128 lanes in the chip's HBM
     sc = (None if scale is None
-          else scale.reshape(nt, tile_m, 1).astype(jnp.float32))
+          else scale.astype(jnp.float32).reshape(nt, tile_m))
     seen_tiles = topk_kernel.as_seen_tiles(seen_tiles, tile_m)
     if seen_tiles is None:
         seen = hits = None
@@ -141,7 +143,7 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         seen_i = None if seen is None else idx(seen)
         v, ids, counts = topk_kernel._score_tile_fold(
             lambda: (carry[0], carry[1], u, idx(tbl),
-                     None if sc is None else idx(sc)),
+                     None if sc is None else idx(sc)[:, None]),
             None if seen is None else (
                 lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
             ),

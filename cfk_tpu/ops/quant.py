@@ -30,8 +30,12 @@ identity — the default path is bit-identical to pre-quantization behavior.
 
 from __future__ import annotations
 
+import concurrent.futures
+import os
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 TABLE_DTYPES = ("float32", "bfloat16", "int8")
 
@@ -90,6 +94,50 @@ def quantize_table(
         jnp.round(f / scale[:, None]), -_INT8_LEVELS, _INT8_LEVELS
     ).astype(jnp.int8)
     return q, scale
+
+
+# rows one host thread quantizes at a time (8 MB of float32 at rank 128)
+_HOST_PIECE_ROWS = 1 << 14
+
+
+def quantize_rows_host(rows: np.ndarray, *, threads: int | None = None):
+    """``quantize_table(rows, "int8")`` in numpy, on the host's cores:
+    (codes [n, k] int8, scales [n] float32) by the same written rule —
+    scale = amax / 127 (1.0 for an all-zero row), code = round-half-even(f /
+    scale) clipped to ±127.
+
+    The serving engine quantizes its item table here, slice by slice, and
+    uploads codes: IEEE float32 division, so a table quantized anywhere by
+    the rule (numpy, XLA:CPU) is this table to the bit, which the chip's own
+    divide does not promise (PERF.md section 6, PR 32, counts the codes that
+    differ), and a quarter of the bytes cross to the device.  Rows are cut
+    into pieces over a thread pool; numpy runs them outside the
+    interpreter's lock."""
+    rows = np.asarray(rows, np.float32)
+    n = rows.shape[0]
+    codes = np.empty(rows.shape, np.int8)
+    scales = np.empty((n,), np.float32)
+
+    def piece(lo):
+        f = rows[lo:lo + _HOST_PIECE_ROWS]
+        amax = np.abs(f).max(axis=-1)
+        scale = np.where(amax == 0, np.float32(1.0),
+                         amax / np.float32(_INT8_LEVELS)).astype(np.float32)
+        q = f / scale[:, None]
+        np.rint(q, out=q)
+        np.clip(q, -_INT8_LEVELS, _INT8_LEVELS, out=q)
+        codes[lo:lo + _HOST_PIECE_ROWS] = q
+        scales[lo:lo + _HOST_PIECE_ROWS] = scale
+
+    starts = range(0, n, _HOST_PIECE_ROWS)
+    threads = threads or max(1, min(16, (os.cpu_count() or 2) - 1))
+    if len(starts) <= 1 or threads == 1:
+        for lo in starts:
+            piece(lo)
+    else:
+        with concurrent.futures.ThreadPoolExecutor(threads) as pool:
+            list(pool.map(piece, starts))
+    return codes, scales
 
 
 def dequantize_table(

@@ -37,6 +37,7 @@ from cfk_tpu.serving.engine import (
     engine_from_model,
     pad_table,
     plan_for_serving,
+    row_reader,
 )
 from cfk_tpu.serving.fleet import (
     DELTAS_TOPIC,
@@ -79,6 +80,7 @@ __all__ = [
     "engine_from_model",
     "plan_for_serving",
     "pad_table",
+    "row_reader",
     "ClusterIndex",
     "build_cluster_index",
     "kmeans_item_clusters",

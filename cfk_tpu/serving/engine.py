@@ -6,9 +6,13 @@ user rows" and "[B, K] ids+scores":
 - the item factor table, padded to the kernel's tile grid, quantized per
   ``ALSConfig.table_dtype`` (``ops.quant``) and kept device-resident (it
   is read every request; re-uploading 30 MB per query would dominate):
-  on one device, or — ``shards=`` / ``mesh=`` — row-sharded over a mesh,
-  staged and uploaded one shard at a time, so that a catalogue past one
-  chip's memory is never whole on any device.  Each chip then scans its
+  on one device, or — ``shards=`` / ``mesh=`` — row-sharded over a mesh.
+  It is taken as an array or as a row reader (``row_reader``) and goes up
+  device by device and slice by slice (``_upload``), an int8 table's
+  codes made on the host's cores by the written rule, so that a catalogue
+  past one chip's memory — or past one chip's memory in float32 — is
+  never whole in float32 on any device or in any host buffer of the
+  engine's.  Over a mesh each chip then scans its
   slice and builds its own slice of the exclusion rectangle from the
   batch's replicated cell list; ``u`` and that list are all a batch sends
   to every chip, and one all_gather of the [B, K] selections merges them
@@ -79,6 +83,42 @@ def pad_table(table: np.ndarray, tile_m: int, shards: int = 1) -> np.ndarray:
     return out
 
 
+# Float32 bytes of item rows that one slice of a sliced table upload reads,
+# stages on the host and hands to the device (2,097,152 rows at rank 128).
+_SLICE_BYTES = 1 << 30
+
+
+def row_reader(movie_factors):
+    """``read(lo, hi)`` → rows [lo, hi) of the item factors as a float32
+    numpy array: ``movie_factors`` itself where it is such a callable (a
+    table made, loaded or exported block by block, never whole on the
+    host), else slices of the array it is."""
+    if callable(movie_factors):
+        return lambda lo, hi: np.asarray(movie_factors(lo, hi), np.float32)
+    return lambda lo, hi: np.asarray(movie_factors[lo:hi], np.float32)
+
+
+def _device_bytes_limit(device):
+    """What the runtime lets one device hold, where it says (a TPU does,
+    the CPU backend does not: None)."""
+    import jax
+
+    stats = (device or jax.devices()[0]).memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+@functools.lru_cache(maxsize=1)
+def _land_fn():
+    """Jitted ``buf[lo:lo + len(piece)] = piece`` on the buffer's device,
+    the buffer donated: a slice lands in place, no second table beside it."""
+    import jax
+
+    return jax.jit(
+        lambda buf, piece, lo: jax.lax.dynamic_update_slice_in_dim(
+            buf, piece, lo, 0),
+        donate_argnums=0)
+
+
 class ServeEngine:
     """Score top-K requests against live factors.
 
@@ -90,7 +130,7 @@ class ServeEngine:
     def __init__(
         self,
         user_factors,  # [U, k] (np or jax; snapshot is taken)
-        movie_factors,  # [M_pad0, k]
+        movie_factors,  # [M_pad0, k], or read(lo, hi) -> rows (row_reader)
         *,
         num_users: int,
         num_movies: int,
@@ -187,8 +227,7 @@ class ServeEngine:
             else np.asarray(seen_indptr, np.int64)
         )
         self._seen_hot: dict[int, list[int]] = {}
-        m_host = np.asarray(movie_factors, np.float32)[:num_movies]
-        self._set_table(m_host)
+        self._set_table(movie_factors)
         self.invalidations = 0
         self.table_swaps = 0
         # Fleet state (ISSUE 18): the factor-table epoch every response is
@@ -229,32 +268,30 @@ class ServeEngine:
             if num_users is not None:
                 self.num_users = int(num_users)
             if movie_factors is not None:
-                self._set_table(
-                    np.asarray(movie_factors, np.float32)[: self.num_movies]
-                )
+                self._set_table(movie_factors)
                 self.table_swaps += 1
             if epoch is not None:
                 self.epoch = int(epoch)
 
     # -- table ---------------------------------------------------------------
 
-    def _set_table(self, movie_factors_host: np.ndarray) -> None:
+    def _set_table(self, movie_factors) -> None:
+        """Upload ``movie_factors`` — a [num_movies, k] array, or a callable
+        ``(lo, hi)`` → rows [lo, hi) as float32 (``row_reader``) — as the
+        live item table."""
         import jax
         import jax.numpy as jnp
 
         from cfk_tpu.ops.quant import quantize_table
 
-        if self.mesh is not None:
-            self._table = self._upload_sharded(movie_factors_host)
-        else:
-            padded = pad_table(movie_factors_host.astype(np.float32),
-                               self.tile_m)
-            data, scale = quantize_table(jnp.asarray(padded),
-                                         self.table_dtype)
-            # one atomic reference swap: a batch in flight keeps the table
-            # it captured; the next batch sees the new one
-            self._table = (jax.device_put(data),
-                           None if scale is None else jax.device_put(scale))
+        read = row_reader(movie_factors)
+        if self.serve_mode == "two_stage" and callable(movie_factors):
+            raise ValueError(
+                "serve_mode='two_stage' clusters the whole item table on "
+                "the host: pass it as an array, not as a row reader")
+        # one atomic reference swap: a batch in flight keeps the table
+        # it captured; the next batch sees the new one
+        self._table = self._upload(read, whole=not callable(movie_factors))
         if self.serve_mode == "two_stage":
             # Rebuild the cluster index with every swap (re-cluster ONLY
             # here — fold-in deltas update rows in place).  Built off to
@@ -262,7 +299,7 @@ class ServeEngine:
             # the (index, table) pair it captured.
             from cfk_tpu.serving.cluster import build_cluster_index
 
-            host = np.asarray(movie_factors_host, np.float32)
+            host = read(0, self.num_movies)
             index = build_cluster_index(
                 host, min(self.clusters, max(host.shape[0], 1)),
                 seed=self.cluster_seed,
@@ -288,49 +325,108 @@ class ServeEngine:
             # chaos contract)
             self._two_stage_disabled = False
 
-    def _upload_sharded(self, host: np.ndarray):
-        """(data, scale) row-sharded over the mesh, shard by shard: shard
-        s's rows go from the caller's array straight to chip s and are
-        quantized there, the four transfers in flight together.  Only a
-        shard that is not a whole contiguous slice of that array (the
-        last, zero-padded to the tile grid) goes through a staging copy,
-        and it has landed before another is made.  No device and no host
-        buffer holds the padded table whole."""
+    def _upload(self, read, *, whole: bool):
+        """(data, scale) on the device, or row-sharded over the mesh, from
+        ``read(lo, hi)``: device by device and, within a device's rows,
+        slice by slice.  A slice is read, zero-padded where it reaches past
+        the catalogue, quantized where the rule is IEEE — int8 on the
+        host's cores (``ops.quant.quantize_rows_host``), so codes go up, a
+        quarter of the bytes; a bfloat16 table is cast on its device — and
+        landed in the device's preallocated [rows, k] table by a donated
+        update, with at most two slices in flight.  Neither the host nor a
+        device ever holds the table whole in float32.
+
+        ``whole`` (the caller handed an array) and a float32 table: what
+        goes up is the table itself, so a device's rows go up in one piece,
+        straight from the caller's array where they are a contiguous slice
+        of it (all but a last, padded share): no staging on either side."""
         import jax
+        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from cfk_tpu.ops.quant import quantize_table
+        from cfk_tpu.ops.quant import (
+            quantize_rows_host, quantize_table, table_itemsize)
         from cfk_tpu.parallel.mesh import AXIS
 
-        shards, rank = self._shards, host.shape[1]
-        quantum = self.tile_m * shards
-        per = -(-host.shape[0] // quantum) * self.tile_m
-        host = np.asarray(host, np.float32)
-        datas, scales = [], []
+        shards, rank = self._shards, int(self._u_base.shape[1])
+        int8 = self.table_dtype == "int8"
+        per = -(-self.num_movies // (self.tile_m * shards)) * self.tile_m
+        step = max(_SLICE_BYTES // (4 * rank), 1)
+        if whole and self.table_dtype == "float32":
+            step = per
+        step = min(step, per)
+        devices = ([None] if self.mesh is None
+                   else list(self.mesh.devices.flat))
+        held = per * (rank * table_itemsize(self.table_dtype) + 4 * int8)
+        in_flight = 0 if step == per else 2 * step * rank * (1 if int8 else 4)
+        limit = _device_bytes_limit(devices[0])
+        if limit is not None and held + in_flight > limit:
+            raise ValueError(
+                f"the item table does not fit its device: {per:,} rows x "
+                f"{rank} as {self.table_dtype} are {held:,} B"
+                + (f" (+ {in_flight:,} B of slices in flight)"
+                   if in_flight else "")
+                + f" against {limit:,} B; shard it over more chips "
+                "(shards=) or quantize it (table_dtype=)")
+        dtype = jnp.dtype(self.table_dtype)
+        datas, scales, slices = [], [], 0
         with span("serve/engine/table_upload", shards=shards,
-                  rows_per_shard=per) as sp:
-            for s, device in enumerate(self.mesh.devices.flat):
-                rows = host[s * per:(s + 1) * per]
-                staged = rows.shape[0] < per or not rows.flags.c_contiguous
-                if staged:
-                    stage = np.zeros((per, rank), np.float32)
-                    stage[:rows.shape[0]] = rows
-                    rows = stage
-                # committed to chip s, so the quantization runs there too
-                data, scale = quantize_table(jax.device_put(rows, device),
-                                             self.table_dtype)
-                if staged:  # at most one staging copy at a time
-                    jax.block_until_ready(data)
+                  rows_per_shard=per, rows_per_slice=step,
+                  table_dtype=self.table_dtype,
+                  quantized_on={"int8": "host", "bfloat16": "device"}.get(
+                      self.table_dtype, "none")) as sp:
+            for d, device in enumerate(devices):
+                data = scale = None
+                if step < per:
+                    data = jnp.zeros((per, rank), dtype, device=device)
+                    scale = (jnp.ones((per,), jnp.float32, device=device)
+                             if int8 else None)
+                for lo in range(d * per, (d + 1) * per, step):
+                    n = min(step, (d + 1) * per - lo)
+                    hi = min(lo + n, self.num_movies)
+                    rows = (read(lo, hi) if hi > lo
+                            else np.zeros((0, rank), np.float32))
+                    staged = (rows.shape[0] < n
+                              or not rows.flags.c_contiguous)
+                    if staged:
+                        stage = np.zeros((n, rank), np.float32)
+                        stage[:rows.shape[0]] = rows
+                        rows = stage
+                    if int8:
+                        q, s = quantize_rows_host(rows)
+                        piece = (jax.device_put(q, device),
+                                 jax.device_put(s, device))
+                    else:
+                        # committed to its chip, so the cast runs there too
+                        piece = quantize_table(
+                            jax.device_put(rows, device), self.table_dtype)
+                    if step == per:
+                        data, scale = piece
+                        if staged:  # at most one staging copy at a time
+                            jax.block_until_ready(data)
+                    else:
+                        # the slice before this one has landed (and its
+                        # device buffer is free) before this one is: two
+                        # in flight at most, one going up, one landing
+                        jax.block_until_ready(data)
+                        data = _land_fn()(data, piece[0], lo - d * per)
+                        if int8:
+                            scale = _land_fn()(scale, piece[1], lo - d * per)
+                    slices += 1
                 datas.append(data)
                 scales.append(scale)
-            jax.block_until_ready(datas)
-            sharding = NamedSharding(self.mesh, P(AXIS))
-            data = jax.make_array_from_single_device_arrays(
-                (per * shards, rank), sharding, datas)
-            scale = None if scales[0] is None else (
-                jax.make_array_from_single_device_arrays(
-                    (per * shards,), sharding, scales))
-            sp.set(bytes=data.nbytes + (0 if scale is None else scale.nbytes))
+            jax.block_until_ready((datas, scales))
+            if self.mesh is None:
+                data, scale = datas[0], scales[0]
+            else:
+                sharding = NamedSharding(self.mesh, P(AXIS))
+                data = jax.make_array_from_single_device_arrays(
+                    (per * shards, rank), sharding, datas)
+                scale = None if not int8 else (
+                    jax.make_array_from_single_device_arrays(
+                        (per * shards,), sharding, scales))
+            sp.set(slices=slices,
+                   bytes=data.nbytes + (0 if scale is None else scale.nbytes))
         return data, scale
 
     @property
@@ -372,10 +468,7 @@ class ServeEngine:
                     event["user_factors"], np.float32
                 )[: self.num_users]
                 self._u_hot.clear()
-                self._set_table(
-                    np.asarray(event["movie_factors"],
-                               np.float32)[: self.num_movies]
-                )
+                self._set_table(event["movie_factors"])
                 self.table_swaps += 1
                 self.epoch += 1
 
@@ -391,7 +484,7 @@ class ServeEngine:
         requantization would produce.  Returns the rows applied."""
         import jax.numpy as jnp
 
-        from cfk_tpu.ops.quant import quantize_table
+        from cfk_tpu.ops.quant import quantize_rows_host, quantize_table
 
         rows = np.asarray(rows, np.int64)
         f = np.asarray(factors, np.float32)
@@ -399,7 +492,11 @@ class ServeEngine:
         rows, f = rows[keep], f[keep]
         if rows.size == 0:
             return 0
-        qd, qs = quantize_table(jnp.asarray(f), self.table_dtype)
+        if self.table_dtype == "int8":
+            # where the table's own codes were made (``_upload``)
+            qd, qs = map(jnp.asarray, quantize_rows_host(f))
+        else:
+            qd, qs = quantize_table(jnp.asarray(f), self.table_dtype)
         with self._lock:
             data, scale = self._table
             set_rows = _set_rows_fn(data.sharding)
@@ -584,7 +681,12 @@ class ServeEngine:
             # exclusion chunks run and tiles that ran any
             sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
                    seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
-                   tiles=self.table_rows // self.tile_m)
+                   tiles=self.table_rows // self.tile_m,
+                   # what the scorer streams from HBM for the batch, all
+                   # shards': the table as it is held, and its scales
+                   table_dtype=self.table_dtype,
+                   scan_bytes=table.nbytes
+                   + (0 if scale is None else scale.nbytes))
             vals, ids = vals[:n], ids[:n]
         self._record_scan(mode="exact", b=b, k=k)
         return vals, ids

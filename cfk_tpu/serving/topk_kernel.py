@@ -17,7 +17,12 @@ every reduction runs along sublanes, the forms Mosaic lowers:
 
 - score block  S = tile · Uᵀ on the MXU (f32 accumulation; an int8 tile is
   dequantized in-register by its per-row scale — the same canonical
-  dequant placement as the Gram kernels, ``ops.quant``),
+  dequant placement as the Gram kernels, ``ops.quant``).  The scales
+  reach the kernel lane-dense, one [1, T] row a tile of a [NT, 1, T] view
+  of the [M_pad] vector (a bitcast), and are turned to a column in
+  register: a [M_pad, 1] operand is padded to 128 lanes a row in HBM,
+  4.8 GB copied per call for 37 MB of scales at 9.35 M rows (PERF.md
+  section 6, PR 32),
 - padding mask: global row ≥ ``num_movies`` → −inf (the table is padded
   to a tile multiple),
 - exclusion mask: already-rated items are −inf'd in-register from a
@@ -97,7 +102,13 @@ def serve_compute_dtype(table_dtype):
     analog of ``ops.solve._gram_compute_dtype``: f32 operands keep the
     full-precision MXU pass (bit-parity with the dense oracle), bf16 tables
     feed the MXU bf16 with f32 accumulation, int8 tables dequantize to f32
-    in-register first (the int8×f32-scale product is exact in f32)."""
+    in-register first (code × the row's scale, rounded to float32 as the
+    reference's dequantized view is) and then take the float32 pass.  What
+    a narrower table buys is BYTES, held and scanned per batch: half at
+    bf16, a quarter plus 4 B a row at int8.  It does not buy that in time:
+    at ``Precision.HIGHEST`` the scorer is bound by the MXU's passes, which
+    an int8 table runs in full (PERF.md sections 5 and 6 hold what each
+    costs on the chip)."""
     if table_dtype == jnp.bfloat16:
         return jnp.bfloat16, None
     return jnp.float32, lax.Precision.HIGHEST
@@ -120,7 +131,8 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     ``dynamic_slice`` on values and lane-offset slices do not).
 
     ``read()`` → (carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty),
-    u [B, k], tile [T, k] (f32/bf16/int8), scale [T, 1] f32 or None),
+    u [B, k], tile [T, k] (f32/bf16/int8), scale [T, 1] or [T, k] f32 — the
+    row's scale in every column — or None),
     ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j < the
     static ``seen_width`` (T = padding), ``seen_hit`` scalar int32 =
     whether any of this tile's slots holds a cell (``SeenTiles.hits``) —
@@ -164,8 +176,8 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
         ct, prec = serve_compute_dtype(tile.dtype)
         if tile.dtype == jnp.int8:
             # canonical dequant placement (ops.quant): codes → f32 ×
-            # per-row scale, before the single matmul
-            tile_f = tile.astype(jnp.float32) * scale
+            # per-row scale, before the single matmul (in ``ct``: float32)
+            tile_f = (tile.astype(jnp.float32) * scale).astype(ct)
         else:
             tile_f = tile.astype(ct)
         scores = jax.lax.dot_general(
@@ -397,6 +409,17 @@ def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
     vals_ref, ids_ref, counts_ref, cv_ref, ci_ref = refs
     i = pl.program_id(0)
 
+    def scale_rows():
+        # the tile's scales are one lane-dense [1, T] row; the tile wants
+        # them down its sublanes: broadcast down whole registers' worth of
+        # sublanes and transposed, every column of row r then holds r's
+        # scale and the multiply needs no broadcast along lanes (a [T, 1]
+        # column broadcast in the multiply: 41.5 against 35.2 ms a call at
+        # 9.35 M rows, PERF.md section 6, PR 32)
+        k = tbl_ref.shape[1]
+        lanes = -(-k // 128) * 128
+        return jnp.broadcast_to(scale_ref[0], (lanes, t)).T[:, :k]
+
     @pl.when(i == 0)
     def _():
         cv_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
@@ -411,7 +434,7 @@ def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
     # seeded with one could not typecheck.
     new_v, new_i, counts = _score_tile_fold(
         lambda: (cv_ref[...], ci_ref[...], u_ref[...], tbl_ref[...],
-                 scale_ref[...] if scale_ref is not None else None),
+                 scale_rows() if with_scale else None),
         (lambda j: seen_ref[0, pl.ds(j, 1), :]) if with_seen else None,
         seen_ref.shape[1] if with_seen else 0,
         hits_ref[i] if with_seen else None, off_ref[0] + i * t,
@@ -515,8 +538,10 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
     ops = [u, table]
     if scale is not None:
-        in_specs.append(pl.BlockSpec((tile_m, 1), lambda i, *_: (i, 0)))
-        ops.append(scale.reshape(m_pad, 1).astype(jnp.float32))
+        # lane-dense: [NT, 1, T] is the [M_pad] vector itself in HBM, where
+        # [M_pad, 1] would be padded to 128 lanes a row
+        in_specs.append(pl.BlockSpec((1, 1, tile_m), lambda i, *_: (i, 0, 0)))
+        ops.append(scale.astype(jnp.float32).reshape(nt, 1, tile_m))
     seen_width = 0
     if seen_tiles is not None:
         seen_width = slots.shape[2]

@@ -7,6 +7,12 @@ shards of a row-sharded table would, keeping a running top-K and the exact
 score at each served id, so its memory is users × block whatever the
 catalogue's size.  ``benchmarks/harness/reference_blocks.py`` is the
 benchmark's own copy.
+
+The quantized case (``quantized_topk``): an int8 row-quantized table serves
+the exact top-K of its DEQUANTIZED view — codes and scales by the written
+rule, code × scale in float32 — so the reference quantizes by the rule,
+dequantizes and runs the same blockwise top-K over that
+(``benchmarks/harness/reference_q8.py`` is the benchmark's own copy).
 """
 
 from __future__ import annotations
@@ -79,3 +85,30 @@ def topk_gaps(vals, best, at):
             np.abs(exact), 1.0)
     score_err = float(np.max(np.where(np.isfinite(exact), err, np.inf)))
     return max(rank_gap, 0.0), score_err
+
+
+def quantize_rows(f):
+    """(codes [n, k] int8, scales [n] float32) by the written rule: a row's
+    scale is its largest magnitude over 127 in float32 (1.0 for an all-zero
+    row); a code is the row over its scale, rounded half to even and clipped
+    to ±127."""
+    f = np.asarray(f, np.float32)
+    amax = np.max(np.abs(f), axis=1)
+    scales = np.where(amax == 0, np.float32(1.0),
+                      amax / np.float32(127.0)).astype(np.float32)
+    codes = np.clip(np.rint(f / scales[:, None]), -127, 127).astype(np.int8)
+    return codes, scales
+
+
+def dequantize_rows(codes, scales):
+    """The float32 view a quantized table's answers are exact against."""
+    return codes.astype(np.float32) * scales[:, None]
+
+
+def quantized_topk(user_vecs, table, seen, k: int, served_ids=None, *,
+                   block: int = 1 << 20):
+    """``exact_topk_blocks`` over the dequantized view of ``table``'s int8
+    row quantization: what ``ServeEngine(table_dtype="int8")`` must answer."""
+    return exact_topk_blocks(
+        user_vecs, dequantize_rows(*quantize_rows(table)), seen, k,
+        served_ids, block=block)

@@ -368,6 +368,23 @@ def test_serve_scorer_amazon14_cell_shape(chip):
     assert "s32[18262]" in text
 
 
+def test_serve_scorer_amazon23_int8_cell_shape(chip):
+    """The int8 cell's own call: 94,122 tiles of 48,190,464 codes on one
+    chip, the scales as a lane-dense [NT, 1, T] view.  As a [M_pad, 1]
+    operand they were copied out to one scale a 128-lane row on every
+    call, 24.7 GB here (ISSUE 32): the view is a bitcast, and the call
+    needs no temporary at all."""
+    compiled = _compile_scorer(chip, i8, b=256, k_top=16, w=16, m=48_190_000)
+    text = compiled.as_text()
+    assert "f32[94122,1,512]" in text and "s32[94122]" in text
+    assert "f32[48190464,1]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
+    # codes + scales + the [NT, 256, 16] rectangle + the small operands
+    held = 48_190_464 * (128 + 4) + 94_122 * 256 * 16 * 4
+    assert held < mem.argument_size_in_bytes < held + (8 << 20)
+
+
 @pytest.mark.parametrize("m,k_top,b,dtype", [
     (59_047, 10, 64, bf16),  # the ML-25M table
     (48_190_000, 16, 256, f32),  # Amazon-2023: 24.7 GB, 6.17 GB a chip

@@ -441,6 +441,99 @@ def test_topk_compiled_matches_twin(table_dtype, order, cells):
     assert np.asarray(n_c).tolist() == np.asarray(n_t).tolist()
 
 
+def test_topk_int8_at_a_size_where_the_scale_layout_shows():
+    """2.1 M rows of int8 codes with exclusion on: the scales reach the kernel
+    as a lane-dense [NT, 1, T] view (a bitcast), so the compiled call needs
+    no temporary — as a [M_pad, 1] operand they were copied out to 128 lanes
+    a row on every call, 1.07 GB here (ISSUE 32) — and the turn in register
+    (broadcast down the sublanes, transposed) gives the twin's answers and
+    numpy's scores."""
+    from cfk_tpu.compat import emulate_topk_counted
+    from cfk_tpu.serving.topk_kernel import (
+        chunk_seen_cells, group_seen_cells, scatter_seen_cells,
+        topk_scores_counted)
+
+    rng = np.random.default_rng(32)
+    m, k, b, k_top, tile = 2_100_000, 128, 64, 16, 512
+    m_pad = -(-m // tile) * tile
+    nt = m_pad // tile
+    codes = rng.integers(-127, 128, (m_pad, k), dtype=np.int8)
+    codes[m:] = 0
+    scales = rng.uniform(1e-3, 2e-3, m_pad).astype(np.float32)
+    u_host = ((rng.random((b, k), dtype=np.float32) - 0.5) * 0.35)
+    seen = [np.sort(rng.choice(m, size=int(rng.integers(1, 40)),
+                               replace=False)).astype(np.int32)
+            for _ in range(b)]
+    indptr = np.zeros(b + 1, np.int64)
+    indptr[1:] = np.cumsum([s.size for s in seen])
+    cells, shape = group_seen_cells(
+        np.concatenate(seen), indptr, np.arange(b), num_movies=m,
+        tile_m=tile, num_tiles=nt)
+    (piece,) = chunk_seen_cells(cells, cells.shape[1], nt)
+    st = jax.jit(scatter_seen_cells, static_argnames=("shape", "tile_m"))(
+        jnp.asarray(piece), shape=shape, tile_m=tile)
+    u, data, scale = map(jnp.asarray, (u_host, codes, scales))
+    kw = dict(k_top=k_top, num_movies=m, tile_m=tile)
+    fn = jax.jit(lambda *a: topk_scores_counted(*a, interpret=False, **kw))
+    mem = fn.lower(u, data, scale, st).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+    v_c, i_c, n_c = map(np.asarray, fn(u, data, scale, st))
+    v_t, i_t, n_t = map(np.asarray, jax.jit(
+        lambda *a: emulate_topk_counted(*a, **kw))(u, data, scale, st))
+    assert n_c.tolist() == n_t.tolist() and n_c[3] > 0
+    assert (i_c == i_t).mean() > 0.99
+    np.testing.assert_allclose(v_c, v_t, rtol=2e-6, atol=2e-6)
+    assert (np.diff(v_c, axis=1) <= 0).all()
+    for row in range(b):
+        assert not set(i_c[row].tolist()) & set(seen[row].tolist())
+        assert (i_c[row] >= 0).all() and (i_c[row] < m).all()
+    # numpy's float32 scores of the dequantized rows at the served ids
+    deq = codes[i_c].astype(np.float32) * scales[i_c][..., None]
+    want = np.einsum("bjk,bk->bj", deq.astype(np.float64),
+                     u_host.astype(np.float64))
+    assert np.abs(v_c - want).max() <= 2e-6
+
+
+def test_sliced_upload_on_the_chip_is_the_whole_table_quantizer(monkeypatch):
+    """2 M x 128 rows handed to the engine as a row reader and uploaded in
+    300,000-row slices of codes (the last short, none a multiple of the
+    tile): the device's table is numpy's by the written rule to the bit, and
+    no float32 slice of it was ever on the device."""
+    from cfk_tpu.ops.quant import quantize_table
+    from cfk_tpu.serving import engine as engine_mod
+    from tests.serve_reference import quantize_rows
+
+    rng = np.random.default_rng(33)
+    m, k = 2_000_123, 128
+    mf = ((rng.random((m, k), dtype=np.float32) - 0.5) * 0.35)
+    mf[7] = 0.0
+    monkeypatch.setattr(engine_mod, "_SLICE_BYTES", 300_000 * k * 4)
+    put = []
+    real_put = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, *a, **kw: put.append((np.shape(x), np.asarray(x).dtype))
+        or real_put(x, *a, **kw))
+    eng = engine_mod.ServeEngine(
+        np.zeros((8, k), np.float32), lambda lo, hi: mf[lo:hi], num_users=8,
+        num_movies=m, table_dtype="int8", tile_m=512)
+    assert {dt for shape, dt in put if len(shape) == 2} == {np.dtype(np.int8)}
+    assert max(shape[0] for shape, _ in put) == 300_000
+    data, scale = map(np.asarray, eng._table)
+    want_codes, want_scales = quantize_rows(mf)
+    np.testing.assert_array_equal(data[:m], want_codes)
+    np.testing.assert_array_equal(scale[:m], want_scales)
+    assert not data[m:].any() and (scale[m:] == 1.0).all()
+    # the chip's own quantizer, for the record: where it differs from the
+    # rule (its float32 divide is not IEEE's) it differs by one step
+    codes_d, scales_d = map(np.asarray, quantize_table(jnp.asarray(mf), "int8"))
+    off = codes_d.astype(np.int16) - want_codes
+    assert np.abs(off).max() <= 1
+    print(f"chip quantize_table against the rule: {int((off != 0).sum())} of "
+          f"{off.size} codes, {int((scales_d != want_scales).sum())} of {m} "
+          "scales differ")
+
+
 def _seen_problem(rng, users, movies, longest):
     seen = [np.sort(rng.choice(movies, size=int(rng.integers(0, longest)),
                                replace=False)).astype(np.int32)

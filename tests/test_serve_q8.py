@@ -1,0 +1,275 @@
+"""An int8 row-quantized item table on one device, uploaded in slices (ISSUE
+32): answers against the plain numpy reference of the quantized semantics on
+one device and over 2- and 4-device meshes; the sliced upload's codes and
+scales against quantizing the whole table at once, to the bit; a control in
+narrower arithmetic failing the stated limits; a table past the device's
+budget going up in slices where it fits quantized and refused in words where
+it does not; the spans."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cfk_tpu import telemetry
+from cfk_tpu.ops.quant import quantize_rows_host, quantize_table
+from cfk_tpu.serving import engine as engine_mod
+from cfk_tpu.serving import topk_kernel
+from tests.serve_reference import (
+    exact_topk_blocks, quantize_rows, quantized_topk, topk_gaps)
+
+RANK, TILE = 8, 16
+# the benchmark's limits (benchmarks/configs/amazon23-serve-r128-int8.json)
+RANK_GAP, SCORE_ERR = 1e-5, 2e-5
+
+
+def _csr(lists):
+    indptr = np.zeros(len(lists) + 1, np.int64)
+    indptr[1:] = np.cumsum([len(x) for x in lists])
+    movies = (np.concatenate([np.asarray(x, np.int32) for x in lists])
+              if indptr[-1] else np.zeros(0, np.int32))
+    return movies, indptr
+
+
+def _problem(seed=0, m=1000, users=24, rank=RANK, scale=0.35):
+    """Seeded weights shaped like the benchmark's: uniform in ±scale/2."""
+    rng = np.random.default_rng(seed)
+    uf = ((rng.random((users, rank), dtype=np.float32) - 0.5) * scale)
+    mf = ((rng.random((m, rank), dtype=np.float32) - 0.5) * scale)
+    mf[3] = 0.0  # an all-zero row: scale 1.0, codes 0
+    lists = [np.sort(rng.choice(m, int(rng.integers(0, 40)), replace=False))
+             for _ in range(users)]
+    rows = rng.integers(0, users, size=13)
+    return uf, mf, lists, rows
+
+
+def _engine(uf, mf, lists, **kw):
+    movies, indptr = _csr(lists)
+    kw.setdefault("tile_m", TILE)
+    kw.setdefault("table_dtype", "int8")
+    return engine_mod.ServeEngine(
+        uf, mf, num_users=uf.shape[0],
+        num_movies=kw.pop("num_movies", None) or mf.shape[0],
+        seen_movies=movies, seen_indptr=indptr, batch_quantum=8, **kw)
+
+
+def _slices(monkeypatch, rows, rank=RANK):
+    monkeypatch.setattr(engine_mod, "_SLICE_BYTES", rows * rank * 4)
+
+
+def _table(eng):
+    data, scale = eng._table
+    return np.asarray(data), None if scale is None else np.asarray(scale)
+
+
+@pytest.mark.parametrize("shards", [None, 2, 4])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_int8_answers_equal_the_quantized_reference(seed, shards):
+    uf, mf, lists, rows = _problem(seed)
+    k = 7
+    vals, ids = _engine(uf, mf, lists, shards=shards).topk(rows, k)
+    best, best_ids, at = quantized_topk(
+        uf[rows], mf, [lists[r] for r in rows], k, ids, block=97)
+    np.testing.assert_array_equal(ids, best_ids)
+    rank_gap, score_err = topk_gaps(vals, best, at)
+    assert rank_gap == 0.0 and score_err <= 2e-6
+    assert rank_gap <= RANK_GAP and score_err <= SCORE_ERR
+    for row, got in zip(rows, ids):
+        assert not set(got.tolist()) & set(np.asarray(lists[row]).tolist())
+    # and it is the quantized table's answer, not the float32 table's
+    exact, _, _ = exact_topk_blocks(uf[rows], mf, [lists[r] for r in rows], k)
+    assert np.abs(exact - vals).max() > 1e-4
+
+
+@pytest.mark.parametrize("reader", ["array", "callable"])
+@pytest.mark.parametrize("shards", [None, 2, 4])
+@pytest.mark.parametrize("slice_rows", [100, 256, 5000])
+def test_sliced_upload_is_the_whole_table_quantizer_to_the_bit(
+        slice_rows, shards, reader, monkeypatch):
+    """100 rows a slice is no multiple of the 16-row tile and leaves a last
+    short slice on every device; 5,000 is the table in one piece."""
+    uf, mf, lists, rows = _problem(2)
+    _slices(monkeypatch, slice_rows)
+    asked = []
+
+    def read(lo, hi):
+        asked.append((lo, hi))
+        return mf[lo:hi]
+
+    eng = _engine(uf, read if reader == "callable" else mf, lists,
+                  shards=shards, num_movies=mf.shape[0])
+    data, scale = _table(eng)
+    per = -(-1000 // ((shards or 1) * TILE)) * TILE
+    assert data.shape == (per * (shards or 1), RANK) and data.dtype == np.int8
+    padded = np.zeros((data.shape[0], RANK), np.float32)
+    padded[:1000] = mf
+    # the rule three ways: the plain reference, the program's device
+    # quantizer run op by op on the CPU (IEEE), the program's host quantizer
+    for codes, scales in (quantize_rows(padded),
+                          quantize_table(jnp.asarray(padded), "int8"),
+                          quantize_rows_host(padded, threads=3)):
+        np.testing.assert_array_equal(data, np.asarray(codes))
+        np.testing.assert_array_equal(scale, np.asarray(scales))
+    assert scale[3] == 1.0 and not data[3].any()
+    if reader == "callable":
+        # every row asked for once, in ranges of at most a slice, none past
+        # the catalogue
+        assert sorted(asked) == asked and asked[0][0] == 0
+        assert all(0 < hi - lo <= slice_rows for lo, hi in asked)
+        assert sum(hi - lo for lo, hi in asked) == 1000
+    want = _engine(uf, mf, lists).topk(rows, 7)
+    for a, b in zip(want, eng.topk(rows, 7)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shards", [None, 2])
+def test_sliced_upload_of_a_float_table_is_the_whole_cast(
+        table_dtype, shards, monkeypatch):
+    uf, mf, lists, rows = _problem(3)
+    whole = _engine(uf, mf, lists, shards=shards, table_dtype=table_dtype)
+    _slices(monkeypatch, 100)
+    sliced = _engine(uf, lambda lo, hi: mf[lo:hi], lists, shards=shards,
+                     table_dtype=table_dtype, num_movies=mf.shape[0])
+    assert sliced._table[1] is None
+    np.testing.assert_array_equal(np.asarray(sliced._table[0]),
+                                  np.asarray(whole._table[0]))
+    for a, b in zip(whole.topk(rows, 7), sliced.topk(rows, 7)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_one_pass_bfloat16_control_fails_the_stated_limits(monkeypatch):
+    """The nearest arithmetic under the stated one: the dequantized tile
+    through a one-pass bfloat16 matmul.  No knob of the program does that;
+    the test patches the fold's compute dtype.  It must fail both limits
+    while every id it serves stays valid."""
+    uf, mf, lists, _ = _problem(4, m=20_001, users=256, rank=128)
+    rows, k = np.arange(256), 10
+    sound = _engine(uf, mf, lists, tile_m=512).topk(rows, k)
+    real = topk_kernel.serve_compute_dtype
+    monkeypatch.setattr(
+        topk_kernel, "serve_compute_dtype",
+        lambda dtype: (jnp.bfloat16, None) if dtype == jnp.int8
+        else real(dtype))
+    # jax keeps traces by function and shapes: another tile height (the
+    # scores do not depend on it) makes the patched fold a trace of its own
+    control = _engine(uf, mf, lists, tile_m=256).topk(rows, k)
+    seen = [lists[r] for r in rows]
+    best, _, at = quantized_topk(uf[rows], mf, seen, k, sound[1])
+    assert topk_gaps(sound[0], best, at) <= (RANK_GAP, SCORE_ERR)
+    best, _, at = quantized_topk(uf[rows], mf, seen, k, control[1])
+    rank_gap, score_err = topk_gaps(control[0], best, at)
+    assert rank_gap > RANK_GAP and score_err > SCORE_ERR
+    for row, got in zip(rows, control[1]):
+        assert len(set(got.tolist())) == k
+        assert not set(got.tolist()) & set(np.asarray(lists[row]).tolist())
+
+
+def test_a_table_past_the_device_budget_goes_up_in_slices_or_is_refused(
+        monkeypatch):
+    """32 KB of float32 against a device that holds 20 KB: whole, it is
+    refused in words before anything is allocated; as int8 it fits, and goes
+    up in 100-row slices of codes: no float32 slice of the table reaches the
+    device, no range over a slice is asked of the reader."""
+    uf, mf, lists, rows = _problem(5)
+    monkeypatch.setattr(engine_mod, "_device_bytes_limit", lambda d: 20_000)
+    _slices(monkeypatch, 100)
+    with pytest.raises(ValueError, match="does not fit its device.*32,256 B"
+                                         ".*20,000 B.*shards=.*table_dtype="):
+        _engine(uf, mf, lists, table_dtype="float32")
+    with pytest.raises(ValueError, match="does not fit its device"):
+        _engine(uf, lambda lo, hi: mf[lo:hi], lists, table_dtype="bfloat16",
+                num_movies=1000)  # 16 KB held + two 3.2 KB slices in flight
+    put, asked = [], []
+    real_put = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, *a, **kw: put.append((np.shape(x), np.asarray(x).dtype))
+        or real_put(x, *a, **kw))
+
+    def read(lo, hi):
+        asked.append(hi - lo)
+        return mf[lo:hi]
+
+    before = {id(a) for a in jax.live_arrays()}
+    tracer = telemetry.configure()
+    try:
+        eng = _engine(uf, read, lists, num_movies=1000)
+        vals, ids = eng.topk(rows, 7)
+        events = {e["name"]: e["args"] for e in tracer.events()
+                  if e.get("ph") == "X"}
+    finally:
+        telemetry.shutdown(write=False)
+    assert max(asked) == 100 and sum(asked) == 1000
+    tables = [(shape, dt) for shape, dt in put if len(shape) == 2]
+    assert tables == [((100, RANK), np.int8)] * 10 + [((8, RANK), np.int8)]
+    for a in jax.live_arrays():
+        if id(a) not in before and a.ndim == 2 and a.shape[0] >= 1000:
+            assert a.dtype == jnp.int8, (a.shape, a.dtype)
+    held = 1008 * RANK + 1008 * 4
+    assert events["serve/engine/table_upload"] == {
+        "shards": 1, "rows_per_shard": 1008, "rows_per_slice": 100,
+        "slices": 11, "bytes": held, "table_dtype": "int8",
+        "quantized_on": "host"}
+    compute = events["serve/batch/compute"]
+    assert compute["table_dtype"] == "int8" and compute["scan_bytes"] == held
+    best, best_ids, at = quantized_topk(
+        uf[rows], mf, [lists[r] for r in rows], 7, ids)
+    np.testing.assert_array_equal(ids, best_ids)
+    assert topk_gaps(vals, best, at) <= (0.0, 2e-6)
+
+
+@pytest.mark.parametrize("shards", [None, 2])
+def test_deltas_and_a_swap_keep_the_rule_and_the_placement(shards,
+                                                          monkeypatch):
+    """A delta row's codes and scale are what quantizing the whole updated
+    table gives, and ``load_state`` takes a row reader as the constructor
+    does."""
+    uf, mf, lists, rows = _problem(6)
+    _slices(monkeypatch, 300)
+    eng = _engine(uf, mf, lists, shards=shards)
+    rng = np.random.default_rng(7)
+    delta_rows = np.array([0, 3, 299, 300, 999])
+    delta = ((rng.random((5, RANK), dtype=np.float32) - 0.5) * 0.35)
+    assert eng.apply_movie_deltas(delta_rows, delta) == 5
+    mf2 = mf.copy()
+    mf2[delta_rows] = delta
+    padded = np.zeros((eng.table_rows, RANK), np.float32)
+    padded[:1000] = mf2
+    for got, want in zip(_table(eng), quantize_rows(padded)):
+        np.testing.assert_array_equal(got, want)
+    mf3 = ((rng.random(mf.shape, dtype=np.float32) - 0.5) * 0.35)
+    placed = eng._table[0].sharding
+    eng.load_state(uf, lambda lo, hi: mf3[lo:hi], epoch=2)
+    assert eng._table[0].sharding == placed and eng.table_swaps == 1
+    padded[:1000] = mf3
+    for got, want in zip(_table(eng), quantize_rows(padded)):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_two_stage_needs_the_table_whole():
+    uf, mf, lists, _ = _problem(8)
+    with pytest.raises(ValueError, match="two_stage.*array"):
+        _engine(uf, lambda lo, hi: mf[lo:hi], lists, num_movies=1000,
+                serve_mode="two_stage", clusters=8, probe_clusters=2)
+
+
+def test_host_quantizer_is_the_rule_on_awkward_rows():
+    """Ties at .5 round to even, the largest magnitude maps to ±127, a
+    zero row keeps scale 1, and the pieces of the thread pool join up."""
+    rng = np.random.default_rng(9)
+    f = ((rng.random((40_000, 16), dtype=np.float32) - 0.5) * 0.35)
+    f[0] = 0.0
+    f[1] = 0.0
+    f[1, :7] = [127.0, 0.5, 1.5, 2.5, 3.5, -0.5, -1.5]  # scale exactly 1
+    f[2, 5] = -3.0
+    for threads in (1, 4):
+        codes, scales = quantize_rows_host(f, threads=threads)
+        want_c, want_s = quantize_rows(f)
+        np.testing.assert_array_equal(codes, want_c)
+        np.testing.assert_array_equal(scales, want_s)
+    assert scales[0] == 1.0 and not codes[0].any()
+    assert codes[2, 5] == -127 and np.abs(codes).max() == 127
+    # halves round to the even neighbour
+    assert scales[1] == 1.0
+    assert codes[1, :7].tolist() == [127, 0, 2, 2, 4, 0, -2]

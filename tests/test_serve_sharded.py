@@ -257,8 +257,9 @@ def test_spans_of_a_sharded_engine():
     finally:
         telemetry.shutdown(write=False)
     up = events["serve/engine/table_upload"]["args"]
-    assert up == {"shards": 4, "rows_per_shard": 256,
-                  "bytes": 1024 * RANK * 4}
+    assert up == {"shards": 4, "rows_per_shard": 256, "rows_per_slice": 256,
+                  "slices": 4, "bytes": 1024 * RANK * 4,
+                  "table_dtype": "float32", "quantized_on": "none"}
     cells = int(sum(len(lists[r]) for r in rows))
     seen = events["serve/batch/seen_tiles"]["args"]
     assert seen["cells"] == cells == sum(seen["shard_cells"])
@@ -276,19 +277,26 @@ def test_spans_of_a_sharded_engine():
     # and the gated exclusion's: chunks run, tiles that ran any
     assert 1 <= compute["seen_hit_tiles"] <= compute["tiles"]
     assert compute["seen_hit_tiles"] <= compute["seen_chunks"]
-    # a one-device engine's spans carry none of it
+    # what the scorer streams for the batch, all four shards'
+    assert compute["table_dtype"] == "float32"
+    assert compute["scan_bytes"] == 1024 * RANK * 4
+    # a one-device engine uploads through the same span (PR 32) and its
+    # batch spans carry nothing of the mesh
     tracer = telemetry.configure()
     try:
         _engine(uf, mf, lists).topk(rows, k)
         events = {e["name"]: e for e in tracer.events() if e.get("ph") == "X"}
     finally:
         telemetry.shutdown(write=False)
-    assert "serve/engine/table_upload" not in events
+    assert events["serve/engine/table_upload"]["args"] == {
+        "shards": 1, "rows_per_shard": 1008, "rows_per_slice": 1008,
+        "slices": 1, "bytes": 1008 * RANK * 4, "table_dtype": "float32",
+        "quantized_on": "none"}
     assert "shards" not in events["serve/batch/upload"]["args"]
     assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
     assert set(events["serve/batch/compute"]["args"]) == {
         "n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
-        "seen_hit_tiles", "tiles"}
+        "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes"}
     assert {x: events["serve/batch/compute"]["args"][x]
             for x in ("seen_chunks", "seen_hit_tiles")} == {
         x: compute[x] for x in ("seen_chunks", "seen_hit_tiles")}

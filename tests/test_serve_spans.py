@@ -30,6 +30,7 @@ BATCH_SPANS = (
     "serve/batch/compute/fetch",
     "serve/batch/respond",
 )
+UPLOAD_SPAN = "serve/engine/table_upload"  # set-up, once per table
 NUM_USERS, NUM_MOVIES, RANK = 12, 40, 4
 
 
@@ -87,7 +88,9 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     assert server.step() == 4
     events = tracer.events()
     spans = _by_name(events)
-    assert sorted(spans) == sorted(BATCH_SPANS)
+    # the engine was built under the tracer: its set-up span (PR 32: on the
+    # one-device route too), then one of each of the batch's
+    assert sorted(spans) == sorted(BATCH_SPANS + (UPLOAD_SPAN,))
     assert all(len(v) == 1 for v in spans.values())
     telemetry.validate_span_tree(events)
     args = {name: v[0]["args"] for name, v in spans.items()}
@@ -141,14 +144,16 @@ def test_children_nest_in_their_parents(tracer):
 
 def test_an_empty_poll_emits_no_event(tracer):
     server, client = _served(_engine(), [])
+    built = tracer.events()  # the engine's set-up span, nothing else
+    assert [e["name"] for e in built] == [UPLOAD_SPAN]
     assert server.step() == 0
-    assert tracer.events() == []
+    assert tracer.events() == built
     # a frame that decodes to nothing still leaves a poll with no requests
     client.transport.produce(server.requests_topic, key=0, value=b"junk",
                              partition=0)
     assert server.step() == 0
     assert server.malformed_requests == 1
-    assert tracer.events() == []
+    assert tracer.events() == built
 
 
 @pytest.mark.parametrize("exclude_seen", [True, False])
@@ -192,7 +197,7 @@ def test_server_responses_identical_traced_and_untraced():
     off, none = answers(False)
     on, events = answers(True)
     assert off == on and len(off) == 4
-    assert none == [] and len(events) == len(BATCH_SPANS)
+    assert none == [] and len(events) == len(BATCH_SPANS) + 1  # + the upload
 
 
 def test_export_is_on_the_unix_epoch_events_on_perf_counter(tmp_path, tracer):
