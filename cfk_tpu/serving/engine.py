@@ -38,6 +38,11 @@ user rows" and "[B, K] ids+scores":
   where the scorer reads it.  A batch with more cells than one piece runs
   the same scatter program again on top, so ``prewarm``'s batch-size
   ladder closes the program set whatever the data,
+- a batch in two halves (``TopKBatch``): ``stage`` gathers, groups and
+  uploads and captures the table; ``compute`` hands one batch to the
+  device and fetches another's answer, the same one for ``topk``, the one
+  a step older for a request server under a backlog, which so keeps the
+  device busy under its own host work,
 - two-stage clustered retrieval (ISSUE 16, ``serve_mode="two_stage"``):
   a k-means index over the item factors (``serving.cluster``), rebuilt
   ATOMICALLY on every table swap, probed by a centroid stage
@@ -567,21 +572,36 @@ class ServeEngine:
 
         ``force_exact`` skips the two-stage candidate path for this one
         batch (same table, same masks, same jitted exact program) — the
-        dense oracle the recall@K measurements score against."""
-        return self._topk(user_rows, k, exclude_seen, force_exact)
+        dense oracle the recall@K measurements score against.
 
-    def _topk(self, user_rows, k, exclude_seen, force_exact,
-              min_seen_chunks=1):
-        """``topk``; ``prewarm`` asks for the seen cells in two pieces at
-        least, which runs every program a batch over the capacity runs."""
+        The two halves of one ``TopKBatch``, back to back: ``stage`` and
+        the hand-over to the device, then the fetch.  The request server
+        runs the same halves one step apart (``compute``)."""
+        batch = self.stage(user_rows, k, exclude_seen=exclude_seen,
+                           force_exact=force_exact)
+        return compute(batch, batch)
+
+    def stage(self, user_rows, k: int, *, exclude_seen: bool = True,
+              force_exact: bool = False,
+              min_seen_chunks: int = 1) -> "TopKBatch":
+        """The host's part of ``topk``'s front half: gather the user rows,
+        group the seen cells, upload both.  The ``TopKBatch`` it returns
+        owns the table it will be scored against (captured under the lock
+        with the user rows and the epoch) and everything its answer needs,
+        so a table swapped before the fetch changes nothing for it: no
+        donation, a batch in flight keeps its table.  ``compute`` hands it
+        to the device and fetches it.  ``prewarm`` asks for the seen cells
+        in two pieces at least, which runs every program a batch over the
+        capacity runs.  The two-stage route syncs with the host between
+        its stages: its batch comes back already answered."""
         import jax
         import jax.numpy as jnp
 
         user_rows = np.asarray(user_rows, dtype=np.int64)
         n = user_rows.shape[0]
         if n == 0:
-            return (np.zeros((0, k), np.float32),
-                    np.zeros((0, k), np.int32))
+            return TopKBatch(self, n=0, k=k, epoch=self.epoch, result=(
+                np.zeros((0, k), np.float32), np.zeros((0, k), np.int32)))
         if np.any((user_rows < 0) | (user_rows >= self.num_users)):
             bad = user_rows[(user_rows < 0)
                             | (user_rows >= self.num_users)][:5]
@@ -595,6 +615,7 @@ class ServeEngine:
             with self._lock:
                 table, scale = self._table
                 cluster = self._cluster
+                epoch = self.epoch
                 u = np.zeros((b, self._u_base.shape[1]), np.float32)
                 u[:n] = self._gather_users(user_rows)
                 seen = self._batch_seen(user_rows) if exclude_seen else None
@@ -615,10 +636,11 @@ class ServeEngine:
             out = self._topk_two_stage(cluster, u, n, b, k, seen_pad,
                                        min_seen_chunks)
             if out is not None:
-                return out
+                return TopKBatch(self, n=n, k=k, epoch=epoch, result=out)
             # a detected fault fell through: the exact path below IS the
             # un-disableable fallback — same table, same jitted program
             # as serve_mode="exact", so the degraded answer is bit-exact
+        tiles = table.shape[0] // self.tile_m
         seen = shape = None
         if seen_pad is not None:
             movies, indptr_pad = seen_pad
@@ -626,8 +648,7 @@ class ServeEngine:
                 cells, shape = group_seen_cells(
                     movies, indptr_pad, np.arange(b),
                     num_movies=self.num_movies,
-                    tile_m=self.tile_m,
-                    num_tiles=self.table_rows // self.tile_m,
+                    tile_m=self.tile_m, num_tiles=tiles,
                 )
                 seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
                 if self.mesh is not None:
@@ -636,8 +657,8 @@ class ServeEngine:
                         cells[0] // (shape[0] // self._shards),
                         minlength=self._shards).tolist())
         # the calls that hand the batch to the runtime; they may return
-        # before the bytes have landed, and the fetch below then waits
-        # for the transfer as well as for the scorer
+        # before the bytes have landed, and the fetch then waits for the
+        # transfer as well as for the scorer
         with span("serve/batch/upload") as sp:
             nbytes = u.nbytes
             if seen is not None:
@@ -654,42 +675,21 @@ class ServeEngine:
                 seen = [put(c) for c in seen]
             u = put(u)
             sp.set(bytes=nbytes)
-        with span("serve/batch/compute", n=n, b=b, k=k) as sp:
-            with span("serve/batch/compute/dispatch"):
-                seen_tiles = self._seen_tiles(seen, shape, self.mesh)
-                if self.mesh is not None:
-                    from cfk_tpu.parallel.spmd import serve_topk_sharded
-
-                    sp.set(shards=self._shards,
-                           merge_candidates=self._shards * k)
-                    vals, ids, counts = serve_topk_sharded(
-                        self.mesh, u, table, scale, seen_tiles, k_top=k,
-                        num_movies=self.num_movies, tile_m=self.tile_m,
-                    )
-                else:
-                    vals, ids, counts = _topk_jit_fn()(
-                        u, table, scale, seen_tiles, k_top=k,
-                        num_movies=self.num_movies, tile_m=self.tile_m,
-                    )
-            with span("serve/batch/compute/fetch") as fetch:
-                vals, ids = np.asarray(vals), np.asarray(ids)
-                # a [4] row a shard: the host adds them, no collective
-                counts = np.asarray(counts).reshape(-1, 4).sum(axis=0)
-                fetch.set(bytes=vals.nbytes + ids.nbytes)
-            # what the data made this batch cost, over every tile scanned
-            # (all shards'): selection rounds run and tiles that ran any,
-            # exclusion chunks run and tiles that ran any
-            sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
-                   seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
-                   tiles=self.table_rows // self.tile_m,
-                   # what the scorer streams from HBM for the batch, all
-                   # shards': the table as it is held, and its scales
-                   table_dtype=self.table_dtype,
-                   scan_bytes=table.nbytes
-                   + (0 if scale is None else scale.nbytes))
-            vals, ids = vals[:n], ids[:n]
-        self._record_scan(mode="exact", b=b, k=k)
-        return vals, ids
+        # what the fetch will say of the batch on ``serve/batch/compute``
+        counters = dict(
+            n=n, b=b, k=k, tiles=tiles,
+            # what the scorer streams from HBM for the batch, all shards':
+            # the table as it is held, and its scales
+            table_dtype=self.table_dtype,
+            scan_bytes=table.nbytes + (0 if scale is None else scale.nbytes))
+        if self.mesh is not None:
+            counters.update(shards=self._shards,
+                            merge_candidates=self._shards * k)
+        return TopKBatch(
+            self, n=n, k=k, epoch=epoch, counters=counters,
+            operands=(u, table, scale, seen, shape),
+            scan=self._scan_record(mode="exact", b=b, k=k,
+                                   table_rows=table.shape[0]))
 
     def _seen_tiles(self, chunks, shape, mesh=None):
         """The [NT, B, W] exclusion rectangle on the device and which of
@@ -776,8 +776,9 @@ class ServeEngine:
             )
             vals = np.asarray(vals)[:n]
             ids = map_shortlist_ids(np.asarray(ids)[:n], shortlist)
-        self._record_scan(mode="two_stage", b=b, k=k, shortlist=shortlist,
-                          probe=probe, index=index)
+        self._publish_scan(self._scan_record(
+            mode="two_stage", b=b, k=k, shortlist=shortlist, probe=probe,
+            index=index))
         return vals, ids
 
     def _two_stage_fault(self, reason: str) -> None:
@@ -802,12 +803,12 @@ class ServeEngine:
         if self.metrics is not None:
             self.metrics.incr("serve/two_stage_fallbacks")
 
-    def _record_scan(self, *, mode, b, k, shortlist=None, probe=0,
-                     index=None) -> None:
+    def _scan_record(self, *, mode, b, k, shortlist=None, probe=0,
+                     index=None, table_rows=None) -> dict:
         """Per-batch scan accounting: the MEASURED byte traffic of the
         executed mode (``utils.roofline.serve_batch_cost`` over the real
-        shortlist union for two_stage), exposed as ``last_scan`` for the
-        bench rows and as metrics gauges."""
+        shortlist union for two_stage), as ``_publish_scan`` exposes it
+        once the batch is answered."""
         from cfk_tpu.utils.roofline import serve_batch_cost
 
         rank = int(self._u_base.shape[1])
@@ -818,7 +819,7 @@ class ServeEngine:
                 probe_clusters=probe,
                 shortlist_rows=shortlist.rows_padded,
             )
-            self.last_scan = {
+            return {
                 "serve_mode": "two_stage",
                 "clusters": index.num_clusters,
                 "probe_clusters": probe,
@@ -827,18 +828,21 @@ class ServeEngine:
                 "index_stale_rows": index.stale_rows,
                 "bytes_scanned_per_batch": round(cost.hbm_bytes),
             }
-        else:
-            cost = serve_batch_cost(
-                self.num_movies, rank, b, k, table_dtype=self.table_dtype,
-                m_pad=self.table_rows,
-            )
-            self.last_scan = {
-                "serve_mode": "exact",
-                "bytes_scanned_per_batch": round(cost.hbm_bytes),
-            }
+        cost = serve_batch_cost(
+            self.num_movies, rank, b, k, table_dtype=self.table_dtype,
+            m_pad=table_rows,
+        )
+        return {
+            "serve_mode": "exact",
+            "bytes_scanned_per_batch": round(cost.hbm_bytes),
+        }
+
+    def _publish_scan(self, record: dict) -> None:
+        """``last_scan`` for the bench rows, and the metrics gauge."""
+        self.last_scan = record
         if self.metrics is not None:
             self.metrics.gauge("serve/bytes_scanned_per_batch",
-                               self.last_scan["bytes_scanned_per_batch"])
+                               record["bytes_scanned_per_batch"])
 
     @property
     def trace_count(self) -> int:
@@ -884,6 +888,12 @@ class ServeEngine:
                 return {"programs": 0, "new_traces": 0, "prewarm_s": 0.0}
             before = trace_count()
             programs = 0
+
+            def warm(take):
+                batch = self.stage(take, k, exclude_seen=exclude_seen,
+                                   min_seen_chunks=2)
+                compute(batch, batch)
+
             b = self.batch_quantum
             while b <= top:
                 take = rows[: min(b, rows.size)]
@@ -892,7 +902,7 @@ class ServeEngine:
                 # sample still traces the intended batch size
                 if take.size < b:
                     take = np.resize(take, b)
-                self._topk(take, k, exclude_seen, False, min_seen_chunks=2)
+                warm(take)
                 programs += 1
                 if self.serve_mode == "two_stage" and rows.size > b:
                     # a second, disjoint sample per rung: the shortlist
@@ -903,8 +913,7 @@ class ServeEngine:
                     alt = rows[b:2 * b]
                     if alt.size < b:
                         alt = np.resize(alt, b)
-                    self._topk(alt, k, exclude_seen, False,
-                               min_seen_chunks=2)
+                    warm(alt)
                 b *= 2
             self.prewarmed = True  # the /readyz gate flips here
             return {
@@ -912,6 +921,103 @@ class ServeEngine:
                 "new_traces": trace_count() - before,
                 "prewarm_s": round(_time.time() - t0, 4),
             }
+
+
+class TopKBatch:
+    """One batch of ``ServeEngine.topk`` between its two halves.
+
+    ``ServeEngine.stage`` makes it; ``dispatch`` hands it to the device
+    (the jitted scatter once per piece of the cell list, then the scorer:
+    asynchronous calls that return at once) and ``fetch`` waits for the
+    answer and copies it to the host.  The fetch reads the batch alone and
+    nothing of the engine (it leaves the batch's scan record there), so
+    whatever the engine became in between (a commit, a delta, a table
+    swap, another engine in its server's place) the answer is that of the
+    table and the ``epoch`` the batch was staged against.  A batch that
+    needs no device (no rows; the two-stage route, which syncs with the
+    host between its stages) is made with its ``result`` and both halves
+    pass it through."""
+
+    def __init__(self, engine, *, n, k, epoch, counters=None,
+                 operands=None, scan=None, result=None) -> None:
+        self.engine = engine
+        self.n, self.k = n, k
+        self.epoch = epoch
+        self.counters = counters
+        self.scan = scan
+        self.result = result
+        self._operands = operands
+        self._out = None
+
+    @property
+    def on_device(self) -> bool:
+        """Handed to the device and not fetched yet."""
+        return self._out is not None
+
+    @property
+    def failed(self) -> bool:
+        """Its fetch raised: nothing is left to answer it from."""
+        return (self._operands is None and self._out is None
+                and self.result is None)
+
+    def dispatch(self) -> None:
+        eng = self.engine
+        u, table, scale, seen, shape = self._operands
+        seen_tiles = eng._seen_tiles(seen, shape, eng.mesh)
+        if eng.mesh is not None:
+            from cfk_tpu.parallel.spmd import serve_topk_sharded
+
+            out = serve_topk_sharded(
+                eng.mesh, u, table, scale, seen_tiles, k_top=self.k,
+                num_movies=eng.num_movies, tile_m=eng.tile_m,
+            )
+        else:
+            out = _topk_jit_fn()(
+                u, table, scale, seen_tiles, k_top=self.k,
+                num_movies=eng.num_movies, tile_m=eng.tile_m,
+            )
+        # the runtime keeps what the programs read
+        self._operands, self._out = None, out
+
+    def fetch(self, sp):
+        """(scores [n, k], movie rows [n, k]) on the host; ``sp`` is the
+        open ``serve/batch/compute`` span, which takes the batch's
+        counters."""
+        (vals, ids, counts), self._out = self._out, None
+        with span("serve/batch/compute/fetch") as fetch:
+            vals, ids = np.asarray(vals), np.asarray(ids)
+            # a [4] row a shard: the host adds them, no collective
+            counts = np.asarray(counts).reshape(-1, 4).sum(axis=0)
+            fetch.set(bytes=vals.nbytes + ids.nbytes)
+        # what the data made this batch cost, over every tile scanned
+        # (all shards'): selection rounds run and tiles that ran any,
+        # exclusion chunks run and tiles that ran any
+        sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
+               seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
+               **self.counters)
+        self.engine._publish_scan(self.scan)
+        self.result = vals[:self.n], ids[:self.n]
+        return self.result
+
+
+def compute(dispatch: TopKBatch | None, fetch: TopKBatch | None):
+    """One ``serve/batch/compute`` span: hand ``dispatch`` to the device,
+    then fetch ``fetch``'s answer and return it (None without one).
+    ``ServeEngine.topk`` passes one batch as both; the request server under
+    a backlog passes the batch it has just polled and the one it handed
+    over a step ago, whose scorer ran under the host's stages since.  The
+    span's counters are the fetched batch's.  Batches that came back from
+    ``stage`` already answered open no span."""
+    to_device = dispatch is not None and dispatch.result is None
+    from_device = fetch is not None and fetch.result is None
+    if to_device or from_device:
+        with span("serve/batch/compute") as sp:
+            if to_device:
+                with span("serve/batch/compute/dispatch"):
+                    dispatch.dispatch()
+            if from_device:
+                fetch.fetch(sp)
+    return None if fetch is None else fetch.result
 
 
 def _seen_chunks(sp, cells: np.ndarray, shape, min_chunks: int):

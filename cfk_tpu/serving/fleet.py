@@ -593,6 +593,8 @@ class FleetReplica:
                 return
             if not got:
                 time.sleep(self.server.poll_wait_s)
+        if not self._kill.is_set():
+            self.server.drain()  # a clean stop answers the batch in flight
 
     def start(self) -> "FleetReplica":
         if self._thread is None or not self._thread.is_alive():
@@ -619,7 +621,8 @@ class FleetReplica:
     def kill(self) -> None:
         """Abrupt termination (the SIGKILL stand-in): the loop exits at
         the next instruction boundary WITHOUT committing cursors — polled
-        but unanswered requests are left for the survivor to re-serve."""
+        but unanswered requests, the batch in flight on the device among
+        them, are left for the survivor to re-serve."""
         self._kill.set()
         self._stop.set()
         if self._thread is not None:

@@ -15,14 +15,23 @@ Kafka producer and PR 6's fold-in micro-batches: under open-loop load the
 natural batch size self-tunes — a busy server finds more requests pending
 per poll, amortizing the per-batch dispatch over more queries, which is
 what makes the QPS-vs-latency trade measurable (PERF.md §4).
+
+Under a backlog the server keeps one batch in flight on the device
+(``RecommendServer.step``): the batch just polled is staged and handed
+over, then the batch handed over a step ago is fetched and answered, so
+the scorer runs under the host's poll, assembly, upload and responses
+instead of after them.  With no backlog a batch is answered in the step
+that polled it.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import numpy as np
 
+from cfk_tpu.serving.engine import TopKBatch, compute
 from cfk_tpu.serving.topk_kernel import _pow2_ceil
 from cfk_tpu.telemetry import get_tracer, record_event, span
 from cfk_tpu.transport.serdes import (
@@ -98,6 +107,10 @@ class RecommendServer:
         # (at-least-once) anything the victim had polled but not yet
         # answered, so no accepted request is ever silently lost.
         self.committed_cursors = dict(self._cursors)
+        # One batch deep: the batch handed to the device and not answered
+        # yet (``step``), and the ordinal the spans of a step share.
+        self._in_flight: _Polled | None = None
+        self._steps = 0
         self.requests_served = 0
         self.batches = 0
         self.malformed_requests = 0
@@ -192,18 +205,43 @@ class RecommendServer:
         return epoch, stale
 
     def step(self) -> int:
-        """Serve ONE coalesced batch; returns the number of requests
-        answered (0 = nothing pending).  Requests shed by admission
-        control are answered too — with an explicit RETRIABLE rejection,
-        never a silent drop — and count toward the return value."""
-        # The spans of one batch share its ordinal: this poll feeds batch
-        # ``batches + 1``.  An empty poll writes no event — an idle server
+        """Poll one coalesced batch and hand it to the device, then fetch
+        and answer the batch in flight; returns the number of requests
+        ANSWERED in this step (0 = nothing answered).
+
+        Whether the batch just polled stays in flight on the device over
+        the next step is decided by what the server sees.  With nothing in
+        flight and nothing more pending after the poll it is answered in
+        this same step, so a lightly loaded server adds no latency.  Under
+        a backlog it stays in flight and the older batch is answered: the
+        device scores batch n+1 under the fetch, the responses and the next
+        poll of batch n, and the first such step answers nothing.  A poll
+        that finds nothing answers the batch in flight, so a caller that
+        steps until every answer is in terminates.
+
+        Requests shed by admission control are answered too — with an
+        explicit RETRIABLE rejection, never a silent drop — and, like the
+        out-of-range rows' errors, with their batch: each once, counted in
+        the return value once."""
+        return self._step(self._poll_requests)
+
+    def drain(self) -> int:
+        """Answer the batch in flight, if there is one, without polling
+        for another; returns the number of requests answered."""
+        return self._step(list)
+
+    def _step(self, poll) -> int:
+        in_flight = self._in_flight
+        # The spans of one step share its ordinal.  A step that neither
+        # polls nor answers anything writes no event — an idle server
         # polls every millisecond.
-        batch = self.batches + 1
-        with span("serve/poll", batch=batch) as sp:
+        ordinal = self._steps + 1
+        with span("serve/poll", batch=ordinal) as sp:
             malformed = self.malformed_requests
-            reqs = self._poll_requests()
-            if not reqs:
+            reqs = poll()
+            # what the log holds of this batch: committed once it is answered
+            cursors = dict(self._cursors)
+            if not reqs and in_flight is None:
                 sp.drop()
             elif get_tracer() is not None:
                 sp.set(requests=len(reqs),
@@ -223,111 +261,167 @@ class RecommendServer:
                 self.malformed_requests += 1
                 self.metrics.incr("serve_malformed_requests")
         reqs = routable
-        if not reqs:
+        if not reqs and in_flight is None:
             return 0
         shed: list[ScoreRequest] = []
-        if self.admission is not None:
+        if reqs and self.admission is not None:
             reqs, shed = self.admission.admit(reqs)
-        t_batch = time.perf_counter()
-        epoch, staleness = self._stamp()
+        self._steps = ordinal
+        # a batch was on the device while this step polled, and stays
+        # there while it assembles, uploads and hands over the next
+        overlapped = in_flight is not None and in_flight.on_device
+        if overlapped:
+            self.metrics.incr("serve_batches_overlapped")
         with span("serve/batch", requests=len(reqs), shed=len(shed),
-                  batch=batch):
-            # Refuse out-of-range rows per REQUEST (an error response),
-            # never per batch — one bad query must not poison its
-            # co-batched neighbors.
-            with span("serve/batch/validate", requests=len(reqs)):
-                valid: list[ScoreRequest] = []
-                errors: list[ScoreRequest] = []
-                for r in reqs:
-                    ok = (0 <= r.user < self.engine.num_users
-                          and 1 <= r.k <= self.engine.num_movies)
-                    (valid if ok else errors).append(r)
-            if valid:
-                k_pad = _pow2_ceil(
-                    max(r.k for r in valid),
-                    min(8, self.engine.num_movies),
+                  batch=ordinal, overlapped=overlapped):
+            polled = (self._validate(reqs, shed, cursors)
+                      if reqs or shed else None)
+            if in_flight is None and self._pending() == 0:
+                # straight through: engine.topk is both halves of the
+                # batch back to back, under the spans of one
+                answer = (self.engine.topk(polled.rows, polled.k)
+                          if polled.valid else None)
+                return self._respond(polled, answer)
+            try:
+                if polled is not None and polled.valid:
+                    # the assemble, seen_tiles and upload spans: the
+                    # host's side of the polled batch's timeline
+                    polled.handle = self.engine.stage(polled.rows, polled.k)
+                    polled.epoch = polled.handle.epoch
+                # one serve/batch/compute: the polled batch's dispatch,
+                # the answered batch's fetch and counters
+                answer = compute(
+                    None if polled is None else polled.handle,
+                    None if in_flight is None else in_flight.handle)
+            except BaseException:
+                # One half failed; the other's batch is not lost with it.
+                # A failed hand-over leaves the older batch in flight.  A
+                # failed fetch drops its batch and leaves the one just
+                # handed over in flight.  Whatever goes unanswered stays
+                # uncommitted, for an heir to re-serve.
+                if in_flight is not None and in_flight.fetch_failed:
+                    self._in_flight = polled
+                raise
+            self._in_flight = polled
+            if in_flight is None:
+                return 0
+            return self._respond(in_flight, answer)
+
+    def _validate(self, reqs, shed, cursors) -> "_Polled":
+        """One polled batch as the engine that serves now sees it; the
+        record keeps all that its answer will need."""
+        t0 = time.perf_counter()
+        epoch, staleness = self._stamp()
+        engine = self.engine
+        # Refuse out-of-range rows per REQUEST (an error response),
+        # never per batch — one bad query must not poison its
+        # co-batched neighbors.
+        with span("serve/batch/validate", requests=len(reqs)):
+            valid: list[ScoreRequest] = []
+            errors: list[tuple[ScoreRequest, str]] = []
+            for r in reqs:
+                if (0 <= r.user < engine.num_users
+                        and 1 <= r.k <= engine.num_movies):
+                    valid.append(r)
+                else:
+                    errors.append((r, (
+                        f"user row {r.user} out of range "
+                        f"[0, {engine.num_users}) or k {r.k} "
+                        f"outside [1, {engine.num_movies}]")))
+        rows = k_pad = None
+        if valid:
+            k_pad = _pow2_ceil(
+                max(r.k for r in valid),
+                min(8, engine.num_movies),
+            )
+            k_pad = min(k_pad, engine.num_movies)
+            rows = np.asarray([r.user for r in valid], np.int64)
+        return _Polled(valid, errors, shed, rows, k_pad, epoch, staleness,
+                       cursors, t0)
+
+    def _respond(self, batch: "_Polled", answer) -> int:
+        """Produce and flush ``batch``'s responses, then commit its read
+        cursors; returns the number of requests answered."""
+        epoch, staleness = batch.epoch, batch.staleness
+        # respond: response objects, encode, produce, flush
+        with span("serve/batch/respond") as sp:
+            responses: list[tuple[int, ScoreResponse]] = []
+            if batch.valid:
+                scores, ids = answer
+            for i, r in enumerate(batch.valid):
+                responses.append((r.reply_partition, ScoreResponse(
+                    req_id=r.req_id,
+                    movie_rows=ids[i, : r.k],
+                    scores=scores[i, : r.k],
+                    epoch=epoch, staleness=staleness,
+                )))
+            for r, text in batch.errors:
+                responses.append((r.reply_partition, ScoreResponse(
+                    req_id=r.req_id,
+                    movie_rows=np.zeros(0, np.int32),
+                    scores=np.zeros(0, np.float32),
+                    error=text, epoch=epoch, staleness=staleness,
+                )))
+            for r in batch.shed:
+                # Explicit retriable rejection: the client backs off
+                # and re-sends; the request is ANSWERED, not dropped.
+                responses.append((r.reply_partition, ScoreResponse(
+                    req_id=r.req_id,
+                    movie_rows=np.zeros(0, np.int32),
+                    scores=np.zeros(0, np.float32),
+                    error="overloaded: admission queue depth exceeded",
+                    retriable=True, epoch=epoch, staleness=staleness,
+                )))
+            produced = 0
+            for part, resp in responses:
+                value = encode_score_response(resp)
+                produced += len(value)
+                self.transport.produce(
+                    self.responses_topic,
+                    key=int(resp.req_id % (1 << 31)),
+                    value=value, partition=part,
                 )
-                k_pad = min(k_pad, self.engine.num_movies)
-                rows = np.asarray([r.user for r in valid], np.int64)
-                # engine.topk opens the assemble, seen_tiles, upload and
-                # compute spans — the kernel side of this batch's timeline
-                scores, ids = self.engine.topk(rows, k_pad)
-            # respond: response objects, encode, produce, flush
-            with span("serve/batch/respond") as sp:
-                responses: list[tuple[int, ScoreResponse]] = []
-                for i, r in enumerate(valid):
-                    responses.append((r.reply_partition, ScoreResponse(
-                        req_id=r.req_id,
-                        movie_rows=ids[i, : r.k],
-                        scores=scores[i, : r.k],
-                        epoch=epoch, staleness=staleness,
-                    )))
-                for r in errors:
-                    responses.append((r.reply_partition, ScoreResponse(
-                        req_id=r.req_id,
-                        movie_rows=np.zeros(0, np.int32),
-                        scores=np.zeros(0, np.float32),
-                        error=(f"user row {r.user} out of range "
-                               f"[0, {self.engine.num_users}) or k {r.k} "
-                               f"outside [1, {self.engine.num_movies}]"),
-                        epoch=epoch, staleness=staleness,
-                    )))
-                for r in shed:
-                    # Explicit retriable rejection: the client backs off
-                    # and re-sends; the request is ANSWERED, not dropped.
-                    responses.append((r.reply_partition, ScoreResponse(
-                        req_id=r.req_id,
-                        movie_rows=np.zeros(0, np.int32),
-                        scores=np.zeros(0, np.float32),
-                        error="overloaded: admission queue depth exceeded",
-                        retriable=True, epoch=epoch, staleness=staleness,
-                    )))
-                produced = 0
-                for part, resp in responses:
-                    value = encode_score_response(resp)
-                    produced += len(value)
-                    self.transport.produce(
-                        self.responses_topic,
-                        key=int(resp.req_id % (1 << 31)),
-                        value=value, partition=part,
-                    )
-                flush = getattr(self.transport, "flush", None)
-                if flush is not None:
-                    flush()
-                sp.set(responses=len(responses), bytes=produced)
-        # Responses durable → commit the read cursors (failover handoff).
-        self.committed_cursors.update(self._cursors)
-        self.requests_served += len(reqs)
+            flush = getattr(self.transport, "flush", None)
+            if flush is not None:
+                flush()
+            sp.set(responses=len(responses), bytes=produced)
+        # Responses durable → commit the read cursors as they stood after
+        # THIS batch's poll (the failover handoff), never ``_cursors`` as
+        # they stand: those already cover the batch in flight.
+        self.committed_cursors.update(batch.cursors)
+        served, shed = len(batch.valid) + len(batch.errors), len(batch.shed)
+        self.requests_served += served
         self.batches += 1
         if shed:
-            self.shed += len(shed)
-            self.metrics.incr("serve_shed", len(shed))
-            record_event("serve", "shed", requests=len(shed),
-                         served=len(reqs))
-        self.metrics.incr("serve_requests", len(reqs))
+            self.shed += shed
+            self.metrics.incr("serve_shed", shed)
+            record_event("serve", "shed", requests=shed, served=served)
+        self.metrics.incr("serve_requests", served)
         self.metrics.incr("serve_batches")
         # Bounded-reservoir latency distributions (ISSUE 14): per-batch
-        # wall and coalesced size — the /metrics summary quantiles.
+        # wall, from its validation to its flush, and coalesced size —
+        # the /metrics summary quantiles.
         self.metrics.observe("serve_batch_ms",
-                             (time.perf_counter() - t_batch) * 1e3)
-        self.metrics.observe("serve_batch_size", len(reqs))
-        record_event("serve", "batch", requests=len(reqs),
+                             (time.perf_counter() - batch.t0) * 1e3)
+        self.metrics.observe("serve_batch_size", served)
+        record_event("serve", "batch", requests=served,
                      batch=self.batches)
-        return len(reqs) + len(shed)
+        return served + shed
 
     def serve_forever(self, *, max_requests: int | None = None,
                       idle_timeout_s: float | None = None,
                       stop=None) -> int:
         """Poll-and-serve loop; returns requests served.  Stops when
         ``stop()`` goes true, after ``max_requests``, or once the topic
-        has been idle ``idle_timeout_s`` (None = keep polling)."""
+        has been idle ``idle_timeout_s`` (None = keep polling), and
+        answers the batch in flight before it returns."""
         served = 0
         idle_since = time.monotonic()
         while True:
             if stop is not None and stop():
-                return served
+                break
             if max_requests is not None and served >= max_requests:
-                return served
+                break
             got = self.step()
             if got:
                 served += got
@@ -335,8 +429,33 @@ class RecommendServer:
                 continue
             if (idle_timeout_s is not None
                     and time.monotonic() - idle_since >= idle_timeout_s):
-                return served
+                break
             time.sleep(self.poll_wait_s)
+        return served + self.drain()
+
+
+@dataclasses.dataclass
+class _Polled:
+    """One polled batch, from its poll to its answer."""
+
+    valid: list  # requests the engine scores
+    errors: list  # (request, error text): rows or k out of range
+    shed: list  # requests admission refused, retriable
+    rows: np.ndarray | None  # the valid requests' user rows
+    k: int | None  # their padded K
+    epoch: int  # of the table it is scored against
+    staleness: int  # read when it was polled
+    cursors: dict  # the read cursors as they stood after its poll
+    t0: float
+    handle: TopKBatch | None = None  # staged on the engine, under a backlog
+
+    @property
+    def on_device(self) -> bool:
+        return self.handle is not None and self.handle.on_device
+
+    @property
+    def fetch_failed(self) -> bool:
+        return self.handle is not None and self.handle.failed
 
 
 class ServeClient:

@@ -395,6 +395,89 @@ def test_failover_reserves_uncommitted_requests_at_least_once():
     assert rid in by_id and not by_id[rid].error
 
 
+def test_a_replica_killed_with_a_batch_in_flight_is_reserved_by_the_heir():
+    """The victim has answered one batch, handed the next to the device and
+    polled a third (ISSUE 33) when it dies: its committed cursor stands
+    exactly past the batch whose responses were flushed, and the heir
+    serves everything after it, the batch in flight included."""
+    fleet, pub, broker, _ = _wired(replicas=2, max_batch=4)
+    client = ServeClient(broker, route_by_user=True)
+    victim, heir = fleet.replicas
+    ids = [client.request(2 * i, 3) for i in range(10)]  # all to partition 0
+    client.flush()
+    assert victim.pump() == 0  # batch 1 handed over, nothing answered
+    assert victim.server.committed_cursors[0] == 0
+    assert victim.pump() == 4  # batch 1 answered, batch 2 in flight
+    assert victim.server._in_flight.on_device
+    assert victim.server._cursors[0] == 8
+    assert victim.server.committed_cursors[0] == 4
+    answered = {r.req_id for r in client.poll_responses()}
+    assert answered == set(ids[:4])
+    victim.kill()
+    fleet.failover(0)
+    assert heir.server.committed_cursors[0] == heir.server._cursors[0] == 4
+    served = 0
+    while served < 6:
+        served += heir.pump()
+    rest = [r for r in client.poll_responses()]
+    assert sorted(r.req_id for r in rest) == sorted(ids[4:])
+    assert all(not r.error for r in rest)
+    assert heir.server.committed_cursors[0] == 10
+    assert heir.server._in_flight is None
+
+
+def test_a_clean_stop_answers_the_batch_in_flight():
+    fleet, pub, broker, _ = _wired(replicas=1, max_batch=4)
+    fleet.prewarm(3, max_batch=4)
+    client = ServeClient(broker)
+    ids = [client.request(i, 3) for i in range(23)]
+    client.flush()
+    fleet.start()
+    replica = fleet.replicas[0]
+    deadline = time.monotonic() + 30
+    while replica.server.batches < 2 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    fleet.stop()
+    # whatever it had polled when it was told to stop has its answer, and
+    # its committed cursor says so: nothing is left in flight
+    assert replica.server._in_flight is None
+    got = {r.req_id for r in client.poll_responses()}
+    done = replica.server.committed_cursors[0]
+    assert done == replica.server._cursors[0] >= 8
+    assert got == set(ids[:done])
+
+
+def test_a_flip_between_dispatch_and_fetch_keeps_the_old_epochs_batch():
+    """The new epoch's engine is swapped in while a batch handed to the old
+    one is on the device: that batch is answered from the old table with
+    the old epoch's stamp, the next from the new."""
+    fleet, pub, broker, (u, m) = _wired(max_batch=4)
+    client = ServeClient(broker)
+    replica = fleet.replicas[0]
+    fleet.prewarm(3, max_batch=4)
+    ids = [client.request(i, 3) for i in range(8)]
+    client.flush()
+    assert replica.pump() == 0 and replica.server._in_flight.on_device
+    u2, m2 = _factors(23)
+    pub.on_commit({"retrain": True, "user_factors": u2,
+                   "movie_factors": m2, "num_users": U})
+    replica.apply_deltas()  # starts the background prewarm
+    replica._pending_thread.join(timeout=60)
+    assert replica._pending is not None
+    assert replica.pump() == 4  # flips, polls batch 2, answers batch 1
+    assert replica.rollovers == 1 and replica.server.engine.epoch == 1
+    assert replica.pump() == 4
+    got = {r.req_id: r for r in client.poll_responses()}
+    old, new = _engine(u, m), _engine(u2, m2)
+    for rows, want_epoch, eng in ((range(4), 0, old), (range(4, 8), 1, new)):
+        s, i = eng.topk(np.asarray(list(rows)), 8)
+        for j, row in enumerate(rows):
+            resp = got[ids[row]]
+            assert resp.epoch == want_epoch
+            np.testing.assert_array_equal(resp.movie_rows, i[j, :3])
+            np.testing.assert_array_equal(resp.scores, s[j, :3])
+
+
 def test_responses_stamped_with_staleness_backlog():
     fleet, pub, broker, _ = _wired()
     ensure_serve_topics(broker)

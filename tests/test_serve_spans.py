@@ -1,7 +1,10 @@
-"""Spans of one serve batch (ISSUE 24): every stretch of the batch period in
+"""Spans of one serve step (ISSUE 24): every stretch of the batch period in
 which the host works or waits has a span at the place the work happens, the
-spans of one batch share its ordinal, tracing changes no answer, and the
-Chrome export is on the unix epoch through the tracer's one clock pair."""
+spans of one step share its ordinal, tracing changes no answer, and the
+Chrome export is on the unix epoch through the tracer's one clock pair.
+Under a backlog (ISSUE 33) a step's ``serve/batch`` holds the stages of the
+batch it polled and, in its one ``serve/batch/compute``, that batch's
+dispatch and the fetch and the counters of the batch it answers."""
 
 import json
 import time
@@ -83,8 +86,8 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
         return grouped[-1]
 
     monkeypatch.setattr(engine_mod, "group_seen_cells", recording)
-    # seven produced, four polled: three wait for the next batch
-    server, _ = _served(_engine(), range(7), max_batch=4)
+    # four produced, four polled, nothing behind them: straight through
+    server, client = _served(_engine(), range(4), max_batch=4)
     assert server.step() == 4
     events = tracer.events()
     spans = _by_name(events)
@@ -97,7 +100,9 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     assert args["serve/poll"]["batch"] == args["serve/batch"]["batch"] == 1
     assert args["serve/poll"]["requests"] == 4
     assert args["serve/poll"]["malformed"] == 0
-    assert args["serve/poll"]["pending_after"] == 3
+    assert args["serve/poll"]["pending_after"] == 0
+    assert args["serve/batch"] == {"requests": 4, "shed": 0, "batch": 1,
+                                   "overlapped": False}
     assert args["serve/batch/assemble"]["seen_cells"] == 4 * 3
     # the rectangle the device builds, and what the host built for it: the
     # batch's twelve cells in one [4, capacity] int32 piece
@@ -116,11 +121,64 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     assert args["serve/batch/respond"]["bytes"] > 0
     # the next batch carries the next ordinal on both of its spans
     tracer.clear()
+    for u in range(3):
+        client.request(u, 3)
     assert server.step() == 3
     spans = _by_name(tracer.events())
     assert spans["serve/poll"][0]["args"]["batch"] == 2
     assert spans["serve/batch"][0]["args"]["batch"] == 2
     assert spans["serve/poll"][0]["args"]["pending_after"] == 0
+
+
+def test_under_a_backlog_each_step_has_one_compute_of_two_batches(tracer):
+    """Eleven requests in batches of four (4, 4, 3): the first step only
+    hands its batch over, each later one hands over the batch it polled and
+    fetches and answers the one before, the last (an empty poll) only
+    answers.  One ``serve/poll``, one ``serve/batch`` and one
+    ``serve/batch/compute`` a step, the ordinals agree, and the compute
+    span's counters are the ANSWERED batch's."""
+    server, _ = _served(_engine(), range(11), max_batch=4)
+    tracer.clear()
+    steps = []
+    for want in (0, 4, 4, 3):
+        assert server.step() == want
+        steps.append(_by_name(tracer.events()))
+        telemetry.validate_span_tree(tracer.events())
+        tracer.clear()
+    assert server.step() == 0 and tracer.events() == []
+    staged = ("serve/batch/validate", "serve/batch/assemble",
+              "serve/batch/seen_tiles", "serve/batch/upload",
+              "serve/batch/compute/dispatch")
+    answered = ("serve/batch/compute/fetch", "serve/batch/respond")
+    always = ("serve/poll", "serve/batch", "serve/batch/compute")
+    for ordinal, spans in enumerate(steps, start=1):
+        want = always + (staged if ordinal < 4 else ()) \
+            + (answered if ordinal > 1 else ())
+        assert sorted(spans) == sorted(want), ordinal
+        assert all(len(v) == 1 for v in spans.values())
+        assert spans["serve/poll"][0]["args"]["batch"] == ordinal
+        batch = spans["serve/batch"][0]["args"]
+        assert batch["batch"] == ordinal
+        assert batch["overlapped"] is (ordinal > 1)
+    polled = [s["serve/batch"][0]["args"]["requests"] for s in steps]
+    assert polled == [4, 4, 3, 0]
+    assert [s["serve/poll"][0]["args"]["pending_after"] for s in steps] \
+        == [7, 3, 0, 0]
+    # the first step's compute holds a dispatch and no batch's counters
+    assert steps[0]["serve/batch/compute"][0]["args"] == {}
+    # the third polls three rows (padded to four) and answers batch two
+    assert steps[2]["serve/batch/assemble"][0]["args"]["n"] == 3
+    keys = {"n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
+            "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes"}
+    for spans, n in zip(steps[1:], (4, 4, 3)):
+        compute = spans["serve/batch/compute"][0]["args"]
+        assert set(compute) == keys
+        assert (compute["n"], compute["b"], compute["k"]) == (n, 4, 8)
+        assert spans["serve/batch/respond"][0]["args"]["responses"] == n
+        assert spans["serve/batch/compute/fetch"][0]["args"]["bytes"] \
+            == 2 * 4 * 8 * 4
+    assert server.metrics.counters["serve_batches_overlapped"] == 3
+    assert server.metrics.counters["serve_batches"] == 3
 
 
 def test_children_nest_in_their_parents(tracer):
