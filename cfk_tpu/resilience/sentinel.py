@@ -98,18 +98,23 @@ def probe_word(u: jax.Array, m: jax.Array, norm_limit: float) -> jax.Array:
     trips both its non-finite bit and its norm bit, which is fine — bits
     compose.
     """
-    limit_sq = jnp.float32(float(norm_limit)) ** 2
+    return (side_word(u, norm_limit, NONFINITE_U, NORM_U)
+            | side_word(m, norm_limit, NONFINITE_M, NORM_M))
 
-    def side(x, nonfinite_bit, norm_bit):
-        xf = x.astype(jnp.float32)
-        finite = jnp.all(jnp.isfinite(xf))
-        norm_sq = jnp.max(jnp.sum(jnp.square(xf), axis=-1))
-        w = jnp.where(finite, jnp.int32(0), jnp.int32(nonfinite_bit))
-        return w | jnp.where(
-            norm_sq > limit_sq, jnp.int32(norm_bit), jnp.int32(0)
-        )
 
-    return side(u, NONFINITE_U, NORM_U) | side(m, NONFINITE_M, NORM_M)
+def side_word(x: jax.Array, norm_limit, nonfinite_bit: int, norm_bit: int,
+              rows=None) -> jax.Array:
+    """One side of ``probe_word``: the two bits of one factor array, over
+    all of its rows or over its first ``rows`` (a traced count: a padded
+    solve's trailing rows are nobody's factors)."""
+    xf = x.astype(jnp.float32)
+    if rows is not None:
+        xf = jnp.where(jnp.arange(xf.shape[0])[:, None] < rows, xf, 0.0)
+    limit_sq = jnp.asarray(norm_limit, jnp.float32) ** 2
+    finite = jnp.all(jnp.isfinite(xf))
+    norm_sq = jnp.max(jnp.sum(jnp.square(xf), axis=-1))
+    w = jnp.where(finite, jnp.int32(0), jnp.int32(nonfinite_bit))
+    return w | jnp.where(norm_sq > limit_sq, jnp.int32(norm_bit), jnp.int32(0))
 
 
 @jax.jit

@@ -240,6 +240,10 @@ class ServeEngine:
         # flag behind /readyz — an engine is live from construction but
         # READY only once prewarm() has traced the batch-bucket set.
         self.epoch = 0
+        # The last stream commit applied (``on_commit``): a batch is staged
+        # against the user rows and seen lists as of this ordinal, captured
+        # with them under the lock, and its answers name it.
+        self.commit_ordinal = 0
         self.prewarmed = False
 
     @property
@@ -438,6 +442,23 @@ class ServeEngine:
     def table_rows(self) -> int:
         return int(self._table[0].shape[0])
 
+    def fold_table(self):
+        """The item table as a fold-in gathers from it: the [M_pad, k]
+        float32 array on the one device, the very buffer the scorer scans
+        (a ``StreamSession(engine=...)`` keeps no copy of its own).  Other
+        tables are refused: a fold-in solves float32 normal equations
+        against whole rows on one device."""
+        if (self.mesh is not None or self.table_dtype != "float32"
+                or self.serve_mode != "exact"):
+            raise ValueError(
+                "a fold-in reads a float32 item table on one device, "
+                f"scanned exactly; this engine holds table_dtype="
+                f"{self.table_dtype!r} over {self._shards} device(s), "
+                f"serve_mode={self.serve_mode!r}: give the session a table "
+                "of its own (no engine=)")
+        with self._lock:
+            return self._table[0]
+
     # -- live-update listener ------------------------------------------------
 
     def attach_session(self, session) -> None:
@@ -460,6 +481,8 @@ class ServeEngine:
                 self._seen_hot.setdefault(int(row), []).append(int(movie))
             self.num_users = max(self.num_users,
                                  int(event.get("num_users", self.num_users)))
+            self.commit_ordinal = max(
+                self.commit_ordinal, int(event.get("stream_step", 0)))
             # Item-side per-row deltas (ISSUE 16): a commit that ships
             # re-solved MOVIE rows updates both table views in place —
             # within each row's existing cluster — without re-clustering.
@@ -564,7 +587,7 @@ class ServeEngine:
         return movies, indptr
 
     def topk(self, user_rows, k: int, *, exclude_seen: bool = True,
-             force_exact: bool = False):
+             force_exact: bool = False, stamp: dict | None = None):
         """(scores [n, k] f32, movie rows [n, k] int32) for the requested
         user rows.  The batch is padded to the pow2 quantum (padding rows
         score with a zero factor vector and are sliced off), so request
@@ -576,9 +599,13 @@ class ServeEngine:
 
         The two halves of one ``TopKBatch``, back to back: ``stage`` and
         the hand-over to the device, then the fetch.  The request server
-        runs the same halves one step apart (``compute``)."""
+        runs the same halves one step apart (``compute``).  ``stamp``, a
+        dict, receives the ``epoch`` and the commit ``ordinal`` the batch
+        was staged against."""
         batch = self.stage(user_rows, k, exclude_seen=exclude_seen,
                            force_exact=force_exact)
+        if stamp is not None:
+            stamp.update(epoch=batch.epoch, ordinal=batch.ordinal)
         return compute(batch, batch)
 
     def stage(self, user_rows, k: int, *, exclude_seen: bool = True,
@@ -600,7 +627,8 @@ class ServeEngine:
         user_rows = np.asarray(user_rows, dtype=np.int64)
         n = user_rows.shape[0]
         if n == 0:
-            return TopKBatch(self, n=0, k=k, epoch=self.epoch, result=(
+            return TopKBatch(self, n=0, k=k, epoch=self.epoch,
+                             ordinal=self.commit_ordinal, result=(
                 np.zeros((0, k), np.float32), np.zeros((0, k), np.int32)))
         if np.any((user_rows < 0) | (user_rows >= self.num_users)):
             bad = user_rows[(user_rows < 0)
@@ -615,7 +643,7 @@ class ServeEngine:
             with self._lock:
                 table, scale = self._table
                 cluster = self._cluster
-                epoch = self.epoch
+                epoch, ordinal = self.epoch, self.commit_ordinal
                 u = np.zeros((b, self._u_base.shape[1]), np.float32)
                 u[:n] = self._gather_users(user_rows)
                 seen = self._batch_seen(user_rows) if exclude_seen else None
@@ -636,7 +664,8 @@ class ServeEngine:
             out = self._topk_two_stage(cluster, u, n, b, k, seen_pad,
                                        min_seen_chunks)
             if out is not None:
-                return TopKBatch(self, n=n, k=k, epoch=epoch, result=out)
+                return TopKBatch(self, n=n, k=k, epoch=epoch,
+                                 ordinal=ordinal, result=out)
             # a detected fault fell through: the exact path below IS the
             # un-disableable fallback — same table, same jitted program
             # as serve_mode="exact", so the degraded answer is bit-exact
@@ -686,7 +715,7 @@ class ServeEngine:
             counters.update(shards=self._shards,
                             merge_candidates=self._shards * k)
         return TopKBatch(
-            self, n=n, k=k, epoch=epoch, counters=counters,
+            self, n=n, k=k, epoch=epoch, ordinal=ordinal, counters=counters,
             operands=(u, table, scale, seen, shape),
             scan=self._scan_record(mode="exact", b=b, k=k,
                                    table_rows=table.shape[0]))
@@ -933,16 +962,16 @@ class TopKBatch:
     nothing of the engine (it leaves the batch's scan record there), so
     whatever the engine became in between (a commit, a delta, a table
     swap, another engine in its server's place) the answer is that of the
-    table and the ``epoch`` the batch was staged against.  A batch that
-    needs no device (no rows; the two-stage route, which syncs with the
-    host between its stages) is made with its ``result`` and both halves
-    pass it through."""
+    table, the ``epoch`` and the commit ``ordinal`` the batch was staged
+    against.  A batch that needs no device (no rows; the two-stage route,
+    which syncs with the host between its stages) is made with its
+    ``result`` and both halves pass it through."""
 
-    def __init__(self, engine, *, n, k, epoch, counters=None,
+    def __init__(self, engine, *, n, k, epoch, ordinal=0, counters=None,
                  operands=None, scan=None, result=None) -> None:
         self.engine = engine
         self.n, self.k = n, k
-        self.epoch = epoch
+        self.epoch, self.ordinal = epoch, ordinal
         self.counters = counters
         self.scan = scan
         self.result = result

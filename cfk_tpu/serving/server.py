@@ -22,6 +22,13 @@ over, then the batch handed over a step ago is fetched and answered, so
 the scorer runs under the host's poll, assembly, upload and responses
 instead of after them.  With no backlog a batch is answered in the step
 that polled it.
+
+A server with a ``StreamSession`` attached (``session=``) is the one loop
+that drives both: before each poll it pumps the session — the micro-batches
+on the device are committed and published to the engine, the next one is
+handed over (several, where the stream has fallen behind its log) — so the
+ratings a user sent are folded in between two request batches, and every
+answer names the commit ordinal its batch saw.
 """
 
 from __future__ import annotations
@@ -79,6 +86,7 @@ class RecommendServer:
         admission=None,
         staleness_fn=None,
         labels: dict | None = None,
+        session=None,
     ) -> None:
         from cfk_tpu.utils.metrics import Metrics
 
@@ -97,6 +105,9 @@ class RecommendServer:
         # staleness bound (the replica's unapplied delta backlog).
         self.admission = admission
         self._staleness_fn = staleness_fn
+        # The stream this server folds in between its polls (a
+        # ``StreamSession``, normally on this engine's table), or None.
+        self.session = session
         nparts = transport.num_partitions(requests_topic)
         own = (range(nparts) if partitions is None
                else [int(p) for p in partitions])
@@ -232,6 +243,12 @@ class RecommendServer:
 
     def _step(self, poll) -> int:
         in_flight = self._in_flight
+        if self.session is not None:
+            # the micro-batches that are due, then the next request batch;
+            # with a scorer in flight the session waits for no fold-in
+            # behind it (``StreamSession.pump``)
+            self.session.pump(
+                device_busy=in_flight is not None and in_flight.on_device)
         # The spans of one step share its ordinal.  A step that neither
         # polls nor answers anything writes no event — an idle server
         # polls every millisecond.
@@ -279,8 +296,10 @@ class RecommendServer:
             if in_flight is None and self._pending() == 0:
                 # straight through: engine.topk is both halves of the
                 # batch back to back, under the spans of one
-                answer = (self.engine.topk(polled.rows, polled.k)
+                stamp: dict = {}
+                answer = (self.engine.topk(polled.rows, polled.k, stamp=stamp)
                           if polled.valid else None)
+                polled.ordinal = stamp.get("ordinal", polled.ordinal)
                 return self._respond(polled, answer)
             try:
                 if polled is not None and polled.valid:
@@ -288,6 +307,7 @@ class RecommendServer:
                     # host's side of the polled batch's timeline
                     polled.handle = self.engine.stage(polled.rows, polled.k)
                     polled.epoch = polled.handle.epoch
+                    polled.ordinal = polled.handle.ordinal
                 # one serve/batch/compute: the polled batch's dispatch,
                 # the answered batch's fetch and counters
                 answer = compute(
@@ -343,6 +363,7 @@ class RecommendServer:
         """Produce and flush ``batch``'s responses, then commit its read
         cursors; returns the number of requests answered."""
         epoch, staleness = batch.epoch, batch.staleness
+        ordinal = batch.ordinal
         # respond: response objects, encode, produce, flush
         with span("serve/batch/respond") as sp:
             responses: list[tuple[int, ScoreResponse]] = []
@@ -353,7 +374,7 @@ class RecommendServer:
                     req_id=r.req_id,
                     movie_rows=ids[i, : r.k],
                     scores=scores[i, : r.k],
-                    epoch=epoch, staleness=staleness,
+                    epoch=epoch, staleness=staleness, ordinal=ordinal,
                 )))
             for r, text in batch.errors:
                 responses.append((r.reply_partition, ScoreResponse(
@@ -448,6 +469,7 @@ class _Polled:
     cursors: dict  # the read cursors as they stood after its poll
     t0: float
     handle: TopKBatch | None = None  # staged on the engine, under a backlog
+    ordinal: int = 0  # the stream commit its rows and seen lists are as of
 
     @property
     def on_device(self) -> bool:

@@ -47,6 +47,9 @@ class StreamBatch:
     cursors_after: dict[int, int]
     duplicates_dropped: int = 0
     gap_repolls: int = 0
+    # per update, when the log took it (``Record.appended``; 0.0 = the
+    # transport does not say): what a commit's visibility is counted from
+    appended: tuple[float, ...] = ()
 
     @property
     def num_records(self) -> int:
@@ -94,8 +97,10 @@ class StreamConsumer:
 
     def _collect_range(self, p: int, lo: int, hi: int):
         """All records of partition ``p`` with offsets exactly [lo, hi) —
-        deduped by offset, sorted, gaps re-polled (at-least-once healing)."""
+        deduped by offset, sorted, gaps re-polled (at-least-once healing):
+        (values, duplicates dropped, re-polls, when the log took each)."""
         seen: dict[int, bytes] = {}
+        stamps: dict[int, float] = {}
         dups = 0
         repolls = 0
         attempts = 0
@@ -119,6 +124,7 @@ class StreamConsumer:
                 prev = seen.get(rec.offset)
                 if prev is None:
                     seen[rec.offset] = rec.value
+                    stamps[rec.offset] = rec.appended
                     this_pass.add(rec.offset)
                 elif prev != rec.value:
                     raise StreamGapError(
@@ -135,7 +141,8 @@ class StreamConsumer:
                     dups += 1
             missing = [o for o in range(lo, hi) if o not in seen]
             if not missing:
-                return [seen[o] for o in range(lo, hi)], dups, repolls
+                return ([seen[o] for o in range(lo, hi)], dups, repolls,
+                        [stamps[o] for o in range(lo, hi)])
             attempts += 1
             if attempts > self.gap_retries:
                 raise StreamGapError(
@@ -161,6 +168,7 @@ class StreamConsumer:
         before = dict(self.cursors)
         after = dict(self.cursors)
         updates: list[RatingUpdate] = []
+        appended: list[float] = []
         dups = 0
         repolls = 0
         for p in range(self.num_partitions):
@@ -169,10 +177,11 @@ class StreamConsumer:
                      lo + batch_records)
             if hi <= lo:
                 continue
-            values, d, r = self._collect_range(p, lo, hi)
+            values, d, r, stamps = self._collect_range(p, lo, hi)
             dups += d
             repolls += r
             updates.extend(decode_rating_update(v) for v in values)
+            appended.extend(stamps)
             after[p] = hi
         if after == before:
             return None
@@ -183,4 +192,5 @@ class StreamConsumer:
             cursors_after=after,
             duplicates_dropped=dups,
             gap_repolls=repolls,
+            appended=tuple(appended),
         )

@@ -42,6 +42,8 @@ import numpy as np
 
 from cfk_tpu.ops.solve import als_half_step
 from cfk_tpu.ops.tiled import tiled_half_step
+from cfk_tpu.resilience import sentinel as _sentinel
+from cfk_tpu.telemetry import span
 
 
 def _pow2_ceil(x: int, floor: int) -> int:
@@ -67,13 +69,19 @@ def trace_count() -> int:
     jax.jit,
     static_argnames=("lam", "solver", "reg_solve_algo"),
 )
-def _padded_fold(fixed, neighbor_idx, rating, mask, count, *, lam, solver,
-                 reg_solve_algo):
+def _padded_fold(fixed, neighbor_idx, rating, mask, count, touched,
+                 norm_limit, *, lam, solver, reg_solve_algo):
+    """(solved rows [E, k], the health sentinel's word over the first
+    ``touched`` of them): one program, so the probe of a batch costs no
+    second hand-over and no second wait."""
     _TRACES[0] += 1
-    return als_half_step(
+    rows = als_half_step(
         fixed, neighbor_idx, rating, mask, count, lam,
         solver=solver, reg_solve_algo=reg_solve_algo,
     )
+    word = _sentinel.side_word(rows, norm_limit, _sentinel.NONFINITE_U,
+                               _sentinel.NORM_U, rows=touched)
+    return rows, word
 
 
 @functools.partial(
@@ -122,25 +130,92 @@ def fold_in_rows(
         raise ValueError(
             f"fold-in layout must be 'padded' or 'tiled', got {layout!r}"
         )
+    return fold_in_dispatch(
+        movie_factors, neighbor_data, lam=lam, solver=solver,
+        pad_multiple=pad_multiple, reg_solve_algo=reg_solve_algo,
+    ).fetch()[0]
+
+
+def _rectangle(neighbor_data, pad_multiple: int, index=None):
+    """The padded [E, P] operands of one fold-in, pow2 in both extents:
+    (neighbor_idx, rating, mask, count); ``index`` maps item rows into a
+    staged window."""
     width = max(int(mv.shape[0]) for mv, _ in neighbor_data)
     p = _pow2_ceil(max(width, 1), max(pad_multiple, 1))
-    e = _pow2_ceil(t, 8)
+    e = _pow2_ceil(len(neighbor_data), 8)
     neighbor_idx = np.zeros((e, p), np.int32)
     rating = np.zeros((e, p), np.float32)
     mask = np.zeros((e, p), np.float32)
     count = np.zeros((e,), np.float32)
     for i, (mv, rt) in enumerate(neighbor_data):
         n = mv.shape[0]
-        neighbor_idx[i, :n] = mv
+        neighbor_idx[i, :n] = mv if index is None else index(mv)
         rating[i, :n] = rt
         mask[i, :n] = 1.0
         count[i] = n
-    out = _padded_fold(
-        movie_factors, jnp.asarray(neighbor_idx), jnp.asarray(rating),
-        jnp.asarray(mask), jnp.asarray(count),
-        lam=float(lam), solver=solver, reg_solve_algo=reg_solve_algo,
-    )
-    return np.asarray(out[:t], np.float32)
+    return neighbor_idx, rating, mask, count
+
+
+class FoldIn:
+    """One padded fold-in between its hand-over to the device and its
+    fetch.  ``fold_in_dispatch`` makes it: the operands are uploaded and
+    ``_padded_fold`` is called, both asynchronous; ``fetch`` waits for the
+    device and copies the solved rows and the sentinel's word to the host.
+    A caller with other work on the device (a request server's scorer)
+    fetches once that work has been answered (``StreamSession.pump``).
+    ``entities`` x ``width`` is the padded rectangle, ``gather_bytes`` the
+    item rows the program gathers for it, ``operand_bytes`` what went up."""
+
+    def __init__(self, out, touched: int, entities: int, width: int,
+                 rank: int, operand_bytes: int) -> None:
+        self._out = out
+        self.touched, self.entities, self.width = touched, entities, width
+        self.rank = rank
+        self.gather_bytes = entities * width * rank * 4
+        self.operand_bytes = operand_bytes
+
+    def fetch(self) -> tuple[np.ndarray, int]:
+        """(rows [touched, k] float32, the user side's health word)."""
+        rows, word = self._out
+        with span("stream/batch/solve"), \
+                span("stream/batch/solve/fetch") as sp:
+            rows = np.asarray(rows, np.float32)
+            word = int(np.asarray(word))
+            sp.set(bytes=rows.nbytes + 4)
+        return rows[:self.touched], word
+
+
+def fold_in_dispatch(
+    movie_factors,
+    neighbor_data,
+    *,
+    lam: float,
+    solver: str = "auto",
+    pad_multiple: int = 8,
+    reg_solve_algo: str | None = None,
+    norm_limit: float = float("inf"),
+    index=None,
+) -> FoldIn:
+    """Hand one padded fold-in (``neighbor_data`` not empty) to the device
+    and return without waiting for it; ``FoldIn.fetch`` has the rows.
+    ``norm_limit`` is the health sentinel's bound on a solved row's norm
+    (the word's non-finite bit needs none)."""
+    t = len(neighbor_data)
+    with span("stream/batch/upload") as sp:
+        host = _rectangle(neighbor_data, pad_multiple, index)
+        operands = tuple(map(jnp.asarray, host))
+        e, p = host[0].shape
+        nbytes = sum(o.nbytes for o in host)
+        sp.set(entities=e, width=p, bytes=nbytes)
+    rank = int(movie_factors.shape[-1])
+    with span("stream/batch/solve", touched=t, entities=e, width=p,
+              gather_bytes=e * p * rank * 4), \
+            span("stream/batch/solve/dispatch"):
+        out = _padded_fold(
+            movie_factors, *operands, np.int32(t), np.float32(norm_limit),
+            lam=float(lam), solver=solver, reg_solve_algo=reg_solve_algo,
+        )
+    return FoldIn(out, t, e, p, rank, nbytes)
 
 
 def fold_in_rows_windowed(
@@ -197,26 +272,12 @@ def fold_in_rows_windowed(
         stats["foldin_staged_bytes"] = (
             stats.get("foldin_staged_bytes", 0) + window.nbytes)
     staged = jnp.asarray(window)
-    width = max(int(mv.shape[0]) for mv, _ in neighbor_data)
-    p = _pow2_ceil(max(width, 1), max(pad_multiple, 1))
-    e = _pow2_ceil(t, 8)
-    neighbor_idx = np.zeros((e, p), np.int32)
-    rating = np.zeros((e, p), np.float32)
-    mask = np.zeros((e, p), np.float32)
-    count = np.zeros((e,), np.float32)
-    for i, (mv, rt) in enumerate(neighbor_data):
-        n = mv.shape[0]
-        neighbor_idx[i, :n] = np.searchsorted(
-            touched, mv.astype(np.int64)).astype(np.int32)
-        rating[i, :n] = rt
-        mask[i, :n] = 1.0
-        count[i] = n
-    out = _padded_fold(
-        staged, jnp.asarray(neighbor_idx), jnp.asarray(rating),
-        jnp.asarray(mask), jnp.asarray(count),
-        lam=float(lam), solver=solver, reg_solve_algo=reg_solve_algo,
-    )
-    solved = np.asarray(out[:t], np.float32)
+    solved = fold_in_dispatch(
+        staged, neighbor_data, lam=lam, solver=solver,
+        pad_multiple=pad_multiple, reg_solve_algo=reg_solve_algo,
+        index=lambda mv: np.searchsorted(
+            touched, mv.astype(np.int64)).astype(np.int32),
+    ).fetch()[0]
     return (solved, staged) if return_staged else solved
 
 

@@ -3,15 +3,28 @@
 ``StreamSession`` closes the loop the reference only sketched: ratings
 arrive continuously on a durable updates topic, micro-batches of touched
 users are folded into the live factor state by one restricted ALS
-half-iteration, and every commit persists the factors ATOMICALLY WITH the
-consumer's offset cursor — the cursor rides the checkpoint manifest
-(``CheckpointManager.save(meta=...)``), whose atomic directory rename plus
-crc32 verification the PR 3/5 machinery already proves out.  There is no
-instant at which the factors and the cursor can disagree on disk; a crash
-replays exactly the uncommitted log suffix, and because micro-batch
-boundaries are log offsets (``StreamConsumer``), the replayed batches —
-and therefore the recovered factors — are bit-identical to an
+half-iteration, and every commit persists what the batch changed
+ATOMICALLY WITH the consumer's offset cursor: the rows it solved, the cells
+it applied and the cursor are one step of the store, written through
+``CheckpointManager``'s atomic directory rename plus crc32 verification
+(the PR 3/5 machinery), the cursor in the step's manifest.  A full snapshot
+of the tables is written at bootstrap and at a retrain only; a resume is
+the newest intact snapshot plus the unbroken run of intact units after it.
+There is no instant at which the factors and the cursor can disagree on
+disk; a crash replays exactly the uncommitted log suffix, and because
+micro-batch boundaries are log offsets (``StreamConsumer``), the replayed
+batches — and therefore the recovered factors — are bit-identical to an
 uninterrupted run.
+
+A batch is two halves (``_begin``: poll, stage, the touched users' lists,
+the hand-over of the fold-in to the device; ``_finish``: fetch, probe,
+apply, commit, publish).  ``step`` runs them back to back; ``pump``, which a
+``RecommendServer`` calls between its polls, runs them a call apart, so the
+fold-in executes between two scorer calls and the host never waits for it
+behind a scorer it did not need.  A stream that has fallen behind its log
+has several batches on the device at once, each staged over the ones before
+it (``StreamState.stage(over=)``): what is committed, and in which order, is
+what ``step`` after ``step`` would have committed, bit for bit.
 
 Delivery semantics, layer by layer:
 
@@ -39,7 +52,11 @@ side's staleness back in without ever serving a cold model.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
+import time
+import warnings
 
 import numpy as np
 
@@ -47,13 +64,18 @@ from cfk_tpu.resilience import sentinel as _sentinel
 from cfk_tpu.resilience.loop import drain_checkpoints, save_checkpoint
 from cfk_tpu.resilience.policy import Overrides, RecoveryPolicy, policy_from_config
 from cfk_tpu.streaming.consumer import StreamConsumer
-from cfk_tpu.streaming.foldin import fold_in_rows
+from cfk_tpu.streaming.foldin import fold_in_dispatch, fold_in_rows
 from cfk_tpu.streaming.producer import UPDATES_TOPIC
-from cfk_tpu.streaming.state import StreamState
+from cfk_tpu.streaming.state import StreamState, overlay_of
 from cfk_tpu.telemetry import record_event, span
 from cfk_tpu.telemetry.recorder import dump_flight
 
 _STREAM_MODEL = "als-stream"
+# The micro-batches one ``pump`` hands to the device while whole ones still
+# wait in the log behind them (a stall, a burst): each costs the requests
+# its fold-in's few milliseconds of the device, so a long backlog of ratings
+# is drained at about twice their rate without starving the requests.
+_PUMP_DEPTH = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,8 +103,9 @@ class StreamConfig:
     gap_wait_s: float = 0.05
     # Sleep between polls while following an idle topic.
     poll_wait_s: float = 0.05
-    # User-table growth quantum: new streamed-in users extend the factor
-    # table in chunks of this many rows (bounds re-jits and reallocations).
+    # The whole user table (``user_factors``, a snapshot) is rounded up to
+    # this many rows once streamed-in users outgrow the base table; the
+    # rows themselves live in an appended segment that doubles.
     grow_multiple: int = 64
 
     def __post_init__(self) -> None:
@@ -105,20 +128,104 @@ class StreamConfig:
             )
 
 
+def jnp_dtype(name):
+    import jax.numpy as jnp
+
+    return jnp.dtype(name)
+
+
 class PoisonedBatchError(RuntimeError):
     """Raised when ``on_unrecoverable='raise'`` and a batch defeats the
     whole recovery ladder."""
+
+
+class _UserRows:
+    """A session's user factor table: the base array as it was handed over
+    — never copied, never written, so a serving engine may hold the same
+    array — and the rows solved since (re-solved base rows and streamed-in
+    users alike) in an appended segment that doubles when it is full: a
+    thousand new users allocate a few small segments, not a thousand
+    copies of the base."""
+
+    def __init__(self, base: np.ndarray) -> None:
+        self.base = base
+        self._slot: dict[int, int] = {}
+        self._rows = np.zeros((0, base.shape[1]), base.dtype)
+        self.allocations = 0  # of the segment; the base is allocated never
+
+    def __len__(self) -> int:
+        return len(self._slot)
+
+    def set(self, rows, values) -> None:
+        slots = []
+        for row in rows:
+            slot = self._slot.get(row)
+            if slot is None:
+                slot = self._slot[row] = len(self._slot)
+            slots.append(slot)
+        if len(self._slot) > self._rows.shape[0]:
+            grown = np.zeros((max(2 * self._rows.shape[0], len(self._slot),
+                                  64), self._rows.shape[1]),
+                             self._rows.dtype)
+            grown[:self._rows.shape[0]] = self._rows
+            self._rows = grown
+            self.allocations += 1
+        self._rows[slots] = values
+
+    def get(self, rows) -> np.ndarray:
+        """Copies of the given rows as they stand (zeros for a row nobody
+        has solved yet)."""
+        out = np.zeros((len(rows), self.base.shape[1]), self.base.dtype)
+        for i, row in enumerate(rows):
+            slot = self._slot.get(int(row))
+            if slot is not None:
+                out[i] = self._rows[slot]
+            elif row < self.base.shape[0]:
+                out[i] = self.base[row]
+        return out
+
+    def table(self, num_rows: int) -> np.ndarray:
+        """The whole table as one new array of at least ``num_rows`` rows
+        (a snapshot, the exit's model: never the per-batch path)."""
+        out = np.zeros((max(num_rows, self.base.shape[0]),
+                        self.base.shape[1]), self.base.dtype)
+        out[:self.base.shape[0]] = self.base
+        if self._slot:
+            out[list(self._slot)] = self._rows[:len(self._slot)]
+        return out
+
+
+@dataclasses.dataclass
+class _Batch:
+    """One micro-batch between its poll and its commit."""
+
+    batch: object  # the consumer's StreamBatch
+    pending: object  # the state's PendingApply
+    solve: tuple  # ``_dispatch``'s: (FoldIn on the device, None), or
+    # (None, (rows, the fixed rows to probe)) of a route with no hand-over
 
 
 class StreamSession:
     """Consume rating updates and fold them into live ALS factors.
 
     ``manager`` (a ``CheckpointManager``-shaped store) is the session's
-    system of record: factors + offset cursor + stream metadata commit as
-    one atomic step per micro-batch.  On construction the session either
-    resumes from the store's newest intact step (rebuilding the rating
-    state by replaying the log below the committed cursor) or bootstraps
-    from ``base_model`` (committing step 0 with a zero cursor).
+    system of record.  A full snapshot (user table, item table, cursor,
+    stream metadata) is written at bootstrap and at a retrain; every
+    micro-batch in between commits ONE UNIT — the solved rows, the cells
+    applied and the cursor, through the same atomic rename and crc32 — so
+    a commit costs what the batch changed, not the tables.  On construction
+    the session either resumes from the store (newest intact snapshot plus
+    the unbroken run of intact units after it, the rating state rebuilt by
+    replaying the log below the committed cursor) or bootstraps from
+    ``base_model`` (committing step 0 with a zero cursor).
+
+    ``dataset`` is a ``Dataset`` or a ``StreamState`` (``from_csr``: a
+    deployment that holds its ratings as a CSR and could never build
+    blocks of them).  ``engine`` (a ``ServeEngine`` with a float32 table on
+    one device) makes the session fold in against the table the engine
+    serves — one item table on the device, owned by the engine, absent
+    from this store's snapshots — and subscribes the engine to the
+    commits.
     """
 
     def __init__(
@@ -133,6 +240,7 @@ class StreamSession:
         metrics=None,
         preemption_guard=None,
         policy: RecoveryPolicy | None = None,
+        engine=None,
     ) -> None:
         from cfk_tpu.config import enable_compile_cache
         from cfk_tpu.utils.metrics import Metrics
@@ -147,7 +255,10 @@ class StreamSession:
         # what makes a cold fold-in process skip the re-COMPILE half of
         # the per-batch trace bound; prewarm() covers the trace half.
         enable_compile_cache(getattr(config, "compile_cache_dir", None))
-        self.dataset = dataset
+        if isinstance(dataset, StreamState):
+            self.dataset, self.state = None, dataset
+        else:
+            self.dataset, self.state = dataset, StreamState(dataset)
         self.config = config
         self.transport = transport
         self.manager = manager
@@ -181,21 +292,43 @@ class StreamSession:
                     "stages ad-hoc windows (foldin_layout 'auto'/'padded')"
                 )
             self._layout = "padded"
+        self._engine = engine
+        if engine is not None:
+            if self._offload or self._layout != "padded":
+                raise ValueError(
+                    "a session that folds in against a serving engine's "
+                    "table runs the padded fold-in on the device: not "
+                    f"foldin_layout={self._layout!r}, not an "
+                    "offload_tier='host_window' session")
+            if self.stream.retrain_every is not None:
+                raise ValueError(
+                    "retrain_every needs a Dataset and a table of the "
+                    "session's own: retrain offline and start the engine "
+                    "and the session from the new model")
+            if jnp_dtype(config.dtype) != np.float32:
+                raise ValueError(
+                    f"the engine's table is float32, config.dtype is "
+                    f"{config.dtype!r}")
+            engine.fold_table()  # refuses, in words, a table no fold-in reads
         self._overrides = Overrides(
             lam=config.lam, fused_epilogue=config.fused_epilogue,
             reg_solve_algo=(None if config.reg_solve_algo == "auto"
                             else config.reg_solve_algo),
         )
-        self.state = StreamState(dataset)
         self.stream_step = 0
         self.quarantined: list[dict] = []
         self._m = None  # jnp [M_pad, k], fixed between retrains
-        self._u = None  # np [U_pad, k], row-mutated by fold-ins
+        self._users: _UserRows | None = None
+        self._fixed_word = (None, 0)  # (table probed, its sentinel bits)
+        # polled and handed over, not committed yet: oldest first
+        self._in_flight: collections.deque[_Batch] = collections.deque()
         # Serving-side subscribers (ISSUE 8): fired AFTER each durable
         # commit with copies of the solved rows, so a hot-user factor
         # cache (serving.ServeEngine.attach_session) re-serves fold-in
         # updates without ever reading this session's mutable arrays.
         self._commit_listeners: list = []
+        if engine is not None:
+            engine.attach_session(self)
         resumed = self._try_resume()
         if not resumed:
             self._bootstrap(base_model)
@@ -203,17 +336,19 @@ class StreamSession:
     # -- bootstrap / resume --------------------------------------------------
 
     def _factor_dtype(self):
-        import jax.numpy as jnp
-
-        return jnp.dtype(self.config.dtype)
+        return jnp_dtype(self.config.dtype)
 
     def _set_movie(self, arr) -> None:
         """Install the fixed movie table: a device array normally, a
         host ``HostFactorStore`` in offload mode — SAME bytes either way
         (the store holds the config dtype verbatim), so the staged
-        fold-in windows read exactly what the resident path would."""
+        fold-in windows read exactly what the resident path would.  A
+        session on an engine's table installs nothing: the engine owns
+        the one table there is."""
         import jax.numpy as jnp
 
+        if self._engine is not None:
+            return
         if self._offload:
             from cfk_tpu.offload.store import HostFactorStore
 
@@ -232,9 +367,11 @@ class StreamSession:
                 "base_model given — train a base model first (train_als) "
                 "or point the session at its existing stream directory"
             )
-        dt = self._factor_dtype()
-        self._u = np.asarray(base_model.user_factors).astype(dt)
-        self._set_movie(base_model.movie_factors)
+        # by reference where the dtype already fits: the base is never
+        # written (``_UserRows``)
+        self._users = _UserRows(np.asarray(base_model.user_factors).astype(
+            self._factor_dtype(), copy=False))
+        self._set_movie(getattr(base_model, "movie_factors", None))
         nparts = self.transport.num_partitions(self.stream.topic)
         self.consumer = StreamConsumer(
             self.transport, topic=self.stream.topic,
@@ -244,14 +381,59 @@ class StreamSession:
         )
         # Step 0 pins the zero cursor atomically with the base factors, so
         # even a crash before the first batch resumes cleanly.
-        self._commit(note="bootstrap")
+        self._commit_snapshot(note="bootstrap")
+
+    def _restore_step(self, iteration: int):
+        """One step of the store, or None (warned, flight-recorded) where
+        it fails its checksums: resume falls back past it."""
+        from cfk_tpu.transport.checkpoint import CheckpointCorruptError
+
+        try:
+            return self.manager.restore(iteration, mmap=True)
+        except CheckpointCorruptError as e:
+            warnings.warn(f"skipping corrupt checkpoint: {e}")
+            record_event("checkpoint", "corrupt_checkpoint_skipped",
+                         iteration=iteration, error=str(e))
+            dump_flight("corrupt_checkpoint")
+            return None
+
+    def _restorable(self):
+        """(snapshot, [units after it]) — the newest intact snapshot of the
+        store and the unbroken run of intact units that follows it, each
+        one stream step after the last; None on an empty store.  A torn
+        unit ends the run: what came after it on disk is the uncommitted
+        suffix, replayed from the log."""
+        steps = self.manager.iterations()
+        units: dict[int, object] = {}
+        snapshot = None
+        for it in reversed(steps):
+            st = self._restore_step(it)
+            if st is None:
+                continue
+            if st.meta.get("kind") == "unit":
+                units[it] = st
+            else:
+                snapshot = st
+                break
+        if snapshot is None:
+            return None
+        run = []
+        expect = int(snapshot.meta.get("stream_step", snapshot.iteration)) + 1
+        for it in steps[steps.index(snapshot.iteration) + 1:]:
+            st = units.get(it)
+            if st is None or int(st.meta["stream_step"]) != expect:
+                break
+            run.append(st)
+            expect += 1
+        return snapshot, run
 
     def _try_resume(self) -> bool:
-        latest = self.manager.latest_valid_iteration()
-        if latest is None:
+        found = self._restorable()
+        if found is None:
             return False
-        st = self.manager.restore(latest)
-        meta = st.meta
+        snapshot, units = found
+        self._pin(snapshot.iteration)
+        meta = dict((units[-1] if units else snapshot).meta)
         if meta.get("model") != _STREAM_MODEL:
             raise ValueError(
                 f"checkpoint store holds model={meta.get('model')!r}, not a "
@@ -271,9 +453,30 @@ class StreamSession:
                 "resume (the rating state replays from it)"
             )
         dt = self._factor_dtype()
-        self._u = np.asarray(st.user_factors).astype(dt)
-        self._set_movie(st.movie_factors)
-        self.stream_step = int(meta.get("stream_step", latest))
+        self._users = _UserRows(
+            np.asarray(snapshot.user_factors).astype(dt, copy=False))
+        if snapshot.meta.get("item_table") == "engine":
+            if self._engine is None:
+                # read-only: the store can be inspected, not folded into
+                self._m = None
+        else:
+            self._set_movie(snapshot.movie_factors)
+        new_users = [int(r) for r in snapshot.meta.get("new_users", [])]
+        events = []
+        for st in units:
+            touched = [int(r) for r in st.meta["touched_rows"]]
+            rows = np.asarray(st.user_factors).astype(dt)
+            self._users.set(touched, rows)
+            new_users += [int(r) for r in st.meta.get("new_users_added", [])]
+            cells = st.meta["cells"]
+            events.append({
+                "touched_rows": touched, "rows": np.array(rows, np.float32),
+                "cells": list(zip(cells["rows"], cells["movies"])),
+                "retrain": False, "stream_step": int(st.meta["stream_step"]),
+                "num_users": int(st.meta["users"]),
+            })
+        meta["new_users"] = new_users
+        self.stream_step = int(meta.get("stream_step", snapshot.iteration))
         self.quarantined = list(meta.get("quarantined", []))
         ov = meta.get("overrides")
         if ov is not None:
@@ -310,10 +513,18 @@ class StreamSession:
             gap_wait_s=self.stream.gap_wait_s,
         )
         self._replay_state(cursors, meta)
+        cells = sum(len(st.meta["cells"]["rows"]) for st in units)
+        self.metrics.incr("replayed_units", len(units))
+        self.metrics.incr("replayed_unit_cells", cells)
+        # a restarted server's engine gets the units' rows and cells as
+        # the commits it missed
+        for event in events:
+            self._fire_commit(event)
         self.metrics.note(
             "stream_resumed",
-            f"step {self.stream_step}, cursor {cursors}, "
-            f"{len(meta.get('new_users', []))} streamed-in users",
+            f"step {self.stream_step} (snapshot {snapshot.iteration} + "
+            f"{len(units)} units), cursor {cursors}, "
+            f"{len(new_users)} streamed-in users",
         )
         record_event("stream", "stream_resumed", step=self.stream_step)
         return True
@@ -351,7 +562,7 @@ class StreamSession:
             lo = 0
             while lo < hi:
                 take = min(hi - lo, 1 << 14)
-                values, _, _ = replay._collect_range(p, lo, lo + take)
+                values, _, _, _ = replay._collect_range(p, lo, lo + take)
                 ranges = skip.get(p, ())
                 values = [
                     v for i, v in enumerate(values)
@@ -383,10 +594,21 @@ class StreamSession:
 
     @property
     def user_factors(self) -> np.ndarray:
-        return self._u
+        """The whole user table as one new array (the base is shared and
+        never written; the solved rows live beside it)."""
+        quantum = self.stream.grow_multiple
+        return self._users.table(
+            -(-self.state.num_users // quantum) * quantum
+            if self.state.num_users > self._users.base.shape[0] else 0)
+
+    def user_rows(self, rows) -> np.ndarray:
+        """Copies of single user rows as committed, without the table."""
+        return self._users.get(rows)
 
     @property
     def movie_factors(self):
+        if self._engine is not None:
+            return self._engine.fold_table()
         if self._offload:
             return self._m_store.as_array()
         return self._m
@@ -402,14 +624,14 @@ class StreamSession:
 
         if self._offload:
             return ALSModel(
-                user_factors=self._u,
+                user_factors=self.user_factors,
                 movie_factors=self._m_store.as_array(),
                 num_users=self.state.num_users,
                 num_movies=self.state.num_movies,
             )
         return ALSModel(
-            user_factors=jnp.asarray(self._u),
-            movie_factors=self._m,
+            user_factors=jnp.asarray(self.user_factors),
+            movie_factors=self.movie_factors,
             num_users=self.state.num_users,
             num_movies=self.state.num_movies,
         )
@@ -417,43 +639,61 @@ class StreamSession:
     def backlog(self) -> int:
         return self.consumer.backlog()
 
-    def _grow_users(self, num_users: int) -> None:
-        """Extend the user factor table for streamed-in new users."""
-        need = num_users
-        have = self._u.shape[0]
-        if need <= have:
-            return
-        quantum = self.stream.grow_multiple
-        target = ((need + quantum - 1) // quantum) * quantum
-        grown = np.zeros((target, self._u.shape[1]), dtype=self._u.dtype)
-        grown[:have] = self._u
-        self._u = grown
+    @property
+    def in_flight(self) -> bool:
+        """A micro-batch is polled and not committed yet."""
+        return bool(self._in_flight)
 
-    def _solve_pending(self, pending, overrides: Overrides):
-        """Fold-in solve of one staged batch under the given overrides;
-        returns (rows [T, k] f32, probe word int)."""
-        import jax.numpy as jnp
+    def _fixed(self):
+        """The item table a fold-in gathers from."""
+        fixed = self.movie_factors
+        if fixed is None:
+            raise ValueError(
+                "this session was resumed without the engine that owns its "
+                "item table: it can be read, not folded into")
+        return fixed
 
-        neighbor_data = [
-            self.state.neighbors(row, pending.cell_writes.get(row))
-            for row in pending.touched_rows
-        ]
-        staged = None
-        with self.metrics.phase("foldin_solve"), \
-                span("stream/batch/solve", touched=len(neighbor_data),
-                     offload=int(self._offload)):
+    def _table_word(self, fixed) -> int:
+        """The sentinel's bits for the fixed side, probed once per table:
+        nothing writes a table between two swaps."""
+        import jax
+
+        if self._fixed_word[0] is not fixed:
+            word = jax.jit(functools.partial(
+                _sentinel.side_word, nonfinite_bit=_sentinel.NONFINITE_M,
+                norm_bit=_sentinel.NORM_M))(fixed, self.health.norm_limit)
+            self._fixed_word = (fixed, int(np.asarray(word)))
+        return self._fixed_word[1]
+
+    def _dispatch(self, pending, overrides: Overrides, over=()) -> tuple:
+        """The front half of one staged batch's solve under the given
+        overrides: the touched users' lists (``over``: the batches it was
+        staged over, not committed yet), the rectangle, the hand-over.
+        Returns (fold, solved): the padded layout on a resident table
+        comes back with the ``FoldIn`` on the device, the others have
+        solved by the time they return, (rows, the fixed rows to probe)."""
+        with span("stream/batch/neighbors") as sp:
+            staged = (*over, pending)
+            neighbor_data = [
+                self.state.neighbors(row, overlay_of(row, staged))
+                for row in pending.touched_rows
+            ]
+            sp.set(touched=len(neighbor_data))
+        with self.metrics.phase("foldin_solve"):
             if self._offload:
                 from cfk_tpu.streaming.foldin import fold_in_rows_windowed
 
-                rows, staged = fold_in_rows_windowed(
-                    self._m_store, neighbor_data,
-                    lam=overrides.lam,
-                    solver=self.config.solver,
-                    pad_multiple=self.config.pad_multiple,
-                    reg_solve_algo=overrides.reg_solve_algo,
-                    stats=self._foldin_stats,
-                    return_staged=True,
-                )
+                with span("stream/batch/solve", touched=len(neighbor_data),
+                          offload=1):
+                    rows, staged = fold_in_rows_windowed(
+                        self._m_store, neighbor_data,
+                        lam=overrides.lam,
+                        solver=self.config.solver,
+                        pad_multiple=self.config.pad_multiple,
+                        reg_solve_algo=overrides.reg_solve_algo,
+                        stats=self._foldin_stats,
+                        return_staged=True,
+                    )
                 self.metrics.gauge(
                     "foldin_windows_staged",
                     self._foldin_stats.get("foldin_windows_staged", 0))
@@ -461,17 +701,49 @@ class StreamSession:
                     "foldin_staged_mb",
                     round(self._foldin_stats.get(
                         "foldin_staged_bytes", 0) / 1e6, 3))
-            else:
-                rows = fold_in_rows(
-                    self._m, neighbor_data,
-                    lam=overrides.lam,
-                    solver=self.config.solver,
-                    layout=self._layout,
-                    pad_multiple=self.config.pad_multiple,
-                    fused_epilogue=overrides.fused_epilogue,
-                    in_kernel_gather=self.config.in_kernel_gather,
-                    reg_solve_algo=overrides.reg_solve_algo,
-                )
+                return None, (rows, staged)
+            if self._layout == "tiled":
+                with span("stream/batch/solve", touched=len(neighbor_data),
+                          offload=0):
+                    rows = fold_in_rows(
+                        self._m, neighbor_data,
+                        lam=overrides.lam,
+                        solver=self.config.solver,
+                        layout="tiled",
+                        fused_epilogue=overrides.fused_epilogue,
+                        in_kernel_gather=self.config.in_kernel_gather,
+                        reg_solve_algo=overrides.reg_solve_algo,
+                    )
+                return None, (rows, self._m)
+            return fold_in_dispatch(
+                self._fixed(), neighbor_data,
+                lam=overrides.lam,
+                solver=self.config.solver,
+                pad_multiple=self.config.pad_multiple,
+                reg_solve_algo=overrides.reg_solve_algo,
+                norm_limit=(self.health.norm_limit
+                            if self.health is not None else float("inf")),
+            ), None
+
+    def _fetch(self, fold, solved):
+        """The back half of ``_dispatch``'s pair: (rows [T, k] f32, probe
+        word int), the health sentinel's word taken BEFORE anything is
+        applied."""
+        import jax.numpy as jnp
+
+        if fold is not None:
+            with self.metrics.phase("foldin_solve"):
+                rows, word = fold.fetch()
+            if self.health is None or not rows.shape[0]:
+                return rows, 0
+            with self.metrics.phase("health_check"), \
+                    span("stream/batch/probe"):
+                # the user side's bits came with the rows, from the
+                # program that solved them
+                word |= self._table_word(self._fixed())
+            self.metrics.incr("health_checks")
+            return rows, word
+        rows, m_probe = solved
         word = 0
         if self.health is not None and rows.shape[0]:
             with self.metrics.phase("health_check"), \
@@ -480,7 +752,6 @@ class StreamSession:
                 # the solve actually read — instead of the full table the
                 # session no longer holds on device; the sentinel bitmask
                 # semantics (non-finite / norm) are unchanged.
-                m_probe = staged if self._offload else self._m
                 word = int(np.asarray(_sentinel.probe_word(
                     jnp.asarray(rows), m_probe, self.health.norm_limit
                 )))
@@ -538,8 +809,8 @@ class StreamSession:
                     "skipped": note}
         mt = max(int(max_touched or self.stream.batch_records), 1)
         if max_width is None:
-            counts = np.asarray(self.dataset.user_blocks.count)
-            max_width = max(int(counts.max()) if counts.size else 1, 1)
+            # the longest list there is, with one new rating on it
+            max_width = self.state.longest_list() + 1
         pm = max(self.config.pad_multiple, 1)
         widths = []
         p = _pow2_ceil(1, pm)
@@ -557,7 +828,10 @@ class StreamSession:
             e *= 2
         before = trace_count()
         programs = 0
-        num_m = int(self._m.shape[0])
+        fixed = self._fixed()
+        num_m = int(fixed.shape[0])
+        if self.health is not None:
+            self._table_word(fixed)  # the fixed side's probe, once a table
         for e in ents:
             for p in widths:
                 # One user at the full width pins the rectangle to
@@ -567,16 +841,13 @@ class StreamSession:
                         .astype(np.int32),
                         np.zeros(p, np.float32))
                 thin = (np.zeros(1, np.int32), np.zeros(1, np.float32))
-                fold_in_rows(
-                    self._m, [wide] + [thin] * (e - 1),
+                fold_in_dispatch(
+                    fixed, [wide] + [thin] * (e - 1),
                     lam=self._overrides.lam,
                     solver=self.config.solver,
-                    layout="padded",
                     pad_multiple=self.config.pad_multiple,
-                    fused_epilogue=self._overrides.fused_epilogue,
-                    in_kernel_gather=self.config.in_kernel_gather,
                     reg_solve_algo=self._overrides.reg_solve_algo,
-                )
+                ).fetch()
                 programs += 1
         out = {
             "programs": programs,
@@ -588,22 +859,20 @@ class StreamSession:
         self.metrics.gauge("prewarm_s", out["prewarm_s"])
         return out
 
-    def _commit(self, note: str | None = None) -> None:
+    def _meta(self, cursors: dict, note: str | None) -> dict:
         meta = {
             "model": _STREAM_MODEL,
             "rank": int(self.config.rank),
             "num_shards": 1,
             "stream_step": self.stream_step,
-            "offsets": {str(p): int(o)
-                        for p, o in self.consumer.cursors.items()},
+            "offsets": {str(p): int(o) for p, o in cursors.items()},
             "batch_records": self.stream.batch_records,
             "seq_high": int(self.state.applied_seq_high),
             "base_users": self.state.num_base_users,
             "users": self.state.num_users,
-            "new_users": [int(r) for r in self.state._new_user_raw],
             # poison ranges whose offsets are consumed but whose writes
             # must never be re-applied — crash replay skips them
-            "quarantined": self.quarantined,
+            "quarantined": list(self.quarantined),
             # the sticky escalation state: post-resume batches must solve
             # under the same overrides an uninterrupted run would have
             # used, or replay is no longer bit-identical (a stream that
@@ -616,15 +885,79 @@ class StreamSession:
         }
         if note:
             meta["note"] = note
+        return meta
+
+    def _save(self, users, movies, meta: dict, note: str | None, *,
+              wait: bool = False) -> int:
+        """One step of the store.  ``wait``: written before this returns,
+        straight from the arrays given (no host copy for a background
+        writer to own: a snapshot's tables are the size of the host)."""
         with self.metrics.phase("commit"), \
-                span("stream/batch/commit", step=self.stream_step):
-            save_checkpoint(
-                self.manager, self.stream_step, self._u,
-                np.asarray(self.movie_factors), meta=meta,
-            )
+                span("stream/batch/commit", step=self.stream_step,
+                     kind=meta["kind"]) as sp:
+            if wait:
+                # a unit of the same step still queued would land on top
+                drain_checkpoints(self.manager)
+                self.manager.save(self.stream_step, users, movies, meta=meta)
+            else:
+                save_checkpoint(self.manager, self.stream_step, users,
+                                movies, meta=meta)
+            nbytes = int(users.nbytes + movies.nbytes)
+            sp.set(bytes=nbytes)
         self.metrics.incr("stream_commits")
         record_event("stream", "commit", step=self.stream_step,
                      note=note or "")
+        return nbytes
+
+    def _commit_snapshot(self, note: str | None = None) -> None:
+        """Both tables and the cursor as one step: at bootstrap and after
+        a retrain, the two moments at which every row is new.  The item
+        table of a session on an engine's table is the engine's to keep."""
+        meta = self._meta(self.consumer.cursors, note)
+        meta["kind"] = "snapshot"
+        meta["new_users"] = [int(r) for r in self.state._new_user_raw]
+        if self._engine is not None:
+            meta["item_table"] = "engine"
+            movies = np.zeros((0, self.config.rank), np.float32)
+        else:
+            movies = np.asarray(self.movie_factors)
+        base = self._users.base
+        users = (base if not len(self._users)
+                 and self.state.num_users <= base.shape[0]
+                 else self.user_factors)
+        self._save(users, movies, meta, note, wait=True)
+        self._pin(self.stream_step)
+
+    def _pin(self, step: int) -> None:
+        """Keep the snapshot every later unit rests on out of the store's
+        ``keep_last_n`` collection (units it collects only shorten the run
+        a resume can take from disk: the log replays the rest)."""
+        pin = getattr(self.manager, "pin", None)
+        if pin is not None:
+            pin(step)
+
+    def _commit_unit(self, batch, pending, rows) -> int:
+        """One micro-batch as one atomic unit of the store: the rows it
+        solved, the cells it applied, the users it added and the cursor
+        after it (rename + crc32, as every step of the store).  ``pending``
+        None: a quarantined batch, whose unit moves the cursor alone."""
+        meta = self._meta(batch.cursors_after, None)
+        meta["kind"] = "unit"
+        writes = {} if pending is None else pending.cell_writes
+        cells = [(row, mv, rt, seq) for row, overlay in writes.items()
+                 for mv, (rt, seq) in overlay.items()]
+        meta["touched_rows"] = (
+            [] if pending is None else [int(r) for r in pending.touched_rows])
+        meta["new_users_added"] = (
+            [] if pending is None else [int(r) for r in pending.new_user_raw])
+        meta["cells"] = {
+            "rows": [int(c[0]) for c in cells],
+            "movies": [int(c[1]) for c in cells],
+            "ratings": [float(c[2]) for c in cells],
+            "seqs": [int(c[3]) for c in cells],
+        }
+        return self._save(rows, np.zeros((0, rows.shape[1]), rows.dtype),
+                          meta, None)
 
     def add_commit_listener(self, fn) -> None:
         """Subscribe ``fn(event: dict)`` to every durable commit.
@@ -659,32 +992,107 @@ class StreamSession:
                 )
 
     def step(self) -> dict | None:
-        """Process ONE micro-batch; returns its summary, or None when
-        caught up with the log."""
+        """Process ONE micro-batch, from its poll to its commit; returns
+        its summary, or None when caught up with the log.  (Batches that
+        ``pump`` left on the device are committed first.)"""
+        while self._in_flight:
+            with span("stream/batch") as sp:
+                self._finish(sp)
+        with span("stream/batch") as sp:
+            if not self._begin():
+                sp.drop()
+                return None
+            return self._finish(sp)
+
+    def pump(self, *, device_busy: bool = False) -> int:
+        """Advance the stream from inside another loop — the request
+        server's, between two of its polls; returns the micro-batches
+        committed.
+
+        A call commits the batches handed to the device by the call before
+        and hands over the next.  With a scorer in flight (``device_busy``)
+        what is handed over sits behind that scorer and is left there: by
+        the next call the server has answered the scorer, the fold-in has
+        run between two scorer calls, and its fetch waits for at most its
+        own few milliseconds, so the host never waits for a fold-in behind
+        a scorer it did not need.  One batch a call keeps up with a stream
+        at the rate it was sized for; while a whole micro-batch still waits
+        in the log behind the one just handed over (a stall, a burst) the
+        call hands over another, ``_PUMP_DEPTH`` at most, each staged over
+        the ones before it.  With nothing else on the device the call
+        commits what it handed over before it returns."""
+        done = begun = 0
+        ready = len(self._in_flight)  # an earlier call's: run by now
+        more = True
+        while ready or more:
+            # one span: the back half of a batch, the front half of another
+            with span("stream/batch") as sp:
+                finished = ready > 0
+                if finished:
+                    self._finish(sp)
+                    ready -= 1
+                    done += 1
+                more = (begun < _PUMP_DEPTH
+                        and (begun == 0 or self.consumer.backlog()
+                             >= self.stream.batch_records)
+                        and self._begin())
+                begun += more
+                if not (finished or more):
+                    sp.drop()
+        while self._in_flight and not device_busy:
+            with span("stream/batch") as sp:
+                self._finish(sp)
+            done += 1
+        return done
+
+    def _begin(self) -> bool:
+        """Poll one micro-batch, stage it against the applied state and
+        the batches in flight, and hand its fold-in to the device; False
+        when caught up."""
         batch = self.consumer.poll(self.stream.batch_records)
         if batch is None:
-            return None
-        with span("stream/batch", step=self.stream_step + 1,
-                  records=batch.num_records):
-            return self._step_batch(batch)
-
-    def _step_batch(self, batch) -> dict:
-        with self.metrics.phase("stage"), \
-                span("stream/batch/stage", records=batch.num_records):
-            pending = self.state.stage(batch.updates)
-        self.metrics.incr("updates_fresh", pending.stats.fresh)
-        self.metrics.incr("updates_stale", pending.stats.stale)
-        self.metrics.incr("updates_unknown_movie", pending.stats.unknown_movie)
+            return False
+        step = self.stream_step + len(self._in_flight) + 1
         if batch.duplicates_dropped:
             self.metrics.incr("delivery_duplicates", batch.duplicates_dropped)
-            record_event("stream", "delivery_duplicates_dropped",
-                         step=self.stream_step + 1,
+            record_event("stream", "delivery_duplicates_dropped", step=step,
                          duplicates=batch.duplicates_dropped)
         if batch.gap_repolls:
             self.metrics.incr("delivery_gap_repolls", batch.gap_repolls)
-            record_event("stream", "delivery_gap_repolls",
-                         step=self.stream_step + 1,
+            record_event("stream", "delivery_gap_repolls", step=step,
                          repolls=batch.gap_repolls)
+        self._in_flight.append(self._hand_over(batch))
+        return True
+
+    def _hand_over(self, batch) -> _Batch:
+        over = [b.pending for b in self._in_flight]
+        with self.metrics.phase("stage"), \
+                span("stream/batch/stage", records=batch.num_records) as sp:
+            pending = self.state.stage(batch.updates, over)
+            sp.set(fresh=pending.stats.fresh,
+                   new_users=pending.stats.new_users)
+        solve = (self._dispatch(pending, self._overrides, over)
+                 if pending.touched_rows else (None, None))
+        return _Batch(batch, pending, solve)
+
+    def _hand_over_again(self) -> None:
+        """What is in flight was staged over a batch that did not commit
+        as staged (a trip: retried under sticky overrides, or quarantined)
+        or solved against a table since retrained: stage and solve it
+        anew, as ``step`` after ``step`` would have."""
+        again, self._in_flight = self._in_flight, collections.deque()
+        for b in again:
+            self._in_flight.append(self._hand_over(b.batch))
+
+    def _finish(self, sp) -> dict:
+        """Fetch the batch in flight, probe it, and — healthy — apply,
+        commit and publish it; ``sp`` is the open ``stream/batch`` span,
+        which takes the committed batch's counts."""
+        b = self._in_flight.popleft()
+        batch, pending, (fold, solved) = b.batch, b.pending, b.solve
+        self.metrics.incr("updates_fresh", pending.stats.fresh)
+        self.metrics.incr("updates_stale", pending.stats.stale)
+        self.metrics.incr("updates_unknown_movie", pending.stats.unknown_movie)
         summary = {
             "records": batch.num_records,
             "fresh": pending.stats.fresh,
@@ -694,11 +1102,12 @@ class StreamSession:
             "quarantined": False,
             "trips": 0,
         }
+        rows = np.zeros((0, self.config.rank), np.float32)
         if pending.touched_rows:
             overrides = self._overrides
             trips = 0
             while True:
-                rows, word = self._solve_pending(pending, overrides)
+                rows, word = self._fetch(fold, solved)
                 if not word:
                     break
                 trips += 1
@@ -741,11 +1150,10 @@ class StreamSession:
                     })
                     self.metrics.incr("quarantined_batches")
                     self.metrics.note("quarantined", msg)
-                    import warnings
-
                     warnings.warn(msg)
                     summary["quarantined"] = True
                     pending = None
+                    rows = rows[:0]
                     break
                 # Rollback is free — nothing was committed — so a retry is
                 # one escalation rung up (λ bump → split epilogue → GJ),
@@ -766,35 +1174,55 @@ class StreamSession:
                     )
                     record_event("fault", "stream_escalation", rung=trips,
                                  lam=overrides.lam)
+                fold, solved = self._dispatch(pending, overrides)
             if pending is not None:
-                self.state.commit(pending)
-                self._grow_users(self.state.num_users)
-                if pending.touched_rows:
-                    self._u[np.asarray(pending.touched_rows)] = (
-                        rows.astype(self._u.dtype)
-                    )
+                with span("stream/batch/apply", touched=len(rows)):
+                    self.state.commit(pending)
+                    rows = rows.astype(self._factor_dtype())
+                    self._users.set(pending.touched_rows, rows)
+            if trips and self._in_flight:
+                self._hand_over_again()
         self.stream_step += 1
-        self._commit()
+        commit_bytes = self._commit_unit(batch, pending, rows)
+        sp.set(step=self.stream_step, ordinal=self.stream_step,
+               records=batch.num_records, fresh=summary["fresh"],
+               touched=len(rows), new_users=summary["new_users"],
+               commit_bytes=commit_bytes)
+        if fold is not None:
+            sp.set(entities=fold.entities, width=fold.width, rank=fold.rank,
+                   gather_bytes=fold.gather_bytes,
+                   operand_bytes=fold.operand_bytes)
         if pending is not None and pending.touched_rows:
-            # publish the COMMITTED representation — read back from the
-            # factor table AFTER the dtype cast, so a bf16-dtype session's
-            # listeners cache exactly what a post-crash engine would
-            # restore from the checkpoint (not the pre-cast f32 solve)
-            touched_idx = np.asarray(pending.touched_rows)
-            self._fire_commit({
-                "touched_rows": [int(r) for r in pending.touched_rows],
-                "rows": np.array(self._u[touched_idx], np.float32),
-                "cells": [
-                    (int(row), int(mv))
-                    for row, overlay in pending.cell_writes.items()
-                    for mv in overlay
-                ],
-                "retrain": False,
-            })
+            # publish the COMMITTED representation — after the dtype cast,
+            # so a bf16-dtype session's listeners cache exactly what a
+            # post-crash engine would restore from the store (not the
+            # pre-cast f32 solve)
+            with span("stream/batch/publish",
+                      ordinal=self.stream_step) as pub:
+                self._fire_commit({
+                    "touched_rows": [int(r) for r in pending.touched_rows],
+                    "rows": np.array(rows, np.float32),
+                    "cells": [
+                        (int(row), int(mv))
+                        for row, overlay in pending.cell_writes.items()
+                        for mv in overlay
+                    ],
+                    "cursors": dict(batch.cursors_after),
+                    "retrain": False,
+                })
+                stamps = [t for t in batch.appended if t]
+                if stamps:
+                    # from the log's taking of each rating to the
+                    # listeners' return: what a reader of it waits
+                    now = time.perf_counter()
+                    waits = sorted((now - t) * 1e3 for t in stamps)
+                    pub.set(visible_ms_p50=waits[len(waits) // 2],
+                            visible_ms_max=waits[-1])
         summary["stream_step"] = self.stream_step
         if (self.stream.retrain_every is not None
                 and self.stream_step % self.stream.retrain_every == 0):
             self.retrain()
+            self._hand_over_again()
         return summary
 
     def run(self, *, max_batches: int | None = None, follow: bool = False,
@@ -866,6 +1294,13 @@ class StreamSession:
         from cfk_tpu.data.blocks import Dataset
         from cfk_tpu.models.als import train_als
 
+        if self.dataset is None or self._engine is not None:
+            raise NotImplementedError(
+                "a warm full retrain rebuilds the Dataset the session was "
+                "given and installs a table of its own: a session on a CSR "
+                "state or on an engine's table retrains offline and starts "
+                "again from the new model"
+            )
         if self._offload:
             raise NotImplementedError(
                 "warm full retrain in an offload_tier='host_window' "
@@ -893,9 +1328,10 @@ class StreamSession:
         perm = ds2.user_map.to_dense(raw_users)  # ds2 row per session row
         # Seed ds2's row order from the live factors.
         k = self.config.rank
+        live = self.user_factors
         u_seed = np.zeros((ds2.user_blocks.padded_entities, k),
-                          dtype=self._u.dtype)
-        u_seed[perm] = self._u[: self.state.num_users]
+                          dtype=live.dtype)
+        u_seed[perm] = live[: self.state.num_users]
         m_seed = np.asarray(self._m)[: ds2.movie_blocks.padded_entities]
         if m_seed.shape[0] < ds2.movie_blocks.padded_entities:
             m_seed = np.concatenate([
@@ -914,14 +1350,15 @@ class StreamSession:
             )
         # Back into session row order; new users keep their appended rows.
         u2 = np.asarray(model.user_factors)
-        u_sess = np.zeros_like(self._u)
+        u_sess = np.zeros_like(live)
         u_sess[: self.state.num_users] = u2[perm]
-        self._u = u_sess
+        self._users = _UserRows(u_sess)  # every row is new: a new base
         self._set_movie(model.movie_factors)
         self.metrics.incr("stream_retrains")
-        self._commit(note=f"warm retrain at step {self.stream_step}")
+        self._commit_snapshot(
+            note=f"warm retrain at step {self.stream_step}")
         self._fire_commit({
             "retrain": True,
-            "user_factors": np.array(self._u, np.float32),
+            "user_factors": np.array(u_sess, np.float32),
             "movie_factors": np.array(np.asarray(self._m), np.float32),
         })

@@ -59,27 +59,76 @@ class PendingApply:
     stats: ApplyStats
 
 
+def overlay_of(row: int, pendings) -> dict | None:
+    """What the given staged batches, in their order, write to ``row``
+    (movie_row -> (rating, seq)), to be read only; None where none of them
+    writes it."""
+    merged = None
+    for pending in pendings:
+        cells = pending.cell_writes.get(row)
+        if cells:
+            merged = cells if merged is None else {**merged, **cells}
+    return merged
+
+
 class StreamState:
-    """Merged base + streamed rating state, queryable per user row."""
+    """Merged base + streamed rating state, queryable per user row.
+
+    Built from a ``Dataset`` (the CLI, the tests: raw ids through both id
+    maps) or, ``from_csr``, from the per-user CSR a serving deployment
+    already holds, with identity id maps: a corpus the block builder cannot
+    hold never has to become a ``Dataset`` to take a stream."""
 
     def __init__(self, dataset) -> None:
         coo = dataset.coo_dense  # dense-index COO
-        self._movie_raw = dataset.movie_map.raw_ids
-        self.num_movies = dataset.movie_map.num_entities
-        self._base_user_raw = dataset.user_map.raw_ids
+        num_users = dataset.user_map.num_entities
         # Per-user CSR over the base ratings (built once, never mutated):
         # streamed deltas overlay it per touched user.
         order = np.argsort(coo.user_raw, kind="stable")
-        self._base_movies = coo.movie_raw[order].astype(np.int32)
-        self._base_ratings = coo.rating[order].astype(np.float32)
-        counts = np.bincount(
-            coo.user_raw.astype(np.int64),
-            minlength=dataset.user_map.num_entities,
+        counts = np.bincount(coo.user_raw.astype(np.int64),
+                             minlength=num_users)
+        indptr = np.zeros(num_users + 1, np.int64)
+        np.cumsum(counts, out=indptr[1:])
+        self._init(
+            indptr, coo.movie_raw[order].astype(np.int32),
+            coo.rating[order].astype(np.float32),
+            num_movies=dataset.movie_map.num_entities,
+            user_raw=dataset.user_map.raw_ids,
+            movie_raw=dataset.movie_map.raw_ids,
         )
-        self._base_indptr = np.zeros(
-            dataset.user_map.num_entities + 1, np.int64
-        )
-        np.cumsum(counts, out=self._base_indptr[1:])
+
+    @classmethod
+    def from_csr(cls, indptr, movies, ratings, *, num_movies: int
+                 ) -> "StreamState":
+        """The state over a per-user CSR of base ratings (``indptr``
+        [U + 1], item rows and ratings per cell, taken by reference), raw
+        ids = rows on both sides.  A list may hold an item twice; the
+        later cell wins, as with a ``Dataset``."""
+        indptr = np.asarray(indptr, np.int64)
+        movies = np.asarray(movies, np.int32)
+        ratings = np.asarray(ratings, np.float32)
+        if (indptr.ndim != 1 or indptr.size < 1 or indptr[0] != 0
+                or indptr[-1] != movies.shape[0]
+                or movies.shape != ratings.shape):
+            raise ValueError(
+                f"not a CSR: indptr {indptr.shape} ends at "
+                f"{indptr[-1] if indptr.size else None}, {movies.shape} item "
+                f"rows, {ratings.shape} ratings")
+        self = cls.__new__(cls)
+        self._init(indptr, movies, ratings, num_movies=int(num_movies),
+                   user_raw=None, movie_raw=None)
+        return self
+
+    def _init(self, indptr, movies, ratings, *, num_movies, user_raw,
+              movie_raw) -> None:
+        self._base_indptr = indptr
+        self._base_movies = movies
+        self._base_ratings = ratings
+        self.num_movies = int(num_movies)
+        # None = identity: a raw id is its row
+        self._base_user_raw = user_raw
+        self._movie_raw = movie_raw
+        self._num_base_users = int(indptr.shape[0] - 1)
         # Streamed overlay: row -> {movie_row: (rating, seq)}; rows past the
         # base user count are streamed-in new users.
         self._delta: dict[int, dict[int, tuple[float, int]]] = {}
@@ -91,7 +140,7 @@ class StreamState:
 
     @property
     def num_base_users(self) -> int:
-        return int(self._base_user_raw.shape[0])
+        return self._num_base_users
 
     @property
     def num_users(self) -> int:
@@ -102,6 +151,8 @@ class StreamState:
         got = self._new_user_rows.get(int(raw))
         if got is not None:
             return got
+        if self._base_user_raw is None:
+            return int(raw) if 0 <= raw < self._num_base_users else None
         i = int(np.searchsorted(self._base_user_raw, raw))
         if i < self.num_base_users and int(self._base_user_raw[i]) == int(raw):
             return i
@@ -109,12 +160,19 @@ class StreamState:
 
     def user_raw_ids(self) -> np.ndarray:
         """Raw ids in row order (base ascending, then streamed new users)."""
+        base = (np.arange(self._num_base_users, dtype=np.int64)
+                if self._base_user_raw is None else self._base_user_raw)
         return np.concatenate([
-            self._base_user_raw,
-            np.asarray(self._new_user_raw, np.int64),
-        ]) if self._new_user_raw else self._base_user_raw
+            base, np.asarray(self._new_user_raw, np.int64),
+        ]) if self._new_user_raw else base
+
+    def _movie_raw_ids(self) -> np.ndarray:
+        return (np.arange(self.num_movies, dtype=np.int64)
+                if self._movie_raw is None else self._movie_raw)
 
     def movie_row(self, raw: int) -> int | None:
+        if self._movie_raw is None:
+            return int(raw) if 0 <= raw < self.num_movies else None
         i = int(np.searchsorted(self._movie_raw, raw))
         if i < self.num_movies and int(self._movie_raw[i]) == int(raw):
             return i
@@ -127,11 +185,11 @@ class StreamState:
         """row's full (movie_row -> (rating, seq)) map, base + delta
         (+ an optional staged overlay for that row)."""
         cells: dict[int, tuple[float, int]] = {}
-        if row < self.num_base_users:
+        if row < self._num_base_users:
             lo, hi = self._base_indptr[row], self._base_indptr[row + 1]
-            for mv, rt in zip(self._base_movies[lo:hi],
-                              self._base_ratings[lo:hi]):
-                cells[int(mv)] = (float(rt), _BASE_SEQ)
+            for mv, rt in zip(self._base_movies[lo:hi].tolist(),
+                              self._base_ratings[lo:hi].tolist()):
+                cells[mv] = (rt, _BASE_SEQ)
         cells.update(self._delta.get(row, {}))
         if overlay:
             cells.update(overlay)
@@ -146,14 +204,16 @@ class StreamState:
         arrival order.
         """
         cells = self._cells(row, overlay)
-        if not cells:
-            return (np.zeros(0, np.int32), np.zeros(0, np.float32))
-        movies = np.fromiter(cells.keys(), np.int32, len(cells))
-        ratings = np.fromiter(
-            (cells[int(m)][0] for m in movies), np.float32, len(cells)
-        )
-        order = np.argsort(movies, kind="stable")
-        return movies[order], ratings[order]
+        movies = sorted(cells)
+        return (np.asarray(movies, np.int32),
+                np.asarray([cells[m][0] for m in movies], np.float32))
+
+    def longest_list(self) -> int:
+        """Cells of the longest base list: the widest rectangle a fold-in
+        of base users starts from."""
+        if self._num_base_users == 0:
+            return 0
+        return int(np.diff(self._base_indptr).max())
 
     def to_coo(self):
         """The merged rating state as a raw-id COO (for warm full retrains:
@@ -184,13 +244,14 @@ class StreamState:
                              np.fromiter(self._delta, np.int64,
                                          len(self._delta)))
         sel = sel[untouched]
-        users = [self._base_user_raw[base_rows[sel]]]
-        movies = [self._movie_raw[self._base_movies[sel]].astype(np.int64)]
+        movie_raw = self._movie_raw_ids()
+        users = [raw_users[base_rows[sel]]]
+        movies = [movie_raw[self._base_movies[sel]].astype(np.int64)]
         ratings = [self._base_ratings[sel]]
         for row in sorted(self._delta):
             mv, rt = self.neighbors(row)
             users.append(np.full(mv.shape[0], raw_users[row], np.int64))
-            movies.append(self._movie_raw[mv].astype(np.int64))
+            movies.append(movie_raw[mv].astype(np.int64))
             ratings.append(rt)
         return RatingsCOO(
             movie_raw=np.concatenate(movies),
@@ -200,7 +261,8 @@ class StreamState:
 
     # -- transactional application -------------------------------------------
 
-    def stage(self, updates: tuple[RatingUpdate, ...] | list[RatingUpdate]
+    def stage(self, updates: tuple[RatingUpdate, ...] | list[RatingUpdate],
+              over: tuple[PendingApply, ...] | list[PendingApply] = ()
               ) -> PendingApply:
         """Dedup a batch against the applied state WITHOUT mutating it.
 
@@ -210,6 +272,11 @@ class StreamState:
         state, only upserts whose seq outranks the cell's current seq are
         fresh.  A user whose batch records are ALL stale is not touched
         (no re-solve — the idempotent no-op for retried appends).
+
+        ``over``: batches staged before this one and not committed yet, in
+        their order.  The applied state is read as it will stand once they
+        are committed (their cells, their new users' rows), so the result
+        is what staging after those commits would have given.
         """
         stats = ApplyStats()
         writes: dict[int, dict[int, tuple[float, int]]] = {}
@@ -217,6 +284,10 @@ class StreamState:
         new_raw: list[int] = []
         new_rows: dict[int, int] = {}
         next_row = self.num_users
+        for earlier in over:
+            for raw in earlier.new_user_raw:
+                new_rows[raw] = next_row
+                next_row += 1
         for upd in updates:
             mv = self.movie_row(upd.movie)
             if mv is None:
@@ -236,7 +307,8 @@ class StreamState:
                 cells = cells_cache.get(row)
                 if cells is None:
                     cells = cells_cache[row] = (
-                        self._cells(row) if row < self.num_users else {}
+                        self._cells(row, overlay_of(row, over))
+                        if row < self.num_users or over else {}
                     )
                 current = cells.get(mv)
             if current is not None and upd.seq <= current[1]:

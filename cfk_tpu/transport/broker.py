@@ -16,6 +16,7 @@ no hashing, so a record's partition is reproducible from its key alone.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Iterator, Protocol
 
 
@@ -24,6 +25,10 @@ class Record:
     key: int
     value: bytes
     offset: int
+    # when the log took the record, on ``time.perf_counter``, where the
+    # transport says (the in-memory log does); 0.0 = not known.  Not part
+    # of a record's identity: the same record from two logs is equal.
+    appended: float = dataclasses.field(default=0.0, compare=False)
 
 
 class Transport(Protocol):
@@ -93,7 +98,8 @@ class InMemoryBroker:
         if not 0 <= partition < len(parts):
             raise IndexError(f"partition {partition} out of range for {topic!r}")
         log = parts[partition]
-        log.append(Record(key=key, value=value, offset=len(log)))
+        log.append(Record(key=key, value=value, offset=len(log),
+                          appended=time.perf_counter()))
 
     def consume(
         self, topic: str, partition: int, start_offset: int = 0

@@ -646,7 +646,11 @@ class CheckpointManager:
                          "crc32")
         }
 
-    def restore(self, iteration: int | None = None) -> CheckpointState:
+    def restore(self, iteration: int | None = None, *,
+                mmap: bool = False) -> CheckpointState:
+        """``mmap``: payloads past 64 MiB come back memory-mapped and
+        read-only (a table the size of the host's memory is then paged in
+        as it is read, not loaded beside the live one)."""
         if iteration is None:
             iteration = self.latest_valid_iteration()
             if iteration is None:
@@ -658,8 +662,12 @@ class CheckpointManager:
         step = self._step_dir(iteration)
         with open(os.path.join(step, _MANIFEST)) as f:
             manifest = json.load(f)
-        u = np.load(os.path.join(step, "user.npy"))
-        m = np.load(os.path.join(step, "movie.npy"))
+        def load(name):
+            path = os.path.join(step, name)
+            big = mmap and os.path.getsize(path) > (1 << 26)
+            return np.load(path, mmap_mode="r" if big else None)
+
+        u, m = load("user.npy"), load("movie.npy")
         want_dtype = manifest.get("dtype", "float32")
         if str(u.dtype) != want_dtype:
             import ml_dtypes  # ships with jax

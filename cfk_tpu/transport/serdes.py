@@ -138,7 +138,9 @@ def decode_score_request(data: bytes) -> ScoreRequest:
 # ``epoch``/``staleness`` (ISSUE 18) stamp every answer with the factor
 # table's epoch and the serving replica's delta-log backlog at score
 # time — the per-response staleness bound of the fleet contract.
-_SCORE_RESPONSE_HDR = struct.Struct(">qiHBii")
+# ``ordinal`` (PR 34) names the stream commit the answer saw: the last
+# fold-in commit applied to the engine when the batch was staged.
+_SCORE_RESPONSE_HDR = struct.Struct(">qiHBiiq")
 _FLAG_RETRIABLE = 0x01
 
 
@@ -150,7 +152,9 @@ class ScoreResponse:
     empty; ``retriable`` distinguishes an admission-control shed (re-send
     later) from a permanent refusal (unknown user, bad k).  ``epoch`` is
     the factor-table epoch that scored the answer and ``staleness`` the
-    replica's unapplied delta backlog at score time (frames)."""
+    replica's unapplied delta backlog at score time (frames); ``ordinal``
+    is the stream commit ordinal the answer saw (0: none yet): the user's
+    vector and seen list are those of that commit."""
 
     req_id: int
     movie_rows: np.ndarray  # int32 [k]
@@ -159,6 +163,7 @@ class ScoreResponse:
     retriable: bool = False
     epoch: int = 0
     staleness: int = 0
+    ordinal: int = 0
 
 
 def encode_score_response(msg: ScoreResponse) -> bytes:
@@ -171,7 +176,8 @@ def encode_score_response(msg: ScoreResponse) -> bytes:
     err = msg.error.encode()
     flags = _FLAG_RETRIABLE if msg.retriable else 0
     return (_SCORE_RESPONSE_HDR.pack(msg.req_id, ids.shape[0], len(err),
-                                     flags, msg.epoch, msg.staleness)
+                                     flags, msg.epoch, msg.staleness,
+                                     msg.ordinal)
             + err + ids.tobytes() + sc.tobytes())
 
 
@@ -179,9 +185,8 @@ def decode_score_response(data: bytes) -> ScoreResponse:
     hdr = _SCORE_RESPONSE_HDR.size
     if len(data) < hdr:
         raise ValueError(f"ScoreResponse frame truncated at {len(data)} bytes")
-    req_id, n, elen, flags, epoch, staleness = _SCORE_RESPONSE_HDR.unpack_from(
-        data, 0
-    )
+    (req_id, n, elen, flags, epoch, staleness,
+     ordinal) = _SCORE_RESPONSE_HDR.unpack_from(data, 0)
     off = hdr
     if n < 0 or off + elen + 8 * n != len(data):
         raise ValueError(
@@ -195,7 +200,7 @@ def decode_score_response(data: bytes) -> ScoreResponse:
     sc = np.frombuffer(data, dtype=">f4", count=n, offset=off).astype(np.float32)
     return ScoreResponse(req_id=req_id, movie_rows=ids, scores=sc, error=err,
                          retriable=bool(flags & _FLAG_RETRIABLE),
-                         epoch=epoch, staleness=staleness)
+                         epoch=epoch, staleness=staleness, ordinal=ordinal)
 
 
 @dataclasses.dataclass(frozen=True)

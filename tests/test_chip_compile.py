@@ -368,6 +368,27 @@ def test_serve_scorer_amazon14_cell_shape(chip):
     assert "s32[18262]" in text
 
 
+@pytest.mark.parametrize("touched,width", [(256, 128), (8, 8)])
+def test_foldin_amazon14_stream_cell_shape(chip, touched, width):
+    """The fold-in of ``amazon14-stream-r128.serve-foldin`` at the two ends
+    of its pow2 grid, against the 9,350,144-row float32 table the engine
+    serves: gather, Gram, XLA's batched Cholesky (the cell's solver: the
+    fused LU-128 kernel takes ~3 min a shape to compile and the grid has
+    30) and the sentinel's word in one program under the name the
+    benchmark's reader looks for."""
+    from cfk_tpu.streaming import foldin
+
+    rect = lambda dt: chip((touched, width), dt)
+    compiled = foldin._padded_fold.lower(
+        chip((9_350_144, 128), f32), rect(jnp.int32), rect(f32), rect(f32),
+        chip((touched,), f32), chip((), jnp.int32), chip((), f32),
+        lam=LAM, solver="cholesky", reg_solve_algo=None).compile()
+    text = compiled.as_text()
+    assert "jit__padded_fold" in text and "tpu_custom_call" not in text
+    # the table is an argument, never a copy: nothing the size of it is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
 def test_serve_scorer_amazon23_int8_cell_shape(chip):
     """The int8 cell's own call: 94,122 tiles of 48,190,464 codes on one
     chip, the scales as a lane-dense [NT, 1, T] view.  As a [M_pad, 1]

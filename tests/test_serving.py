@@ -1062,3 +1062,249 @@ def test_factor_delta_round_trip():
     assert back.kind == "epoch" and back.user_rows.size == 0
     with pytest.raises(ValueError, match="kind"):
         encode_factor_delta(make_factor_delta(1, 1, "nope"))
+
+
+# --- one loop for requests and the stream (PR 34) ---------------------------
+
+
+def _stream_stack(tmp_path, *, max_batch=8, batch_records=8, table_dtype=None,
+                  seed=5):
+    """A seeded catalogue (300 users, 200 items, rank 8) served by an engine
+    whose request server also drives a stream session built from the seen
+    lists' CSR and folding in against the engine's own table."""
+    import types
+
+    from cfk_tpu.config import ALSConfig
+    from cfk_tpu.serving import (
+        RecommendServer,
+        ServeClient,
+        ServeEngine,
+        ensure_serve_topics,
+    )
+    from cfk_tpu.streaming import (
+        StreamConfig,
+        StreamProducer,
+        StreamSession,
+        StreamState,
+    )
+    from cfk_tpu.transport import InMemoryBroker
+    from cfk_tpu.transport.checkpoint import CheckpointManager
+
+    rng = np.random.default_rng(seed)
+    users_n, items_n, rank = 300, 200, 8
+    lens = rng.integers(1, 9, users_n)
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    items = np.concatenate([np.sort(rng.choice(items_n, n, replace=False))
+                            for n in lens]).astype(np.int32)
+    values = rng.integers(1, 6, items.size).astype(np.float32)
+    u_tab = ((rng.random((users_n, rank)) - 0.5) * 0.35).astype(np.float32)
+    m_tab = ((rng.random((items_n, rank)) - 0.5) * 0.35).astype(np.float32)
+    engine = ServeEngine(
+        u_tab, m_tab, num_users=users_n, num_movies=items_n,
+        seen_movies=items, seen_indptr=indptr, tile_m=64,
+        table_dtype=table_dtype)
+    broker = InMemoryBroker()
+    ensure_serve_topics(broker)
+    s = types.SimpleNamespace(
+        indptr=indptr, items=items, values=values, u_tab=u_tab, m_tab=m_tab,
+        engine=engine, broker=broker, lam=0.05, k=5,
+        producer=StreamProducer(broker), client=ServeClient(broker))
+    s.session = StreamSession(
+        StreamState.from_csr(indptr, items, values, num_movies=items_n),
+        ALSConfig(rank=rank, lam=s.lam, health_check_every=1), broker,
+        CheckpointManager(str(tmp_path)),
+        stream=StreamConfig(batch_records=batch_records),
+        base_model=types.SimpleNamespace(user_factors=u_tab), engine=engine)
+    s.events = []
+    s.session.add_commit_listener(s.events.append)
+    s.server = RecommendServer(engine, broker, max_batch=max_batch,
+                               session=s.session)
+    return s
+
+
+def test_one_item_table_on_the_device_with_a_session_attached(tmp_path):
+    import jax
+
+    s = _stream_stack(tmp_path)
+    table = s.engine._table[0]
+    # the session keeps none of its own: it folds in against the engine's
+    assert s.session._m is None and s.session.movie_factors is table
+    assert s.engine.fold_table() is table
+    assert sum(a.shape == table.shape for a in jax.live_arrays()) == 1
+    s.producer.send(3, 7, 5.0)
+    assert s.server.step() == 0 and s.session.stream_step == 1
+    assert s.engine.commit_ordinal == 1 and s.engine._table[0] is table
+    assert sum(a.shape == table.shape for a in jax.live_arrays()) == 1
+    # the base user table is the caller's, shared by engine and session
+    assert s.session._users.base is s.u_tab
+    assert np.shares_memory(s.engine._u_base, s.u_tab)
+    # a table a fold-in cannot read is refused in words
+    with pytest.raises(ValueError, match="float32 item table on one device"):
+        _stream_stack(tmp_path / "q", table_dtype="int8")
+
+
+def _as_of(s, user, ordinal, sent):
+    """(the user's vector, its list) as of a commit ordinal, from what the
+    commit listener saw and the plain reference."""
+    from benchmarks.harness import reference_foldin
+
+    vec = s.u_tab[user]
+    for e in s.events:
+        if e["stream_step"] <= ordinal and user in e["touched_rows"]:
+            vec = e["rows"][e["touched_rows"].index(user)]
+    committed = {(row, mv): e["stream_step"] for e in s.events
+                 for row, mv in e["cells"]}
+    lo, hi = s.indptr[user], s.indptr[user + 1]
+    mine = [(mv, rt, committed.get((u, mv), np.inf))
+            for u, mv, rt in sent if u == user]
+    return vec, reference_foldin.list_as_of(
+        s.items[lo:hi], s.values[lo:hi], mine, ordinal)
+
+
+def _against_the_reference(s, asked, got, sent):
+    """Every answer against the plain reference as of the ordinal it names:
+    (worst rank gap, worst score error, worst folded-in row error, answers
+    scored with a folded-in row)."""
+    from benchmarks.harness import reference_foldin
+
+    worst_gap = worst_score = worst_row = 0.0
+    touched_answers = 0
+    for rid, resp in got.items():
+        assert not resp.error
+        user = asked[rid]
+        vec, (mine, ratings) = _as_of(s, user, resp.ordinal, sent)
+        assert reference_foldin.invalid_id_sets(
+            [resp.movie_rows], [mine], 200, s.k) == 0
+        best, scores = reference_foldin.exact_topk(vec[None], s.m_tab,
+                                                   [mine], s.k)
+        gap, err = reference_foldin.topk_gaps(
+            resp.movie_rows[None], resp.scores[None], best, scores)
+        worst_gap, worst_score = max(worst_gap, gap), max(worst_score, err)
+        if vec is not s.u_tab[user] and not np.array_equal(vec,
+                                                           s.u_tab[user]):
+            touched_answers += 1
+            exact = reference_foldin.solve_row(s.m_tab, mine, ratings, s.lam)
+            worst_row = max(worst_row, reference_foldin.row_err(vec, exact))
+    return worst_gap, worst_score, worst_row, touched_answers
+
+
+def test_answers_served_during_a_stream_against_the_plain_reference(tmp_path):
+    """Every answer of a server that folds a stream in between its batches
+    is the exact top-K of the user's vector as of the commit ordinal it
+    names, with the list as of that ordinal excluded; every folded-in row is
+    the reference's solve."""
+    from benchmarks.harness import reference_foldin
+
+    s = _stream_stack(tmp_path)
+    rng = np.random.default_rng(9)
+    sent, asked, got = [], {}, {}
+    for round_ in range(12):
+        for _ in range(6):
+            u, mv = int(rng.integers(0, 300)), int(rng.integers(0, 200))
+            rt = float(rng.integers(1, 6))
+            s.producer.send(u, mv, rt)
+            sent.append((u, mv, rt))
+        # more than max_batch requests a round: a backlog, a batch in
+        # flight; a third of them from users who have just rated
+        for i in range(12):
+            u = sent[-1 - i][0] if i < 4 else int(rng.integers(0, 300))
+            asked[s.client.request(u, s.k)] = u
+        s.server.step()
+        got.update((r.req_id, r) for r in s.client.poll_responses())
+    while len(got) < len(asked) or s.session.backlog() or s.session.in_flight:
+        s.server.step()
+        got.update((r.req_id, r) for r in s.client.poll_responses())
+    assert s.session.stream_step == len(s.events) >= 9
+    assert s.server.metrics.counters.get("serve_batches_overlapped", 0) > 0
+    ordinals = [got[rid].ordinal for rid in sorted(got)]
+    assert ordinals == sorted(ordinals) and len(set(ordinals)) > 3
+    assert ordinals[-1] <= s.session.stream_step
+    worst_gap, worst_score, worst_row, touched_answers = \
+        _against_the_reference(s, asked, got, sent)
+    assert touched_answers >= 20
+    assert worst_gap <= 1e-5 and worst_score <= 2e-5 and worst_row < 1e-5
+
+
+def test_a_burst_of_ratings_is_folded_in_several_batches_a_step(tmp_path):
+    """Five micro-batches' worth of ratings arrive while a request batch is
+    on the device: the server's steps hand over three fold-ins at once, each
+    staged over the ones before (the same users rate again and again), wait
+    behind no scorer, and every answer in between is exact as of the
+    ordinal it names."""
+    s = _stream_stack(tmp_path)
+    rng = np.random.default_rng(2)
+    sent, asked, got = [], {}, {}
+
+    def ask(n):
+        for _ in range(n):
+            u = int(rng.integers(0, 12))
+            asked[s.client.request(u, s.k)] = u
+
+    ask(16)
+    assert s.server.step() == 0 and s.server._in_flight.on_device
+    for _ in range(40):
+        u, mv = int(rng.integers(0, 12)), int(rng.integers(0, 200))
+        rt = float(rng.integers(1, 6))
+        s.producer.send(u, mv, rt)
+        sent.append((u, mv, rt))
+    depth, commits = [], []
+    while len(got) < len(asked) or s.session.backlog() or s.session.in_flight:
+        if len(asked) < 64:
+            ask(8)
+        s.server.step()
+        depth.append(len(s.session._in_flight))
+        commits.append(s.session.stream_step)
+        got.update((r.req_id, r) for r in s.client.poll_responses())
+    # three handed over in the first step, committed in the second with the
+    # last two handed over, those committed in the third
+    assert depth[:3] == [3, 2, 0] and commits[:3] == [0, 3, 5]
+    assert s.server.metrics.counters.get("serve_batches_overlapped", 0) > 2
+    ordinals = [got[rid].ordinal for rid in sorted(got)]
+    assert ordinals == sorted(ordinals) and {0, 3, 5} <= set(ordinals)
+    gap, err, row, touched = _against_the_reference(s, asked, got, sent)
+    assert touched >= 20 and gap <= 1e-5 and err <= 2e-5 and row < 1e-5
+
+
+def test_ordinal_and_read_your_writes_with_a_batch_in_flight(tmp_path):
+    """A batch names the ordinal it was STAGED against, captured with its
+    rows and seen lists under the engine's lock: one in flight across a
+    commit is answered as of the ordinal before; a request polled after the
+    commit was published sees the rating."""
+    s = _stream_stack(tmp_path)
+    user = 11
+    lo, hi = s.indptr[user], s.indptr[user + 1]
+    unseen = [m for m in range(200) if m not in set(s.items[lo:hi])]
+    base_s, base_i = s.engine.topk(np.asarray([user]), s.k)
+    rated = int(base_i[0, 0])  # the user's best item: rating it hides it
+    assert rated in unseen
+    # a backlog: 8 requests polled and handed over, 8 waiting
+    first = [s.client.request(user if i == 0 else 20 + i, s.k)
+             for i in range(16)]
+    assert s.server.step() == 0 and s.server._in_flight.on_device
+    # the user's rating arrives while that batch is on the device
+    s.producer.send(user, rated, 1.0)
+    # the next step hands the fold-in over and answers the batch staged
+    # before the rating was sent: as of ordinal 0, the base answer
+    assert s.server.step() == 8
+    early = {r.req_id: r for r in s.client.poll_responses()}
+    assert early[first[0]].ordinal == 0
+    np.testing.assert_array_equal(early[first[0]].movie_rows, base_i[0])
+    # the commit lands a step later at the latest (the fold-in is fetched
+    # once it is ready, never waited for behind the scorer in flight)
+    extra = [s.client.request(30 + i, s.k) for i in range(16)]
+    steps = 0
+    while s.engine.commit_ordinal < 1:
+        s.server.step()
+        steps += 1
+    assert steps <= 2 and s.session.stream_step == 1
+    # a request polled after the commit's listener returned sees the rating
+    late_req = s.client.request(user, s.k)
+    while late_req not in early or len(early) < 33:
+        s.server.step()
+        early.update((r.req_id, r) for r in s.client.poll_responses())
+    assert early[late_req].ordinal == 1
+    assert rated not in early[late_req].movie_rows.tolist()
+    assert not np.array_equal(early[late_req].scores, base_s[0])
+    seen = [early[rid].ordinal for rid in first + extra + [late_req]]
+    assert seen == sorted(seen) and set(seen) == {0, 1}
+    assert not s.session.in_flight and s.session.backlog() == 0

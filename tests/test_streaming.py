@@ -17,6 +17,7 @@ The contracts under test (ISSUE 6):
 """
 
 import os
+import dataclasses
 import warnings
 import zlib
 
@@ -670,3 +671,352 @@ def test_periodic_warm_retrain_and_resume(ds, cfg, base, tmp_path):
     )
     assert s2.state.num_users == sess.state.num_users
     assert _crc(s2.model()) == _crc(model)
+
+
+# --- commit units, a CSR state, a base table never copied (PR 34) ------------
+
+
+def _csr_of(ds):
+    state = StreamState(ds)
+    return state._base_indptr, state._base_movies, state._base_ratings
+
+
+def test_session_from_csr_equals_session_from_dataset(ds, cfg, base,
+                                                       tmp_path):
+    """The state a serving deployment builds from the CSR it holds (identity
+    id maps, no Dataset) folds the same ratings into the same bits."""
+    rng = np.random.default_rng(7)
+    users = rng.choice(ds.user_map.raw_ids, 60)
+    movies = rng.choice(ds.movie_map.raw_ids, 60)
+    ratings = rng.integers(1, 6, 60).astype(np.float32)
+    # one user the base has never seen: the same raw id is past both row
+    # spaces, so both sessions grow the same row for it
+    users[17] = users[41] = 4242
+    by_raw, by_row = InMemoryBroker(), InMemoryBroker()
+    StreamProducer(by_raw, num_partitions=2).send_many(users, movies, ratings)
+    known = users != 4242
+    rows = np.where(known, ds.user_map.to_dense(np.where(known, users,
+                                                         users[0])), 4242)
+    StreamProducer(by_row, num_partitions=2).send_many(
+        rows, ds.movie_map.to_dense(movies), ratings)
+    a, _ = _run(ds, cfg, by_raw, CheckpointManager(str(tmp_path / "a")),
+                base=base)
+    indptr, items, values = _csr_of(ds)
+    state = StreamState.from_csr(indptr, items, values,
+                                 num_movies=ds.movie_map.num_entities)
+    b, _ = _run(state, cfg, by_row, CheckpointManager(str(tmp_path / "b")),
+                base=base)
+    assert b.dataset is None and b.stream_step == a.stream_step >= 4
+    assert b.state.num_users == a.state.num_users == 61
+    np.testing.assert_array_equal(a.user_factors, b.user_factors)
+    for row in sorted(a.state._delta):
+        for x, y in zip(a.state.neighbors(row), b.state.neighbors(row)):
+            np.testing.assert_array_equal(x, y)
+    with pytest.raises(ValueError, match="not a CSR"):
+        StreamState.from_csr(indptr[:-1], items, values, num_movies=30)
+
+
+def test_kill_after_commit_units_resumes_bit_identical(ds, cfg, base,
+                                                       tmp_path):
+    """A commit is the rows the batch solved, the cells it applied and the
+    cursor, one atomic unit: the store holds one snapshot and a unit a
+    batch; a process killed after n of them resumes to the rows and the
+    cursor of the uninterrupted run, bit for bit."""
+    broker = InMemoryBroker()
+    _produce_stream(broker, ds, n=60, parts=1, new_users=(4242,))
+    clean, _ = _run(ds, cfg, broker, CheckpointManager(str(tmp_path / "a")),
+                    base=base)
+    s2 = StreamSession(
+        ds, cfg, broker, CheckpointManager(str(tmp_path / "b")),
+        stream=StreamConfig(batch_records=8), base_model=base,
+    )
+    s2.run(max_batches=4)
+    at_kill, cursor_at_kill = s2.user_factors, dict(s2.consumer.cursors)
+    del s2
+    mgr = CheckpointManager(str(tmp_path / "b"))
+    assert mgr.iterations() == [0, 1, 2, 3, 4]
+    snap = mgr.restore(0)
+    assert snap.meta["kind"] == "snapshot"
+    assert snap.user_factors.shape[0] >= 60 and snap.movie_factors.shape[0]
+    applied = 0
+    for it in range(1, 5):
+        unit = mgr.restore(it)
+        assert unit.meta["kind"] == "unit" and unit.meta["stream_step"] == it
+        assert unit.meta["offsets"] == {"0": 8 * it}
+        touched, cells = unit.meta["touched_rows"], unit.meta["cells"]
+        assert unit.user_factors.shape == (len(touched), cfg.rank)
+        assert 1 <= len(touched) <= 8 and unit.movie_factors.shape[0] == 0
+        assert set(cells["rows"]) == set(touched)
+        assert (len(cells["rows"]) == len(cells["movies"])
+                == len(cells["ratings"]) == len(cells["seqs"]) <= 8)
+        applied += len(cells["rows"])
+    s3 = StreamSession(ds, cfg, broker, mgr,
+                       stream=StreamConfig(batch_records=8))
+    assert s3.stream_step == 4 and s3.consumer.cursors == cursor_at_kill
+    assert s3.metrics.counters["replayed_units"] == 4
+    assert s3.metrics.counters["replayed_unit_cells"] == applied
+    np.testing.assert_array_equal(s3.user_factors, at_kill)
+    s3.run()
+    assert s3.stream_step == clean.stream_step
+    assert s3.consumer.cursors == clean.consumer.cursors
+    np.testing.assert_array_equal(s3.user_factors, clean.user_factors)
+
+
+def test_a_thousand_new_users_never_copy_the_base_table(ds, cfg, base,
+                                                        tmp_path):
+    """Streamed-in users land in an appended segment that doubles: the base
+    user table is the caller's array, by reference, never written and never
+    copied, whatever the stream adds."""
+    import types
+
+    table = np.array(np.asarray(base.user_factors), np.float32)
+    before = table.copy()
+    model = types.SimpleNamespace(user_factors=table,
+                                  movie_factors=base.movie_factors)
+    broker = InMemoryBroker()
+    prod = StreamProducer(broker)
+    rng = np.random.default_rng(3)
+    prod.send_many(10_000 + np.arange(1000),
+                   rng.choice(ds.movie_map.raw_ids, 1000),
+                   rng.integers(1, 6, 1000).astype(np.float32))
+    sess = StreamSession(
+        ds, cfg, broker, CheckpointManager(str(tmp_path)),
+        stream=StreamConfig(batch_records=16), base_model=model,
+    )
+    assert sess._users.base is table
+    sess.run()
+    assert sess.state.num_users == sess.state.num_base_users + 1000
+    assert sess._users.base is table and np.array_equal(table, before)
+    # 1,000 rows in a segment that doubles from 64: five allocations, not
+    # the sixteen of a table regrown every 64 users
+    assert len(sess._users) == 1000 and sess._users.allocations <= 5
+    whole = sess.user_factors
+    assert whole.shape[0] >= sess.state.num_users
+    np.testing.assert_array_equal(whole[:sess.state.num_base_users],
+                                  before[:sess.state.num_base_users])
+    assert np.abs(whole[sess.state.num_base_users:sess.state.num_users]
+                  ).sum(axis=1).min() > 0
+
+
+def test_fold_in_rows_against_the_plain_reference(tmp_path):
+    """Every row a stream commits is the float64 solve of the user's own
+    ALS-WR normal equations over the float32 item table and the user's list
+    as of that commit (``benchmarks/harness/reference_foldin.py``: numpy,
+    nothing of the program)."""
+    import types
+
+    from benchmarks.harness import reference_foldin
+
+    rng = np.random.default_rng(11)
+    users_n, items_n, rank, lam = 80, 50, 8, 0.05
+    lens = rng.integers(1, 9, users_n)
+    indptr = np.concatenate([[0], np.cumsum(lens)])
+    items = np.concatenate([np.sort(rng.choice(items_n, n, replace=False))
+                            for n in lens]).astype(np.int32)
+    values = rng.integers(1, 6, items.size).astype(np.float32)
+    u_tab = ((rng.random((users_n, rank)) - 0.5) * 0.35).astype(np.float32)
+    m_tab = ((rng.random((items_n, rank)) - 0.5) * 0.35).astype(np.float32)
+    broker = InMemoryBroker()
+    r_users = rng.integers(0, users_n, 64)
+    r_items = rng.integers(0, items_n, 64)
+    r_values = rng.integers(1, 6, 64).astype(np.float32)
+    StreamProducer(broker).send_many(r_users, r_items, r_values)
+    sess = StreamSession(
+        StreamState.from_csr(indptr, items, values, num_movies=items_n),
+        ALSConfig(rank=rank, lam=lam, health_check_every=1), broker,
+        CheckpointManager(str(tmp_path)),
+        stream=StreamConfig(batch_records=8),
+        base_model=types.SimpleNamespace(user_factors=u_tab,
+                                         movie_factors=m_tab),
+    )
+    events = []
+    sess.add_commit_listener(events.append)
+    sess.run()
+    assert len(events) == 8
+    committed = np.repeat([e["stream_step"] for e in events], 8)
+    worst = 0.0
+    for e in events:
+        assert e["cursors"] == {0: 8 * e["stream_step"]}
+        for row, solved in zip(e["touched_rows"], e["rows"]):
+            mine = [(r_items[j], r_values[j], committed[j])
+                    for j in np.nonzero(r_users == row)[0]]
+            lo, hi = indptr[row], indptr[row + 1]
+            exact = reference_foldin.solve_row(
+                m_tab, *reference_foldin.list_as_of(
+                    items[lo:hi], values[lo:hi], mine, e["stream_step"]),
+                lam)
+            worst = max(worst, reference_foldin.row_err(solved, exact))
+    # float32 normal equations against float64's: rounding, nothing else
+    assert 0 < worst < 1e-5
+    # the lower-precision control is three orders of magnitude off
+    lo, hi = indptr[0], indptr[1]
+    narrow = reference_foldin.solve_row(
+        m_tab, items[lo:hi], values[lo:hi], lam, dtype=np.float16)
+    exact = reference_foldin.solve_row(m_tab, items[lo:hi], values[lo:hi],
+                                       lam)
+    assert reference_foldin.row_err(narrow, exact) > 1e-4
+
+
+def test_retention_keeps_the_snapshot_the_units_rest_on(ds, cfg, base,
+                                                        tmp_path):
+    """``keep_last_n`` collects old units, never the snapshot they rest on:
+    a resume then takes the shorter run from disk and the rest from the
+    log, to the same bits."""
+    broker = InMemoryBroker()
+    _produce_stream(broker, ds, n=48, parts=1)
+    clean, _ = _run(ds, cfg, broker, CheckpointManager(str(tmp_path / "a")),
+                    base=base)
+    mgr = CheckpointManager(str(tmp_path / "b"), keep_last_n=2)
+    s2, _ = _run(ds, cfg, broker, mgr, base=base)
+    assert mgr.iterations() == [0, 5, 6]  # the snapshot and the newest two
+    s3 = StreamSession(ds, cfg, broker,
+                       CheckpointManager(str(tmp_path / "b"), keep_last_n=2),
+                       stream=StreamConfig(batch_records=8))
+    # unit 1 is gone, so nothing after the snapshot can be taken from disk
+    assert s3.stream_step == 0 and s3.backlog() == 48
+    s3.run()
+    np.testing.assert_array_equal(s3.user_factors, clean.user_factors)
+    assert s3.consumer.cursors == clean.consumer.cursors
+
+
+# --- several batches in flight (a stream behind its log) ---------------------
+
+
+def _pendings_equal(a, b):
+    return (a.touched_rows == b.touched_rows
+            and a.new_user_raw == b.new_user_raw
+            and a.cell_writes == b.cell_writes and a.stats == b.stats)
+
+
+def test_staging_over_batches_in_flight_equals_staging_after_their_commits(
+        ds):
+    """``stage(over=)`` reads the state as it will stand once the batches
+    before it are committed: their cells (a re-rate, a retried append),
+    their new users' rows; the lists the solve reads are the same too."""
+    from cfk_tpu.streaming.state import overlay_of
+    from cfk_tpu.transport.serdes import RatingUpdate
+
+    rng = np.random.default_rng(3)
+    users = [int(u) for u in ds.user_map.raw_ids[:6]] + [9001, 9002, 9003]
+    movies = [int(m) for m in ds.movie_map.raw_ids[:5]]
+    ups = [RatingUpdate(user=int(rng.choice(users)),
+                        movie=int(rng.choice(movies)),
+                        rating=float(rng.integers(1, 6)), seq=seq)
+           for seq in range(40)]
+    ups[17] = dataclasses.replace(ups[3], rating=ups[3].rating)  # a retry
+    batches = [ups[i:i + 8] for i in range(0, 40, 8)]
+    one, many = StreamState(ds), StreamState(ds)
+    in_flight = []
+    for batch in batches:
+        after = one.stage(batch)
+        over = many.stage(batch, in_flight)
+        assert _pendings_equal(after, over)
+        for row in after.touched_rows:
+            a = one.neighbors(row, after.cell_writes.get(row))
+            b = many.neighbors(row, overlay_of(row, (*in_flight, over)))
+            np.testing.assert_array_equal(a[0], b[0])
+            np.testing.assert_array_equal(a[1], b[1])
+        one.commit(after)
+        in_flight.append(over)
+    assert sum(p.stats.new_users for p in in_flight) == 3
+    assert sum(p.stats.stale for p in in_flight) >= 1
+    assert any(set(p.touched_rows) & set(q.touched_rows)
+               for p, q in zip(in_flight, in_flight[1:]))
+
+
+@pytest.mark.parametrize("fault", ["none", "poison", "singular"])
+def test_a_backlog_pumped_several_batches_deep_commits_what_step_after_step_does(
+        ds, cfg, base, tmp_path, fault):
+    """``pump`` under a busy device hands over several micro-batches while
+    whole ones wait in the log, each staged over the ones before it; what
+    it commits — rows, cells, cursors, unit by unit — is what ``step`` after
+    ``step`` commits, bit for bit, also where the batch at the head trips
+    (retried under sticky overrides, or quarantined) with others behind."""
+    from cfk_tpu.streaming import session as session_mod
+
+    if fault == "singular":
+        from cfk_tpu.models.als import train_als
+        from cfk_tpu.resilience.faults import blockstructured_coo
+
+        ds = Dataset.from_coo(blockstructured_coo(seed=0))
+        cfg = ALSConfig(rank=4, num_iterations=4, lam=0.0,
+                        health_check_every=1)
+        base = train_als(ds, cfg)
+    broker = InMemoryBroker()
+    prod = _produce_stream(broker, ds, n=20, parts=1, new_users=(4242,))
+    if fault == "poison":
+        prod.send(int(ds.user_map.raw_ids[0]), int(ds.movie_map.raw_ids[1]),
+                  float("nan"))
+    if fault == "singular":
+        prod.send(777, int(ds.movie_map.raw_ids[0]), 5.0)
+    rng = np.random.default_rng(5)
+    prod.send_many(rng.choice(ds.user_map.raw_ids[:8], 43),
+                   rng.choice(ds.movie_map.raw_ids, 43),
+                   rng.integers(1, 6, 43).astype(np.float32))
+    prod.send(4242, int(ds.movie_map.raw_ids[3]), 2.0)
+
+    def session(where):
+        s = StreamSession(
+            ds, cfg, broker, CheckpointManager(str(tmp_path / where)),
+            stream=StreamConfig(batch_records=4), base_model=base)
+        events = []
+        s.add_commit_listener(events.append)
+        return s, events
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        one, one_events = session("step")
+        one.run()
+        many, many_events = session("pump")
+        deepest = pumps = 0
+        while many.backlog() or many.in_flight:
+            many.pump(device_busy=True)
+            deepest = max(deepest, len(many._in_flight))
+            pumps += 1
+    assert deepest == session_mod._PUMP_DEPTH == 3
+    assert pumps < one.stream_step  # several commits a call
+    assert many.stream_step == one.stream_step >= 16
+    assert many.consumer.cursors == one.consumer.cursors
+    np.testing.assert_array_equal(many.user_factors, one.user_factors)
+    assert many.quarantined == one.quarantined
+    assert len(one.quarantined) == (fault == "poison")
+    assert many._overrides == one._overrides
+    # a trip's escalation is sticky: the batches behind it solved under it
+    assert (one._overrides.lam > cfg.lam) == (fault != "none")
+    assert one.metrics.counters.get("health_trips", 0) == \
+        many.metrics.counters.get("health_trips", 0)
+    assert many.metrics.counters["updates_fresh"] == \
+        one.metrics.counters["updates_fresh"]
+    assert len(many_events) == len(one_events)
+    for a, b in zip(one_events, many_events):
+        assert a["stream_step"] == b["stream_step"]
+        assert a["touched_rows"] == b["touched_rows"]
+        assert a["cells"] == b["cells"] and a["cursors"] == b["cursors"]
+        np.testing.assert_array_equal(a["rows"], b["rows"])
+    # the stores hold the same units, and either resumes to the same bits
+    from cfk_tpu.resilience.loop import drain_checkpoints
+
+    drain_checkpoints(one.manager)
+    drain_checkpoints(many.manager)
+    for it in many.manager.iterations()[1:]:
+        a, b = one.manager.restore(it), many.manager.restore(it)
+        assert a.meta == b.meta
+        np.testing.assert_array_equal(a.user_factors, b.user_factors)
+    again = StreamSession(
+        ds, cfg, broker, CheckpointManager(str(tmp_path / "pump")),
+        stream=StreamConfig(batch_records=4))
+    assert again.stream_step == one.stream_step
+    np.testing.assert_array_equal(again.user_factors, one.user_factors)
+
+
+def test_a_pump_with_nothing_else_on_the_device_commits_what_it_handed_over(
+        ds, cfg, base, tmp_path):
+    broker = InMemoryBroker()
+    _produce_stream(broker, ds, n=20, parts=1)
+    s = StreamSession(ds, cfg, broker, CheckpointManager(str(tmp_path)),
+                      stream=StreamConfig(batch_records=4), base_model=base)
+    assert s.pump() == 3 and not s.in_flight and s.backlog() == 8
+    # one batch a call where less than a whole one waits behind it
+    assert s.pump(device_busy=True) == 0 and len(s._in_flight) == 2
+    assert s.pump(device_busy=True) == 2 and not s.in_flight
+    assert s.backlog() == 0 and s.stream_step == 5
