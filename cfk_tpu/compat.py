@@ -116,6 +116,8 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     m_pad = table.shape[0]
     nt = m_pad // tile_m
     tbl = table.reshape(nt, tile_m, -1)
+    # what the fold reads as u, made once a call as the kernel's is
+    u = topk_kernel.resident_operand(u, table.dtype)
     # one dense [tile_m] row a tile, a column only inside the step: a
     # trailing axis of 1 is padded to 128 lanes in the chip's HBM
     sc = (None if scale is None

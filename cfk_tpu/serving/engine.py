@@ -70,6 +70,7 @@ from cfk_tpu.serving.topk_kernel import (
     chunk_seen_cells,
     group_seen_cells,
     scatter_seen_cells,
+    score_passes,
     seen_cell_capacity,
     topk_scores_counted,
 )
@@ -710,7 +711,9 @@ class ServeEngine:
             # what the scorer streams from HBM for the batch, all shards':
             # the table as it is held, and its scales
             table_dtype=self.table_dtype,
-            scan_bytes=table.nbytes + (0 if scale is None else scale.nbytes))
+            scan_bytes=table.nbytes + (0 if scale is None else scale.nbytes),
+            # the MXU passes the fold runs over each tile of such a table
+            score_passes=score_passes(table.dtype))
         if self.mesh is not None:
             counters.update(shards=self._shards,
                             merge_candidates=self._shards * k)

@@ -15,14 +15,19 @@ Per grid step (one movie tile), everything MOVIE-MAJOR — [T, B] scores,
 [K, B] carry — so that every dynamic index is a single-sublane ref row and
 every reduction runs along sublanes, the forms Mosaic lowers:
 
-- score block  S = tile · Uᵀ on the MXU (f32 accumulation; an int8 tile is
-  dequantized in-register by its per-row scale — the same canonical
-  dequant placement as the Gram kernels, ``ops.quant``).  The scales
-  reach the kernel lane-dense, one [1, T] row a tile of a [NT, 1, T] view
-  of the [M_pad] vector (a bitcast), and are turned to a column in
-  register: a [M_pad, 1] operand is padded to 128 lanes a row in HBM,
-  4.8 GB copied per call for 37 MB of scales at 9.35 M rows (PERF.md
-  section 6, PR 32),
+- score block  S = tile · Uᵀ on the MXU (f32 accumulation).  An int8
+  tile is not dequantized at all: a code is an integer in ±127, exact in
+  bfloat16, and the row's scale comes out of the sum, so the block is
+  three bfloat16 passes of the codes against the three bfloat16 pieces of
+  U (``split_bf16x3``, made once a call: U is the resident operand), every
+  product exact in the float32 accumulator, and one float32 multiply of
+  the [T, B] block by the row's scale — float32 arithmetic on the
+  dequantized view at half the six passes a float32 tile takes at
+  ``Precision.HIGHEST`` (PERF.md section 6, PR 35).  The scales reach the
+  kernel lane-dense, one [1, T] row a tile of a [NT, 1, T] view of the
+  [M_pad] vector (a bitcast), and are turned to columns in register: a
+  [M_pad, 1] operand is padded to 128 lanes a row in HBM, 4.8 GB copied
+  per call for 37 MB of scales at 9.35 M rows (PERF.md section 6, PR 32),
 - padding mask: global row ≥ ``num_movies`` → −inf (the table is padded
   to a tile multiple),
 - exclusion mask: already-rated items are −inf'd in-register from a
@@ -101,17 +106,67 @@ def serve_compute_dtype(table_dtype):
     """(compute dtype, matmul precision) for the score block — the serving
     analog of ``ops.solve._gram_compute_dtype``: f32 operands keep the
     full-precision MXU pass (bit-parity with the dense oracle), bf16 tables
-    feed the MXU bf16 with f32 accumulation, int8 tables dequantize to f32
-    in-register first (code × the row's scale, rounded to float32 as the
-    reference's dequantized view is) and then take the float32 pass.  What
-    a narrower table buys is BYTES, held and scanned per batch: half at
-    bf16, a quarter plus 4 B a row at int8.  It does not buy that in time:
-    at ``Precision.HIGHEST`` the scorer is bound by the MXU's passes, which
-    an int8 table runs in full (PERF.md sections 5 and 6 hold what each
-    costs on the chip)."""
+    feed the MXU bf16 with f32 accumulation, int8 tables are scored in
+    float32 arithmetic against the dequantized view (code × the row's
+    scale).  What a narrower table buys is BYTES, held and scanned per
+    batch: half at bf16, a quarter plus 4 B a row at int8.  At
+    ``Precision.HIGHEST`` the scorer is bound by the MXU's passes, six over
+    a float32 tile; an int8 code is exact in bfloat16, so an int8 tile
+    needs three (``score_passes``, ``split_bf16x3``; PERF.md sections 5
+    and 6 hold what each costs on the chip).  The two control tests patch
+    this function to ``(bfloat16, None)`` for int8: the fold then runs the
+    arithmetic under the stated one, the dequantized tile and ``u`` each
+    rounded to bfloat16, in one pass."""
     if table_dtype == jnp.bfloat16:
         return jnp.bfloat16, None
     return jnp.float32, lax.Precision.HIGHEST
+
+
+def score_passes(table_dtype) -> int:
+    """bfloat16 MXU passes the fold runs over one tile of such a table: 1
+    where the compute dtype is bfloat16, else the pieces of a float32
+    operand pair that can be non-zero — six of a float32 tile's nine at
+    ``Precision.HIGHEST``, three for an int8 tile, whose codes have one
+    piece.  What ``ServeEngine`` puts on ``serve/batch/compute``."""
+    ct, _ = serve_compute_dtype(table_dtype)
+    if ct == jnp.bfloat16:
+        return 1
+    return 3 if table_dtype == jnp.int8 else 6
+
+
+def split_bf16x3(u):
+    """``u`` (float32) as three bfloat16 pieces, [3, *u.shape], high to
+    low, whose float32 sum is ``u`` bit for bit: each piece is the
+    remainder rounded to 8 significant bits, and 24 are all a float32
+    has.  ``reduce_precision`` does the rounding because a float32 →
+    bfloat16 → float32 round trip is one XLA may drop as excess
+    precision.  Piece 0 is ``u.astype(bfloat16)``."""
+    u = u.astype(jnp.float32)
+    pieces = []
+    for _ in range(3):
+        piece = lax.reduce_precision(u, exponent_bits=8, mantissa_bits=7)
+        pieces.append(piece.astype(jnp.bfloat16))
+        u = u - piece
+    return jnp.stack(pieces)
+
+
+def resident_operand(u, table_dtype):
+    """What the fold reads as ``u`` for a table of this dtype, made once a
+    call: the [B, k] batch itself, or for int8 codes its three bfloat16
+    pieces (``split_bf16x3``), [3, B, k]."""
+    return split_bf16x3(u) if table_dtype == jnp.int8 else u
+
+
+def _times_row_scale(x, scale):
+    """``x`` [T, n] times its row's scale, ``scale`` [T, L] with the row's
+    scale in every column: L = 1 (a column, broadcast), or one register's
+    width of lanes applied to each L-column piece of ``x``."""
+    n, l = x.shape[1], scale.shape[1]
+    if l == 1 or n <= l:
+        return x * scale[:, :n]
+    return jnp.concatenate(
+        [x[:, j:j + l] * scale[:, :min(l, n - j)] for j in range(0, n, l)],
+        axis=1)
 
 
 def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
@@ -131,8 +186,10 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     ``dynamic_slice`` on values and lane-offset slices do not).
 
     ``read()`` → (carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty),
-    u [B, k], tile [T, k] (f32/bf16/int8), scale [T, 1] or [T, k] f32 — the
-    row's scale in every column — or None),
+    u [B, k] — for an int8 tile its three bfloat16 pieces [3, B, k]
+    (``resident_operand``) — tile [T, k] (f32/bf16/int8), scale f32 [T, 1]
+    or [T, L] with the row's scale in every column (``_times_row_scale``),
+    or None),
     ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j < the
     static ``seen_width`` (T = padding), ``seen_hit`` scalar int32 =
     whether any of this tile's slots holds a cell (``SeenTiles.hits``) —
@@ -172,20 +229,39 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
 
     def fold(masked):
         carry_v, carry_i, u, tile, scale = read()
-        b = u.shape[0]
+        b = u.shape[-2]
         ct, prec = serve_compute_dtype(tile.dtype)
-        if tile.dtype == jnp.int8:
-            # canonical dequant placement (ops.quant): codes → f32 ×
-            # per-row scale, before the single matmul (in ``ct``: float32)
-            tile_f = (tile.astype(jnp.float32) * scale).astype(ct)
+        dims = (((1,), (1,)), ((), ()))
+        if tile.dtype == jnp.int8 and ct == jnp.float32:
+            # A code is exact in bfloat16 and the row's scale comes out of
+            # the sum: three bfloat16 passes against the pieces of u, every
+            # product exact in the float32 accumulator, added low to high,
+            # then the [T, B] block times the scale.  Float32 arithmetic
+            # on the dequantized view, at half HIGHEST's six passes.
+            codes = tile.astype(jnp.bfloat16)
+
+            def one_pass(piece):
+                return jax.lax.dot_general(
+                    codes, u[piece], dimension_numbers=dims,
+                    preferred_element_type=jnp.float32)
+
+            scores = _times_row_scale(
+                (one_pass(2) + one_pass(1)) + one_pass(0), scale)  # [T, B]
         else:
-            tile_f = tile.astype(ct)
-        scores = jax.lax.dot_general(
-            tile_f, u.astype(ct),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec,
-        )  # [T, B]
+            if tile.dtype == jnp.int8:
+                # the controls' one pass (``serve_compute_dtype`` patched):
+                # the dequantized tile and u, each rounded to ``ct``
+                tile_f = _times_row_scale(tile.astype(jnp.float32),
+                                          scale).astype(ct)
+                u = u[0]
+            else:
+                tile_f = tile.astype(ct)
+            scores = jax.lax.dot_general(
+                tile_f, u.astype(ct),
+                dimension_numbers=dims,
+                preferred_element_type=jnp.float32,
+                precision=prec,
+            )  # [T, B]
         row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile row
         neg = jnp.float32(-jnp.inf)
         if masked:
@@ -410,15 +486,14 @@ def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
     i = pl.program_id(0)
 
     def scale_rows():
-        # the tile's scales are one lane-dense [1, T] row; the tile wants
-        # them down its sublanes: broadcast down whole registers' worth of
-        # sublanes and transposed, every column of row r then holds r's
-        # scale and the multiply needs no broadcast along lanes (a [T, 1]
-        # column broadcast in the multiply: 41.5 against 35.2 ms a call at
-        # 9.35 M rows, PERF.md section 6, PR 32)
-        k = tbl_ref.shape[1]
-        lanes = -(-k // 128) * 128
-        return jnp.broadcast_to(scale_ref[0], (lanes, t)).T[:, :k]
+        # the tile's scales are one lane-dense [1, T] row; the score block
+        # wants them down its sublanes: broadcast down one register's
+        # width of sublanes and transposed, every column of row r then
+        # holds r's scale and the multiply, one for each 128-lane piece of
+        # the block, needs no broadcast along lanes (a [T, 1] column
+        # broadcast in the multiply: 41.5 against 35.2 ms a call at 9.35 M
+        # rows, PERF.md section 6, PR 32)
+        return jnp.broadcast_to(scale_ref[0], (128, t)).T
 
     @pl.when(i == 0)
     def _():
@@ -529,10 +604,13 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             u, table, scale, seen_tiles, k_top=k_top,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
+    # u is the resident operand: what the fold needs of it is made here,
+    # once a call (an int8 table's three bfloat16 pieces)
+    u = resident_operand(u, table.dtype)
     # index maps take the grid step and then the scalar-prefetch refs: the
     # row offset and, with exclusion, the tiles' hits
     in_specs = [
-        pl.BlockSpec((b, k), lambda i, *_: (0, 0)),  # u: resident
+        pl.BlockSpec(u.shape, lambda i, *_: (0,) * u.ndim),  # u: resident
         pl.BlockSpec((tile_m, k), lambda i, *_: (i, 0)),  # table: streamed
     ]
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]

@@ -78,8 +78,9 @@ def default_two_stage_params(num_movies: int, *,
 
 def _coarse_call(u, centroids, scale, *, probe):
     """Centroid score + per-user top-``probe`` clusters — the candidate
-    stage, scored exactly like the kernel scores a tile (same compute
-    dtype / precision / canonical int8 dequant as ``_score_tile_fold``)."""
+    stage, scored in the kernel's arithmetic (``serve_compute_dtype``'s
+    compute dtype and precision; int8 centroids are dequantized here,
+    code × scale, before the one matmul: they are few)."""
     import jax
     import jax.numpy as jnp
     from jax import lax

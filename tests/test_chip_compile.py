@@ -394,11 +394,16 @@ def test_serve_scorer_amazon23_int8_cell_shape(chip):
     chip, the scales as a lane-dense [NT, 1, T] view.  As a [M_pad, 1]
     operand they were copied out to one scale a 128-lane row on every
     call, 24.7 GB here (ISSUE 32): the view is a bitcast, and the call
-    needs no temporary at all."""
+    needs no temporary at all.  The body is the three-pass one (ISSUE 35):
+    the codes to bfloat16 in register, three bfloat16 matmuls against the
+    pieces of ``u``, which reach the kernel as one resident [3, B, k]
+    operand split outside it, and the scale on each 128-lane half of the
+    block (under ``shard_map``: ``test_serve_sharded_four_devices``)."""
     compiled = _compile_scorer(chip, i8, b=256, k_top=16, w=16, m=48_190_000)
     text = compiled.as_text()
     assert "f32[94122,1,512]" in text and "s32[94122]" in text
     assert "f32[48190464,1]" not in text
+    assert "bf16[3,256,128]" in text and "reduce-precision" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 << 20
     # codes + scales + the [NT, 256, 16] rectangle + the small operands
@@ -409,6 +414,7 @@ def test_serve_scorer_amazon23_int8_cell_shape(chip):
 @pytest.mark.parametrize("m,k_top,b,dtype", [
     (59_047, 10, 64, bf16),  # the ML-25M table
     (48_190_000, 16, 256, f32),  # Amazon-2023: 24.7 GB, 6.17 GB a chip
+    (48_190_000, 16, 256, i8),  # its codes over four chips: the int8 body
 ])
 def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
     """Item-axis sharded serving as one program over the described 2×2
@@ -429,15 +435,18 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
     m_pad, nt = 4 * per, 4 * per // tile_m
     on = lambda shape, dt, spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(mesh, spec))
-    fn = spmd._serve_topk_sharded_fn(mesh, per, False, True, k_top, m, tile_m)
+    fn = spmd._serve_topk_sharded_fn(mesh, per, dtype == i8, True, k_top, m,
+                                     tile_m)
     seen = SeenTiles(on((nt, b, w), i32, P(AXIS)), on((nt,), i32, P(AXIS)))
+    scale = [on((m_pad,), f32, P(AXIS))] if dtype == i8 else []
     scorer = fn.lower(
-        on((b, k), f32, P()), on((m_pad, k), dtype, P(AXIS)), seen,
+        on((b, k), f32, P()), on((m_pad, k), dtype, P(AXIS)), *scale, seen,
     ).compile()
     text = scorer.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     assert "%_topk_shard_call." in text
-    shard_bytes = per * k * jnp.dtype(dtype).itemsize + nt // 4 * b * w * 4
+    shard_bytes = (per * (k * jnp.dtype(dtype).itemsize + 4 * len(scale))
+                   + nt // 4 * b * w * 4)
     # (a narrow batch's rectangle is padded to whole 128-lane registers)
     assert scorer.memory_analysis().argument_size_in_bytes < 1.5 * shard_bytes
     for fresh in (True, False):

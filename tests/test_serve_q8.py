@@ -4,7 +4,10 @@ one device and over 2- and 4-device meshes; the sliced upload's codes and
 scales against quantizing the whole table at once, to the bit; a control in
 narrower arithmetic failing the stated limits; a table past the device's
 budget going up in slices where it fits quantized and refused in words where
-it does not; the spans."""
+it does not; the spans.  And the arithmetic of an int8 tile's score block
+(ISSUE 35): the three bfloat16 pieces of ``u`` sum to it bit for bit, every
+code is exact in bfloat16, the three-pass block sits where a float32 sum
+can, and each piece is needed."""
 
 import jax
 import jax.numpy as jnp
@@ -273,3 +276,122 @@ def test_host_quantizer_is_the_rule_on_awkward_rows():
     # halves round to the even neighbour
     assert scales[1] == 1.0
     assert codes[1, :7].tolist() == [127, 0, 2, 2, 4, 0, -2]
+
+
+# -- the int8 tile's score block: three exact bfloat16 passes (ISSUE 35) -----
+
+def _split_cases(name):
+    rng = np.random.default_rng(35)
+    if name == "weights":  # what the benchmark's user factors look like
+        return (rng.random((256, 128), dtype=np.float32) - 0.5) * 0.35
+    if name == "normal":
+        return rng.standard_normal((256, 128)).astype(np.float32)
+    if name == "wide":  # every binade from 1e-30 to 1e30, both signs
+        mag = np.exp(rng.uniform(np.log(1e-30), np.log(1e30), (256, 128)))
+        return (mag * rng.choice([-1.0, 1.0], mag.shape)).astype(np.float32)
+    # zeros, negatives, powers of two, their neighbours a float32 ulp off,
+    # values that round up into the next binade, a very small one
+    pows = 2.0 ** np.arange(-40, 41, dtype=np.float32)
+    return np.concatenate([
+        np.array([0.0, -0.0, 1.0, -1.0, 1e-30, -1e-30, 3.0, -0.1,
+                  1.9999999, 255.5, 65535.99, np.pi, -np.e], np.float32),
+        pows, -pows, np.nextafter(pows, np.float32(0)),
+        np.nextafter(pows, np.float32(np.inf)),
+    ]).astype(np.float32)[None]
+
+
+@pytest.mark.parametrize("jitted", [False, True])
+@pytest.mark.parametrize("values", ["weights", "normal", "wide", "awkward"])
+def test_three_bfloat16_pieces_sum_to_u_bit_for_bit(values, jitted):
+    u = _split_cases(values)
+    split = topk_kernel.split_bf16x3
+    pieces = (jax.jit(split) if jitted else split)(jnp.asarray(u))
+    assert pieces.dtype == jnp.bfloat16 and pieces.shape == (3,) + u.shape
+    hi, mid, lo = (np.asarray(p.astype(jnp.float32)) for p in pieces)
+    for total in ((hi + mid) + lo, hi + (mid + lo)):
+        assert total.dtype == np.float32
+        np.testing.assert_array_equal(total, u)
+        # to the bit, but for the sign of a zero (-0.0 comes back 0.0)
+        np.testing.assert_array_equal(total.view(np.uint32)[u != 0],
+                                      u.view(np.uint32)[u != 0])
+    # the first piece is u rounded to bfloat16: the controls' operand
+    np.testing.assert_array_equal(
+        hi, np.asarray(jnp.asarray(u).astype(jnp.bfloat16)
+                       .astype(jnp.float32)))
+    # each piece has what is left over: 2^-8, 2^-16 of the one before
+    assert (np.abs(mid) <= np.abs(hi) * 2.0 ** -8).all()
+    assert (np.abs(lo) <= np.abs(hi) * 2.0 ** -16).all()
+
+
+@pytest.mark.parametrize("jitted", [False, True])
+def test_every_code_is_exact_in_bfloat16(jitted):
+    codes = np.arange(-127, 128, dtype=np.int8)
+    assert codes.size == 255
+    through = lambda c: c.astype(jnp.bfloat16).astype(jnp.float32)
+    got = (jax.jit(through) if jitted else through)(jnp.asarray(codes))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  codes.astype(np.float32))
+
+
+def _block_error(route, seed, monkeypatch=None, drop=()):
+    """Worst distance of the scorer's int8 scores at rank 128, tile 512
+    from the float64 value of s_j sum_i u_i c_ij, over s_j sum_i |u_i
+    c_ij|, at the ids it served (every row of four tiles enters a top-K
+    somewhere: K = 64 of 2,048 rows for 32 users)."""
+    from cfk_tpu.compat import emulate_topk_scores
+
+    rng = np.random.default_rng(seed)
+    m, k, b, k_top, tile = 2_048, 128, 32, 64, 512
+    codes = rng.integers(-127, 128, (m, k), dtype=np.int8)
+    scales = rng.uniform(1e-3, 2e-3, m).astype(np.float32)
+    u = ((rng.random((b, k), dtype=np.float32) - 0.5) * 0.35)
+    if drop:
+        real = topk_kernel.split_bf16x3
+        monkeypatch.setattr(
+            topk_kernel, "split_bf16x3",
+            lambda x: real(x).at[np.asarray(drop)].set(0))
+    fn = (topk_kernel.topk_scores_pallas if route == "kernel"
+          else emulate_topk_scores)
+    vals, ids = fn(jnp.asarray(u), jnp.asarray(codes), jnp.asarray(scales),
+                   None, k_top=k_top, num_movies=m, tile_m=tile)
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    assert vals.dtype == np.float32 and (ids >= 0).all()
+    terms = u.astype(np.float64)[:, None, :] * codes[ids].astype(np.float64)
+    s = scales[ids].astype(np.float64)
+    return (np.abs(vals - s * terms.sum(-1))
+            / (s * np.abs(terms).sum(-1))).max()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("route", ["kernel", "twin"])
+def test_three_passes_are_a_float32_sum_and_each_piece_is_needed(
+        route, seed, monkeypatch):
+    sound = _block_error(route, seed)
+    assert sound <= 2.0 ** -20
+    # u_lo dropped: 16 bits of u instead of 24
+    two = _block_error(route, seed, monkeypatch, drop=(2,))
+    assert two >= 10 * sound and two <= 2.0 ** -15
+    # and u_mid with it: the one-pass arithmetic of the controls
+    one = _block_error(route, seed, monkeypatch, drop=(1, 2))
+    assert one >= 10 * two
+
+
+@pytest.mark.parametrize("shards", [None, 2])
+@pytest.mark.parametrize("table_dtype,passes", [
+    ("int8", 3), ("float32", 6), ("bfloat16", 1)])
+def test_compute_span_says_how_many_passes_a_tile_takes(table_dtype, passes,
+                                                        shards):
+    uf, mf, lists, rows = _problem(10)
+    assert topk_kernel.score_passes(jnp.dtype(table_dtype)) == passes
+    tracer = telemetry.configure()
+    try:
+        _engine(uf, mf, lists, shards=shards,
+                table_dtype=table_dtype).topk(rows, 7)
+        (compute,) = [e["args"] for e in tracer.events()
+                      if e.get("ph") == "X"
+                      and e["name"] == "serve/batch/compute"]
+    finally:
+        telemetry.shutdown(write=False)
+    assert compute["table_dtype"] == table_dtype
+    assert compute["score_passes"] == passes
+    assert compute.get("shards") == shards

@@ -296,7 +296,8 @@ def test_spans_of_a_sharded_engine():
     assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
     assert set(events["serve/batch/compute"]["args"]) == {
         "n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
-        "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes"}
+        "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes",
+        "score_passes"}
     assert {x: events["serve/batch/compute"]["args"][x]
             for x in ("seen_chunks", "seen_hit_tiles")} == {
         x: compute[x] for x in ("seen_chunks", "seen_hit_tiles")}

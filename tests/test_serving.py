@@ -127,7 +127,11 @@ def test_quantized_table_self_consistency(rng, table_dtype):
     # the quantization metric contract: the kernel on a quantized table
     # returns EXACTLY the top-K of the dequantized-table scores —
     # quantization error lives in the table, the kernel adds none
-    # (bit-pinned against the twin scoring the dequantized view).
+    # (bit-pinned against the twin scoring the dequantized view; an int8
+    # tile's block is the same float32 sum with the row's scale taken out
+    # of it, three exact bfloat16 passes and one multiply (ISSUE 35), so
+    # its scores sit within the sum's own rounding of the view's, not on
+    # its bits: tests/test_serve_q8.py holds that arithmetic to float64).
     from cfk_tpu.ops.quant import dequantize_table, quantize_table
 
     u, mf, tbl, *_ = _problem(rng)
@@ -140,7 +144,11 @@ def test_quantized_table_self_consistency(rng, table_dtype):
     v2, i2 = emulate_topk_scores(
         jnp.asarray(u), dq, None, None, k_top=5, num_movies=50, tile_m=16,
     )
-    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    v1, v2 = np.asarray(v1), np.asarray(v2)
+    if table_dtype == "int8":
+        assert (np.abs(v1 - v2) <= 4 * np.spacing(np.abs(v2))).all()
+    else:
+        np.testing.assert_array_equal(v1, v2)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
 
