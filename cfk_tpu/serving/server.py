@@ -513,6 +513,9 @@ class ServeClient:
         # mis-attribute answers — a random base makes that astronomically
         # unlikely instead of guaranteed.
         self._next_req = int.from_bytes(os.urandom(5), "big") << 16
+        # the send under way, opened by the first request since the last
+        # flush: (the tracer's clock then, 0 with tracing off; its req_id)
+        self._send: tuple[int, int] | None = None
         self._cursor = transport.end_offset(responses_topic, reply_partition)
         self.malformed_responses = 0
         self.retries = 0
@@ -521,6 +524,9 @@ class ServeClient:
     def request(self, user: int, k: int) -> int:
         """Send one query; returns its req_id (the response's echo key)."""
         req_id = self._next_req
+        if self._send is None:
+            self._send = (time.perf_counter_ns()
+                          if get_tracer() is not None else 0, req_id)
         self._next_req += 1
         part = (int(user) if self.route_by_user else req_id) % self._req_parts
         self.transport.produce(
@@ -535,25 +541,42 @@ class ServeClient:
         return req_id
 
     def flush(self) -> None:
+        """Hand every request produced since the last flush to the log.
+        One ``serve/client/flush`` span a call, the client's send: from the
+        first of those requests to the flush's return (none where nothing
+        was sent)."""
+        send, self._send = self._send, None
         flush = getattr(self.transport, "flush", None)
         if flush is not None:
             flush()
+        tracer = get_tracer()
+        if tracer is not None and send is not None and send[0]:
+            tracer.complete("serve/client/flush", send[0],
+                            requests=self._next_req - send[1])
 
     def poll_responses(self) -> list[ScoreResponse]:
         """All responses that arrived since the last poll.  A malformed
         frame is counted and skipped with the cursor advanced — the same
-        no-wedge rule as the server's request poll."""
-        out = []
-        seen = 0
-        for rec in self.transport.consume(
-            self.responses_topic, self.reply_partition, self._cursor
-        ):
-            seen += 1
-            try:
-                out.append(decode_score_response(rec.value))
-            except ValueError:
-                self.malformed_responses += 1
-        self._cursor += seen
+        no-wedge rule as the server's request poll.  One
+        ``serve/client/poll`` span a call, the client's collect; a poll
+        that finds nothing writes no event."""
+        with span("serve/client/poll") as sp:
+            out = []
+            malformed = self.malformed_responses
+            recs = list(self.transport.consume(
+                self.responses_topic, self.reply_partition, self._cursor))
+            for rec in recs:
+                try:
+                    out.append(decode_score_response(rec.value))
+                except ValueError:
+                    self.malformed_responses += 1
+            self._cursor += len(recs)
+            if not recs:
+                sp.drop()
+            elif get_tracer() is not None:
+                sp.set(responses=len(out),
+                       bytes=sum(len(rec.value) for rec in recs),
+                       malformed=self.malformed_responses - malformed)
         return out
 
     def ask(self, users, k: int, *, server=None, timeout_s: float = 30.0,

@@ -40,6 +40,7 @@ from cfk_tpu.telemetry.recorder import (
     record_event,
 )
 from cfk_tpu.telemetry.trace import (
+    GC_SPAN,
     Tracer,
     configure,
     get_tracer,
@@ -51,6 +52,7 @@ from cfk_tpu.telemetry.trace import (
 
 __all__ = [
     "FlightRecorder",
+    "GC_SPAN",
     "Histogram",
     "Metrics",
     "MetricsEmitter",

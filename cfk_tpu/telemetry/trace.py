@@ -16,8 +16,9 @@ Design constraints (the sentinel discipline, ISSUE 3's ≤2% budget):
 - **Off is near-free and bit-identical.**  No tracer installed ⇒
   ``span()`` returns a module-level null context manager: one global read
   and one function call, no allocation.  Tracing never touches device
-  values, so on/off factors are crc-identical by construction (pinned by
-  ``chaos_lab telemetry_overhead``).
+  values, so on/off results are identical by construction (held by
+  ``tests/test_telemetry.py::test_tracer_on_trains_the_same_factors`` and
+  ``tests/test_serve_spans.py::test_answers_are_bit_identical_traced_and_untraced``).
 - **Thread-aware.**  Every event records its OS thread; staging-pool
   worker spans carry the (shard, window) ids their task staged, so pool
   overlap is *visible* in the trace instead of inferred from counters.
@@ -30,6 +31,14 @@ Design constraints (the sentinel discipline, ISSUE 3's ≤2% budget):
   ``perf_counter`` microseconds; the Chrome export is on the unix epoch
   through that pair (``Tracer.to_unix_ns``), the clock a ``jax.profiler``
   trace's ``profile_start_time`` is on, so the two files line up.
+- **Runtime hooks live with the tracer.**  What pauses a stage without
+  being a stage is put on the same clock by a hook that ``configure()``
+  installs and ``shutdown()`` removes: with no tracer installed the
+  runtime holds nothing of ours.  The one hook is on ``gc.callbacks``:
+  every pass of Python's cyclic collector is a ``runtime/gc`` span
+  (``GC_SPAN``) on the thread that ran it, nested in whatever stage was
+  open.  ``Tracer.complete`` writes a span whose start was stamped
+  earlier, for work that begins in one call and ends in another.
 
 Span naming: callers pass the FULL span-name path (``train/iter/half_step/
 window_stage``) — explicit at the call site, zero path-joining overhead
@@ -39,6 +48,7 @@ in the hot path.  The naming scheme is documented in ARCHITECTURE.md
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -47,6 +57,11 @@ import time
 # Hard cap on buffered events: a runaway loop must degrade to dropped
 # events (counted), never to unbounded memory.
 MAX_EVENTS = 1_000_000
+
+# One pass of Python's cyclic collector, on the thread that ran it.  A
+# reader that finds this name here and no such span in a trace knows the
+# hook was in and no pass ran; a program without the name has no hook.
+GC_SPAN = "runtime/gc"
 
 
 class _NullSpan:
@@ -126,7 +141,11 @@ class Tracer:
     def __init__(self, trace_dir: str | None = None) -> None:
         self.trace_dir = trace_dir
         self._events: list[dict] = []
-        self._lock = threading.Lock()
+        # re-entrant: a collector pass can start on a thread that holds it
+        # (between two bytecodes of ``_emit``), and its span is emitted
+        # from inside the pass
+        self._lock = threading.RLock()
+        self._gc_t0 = 0  # perf_counter_ns at the start of the pass under way
         self._thread_names: dict[int, str] = {}
         self.dropped = 0
         # the one reading of both clocks: perf_counter (what every event is
@@ -157,6 +176,27 @@ class Tracer:
 
     def span(self, name: str, **attrs) -> _SpanCM:
         return _SpanCM(self, name, attrs)
+
+    def complete(self, name: str, t0_ns: int, **attrs) -> None:
+        """A span on this thread from ``t0_ns``, a ``perf_counter_ns``
+        reading taken when the work began, to now: for work that begins
+        in one call and ends in another."""
+        ts = t0_ns // 1000
+        self._emit(name, ts, time.perf_counter_ns() // 1000 - ts,
+                   threading.get_ident(), attrs)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """The ``gc.callbacks`` hook: a pass is one ``GC_SPAN``.  Passes
+        never overlap in a process (the collector is not re-entrant), so
+        one stamp serves; a pass already under way when the hook went in
+        has no start and writes nothing."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0:
+            t0, self._gc_t0 = self._gc_t0, 0
+            self.complete(GC_SPAN, t0, generation=info["generation"],
+                          collected=info["collected"],
+                          uncollectable=info["uncollectable"])
 
     # -- export --------------------------------------------------------------
 
@@ -215,11 +255,19 @@ class Tracer:
 _TRACER: Tracer | None = None
 
 
+def _unhook(tracer: Tracer | None) -> None:
+    if tracer is not None and tracer._on_gc in gc.callbacks:
+        gc.callbacks.remove(tracer._on_gc)
+
+
 def configure(trace_dir: str | None = None) -> Tracer:
-    """Install (and return) the process tracer.  Until this is called,
-    every ``span()`` is the null fast path."""
+    """Install (and return) the process tracer and its runtime hook (one
+    callback on ``gc.callbacks``); a tracer installed before gives both
+    up.  Until this is called, every ``span()`` is the null fast path."""
     global _TRACER
+    _unhook(_TRACER)
     _TRACER = Tracer(trace_dir=trace_dir)
+    gc.callbacks.append(_TRACER._on_gc)
     return _TRACER
 
 
@@ -228,10 +276,12 @@ def get_tracer() -> Tracer | None:
 
 
 def shutdown(write: bool = True) -> str | None:
-    """Uninstall the tracer; optionally write its trace first."""
+    """Uninstall the tracer and its runtime hook; optionally write its
+    trace first."""
     global _TRACER
     t = _TRACER
     _TRACER = None
+    _unhook(t)
     if t is not None and write:
         return t.write()
     return None

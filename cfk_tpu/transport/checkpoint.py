@@ -26,6 +26,8 @@ from collections import deque
 
 import numpy as np
 
+from cfk_tpu.telemetry.trace import span
+
 _MANIFEST = "manifest.json"
 _STEP_PREFIX = "step_"
 
@@ -348,6 +350,16 @@ class CheckpointManager:
     always keeping the newest N plus any ``pin()``ned step — the resilient
     loop pins its last verified-good rollback anchor, so the step the
     recovery ladder points at can never be collected out from under it.
+
+    Under the tracer each job of the writer thread is a span
+    ``checkpoint/write`` on that thread (``cfk-checkpoint-writer``), from
+    its being taken up to the rename and the directory's fsync (``step``,
+    ``kind`` where the job's meta has one, ``bytes``, ``fsyncs``,
+    ``queued_ms`` = from ``save_async`` taking the job, before any wait
+    for room, to the writer taking it up: ``queued_ms`` + the span is the
+    caller's hand-over to durable).  A ``save_async`` that waits for room
+    writes ``checkpoint/backpressure`` on the caller's thread (``pending``,
+    ``max_pending``); one that does not wait writes nothing.
     """
 
     def __init__(
@@ -378,6 +390,8 @@ class CheckpointManager:
         self._inflight = 0
         self._writer_thread: threading.Thread | None = None
         self._writer_error: BaseException | None = None
+        # what the last ``save`` of each thread wrote: (bytes, fsyncs)
+        self._wrote = threading.local()
         os.makedirs(directory, exist_ok=True)
 
     def _step_dir(self, iteration: int) -> str:
@@ -419,12 +433,17 @@ class CheckpointManager:
             self.save(iteration, hu, hm, meta=meta)
             return
         _LIVE_MANAGERS.add(self)
+        queued = time.perf_counter()
         with self._lock:
             self._raise_writer_error_locked()
-            while len(self._jobs) + self._inflight >= self.max_pending:
-                self._queue_nonfull.wait()
-                self._raise_writer_error_locked()
-            self._jobs.append((iteration, hu, hm, dict(meta or {})))
+            pending = len(self._jobs) + self._inflight
+            if pending >= self.max_pending:
+                with span("checkpoint/backpressure", pending=pending,
+                          max_pending=self.max_pending):
+                    while len(self._jobs) + self._inflight >= self.max_pending:
+                        self._queue_nonfull.wait()
+                        self._raise_writer_error_locked()
+            self._jobs.append((iteration, hu, hm, dict(meta or {}), queued))
             if self._writer_thread is None or not self._writer_thread.is_alive():
                 self._writer_thread = threading.Thread(
                     target=self._writer_loop,
@@ -464,11 +483,19 @@ class CheckpointManager:
                     # the next save_async (no join-at-shutdown bookkeeping).
                     self._writer_thread = None
                     return
-                iteration, hu, hm, meta = self._jobs.popleft()
+                iteration, hu, hm, meta, queued = self._jobs.popleft()
                 self._inflight += 1
                 self._queue_nonfull.notify_all()
             try:
-                self.save(iteration, hu, hm, meta=meta)
+                with span("checkpoint/write", step=iteration,
+                          queued_ms=(time.perf_counter() - queued) * 1e3
+                          ) as sp:
+                    if "kind" in meta:
+                        sp.set(kind=meta["kind"])
+                    self._wrote.last = (0, 0)
+                    self.save(iteration, hu, hm, meta=meta)
+                    nbytes, fsyncs = self._wrote.last
+                    sp.set(bytes=nbytes, fsyncs=fsyncs)
             except BaseException as e:
                 with self._lock:
                     if self._writer_error is None:
@@ -529,8 +556,9 @@ class CheckpointManager:
                 },
                 **(meta or {}),
             }
+            text = json.dumps(manifest)
             with open(os.path.join(tmp, _MANIFEST), "w") as f:
-                json.dump(manifest, f)
+                f.write(text)
                 f.flush()
                 os.fsync(f.fileno())
             # fsync payloads + the directories on both sides of the rename:
@@ -545,6 +573,10 @@ class CheckpointManager:
                 shutil.rmtree(final)
             os.rename(tmp, final)
             _fsync_dir(self.directory)
+            # durable from here: the payloads' and the manifest's bytes, and
+            # the fsyncs asked for (the manifest's, the two payloads', the
+            # directories' on both sides of the rename)
+            self._wrote.last = (u.nbytes + m.nbytes + len(text), 1 + 2 + 2)
             self._retain(iteration)
             # Flight-record the commit (post-rename — the event means "this
             # step is durably on disk", the fact an incident reader needs).
