@@ -72,6 +72,7 @@ from cfk_tpu.serving.topk_kernel import (
     scatter_seen_cells,
     score_passes,
     seen_cell_capacity,
+    slab_tiles,
     topk_scores_counted,
 )
 from cfk_tpu.telemetry import dump_flight, record_event, span
@@ -706,8 +707,16 @@ class ServeEngine:
             u = put(u)
             sp.set(bytes=nbytes)
         # what the fetch will say of the batch on ``serve/batch/compute``
+        shard_tiles = tiles // self._shards
+        slab = slab_tiles(shard_tiles, b, 0 if shape is None else shape[2],
+                          u.shape[1], table.dtype, tile_m=self.tile_m,
+                          k_top=k)
         counters = dict(
             n=n, b=b, k=k, tiles=tiles,
+            # the tiles one grid step of the scorer streams and folds, and
+            # the steps that makes of the table, all shards'
+            slab_tiles=slab,
+            grid_steps=-(-shard_tiles // slab) * self._shards,
             # what the scorer streams from HBM for the batch, all shards':
             # the table as it is held, and its scales
             table_dtype=self.table_dtype,

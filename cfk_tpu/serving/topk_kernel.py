@@ -11,9 +11,23 @@ block on the MXU, and folds it into a running per-user K-selection carried
 in VMEM — so the only thing that ever reaches HBM is the [B, K] result.
 No [B, num_movies] score matrix exists anywhere, on-chip or off.
 
-Per grid step (one movie tile), everything MOVIE-MAJOR — [T, B] scores,
-[K, B] carry — so that every dynamic index is a single-sublane ref row and
-every reduction runs along sublanes, the forms Mosaic lowers:
+A grid step streams a SLAB of G consecutive tiles — the table's block is
+[G·T, k], the scales' [G, 1, T], the rectangle's [G, W, B] — and folds them
+in loops inside the body; the step's height is not the fold's tile.  What a
+tile's fold does before it needs the carry, its score block and per-user
+maximum, is done for a group of ``_GROUP_TILES`` tiles of the slab in
+straight-line code, so that the MXU runs their blocks back to back and no
+matmul waits for the scalar the tile before was gated on; then the tiles
+take their turns at the carry in order (``_topk_kernel``; what each part
+costs on the chip: PERF.md section 7, row 17).  G is as large as the table's
+length and the VMEM budget allow (``slab_tiles``: a function of the shapes,
+no knob; the ragged last step folds the tiles there are, its blocks clipped
+at the table's end).  ``tile_m`` stays the tile of the fold, of the gate, of
+the rectangle and of every count.
+
+Per tile, everything MOVIE-MAJOR — [T, B] scores, [K, B] carry — so that
+every dynamic index is a single-sublane ref row and every reduction runs
+along sublanes, the forms Mosaic lowers:
 
 - score block  S = tile · Uᵀ on the MXU (f32 accumulation).  An int8
   tile is not dequantized at all: a code is an integer in ±127, exact in
@@ -70,7 +84,7 @@ counts included — the same twin discipline as the Gram kernels.  The
 kernel compiles for the v5e at f32/bf16/int8 and under the 2×2 shard_map
 (``tests/test_chip_compile.py``) and matches the twin on the chip
 (``tests/test_pallas_tpu.py``); what it costs there is in PERF.md
-(sections 5 and 6, PRs 27 and 31).
+(sections 5 and 6, PRs 27, 31, 35 and 38; section 7, row 17).
 """
 
 from __future__ import annotations
@@ -93,6 +107,14 @@ import numpy as np
 # row iota this many slots per loop trip (unrolled by hand), and W is padded
 # to a multiple of it.
 _SEEN_CHUNK = 16
+
+# Tiles a grid step may stream (``slab_tiles`` takes the largest that fits),
+# and what a call may ask of the v5e's 128 MiB of VMEM.
+_SLAB_LADDER = (16, 8, 4, 2, 1)
+_VMEM_CAP = 110 << 20
+# Tiles of a slab scored in straight-line code ahead of their gates (a
+# rung of the ladder: it divides every G at or above it).
+_GROUP_TILES = 8
 
 
 def _pow2_ceil(x: int, floor: int = 1) -> int:
@@ -169,6 +191,113 @@ def _times_row_scale(x, scale):
         axis=1)
 
 
+def _tile_scores(u, tile, scale):
+    """The [T, B] float32 score block of one tile, movie-major: ``tile``
+    [T, k] (f32 / bf16 / int8) against ``u`` [B, k] — for an int8 tile its
+    three bfloat16 pieces [3, B, k] (``resident_operand``) and ``scale``
+    f32 [T, 1] or [T, L] with the row's scale in every column
+    (``_times_row_scale``)."""
+    ct, prec = serve_compute_dtype(tile.dtype)
+    dims = (((1,), (1,)), ((), ()))
+    if tile.dtype == jnp.int8 and ct == jnp.float32:
+        # A code is exact in bfloat16 and the row's scale comes out of
+        # the sum: three bfloat16 passes against the pieces of u, every
+        # product exact in the float32 accumulator, added low to high,
+        # then the [T, B] block times the scale.  Float32 arithmetic
+        # on the dequantized view, at half HIGHEST's six passes.
+        codes = tile.astype(jnp.bfloat16)
+
+        def one_pass(piece):
+            return jax.lax.dot_general(
+                codes, u[piece], dimension_numbers=dims,
+                preferred_element_type=jnp.float32)
+
+        return _times_row_scale(
+            (one_pass(2) + one_pass(1)) + one_pass(0), scale)  # [T, B]
+    if tile.dtype == jnp.int8:
+        # the controls' one pass (``serve_compute_dtype`` patched):
+        # the dequantized tile and u, each rounded to ``ct``
+        tile_f = _times_row_scale(tile.astype(jnp.float32),
+                                  scale).astype(ct)
+        u = u[0]
+    else:
+        tile_f = tile.astype(ct)
+    return jax.lax.dot_general(
+        tile_f, u.astype(ct),
+        dimension_numbers=dims,
+        preferred_element_type=jnp.float32,
+        precision=prec,
+    )  # [T, B]
+
+
+def _mask_scores(scores, seen_row, seen_width, tile_base, num_movies):
+    """``scores`` [T, B] with −inf on the rows at or past ``num_movies``
+    and, with exclusion, on each user's seen rows: ``seen_row(j)`` → [1, B]
+    int32 in-tile rows of exclusion slot j < the static ``seen_width`` (T =
+    padding), or None."""
+    t, b = scores.shape
+    row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile row
+    neg = jnp.float32(-jnp.inf)
+    scores = jnp.where(tile_base + row < num_movies, scores, neg)
+    if seen_row is None:
+        return scores
+
+    def mask_chunk(c, sc):
+        # _SEEN_CHUNK slots per trip, fully unrolled (Mosaic's loop
+        # lowering takes unroll=1 or a full unroll only) by the lowering,
+        # not by Python: a slot's compare is traced once a program, and
+        # tracing is what a warm start pays for (PERF.md section 6, PR 38)
+        return lax.fori_loop(
+            0, _SEEN_CHUNK,
+            lambda j, sc: jnp.where(
+                row == seen_row(c * _SEEN_CHUNK + j), neg, sc),
+            sc, unroll=True)
+
+    return lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk, scores)
+
+
+def _tile_max(sc):
+    return jnp.max(sc, axis=0, keepdims=True)  # [1, B]
+
+
+def _entrant(ms, kth):
+    """Whether some user's tile maximum ``ms`` [1, B] is STRICTLY above
+    its K-th score ``kth`` [1, B]: a scalar."""
+    return jnp.max((ms > kth).astype(jnp.int32)) > 0
+
+
+def _select_round(sc, cv, ci, ms, tile_base):
+    """One selection round: each user's tile maximum ``ms`` (earliest row
+    holding it) inserted into its sorted carry behind every value ≥ it — a
+    shift by one sublane — and consumed from the tile: ``(sc, cv, ci,
+    ms)`` after it."""
+    t, b = sc.shape
+    row = lax.broadcasted_iota(jnp.int32, (t, b), 0)
+    first = lax.broadcasted_iota(jnp.int32, cv.shape, 0) == 0
+    pos = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
+    # Slots whose value is >= the entrant stay; the entrant lands
+    # in the first slot that is not, and the rest move one sublane
+    # down.  A user with nothing to enter (ms <= its K-th score)
+    # keeps every slot, so it needs no mask of its own — and its
+    # consumed tile row could not have entered later either (the
+    # K-th score only rises).
+    stay = cv >= ms
+    up_v, up_i = pltpu.roll(cv, 1, 0), pltpu.roll(ci, 1, 0)
+    here = first | (up_v >= ms)
+    cv = jnp.where(stay, cv, jnp.where(here, ms, up_v))
+    ci = jnp.where(stay, ci, jnp.where(here, tile_base + pos, up_i))
+    sc = jnp.where(row == pos, jnp.float32(-jnp.inf), sc)
+    return sc, cv, ci, _tile_max(sc)
+
+
+def _tile_counts(rounds, hit, seen_width):
+    """What one tile adds to the four counts: the selection rounds it ran
+    and whether it ran any, the exclusion chunks of ``_SEEN_CHUNK`` slots
+    it ran (its whole width, or none) and whether it ran any."""
+    return (rounds, (rounds > 0).astype(jnp.int32),
+            hit * (seen_width // _SEEN_CHUNK), hit)
+
+
 def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
                      tile_m, num_movies, k_top):
     """Fold one movie tile into the running top-K carry: ``(carry_v,
@@ -177,8 +306,12 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     exclusion chunks of ``_SEEN_CHUNK`` slots it ran (its whole width, or
     none) and whether it ran any: what ``topk_scores_counted`` adds up.
 
-    The ONE copy of the per-tile math — the Mosaic kernel body and the XLA
-    twin both call exactly this.  Everything is MOVIE-MAJOR ([T, B] scores,
+    The per-tile math as one function of one tile: what the XLA twin scans
+    (``compat.emulate_topk_counted``).  The Mosaic kernel body runs the same
+    pieces (``_tile_scores``, ``_mask_scores``, ``_entrant``,
+    ``_select_round``, ``_tile_counts``) on the same tiles in the same
+    order, the score blocks of a few tiles ahead of their gates
+    (``_topk_kernel``).  Everything is MOVIE-MAJOR ([T, B] scores,
     [K, B] carry): per-slot exclusion rows and per-round selections are
     then single-sublane ref rows broadcast down the tile, and every
     reduction runs along sublanes — the only forms of dynamic indexing and
@@ -200,14 +333,7 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     past ``num_movies`` (the table's last) runs both masks, every other
     tile — nine in ten of a serve cell's — runs neither, and loses
     nothing: its slot rows are all T, which no row equals, and none of its
-    rows is padding.  Each branch is the whole fold, matmul to selection,
-    and reads its operands itself (``read``), so the masks stay fused with
-    the score block as they are without the gate and only the [K, B] carry
-    crosses the branch.  On the v5e that is what the gate has to be: a
-    trip count on the mask loops, or a branch around the masks alone,
-    leaves the [T, B] block in VMEM between matmul and masks and costs
-    more than the masks do, and operands read ahead of the branch are
-    spilled across it (PERF.md section 6, PR 31).
+    rows is padding.
 
     The carry is SORTED: scores descending, equal scores by ascending
     global row, empty slots (−inf / −1) at the tail — so its last row is
@@ -229,86 +355,17 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
 
     def fold(masked):
         carry_v, carry_i, u, tile, scale = read()
-        b = u.shape[-2]
-        ct, prec = serve_compute_dtype(tile.dtype)
-        dims = (((1,), (1,)), ((), ()))
-        if tile.dtype == jnp.int8 and ct == jnp.float32:
-            # A code is exact in bfloat16 and the row's scale comes out of
-            # the sum: three bfloat16 passes against the pieces of u, every
-            # product exact in the float32 accumulator, added low to high,
-            # then the [T, B] block times the scale.  Float32 arithmetic
-            # on the dequantized view, at half HIGHEST's six passes.
-            codes = tile.astype(jnp.bfloat16)
-
-            def one_pass(piece):
-                return jax.lax.dot_general(
-                    codes, u[piece], dimension_numbers=dims,
-                    preferred_element_type=jnp.float32)
-
-            scores = _times_row_scale(
-                (one_pass(2) + one_pass(1)) + one_pass(0), scale)  # [T, B]
-        else:
-            if tile.dtype == jnp.int8:
-                # the controls' one pass (``serve_compute_dtype`` patched):
-                # the dequantized tile and u, each rounded to ``ct``
-                tile_f = _times_row_scale(tile.astype(jnp.float32),
-                                          scale).astype(ct)
-                u = u[0]
-            else:
-                tile_f = tile.astype(ct)
-            scores = jax.lax.dot_general(
-                tile_f, u.astype(ct),
-                dimension_numbers=dims,
-                preferred_element_type=jnp.float32,
-                precision=prec,
-            )  # [T, B]
-        row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile row
-        neg = jnp.float32(-jnp.inf)
+        scores = _tile_scores(u, tile, scale)
         if masked:
-            scores = jnp.where(tile_base + row < num_movies, scores, neg)
-        if masked and seen_row is not None:
-            def mask_chunk(c, sc):
-                # _SEEN_CHUNK slots per trip, unrolled by hand: Mosaic's
-                # loop lowering takes unroll=1 or a full unroll only
-                for j in range(_SEEN_CHUNK):
-                    sc = jnp.where(row == seen_row(c * _SEEN_CHUNK + j),
-                                   neg, sc)
-                return sc
-
-            scores = lax.fori_loop(0, seen_width // _SEEN_CHUNK, mask_chunk,
-                                   scores)
-        first = lax.broadcasted_iota(jnp.int32, (k_top, b), 0) == 0
-
-        def tile_max(sc):
-            return jnp.max(sc, axis=0, keepdims=True)  # [1, B]
-
-        def entrant(state):
-            _, cv, _, ms, _ = state
-            return jnp.max((ms > cv[k_top - 1:]).astype(jnp.int32)) > 0
-
-        def select(state):
-            sc, cv, ci, ms, rounds = state
-            pos = jnp.min(jnp.where(sc == ms, row, t), axis=0, keepdims=True)
-            # Slots whose value is >= the entrant stay; the entrant lands
-            # in the first slot that is not, and the rest move one sublane
-            # down.  A user with nothing to enter (ms <= its K-th score)
-            # keeps every slot, so it needs no mask of its own — and its
-            # consumed tile row could not have entered later either (the
-            # K-th score only rises).
-            stay = cv >= ms
-            up_v, up_i = pltpu.roll(cv, 1, 0), pltpu.roll(ci, 1, 0)
-            here = first | (up_v >= ms)
-            cv = jnp.where(stay, cv, jnp.where(here, ms, up_v))
-            ci = jnp.where(stay, ci, jnp.where(here, tile_base + pos, up_i))
-            sc = jnp.where(row == pos, neg, sc)
-            return sc, cv, ci, tile_max(sc), rounds + 1
-
+            scores = _mask_scores(scores, seen_row, seen_width, tile_base,
+                                  num_movies)
         # Under shard_map's own tracing (the twin's sharded route) the
         # loop state varies over the mesh like the tile's scores do.
         rounds = match_varying(jnp.int32(0), scores)
         _, carry_v, carry_i, _, rounds = lax.while_loop(
-            entrant, select,
-            (scores, carry_v, carry_i, tile_max(scores), rounds))
+            lambda st: _entrant(st[3], st[1][k_top - 1:]),
+            lambda st: _select_round(*st[:4], tile_base) + (st[4] + 1,),
+            (scores, carry_v, carry_i, _tile_max(scores), rounds))
         return carry_v, carry_i, rounds
 
     if seen_row is None:
@@ -318,9 +375,7 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
         carry_v, carry_i, rounds = lax.cond(
             (hit > 0) | (tile_base + t > num_movies),
             lambda: fold(True), lambda: fold(False))
-    counts = (rounds, (rounds > 0).astype(jnp.int32),
-              hit * (seen_width // _SEEN_CHUNK), hit)
-    return carry_v, carry_i, counts
+    return carry_v, carry_i, _tile_counts(rounds, hit, seen_width)
 
 
 def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
@@ -460,32 +515,94 @@ def as_seen_tiles(seen_tiles, tile_m):
         jnp.any(seen_tiles != tile_m, axis=(1, 2)).astype(jnp.int32))
 
 
-def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
-                 with_scale):
-    """Grid step i: fold movie tile i into the resident [K, B] carry.
+def slab_tiles(num_tiles, batch, seen_width, rank, table_dtype, *,
+               tile_m=512, k_top=16) -> int:
+    """G: how many consecutive tiles one grid step of the scorer streams —
+    the largest rung of ``_SLAB_LADDER`` that the table has tiles for and
+    whose VMEM need (``_vmem_bytes``) stays inside ``_VMEM_CAP``.  The
+    slab is what lets the body score ``_GROUP_TILES`` tiles back to back,
+    ahead of their gates (``_topk_kernel``), and a grid step costs a
+    little whatever its height (0.06 µs: PERF.md section 7, row 17), so
+    the step is as tall as the budget and the table allow: the ladder's
+    top at a serve cell's 18,262 tiles, NT's rung at a shortlist's
+    handful, 1 where a wide rectangle leaves room for one tile only.  A
+    function of the shapes alone; ``tile_m`` stays the tile of the fold,
+    of the gate, of the rectangle and of every count."""
+    for g in _SLAB_LADDER:
+        if g <= max(num_tiles, 1) and _vmem_bytes(
+                g, batch, seen_width, rank, table_dtype, tile_m=tile_m,
+                k_top=k_top) <= _VMEM_CAP:
+            return g
+    return 1
+
+
+def _vmem_bytes(g, batch, seen_width, rank, table_dtype, *, tile_m, k_top):
+    """What a call at G = ``g`` asks of VMEM: the [K, B] result (2x for
+    Mosaic's output double-buffer; the scratch carry is as large again),
+    the slab of ``g`` streamed tiles with their scales and the slab's
+    slice of the seen rectangle, each double-buffered, the [T, B] score
+    blocks of a group in scratch, one more with its selection temporaries,
+    and headroom."""
+    out_bytes = 2 * batch * k_top * 8
+    tile_bytes = tile_m * (rank * jnp.dtype(table_dtype).itemsize + 4)
+    seen_bytes = batch * seen_width * 4
+    return (2 * out_bytes + 2 * g * (tile_bytes + seen_bytes)
+            + (min(g, _GROUP_TILES) + 8) * tile_m * batch * 4 + (16 << 20))
+
+
+def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
+                 with_seen, with_scale):
+    """Grid step i: fold the slab of movie tiles [i·G, min((i+1)·G, NT))
+    into the resident [K, B] carry, a group of P tiles at a time.
+
+    The step's height is not the fold's tile: the table's block is [G·T,
+    k], the scales' [G, 1, T], the rectangle's [G, W, B], and a loop over
+    the slab's G / P groups reads tile ``s`` of each.  What a tile's fold
+    does before it needs the carry — the score block and its per-user
+    maximum — is done for the P tiles of a group in straight-line code,
+    into scratch (``sc_ref`` [P, T, B], ``ms_ref`` [P, 1, B]), with each
+    tile's first gate as a scalar in SMEM (``gate_ref`` [P]): the MXU runs
+    the P blocks back to back and no matmul waits for the scalar of the
+    tile before — a scalar read out of a vector costs 0.22 µs when the
+    next instruction waits for it, 4 ms a call at 18,262 tiles (PERF.md
+    section 6, PR 38).  Then a second loop gives the tiles their turns at
+    the carry, in order: a tile that holds a cell or reaches past
+    ``num_movies`` has its block masked and its maximum taken again; a
+    tile whose first gate was open runs ``_score_tile_fold``'s rounds on
+    its block in scratch (``rounds_of``).  The first gate is read against
+    the K-th scores as of the group's start and from the unmasked block:
+    both can only make it open where the exact gate is shut (a K-th score
+    only rises, a mask only lowers a maximum), never the other way, so the
+    rounds run, their order and the counts are the twin's to the bit.  On
+    the last step of a table whose NT is no multiple of G the blocks are
+    clipped at the table's end and the tiles past NT are scored (whatever
+    the buffers hold) and never folded, masked or counted.
 
     The carry is two VMEM scratch blocks: step 0 initializes them, every
-    step merges its tile, the last step copies the final state to the
+    tile merges into them, the last step copies the final state to the
     (constant-index, resident) output blocks.  ``off_ref`` (scalar-
     prefetched, [1] int32) is the shard's global row offset — 0 on a
-    single device; under item-axis sharding each shard's tile i covers
-    global movie rows [off + i·T, off + (i+1)·T).  With exclusion a second
+    single device; under item-axis sharding each shard's tile n covers
+    global movie rows [off + n·T, off + (n+1)·T).  With exclusion a second
     scalar-prefetched operand follows it, ``hits_ref`` ([NT] int32,
-    ``SeenTiles.hits``): whether tile i holds a cell to mask.
+    ``SeenTiles.hits``): whether tile n holds a cell to mask.
     ``counts_ref`` (SMEM, [4] int32) accumulates the selection rounds run,
     the tiles that ran at least one, the exclusion chunks run
     (``_SEEN_CHUNK`` slots each, the rectangle's whole width on a tile
-    that is hit) and the tiles that ran them.
+    that is hit) and the tiles that ran them — tiles of T rows, whatever G
+    and P are.
     """
     refs = list(refs)
     hits_ref = refs.pop(0) if with_seen else None
     u_ref, tbl_ref = refs.pop(0), refs.pop(0)
     scale_ref = refs.pop(0) if with_scale else None
     seen_ref = refs.pop(0) if with_seen else None
-    vals_ref, ids_ref, counts_ref, cv_ref, ci_ref = refs
+    (vals_ref, ids_ref, counts_ref, cv_ref, ci_ref, sc_ref, ms_ref,
+     gate_ref) = refs
     i = pl.program_id(0)
+    seen_width = seen_ref.shape[1] if with_seen else 0
 
-    def scale_rows():
+    def scale_rows(s):
         # the tile's scales are one lane-dense [1, T] row; the score block
         # wants them down its sublanes: broadcast down one register's
         # width of sublanes and transposed, every column of row r then
@@ -493,7 +610,7 @@ def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
         # the block, needs no broadcast along lanes (a [T, 1] column
         # broadcast in the multiply: 41.5 against 35.2 ms a call at 9.35 M
         # rows, PERF.md section 6, PR 32)
-        return jnp.broadcast_to(scale_ref[0], (128, t)).T
+        return jnp.broadcast_to(scale_ref[s], (128, t)).T
 
     @pl.when(i == 0)
     def _():
@@ -502,28 +619,90 @@ def _topk_kernel(off_ref, *refs, t, k_top, num_movies, b, with_seen,
         for j in range(4):
             counts_ref[j] = 0
 
-    # The carry lives in scratch, not in the output blocks: the selection
-    # loop's state starts from it, and inside a compiled kernel under
-    # shard_map a value read from an OUTPUT ref keeps the out_shape's vma
-    # while everything computed from it has none (jax 0.9.0) — a loop
-    # seeded with one could not typecheck.
-    new_v, new_i, counts = _score_tile_fold(
-        lambda: (cv_ref[...], ci_ref[...], u_ref[...], tbl_ref[...],
-                 scale_rows() if with_scale else None),
-        (lambda j: seen_ref[0, pl.ds(j, 1), :]) if with_seen else None,
-        seen_ref.shape[1] if with_seen else 0,
-        hits_ref[i] if with_seen else None, off_ref[0] + i * t,
-        tile_m=t, num_movies=num_movies, k_top=k_top,
-    )
-    cv_ref[...] = new_v
-    ci_ref[...] = new_i
+    def rounds_of(j, tile_base):
+        """Tile j of the group, whose first gate was open, against the
+        carry as it stands: ``_score_tile_fold``'s rounds on the tile's
+        block in scratch.  The first round is run before the exact gate is
+        known and counted only if that gate was open: where it was shut
+        no user has an entrant, every slot of every carry stays
+        (``_select_round``), and the tile's consumed row could not have
+        entered later either — so the scalar of the exact gate feeds the
+        count alone and no vector work waits for it."""
+        def select(state):
+            cv, ci, ms, rounds, _ = state
+            sc, cv, ci, ms = _select_round(sc_ref[j], cv, ci, ms, tile_base)
+            sc_ref[j] = sc
+            return (cv, ci, ms, rounds + 1,
+                    _entrant(ms, cv[k_top - 1:]).astype(jnp.int32))
+
+        # The carry lives in scratch, not in the output blocks: the loop's
+        # state starts from it, and inside a compiled kernel under
+        # shard_map a value read from an OUTPUT ref keeps the out_shape's
+        # vma while everything computed from it has none (jax 0.9.0) — a
+        # loop seeded with one could not typecheck.
+        cv, ci, ms = cv_ref[...], ci_ref[...], ms_ref[j]
+        open_ = _entrant(ms, cv[k_top - 1:]).astype(jnp.int32)
+        # a do-while: the state's last word says whether another round
+        # is due, and the first is (one trace of the round a program)
+        cv, ci, _, rounds, _ = lax.while_loop(
+            lambda st: st[4] > 0, select,
+            (cv, ci, ms, open_ - 1, jnp.int32(1)))
+        cv_ref[...] = cv
+        ci_ref[...] = ci
+        return rounds
+
+    def fold_tile(j, counts, *, first):
+        s = first + j
+        n = i * g + s  # the tile's place in the table (or the shard)
+        tile_base = off_ref[0] + n * t
+        there = True if nt % g == 0 else n < nt
+        hit = hits_ref[jnp.minimum(n, nt - 1)] if with_seen else jnp.int32(0)
+
+        @pl.when(there & ((hit > 0) | (tile_base + t > num_movies)))
+        def _():
+            sc = _mask_scores(
+                sc_ref[j],
+                (lambda w: seen_ref[s, pl.ds(w, 1), :]) if with_seen
+                else None, seen_width, tile_base, num_movies)
+            sc_ref[j] = sc
+            ms_ref[j] = _tile_max(sc)
+
+        rounds = lax.cond(there & (gate_ref[j] > 0),
+                          lambda: rounds_of(j, tile_base),
+                          lambda: jnp.int32(0))
+        tile_counts = _tile_counts(rounds, jnp.where(there, hit, 0),
+                                   seen_width)
+        return tuple(c + d for c, d in zip(counts, tile_counts))
+
+    def fold_group(q, counts):
+        first = q * p  # the group's first tile within the slab
+        kth = cv_ref[k_top - 1:, :]
+
+        def score_tile(j, _):  # nothing here reads what a tile before wrote
+            s = first + j
+            sc = _tile_scores(u_ref[...],
+                              tbl_ref[pl.ds(pl.multiple_of(s * t, t), t), :],
+                              scale_rows(s) if with_scale else None)
+            sc_ref[j] = sc
+            ms = _tile_max(sc)
+            ms_ref[j] = ms
+            gate_ref[j] = _entrant(ms, kth).astype(jnp.int32)
+            return _
+
+        # straight-line code for the P tiles, unrolled by the lowering:
+        # traced once a program (as ``_mask_scores``'s slots are)
+        lax.fori_loop(0, p, score_tile, 0, unroll=True)
+        return lax.fori_loop(0, p, functools.partial(fold_tile, first=first),
+                             counts)
+
+    counts = lax.fori_loop(0, g // p, fold_group, (jnp.int32(0),) * 4)
     for j, n in enumerate(counts):
         counts_ref[j] += n
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
-        vals_ref[...] = new_v
-        ids_ref[...] = new_i
+        vals_ref[...] = cv_ref[...]
+        ids_ref[...] = ci_ref[...]
 
 
 def topk_scores_pallas(
@@ -607,47 +786,45 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     # u is the resident operand: what the fold needs of it is made here,
     # once a call (an int8 table's three bfloat16 pieces)
     u = resident_operand(u, table.dtype)
+    seen_width = 0 if seen_tiles is None else slots.shape[2]
+    # G tiles a grid step, from the shapes (``slab_tiles``)
+    g = slab_tiles(nt, b, seen_width, k, table.dtype, tile_m=tile_m,
+                   k_top=k_top)
+    p = min(g, _GROUP_TILES)  # tiles scored ahead of their gates
     # index maps take the grid step and then the scalar-prefetch refs: the
-    # row offset and, with exclusion, the tiles' hits
+    # row offset and, with exclusion, the tiles' hits.  Where G does not
+    # divide NT the last step's blocks reach past the arrays and are
+    # clipped; the kernel folds the tiles there are.
     in_specs = [
         pl.BlockSpec(u.shape, lambda i, *_: (0,) * u.ndim),  # u: resident
-        pl.BlockSpec((tile_m, k), lambda i, *_: (i, 0)),  # table: streamed
+        pl.BlockSpec((g * tile_m, k), lambda i, *_: (i, 0)),  # table: slabs
     ]
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
     ops = [u, table]
     if scale is not None:
         # lane-dense: [NT, 1, T] is the [M_pad] vector itself in HBM, where
         # [M_pad, 1] would be padded to 128 lanes a row
-        in_specs.append(pl.BlockSpec((1, 1, tile_m), lambda i, *_: (i, 0, 0)))
+        in_specs.append(pl.BlockSpec((g, 1, tile_m), lambda i, *_: (i, 0, 0)))
         ops.append(scale.astype(jnp.float32).reshape(nt, 1, tile_m))
-    seen_width = 0
     if seen_tiles is not None:
-        seen_width = slots.shape[2]
         prefetch.append(hits)
         # slot-major for the kernel: one exclusion slot = one [1, B] row
         in_specs.append(
-            pl.BlockSpec((1, seen_width, b), lambda i, *_: (i, 0, 0))
+            pl.BlockSpec((g, seen_width, b), lambda i, *_: (i, 0, 0))
         )
         ops.append(jnp.swapaxes(slots, 1, 2))
     kwargs = {}
     if not interpret:
-        # the [K, B] result (2× for Mosaic's output double-buffer; the
-        # scratch carry is as large again) + one streamed tile
-        # double-buffered + the seen rectangle + the [T, B] score block
-        # and its selection temporaries + headroom
-        out_bytes = 2 * b * k_top * 8
-        tile_bytes = 2 * tile_m * (k + 1) * 4
-        seen_bytes = 2 * b * seen_width * 4
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=min(
-                2 * out_bytes + 2 * tile_bytes + seen_bytes
-                + 8 * tile_m * b * 4 + (16 << 20),
-                110 << 20,
+                _vmem_bytes(g, b, seen_width, k, table.dtype, tile_m=tile_m,
+                            k_top=k_top),
+                _VMEM_CAP,
             )
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(nt,),
+        grid=(pl.cdiv(nt, g),),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((k_top, b), lambda i, *_: (0, 0)),
@@ -655,7 +832,11 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[pltpu.VMEM((k_top, b), jnp.float32),
-                        pltpu.VMEM((k_top, b), jnp.int32)],
+                        pltpu.VMEM((k_top, b), jnp.int32),
+                        # a group's score blocks and their maxima
+                        pltpu.VMEM((p, tile_m, b), jnp.float32),
+                        pltpu.VMEM((p, 1, b), jnp.float32),
+                        pltpu.SMEM((p,), jnp.int32)],
     )
     # under shard_map the selection varies over the mesh like the table
     # slice it was scored from (as in ops.pallas.gram_kernel)
@@ -665,8 +846,8 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     )
     vals, ids, counts = pl.pallas_call(
         functools.partial(
-            _topk_kernel, t=tile_m, k_top=k_top, num_movies=num_movies,
-            b=b, with_seen=seen_tiles is not None,
+            _topk_kernel, t=tile_m, g=g, p=p, nt=nt, k_top=k_top,
+            num_movies=num_movies, b=b, with_seen=seen_tiles is not None,
             with_scale=scale is not None,
         ),
         grid_spec=grid_spec,

@@ -357,6 +357,40 @@ def test_serve_scorer_gated_selection_shapes(chip, b, k_top):
     _compile_scorer(chip, f32, b=b, k_top=k_top, w=16)
 
 
+@pytest.mark.parametrize("dtype,m,g_want,ragged", [
+    (f32, 9_350_000, 16, 6),  # the one-chip cell: 18,262 = 16 x 1,141 + 6
+    (f32, 12_047_500, 16, 11),  # a shard of the four-chip cell: 23,531
+    (bf16, 9_350_000, 16, 6),  # a bfloat16 table of the same catalogue
+    (i8, 48_190_000, 16, 10),  # the int8 cell: 94,122 = 16 x 5,882 + 10
+])
+def test_serve_scorer_slab_of_the_cells(chip, dtype, m, g_want, ragged):
+    """A grid step streams G tiles ([G·512, 128] of table, [G, 1, 512] of
+    scales, [G, 16, 256] of the rectangle) and folds them in a loop whose
+    trip count on the last step is what is left of the table: at the three
+    cells' sizes no rung of the ladder divides NT, so every one of them
+    compiles the clipped edge blocks and the dynamic trip count."""
+    from cfk_tpu.serving.topk_kernel import slab_tiles
+
+    nt = -(-m // 512)
+    g = slab_tiles(nt, 256, 16, 128, dtype, tile_m=512, k_top=16)
+    assert (g, nt % g) == (g_want, ragged)
+    _compile_scorer(chip, dtype, b=256, k_top=16, w=16, m=m)
+
+
+@pytest.mark.parametrize("g", [1, 2, 4, 8, 16])
+@pytest.mark.parametrize("dtype", [f32, i8])
+def test_serve_scorer_every_rung_of_the_slab_ladder(chip, monkeypatch, dtype,
+                                                    g):
+    """Each step height the ladder holds, over 117 tiles (ragged at every
+    G but 1) and a rectangle 64 slots wide: what a call takes when the
+    budget or the table's length brings G down."""
+    from cfk_tpu.serving import topk_kernel
+
+    assert g in topk_kernel._SLAB_LADDER
+    monkeypatch.setattr(topk_kernel, "slab_tiles", lambda *a, **k: g)
+    _compile_scorer(chip, dtype, b=64, k_top=10, w=64, m=59_600)
+
+
 def test_serve_scorer_amazon14_cell_shape(chip):
     """The one-chip serve cell's own call: 18,262 tiles × 256 rows × 16
     slots, so the tiles' hits ride in as a second scalar-prefetch operand
@@ -445,6 +479,11 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
     text = scorer.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     assert "%_topk_shard_call." in text
+    # each shard's call streams slabs of its own tiles, the last one ragged
+    # at the Amazon-2023 size (23,531 tiles a shard)
+    from cfk_tpu.serving.topk_kernel import slab_tiles
+    g = slab_tiles(nt // 4, b, w, k, dtype, tile_m=tile_m, k_top=k_top)
+    assert g == 16 and (nt // 4) % g == (11 if m > 59_047 else 13)
     shard_bytes = (per * (k * jnp.dtype(dtype).itemsize + 4 * len(scale))
                    + nt // 4 * b * w * 4)
     # (a narrow batch's rectangle is padded to whole 128-lane registers)

@@ -353,10 +353,29 @@ def test_dense_gather_f32_rank128_compiled():
 @pytest.mark.parametrize("order", ["random", "ascending", "equal"])
 @pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
 def test_topk_compiled_matches_twin(table_dtype, order, cells):
+    _topk_compiled_against_twin(table_dtype, order, cells, m=2_000)
+
+
+@pytest.mark.parametrize("cells", ["one_tile", "every_tile"])
+@pytest.mark.parametrize("order", ["random", "ascending", "equal"])
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
+def test_topk_compiled_ragged_slab_matches_twin(table_dtype, order, cells):
+    """The same orders over 2 G + 3 tiles: two whole slabs and a ragged
+    last step of three tiles, whose blocks the pipeline clips at the
+    table's end, the last tile reaching past ``num_movies``."""
+    from cfk_tpu.serving.topk_kernel import _SLAB_LADDER, slab_tiles
+
+    nt = 2 * _SLAB_LADDER[0] + 3
+    assert nt % slab_tiles(nt, 64, 16, 128, jnp.float32, tile_m=512,
+                           k_top=10) == 3
+    _topk_compiled_against_twin(table_dtype, order, cells, m=nt * 512 - 48)
+
+
+def _topk_compiled_against_twin(table_dtype, order, cells, m):
     """The serve scorer compiled (movie-major fold, selection rounds gated
     on the carry's K-th score, the masks on the tiles that hold a cell or
     cross ``num_movies``: ``cells`` puts the seen rows nowhere, in the
-    third of the four tiles, or anywhere) against its XLA twin: the same
+    third of the tiles, or anywhere) against its XLA twin: the same
     ids wherever scores are not within round-off of each other, the same
     scores to MXU tolerance.  ``ascending`` makes every tile enter every
     user's top-K (the most rounds the gate can ask for) with scores exact in
@@ -371,8 +390,9 @@ def test_topk_compiled_matches_twin(table_dtype, order, cells):
     )
 
     rng = np.random.default_rng(3)
-    m, k, b, k_top, tile = 2_000, 128, 64, 10, 512
+    k, b, k_top, tile = 128, 64, 10, 512
     m_pad = -(-m // tile) * tile
+    nt = m_pad // tile
     tbl = np.zeros((m_pad, k), np.float32)
     u = rng.standard_normal((b, k)).astype(np.float32)
     if order == "random":
@@ -380,11 +400,13 @@ def test_topk_compiled_matches_twin(table_dtype, order, cells):
     elif order == "ascending":
         # row r scores 2^e_b · (r + 1): two small integers a row, one
         # power of two a user — products and the one sum are exact
-        tbl[:m, 0] = np.arange(1, m + 1) // 64
-        tbl[:m, 1] = np.arange(1, m + 1) % 64
+        # (both under 256: exact in a bfloat16 table too)
+        base = 64 if m < 64 * 256 else 128
+        tbl[:m, 0] = np.arange(1, m + 1) // base
+        tbl[:m, 1] = np.arange(1, m + 1) % base
         u = np.zeros((b, k), np.float32)
         u[:, 1] = 2.0 ** rng.integers(-3, 4, b)
-        u[:, 0] = 64 * u[:, 1]
+        u[:, 0] = base * u[:, 1]
     else:
         tbl[:m] = rng.standard_normal(k).astype(np.float32)
     data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
@@ -401,7 +423,7 @@ def test_topk_compiled_matches_twin(table_dtype, order, cells):
         tile_m=tile))
     # a tile a user has rated into runs the rectangle's width, 16 slots a
     # chunk; the others none
-    hit = {"none": 0, "one_tile": 1, "every_tile": 4}[cells]
+    hit = {"none": 0, "one_tile": 1, "every_tile": nt}[cells]
     assert hit == len(np.unique(np.concatenate(seen) // tile))
     chunks = [hit * (st.shape[2] // 16), hit]
     kw = dict(k_top=k_top, num_movies=m, tile_m=tile)
@@ -424,8 +446,8 @@ def test_topk_compiled_matches_twin(table_dtype, order, cells):
     unseen = [np.setdiff1d(np.arange(m), s) for s in seen]
     if order == "ascending":
         # every tile enters: K rounds each (the last has 464 real rows)
-        assert np.asarray(n_c)[:2].tolist() == [k_top * 4, 4]
-        assert np.asarray(n_t)[:2].tolist() == [k_top * 4, 4]
+        assert np.asarray(n_c)[:2].tolist() == [k_top * nt, nt]
+        assert np.asarray(n_t)[:2].tolist() == [k_top * nt, nt]
         if table_dtype == "int8":
             # the codes round: neither exact sums nor the strict order hold
             assert (i_c == i_t).mean() > 0.95

@@ -280,6 +280,8 @@ def test_spans_of_a_sharded_engine():
     # what the scorer streams for the batch, all four shards'
     assert compute["table_dtype"] == "float32"
     assert compute["scan_bytes"] == 1024 * RANK * 4
+    # 16 tiles a shard, one grid step of 16 tiles on each
+    assert (compute["slab_tiles"], compute["grid_steps"]) == (16, 4)
     # a one-device engine uploads through the same span (PR 32) and its
     # batch spans carry nothing of the mesh
     tracer = telemetry.configure()
@@ -297,10 +299,47 @@ def test_spans_of_a_sharded_engine():
     assert set(events["serve/batch/compute"]["args"]) == {
         "n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
         "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes",
-        "score_passes"}
+        "score_passes", "slab_tiles", "grid_steps"}
     assert {x: events["serve/batch/compute"]["args"][x]
             for x in ("seen_chunks", "seen_hit_tiles")} == {
         x: compute[x] for x in ("seen_chunks", "seen_hit_tiles")}
+    # 63 tiles on one device: three steps of 16 and a ragged one of 15
+    assert events["serve/batch/compute"]["args"]["tiles"] == 63
+    assert events["serve/batch/compute"]["args"]["slab_tiles"] == 16
+    assert events["serve/batch/compute"]["args"]["grid_steps"] == 4
+
+
+@pytest.mark.parametrize("shards", SHARDS)
+@pytest.mark.parametrize("case", ["ragged", "k_over_a_shard",
+                                  "last_shard_all_padding"])
+def test_slab_counts_on_the_span_over_shards(case, shards):
+    """``slab_tiles`` is the G the scorer takes for one shard's tiles and
+    ``grid_steps`` the steps that makes of the table, the ragged last step
+    of every shard counted, added up over the shards; the counts of the
+    fold stay counts of tiles, and the answers the one-device engine's
+    (whose kernel body runs the slabs) to the bit."""
+    from cfk_tpu.serving.topk_kernel import slab_tiles
+
+    uf, mf, lists, rows, k = _problem(case)
+    one_vals, one_ids = _engine(uf, mf, lists).topk(rows, k)
+    tracer = telemetry.configure()
+    try:
+        eng = _engine(uf, mf, lists, shards=shards)
+        vals, ids = eng.topk(rows, k)
+        compute = next(e["args"] for e in tracer.events()
+                       if e["name"] == "serve/batch/compute")
+    finally:
+        telemetry.shutdown(write=False)
+    np.testing.assert_array_equal(ids, one_ids)
+    np.testing.assert_array_equal(vals, one_vals)
+    per = eng.table_rows // shards // TILE  # tiles a shard
+    assert compute["tiles"] == per * shards
+    g = slab_tiles(per, compute["b"], 16, RANK, jnp.float32, tile_m=TILE,
+                   k_top=compute["k"])
+    assert compute["slab_tiles"] == g <= per
+    assert compute["grid_steps"] == shards * -(-per // g)
+    assert compute["tiles"] / compute["grid_steps"] <= g
+    assert compute["seen_hit_tiles"] <= compute["tiles"]
 
 
 @pytest.mark.parametrize("shards", SHARDS)

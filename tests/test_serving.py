@@ -421,6 +421,104 @@ def test_gated_masks_equal_oracle_and_full_width_run(case, table_dtype):
     assert counts["every_tile"][:2] == counts["cell_list"][:2]
 
 
+# -- a grid step streams a slab of G tiles and folds them one by one ---------
+
+_SLAB_TILE, _SLAB_B, _SLAB_K, _SLAB_RANK = 16, 8, 10, 16
+
+
+def _slab_num_tiles(which):
+    from cfk_tpu.serving.topk_kernel import _SLAB_LADDER
+
+    g = _SLAB_LADDER[0]
+    return {"1": 1, "G-1": g - 1, "G": g, "G+1": g + 1,
+            "2G+3": 2 * g + 3}[which]
+
+
+@pytest.mark.parametrize("exclude", [True, False], ids=["seen", "no_seen"])
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("which", ["1", "G-1", "G", "G+1", "2G+3"])
+def test_slab_kernel_equals_twin_to_the_bit(which, table_dtype, exclude):
+    """The kernel on the interpret path, G tiles a grid step and the last
+    step ragged, against the twin that scans tile by tile: scores, ids and
+    all four counts to the bit, at NT on both sides of the ladder's top.
+    The table's last tile reaches past ``num_movies``, holds user 0's best
+    row (an entrant in the last slab's last tile) and user 1's best row,
+    which user 1 has rated (a hit there)."""
+    from cfk_tpu.ops.quant import quantize_table
+    from cfk_tpu.serving.topk_kernel import slab_tiles
+
+    t, b, k_top = _SLAB_TILE, _SLAB_B, _SLAB_K
+    nt = _slab_num_tiles(which)
+    m = nt * t - 5  # the last tile's last five rows are padding
+    rng = np.random.default_rng(nt)
+    u = rng.standard_normal((b, _SLAB_RANK)).astype(np.float32)
+    tbl = np.zeros((nt * t, _SLAB_RANK), np.float32)
+    tbl[:m] = rng.standard_normal((m, _SLAB_RANK))
+    tbl[m - 1], tbl[m - 2] = 4 * u[0], 4 * u[1]
+    seen = [np.sort(rng.choice(m - 2, size=min(int(rng.integers(0, 9)), m - 2),
+                               replace=False)).astype(np.int32)
+            for _ in range(b)]
+    seen[1] = np.append(seen[1], m - 2).astype(np.int32)
+    indptr = np.zeros(b + 1, np.int64)
+    indptr[1:] = np.cumsum([x.size for x in seen])
+    st = jnp.asarray(build_seen_tiles(
+        np.concatenate(seen), indptr, np.arange(b), num_movies=m,
+        tile_m=t)) if exclude else None
+    data, scale = quantize_table(jnp.asarray(tbl), table_dtype)
+    g = slab_tiles(nt, b, 16 if exclude else 0, _SLAB_RANK, data.dtype,
+                   tile_m=t, k_top=k_top)
+    assert 1 <= g <= nt and (nt % g != 0) == (which in ("G-1", "G+1", "2G+3"))
+    kernel, twin = (
+        tuple(map(np.asarray, fn(jnp.asarray(u), data, scale, st)))
+        for fn in _counted_programs(k_top, m, t))
+    for got, want in zip(kernel, twin):
+        np.testing.assert_array_equal(got, want)
+    vals, ids, counts = kernel
+    assert ids[0, 0] == m - 1  # the entrant of the last tile
+    assert (ids[1, 0] == m - 2) == (not exclude)  # and the hit there
+    real = [row[row >= 0] for row in ids]  # the -1 tail where m < K
+    assert ids.max() < m and all(np.unique(r).size == r.size for r in real)
+    if exclude:
+        assert not any(np.isin(ids[i], seen[i]).any() for i in range(b))
+        hit = np.unique(np.concatenate(seen) // t).size
+        assert counts[2:].tolist() == [hit, hit]  # W = 16: a chunk a tile
+    else:
+        assert counts[2:].tolist() == [0, 0]
+    assert 1 <= counts[1] <= nt and counts[1] <= counts[0]
+
+
+def test_slab_tiles_fits_the_table_and_the_budget():
+    """G is the ladder's largest rung that the table has tiles for and the
+    VMEM budget room for: the top at every cell's shape, NT's own rung on a
+    shortlist's few tiles, 1 where a wide rectangle leaves room for one."""
+    from cfk_tpu.serving.topk_kernel import (
+        _SLAB_LADDER, _VMEM_CAP, _vmem_bytes, slab_tiles)
+
+    assert _SLAB_LADDER[-1] == 1 and list(_SLAB_LADDER) == sorted(
+        _SLAB_LADDER, reverse=True)
+    shapes = [(nt, b, w, dt) for nt in (1, 2, 3, 5, 15, 16, 17, 100, 18_262,
+                                        23_531, 94_122)
+              for b in (8, 256) for w in (0, 16, 64, 1024, 32_768)
+              for dt in (jnp.float32, jnp.bfloat16, jnp.int8)]
+    for nt, b, w, dt in shapes:
+        g = slab_tiles(nt, b, w, 128, dt, tile_m=512, k_top=16)
+        need = functools.partial(_vmem_bytes, batch=b, seen_width=w,
+                                 rank=128, table_dtype=dt, tile_m=512,
+                                 k_top=16)
+        assert g in _SLAB_LADDER and g <= nt
+        assert g == 1 or need(g) <= _VMEM_CAP
+        bigger = [r for r in _SLAB_LADDER if g < r <= nt]
+        assert all(need(r) > _VMEM_CAP for r in bigger), (nt, b, w, dt)
+    for nt, dt in ((18_262, jnp.float32), (23_531, jnp.float32),
+                   (94_122, jnp.int8)):  # the cells' calls: B 256, W 16
+        assert slab_tiles(nt, 256, 16, 128, dt, tile_m=512,
+                          k_top=16) == _SLAB_LADDER[0]
+    # a rectangle 32,768 slots wide is 33.5 MB a tile: no room for two
+    assert slab_tiles(18_262, 256, 32_768, 128, jnp.float32, tile_m=512,
+                      k_top=16) == 1
+    assert slab_tiles(0, 8, 0, 16, jnp.float32, tile_m=16, k_top=4) == 1
+
+
 @pytest.mark.parametrize("shards", [2, 4])
 def test_sharded_serve_equals_single_shard(rng, shards):
     from cfk_tpu.parallel.mesh import make_mesh
