@@ -870,6 +870,11 @@ def _serve_impl(args) -> int:
     from cfk_tpu.config import enable_compile_cache
 
     enable_compile_cache(args.compile_cache_dir)
+    if args.stream_dir and (not args.broker or args.replicas > 1):
+        _eprint("error: --stream-dir folds in the ratings of a --broker's "
+                "log into ONE server's factors: give --broker, and no "
+                "--replicas")
+        return 2
     if args.format == "netflix":
         coo = parse_netflix(args.data)
     else:
@@ -957,9 +962,40 @@ def _serve_impl(args) -> int:
             transport, request_partitions=args.request_partitions,
             response_partitions=args.response_partitions,
         )
+        session, new_session = None, None
+        if args.stream_dir:
+            # The server folds the broker's rating-updates topic into the
+            # factors it serves and is the stream task's supervisor: a
+            # session whose pump raises is abandoned and replaced from the
+            # store, the server answering all the while.
+            from cfk_tpu.config import ALSConfig
+            from cfk_tpu.streaming import (
+                StreamConfig, StreamSession, StreamState,
+                ensure_updates_topic)
+            from cfk_tpu.transport.checkpoint import CheckpointManager
+
+            ensure_updates_topic(transport)
+            base_state = StreamState(ds)
+            als = ALSConfig(rank=int(state.user_factors.shape[-1]),
+                            lam=args.stream_lam, health_check_every=1)
+
+            def new_session():
+                return StreamSession(
+                    base_state.fresh(), als, transport,
+                    CheckpointManager(args.stream_dir),
+                    stream=StreamConfig(
+                        batch_records=args.stream_batch_records),
+                    base_model=model, engine=engine)
+
+            session = new_session()
+            _eprint(f"folding in {args.stream_dir}: stream step "
+                    f"{session.stream_step}, cursor "
+                    f"{session.consumer.cursors}")
         server = RecommendServer(engine, transport,
                                  max_batch=args.max_batch,
-                                 metrics_port=args.metrics_port)
+                                 metrics_port=args.metrics_port,
+                                 session=session,
+                                 session_factory=new_session)
         if server.metrics_server is not None:
             _eprint(f"metrics endpoint: {server.metrics_server.url}")
         _eprint(
@@ -1794,6 +1830,18 @@ def build_parser() -> argparse.ArgumentParser:
                     "with explicit RETRIABLE rejections, never dropped")
     sv.add_argument("--request-partitions", type=int, default=1)
     sv.add_argument("--response-partitions", type=int, default=1)
+    sv.add_argument("--stream-dir", default=None, metavar="DIR",
+                    help="with --broker: fold the broker's rating-updates "
+                    "topic into the served factors between request "
+                    "batches, committing to the stream store DIR "
+                    "(resumed if it holds one); the server supervises "
+                    "the stream task and replaces one that dies from "
+                    "the store, answering all the while (float32 table, "
+                    "exact mode)")
+    sv.add_argument("--stream-lam", type=float, default=0.05,
+                    help="--stream-dir: the fold-in's ALS-WR lambda")
+    sv.add_argument("--stream-batch-records", type=int, default=256,
+                    help="--stream-dir: log records a micro-batch")
     sv.add_argument("--loadgen-qps", type=float, default=100.0)
     sv.add_argument("--loadgen-requests", type=int, default=256)
     sv.add_argument("--seed", type=int, default=0)

@@ -461,6 +461,14 @@ class ServeEngine:
         with self._lock:
             return self._table[0]
 
+    def user_base(self) -> np.ndarray:
+        """The base user table as the engine holds it (by reference, never
+        written): what a ``StreamSession(engine=...)`` resumed from its
+        store lays its solved rows over, so that neither its store nor its
+        resume holds a second copy."""
+        with self._lock:
+            return self._u_base
+
     # -- live-update listener ------------------------------------------------
 
     def attach_session(self, session) -> None:

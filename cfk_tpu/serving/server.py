@@ -29,11 +29,23 @@ on the device are committed and published to the engine, the next one is
 handed over (several, where the stream has fallen behind its log) — so the
 ratings a user sent are folded in between two request batches, and every
 answer names the commit ordinal its batch saw.
+
+That server is also the stream task's supervisor (``session_factory=``):
+an exception out of ``session.pump`` ends THAT session, not the loop.  The
+server abandons it (``StreamSession.abandon``: what it had in flight and
+what its writer had not renamed is gone, as after a kill of the task),
+keeps answering from the engine as the last published ordinal left it, and
+brings up a successor from the store beside the loop (a thread: the resume
+reads files and folds arrays, never the device); the step after the
+successor is up pumps it, and it replays from the log what the dead one had
+not made durable.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import sys
+import threading
 import time
 
 import numpy as np
@@ -87,6 +99,7 @@ class RecommendServer:
         staleness_fn=None,
         labels: dict | None = None,
         session=None,
+        session_factory=None,
     ) -> None:
         from cfk_tpu.utils.metrics import Metrics
 
@@ -106,8 +119,27 @@ class RecommendServer:
         self.admission = admission
         self._staleness_fn = staleness_fn
         # The stream this server folds in between its polls (a
-        # ``StreamSession``, normally on this engine's table), or None.
+        # ``StreamSession``, normally on this engine's table), or None
+        # (no stream, or its successor is being brought up).
+        # ``session_factory()`` returns a new session on the same store,
+        # log and engine: given one, the server replaces a session whose
+        # ``pump`` raised; with none, that exception ends the loop.
         self.session = session
+        self._session_factory = session_factory
+        if session is not None:
+            # This thread shares the interpreter with the store's writer
+            # threads and, after a kill, with the successor's resume: work
+            # that is many short file operations, each of which gives the
+            # interpreter's lock away and asks for it back.  At the default
+            # 5 ms a turn a commit unit's ~20 such turns took 100 ms under a
+            # busy serving thread (PERF.md section 6, PR 39); the threads
+            # beside the loop are asked for theirs after 1 ms.
+            sys.setswitchinterval(min(sys.getswitchinterval(), 1e-3))
+        self._recovering: _Recovery | None = None
+        # one record a recovery, in order (``_Recovery.report``): the
+        # numbers of the ``stream/recover`` spans, for a caller with no
+        # tracer
+        self.recoveries: list[dict] = []
         nparts = transport.num_partitions(requests_topic)
         own = (range(nparts) if partitions is None
                else [int(p) for p in partitions])
@@ -243,12 +275,21 @@ class RecommendServer:
 
     def _step(self, poll) -> int:
         in_flight = self._in_flight
+        if self._recovering is not None:
+            self._supervise()
         if self.session is not None:
             # the micro-batches that are due, then the next request batch;
             # with a scorer in flight the session waits for no fold-in
             # behind it (``StreamSession.pump``)
-            self.session.pump(
-                device_busy=in_flight is not None and in_flight.on_device)
+            try:
+                self.session.pump(
+                    device_busy=in_flight is not None and in_flight.on_device)
+            except Exception as e:
+                if self._session_factory is None:
+                    raise
+                self._session_died(e)
+            if self._recovering is not None:
+                self._recovering.pumped(self.session)
         # The spans of one step share its ordinal.  A step that neither
         # polls nor answers anything writes no event — an idle server
         # polls every millisecond.
@@ -326,6 +367,41 @@ class RecommendServer:
             if in_flight is None:
                 return 0
             return self._respond(in_flight, answer)
+
+    # -- the stream task's supervisor ---------------------------------------
+
+    def _session_died(self, error: Exception) -> None:
+        """``pump`` raised: the session is abandoned here and now, its
+        successor is brought up beside the loop."""
+        dead, self.session = self.session, None
+        if self._recovering is not None:  # the successor died catching up
+            self.recoveries.append(self._recovering.report())
+        rec = self._recovering = _Recovery(dead, error)
+        self.metrics.incr("serve_session_deaths")
+        record_event("serve", "session_died",
+                     error=f"{type(error).__name__}: {error}",
+                     stream_step=rec.dropped["stream_step"])
+        rec.thread = threading.Thread(
+            target=rec.bring_up, args=(self._session_factory,),
+            name="cfk-stream-recover", daemon=True)
+        rec.thread.start()
+
+    def _supervise(self) -> None:
+        """Between two steps of a recovery: adopt the successor once it is
+        up, close the record once it has caught up.  A factory that raised
+        ends the loop with its error: a store that cannot be resumed is not
+        survivable."""
+        rec = self._recovering
+        if self.session is None:
+            if rec.thread.is_alive():
+                return
+            if rec.error is not None:
+                self._recovering = None
+                raise rec.error
+            self.session = rec.adopt()
+        elif rec.done:
+            self.recoveries.append(rec.report())
+            self._recovering = None
 
     def _validate(self, reqs, shed, cursors) -> "_Polled":
         """One polled batch as the engine that serves now sees it; the
@@ -453,6 +529,129 @@ class RecommendServer:
                 break
             time.sleep(self.poll_wait_s)
         return served + self.drain()
+
+
+class _Recovery:
+    """One replacement of a stream session, from the exception out of its
+    ``pump`` to its successor having caught up with the log.
+
+    Spans (the tracer's; the same numbers are in ``report()``):
+    ``stream/recover`` from the exception to the successor's first
+    publication of a unit of its own (to its adoption where the log holds
+    nothing for it), with ``units``, ``snapshot_bytes``, ``unit_bytes``
+    read by the resume, ``replayed_records`` (consumed by the dead session
+    and not durable: read from the log again), ``lost_units`` (handed to
+    its writer, or committed in memory, and not renamed at the kill),
+    ``in_flight_batches`` dropped; the resume's own
+    ``stream/recover/restore``, ``.../state``, ``.../republish`` on the
+    recovery thread (``StreamSession._try_resume``); ``stream/recover/catchup``
+    from that first publication to a backlog under one micro-batch
+    (``records``, ``micro_batches``)."""
+
+    def __init__(self, dead, error: Exception) -> None:
+        self.t0_ns = time.perf_counter_ns()
+        self.cause = f"{type(error).__name__}: {error}"
+        self.dropped = dead.abandon()
+        # the dead session's store alone: the session itself (its overlay,
+        # its solved rows) is let go here, not held beside its successor's
+        self._dead_store = dead.manager
+        self.thread: threading.Thread | None = None
+        self.successor = None
+        self.error: BaseException | None = None
+        self.up_s = self.publishing_s = self.caught_up_s = None
+        self.resume_s = None  # the factory's call alone
+        self.lost_units = self.replayed_records = 0
+        self.catchup: dict = {}
+        self.done = False
+
+    def _since_s(self) -> float:
+        return (time.perf_counter_ns() - self.t0_ns) * 1e-9
+
+    def bring_up(self, factory) -> None:
+        """On the recovery thread: wait out the write the dead session's
+        writer had under way (it lands whole or not at all, and must not
+        land over a step of the successor), then resume from the store."""
+        from cfk_tpu.resilience.loop import drain_checkpoints
+
+        try:
+            try:
+                drain_checkpoints(self._dead_store)
+            except Exception:  # the dead writer's error died with it
+                pass
+            t0 = time.perf_counter()
+            self.successor = factory()
+            self.resume_s = time.perf_counter() - t0
+        except BaseException as e:
+            self.error = e
+        self.up_s = self._since_s()
+
+    def adopt(self):
+        """On the serving thread, once the successor is up: what the dead
+        session had and the store had not."""
+        new, dropped = self.successor, self.dropped
+        self.lost_units = dropped["stream_step"] - new.stream_step
+        self.replayed_records = sum(
+            dropped["cursors"][p] - new.consumer.cursors.get(p, 0)
+            for p in dropped["cursors"])
+        self.step0 = new.stream_step
+        self.cursor0 = sum(new.consumer.cursors.values())
+        new.metrics.incr("session_restarts")
+        new.metrics.incr("replayed_records", self.replayed_records)
+        new.metrics.incr("units_discarded", self.lost_units)
+        record_event("serve", "session_replaced", stream_step=self.step0,
+                     lost_units=self.lost_units,
+                     replayed_records=self.replayed_records)
+        return new
+
+    def pumped(self, session) -> None:
+        """After each ``pump`` of the successor: has it published a unit of
+        its own yet, has it caught up."""
+        if session is None:
+            return
+        tracer = get_tracer()
+        published = session.published_step
+        if self.publishing_s is None and (
+                published > self.step0
+                or not (session.backlog() or session.in_flight)):
+            self.publishing_s = self._since_s()
+            if tracer is not None:
+                tracer.complete(
+                    "stream/recover", self.t0_ns, cause=self.cause,
+                    lost_units=self.lost_units,
+                    replayed_records=self.replayed_records,
+                    in_flight_batches=self.dropped["in_flight_batches"],
+                    **{k: session.resume_stats.get(k, 0) for k in (
+                        "units", "snapshot_bytes", "unit_bytes")})
+            self.t1_ns = time.perf_counter_ns()
+        if (self.publishing_s is not None
+                and session.backlog() < session.stream.batch_records):
+            self.caught_up_s = self._since_s()
+            self.catchup = {
+                "records": sum(session.consumer.cursors.values())
+                - self.cursor0,
+                "micro_batches": session.stream_step - self.step0}
+            if tracer is not None:
+                tracer.complete("stream/recover/catchup", self.t1_ns,
+                                **self.catchup)
+            self.done = True
+
+    def report(self) -> dict:
+        stats = getattr(self.successor, "resume_stats", {})
+        return {
+            "cause": self.cause,
+            "killed_at": self.t0_ns * 1e-9,  # on ``time.perf_counter``
+            "up_s": self.up_s, "publishing_s": self.publishing_s,
+            "caught_up_s": self.caught_up_s,
+            "lost_units": self.lost_units,
+            "replayed_records": self.replayed_records,
+            "in_flight_batches": self.dropped["in_flight_batches"],
+            "resume_s": self.resume_s,
+            **{k: stats.get(k, 0) for k in (
+                "units", "snapshot_bytes", "unit_bytes", "republished")},
+            **{k: stats.get(k) for k in (
+                "restore_s", "state_s", "republish_s")},
+            **{"catchup_" + k: v for k, v in self.catchup.items()},
+        }
 
 
 @dataclasses.dataclass

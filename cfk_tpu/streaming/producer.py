@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from cfk_tpu.telemetry import span
 from cfk_tpu.transport.broker import Transport, mod_partition
 from cfk_tpu.transport.serdes import RatingUpdate, encode_rating_update
 
@@ -109,7 +110,11 @@ class StreamProducer:
         array order — the array order IS the stream's logical time).  Uses
         the transport's bulk frame path per partition when available
         (``FileBroker.produce_frames``), so synthetic bench streams of 100k
-        updates don't pay a Python loop of fsync'd appends.
+        updates don't pay a Python loop of fsync'd appends: one append and
+        one fsync a partition a call.  When this returns the run is in the
+        log (on a ``FileBroker(fsync=True)``: on disk).  One
+        ``stream/log/append`` span a call: ``records``, ``bytes``, and
+        ``fsync_ms`` where the transport says how long its sync took.
         """
         users = np.asarray(users, np.int64)
         movies = np.asarray(movies, np.int64)
@@ -127,6 +132,14 @@ class StreamProducer:
         first = self._next_seq
         seqs = first + np.arange(n, dtype=np.int64)
         self._next_seq = first + n
+        with span("stream/log/append", records=n, bytes=28 * n) as sp:
+            self._append(users, movies, ratings, seqs)
+            fsync_ms = getattr(self.transport, "last_fsync_ms", None)
+            if fsync_ms is not None:
+                sp.set(fsync_ms=fsync_ms)
+        return first
+
+    def _append(self, users, movies, ratings, seqs) -> None:
         parts = (users % self.num_partitions).astype(np.int64)
         fast = getattr(self.transport, "produce_frames", None)
         for p in range(self.num_partitions):
@@ -151,4 +164,3 @@ class StreamProducer:
                         )),
                         partition=p,
                     )
-        return first

@@ -8,13 +8,24 @@ ATOMICALLY WITH the consumer's offset cursor: the rows it solved, the cells
 it applied and the cursor are one step of the store, written through
 ``CheckpointManager``'s atomic directory rename plus crc32 verification
 (the PR 3/5 machinery), the cursor in the step's manifest.  A full snapshot
-of the tables is written at bootstrap and at a retrain only; a resume is
-the newest intact snapshot plus the unbroken run of intact units after it.
-There is no instant at which the factors and the cursor can disagree on
-disk; a crash replays exactly the uncommitted log suffix, and because
-micro-batch boundaries are log offsets (``StreamConsumer``), the replayed
-batches — and therefore the recovered factors — are bit-identical to an
-uninterrupted run.
+of the tables is written at bootstrap and at a retrain only (a session on
+an engine's tables keeps neither table in it); every
+``snapshot_every_units`` units the store's second writer thread folds the
+units since the last one into an OVERLAY snapshot (the solved rows, the
+applied cells, the streamed-in users, the cursor: never the base), so a
+resume reads the newest intact overlay snapshot plus the unbroken run of
+intact units after it, and costs what changed, not what exists: no byte of
+the base tables, no record of the log below the cursor.  There is no
+instant at which the factors and the cursor can disagree on disk; a crash
+replays exactly the uncommitted log suffix.
+
+READ COMMITTED: a unit is published to the listeners (the serving engine)
+once its step is renamed into place, never when it is handed to the
+writer, so an ordinal an answer names is a unit of the store, with the
+cursor and the rows the engine was given under it; ``pump`` reads the
+store's durable watermark and never waits on an fsync.  A successor on
+the same store (``RecommendServer``'s supervisor, a restarted process)
+publishes only what the engine lacks.
 
 A batch is two halves (``_begin``: poll, stage, the touched users' lists,
 the hand-over of the fold-in to the device; ``_finish``: fetch, probe,
@@ -52,11 +63,15 @@ side's staleness back in without ever serving a cold model.
 
 from __future__ import annotations
 
+import bisect
 import collections
+import contextlib
 import dataclasses
 import functools
+import os
 import time
 import warnings
+import zlib
 
 import numpy as np
 
@@ -66,9 +81,12 @@ from cfk_tpu.resilience.policy import Overrides, RecoveryPolicy, policy_from_con
 from cfk_tpu.streaming.consumer import StreamConsumer
 from cfk_tpu.streaming.foldin import fold_in_dispatch, fold_in_rows
 from cfk_tpu.streaming.producer import UPDATES_TOPIC
-from cfk_tpu.streaming.state import StreamState, overlay_of
+from cfk_tpu.streaming.state import (
+    CELL, StreamState, cells_array, last_per_cell, overlay_of)
 from cfk_tpu.telemetry import record_event, span
 from cfk_tpu.telemetry.recorder import dump_flight
+from cfk_tpu.transport.checkpoint import (
+    ARRAYS, CheckpointCorruptError, CheckpointManager)
 
 _STREAM_MODEL = "als-stream"
 # The micro-batches one ``pump`` hands to the device while whole ones still
@@ -107,6 +125,11 @@ class StreamConfig:
     # this many rows once streamed-in users outgrow the base table; the
     # rows themselves live in an appended segment that doubles.
     grow_multiple: int = 64
+    # Every this many published units the units since the last overlay
+    # snapshot are folded into a new one, on a writer thread of its own:
+    # a resume then reads one overlay snapshot and at most about this many
+    # units, however long the stream has run.
+    snapshot_every_units: int = 256
 
     def __post_init__(self) -> None:
         if self.batch_records < 1:
@@ -125,6 +148,11 @@ class StreamConfig:
         if self.grow_multiple < 1:
             raise ValueError(
                 f"grow_multiple must be >= 1, got {self.grow_multiple}"
+            )
+        if self.snapshot_every_units < 1:
+            raise ValueError(
+                f"snapshot_every_units must be >= 1, got "
+                f"{self.snapshot_every_units}"
             )
 
 
@@ -172,6 +200,12 @@ class _UserRows:
             self.allocations += 1
         self._rows[slots] = values
 
+    def load(self, ids: np.ndarray, rows: np.ndarray) -> None:
+        """Install the solved rows a store restored (``ids`` distinct), in
+        one copy, on a table nothing has been solved into."""
+        self._slot = dict(zip(ids.tolist(), range(ids.shape[0])))
+        self._rows = np.array(rows, self.base.dtype)
+
     def get(self, rows) -> np.ndarray:
         """Copies of the given rows as they stand (zeros for a row nobody
         has solved yet)."""
@@ -205,6 +239,105 @@ class _Batch:
     # (None, (rows, the fixed rows to probe)) of a route with no hand-over
 
 
+@dataclasses.dataclass
+class _Unit:
+    """One commit unit as the store holds it: handed to the writer by
+    ``_finish`` or restored by a resume, published once it is durable."""
+
+    step: int
+    meta: dict
+    touched: np.ndarray  # int64 [T], the rows solved, ascending
+    rows: np.ndarray  # [T, k], in the store's dtype
+    cells: np.ndarray  # ``CELL`` [C], the cells applied, in their order
+    new_users: np.ndarray  # int64, raw ids of the rows grown, in order
+    cursors: dict
+    appended: tuple = ()  # when the log took each record, where it says
+
+    @classmethod
+    def restored(cls, st, dtype) -> "_Unit":
+        return cls(
+            step=int(st.meta["stream_step"]), meta=dict(st.meta),
+            touched=st.arrays["touched"],
+            rows=np.asarray(st.user_factors).astype(dtype, copy=False),
+            cells=st.arrays["cells"], new_users=st.arrays["new_users"],
+            cursors={int(p): int(o)
+                     for p, o in st.meta.get("offsets", {}).items()})
+
+    @classmethod
+    def snapshot(cls, st, dtype) -> "_Unit":
+        """What a full snapshot holds of the overlay: no solved row (they
+        are in its table), the cells applied so far, the users grown."""
+        return cls(
+            step=st.iteration, meta=dict(st.meta),
+            touched=np.zeros(0, np.int64),
+            rows=np.zeros((0, int(st.meta["rank"])), dtype),
+            cells=st.arrays.get("cells", np.zeros(0, CELL)),
+            new_users=st.arrays.get("new_users", np.zeros(0, np.int64)),
+            cursors={})
+
+    def arrays(self) -> dict:
+        """The payloads of this unit's step beside its rows."""
+        return {"touched": self.touched, "cells": self.cells,
+                "new_users": self.new_users}
+
+    def event(self) -> dict:
+        """What a listener is given of this unit: copies, never views."""
+        return {
+            "touched_rows": self.touched.tolist(),
+            "rows": np.array(self.rows, np.float32),
+            "cells": list(zip(self.cells["row"].tolist(),
+                              self.cells["movie"].tolist())),
+            "cursors": dict(self.cursors),
+            "retrain": False,
+            "stream_step": self.step,
+            "num_users": int(self.meta["users"]),
+        }
+
+
+def _fold(base: _Unit, units) -> _Unit:
+    """``base`` with ``units`` (in commit order) applied, as one unit: each
+    row's last solve, each cell's last write, the users in the order they
+    came, the step, meta and cursor of the last."""
+    ids = np.concatenate([base.touched] + [u.touched for u in units])
+    rows = np.concatenate([base.rows] + [u.rows for u in units])
+    _, at = np.unique(ids[::-1], return_index=True)
+    keep = np.sort(ids.shape[0] - 1 - at)
+    last = units[-1] if units else base
+    return _Unit(
+        step=last.step, meta=last.meta, touched=ids[keep], rows=rows[keep],
+        cells=last_per_cell(
+            np.concatenate([base.cells] + [u.cells for u in units])),
+        new_users=np.concatenate(
+            [base.new_users] + [u.new_users for u in units]),
+        cursors=last.cursors)
+
+
+def _table_digest(table, num_rows: int) -> dict:
+    """What a snapshot records of a base table it does not hold: the rows
+    the stream's base covers, the dtype, and a crc32 over 4,096 evenly
+    spaced rows of them: enough to tell whether a store's units were solved
+    over this table, for a few microseconds a gigabyte (the whole table's
+    crc32 is what a resume used to pay: 9 s for 10.75 GB)."""
+    n = min(int(num_rows), int(table.shape[0]))
+    at = np.unique(
+        np.linspace(0, max(n - 1, 0), min(n, 4096)).astype(np.int64))
+    return {"rows": n, "dtype": str(table.dtype),
+            "crc32": zlib.crc32(np.ascontiguousarray(table[at]).tobytes())
+            if n else 0}
+
+
+@functools.lru_cache(maxsize=None)
+def _side_word_fn():
+    """The fixed side's sentinel probe as ONE jitted function for the
+    process: a successor session probes the table its predecessor probed
+    and traces nothing."""
+    import jax
+
+    return jax.jit(functools.partial(
+        _sentinel.side_word, nonfinite_bit=_sentinel.NONFINITE_M,
+        norm_bit=_sentinel.NORM_M))
+
+
 class StreamSession:
     """Consume rating updates and fold them into live ALS factors.
 
@@ -214,18 +347,19 @@ class StreamSession:
     micro-batch in between commits ONE UNIT — the solved rows, the cells
     applied and the cursor, through the same atomic rename and crc32 — so
     a commit costs what the batch changed, not the tables.  On construction
-    the session either resumes from the store (newest intact snapshot plus
-    the unbroken run of intact units after it, the rating state rebuilt by
-    replaying the log below the committed cursor) or bootstraps from
+    the session either resumes from the store (newest intact overlay
+    snapshot, or the full one, plus the unbroken run of intact units after
+    it; the rating state rebuilt from their cells) or bootstraps from
     ``base_model`` (committing step 0 with a zero cursor).
 
     ``dataset`` is a ``Dataset`` or a ``StreamState`` (``from_csr``: a
     deployment that holds its ratings as a CSR and could never build
     blocks of them).  ``engine`` (a ``ServeEngine`` with a float32 table on
     one device) makes the session fold in against the table the engine
-    serves — one item table on the device, owned by the engine, absent
-    from this store's snapshots — and subscribes the engine to the
-    commits.
+    serves — one item table on the device, one user base on the host, both
+    owned by the engine and absent from this store's snapshots — and
+    subscribes the engine to the commits.  ``listeners`` are subscribed
+    before a resume publishes what the engine lacks.
     """
 
     def __init__(
@@ -241,6 +375,7 @@ class StreamSession:
         preemption_guard=None,
         policy: RecoveryPolicy | None = None,
         engine=None,
+        listeners=(),
     ) -> None:
         from cfk_tpu.config import enable_compile_cache
         from cfk_tpu.utils.metrics import Metrics
@@ -326,9 +461,33 @@ class StreamSession:
         # commit with copies of the solved rows, so a hot-user factor
         # cache (serving.ServeEngine.attach_session) re-serves fold-in
         # updates without ever reading this session's mutable arrays.
-        self._commit_listeners: list = []
+        self._commit_listeners: list = list(listeners)
         if engine is not None:
             engine.attach_session(self)
+        # handed to the writer, not published yet: oldest first
+        self._unpublished: collections.deque[_Unit] = collections.deque()
+        self._durable_step = -1
+        self.published_step = 0  # the last unit the listeners were given
+        self._abandoned = False
+        # Overlay snapshots: a second store under the first, with a writer
+        # thread of its own (a 150 MB fold never stands in front of a unit).
+        # ``_overlay``: what the newest restore point holds of the overlay
+        # (an overlay snapshot's content, or a full snapshot's cells), as
+        # one unit, held to fold the next from; ``_since``: the units
+        # published after it; ``_overlay_job``: the snapshot being written
+        # (its step, its units, where its content lands).
+        self._overlay_store = None
+        directory = getattr(manager, "directory", None)
+        if directory is not None:
+            self._overlay_store = CheckpointManager(
+                os.path.join(directory, "overlay"), keep_last_n=2,
+                max_pending=1)
+        self._overlay: _Unit | None = None
+        self._since: list[_Unit] = []
+        self._overlay_job: tuple | None = None
+        self._snapshot_step = 0  # the full snapshot every unit rests on
+        # what the last resume read and fired (``RecommendServer`` reports it)
+        self.resume_stats: dict = {}
         resumed = self._try_resume()
         if not resumed:
             self._bootstrap(base_model)
@@ -383,13 +542,11 @@ class StreamSession:
         # even a crash before the first batch resumes cleanly.
         self._commit_snapshot(note="bootstrap")
 
-    def _restore_step(self, iteration: int):
+    def _restore_step(self, iteration: int, store=None):
         """One step of the store, or None (warned, flight-recorded) where
         it fails its checksums: resume falls back past it."""
-        from cfk_tpu.transport.checkpoint import CheckpointCorruptError
-
         try:
-            return self.manager.restore(iteration, mmap=True)
+            return (store or self.manager).restore(iteration, mmap=True)
         except CheckpointCorruptError as e:
             warnings.warn(f"skipping corrupt checkpoint: {e}")
             record_event("checkpoint", "corrupt_checkpoint_skipped",
@@ -398,42 +555,89 @@ class StreamSession:
             return None
 
     def _restorable(self):
-        """(snapshot, [units after it]) — the newest intact snapshot of the
-        store and the unbroken run of intact units that follows it, each
-        one stream step after the last; None on an empty store.  A torn
-        unit ends the run: what came after it on disk is the uncommitted
-        suffix, replayed from the log."""
+        """(snapshot, overlay snapshot or None, [units after them]) — the
+        full snapshot every unit rests on, the newest intact overlay
+        snapshot over it, and the unbroken run of intact units that
+        follows, each one stream step after the last; None on an empty
+        store.  A torn unit ends the run: what came after it on disk is the
+        uncommitted suffix, replayed from the log.  A torn overlay snapshot
+        falls back to the one before it and the longer run after that."""
         steps = self.manager.iterations()
+        if not steps:
+            return None
+        snapshot = overlay = None
         units: dict[int, object] = {}
-        snapshot = None
-        for it in reversed(steps):
-            st = self._restore_step(it)
-            if st is None:
-                continue
-            if st.meta.get("kind") == "unit":
-                units[it] = st
-            else:
-                snapshot = st
-                break
+        if self._overlay_store is not None:
+            for it in reversed(self._overlay_store.iterations()):
+                st = self._restore_step(it, self._overlay_store)
+                if st is not None and int(st.meta["base_step"]) in steps:
+                    base = self._restore_step(int(st.meta["base_step"]))
+                    if base is not None and base.meta.get("kind") != "unit":
+                        snapshot, overlay = base, st
+                        break
+        if snapshot is None:
+            for it in reversed(steps):
+                st = self._restore_step(it)
+                if st is None:
+                    continue
+                if st.meta.get("kind") == "unit":
+                    units[it] = st
+                else:
+                    snapshot = st
+                    break
         if snapshot is None:
             return None
         run = []
-        expect = int(snapshot.meta.get("stream_step", snapshot.iteration)) + 1
-        for it in steps[steps.index(snapshot.iteration) + 1:]:
-            st = units.get(it)
-            if st is None or int(st.meta["stream_step"]) != expect:
+        start = (overlay or snapshot).iteration
+        expect = start + 1
+        for it in steps[bisect.bisect_right(steps, start):]:
+            st = units.get(it) or self._restore_step(it)
+            if st is None:
+                break
+            if st.meta.get("kind") != "unit":
+                # a later full snapshot (a retrain's) supersedes all before
+                snapshot, overlay, run = st, None, []
+                expect = st.iteration + 1
+                continue
+            if int(st.meta["stream_step"]) != expect:
                 break
             run.append(st)
             expect += 1
-        return snapshot, run
+        return snapshot, overlay, run
+
+    @contextlib.contextmanager
+    def _timed(self, stage: str):
+        """A stage of the resume on the host's clock, for a caller with no
+        tracer (``resume_stats[stage + "_s"]``)."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.resume_stats[stage + "_s"] = time.perf_counter() - t0
 
     def _try_resume(self) -> bool:
-        found = self._restorable()
-        if found is None:
-            return False
-        snapshot, units = found
+        with self._timed("restore"), span("stream/recover/restore") as sp:
+            found = self._restorable()
+            if found is None:
+                sp.drop()
+                return False
+            snapshot, overlay, restored = found
+            dt = self._factor_dtype()
+            units = [_Unit.restored(st, dt) for st in restored]
+
+            def nbytes(st) -> int:
+                return int(st.user_factors.nbytes + sum(
+                    a.nbytes for a in st.arrays.values()))
+
+            read = {"units": len(units),
+                    "snapshot_bytes": nbytes(snapshot) + (
+                        nbytes(overlay) if overlay is not None else 0),
+                    "unit_bytes": sum(map(nbytes, restored))}
+            self.resume_stats.update(read)
+            sp.set(**read)
         self._pin(snapshot.iteration)
-        meta = dict((units[-1] if units else snapshot).meta)
+        self._snapshot_step = snapshot.iteration
+        meta = dict((restored[-1] if restored else overlay or snapshot).meta)
         if meta.get("model") != _STREAM_MODEL:
             raise ValueError(
                 f"checkpoint store holds model={meta.get('model')!r}, not a "
@@ -450,33 +654,34 @@ class StreamSession:
                 "stream checkpoint was committed against a base dataset "
                 f"with {meta.get('base_users')} users; this dataset has "
                 f"{self.state.num_base_users} — same --data required to "
-                "resume (the rating state replays from it)"
+                "resume (the rating state is the base plus the store's cells)"
             )
-        dt = self._factor_dtype()
-        self._users = _UserRows(
-            np.asarray(snapshot.user_factors).astype(dt, copy=False))
-        if snapshot.meta.get("item_table") == "engine":
-            if self._engine is None:
-                # read-only: the store can be inspected, not folded into
-                self._m = None
-        else:
-            self._set_movie(snapshot.movie_factors)
-        new_users = [int(r) for r in snapshot.meta.get("new_users", [])]
-        events = []
-        for st in units:
-            touched = [int(r) for r in st.meta["touched_rows"]]
-            rows = np.asarray(st.user_factors).astype(dt)
-            self._users.set(touched, rows)
-            new_users += [int(r) for r in st.meta.get("new_users_added", [])]
-            cells = st.meta["cells"]
-            events.append({
-                "touched_rows": touched, "rows": np.array(rows, np.float32),
-                "cells": list(zip(cells["rows"], cells["movies"])),
-                "retrain": False, "stream_step": int(st.meta["stream_step"]),
-                "num_users": int(st.meta["users"]),
-            })
-        meta["new_users"] = new_users
+        with self._timed("state"), span("stream/recover/state") as sp:
+            self._users = _UserRows(self._resumed_base(snapshot, dt))
+            if snapshot.meta.get("item_table") == "engine":
+                if self._engine is None:
+                    # read-only: the store can be inspected, not folded into
+                    self._m = None
+            else:
+                self._set_movie(snapshot.movie_factors)
+            self._overlay = (_Unit.restored(overlay, dt)
+                             if overlay is not None
+                             else _Unit.snapshot(snapshot, dt))
+            now = _fold(self._overlay, units) if units else self._overlay
+            self._users.load(now.touched, now.rows)
+            self.state.load_overlay(now.cells, now.new_users)
+            if self._overlay_store is not None:
+                self._since = list(units)
+            sp.set(rows=int(now.touched.shape[0]),
+                   cells=int(now.cells.shape[0]))
+        if self.state.num_users != int(meta.get("users",
+                                                self.state.num_users)):
+            raise ValueError(
+                f"restored state has {self.state.num_users} users, commit "
+                f"recorded {meta.get('users')} — store and base disagree"
+            )
         self.stream_step = int(meta.get("stream_step", snapshot.iteration))
+        self._durable_step = self.published_step = self.stream_step
         self.quarantined = list(meta.get("quarantined", []))
         ov = meta.get("overrides")
         if ov is not None:
@@ -512,83 +717,57 @@ class StreamSession:
             gap_retries=self.stream.gap_retries,
             gap_wait_s=self.stream.gap_wait_s,
         )
-        self._replay_state(cursors, meta)
-        cells = sum(len(st.meta["cells"]["rows"]) for st in units)
         self.metrics.incr("replayed_units", len(units))
-        self.metrics.incr("replayed_unit_cells", cells)
-        # a restarted server's engine gets the units' rows and cells as
-        # the commits it missed
-        for event in events:
-            self._fire_commit(event)
+        self.metrics.incr("replayed_unit_cells",
+                          sum(int(u.cells.shape[0]) for u in units))
+        self.metrics.incr("restored_cells", int(now.cells.shape[0]))
+        with self._timed("republish"), \
+                span("stream/recover/republish") as sp:
+            # the engine of a restarted server gets everything as the
+            # commits it missed; the engine a predecessor in this process
+            # published to gets only what it lacks
+            have = (int(self._engine.commit_ordinal)
+                    if self._engine is not None else 0)
+            events = []
+            if overlay is not None and have < overlay.iteration:
+                # every unit up to the overlay snapshot, as one commit
+                events.append(self._overlay.event())
+            events += [u.event() for u in units
+                       if u.step > have and u.touched.size]
+            for event in events:
+                self._fire_commit(event)
+            self.resume_stats["republished"] = len(events)
+            sp.set(units=len(events), engine_had=have)
         self.metrics.note(
             "stream_resumed",
-            f"step {self.stream_step} (snapshot {snapshot.iteration} + "
-            f"{len(units)} units), cursor {cursors}, "
-            f"{len(new_users)} streamed-in users",
+            f"step {self.stream_step} (snapshot {snapshot.iteration}"
+            + (f", overlay snapshot {overlay.iteration}" if overlay is not None
+               else "")
+            + f" + {len(units)} units), cursor {cursors}, "
+            f"{len(self.state._new_user_raw)} streamed-in users",
         )
         record_event("stream", "stream_resumed", step=self.stream_step)
         return True
 
-    def _replay_state(self, cursors: dict[int, int], meta: dict) -> None:
-        """Rebuild the rating state = base + log[0, committed cursor).
-
-        Only the STATE is replayed (dedup + upserts) — no solving; the
-        factors came from the checkpoint.  New-user rows are pre-assigned
-        from the committed order, so the rebuilt rows line up with the
-        checkpointed factor rows regardless of how this replay chunks the
-        log (live runs interleave partitions batch by batch; the replay
-        need not re-cut those boundaries just to rebuild a
-        composition-independent state).  QUARANTINED offset ranges (poison
-        batches whose offsets were consumed but whose writes never reached
-        the state) are recorded in every commit and skipped here — the
-        state must stay a pure function of the log MINUS the quarantine,
-        or resume would re-apply the very writes the ladder rejected.
-        """
-        for i, raw in enumerate(meta.get("new_users", [])):
-            self.state._new_user_rows[int(raw)] = self.state.num_base_users + i
-            self.state._new_user_raw.append(int(raw))
-        skip: dict[int, list[tuple[int, int]]] = {}
-        for q in self.quarantined:
-            for p, (qlo, qhi) in q.get("offsets", {}).items():
-                skip.setdefault(int(p), []).append((int(qlo), int(qhi)))
-        replay = StreamConsumer(
-            self.transport, topic=self.stream.topic,
-            cursors={p: 0 for p in cursors},
-            gap_retries=self.stream.gap_retries,
-            gap_wait_s=self.stream.gap_wait_s,
-        )
-        applied = 0
-        for p, hi in sorted(cursors.items()):
-            lo = 0
-            while lo < hi:
-                take = min(hi - lo, 1 << 14)
-                values, _, _, _ = replay._collect_range(p, lo, lo + take)
-                ranges = skip.get(p, ())
-                values = [
-                    v for i, v in enumerate(values)
-                    if not any(qlo <= lo + i < qhi for qlo, qhi in ranges)
-                ]
-                from cfk_tpu.transport.serdes import decode_rating_update
-
-                pending = self.state.stage(
-                    [decode_rating_update(v) for v in values]
-                )
-                if pending.new_user_raw:
-                    raise ValueError(
-                        "stream checkpoint's new-user list does not cover "
-                        f"raw ids {pending.new_user_raw[:4]} found below "
-                        "the committed cursor — store and log disagree"
-                    )
-                self.state.commit(pending)
-                applied += pending.stats.fresh
-                lo += take
-        if self.state.num_users != int(meta.get("users",
-                                                self.state.num_users)):
+    def _resumed_base(self, snapshot, dt) -> np.ndarray:
+        """The user base a resumed session's rows lie over: the snapshot's
+        own table, or, where the snapshot names the engine's, the engine's
+        (held to the digest taken at bootstrap); a store reopened without
+        its engine has no base (its solved rows can be read)."""
+        if snapshot.meta.get("user_base") != "engine":
+            return np.asarray(snapshot.user_factors).astype(dt, copy=False)
+        if self._engine is None:
+            return np.zeros((0, self.config.rank), dt)
+        base = self._engine.user_base()
+        want = snapshot.meta.get("user_base_digest")
+        got = _table_digest(base, self.state.num_base_users)
+        if got != want:
             raise ValueError(
-                f"replayed state has {self.state.num_users} users, commit "
-                f"recorded {meta.get('users')} — store and log disagree"
-            )
-        self.metrics.incr("replayed_updates", applied)
+                "this store's units were solved over another user table "
+                f"than the engine serves (recorded {want}, engine {got}): "
+                "start the engine from the model the stream was started "
+                "on, or give the stream a new directory")
+        return base.astype(dt, copy=False)
 
     # -- the loop ------------------------------------------------------------
 
@@ -641,8 +820,9 @@ class StreamSession:
 
     @property
     def in_flight(self) -> bool:
-        """A micro-batch is polled and not committed yet."""
-        return bool(self._in_flight)
+        """A micro-batch is polled and not published yet (on the device,
+        or handed to the store's writer and not durable)."""
+        return bool(self._in_flight or self._unpublished)
 
     def _fixed(self):
         """The item table a fold-in gathers from."""
@@ -656,12 +836,8 @@ class StreamSession:
     def _table_word(self, fixed) -> int:
         """The sentinel's bits for the fixed side, probed once per table:
         nothing writes a table between two swaps."""
-        import jax
-
         if self._fixed_word[0] is not fixed:
-            word = jax.jit(functools.partial(
-                _sentinel.side_word, nonfinite_bit=_sentinel.NONFINITE_M,
-                norm_bit=_sentinel.NORM_M))(fixed, self.health.norm_limit)
+            word = _side_word_fn()(fixed, self.health.norm_limit)
             self._fixed_word = (fixed, int(np.asarray(word)))
         return self._fixed_word[1]
 
@@ -888,7 +1064,7 @@ class StreamSession:
         return meta
 
     def _save(self, users, movies, meta: dict, note: str | None, *,
-              wait: bool = False) -> int:
+              arrays: dict, wait: bool = False) -> int:
         """One step of the store.  ``wait``: written before this returns,
         straight from the arrays given (no host copy for a background
         writer to own: a snapshot's tables are the size of the host)."""
@@ -898,11 +1074,13 @@ class StreamSession:
             if wait:
                 # a unit of the same step still queued would land on top
                 drain_checkpoints(self.manager)
-                self.manager.save(self.stream_step, users, movies, meta=meta)
+                self.manager.save(self.stream_step, users, movies,
+                                  meta={**meta, ARRAYS: arrays})
             else:
                 save_checkpoint(self.manager, self.stream_step, users,
-                                movies, meta=meta)
-            nbytes = int(users.nbytes + movies.nbytes)
+                                movies, meta={**meta, ARRAYS: arrays})
+            nbytes = int(users.nbytes + movies.nbytes
+                         + sum(a.nbytes for a in arrays.values()))
             sp.set(bytes=nbytes)
         self.metrics.incr("stream_commits")
         record_event("stream", "commit", step=self.stream_step,
@@ -910,23 +1088,38 @@ class StreamSession:
         return nbytes
 
     def _commit_snapshot(self, note: str | None = None) -> None:
-        """Both tables and the cursor as one step: at bootstrap and after
-        a retrain, the two moments at which every row is new.  The item
-        table of a session on an engine's table is the engine's to keep."""
+        """Both tables, the cells applied so far and the cursor as one
+        step: at bootstrap and after a retrain, the two moments at which
+        every row is new.  The tables of a session on an engine are the
+        engine's to keep: the item table it scans, and the user base it
+        holds by reference (recorded by shape, dtype and a sampled digest;
+        no copy is written, none is read back)."""
+        self._publish_durable(wait=True)
         meta = self._meta(self.consumer.cursors, note)
         meta["kind"] = "snapshot"
-        meta["new_users"] = [int(r) for r in self.state._new_user_raw]
+        arrays = {"cells": self.state.overlay_cells(),
+                  "new_users": np.asarray(self.state._new_user_raw, np.int64)}
+        base = self._users.base
         if self._engine is not None:
             meta["item_table"] = "engine"
             movies = np.zeros((0, self.config.rank), np.float32)
+            meta["user_base"] = "engine"
+            meta["user_base_digest"] = _table_digest(
+                base, self.state.num_base_users)
+            users = np.zeros((0, self.config.rank), base.dtype)
         else:
             movies = np.asarray(self.movie_factors)
-        base = self._users.base
-        users = (base if not len(self._users)
-                 and self.state.num_users <= base.shape[0]
-                 else self.user_factors)
-        self._save(users, movies, meta, note, wait=True)
+            users = (base if not len(self._users)
+                     and self.state.num_users <= base.shape[0]
+                     else self.user_factors)
+        self._save(users, movies, meta, note, arrays=arrays, wait=True)
         self._pin(self.stream_step)
+        self._snapshot_step = self._durable_step = self.stream_step
+        # overlay snapshots over an older full snapshot are void
+        self._overlay = _Unit(
+            step=self.stream_step, meta=meta, touched=np.zeros(0, np.int64),
+            rows=users[:0], cursors={}, **arrays)
+        self._since, self._overlay_job = [], None
 
     def _pin(self, step: int) -> None:
         """Keep the snapshot every later unit rests on out of the store's
@@ -938,26 +1131,124 @@ class StreamSession:
 
     def _commit_unit(self, batch, pending, rows) -> int:
         """One micro-batch as one atomic unit of the store: the rows it
-        solved, the cells it applied, the users it added and the cursor
-        after it (rename + crc32, as every step of the store).  ``pending``
-        None: a quarantined batch, whose unit moves the cursor alone."""
+        solved, the cells it applied, the users it added (arrays of the
+        step's payload) and the cursor after it (rename + crc32, as every
+        step of the store).  ``pending`` None: a quarantined batch, whose
+        unit moves the cursor alone.  Returns its bytes; the unit waits in
+        ``_unpublished`` for its rename."""
         meta = self._meta(batch.cursors_after, None)
         meta["kind"] = "unit"
-        writes = {} if pending is None else pending.cell_writes
-        cells = [(row, mv, rt, seq) for row, overlay in writes.items()
-                 for mv, (rt, seq) in overlay.items()]
-        meta["touched_rows"] = (
-            [] if pending is None else [int(r) for r in pending.touched_rows])
-        meta["new_users_added"] = (
-            [] if pending is None else [int(r) for r in pending.new_user_raw])
-        meta["cells"] = {
-            "rows": [int(c[0]) for c in cells],
-            "movies": [int(c[1]) for c in cells],
-            "ratings": [float(c[2]) for c in cells],
-            "seqs": [int(c[3]) for c in cells],
-        }
-        return self._save(rows, np.zeros((0, rows.shape[1]), rows.dtype),
-                          meta, None)
+        unit = _Unit(
+            step=self.stream_step, meta=meta,
+            touched=np.asarray(
+                () if pending is None else pending.touched_rows, np.int64),
+            rows=rows,
+            cells=cells_array({} if pending is None else pending.cell_writes),
+            new_users=np.asarray(
+                () if pending is None else pending.new_user_raw, np.int64),
+            cursors=dict(batch.cursors_after), appended=batch.appended)
+        nbytes = self._save(
+            rows, np.zeros((0, rows.shape[1]), rows.dtype), meta, None,
+            arrays=unit.arrays())
+        self._unpublished.append(unit)
+        return nbytes
+
+    # -- read committed: publication follows the rename ----------------------
+
+    def _publish_durable(self, *, wait: bool = False) -> int:
+        """Publish, in order, every unit whose step is renamed into place;
+        returns how many.  ``wait``: after the writer has drained (the
+        callers that are no serving loop: ``step``, an exit, a retrain).
+        ``pump`` never waits: it reads the store's watermark."""
+        if wait and self._unpublished:
+            drain_checkpoints(self.manager)
+        take = getattr(self.manager, "take_durable", None)
+        if take is None:
+            # a store with no writer thread: durable when ``save`` returned
+            self._durable_step = self.stream_step
+        else:
+            for step in take():
+                self._durable_step = max(self._durable_step, step)
+        published = 0
+        while (self._unpublished
+               and self._unpublished[0].step <= self._durable_step):
+            self._publish(self._unpublished.popleft())
+            published += 1
+        return published
+
+    def _publish(self, unit: _Unit) -> None:
+        self.published_step = unit.step
+        if unit.touched.size:
+            # the COMMITTED representation — after the dtype cast, so a
+            # bf16-dtype session's listeners cache exactly what a
+            # post-crash engine would restore from the store (not the
+            # pre-cast f32 solve)
+            with span("stream/batch/publish", ordinal=unit.step) as pub:
+                self._fire_commit(unit.event())
+                stamps = [t for t in unit.appended if t]
+                if stamps:
+                    # from the log's taking of each rating to the
+                    # listeners' return: what a reader of it waits
+                    now = time.perf_counter()
+                    waits = sorted((now - t) * 1e3 for t in stamps)
+                    pub.set(visible_ms_p50=waits[len(waits) // 2],
+                            visible_ms_max=waits[-1])
+        if self._overlay_store is not None:
+            self._since.append(unit)
+            self._snapshot_overlay()
+
+    def _snapshot_overlay(self) -> None:
+        """Every ``snapshot_every_units`` published units, fold the units
+        since the last overlay snapshot into the next one, on the overlay
+        store's writer thread: from arrays this thread only holds by
+        reference, so it pays neither the fold nor a copy.  Every unit
+        folded is durable (it was published), so the snapshot never holds
+        what the store's units do not."""
+        store = self._overlay_store
+        if self._overlay_job is not None:
+            step, count, landed = self._overlay_job
+            if "content" in landed and step in store.take_durable():
+                self._overlay, self._since = landed["content"], self._since[count:]
+                self._overlay_job = None
+        if (self._overlay_job is not None
+                or len(self._since) < self.stream.snapshot_every_units):
+            return
+        units, base = list(self._since), self._overlay
+        meta = dict(units[-1].meta, kind="overlay",
+                    base_step=self._snapshot_step)
+        landed: dict = {}
+
+        def build():
+            with span("stream/snapshot/overlay", units=len(units)) as sp:
+                now = landed["content"] = _fold(base, units)
+                sp.set(bytes=int(now.rows.nbytes + now.cells.nbytes))
+            return (now.rows, now.rows[:0], {**meta, ARRAYS: now.arrays()})
+
+        self._overlay_job = (units[-1].step, len(units), landed)
+        store.submit(units[-1].step, build)
+
+    def abandon(self) -> dict:
+        """What a kill of the stream task leaves of this session: nothing.
+        The micro-batches polled or on the device are dropped, the units
+        handed to the writer and not renamed yet are discarded
+        (``CheckpointManager.abort_pending``), nothing more is published.
+        What lives on is the log, the store's renamed steps and whatever
+        the listeners were given; a successor on the same store replays the
+        rest from the log.  Returns what was dropped."""
+        dropped = {"in_flight_batches": len(self._in_flight),
+                   "unpublished_units": len(self._unpublished),
+                   "stream_step": self.stream_step,
+                   "cursors": dict(self.consumer.cursors)}
+        self._in_flight.clear()
+        self._unpublished.clear()
+        self._abandoned = True
+        for store in (self.manager, self._overlay_store):
+            abort = getattr(store, "abort_pending", None)
+            if abort is not None:
+                abort()
+        record_event("stream", "session_abandoned", **{
+            k: v for k, v in dropped.items() if k != "cursors"})
+        return dropped
 
     def add_commit_listener(self, fn) -> None:
         """Subscribe ``fn(event: dict)`` to every durable commit.
@@ -967,9 +1258,10 @@ class StreamSession:
         factor rows), ``cells`` [(user_row, movie_row), ...] (the rated
         cells the batch applied), ``num_users``, ``stream_step``; a warm
         retrain instead fires ``retrain=True`` with full ``user_factors``/
-        ``movie_factors`` snapshots.  Fired AFTER the factor+cursor commit
-        is handed to the (async) writer — a request served after the
-        listener returns reflects the folded-in factors."""
+        ``movie_factors`` snapshots.  Fired once the factor+cursor commit
+        is renamed into place in the store (read committed), in commit
+        order — a request served after the listener returns reflects the
+        folded-in factors, and names an ordinal the store holds."""
         self._commit_listeners.append(fn)
 
     def _fire_commit(self, event: dict) -> None:
@@ -995,14 +1287,21 @@ class StreamSession:
         """Process ONE micro-batch, from its poll to its commit; returns
         its summary, or None when caught up with the log.  (Batches that
         ``pump`` left on the device are committed first.)"""
+        self._alive()
         while self._in_flight:
             with span("stream/batch") as sp:
                 self._finish(sp)
+                self._publish_durable(wait=True)
         with span("stream/batch") as sp:
             if not self._begin():
-                sp.drop()
+                if not self._publish_durable(wait=True):
+                    sp.drop()
                 return None
-            return self._finish(sp)
+            summary = self._finish(sp)
+            # committed means durable: the unit is published before this
+            # returns (``pump`` is the loop that does not wait)
+            self._publish_durable(wait=True)
+            return summary
 
     def pump(self, *, device_busy: bool = False) -> int:
         """Advance the stream from inside another loop — the request
@@ -1020,13 +1319,21 @@ class StreamSession:
         in the log behind the one just handed over (a stall, a burst) the
         call hands over another, ``_PUMP_DEPTH`` at most, each staged over
         the ones before it.  With nothing else on the device the call
-        commits what it handed over before it returns."""
+        commits what it handed over before it returns.
+
+        A unit is published once the store's writer has renamed it into
+        place (read committed): each call publishes what has landed since
+        the one before, and with a scorer in flight never waits for the
+        writer; with nothing else on the device it sees its units into the
+        store before it returns."""
+        self._alive()
         done = begun = 0
         ready = len(self._in_flight)  # an earlier call's: run by now
         more = True
         while ready or more:
             # one span: the back half of a batch, the front half of another
             with span("stream/batch") as sp:
+                published = self._publish_durable()
                 finished = ready > 0
                 if finished:
                     self._finish(sp)
@@ -1037,12 +1344,25 @@ class StreamSession:
                              >= self.stream.batch_records)
                         and self._begin())
                 begun += more
-                if not (finished or more):
+                if not (finished or more or published):
                     sp.drop()
         while self._in_flight and not device_busy:
             with span("stream/batch") as sp:
+                self._publish_durable()
                 self._finish(sp)
             done += 1
+        if self._unpublished and not device_busy:
+            # nothing else on the device, so no request batch is held up
+            # behind this: see the units into the store and publish them,
+            # as the call saw their fold-ins off the device (a lightly
+            # loaded server polls its next requests over what it just
+            # committed, a stalled one over what the stall held back)
+            self._publish_durable(wait=True)
+        elif self._unpublished and not (done or begun):
+            # nothing to do but see the writer's rename: a caller that
+            # spins on this call would hold the interpreter against the
+            # very thread it waits for
+            time.sleep(0.0005)
         return done
 
     def _begin(self) -> bool:
@@ -1192,38 +1512,18 @@ class StreamSession:
             sp.set(entities=fold.entities, width=fold.width, rank=fold.rank,
                    gather_bytes=fold.gather_bytes,
                    operand_bytes=fold.operand_bytes)
-        if pending is not None and pending.touched_rows:
-            # publish the COMMITTED representation — after the dtype cast,
-            # so a bf16-dtype session's listeners cache exactly what a
-            # post-crash engine would restore from the store (not the
-            # pre-cast f32 solve)
-            with span("stream/batch/publish",
-                      ordinal=self.stream_step) as pub:
-                self._fire_commit({
-                    "touched_rows": [int(r) for r in pending.touched_rows],
-                    "rows": np.array(rows, np.float32),
-                    "cells": [
-                        (int(row), int(mv))
-                        for row, overlay in pending.cell_writes.items()
-                        for mv in overlay
-                    ],
-                    "cursors": dict(batch.cursors_after),
-                    "retrain": False,
-                })
-                stamps = [t for t in batch.appended if t]
-                if stamps:
-                    # from the log's taking of each rating to the
-                    # listeners' return: what a reader of it waits
-                    now = time.perf_counter()
-                    waits = sorted((now - t) * 1e3 for t in stamps)
-                    pub.set(visible_ms_p50=waits[len(waits) // 2],
-                            visible_ms_max=waits[-1])
         summary["stream_step"] = self.stream_step
         if (self.stream.retrain_every is not None
                 and self.stream_step % self.stream.retrain_every == 0):
             self.retrain()
             self._hand_over_again()
         return summary
+
+    def _alive(self) -> None:
+        if self._abandoned:
+            raise RuntimeError(
+                "this session was abandoned: a successor on its store "
+                "carries the stream on")
 
     def run(self, *, max_batches: int | None = None, follow: bool = False,
             before_batch=None):
@@ -1260,12 +1560,16 @@ class StreamSession:
         finally:
             # Same exit contract as the training loop: only committed
             # steps are left behind for the next reader.
+            if not self._abandoned:
+                self._publish_durable(wait=True)
             drain_checkpoints(self.manager)
+            drain_checkpoints(self._overlay_store)
         return self.model()
 
     def _evict(self) -> None:
         """Eviction: the last commit already carries the cursor — drain
         the writer so it is durably on disk, then return resumable."""
+        self._publish_durable(wait=True)
         drain_checkpoints(self.manager)
         record_event("signal", "stream_evicted", step=self.stream_step,
                      signal=self.guard.signal_name)

@@ -9,10 +9,10 @@ ratings and every applied ``(user, movie, rating, seq)`` upsert, with
 last-seq-wins per (user, movie) cell (equal seq = a retried append,
 dropped).
 
-Nothing here is persisted: the state is a deterministic function of (base
-dataset, the updates-log prefix below the committed cursor), so crash
-recovery rebuilds it by replaying the log — the factors + cursor commit
-(``cfk_tpu.streaming.session``) is the only durable artifact.
+Nothing here is persisted by the state itself: it is a deterministic
+function of (base dataset, the cells every commit unit of the store applied),
+so crash recovery rebuilds it from the store's own cells (``CELL`` arrays, in
+commit order: ``load_overlay``), never from the log below the cursor.
 
 Application is TRANSACTIONAL: ``stage()`` computes the post-batch view
 without mutating anything, the session solves and probes against it, and
@@ -37,6 +37,31 @@ import numpy as np
 from cfk_tpu.transport.serdes import RatingUpdate
 
 _BASE_SEQ = -1
+
+# One applied cell as the store holds it: a commit unit's, a snapshot's.
+CELL = np.dtype([("row", "<i8"), ("movie", "<i4"), ("rating", "<f4"),
+                 ("seq", "<i8")])
+
+
+def cells_array(cell_writes: dict) -> np.ndarray:
+    """A staged batch's writes (row -> {movie_row: (rating, seq)}) as a
+    ``CELL`` array, in the order the dicts hold them."""
+    flat = [(row, mv, rt, seq) for row, overlay in cell_writes.items()
+            for mv, (rt, seq) in overlay.items()]
+    return np.array(flat, CELL) if flat else np.zeros(0, CELL)
+
+
+def last_per_cell(cells: np.ndarray) -> np.ndarray:
+    """``cells`` (in the order they were applied) with one entry a (row,
+    movie): the last applied, sorted by row then movie."""
+    if not cells.shape[0]:
+        return cells
+    key = (cells["row"].astype(np.int64) << 32) | cells["movie"].astype(np.int64)
+    order = np.argsort(key, kind="stable")
+    ks = key[order]
+    last = np.ones(ks.shape[0], bool)
+    last[:-1] = ks[1:] != ks[:-1]
+    return cells[order[last]]
 
 
 @dataclasses.dataclass
@@ -118,6 +143,16 @@ class StreamState:
         self._init(indptr, movies, ratings, num_movies=int(num_movies),
                    user_raw=None, movie_raw=None)
         return self
+
+    def fresh(self) -> "StreamState":
+        """A state nothing has been applied to, over this one's base (the
+        same arrays, by reference): what a successor session starts from
+        before it loads the store's overlay."""
+        new = type(self).__new__(type(self))
+        new._init(self._base_indptr, self._base_movies, self._base_ratings,
+                  num_movies=self.num_movies, user_raw=self._base_user_raw,
+                  movie_raw=self._movie_raw)
+        return new
 
     def _init(self, indptr, movies, ratings, *, num_movies, user_raw,
               movie_raw) -> None:
@@ -322,6 +357,39 @@ class StreamState:
             cell_writes=writes,
             stats=stats,
         )
+
+    def load_overlay(self, cells: np.ndarray, new_users) -> None:
+        """Install what a store's commits applied over the base, on a state
+        nothing has been applied to yet: ``cells`` (``CELL``, in the order
+        they were applied, a later cell of a (row, movie) replacing an
+        earlier one) and the raw ids of the streamed-in users in the order
+        their rows were grown.  The cells are cut to one a (row, movie) by
+        a sort, then entered in one pass: the state a live session would
+        hold, so the batches after it cost what they cost that one."""
+        if self._delta or self._new_user_raw:
+            raise ValueError("load_overlay needs a state nothing was applied to")
+        for raw in np.asarray(new_users, np.int64).tolist():
+            self._new_user_rows[raw] = self.num_users
+            self._new_user_raw.append(raw)
+        cells = last_per_cell(np.asarray(cells, CELL))
+        if not cells.shape[0]:
+            return
+        delta = self._delta
+        for row, mv, rt, seq in zip(
+                cells["row"].tolist(), cells["movie"].tolist(),
+                cells["rating"].tolist(), cells["seq"].tolist()):
+            mine = delta.get(row)
+            if mine is None:
+                mine = delta[row] = {}
+            mine[mv] = (rt, seq)
+        self.applied_seq_high = max(self.applied_seq_high,
+                                    int(cells["seq"].max()))
+
+    def overlay_cells(self) -> np.ndarray:
+        """Every cell applied over the base, one a (row, movie), sorted by
+        row then item, as a ``CELL`` array: what a snapshot of the store
+        keeps of this state."""
+        return last_per_cell(cells_array(self._delta))
 
     def commit(self, pending: PendingApply) -> None:
         """Fold a staged batch into the applied state."""

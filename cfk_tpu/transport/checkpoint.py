@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import atexit
 import dataclasses
+import io
 import json
 import os
 import shutil
@@ -29,7 +30,29 @@ import numpy as np
 from cfk_tpu.telemetry.trace import span
 
 _MANIFEST = "manifest.json"
+_ARRAYS = "arrays.bin"
+# A payload up to this size is read back with one read and parsed in memory
+# (a stream's commit unit is four small files, and every reopening of one is
+# a turn of the interpreter's lock given away to the thread beside); a larger
+# one (a table) is checked through its file, then mapped or loaded from it.
+_SMALL = 1 << 26
+# A ``.npy`` payload up to this size is serialized in memory and written and
+# synced through one descriptor.  Past it (an overlay snapshot's rows, a
+# table) the array goes to its file by ``np.save``, which holds the
+# interpreter's lock for no copy: a writer thread that serialized 50 MB in
+# memory held the serving thread beside it for 100 ms (PERF.md section 6,
+# PR 39).
+_INLINE = 1 << 20
+# A step's further payloads ride in its ``meta`` under this key, as a dict of
+# arrays, and are written as one checksummed ``arrays.bin`` beside the
+# factors (their raw bytes end to end; names, dtypes, shapes and offsets in
+# the manifest under ``arrays``): every ``save`` override and wrapper
+# that passes ``meta`` through carries them unchanged.
+ARRAYS = "__arrays__"
 _STEP_PREFIX = "step_"
+# what a manifest holds of its own, beside the caller's ``meta``
+_MANIFEST_OWN = ("iteration", "user_shape", "movie_shape", "dtype", "crc32",
+                 "arrays")
 
 # Managers with a live background writer, drained at interpreter exit so a
 # process that finishes (or is SIGTERM'd into a clean shutdown) never leaves
@@ -312,6 +335,55 @@ def _crc32_file(path: str) -> int:
             crc = zlib.crc32(chunk, crc)
 
 
+def _write_synced(path: str, parts) -> int:
+    """The buffers of ``parts`` end to end into a new file, synced before
+    it is closed; the crc32 of the whole.  Nothing is copied on the way."""
+    crc = 0
+    with open(path, "wb") as f:
+        for part in parts:
+            f.write(part)
+            crc = zlib.crc32(part, crc)
+        f.flush()
+        os.fsync(f.fileno())
+    return crc
+
+
+def _save_array(path: str, arr: np.ndarray) -> int:
+    """One ``.npy`` payload, on disk and synced; its crc32."""
+    if arr.nbytes > _INLINE:
+        np.save(path, arr)
+        _fsync_file(path)
+        return _crc32_file(path)
+    buf = io.BytesIO()
+    np.save(buf, arr)
+    return _write_synced(path, [buf.getbuffer()])
+
+
+def _pack_arrays(arrays: dict) -> tuple[list, dict]:
+    """(the arrays' raw bytes as views of them, to be written end to end;
+    {name: dtype, shape, offset})."""
+    index, parts, at = {}, [], 0
+    for name, arr in arrays.items():
+        arr = np.ascontiguousarray(arr)
+        index[name] = {
+            "dtype": arr.dtype.descr if arr.dtype.names else arr.dtype.str,
+            "shape": list(arr.shape), "offset": at}
+        parts.append(arr.reshape(-1).view(np.uint8))
+        at += arr.nbytes
+    return parts, index
+
+
+def _unpack_arrays(data: bytes, index: dict) -> dict:
+    out = {}
+    for name, at in index.items():
+        dtype = (np.dtype([tuple(f) for f in at["dtype"]])
+                 if isinstance(at["dtype"], list) else np.dtype(at["dtype"]))
+        count = int(np.prod(at["shape"]))
+        out[name] = np.frombuffer(data, dtype, count, at["offset"]).reshape(
+            at["shape"])
+    return out
+
+
 def should_save(done: int, every: int, total: int) -> bool:
     """Save cadence: every ``every`` completed iterations, and always at the end."""
     if every < 1:
@@ -325,6 +397,14 @@ class CheckpointState:
     user_factors: np.ndarray
     movie_factors: np.ndarray
     meta: dict
+    # a step's further payloads by name (``meta[ARRAYS]`` of its save): one
+    # checksummed ``arrays.bin`` beside the factors, absent where none
+    arrays: dict = dataclasses.field(default_factory=dict)
+
+
+class _WriteAborted(Exception):
+    """The write under way was discarded by ``abort_pending`` before its
+    rename: its step never existed."""
 
 
 class CheckpointManager:
@@ -360,6 +440,13 @@ class CheckpointManager:
     caller's hand-over to durable).  A ``save_async`` that waits for room
     writes ``checkpoint/backpressure`` on the caller's thread (``pending``,
     ``max_pending``); one that does not wait writes nothing.
+
+    What is on disk is told apart from what was handed over:
+    ``take_durable()`` returns the steps renamed into place since it was
+    last asked (a caller that must not show a step before it is durable
+    polls it and never waits on an fsync); ``abort_pending()`` is what a
+    kill does to the writer: queued jobs are dropped and the job being
+    written is discarded unless its rename is already under way.
     """
 
     def __init__(
@@ -392,6 +479,13 @@ class CheckpointManager:
         self._writer_error: BaseException | None = None
         # what the last ``save`` of each thread wrote: (bytes, fsyncs)
         self._wrote = threading.local()
+        # steps renamed into place and not yet reported (``take_durable``)
+        self._durable: list[int] = []
+        # bumped by ``abort_pending``: a writer job taken up under an older
+        # value discards its step instead of renaming it
+        self._abort_gen = 0
+        # payload bytes ``verify`` has checksummed: what a restore read
+        self.bytes_verified = 0
         os.makedirs(directory, exist_ok=True)
 
     def _step_dir(self, iteration: int) -> str:
@@ -429,7 +523,23 @@ class CheckpointManager:
         ``async_write=False`` (the A/B baseline) this is exactly ``save``.
         """
         hu, hm = _host_snapshot(user_factors), _host_snapshot(movie_factors)
+        meta = dict(meta or {})
+        if meta.get(ARRAYS):
+            meta[ARRAYS] = {k: np.array(v, copy=True)
+                            for k, v in meta[ARRAYS].items()}
+        self._enqueue(iteration, lambda: (hu, hm, meta))
+
+    def submit(self, iteration: int, build) -> None:
+        """Enqueue a step whose payload is made on the writer thread:
+        ``build()`` returns ``(user_factors, movie_factors, meta)``.
+        For a step folded from what the caller already holds by reference
+        (a stream's overlay snapshot): the caller's thread pays neither the
+        fold nor a copy."""
+        self._enqueue(iteration, build)
+
+    def _enqueue(self, iteration: int, build) -> None:
         if not self.async_write:
+            hu, hm, meta = build()
             self.save(iteration, hu, hm, meta=meta)
             return
         _LIVE_MANAGERS.add(self)
@@ -443,7 +553,7 @@ class CheckpointManager:
                     while len(self._jobs) + self._inflight >= self.max_pending:
                         self._queue_nonfull.wait()
                         self._raise_writer_error_locked()
-            self._jobs.append((iteration, hu, hm, dict(meta or {}), queued))
+            self._jobs.append((iteration, build, queued, self._abort_gen))
             if self._writer_thread is None or not self._writer_thread.is_alive():
                 self._writer_thread = threading.Thread(
                     target=self._writer_loop,
@@ -469,6 +579,29 @@ class CheckpointManager:
             self._raise_writer_error_locked()
         return True
 
+    def take_durable(self) -> list[int]:
+        """The steps renamed into place (``save`` past its directory
+        fsync) since this was last asked, in the order they landed."""
+        with self._lock:
+            done, self._durable = self._durable, []
+        return done
+
+    def abort_pending(self) -> int:
+        """What a kill of the process does to the writer, without the
+        kill: every queued job is dropped, and the job being written is
+        discarded before its rename (one whose rename is already under
+        way lands whole: a step is on disk entirely or not at all, as
+        ever).  Returns the queued jobs dropped.  Does not wait;
+        ``wait_pending`` returns once the writer is idle."""
+        with self._lock:
+            dropped = len(self._jobs)
+            self._jobs.clear()
+            self._abort_gen += 1
+            self._queue_nonfull.notify_all()
+            if not self._inflight:
+                self._queue_empty.notify_all()
+        return dropped
+
     def _raise_writer_error_locked(self) -> None:
         if self._writer_error is not None:
             err, self._writer_error = self._writer_error, None
@@ -483,19 +616,26 @@ class CheckpointManager:
                     # the next save_async (no join-at-shutdown bookkeeping).
                     self._writer_thread = None
                     return
-                iteration, hu, hm, meta, queued = self._jobs.popleft()
+                iteration, build, queued, gen = self._jobs.popleft()
                 self._inflight += 1
                 self._queue_nonfull.notify_all()
             try:
                 with span("checkpoint/write", step=iteration,
                           queued_ms=(time.perf_counter() - queued) * 1e3
                           ) as sp:
+                    hu, hm, meta = build()
                     if "kind" in meta:
                         sp.set(kind=meta["kind"])
                     self._wrote.last = (0, 0)
-                    self.save(iteration, hu, hm, meta=meta)
+                    self._wrote.abort_gen = gen
+                    try:
+                        self.save(iteration, hu, hm, meta=meta)
+                    finally:
+                        self._wrote.abort_gen = None
                     nbytes, fsyncs = self._wrote.last
                     sp.set(bytes=nbytes, fsyncs=fsyncs)
+            except _WriteAborted:
+                pass
             except BaseException as e:
                 with self._lock:
                     if self._writer_error is None:
@@ -528,6 +668,8 @@ class CheckpointManager:
         movie_factors,
         meta: dict | None = None,
     ) -> str:
+        meta = dict(meta or {})
+        arrays = meta.pop(ARRAYS, None)
         u = np.asarray(user_factors)
         m = np.asarray(movie_factors)
         stored_dtype = str(u.dtype)
@@ -538,36 +680,45 @@ class CheckpointManager:
             m = m.astype(np.float32)
         tmp = tempfile.mkdtemp(dir=self.directory, prefix=".tmp_")
         try:
-            np.save(os.path.join(tmp, "user.npy"), u)
-            np.save(os.path.join(tmp, "movie.npy"), m)
+            # each payload synced as it is written, all of them before
+            # the manifest that names their checksums
+            crc = {"user.npy": _save_array(os.path.join(tmp, "user.npy"), u),
+                   "movie.npy": _save_array(os.path.join(tmp, "movie.npy"), m)}
+            extra_bytes, index = 0, None
+            if arrays:
+                # one file for all of them: one more fsync a step, not one
+                # more a payload
+                parts, index = _pack_arrays(arrays)
+                crc[_ARRAYS] = _write_synced(os.path.join(tmp, _ARRAYS), parts)
+                extra_bytes = sum(part.nbytes for part in parts)
             manifest = {
                 "iteration": iteration,
                 "user_shape": list(u.shape),
                 "movie_shape": list(m.shape),
                 "dtype": stored_dtype,
-                # Content checksums of the npy payloads: the atomic rename
+                # Content checksums of the payloads: the atomic rename
                 # makes half-written step dirs impossible, but not silent
                 # corruption *after* commit (torn page on power loss, bad
                 # sector, an operator's stray truncate) — restore verifies
                 # these and falls back to the previous complete step.
-                "crc32": {
-                    name: _crc32_file(os.path.join(tmp, name))
-                    for name in ("user.npy", "movie.npy")
-                },
-                **(meta or {}),
+                "crc32": crc,
+                **meta,
             }
+            if index is not None:
+                manifest["arrays"] = index
             text = json.dumps(manifest)
             with open(os.path.join(tmp, _MANIFEST), "w") as f:
                 f.write(text)
                 f.flush()
                 os.fsync(f.fileno())
-            # fsync payloads + the directories on both sides of the rename:
-            # the emergency (preemption) save path relies on a committed
-            # step surviving an immediately-following power-off/kill, not
-            # just an orderly process exit.
-            for name in ("user.npy", "movie.npy"):
-                _fsync_file(os.path.join(tmp, name))
+            # payloads and manifest are synced; now the directories on both
+            # sides of the rename: the emergency (preemption) save path
+            # relies on a committed step surviving an immediately-following
+            # power-off/kill, not just an orderly process exit.
             _fsync_dir(tmp)
+            gen = getattr(self._wrote, "abort_gen", None)
+            if gen is not None and gen != self._abort_gen:
+                raise _WriteAborted(iteration)
             final = self._step_dir(iteration)
             if os.path.exists(final):
                 shutil.rmtree(final)
@@ -576,7 +727,10 @@ class CheckpointManager:
             # durable from here: the payloads' and the manifest's bytes, and
             # the fsyncs asked for (the manifest's, the two payloads', the
             # directories' on both sides of the rename)
-            self._wrote.last = (u.nbytes + m.nbytes + len(text), 1 + 2 + 2)
+            self._wrote.last = (u.nbytes + m.nbytes + extra_bytes + len(text),
+                                1 + len(crc) + 2)
+            with self._lock:
+                self._durable.append(iteration)
             self._retain(iteration)
             # Flight-record the commit (post-rename — the event means "this
             # step is durably on disk", the fact an incident reader needs).
@@ -612,32 +766,52 @@ class CheckpointManager:
         payload must match byte-for-byte.  Checksum-less legacy steps
         pass with only the parse check.
         """
+        manifest = self._manifest(iteration)
+        for name, want in (manifest.get("crc32") or {}).items():
+            self._read_checked(iteration, name, want)
+
+    def _read_checked(self, iteration: int, name: str,
+                      want: int | None) -> bytes | None:
+        """One payload held to its manifest checksum (``want`` None: a
+        checksum-less legacy step passes).  A small payload is read once
+        and its bytes returned; a large one is checked through its file and
+        None returned: the caller maps or loads it from there."""
+        path = os.path.join(self._step_dir(iteration), name)
+        try:
+            size = os.path.getsize(path)
+            if size > _SMALL:
+                got, data = _crc32_file(path), None
+            else:
+                with open(path, "rb") as f:
+                    data = f.read()
+                got = zlib.crc32(data)
+        except OSError as e:
+            raise CheckpointCorruptError(
+                f"checkpoint step {iteration} is missing payload "
+                f"{name!r} ({e})"
+            ) from None
+        self.bytes_verified += size
+        if want is not None and got != want:
+            raise CheckpointCorruptError(
+                f"checkpoint step {iteration} payload {name!r} fails "
+                f"its manifest checksum (crc32 {got:#010x} != recorded "
+                f"{want:#010x}); the file is torn or corrupted — "
+                f"delete {self._step_dir(iteration)} or restore an earlier "
+                "step"
+            )
+        return data
+
+    def _manifest(self, iteration: int) -> dict:
         step = self._step_dir(iteration)
         try:
             with open(os.path.join(step, _MANIFEST)) as f:
-                manifest = json.load(f)
+                return json.load(f)
         except (OSError, json.JSONDecodeError) as e:
             raise CheckpointCorruptError(
                 f"checkpoint step {iteration} in {self.directory} has an "
                 f"unreadable manifest ({e}); the write was torn — delete "
                 f"{step} or restore an earlier step"
             ) from None
-        for name, want in (manifest.get("crc32") or {}).items():
-            path = os.path.join(step, name)
-            try:
-                got = _crc32_file(path)
-            except OSError as e:
-                raise CheckpointCorruptError(
-                    f"checkpoint step {iteration} is missing payload "
-                    f"{name!r} ({e})"
-                ) from None
-            if got != want:
-                raise CheckpointCorruptError(
-                    f"checkpoint step {iteration} payload {name!r} fails "
-                    f"its manifest checksum (crc32 {got:#010x} != recorded "
-                    f"{want:#010x}); the file is torn or corrupted — "
-                    f"delete {step} or restore an earlier step"
-                )
 
     def latest_valid_iteration(self) -> int | None:
         """Newest step that passes integrity verification; corrupt steps
@@ -674,8 +848,7 @@ class CheckpointManager:
         return {
             k: v
             for k, v in manifest.items()
-            if k not in ("iteration", "user_shape", "movie_shape", "dtype",
-                         "crc32")
+            if k not in _MANIFEST_OWN
         }
 
     def restore(self, iteration: int | None = None, *,
@@ -689,15 +862,16 @@ class CheckpointManager:
                 raise FileNotFoundError(
                     f"no intact checkpoints in {self.directory}"
                 )
-        else:
-            self.verify(iteration)
         step = self._step_dir(iteration)
-        with open(os.path.join(step, _MANIFEST)) as f:
-            manifest = json.load(f)
+        manifest = self._manifest(iteration)
+        crc = manifest.get("crc32") or {}
+
         def load(name):
-            path = os.path.join(step, name)
-            big = mmap and os.path.getsize(path) > (1 << 26)
-            return np.load(path, mmap_mode="r" if big else None)
+            data = self._read_checked(iteration, name, crc.get(name))
+            if data is not None:
+                return np.load(io.BytesIO(data))
+            return np.load(os.path.join(step, name),
+                           mmap_mode="r" if mmap else None)
 
         u, m = load("user.npy"), load("movie.npy")
         want_dtype = manifest.get("dtype", "float32")
@@ -709,9 +883,16 @@ class CheckpointManager:
         meta = {
             k: v
             for k, v in manifest.items()
-            if k not in ("iteration", "user_shape", "movie_shape", "dtype",
-                         "crc32")
+            if k not in _MANIFEST_OWN
         }
+        extra = {}
+        if "arrays" in manifest:
+            data = self._read_checked(iteration, _ARRAYS, crc.get(_ARRAYS))
+            if data is None:  # past ``_SMALL``: checked above, read now
+                with open(os.path.join(step, _ARRAYS), "rb") as f:
+                    data = f.read()
+            extra = _unpack_arrays(data, manifest["arrays"])
         return CheckpointState(
-            iteration=manifest["iteration"], user_factors=u, movie_factors=m, meta=meta
+            iteration=manifest["iteration"], user_factors=u, movie_factors=m,
+            meta=meta, arrays=extra,
         )

@@ -19,6 +19,7 @@ import json
 import os
 import shutil
 import struct
+import time
 from typing import Iterator
 
 from cfk_tpu.transport.broker import Record, mod_partition
@@ -79,6 +80,13 @@ class FileBroker:
         self._counts: dict[tuple[str, int], int] = {}
         self._bytes: dict[tuple[str, int], int] = {}
         self._index: dict[tuple[str, int], list[int]] = {}
+        # where the last ``consume`` of a partition stopped: (record
+        # offset, byte position).  A consumer that follows the log asks
+        # for that very offset next and seeks straight to it; the sparse
+        # index serves every other start.
+        self._left_at: dict[tuple[str, int], tuple[int, int]] = {}
+        # how long the last append's fsync took (0.0 with ``fsync=False``)
+        self.last_fsync_ms = 0.0
         self._partitions: dict[str, int] = {}
         os.makedirs(directory, exist_ok=True)
         for topic in sorted(os.listdir(directory)):
@@ -135,6 +143,7 @@ class FileBroker:
             self._counts.pop((name, p), None)
             self._bytes.pop((name, p), None)
             self._index.pop((name, p), None)
+            self._left_at.pop((name, p), None)
         del self._partitions[name]
         shutil.rmtree(os.path.join(self.directory, name), ignore_errors=True)
 
@@ -166,11 +175,18 @@ class FileBroker:
         # duplicate on retry and silently mislabel every indexed consume.
         if self._counts[(topic, partition)] % _INDEX_EVERY == 0:
             self._index[(topic, partition)].append(self._bytes[(topic, partition)])
-        if self._fsync:
-            fh.flush()
-            os.fsync(fh.fileno())
+        self._sync(fh)
         self._counts[(topic, partition)] += 1
         self._bytes[(topic, partition)] += _HEADER.size + len(value)
+
+    def _sync(self, fh) -> None:
+        """One fsync an append call, however many frames it wrote."""
+        if not self._fsync:
+            return
+        t0 = time.perf_counter()
+        fh.flush()
+        os.fsync(fh.fileno())
+        self.last_fsync_ms = (time.perf_counter() - t0) * 1e3
 
     def produce_frames(
         self, topic: str, keys, frames, partition: int
@@ -219,9 +235,7 @@ class FileBroker:
         first = (-base_count) % _INDEX_EVERY
         for i in range(first, n, _INDEX_EVERY):
             index.append(base_bytes + i * rec_bytes)
-        if self._fsync:
-            fh.flush()
-            os.fsync(fh.fileno())
+        self._sync(fh)
         self._counts[(topic, partition)] = base_count + n
         self._bytes[(topic, partition)] = base_bytes + n * rec_bytes
 
@@ -242,25 +256,35 @@ class FileBroker:
         index = self._index[(topic, partition)]
         offset = 0
         seek_to = 0
-        if start_offset > 0 and index:
+        left = self._left_at.get((topic, partition))
+        if left is not None and left[0] == start_offset:
+            offset, seek_to = left
+        elif start_offset > 0 and index:
             i = min(start_offset // _INDEX_EVERY, len(index) - 1)
             offset = i * _INDEX_EVERY
             seek_to = index[i]
         with open(path, "rb") as f:
             f.seek(seek_to)
-            while offset < end:
-                header = f.read(_HEADER.size)
-                if len(header) < _HEADER.size:
-                    return
-                key, vlen = _HEADER.unpack(header)
-                if offset < start_offset:
-                    f.seek(vlen, os.SEEK_CUR)
-                else:
-                    value = f.read(vlen)
-                    if len(value) < vlen:
+            pos = seek_to
+            try:
+                while offset < end:
+                    header = f.read(_HEADER.size)
+                    if len(header) < _HEADER.size:
                         return
-                    yield Record(key=key, value=value, offset=offset)
-                offset += 1
+                    key, vlen = _HEADER.unpack(header)
+                    if offset < start_offset:
+                        f.seek(vlen, os.SEEK_CUR)
+                    else:
+                        value = f.read(vlen)
+                        if len(value) < vlen:
+                            return
+                        yield Record(key=key, value=value, offset=offset)
+                    offset += 1
+                    pos += _HEADER.size + vlen
+            finally:
+                # also where the consumer stopped reading early
+                # (``GeneratorExit``): the next batch starts here
+                self._left_at[(topic, partition)] = (offset, pos)
 
     def num_partitions(self, topic: str) -> int:
         return self._num_partitions_checked(topic)

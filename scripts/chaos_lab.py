@@ -853,8 +853,8 @@ def scenario_stream_crash_replay() -> dict:
         "detected": bool(resumed_from == 2),  # torn step 3 was rejected
         "recovered": bit_exact,
         "resumed_from_step": resumed_from,
-        "replayed_updates": s_resume.metrics.counters.get(
-            "replayed_updates", 0),
+        "restored_cells": s_resume.metrics.counters.get(
+            "restored_cells", 0),
         "factors_bit_exact": bit_exact,
         "clean_crc32": crc_clean,
         "replayed_crc32": crc_replayed,

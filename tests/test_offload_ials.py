@@ -318,7 +318,7 @@ def test_streaming_offload_session_parity_and_crash_replay(tmp_path):
         stream=StreamConfig(batch_records=8),
     )
     m_rep = s2.run()
-    assert s2.metrics.counters.get("replayed_updates", 0) > 0
+    assert s2.metrics.counters.get("restored_cells", 0) > 0
     assert _crc(m_rep) == _crc(m_off)
 
 

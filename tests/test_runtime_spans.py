@@ -23,13 +23,6 @@ from cfk_tpu.transport import InMemoryBroker
 
 
 @pytest.fixture
-def tracer():
-    t = telemetry.configure()
-    yield t
-    telemetry.shutdown(write=False)
-
-
-@pytest.fixture
 def collector_off():
     """No pass but the ones a test forces."""
     was = gc.isenabled()
@@ -37,6 +30,13 @@ def collector_off():
     yield
     if was:
         gc.enable()
+
+
+@pytest.fixture
+def tracer():
+    t = telemetry.configure()
+    yield t
+    telemetry.shutdown(write=False)
 
 
 def _named(tracer, name):
@@ -52,7 +52,7 @@ def _our_hooks():
 
 
 def test_a_forced_pass_is_one_span_nested_in_the_open_stage(
-        tracer, collector_off):
+        collector_off, tracer):
     cycle = []
     cycle.append(cycle)
     del cycle
@@ -70,7 +70,7 @@ def test_a_forced_pass_is_one_span_nested_in_the_open_stage(
     telemetry.validate_span_tree(tracer.events())
 
 
-def test_a_pass_lands_on_the_thread_that_ran_it(tracer, collector_off):
+def test_a_pass_lands_on_the_thread_that_ran_it(collector_off, tracer):
     def work():
         with telemetry.span("worker/stage"):
             gc.collect(0)
@@ -111,7 +111,7 @@ def test_the_hook_lives_and_dies_with_the_tracer(collector_off):
 
 
 def test_a_pass_that_starts_inside_the_tracers_lock_does_not_deadlock(
-        tracer, collector_off):
+        collector_off, tracer):
     """A pass can start on a thread between two bytecodes of ``_emit``,
     which holds the tracer's lock; its span is emitted from inside."""
     done = threading.Event()

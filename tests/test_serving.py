@@ -1239,6 +1239,8 @@ def test_one_item_table_on_the_device_with_a_session_attached(tmp_path):
     assert sum(a.shape == table.shape for a in jax.live_arrays()) == 1
     s.producer.send(3, 7, 5.0)
     assert s.server.step() == 0 and s.session.stream_step == 1
+    while s.session.in_flight:  # published once the store has renamed it
+        s.server.step()
     assert s.engine.commit_ordinal == 1 and s.engine._table[0] is table
     assert sum(a.shape == table.shape for a in jax.live_arrays()) == 1
     # the base user table is the caller's, shared by engine and session
@@ -1365,8 +1367,16 @@ def test_a_burst_of_ratings_is_folded_in_several_batches_a_step(tmp_path):
     # last two handed over, those committed in the third
     assert depth[:3] == [3, 2, 0] and commits[:3] == [0, 3, 5]
     assert s.server.metrics.counters.get("serve_batches_overlapped", 0) > 2
+    # a unit is published when the store has renamed it, whichever step
+    # that is: the answers in between name ordinals in order, and one asked
+    # after the last publication names the last
+    ask(8)
+    while len(got) < len(asked):
+        s.server.step()
+        got.update((r.req_id, r) for r in s.client.poll_responses())
     ordinals = [got[rid].ordinal for rid in sorted(got)]
-    assert ordinals == sorted(ordinals) and {0, 3, 5} <= set(ordinals)
+    assert ordinals == sorted(ordinals) and {0, 5} <= set(ordinals)
+    assert s.engine.commit_ordinal == s.session.published_step == 5
     gap, err, row, touched = _against_the_reference(s, asked, got, sent)
     assert touched >= 20 and gap <= 1e-5 and err <= 2e-5 and row < 1e-5
 
@@ -1399,10 +1409,13 @@ def test_ordinal_and_read_your_writes_with_a_batch_in_flight(tmp_path):
     # once it is ready, never waited for behind the scorer in flight)
     extra = [s.client.request(30 + i, s.k) for i in range(16)]
     steps = 0
-    while s.engine.commit_ordinal < 1:
+    while s.session.stream_step < 1:
         s.server.step()
         steps += 1
-    assert steps <= 2 and s.session.stream_step == 1
+    assert steps <= 2
+    while s.engine.commit_ordinal < 1:  # published once it is renamed
+        s.server.step()
+    assert s.session.stream_step == 1
     # a request polled after the commit's listener returned sees the rating
     late_req = s.client.request(user, s.k)
     while late_req not in early or len(early) < 33:
