@@ -292,11 +292,13 @@ class ALSConfig:
     # DEPRECATED alias: entities per padded-layout solve chunk, overriding
     # the derived value.  Use hbm_chunk_elems.
     solve_chunk: int | None = None
-    # Batched k×k SPD solve backend: "cholesky" = XLA custom calls;
+    # Batched k×k SPD solve backend: "cholesky" = ops.solve.batched_spd_solve
+    # (XLA custom calls; on a TPU the lane-batched Cholesky kernel for
+    # float32 systems of k <= 128, k % 8 == 0);
     # "pallas" = lane-vectorized Gauss-Jordan TPU kernel (cfk_tpu.ops.pallas);
     # "auto" = pallas on TPU for ranks within the kernel's VMEM budget
-    # (~1.7× faster end-to-end at full-Netflix scale — XLA's batched
-    # cholesky/triangular custom calls are latency-bound at small k),
+    # (~1.7× faster end-to-end at full-Netflix scale than XLA's batched
+    # cholesky/triangular custom calls, latency-bound at small k),
     # cholesky everywhere else (CPU interpret-mode pallas is test-only slow).
     solver: Literal["auto", "cholesky", "pallas"] = "auto"
     # Pad ragged neighbor lists up to a multiple of this (MXU-friendly tiling).

@@ -1,31 +1,44 @@
-"""Pallas TPU kernel: batched small-SPD solve via lane-vectorized Gauss-Jordan.
+"""Pallas TPU kernels: batched small-SPD solves with the batch along the lanes.
 
 The framework's FLOP hot spot after the Gram matmuls is solving E independent
 k×k SPD systems (k = rank, 5..128; E = entities per shard).  XLA lowers
 ``jnp.linalg.cholesky`` + two ``triangular_solve``s to sequential custom
-calls that vectorize poorly for small k.  This kernel instead runs
-Gauss-Jordan elimination with the *batch* dimension laid out along the TPU's
-128-wide vector lanes: every scalar step of the textbook algorithm becomes a
+calls that walk the columns with one system in the vector unit at a time.
+The kernels here lay the *batch* dimension out along the TPU's 128-wide
+vector lanes instead: every scalar step of the textbook algorithm becomes a
 [k, T] or [k, k, T] VPU op over T systems at once.  No pivoting — the
 systems are SPD with a λ·n ≥ λ ridge (``regularized_solve``), so diagonal
-pivots stay safely positive.
+pivots stay safely positive.  Three eliminations:
 
-Layout contract: A is passed [k, k, E] and b [k, E] (batch LAST, so tiles
-sit in the lane dimension).  The dispatcher (``ops.solve.dispatch_spd_solve``)
-pays an explicit transpose from the batch-first Gram layout — measured at
-0.024 s/iter of the 0.82 full-Netflix iteration (round-3 profile), i.e.
-~3%: emitting batch-last from the Gram kernel would force its per-entity
-flush onto dynamic LANE offsets (lane-shift ops per flush), a worse trade
-than the one bulk transpose, so the transpose stays by choice now rather
-than as a follow-up.
+- **Gauss-Jordan** (``gj_solve_lanes``; ``gauss_solve_pallas`` and the
+  multi-RHS form): ≈ 2k³ FLOPs a system, fully unrolled over k with
+  [k, k, TILE] temporaries in VMEM, so k ≤ PALLAS_MAX_RANK (= 64 → A tile
+  2 MiB; at k = 128 the unrolled O(k³) chain measured ~10× slower than
+  XLA's calls).  Ranks up to 128 compose two of them by one level of Schur
+  elimination (``ops.solve._blocked_spd_solve_pallas``).
+- **Reverse-order LU** (``lu_solve_lanes``; ``gauss_solve_reg_pallas`` with
+  ``algo="lu"``, the fused ridge + solve the training half-steps take under
+  ``solver="pallas"``): k³/3, unrolled over k with a shrinking trailing
+  matrix, k ≤ LU_MAX_RANK (= 128).  ~1.1 µs a system at k = 128 in the
+  pre-ledger record, but 128 steps of 128 different shapes: it compiles in
+  ~3 min a shape there (173 s for the described v5e).
+- **Cholesky** (``_chol_lanes_kernel``; ``cholesky_solve_lanes``, what
+  ``ops.solve.batched_spd_solve`` — ``solver="cholesky"`` — runs on a TPU
+  since PR 40): the same factorisation as a LOOP over the columns (a
+  symmetric matrix's column j is its row j: a dynamic index on the leading
+  axis of a VMEM scratch) with a loop over the rows below the pivot inside
+  it, k³/2 register updates, each body traced once.  It compiles in well
+  under a second at k = 128, which is what a caller with many shapes
+  needs: the streaming fold-in's pow2 grid has 30.
 
-Cost: ≈ 2k³ FLOPs per system (vs k³/3 for Cholesky) — a 6× FLOP overhead
-traded for full lane utilization, a win while the custom-call path is
-latency-bound on small k.  The fully-unrolled k-loop holds [k, k, TILE]
-temporaries in VMEM, which bounds the supported rank: k ≤ PALLAS_MAX_RANK
-(= 64 → A tile 2 MiB); larger ranks must use the cholesky backend (the
-dispatcher falls back automatically).  Falls back to interpret mode off-TPU
-so tests run on CPU.
+Layout contract: the batch-last kernels take A as [k, k, E] and b as [k, E]
+(tiles sit in the lane dimension); ``gauss_solve_reg_pallas`` and
+``cholesky_solve_lanes`` take the batch-first Gram layout.  The first turns
+its block in VMEM; the second leaves the turn to XLA, where it fuses with
+the ridge's add.  (Emitting batch-last from the Gram kernel would force its
+per-entity flush onto dynamic LANE offsets, a worse trade than one bulk
+transpose: ~3 % of the full-Netflix iteration in the round-3 profile.)
+All fall back to interpret mode off-TPU so tests run on CPU.
 """
 
 from __future__ import annotations
@@ -54,6 +67,11 @@ PALLAS_MAX_RANK = 64
 # k = 128: one direct LU beats the blocked Schur composition of k=64 GJ
 # kernels AND skips Schur's XLA-level [E,k,k] transposes.
 LU_MAX_RANK = 128
+# The lane-batched Cholesky (``cholesky_solve_lanes``) is a loop over the
+# columns, not an unrolled program: one tile keeps A, its factor and the
+# double-buffered input block in VMEM (4 x 8 MiB at k = 128) and compiles
+# in under a second at any rank up to the lane width.
+CHOL_MAX_RANK = 128
 
 
 def gj_solve_lanes(a, b, *, k: int):
@@ -107,6 +125,75 @@ def lu_solve_lanes(tr, y, u_scr, y_scr, x_scr, *, k: int):
         corr = jnp.sum(u_scr[j, :j, :] * x_scr[:j, :], axis=0)
         x_scr[j, :] = y_scr[j, :] - corr
     return x_scr[...]
+
+
+def _chol_lanes_kernel(a_ref, b_ref, x_ref, a_scr, l_scr, y_scr, x_scr, *,
+                       k: int):
+    """Cholesky factor-and-solve of T systems, one a lane: a_ref [k,k,T],
+    b_ref [k,T] -> x_ref [k,T].  ``A = L L^T`` by the right-looking
+    (outer-product) form, no pivoting: SPD + ridge, as the LU kernel relies
+    on.
+
+    What keeps it a loop where ``lu_solve_lanes`` is an unrolled program:
+    column j of a symmetric matrix is its row j, and ``a_scr[j]`` is a
+    dynamic index on the leading, untiled axis of a VMEM scratch.  A step
+    reads that row and the pivot (one sublane of it), scales by the pivot's
+    inverse root, masks to rows >= j and subtracts ``l_i * l`` from row i of
+    the trailing matrix, row by row from the pivot down in an inner loop
+    whose body is ``k / 8`` registers wide: no offset slice of a value
+    (Mosaic's sublane broadcast refuses those), no shrinking shape; the
+    rows behind the pivot are the k^3 / 2 the dynamic bound gives back.
+    The right-hand side rides along as one more row (forward substitution
+    inside the column loop); the back substitution is a loop of ``[k, T]``
+    products over the stored factor.  Lanes never mix: a system's bits do
+    not depend on its tile's others.
+
+    Kept this small on purpose: every equation of the body is lowered
+    again, in Python, by each program that holds the call (this body:
+    ~0.06 s a program on the chip machine's host, and the fold-in prewarms
+    30 on every start).  Static column stages that skip the lower triangle
+    (the other k^3 / 6) measured 0.227 ms (stages of 64) and 0.162 ms
+    (stages of 32, rows unrolled by 4) for 256 systems of 128 on a v5e
+    against this body's 0.278, and cost 1 s and ~3.5 s more of every warm
+    start (PERF.md, section 6, PR 40).
+    """
+    a_scr[...] = a_ref[...]
+    y_scr[...] = b_ref[...]
+    x_scr[...] = jnp.zeros_like(x_scr)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (k, _LANES), 0)
+    zeros = jnp.zeros((k, _LANES), jnp.float32)
+
+    def column(j, carry):
+        inv = jax.lax.rsqrt(a_scr[j, pl.ds(j, 1), :])  # [1,T]
+        col = jax.lax.select(rows >= j, a_scr[j] * inv, zeros)
+        l_scr[j] = col  # column j of L, zero above the diagonal
+        # Forward substitution: rows <= j of y_scr hold y, rows > j the
+        # right-hand side less the columns done (col is 0 at rows < j, so
+        # those stay; row j is set last).
+        yj = y_scr[pl.ds(j, 1), :] * inv
+        y_scr[...] = y_scr[...] - yj * col
+        y_scr[pl.ds(j, 1), :] = yj
+
+        def row(i, c):
+            a_scr[i] = a_scr[i] - l_scr[j, pl.ds(i, 1), :] * col
+            return c
+
+        jax.lax.fori_loop(j + 1, k, row, 0)
+        return carry
+
+    jax.lax.fori_loop(0, k, column, 0)
+
+    def back(n, carry):
+        i = k - 1 - n
+        # x is 0 at rows <= i still, L's column i at rows < i: the sum is
+        # over the rows below the diagonal.
+        dot = jnp.sum(l_scr[i] * x_scr[...], axis=0, keepdims=True)
+        x_scr[pl.ds(i, 1), :] = (
+            (y_scr[pl.ds(i, 1), :] - dot) / l_scr[i, pl.ds(i, 1), :])
+        return carry
+
+    jax.lax.fori_loop(0, k, back, 0)
+    x_ref[...] = x_scr[...]
 
 
 def _gauss_kernel(a_ref, b_ref, x_ref, *, k: int):
@@ -434,7 +521,7 @@ def _lane_padded_inputs(a, b, b_pad_axis, interpret):
 
 
 def _solve_call(kernel, a_p, b_p, b_block, out_struct, tile, interpret,
-                vmem_limit=None):
+                vmem_limit=None, scratch=(), name=None):
     """Shared pallas_call plumbing: VMEM block specs (skipped in interpret
     mode), vma tagging of the output aval (under shard_map the output must
     carry the inputs' varying-mesh-axes), and the optional scoped-VMEM
@@ -461,8 +548,11 @@ def _solve_call(kernel, a_p, b_p, b_block, out_struct, tile, interpret,
     if vmem_limit is not None and not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit)
+    if scratch:
+        kwargs["scratch_shapes"] = list(scratch)
     return pl.pallas_call(
         kernel,
+        name=name,
         out_shape=out_shape,
         grid=(e_pad // tile,),
         interpret=bool(interpret),
@@ -527,3 +617,58 @@ def gauss_solve_pallas(
         a_p, b_p, (k, tile), ((k, e_pad), a.dtype), tile, interpret,
     )
     return x[:, :e]
+
+
+def cholesky_solve_lanes(
+    a: jax.Array,  # [E, k, k] float32, SPD per system (batch-FIRST)
+    b: jax.Array,  # [E, k] float32
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:  # [E, k]
+    """Cholesky-solve A[e] x = b[e] for every e with the batch along the
+    lanes: what ``ops.solve.batched_spd_solve`` runs on a TPU.
+
+    Batch-first in and out, like XLA's calls it stands in for; the turn to
+    ``[k, k, E]`` and the pad to whole tiles of 128 systems are the
+    caller's XLA, where they fuse with whatever made ``a`` (in the fold-in:
+    the ridge's add).  A padded lane holds the zero system: its 0 / 0 stays
+    in its lane and is sliced off again.  The kernel sits behind its own
+    ``jit`` on the padded operands, so every program that agrees on
+    ``(k, ceil(E / 128))`` shares one trace of it.
+    """
+    e, k, k2 = a.shape
+    if k != k2 or b.shape != (e, k):
+        raise ValueError(f"bad shapes a={a.shape} b={b.shape}")
+    if k > CHOL_MAX_RANK or k % 8:
+        raise ValueError(
+            f"cholesky_solve_lanes supports rank <= {CHOL_MAX_RANK} in "
+            f"multiples of 8, got {k}"
+        )
+    e_pad = -(-e // _LANES) * _LANES
+    x = _cholesky_lanes_call(
+        _pad_to(jnp.transpose(a, (1, 2, 0)), e_pad, axis=2),
+        _pad_to(b.T, e_pad, axis=1),
+        interpret=resolve_interpret(interpret),
+    )
+    return x[:, :e].T
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _cholesky_lanes_call(a_t, b_t, *, interpret):
+    """a_t [k, k, E], b_t [k, E] -> x [k, E], E in whole tiles."""
+    k, e_pad = b_t.shape
+    block = (k, _LANES)
+    return _solve_call(
+        functools.partial(_chol_lanes_kernel, k=k),
+        a_t, b_t, block, ((k, e_pad), a_t.dtype), _LANES, interpret,
+        # the input block twice (the pipeline's two buffers), the working
+        # copy, the factor, and room for the [k, T] rows
+        vmem_limit=(4 * k * k + 64 * k) * _LANES * 4 + (4 << 20),
+        scratch=(
+            pltpu.VMEM((k, k, _LANES), jnp.float32),  # trailing matrix
+            pltpu.VMEM((k, k, _LANES), jnp.float32),  # L, column j at [j]
+            pltpu.VMEM(block, jnp.float32),  # y over the right-hand side
+            pltpu.VMEM(block, jnp.float32),  # x
+        ),
+        name="cholesky_solve_lanes",
+    )

@@ -78,11 +78,42 @@ def gather_gram(
     return a, b
 
 
-def batched_spd_solve(a: jax.Array, b: jax.Array) -> jax.Array:
-    """Solve A x = b for a batch of SPD k×k systems via Cholesky.
+def spd_solve_route(a: jax.Array, b: jax.Array) -> str:
+    """Which way ``batched_spd_solve`` factors ``a`` [E, k, k] against ``b``
+    [E, k], from what it can see of them: ``"lanes"``, the Pallas Cholesky
+    with the batch along the lanes (``ops.pallas.solve_kernel.
+    cholesky_solve_lanes``), on a TPU backend for float32 systems of
+    ``k <= 128``, ``k % 8 == 0``; ``"xla"``, XLA's ``cholesky`` and two
+    ``triangular_solve`` calls, everywhere else.  Shapes and dtypes are
+    enough: ``jax.ShapeDtypeStruct``s do (``streaming.foldin`` names the
+    route on its span that way)."""
+    from cfk_tpu.ops.pallas.solve_kernel import CHOL_MAX_RANK
 
-    a: [E, k, k], b: [E, k] → x: [E, k].
+    k = a.shape[-1]
+    lanes = (
+        jax.default_backend() == "tpu"
+        and len(a.shape) == 3 and len(b.shape) == 2
+        and a.dtype == jnp.float32 and b.dtype == jnp.float32
+        and k <= CHOL_MAX_RANK and k % 8 == 0
+    )
+    return "lanes" if lanes else "xla"
+
+
+def batched_spd_solve(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Solve A x = b for a batch of SPD k×k systems via Cholesky
+    (``A = L Lᵀ``, forward and back substitution, no pivoting).
+
+    a: [E, k, k], b: [E, k] → x: [E, k].  One algorithm, vectorised two
+    ways (``spd_solve_route``): XLA's custom calls walk the columns with one
+    system's column in the vector unit at a time (6.3 ms for 256 systems of
+    128 on a v5e), the lane-batched kernel walks them with 128 systems
+    abreast (0.28 ms: PERF.md section 6, PR 40).  The same float32 normal
+    equations either way; the two agree to rounding, not to the bit.
     """
+    if spd_solve_route(a, b) == "lanes":
+        from cfk_tpu.ops.pallas.solve_kernel import cholesky_solve_lanes
+
+        return cholesky_solve_lanes(a, b)
     chol = jnp.linalg.cholesky(a)
     y = lax.linalg.triangular_solve(
         chol, b[..., None], left_side=True, lower=True, transpose_a=False
@@ -362,18 +393,22 @@ def _blocked_spd_solve_pallas(a: jax.Array, b: jax.Array) -> jax.Array:
 def dispatch_spd_solve(a: jax.Array, b: jax.Array, solver: str) -> jax.Array:
     """Solve batched SPD systems with the selected backend.
 
-    ``"cholesky"`` — XLA's cholesky + triangular solves.
+    ``"cholesky"`` — ``batched_spd_solve``: the Cholesky factorisation and
+                     its two substitutions, by the lane-batched Pallas
+                     kernel on a TPU (float32, k <= 128, k % 8 == 0) and by
+                     XLA's cholesky + triangular solves everywhere else.
     ``"pallas"``   — lane-vectorized Gauss-Jordan TPU kernel
                      (``cfk_tpu.ops.pallas``); interpret-mode off TPU.
-    ``"auto"``     — pallas on a TPU backend (XLA's batched cholesky custom
-                     calls are latency-bound at small k; the kernel is
-                     ~7× faster on 100k rank-64 systems and ~1.7× on the
-                     end-to-end full-Netflix iteration), cholesky elsewhere.
+    ``"auto"``     — pallas on a TPU backend (against XLA's batched
+                     cholesky custom calls, latency-bound at small k, the
+                     kernel was ~7× faster on 100k rank-64 systems and
+                     ~1.7× on the end-to-end full-Netflix iteration:
+                     pre-ledger), cholesky elsewhere.
 
     The pallas path pays an explicit [E,k,k] → [k,k,E] transpose to put the
     batch in the lane dimension.  Ranks in (PALLAS_MAX_RANK, 2·PALLAS_MAX_RANK]
     use one level of blocked Schur elimination on the same kernels; anything
-    larger falls back to cholesky.
+    larger falls back to ``batched_spd_solve`` (XLA's calls: k > 128).
     """
     solver = _resolve_solver(solver)
     if solver == "cholesky":
@@ -395,6 +430,20 @@ def _resolve_solver(solver: str) -> str:
     if solver == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "cholesky"
     return solver
+
+
+def solve_route(solver: str, rank: int) -> str:
+    """The route a half-step's float32 normal equations of ``rank`` take
+    under ``solver``, named for a span: ``"lanes"`` or ``"xla"`` where it
+    resolves to ``"cholesky"`` (``spd_solve_route``, asked with the shapes
+    alone), else the solver's own name."""
+    solver = _resolve_solver(solver)
+    if solver != "cholesky":
+        return solver
+    return spd_solve_route(
+        jax.ShapeDtypeStruct((1, rank, rank), jnp.float32),
+        jax.ShapeDtypeStruct((1, rank), jnp.float32),
+    )
 
 
 def default_fused_epilogue() -> bool:

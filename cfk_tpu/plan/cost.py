@@ -41,8 +41,13 @@ _GATHER_PAD_FACTOR = {
 # Interpret-mode pallas off-TPU is a test-only path, orders of magnitude
 # slow — the model must never pick it on a cpu/gpu device.
 _OFFCHIP_PALLAS_SOLVER_PENALTY = 50.0
-# XLA's batched-Cholesky custom calls measured ~1.7× the fused pallas
-# solve end-to-end on TPU (pre-ledger round 2; PERF.md §8).
+# What ``solver="cholesky"`` costs a training iteration on a TPU over the
+# fused pallas solve: ~1.7× end to end when it was XLA's batched-Cholesky
+# custom calls (pre-ledger round 2; PERF.md §8).  Since PR 40 those systems
+# (float32, k <= 128, k % 8 == 0) take the lane-batched Cholesky kernel, so
+# the figure now prices the split schedule (the [E, k, k] Gram batch written
+# to HBM, turned and read back) plus that kernel, and over-prices it; no
+# train cell has measured the new ratio, so it stays until one does.
 _TPU_CHOLESKY_PENALTY = 1.7
 
 
@@ -97,7 +102,7 @@ def train_iteration_cost(shape: ProblemShape, device: DeviceSpec,
     # min-bytes already include the gather bytes).
     compute_s = base.model_flops / shards / device.peak_flops
     if plan.solver == "cholesky":
-        # the solve share of the flops pays the latency-bound custom call
+        # the solve share of the flops pays the split schedule's solve
         solve_flops = (shape.num_users + shape.num_movies) * (
             k**3 / 3.0 + 2.0 * k**2
         )

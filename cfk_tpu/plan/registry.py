@@ -341,7 +341,9 @@ def resolve_fused_chunk_lam(fused_epilogue, solver, k, num_segments,
     the per-call/config/process fused knob, the pallas Gram backend (the
     XLA A/B backend has no VMEM residency to exploit), ``mosaic_tpu``
     registry availability, the pallas solver (cholesky callers asked for
-    XLA's solve — honoring that means splitting), the fused elimination's
+    ``batched_spd_solve`` — a program of its own after the Gram, XLA's
+    calls or on a TPU the lane-batched Cholesky kernel: honoring that
+    means splitting), the fused elimination's
     rank/VMEM caps (for the elimination ``algo`` the caller threads — GJ
     caps at 64 where LU reaches 128), and a concretizable λ (the kernel
     bakes it in as a compile-time constant; a traced per-step λ falls

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cfk_tpu.ops.solve import als_half_step
+from cfk_tpu.ops.solve import als_half_step, solve_route
 from cfk_tpu.ops.tiled import tiled_half_step
 from cfk_tpu.resilience import sentinel as _sentinel
 from cfk_tpu.telemetry import span
@@ -209,7 +209,8 @@ def fold_in_dispatch(
         sp.set(entities=e, width=p, bytes=nbytes)
     rank = int(movie_factors.shape[-1])
     with span("stream/batch/solve", touched=t, entities=e, width=p,
-              gather_bytes=e * p * rank * 4), \
+              gather_bytes=e * p * rank * 4,
+              solve_route=solve_route(solver, rank)), \
             span("stream/batch/solve/dispatch"):
         out = _padded_fold(
             movie_factors, *operands, np.int32(t), np.float32(norm_limit),

@@ -132,3 +132,103 @@ def test_sharded_pallas_matches_single_device(tiny_coo):
             mesh,
         ).predict_dense()
         np.testing.assert_allclose(got, ref, rtol=1e-2, atol=1e-2, err_msg=exchange)
+
+
+# -- the lane-batched Cholesky (what solver="cholesky" runs on a TPU) --------
+
+LAM = 0.05
+
+
+def _normal_equations(rng, e, k, width=24):
+    """ALS-WR systems as the fold-in makes them: A = sum f f^T + lam max(n,1) I
+    over n rows of +-0.175, b = sum r f.  System 0 is all padding (n = 0:
+    lam I against 0), system 1 sits at the ridge's floor (one row repeated:
+    rank one + lam n I)."""
+    f = rng.uniform(-0.175, 0.175, (e, width, k)).astype(np.float32)
+    n = rng.integers(1, width + 1, size=e)
+    n[0] = 0
+    f[1] = f[1, :1]
+    f *= (np.arange(width)[None, :, None] < n[:, None, None])
+    r = rng.integers(1, 6, size=(e, width)).astype(np.float32)
+    a = np.einsum("epk,epl->ekl", f, f)
+    a += (LAM * np.maximum(n, 1))[:, None, None] * np.eye(k, dtype=np.float32)
+    b = np.einsum("epk,ep->ek", f, r)
+    return a.astype(np.float32), b.astype(np.float32)
+
+
+@pytest.mark.parametrize("e", [8, 200, 256])
+@pytest.mark.parametrize("k", [8, 64, 128])
+def test_cholesky_lanes_matches_float64(k, e):
+    """The kernel body under the Pallas interpreter against numpy's float64
+    solve of the same systems: whole tiles, a ragged last tile (200) and
+    fewer systems than lanes (8); and a system's bits are its own, whatever
+    shares its tile."""
+    from cfk_tpu.ops.pallas.solve_kernel import cholesky_solve_lanes
+
+    rng = np.random.default_rng(1000 * k + e)
+    a, b = _normal_equations(rng, e, k)
+    got = np.asarray(cholesky_solve_lanes(jnp.asarray(a), jnp.asarray(b)))
+    want = np.linalg.solve(a.astype(np.float64),
+                           b.astype(np.float64)[..., None])[..., 0]
+    assert got.shape == (e, k) and got.dtype == np.float32
+    np.testing.assert_array_equal(got[0], 0.0)  # lam I x = 0
+    err = np.abs(got - want) / np.maximum(np.abs(want), 1.0)
+    assert err.max() < 1e-5, err.max()
+    # other lanes, other neighbours, another tile count: the same bits
+    order = rng.permutation(e)
+    others, _ = _normal_equations(rng, 131, k)
+    a2 = np.concatenate([others, a[order]])
+    b2 = np.concatenate([np.ones((131, k), np.float32), b[order]])
+    again = np.asarray(cholesky_solve_lanes(jnp.asarray(a2), jnp.asarray(b2)))
+    np.testing.assert_array_equal(again[131:], got[order])
+
+
+def _xla_cholesky_solve(a, b):
+    import jax
+
+    chol = jnp.linalg.cholesky(a)
+    y = jax.lax.linalg.triangular_solve(
+        chol, b[..., None], left_side=True, lower=True, transpose_a=False)
+    return jax.lax.linalg.triangular_solve(
+        chol, y, left_side=True, lower=True, transpose_a=True)[..., 0]
+
+
+@pytest.mark.parametrize("case,backend,k,dtype,route", [
+    ("cpu", "cpu", 128, "float32", "xla"),
+    ("k136", "tpu", 136, "float32", "xla"),
+    ("k12", "tpu", 12, "float32", "xla"),
+    ("float64", "tpu", 8, "float64", "xla"),
+    ("the_fold_in", "tpu", 128, "float32", "lanes"),
+    ("rank8", "tpu", 8, "float32", "lanes"),
+])
+def test_batched_spd_solve_gate(monkeypatch, case, backend, k, dtype, route):
+    """``batched_spd_solve`` adapts on what it sees of its input: only a TPU
+    backend with float32 systems of k <= 128, k % 8 == 0 takes the lane
+    kernel; everything else runs XLA's three calls, to the bit."""
+    import jax
+
+    from cfk_tpu.ops import solve
+    from cfk_tpu.ops.pallas import solve_kernel
+
+    calls = []
+
+    def lanes(a, b):
+        calls.append(a.shape)
+        return _xla_cholesky_solve(a, b)
+
+    monkeypatch.setattr(solve_kernel, "cholesky_solve_lanes", lanes)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with jax.enable_x64(dtype == "float64"):
+        rng = np.random.default_rng(k)
+        a, b = _normal_equations(rng, 6, k)
+        a, b = jnp.asarray(a, dtype), jnp.asarray(b, dtype)
+        assert a.dtype == dtype
+        assert solve.spd_solve_route(a, b) == route
+        # the span's name for it asks about float32 systems of this rank
+        assert solve.solve_route("cholesky", k) == (
+            route if dtype == "float32" else "lanes")
+        got = np.asarray(solve.batched_spd_solve(a, b))
+        want = np.asarray(_xla_cholesky_solve(a, b))
+    assert calls == ([(6, k, k)] if route == "lanes" else [])
+    np.testing.assert_array_equal(got, want)
+    assert solve.solve_route("pallas", k) == "pallas"

@@ -52,6 +52,30 @@ def test_gauss_solve_compiled_matches_cholesky(k, e):
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("k,e", [(128, 256), (128, 8), (64, 200), (8, 300)])
+def test_cholesky_lanes_compiled_matches_float64(k, e):
+    """What ``batched_spd_solve`` runs here (float32, k % 8 == 0, k <= 128):
+    the lane-batched Cholesky, compiled, against numpy's float64 solve of
+    ALS-WR systems as the fold-in makes them (an all-padding system and one
+    at the ridge's floor among them), and no further from it than XLA's
+    own calls on the same systems."""
+    from cfk_tpu.ops.solve import batched_spd_solve, spd_solve_route
+    from tests.test_pallas_solve import (_normal_equations,
+                                         _xla_cholesky_solve)
+
+    rng = np.random.default_rng(1000 * k + e)
+    a, b = _normal_equations(rng, e, k)
+    assert spd_solve_route(a, b) == "lanes"
+    got = np.asarray(batched_spd_solve(jnp.asarray(a), jnp.asarray(b)))
+    xla = np.asarray(_xla_cholesky_solve(jnp.asarray(a), jnp.asarray(b)))
+    want = np.linalg.solve(a.astype(np.float64),
+                           b.astype(np.float64)[..., None])[..., 0]
+    err = lambda x: (np.abs(x - want) / np.maximum(np.abs(want), 1.0)).max()
+    assert np.all(got[0] == 0.0)
+    assert err(got) < 1e-5 and err(got) < 4 * err(xla) + 1e-6, (
+        err(got), err(xla))
+
+
 @pytest.mark.parametrize("k", [96, 128])
 def test_blocked_solve_compiled_matches_cholesky(k):
     from cfk_tpu.ops.solve import batched_spd_solve, dispatch_spd_solve
