@@ -1303,7 +1303,6 @@ def _stream_impl(args, metrics) -> int:
             base_model = train_als(ds, config, metrics=metrics)
     stream = StreamConfig(
         batch_records=args.batch_records,
-        foldin_layout=args.foldin_layout,
         retrain_every=args.retrain_every,
     )
     import contextlib
@@ -1943,11 +1942,8 @@ def build_parser() -> argparse.ArgumentParser:
     st.add_argument("--seed", type=int, default=42)
     st.add_argument("--layout", choices=["padded", "tiled"],
                     default="padded",
-                    help="base dataset layout; also the fold-in default "
-                    "(tiled runs the at-scale fused kernels)")
-    st.add_argument("--foldin-layout", choices=["auto", "padded", "tiled"],
-                    default="auto",
-                    help="fold-in solve layout ('auto' follows --layout)")
+                    help="base dataset layout (the fold-in takes its "
+                    "route from each micro-batch's lists)")
     st.add_argument("--solver", choices=["auto", "cholesky", "pallas"],
                     default="auto")
     st.add_argument("--dtype", choices=["float32", "bfloat16"],

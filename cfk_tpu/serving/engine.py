@@ -60,6 +60,7 @@ user rows" and "[B, K] ids+scores":
 
 from __future__ import annotations
 
+import bisect
 import functools
 import threading
 
@@ -76,6 +77,7 @@ from cfk_tpu.serving.topk_kernel import (
     topk_scores_counted,
 )
 from cfk_tpu.telemetry import dump_flight, record_event, span
+from cfk_tpu.utils.search import csr_find
 
 
 def pad_table(table: np.ndarray, tile_m: int, shards: int = 1) -> np.ndarray:
@@ -233,6 +235,8 @@ class ServeEngine:
             None if seen_indptr is None
             else np.asarray(seen_indptr, np.int64)
         )
+        # row -> the items the stream added to the user's list, ascending,
+        # none of them in the base slice (``_extend_seen``)
         self._seen_hot: dict[int, list[int]] = {}
         self._set_table(movie_factors)
         self.invalidations = 0
@@ -274,8 +278,7 @@ class ServeEngine:
                  for r, f in hot_rows.items()} if hot_rows else {}
             )
             self._seen_hot = {}
-            for row, movie in seen_cells or ():
-                self._seen_hot.setdefault(int(row), []).append(int(movie))
+            self._extend_seen(seen_cells or ())
             if num_users is not None:
                 self.num_users = int(num_users)
             if movie_factors is not None:
@@ -487,8 +490,7 @@ class ServeEngine:
                 for i, row in enumerate(touched):
                     self._u_hot[int(row)] = np.array(rows[i], np.float32)
                 self.invalidations += len(touched)
-            for row, movie in event.get("cells") or ():
-                self._seen_hot.setdefault(int(row), []).append(int(movie))
+            self._extend_seen(event.get("cells") or ())
             self.num_users = max(self.num_users,
                                  int(event.get("num_users", self.num_users)))
             self.commit_ordinal = max(
@@ -569,31 +571,66 @@ class ServeEngine:
             # else: streamed-in user with no commit yet → zero row
         return u
 
+    def _extend_seen(self, cells) -> None:
+        """A commit's rated (user row, item row) cells into the seen
+        overlay: per user one ascending list of the items the stream added
+        that the base list does not hold (one lookup for the whole commit),
+        kept in order here, once a commit, so a batch reads it as it
+        stands."""
+        cells = np.asarray(list(cells), np.int64).reshape(-1, 2)
+        if self._seen_movies is not None and cells.shape[0]:
+            cells = cells[csr_find(self._seen_indptr, self._seen_movies,
+                                   cells[:, 0], cells[:, 1]) < 0]
+        for row, movie in cells.tolist():
+            have = self._seen_hot.get(row)
+            if have is None:
+                self._seen_hot[row] = [movie]
+                continue
+            at = bisect.bisect_left(have, movie)
+            if at == len(have) or have[at] != movie:
+                have.insert(at, movie)
+
     def _batch_seen(self, user_rows: np.ndarray):
-        """Per-batch CSR = base slice ⊕ hot overlay, sorted per user."""
+        """Per-batch CSR = base slice ⊕ hot overlay, sorted per user: the
+        base slices in one gather; where users of the batch have an overlay
+        (disjoint from its base and ascending: ``_extend_seen``) the runs
+        between them are copied whole and each of theirs takes one sorted
+        insert, so what runs in Python follows the hot users, not the
+        lists."""
         if self._seen_movies is None and not self._seen_hot:
             return None
-        per_user = []
-        base_n = (0 if self._seen_indptr is None
-                  else self._seen_indptr.shape[0] - 1)
-        for row in user_rows:
-            row = int(row)
-            if self._seen_movies is not None and row < base_n:
-                base = self._seen_movies[
-                    self._seen_indptr[row]: self._seen_indptr[row + 1]
-                ]
-            else:
-                base = np.zeros(0, np.int32)
-            extra = self._seen_hot.get(row)
-            if extra:
-                base = np.unique(np.concatenate(
-                    [base, np.asarray(extra, np.int32)]
-                ))
-            per_user.append(base)
-        indptr = np.zeros(len(per_user) + 1, np.int64)
-        indptr[1:] = np.cumsum([a.size for a in per_user])
-        movies = (np.concatenate(per_user) if indptr[-1]
-                  else np.zeros(0, np.int32))
+        rows = np.asarray(user_rows, np.int64)
+        n = rows.shape[0]
+        lo = np.zeros(n, np.int64)
+        size = np.zeros(n, np.int64)
+        if self._seen_movies is not None:
+            based = rows < self._seen_indptr.shape[0] - 1
+            lo[based] = self._seen_indptr[rows[based]]
+            size[based] = self._seen_indptr[rows[based] + 1] - lo[based]
+        base_ptr = np.zeros(n + 1, np.int64)
+        np.cumsum(size, out=base_ptr[1:])
+        total = int(base_ptr[-1])
+        # cell j of slot i's base slice: seen_movies[lo[i] + j]
+        base = (self._seen_movies[np.repeat(lo - base_ptr[:-1], size)
+                                  + np.arange(total)]
+                if total else np.zeros(0, np.int32))
+        hot = ([(i, self._seen_hot[r]) for i, r in enumerate(rows.tolist())
+                if r in self._seen_hot] if self._seen_hot else [])
+        if not hot:
+            return base, base_ptr
+        grow = np.zeros(n + 1, np.int64)
+        for i, extra in hot:
+            grow[i + 1] = len(extra)
+        indptr = base_ptr + np.cumsum(grow)
+        movies = np.empty(int(indptr[-1]), np.int32)
+        done = 0  # slots copied so far
+        for i, extra in hot:
+            movies[indptr[done]:indptr[i]] = base[base_ptr[done]:base_ptr[i]]
+            mine = base[base_ptr[i]:base_ptr[i + 1]]
+            movies[indptr[i]:indptr[i + 1]] = np.insert(
+                mine, np.searchsorted(mine, extra), extra)
+            done = i + 1
+        movies[indptr[done]:] = base[base_ptr[done]:]
         return movies, indptr
 
     def topk(self, user_rows, k: int, *, exclude_seen: bool = True,

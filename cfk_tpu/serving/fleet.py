@@ -539,8 +539,7 @@ class FleetReplica:
                 eng.epoch = snap["epoch"]
                 for row, f in snap["overlay"].items():
                     eng._u_hot[int(row)] = np.asarray(f, np.float32)
-                for row, mv in snap["cells"]:
-                    eng._seen_hot.setdefault(int(row), []).append(int(mv))
+                eng._extend_seen(snap["cells"])
                 eng.prewarm(self.prewarm_k, max_batch=self.prewarm_batch)
                 self._pending = (snap["epoch"], eng, snap["seq"])
 

@@ -79,7 +79,9 @@ from cfk_tpu.resilience import sentinel as _sentinel
 from cfk_tpu.resilience.loop import drain_checkpoints, save_checkpoint
 from cfk_tpu.resilience.policy import Overrides, RecoveryPolicy, policy_from_config
 from cfk_tpu.streaming.consumer import StreamConsumer
-from cfk_tpu.streaming.foldin import fold_in_dispatch, fold_in_rows
+from cfk_tpu.streaming.foldin import (
+    CHUNK, SLABS, _pow2_ceil, fold_in_dispatch, fold_in_rows_windowed,
+    trace_count)
 from cfk_tpu.streaming.producer import UPDATES_TOPIC
 from cfk_tpu.streaming.state import (
     CELL, StreamState, cells_array, last_per_cell, overlay_of)
@@ -106,10 +108,6 @@ class StreamConfig:
     # recorded in every commit and the committed value wins on resume (a
     # changed setting applies only to batches past the committed cursor).
     batch_records: int = 256
-    # Fold-in solve layout: "padded" | "tiled" | "auto" (= tiled when the
-    # training config's layout is tiled — the same kernels as training —
-    # else padded).
-    foldin_layout: str = "auto"
     # Warm full retrain every N stream commits (None = never): rebuild the
     # dataset from the merged state and run the resilient training loop
     # warm-started from the current factors.
@@ -135,11 +133,6 @@ class StreamConfig:
         if self.batch_records < 1:
             raise ValueError(
                 f"batch_records must be >= 1, got {self.batch_records}"
-            )
-        if self.foldin_layout not in ("auto", "padded", "tiled"):
-            raise ValueError(
-                f"foldin_layout must be auto/padded/tiled, got "
-                f"{self.foldin_layout!r}"
             )
         if self.retrain_every is not None and self.retrain_every < 1:
             raise ValueError(
@@ -402,10 +395,6 @@ class StreamSession:
         self.guard = preemption_guard
         self.policy = policy or policy_from_config(config)
         self.health = _sentinel.health_from_config(config)
-        self._layout = (
-            self.stream.foldin_layout if self.stream.foldin_layout != "auto"
-            else ("tiled" if config.layout == "tiled" else "padded")
-        )
         # Out-of-core sessions (ISSUE 19): with offload_tier='host_window'
         # the movie table lives in a host-resident ``HostFactorStore``
         # (the user table always was host numpy) and every fold-in stages
@@ -419,21 +408,12 @@ class StreamSession:
         )
         self._m_store = None
         self._foldin_stats: dict = {}
-        if self._offload:
-            if self.stream.foldin_layout == "tiled":
-                raise ValueError(
-                    "foldin_layout='tiled' needs the device-resident "
-                    "movie table; an offload_tier='host_window' session "
-                    "stages ad-hoc windows (foldin_layout 'auto'/'padded')"
-                )
-            self._layout = "padded"
         self._engine = engine
         if engine is not None:
-            if self._offload or self._layout != "padded":
+            if self._offload:
                 raise ValueError(
                     "a session that folds in against a serving engine's "
-                    "table runs the padded fold-in on the device: not "
-                    f"foldin_layout={self._layout!r}, not an "
+                    "table gathers from it on the device: not an "
                     "offload_tier='host_window' session")
             if self.stream.retrain_every is not None:
                 raise ValueError(
@@ -845,20 +825,20 @@ class StreamSession:
         """The front half of one staged batch's solve under the given
         overrides: the touched users' lists (``over``: the batches it was
         staged over, not committed yet), the rectangle, the hand-over.
-        Returns (fold, solved): the padded layout on a resident table
-        comes back with the ``FoldIn`` on the device, the others have
-        solved by the time they return, (rows, the fixed rows to probe)."""
+        Returns (fold, solved): on a resident table the ``FoldIn`` comes
+        back on the device (either route of ``foldin.fold_route``); an
+        out-of-core session has solved by the time it returns, (rows, the
+        fixed rows to probe)."""
         with span("stream/batch/neighbors") as sp:
             staged = (*over, pending)
-            neighbor_data = [
-                self.state.neighbors(row, overlay_of(row, staged))
-                for row in pending.touched_rows
-            ]
-            sp.set(touched=len(neighbor_data))
+            neighbor_data = self.state.neighbors_many(
+                pending.touched_rows,
+                [overlay_of(row, staged) for row in pending.touched_rows])
+            lens = [mv.shape[0] for mv, _ in neighbor_data]
+            sp.set(touched=len(neighbor_data), cells=sum(lens),
+                   longest=max(lens, default=0))
         with self.metrics.phase("foldin_solve"):
             if self._offload:
-                from cfk_tpu.streaming.foldin import fold_in_rows_windowed
-
                 with span("stream/batch/solve", touched=len(neighbor_data),
                           offload=1):
                     rows, staged = fold_in_rows_windowed(
@@ -878,19 +858,6 @@ class StreamSession:
                     round(self._foldin_stats.get(
                         "foldin_staged_bytes", 0) / 1e6, 3))
                 return None, (rows, staged)
-            if self._layout == "tiled":
-                with span("stream/batch/solve", touched=len(neighbor_data),
-                          offload=0):
-                    rows = fold_in_rows(
-                        self._m, neighbor_data,
-                        lam=overrides.lam,
-                        solver=self.config.solver,
-                        layout="tiled",
-                        fused_epilogue=overrides.fused_epilogue,
-                        in_kernel_gather=self.config.in_kernel_gather,
-                        reg_solve_algo=overrides.reg_solve_algo,
-                    )
-                return None, (rows, self._m)
             return fold_in_dispatch(
                 self._fixed(), neighbor_data,
                 lam=overrides.lam,
@@ -899,7 +866,15 @@ class StreamSession:
                 reg_solve_algo=overrides.reg_solve_algo,
                 norm_limit=(self.health.norm_limit
                             if self.health is not None else float("inf")),
+                cells_entities=self._cells_entities(),
             ), None
+
+    def _cells_entities(self) -> int:
+        """The entity bucket of the cells route: the most users one
+        micro-batch can touch (``batch_records`` a partition), so that the
+        route runs one set of programs whatever the batch."""
+        return _pow2_ceil(
+            self.stream.batch_records * self.consumer.num_partitions, 8)
 
     def _fetch(self, fold, solved):
         """The back half of ``_dispatch``'s pair: (rows [T, k] f32, probe
@@ -936,29 +911,30 @@ class StreamSession:
 
     def prewarm(self, *, max_touched: int | None = None,
                 max_width: int | None = None) -> dict:
-        """Trace the fold-in pow2 bucket grid up front (ISSUE 13).
+        """Run every fold-in program once, up front (ISSUE 13).
 
-        The solve shapes a live stream produces are bounded: touched
-        users bucket to ``_pow2_ceil(t, 8)`` up to ``batch_records`` and
-        rectangle widths to pow2 multiples of ``pad_multiple`` up to the
-        heaviest neighbor list.  Walking that grid once with synthetic
-        zero batches compiles every program a cold process would
-        otherwise trace mid-stream — the ROADMAP-measured fold-in bound
-        ("per-batch jit re-trace dominates") paid at startup instead of
-        against live updates (and not at all on a warm restart when
-        ``ALSConfig.compile_cache_dir`` is wired — the persistent cache
-        serves each compile).  Results are discarded; the jit cache keys
-        on shapes, so the stream's bits are untouched.
-
-        Covers the PADDED fold layout (the micro-batch default).  Tiled
-        fold-in block statics are data-dependent (chunk cuts follow the
-        batch's actual neighbor lists), so a tiled-layout session
-        returns ``{"skipped": ...}`` — its first-batch compile is
-        bounded by the compile cache instead.
+        The programs a live stream runs are a FIXED set, whatever its
+        lists (``foldin.fold_route``): the padded route's rectangles —
+        touched users bucket to ``_pow2_ceil(t, 8)`` up to
+        ``batch_records`` and widths to pow2 multiples of ``pad_multiple``
+        up to ``foldin.CHUNK`` (6 x 5 = 30 at the defaults) — and the
+        cells route's ``len(foldin.SLABS)`` Gram programs and one solve
+        (5), which take every batch with a longer list in it, however long.
+        Walking them once with synthetic zero batches compiles every
+        program a cold process would otherwise trace mid-stream — the
+        ROADMAP-measured fold-in bound ("per-batch jit re-trace dominates")
+        paid at startup instead of against live updates (and not at all on
+        a warm restart when ``ALSConfig.compile_cache_dir`` is wired — the
+        persistent cache serves each compile).  No list can outgrow the
+        set inside a window.  Results are discarded; the jit cache keys on
+        shapes, so the stream's bits are untouched.  ``max_touched`` /
+        ``max_width`` cut the padded route's grid short (a test's, a tool's
+        smaller stream).
 
         Returns ``{"programs", "new_traces", "prewarm_s"}``; serving a
-        first real batch inside the warmed grid afterwards traces
-        nothing (``tests/test_staging.py`` pins it)."""
+        first real batch afterwards traces nothing
+        (``tests/test_staging.py``, ``tests/test_foldin_cells.py`` pin
+        it)."""
         with span("stream/prewarm"):
             return self._prewarm_impl(max_touched=max_touched,
                                       max_width=max_width)
@@ -966,8 +942,6 @@ class StreamSession:
     def _prewarm_impl(self, *, max_touched: int | None = None,
                       max_width: int | None = None) -> dict:
         import time as _time
-
-        from cfk_tpu.streaming.foldin import _pow2_ceil, trace_count
 
         t0 = _time.time()
         if self._offload:
@@ -977,22 +951,13 @@ class StreamSession:
             self.metrics.note("prewarm", note)
             return {"programs": 0, "new_traces": 0, "prewarm_s": 0.0,
                     "skipped": note}
-        if self._layout != "padded":
-            note = ("skipped: tiled fold-in block statics are "
-                    "data-dependent; rely on compile_cache_dir")
-            self.metrics.note("prewarm", note)
-            return {"programs": 0, "new_traces": 0, "prewarm_s": 0.0,
-                    "skipped": note}
         mt = max(int(max_touched or self.stream.batch_records), 1)
-        if max_width is None:
-            # the longest list there is, with one new rating on it
-            max_width = self.state.longest_list() + 1
         pm = max(self.config.pad_multiple, 1)
         widths = []
         p = _pow2_ceil(1, pm)
-        while True:
+        while p <= CHUNK:
             widths.append(p)
-            if p >= max_width:
+            if p >= (max_width or CHUNK):
                 break
             p *= 2
         ents = []
@@ -1008,23 +973,35 @@ class StreamSession:
         num_m = int(fixed.shape[0])
         if self.health is not None:
             self._table_word(fixed)  # the fixed side's probe, once a table
+
+        def run(lists) -> None:
+            fold_in_dispatch(
+                fixed, lists,
+                lam=self._overrides.lam,
+                solver=self.config.solver,
+                pad_multiple=self.config.pad_multiple,
+                reg_solve_algo=self._overrides.reg_solve_algo,
+                cells_entities=self._cells_entities(),
+            ).fetch()
+
+        def zeros(n):
+            # valid table rows, ratings zero: the solved values are
+            # discarded
+            return (np.minimum(np.arange(n), num_m - 1).astype(np.int32),
+                    np.zeros(n, np.float32))
+
         for e in ents:
             for p in widths:
-                # One user at the full width pins the rectangle to
-                # exactly (e, p); movie rows are valid table rows,
-                # ratings zero — the solved values are discarded.
-                wide = (np.minimum(np.arange(p), num_m - 1)
-                        .astype(np.int32),
-                        np.zeros(p, np.float32))
-                thin = (np.zeros(1, np.int32), np.zeros(1, np.float32))
-                fold_in_dispatch(
-                    fixed, [wide] + [thin] * (e - 1),
-                    lam=self._overrides.lam,
-                    solver=self.config.solver,
-                    pad_multiple=self.config.pad_multiple,
-                    reg_solve_algo=self._overrides.reg_solve_algo,
-                ).fetch()
+                # one user at the full width pins the rectangle to
+                # exactly (e, p)
+                run([zeros(p)] + [zeros(1)] * (e - 1))
                 programs += 1
+        for slab in SLABS:
+            # one list of exactly ``slab`` chunk rows runs that slab's Gram
+            # program (and the route's one solve, counted once below)
+            run([zeros(slab * CHUNK)])
+            programs += 1
+        programs += 1
         out = {
             "programs": programs,
             "new_traces": trace_count() - before,
@@ -1389,7 +1366,8 @@ class StreamSession:
         with self.metrics.phase("stage"), \
                 span("stream/batch/stage", records=batch.num_records) as sp:
             pending = self.state.stage(batch.updates, over)
-            sp.set(fresh=pending.stats.fresh,
+            sp.set(fresh=pending.stats.fresh, stale=pending.stats.stale,
+                   rerated=pending.stats.rerated,
                    new_users=pending.stats.new_users)
         solve = (self._dispatch(pending, self._overrides, over)
                  if pending.touched_rows else (None, None))
@@ -1509,9 +1487,8 @@ class StreamSession:
                touched=len(rows), new_users=summary["new_users"],
                commit_bytes=commit_bytes)
         if fold is not None:
-            sp.set(entities=fold.entities, width=fold.width, rank=fold.rank,
-                   gather_bytes=fold.gather_bytes,
-                   operand_bytes=fold.operand_bytes)
+            sp.set(rank=fold.rank, gather_bytes=fold.gather_bytes,
+                   operand_bytes=fold.operand_bytes, **fold.counts())
         summary["stream_step"] = self.stream_step
         if (self.stream.retrain_every is not None
                 and self.stream_step % self.stream.retrain_every == 0):

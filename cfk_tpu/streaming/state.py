@@ -7,7 +7,18 @@ updates to the same cell in either order, must converge to the same state.
 ``StreamState`` provides exactly that: the merge of the base dataset's
 ratings and every applied ``(user, movie, rating, seq)`` upsert, with
 last-seq-wins per (user, movie) cell (equal seq = a retried append,
-dropped).
+dropped).  ``seq`` is the EVENT's sequence number, not its place in the log:
+a producer that is handed the events' own numbers
+(``StreamProducer.send_many(seqs=)``) may append them in any order, and a
+record that arrives after a newer one of its cell is outranked by the
+cell's applied ``seq``: counted ``stale``, consumed and committed with its
+batch (its offset is under the unit's cursor), and changes nothing.
+
+A list is read as ARRAYS: the base CSR slice merged with the cells the
+stream changed (the user's delta, a staged overlay) by numpy, so what runs
+in Python is proportional to the cells that changed, never to the list (a
+reviewer with 10,000 items costs a slice, a ``searchsorted`` and an
+insert); ``stage`` looks the one cell of each record up.
 
 Nothing here is persisted by the state itself: it is a deterministic
 function of (base dataset, the cells every commit unit of the store applied),
@@ -31,12 +42,16 @@ picked up when the operator retrains from base + log.
 from __future__ import annotations
 
 import dataclasses
+import weakref
 
 import numpy as np
 
 from cfk_tpu.transport.serdes import RatingUpdate
+from cfk_tpu.utils.search import bisect_slices, csr_find
 
 _BASE_SEQ = -1
+_NO_MOVIES = np.zeros(0, np.int32)
+_NO_RATINGS = np.zeros(0, np.float32)
 
 # One applied cell as the store holds it: a commit unit's, a snapshot's.
 CELL = np.dtype([("row", "<i8"), ("movie", "<i4"), ("rating", "<f4"),
@@ -64,12 +79,57 @@ def last_per_cell(cells: np.ndarray) -> np.ndarray:
     return cells[order[last]]
 
 
+def _ascending_lists(indptr: np.ndarray, movies: np.ndarray) -> bool:
+    """Every list of the CSR strictly ascending (no item twice): one pass."""
+    n = movies.shape[0]
+    if n < 2:
+        return True
+    ok = movies[1:] > movies[:-1]
+    starts = indptr[1:-1]
+    ok[starts[(starts > 0) & (starts < n)] - 1] = True  # between two lists
+    return bool(ok.all())
+
+
+# The (indptr, item rows) pairs ``_ascending_lists`` has passed, held weakly:
+# a state is built over the same arrays again by every successor of a killed
+# stream task, inside the serving window, and the pass reads every cell (0.9 s
+# for 144 M of them).  A base CSR is never written once a state holds it.
+_PASSED: list[tuple[weakref.ref, weakref.ref]] = []
+
+
+def _canonical_csr(indptr, movies, ratings):
+    """The CSR with every list strictly ascending: itself (by reference)
+    where it already is, else a copy sorted by (row, item) with the LAST
+    cell of a repeated item kept (a ``Dataset``'s order, a repeated
+    observation)."""
+    _PASSED[:] = [(i, m) for i, m in _PASSED
+                  if i() is not None and m() is not None]
+    if any(i() is indptr and m() is movies for i, m in _PASSED):
+        return indptr, movies, ratings
+    if _ascending_lists(indptr, movies):
+        _PASSED.append((weakref.ref(indptr), weakref.ref(movies)))
+        return indptr, movies, ratings
+    n = movies.shape[0]
+    rows = np.repeat(np.arange(indptr.shape[0] - 1, dtype=np.int64),
+                     np.diff(indptr))
+    order = np.lexsort((np.arange(n), movies, rows))
+    r, m = rows[order], movies[order]
+    last = np.ones(n, bool)
+    last[:-1] = (r[1:] != r[:-1]) | (m[1:] != m[:-1])
+    keep = order[last]
+    out = np.zeros(indptr.shape[0], np.int64)
+    np.cumsum(np.bincount(r[last], minlength=indptr.shape[0] - 1),
+              out=out[1:])
+    return out, movies[keep], ratings[keep]
+
+
 @dataclasses.dataclass
 class ApplyStats:
     """What one batch application did — chaos tests assert these fired."""
 
     fresh: int = 0          # state-changing upserts applied
     stale: int = 0          # outranked by an already-applied seq (dup/reorder)
+    rerated: int = 0        # of the fresh: the cell already held a value
     unknown_movie: int = 0  # no factor column for this movie — dropped
     new_users: int = 0      # rows grown for first-seen users
 
@@ -126,9 +186,11 @@ class StreamState:
     def from_csr(cls, indptr, movies, ratings, *, num_movies: int
                  ) -> "StreamState":
         """The state over a per-user CSR of base ratings (``indptr``
-        [U + 1], item rows and ratings per cell, taken by reference), raw
-        ids = rows on both sides.  A list may hold an item twice; the
-        later cell wins, as with a ``Dataset``."""
+        [U + 1], item rows and ratings per cell, taken by reference where
+        every list is ascending with no item twice, which one pass checks),
+        raw ids = rows on both sides.  A list may hold an item twice or out
+        of order; the state then keeps a sorted copy in which the later
+        cell wins, as with a ``Dataset``."""
         indptr = np.asarray(indptr, np.int64)
         movies = np.asarray(movies, np.int32)
         ratings = np.asarray(ratings, np.float32)
@@ -151,11 +213,15 @@ class StreamState:
         new = type(self).__new__(type(self))
         new._init(self._base_indptr, self._base_movies, self._base_ratings,
                   num_movies=self.num_movies, user_raw=self._base_user_raw,
-                  movie_raw=self._movie_raw)
+                  movie_raw=self._movie_raw, canonical=True)
         return new
 
     def _init(self, indptr, movies, ratings, *, num_movies, user_raw,
-              movie_raw) -> None:
+              movie_raw, canonical: bool = False) -> None:
+        # every base list strictly ascending, one cell an item: what every
+        # read below relies on, established here, once
+        if not canonical:
+            indptr, movies, ratings = _canonical_csr(indptr, movies, ratings)
         self._base_indptr = indptr
         self._base_movies = movies
         self._base_ratings = ratings
@@ -215,50 +281,100 @@ class StreamState:
 
     # -- queries -------------------------------------------------------------
 
-    def _cells(self, row: int, overlay: dict | None = None
-               ) -> dict[int, tuple[float, int]]:
-        """row's full (movie_row -> (rating, seq)) map, base + delta
-        (+ an optional staged overlay for that row)."""
-        cells: dict[int, tuple[float, int]] = {}
-        if row < self._num_base_users:
-            lo, hi = self._base_indptr[row], self._base_indptr[row + 1]
-            for mv, rt in zip(self._base_movies[lo:hi].tolist(),
-                              self._base_ratings[lo:hi].tolist()):
-                cells[mv] = (rt, _BASE_SEQ)
-        cells.update(self._delta.get(row, {}))
-        if overlay:
-            cells.update(overlay)
-        return cells
+    def _written(self, row: int, movie: int, over=()) -> tuple | None:
+        """(rating, seq) of one cell as the stream wrote it, or None: the
+        newest of the staged batches ``over`` that writes it, else the
+        delta."""
+        for pending in reversed(over):
+            got = pending.cell_writes.get(row)
+            if got is not None and movie in got:
+                return got[movie]
+        got = self._delta.get(row)
+        if got is not None and movie in got:
+            return got[movie]
+        return None
+
+    def _held(self, row: int, movie: int, over=()) -> tuple | None:
+        """(rating, seq) of one cell as the applied state holds it once
+        the staged batches ``over`` are committed, or None: what the stream
+        wrote (``_written``), else the base (seq -1)."""
+        got = self._written(row, movie, over)
+        if got is None:
+            at = int(csr_find(self._base_indptr, self._base_movies,
+                              np.array([row]), np.array([movie]))[0])
+            if at >= 0:
+                got = float(self._base_ratings[at]), _BASE_SEQ
+        return got
 
     def neighbors(self, row: int, overlay: dict | None = None
                   ) -> tuple[np.ndarray, np.ndarray]:
-        """(movie rows int32 ascending, ratings f32) for one user row.
+        """(movie rows int32 ascending, ratings f32) for one user row:
+        base + delta (+ an optional staged overlay for that row).
 
         Sorted by movie row — the canonical neighbor order, so the solve
         input (and therefore its bits) depends only on the state, never on
         arrival order.
         """
-        cells = self._cells(row, overlay)
-        movies = sorted(cells)
-        return (np.asarray(movies, np.int32),
-                np.asarray([cells[m][0] for m in movies], np.float32))
+        return self.neighbors_many((row,), (overlay,))[0]
 
-    def longest_list(self) -> int:
-        """Cells of the longest base list: the widest rectangle a fold-in
-        of base users starts from."""
-        if self._num_base_users == 0:
-            return 0
-        return int(np.diff(self._base_indptr).max())
+    def neighbors_many(self, rows, overlays
+                       ) -> list[tuple[np.ndarray, np.ndarray]]:
+        """``neighbors(row, overlay)`` of each row, as views of two flat
+        arrays: the rows' base slices laid end to end by one concatenate,
+        and the cells the stream changed (the delta, the overlays) merged in
+        by ONE search over the whole batch: a changed cell the base holds
+        replaces its rating in place, the others are inserted.  What runs in
+        Python is proportional to the rows and their CHANGED cells (a
+        micro-batch's touched users: one or two each), never to the lists,
+        and the numpy calls are a dozen a batch, not a dozen a row."""
+        n = len(rows)
+        base_n, indptr = self._num_base_users, self._base_indptr
+        cuts = [(int(indptr[row]), int(indptr[row + 1]))
+                if row < base_n else (0, 0) for row in rows]
+        ptr = np.zeros(n + 1, np.int64)
+        np.cumsum([hi - lo for lo, hi in cuts], out=ptr[1:])
+        if ptr[-1]:
+            mv = np.concatenate([self._base_movies[lo:hi] for lo, hi in cuts])
+            rt = np.concatenate([self._base_ratings[lo:hi] for lo, hi in cuts])
+        else:
+            mv, rt = _NO_MOVIES, _NO_RATINGS
+        # the changed cells of the batch: (slot, movie row, rating)
+        c_len, c_mv, c_rt = [0] * n, [], []
+        for slot, (row, overlay) in enumerate(zip(rows, overlays)):
+            changed = self._delta.get(row)
+            if overlay:
+                changed = {**changed, **overlay} if changed else overlay
+            if changed:
+                c_len[slot] = len(changed)
+                c_mv.extend(changed)
+                c_rt.extend([cell[0] for cell in changed.values()])
+        if c_mv:
+            c_slot = np.repeat(np.arange(n), c_len)
+            c_mv = np.asarray(c_mv, np.int32)
+            order = np.lexsort((c_mv, c_slot))
+            c_slot, c_mv = c_slot[order], c_mv[order]
+            c_rt = np.asarray(c_rt, np.float32)[order]
+            end = ptr[c_slot + 1]
+            at = bisect_slices(mv, ptr[c_slot], end, c_mv)
+            held = at < end
+            held[held] = mv[at[held]] == c_mv[held]
+            rt[at[held]] = c_rt[held]  # rt is this call's own copy
+            new = ~held
+            mv = np.insert(mv, at[new], c_mv[new])
+            rt = np.insert(rt, at[new], c_rt[new])
+            ptr[1:] += np.cumsum(np.bincount(c_slot[new], minlength=n))
+        ends = ptr.tolist()
+        return [(mv[lo:hi], rt[lo:hi]) for lo, hi in zip(ends[:-1], ends[1:])]
 
     def to_coo(self):
         """The merged rating state as a raw-id COO (for warm full retrains:
         base + every committed upsert, exactly what the factors model).
 
-        Rows the stream never touched pass through vectorized (deduped to
-        last-occurrence per cell, matching ``_cells``'s dict semantics for
-        repeated base observations); only delta rows pay the per-row merge
-        — O(touched) Python work, not O(all users), so ML-25M-scale exits
-        and periodic retrains don't stall on an interpreter loop."""
+        Rows the stream never touched pass through vectorized (the base
+        CSR is one cell an item, ascending: ``_canonical_csr``); the delta
+        rows are merged in one ``neighbors_many`` — O(touched) Python work,
+        not O(all users), so ML-25M-scale exits and periodic retrains don't
+        stall on an interpreter loop."""
         from cfk_tpu.data.blocks import RatingsCOO
 
         raw_users = self.user_raw_ids()
@@ -266,25 +382,15 @@ class StreamState:
         base_rows = np.repeat(
             np.arange(self.num_base_users, dtype=np.int64), counts
         )
-        # last-occurrence dedup per (row, movie) cell: stable sort keeps
-        # original order within equal keys, so each group's tail is the
-        # entry _cells would have kept
-        key = base_rows * np.int64(self.num_movies) + self._base_movies
-        order = np.argsort(key, kind="stable")
-        ks = key[order]
-        last = np.ones(ks.shape[0], bool)
-        last[:-1] = ks[1:] != ks[:-1]
-        sel = order[last]
-        untouched = ~np.isin(base_rows[sel],
-                             np.fromiter(self._delta, np.int64,
-                                         len(self._delta)))
-        sel = sel[untouched]
+        sel = np.flatnonzero(~np.isin(
+            base_rows, np.fromiter(self._delta, np.int64, len(self._delta))))
         movie_raw = self._movie_raw_ids()
         users = [raw_users[base_rows[sel]]]
         movies = [movie_raw[self._base_movies[sel]].astype(np.int64)]
         ratings = [self._base_ratings[sel]]
-        for row in sorted(self._delta):
-            mv, rt = self.neighbors(row)
+        touched = sorted(self._delta)
+        for row, (mv, rt) in zip(touched, self.neighbors_many(
+                touched, [None] * len(touched))):
             users.append(np.full(mv.shape[0], raw_users[row], np.int64))
             movies.append(movie_raw[mv].astype(np.int64))
             ratings.append(rt)
@@ -315,7 +421,6 @@ class StreamState:
         """
         stats = ApplyStats()
         writes: dict[int, dict[int, tuple[float, int]]] = {}
-        cells_cache: dict[int, dict] = {}  # applied view, one build per row
         new_raw: list[int] = []
         new_rows: dict[int, int] = {}
         next_row = self.num_users
@@ -323,6 +428,7 @@ class StreamState:
             for raw in earlier.new_user_raw:
                 new_rows[raw] = next_row
                 next_row += 1
+        rows, movies, known = [], [], []
         for upd in updates:
             mv = self.movie_row(upd.movie)
             if mv is None:
@@ -337,19 +443,28 @@ class StreamState:
                 new_raw.append(int(upd.user))
                 next_row += 1
                 stats.new_users += 1
-            current = writes.get(row, {}).get(mv)
+            rows.append(row)
+            movies.append(mv)
+            known.append(upd)
+        # the base's cells among them, in one lookup for the batch
+        in_base = (csr_find(self._base_indptr, self._base_movies,
+                            np.asarray(rows, np.int64),
+                            np.asarray(movies, np.int64)) >= 0).tolist()
+        for row, mv, upd, based in zip(rows, movies, known, in_base):
+            mine = writes.get(row)
+            current = mine.get(mv) if mine else None
             if current is None:
-                cells = cells_cache.get(row)
-                if cells is None:
-                    cells = cells_cache[row] = (
-                        self._cells(row, overlay_of(row, over))
-                        if row < self.num_users or over else {}
-                    )
-                current = cells.get(mv)
-            if current is not None and upd.seq <= current[1]:
-                stats.stale += 1
-                continue
-            writes.setdefault(row, {})[mv] = (float(upd.rating), int(upd.seq))
+                current = self._written(row, mv, over)
+            seq = (current[1] if current is not None
+                   else _BASE_SEQ if based else None)
+            if seq is not None:
+                if upd.seq <= seq:
+                    stats.stale += 1
+                    continue
+                stats.rerated += 1
+            if mine is None:
+                mine = writes[row] = {}
+            mine[mv] = (float(upd.rating), int(upd.seq))
             stats.fresh += 1
         return PendingApply(
             touched_rows=tuple(sorted(writes)),

@@ -1736,7 +1736,7 @@ def scenario_serve_under_foldin() -> dict:
             seen_indptr=np.asarray([0, hi - lo], np.int64),
         )
         if extra_seen:
-            e2._seen_hot[0] = list(extra_seen)
+            e2._extend_seen([(0, mv) for mv in extra_seen])
         sc, ids_ = e2.topk(np.asarray([0]), k)
         return sc[0], ids_[0]
 

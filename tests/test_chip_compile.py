@@ -432,6 +432,37 @@ def test_foldin_amazon14_stream_cell_shape(chip, as_tpu, touched, width):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
+@pytest.mark.parametrize("slab", [4096, 1024, 256, 64])
+def test_foldin_cells_route_amazon14_skew_cell_shape(chip, as_tpu, slab):
+    """The cells route of ``amazon14-stream-r128-skew.serve-foldin-skew``:
+    each slab's Gram program (gather, one batched ``HIGHEST`` GEMM over the
+    chunk rows, summed by owner onto 256 systems) against the 9,350,144-row
+    float32 table, and the route's one solve (the lane-batched Cholesky
+    inside it), under the names the benchmark's readers look for.  The
+    largest slab gathers 268 MB and makes as much of chunk Grams: nothing
+    the size of the table."""
+    import time
+
+    from cfk_tpu.streaming import foldin
+
+    assert slab in foldin.SLABS and len(foldin.SLABS) == 4
+    k, e = 128, 256
+    t0 = time.perf_counter()
+    gram = foldin._cells_fold_gram.lower(
+        chip((9_350_144, k), f32), chip((slab, 2 * foldin.CHUNK + 2), i32),
+        chip((e, k, k), f32), chip((e, k), f32)).compile()
+    assert time.perf_counter() - t0 < 20.0
+    assert "jit__cells_fold_gram" in gram.as_text()
+    assert gram.memory_analysis().temp_size_in_bytes < 1 << 30
+    solve = foldin._cells_fold_solve.lower(
+        chip((e, k, k), f32), chip((e, k), f32), chip((e,), f32),
+        chip((), i32), chip((), f32),
+        lam=LAM, solver="cholesky", reg_solve_algo=None).compile()
+    text = solve.as_text()
+    assert "jit__cells_fold_solve" in text and "tpu_custom_call" in text
+    assert "Cholesky" not in text and "triangular-solve" not in text.lower()
+
+
 @pytest.mark.parametrize("k,e", [(128, 2561), (64, 333), (8, 8)])
 def test_cholesky_lanes(chip, k, e):
     """The lane-batched Cholesky alone, at the widths the half-steps name

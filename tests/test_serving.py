@@ -886,7 +886,7 @@ def test_hot_user_cache_reserves_foldin_commits(tmp_path):
         seen_movies=eng._seen_movies, seen_indptr=eng._seen_indptr,
         tile_m=16,
     )
-    live._seen_hot[vrow] = [rated_row]
+    live._extend_seen([(vrow, rated_row)])
     want_s, want_i = live.topk(np.asarray([vrow]), 5)
     np.testing.assert_array_equal(after_s, want_s)
     np.testing.assert_array_equal(after_i, want_i)

@@ -321,32 +321,6 @@ def test_stream_session_prewarm_pins_zero_new_traces(tmp_path):
         "first real fold-in batch re-traced after prewarm"
 
 
-def test_stream_session_prewarm_skips_tiled_layout(tmp_path):
-    from cfk_tpu.config import ALSConfig
-    from cfk_tpu.data.blocks import Dataset
-    from cfk_tpu.data.synthetic import synthetic_netflix_coo
-    from cfk_tpu.models.als import train_als
-    from cfk_tpu.streaming import StreamConfig, StreamSession
-    from cfk_tpu.transport import InMemoryBroker
-    from cfk_tpu.transport.checkpoint import CheckpointManager
-
-    from cfk_tpu.streaming import ensure_updates_topic
-
-    ds = Dataset.from_coo(synthetic_netflix_coo(30, 12, 260, seed=0))
-    cfg = ALSConfig(rank=4, num_iterations=1)
-    base = train_als(ds, cfg)
-    broker = InMemoryBroker()
-    ensure_updates_topic(broker)
-    sess = StreamSession(
-        ds, cfg, broker, CheckpointManager(str(tmp_path)),
-        stream=StreamConfig(batch_records=8, foldin_layout="tiled"),
-        base_model=base,
-    )
-    warm = sess.prewarm()
-    assert warm["programs"] == 0
-    assert "skipped" in warm
-
-
 # --- compile cache -----------------------------------------------------------
 
 

@@ -192,8 +192,7 @@ def _expected_rows(state, rows, m_host, lam):
     return out
 
 
-@pytest.mark.parametrize("layout", ["padded", "tiled"])
-def test_fold_in_matches_batch_half_solve(ds, layout):
+def test_fold_in_matches_batch_half_solve(ds):
     """The restricted half-iteration == a direct batch solve of the same
     rows' normal equations (the ISSUE's one-half-iteration parity)."""
     import jax.numpy as jnp
@@ -209,28 +208,9 @@ def test_fold_in_matches_batch_half_solve(ds, layout):
     neighbor_data = [state.neighbors(r) for r in rows]
     got = fold_in_rows(
         jnp.asarray(m_host), neighbor_data, lam=0.05, solver="cholesky",
-        layout=layout,
     )
     want = _expected_rows(state, rows, m_host, 0.05)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-4)
-
-
-def test_fold_in_tiled_padded_parity(ds):
-    import jax.numpy as jnp
-
-    from cfk_tpu.streaming.foldin import fold_in_rows
-
-    state = StreamState(ds)
-    rng = np.random.default_rng(1)
-    m_host = rng.standard_normal(
-        (ds.movie_blocks.padded_entities, 4)
-    ).astype(np.float32)
-    neighbor_data = [state.neighbors(r) for r in range(8)]
-    a = fold_in_rows(jnp.asarray(m_host), neighbor_data, lam=0.05,
-                     solver="cholesky", layout="padded")
-    b = fold_in_rows(jnp.asarray(m_host), neighbor_data, lam=0.05,
-                     solver="cholesky", layout="tiled")
-    np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
 
 def test_session_foldin_rmse_parity_with_batch_solve(ds, cfg, base, tmp_path):
