@@ -1607,10 +1607,12 @@ def serve_seen_tiles_sharded(mesh: Mesh, cells, seen_tiles, *, shape,
                              tile_m: int):
     """The [NT, B, W] exclusion rectangle of ``serve_topk_sharded`` and its
     [NT] hits (a ``SeenTiles``), each shard's NT / shards tiles built on
-    the chip that scans them: ``cells`` (one replicated [4, capacity] piece
-    of ``chunk_seen_cells``) goes to every chip, and none ever holds the
-    other chips' slices.  ``seen_tiles`` None starts the rectangle; one
-    that earlier pieces went into is donated and takes this piece on
+    the chip that scans them: ``cells`` (the batch's whole cell list as
+    ``engine._seen_chunks`` pads it to a rung of ``SEEN_PIECE_RUNGS``,
+    replicated) goes to every chip, each keeps its own tiles' cells in one
+    run of one program, and none ever holds the other chips' slices.
+    ``seen_tiles`` None starts the rectangle; one that an earlier run went
+    into (a list past the top rung) is donated and takes this one on
     top."""
     fn = _serve_seen_tiles_sharded_fn(mesh, tuple(shape), tile_m,
                                       seen_tiles is None)

@@ -33,11 +33,15 @@ user rows" and "[B, K] ids+scores":
   re-tracing per batch — the same trick PR 6 used for fold-in shapes,
 - the exclusion rectangle built on the device: the host groups the
   batch's seen cells at tile boundaries (a few thousand entries) and
-  hands over that list in pieces whose size depends on the padded batch
-  size alone; a scatter program fills and writes the [NT, B, W] rectangle
-  where the scorer reads it.  A batch with more cells than one piece runs
-  the same scatter program again on top, so ``prewarm``'s batch-size
-  ladder closes the program set whatever the data,
+  hands over that list whole, padded to a number of pieces from a short
+  ladder (1, 2, 4, 8, 16: ``SEEN_PIECE_RUNGS``), a piece's size depending
+  on the padded batch size alone; ONE run of one scatter program fills,
+  scatters and lays out the [NT, B, W] rectangle where the scorer reads
+  it, whatever the batch's cells (a list cut into pieces, each a run of
+  its own, paid a pass or two over the 299 MB rectangle a piece: PERF.md
+  section 6, PR 42).  A list past the top rung runs the top program
+  again on its own result, so ``prewarm``'s batch-size ladder, walked
+  over the rungs, closes the program set whatever the data,
 - a batch in two halves (``TopKBatch``): ``stage`` gathers, groups and
   uploads and captures the table; ``compute`` hands one batch to the
   device and fetches another's answer, the same one for ``topk``, the one
@@ -67,12 +71,14 @@ import threading
 import numpy as np
 
 from cfk_tpu.serving.topk_kernel import (
+    SEEN_PIECE_RUNGS,
     _pow2_ceil,
     chunk_seen_cells,
     group_seen_cells,
     scatter_seen_cells,
     score_passes,
     seen_cell_capacity,
+    seen_piece_rung,
     slab_tiles,
     topk_scores_counted,
 )
@@ -656,21 +662,19 @@ class ServeEngine:
         return compute(batch, batch)
 
     def stage(self, user_rows, k: int, *, exclude_seen: bool = True,
-              force_exact: bool = False,
-              min_seen_chunks: int = 1) -> "TopKBatch":
+              force_exact: bool = False, warm: bool = False) -> "TopKBatch":
         """The host's part of ``topk``'s front half: gather the user rows,
         group the seen cells, upload both.  The ``TopKBatch`` it returns
         owns the table it will be scored against (captured under the lock
         with the user rows and the epoch) and everything its answer needs,
         so a table swapped before the fetch changes nothing for it: no
         donation, a batch in flight keeps its table.  ``compute`` hands it
-        to the device and fetches it.  ``prewarm`` asks for the seen cells
-        in two pieces at least, which runs every program a batch over the
-        capacity runs.  The two-stage route syncs with the host between
+        to the device and fetches it.  ``warm`` is ``prewarm``'s: the
+        batch's cell list is padded past the top rung, so that it runs
+        both of that rung's programs, and the lower rungs' run beside it
+        (``_warm_seen_rungs``): every program the rectangle of such a
+        batch can take.  The two-stage route syncs with the host between
         its stages: its batch comes back already answered."""
-        import jax
-        import jax.numpy as jnp
-
         user_rows = np.asarray(user_rows, dtype=np.int64)
         n = user_rows.shape[0]
         if n == 0:
@@ -708,8 +712,7 @@ class ServeEngine:
                 sp.set(seen_cells=len(movies))
         if (self.serve_mode == "two_stage" and not force_exact
                 and not self._two_stage_disabled):
-            out = self._topk_two_stage(cluster, u, n, b, k, seen_pad,
-                                       min_seen_chunks)
+            out = self._topk_two_stage(cluster, u, n, b, k, seen_pad, warm)
             if out is not None:
                 return TopKBatch(self, n=n, k=k, epoch=epoch,
                                  ordinal=ordinal, result=out)
@@ -726,7 +729,7 @@ class ServeEngine:
                     num_movies=self.num_movies,
                     tile_m=self.tile_m, num_tiles=tiles,
                 )
-                seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
+                seen = _seen_chunks(sp, cells, shape, warm)
                 if self.mesh is not None:
                     # how many of the cells each chip keeps for its slice
                     sp.set(shard_cells=np.bincount(
@@ -739,18 +742,15 @@ class ServeEngine:
             nbytes = u.nbytes
             if seen is not None:
                 nbytes += sum(c.nbytes for c in seen)
-            if self.mesh is None:
-                put = jnp.asarray
-            else:
-                # u and the cell pieces go to every chip, from the host
-                put = functools.partial(
-                    jax.device_put, device=_replicated(self.mesh))
+            if self.mesh is not None:
                 sp.set(shards=self._shards,
                        replicated_bytes=nbytes * self._shards)
             if seen is not None:
-                seen = [put(c) for c in seen]
-            u = put(u)
+                seen = [_put(c, self.mesh) for c in seen]
+            u = _put(u, self.mesh)
             sp.set(bytes=nbytes)
+        if warm and seen is not None:
+            self._warm_seen_rungs(shape, self.mesh)
         # what the fetch will say of the batch on ``serve/batch/compute``
         shard_tiles = tiles // self._shards
         slab = slab_tiles(shard_tiles, b, 0 if shape is None else shape[2],
@@ -779,13 +779,15 @@ class ServeEngine:
 
     def _seen_tiles(self, chunks, shape, mesh=None):
         """The [NT, B, W] exclusion rectangle on the device and which of
-        its tiles hold a cell (a ``SeenTiles``), from the uploaded pieces
-        of the batch's cell list (``_seen_chunks``; None = no exclusion):
-        one run of the scatter program per piece, the first onto a fresh
-        all-padding rectangle.  Every caller — exact,
-        item-sharded, two-stage rescore — gets its rectangle here, from
-        the one ``scatter_seen_cells``; over a mesh each chip builds the
-        tiles it scans (``parallel.spmd.serve_seen_tiles_sharded``)."""
+        its tiles hold a cell (a ``SeenTiles``), from the batch's uploaded
+        cell list (``_seen_chunks``; None = no exclusion): one run of the
+        scatter program, which starts from a fresh all-padding rectangle;
+        a list past the ladder's top rung comes as several arrays and each
+        further one runs the top rung's program on the rectangle so far.
+        Every caller — exact, item-sharded, two-stage rescore — gets its
+        rectangle here, from the one ``scatter_seen_cells``; over a mesh
+        each chip builds the tiles it scans
+        (``parallel.spmd.serve_seen_tiles_sharded``)."""
         if mesh is None:
             build = _seen_tiles_jit_fn()
         else:
@@ -798,8 +800,22 @@ class ServeEngine:
                                tile_m=self.tile_m)
         return seen_tiles
 
-    def _topk_two_stage(self, cluster, u, n, b, k, seen_pad,
-                        min_seen_chunks=1):
+    def _warm_seen_rungs(self, shape, mesh=None) -> None:
+        """``prewarm``'s: the rectangle's program of every rung under the
+        top, run once over an all-padding list and waited for, so that no
+        two of their rectangles are alive at once.  The top rung's two
+        programs, the one that starts a rectangle and the one that adds to
+        it, are the warm batch's own (``_seen_chunks``)."""
+        import jax
+
+        nt, b, _ = shape
+        none = np.zeros((4, 0), np.int32)
+        for rung in SEEN_PIECE_RUNGS[:-1]:
+            (pad,) = chunk_seen_cells(none, rung * seen_cell_capacity(b), nt)
+            jax.block_until_ready(
+                self._seen_tiles([_put(pad, mesh)], shape, mesh))
+
+    def _topk_two_stage(self, cluster, u, n, b, k, seen_pad, warm=False):
         """One two-stage batch: centroid probe → batch-union shortlist →
         exact rescore.  Returns ``(vals, ids)`` sliced to ``n``, or None
         after recording a fault — the caller then takes the exact scan."""
@@ -848,10 +864,12 @@ class ServeEngine:
                         index, shortlist, movies, indptr_pad, b,
                         tile_m=self.tile_m,
                     )
-                    seen = _seen_chunks(sp, cells, shape, min_seen_chunks)
+                    seen = _seen_chunks(sp, cells, shape, warm)
                 with span("serve/batch/upload",
                           bytes=sum(c.nbytes for c in seen)):
                     seen = [jnp.asarray(c) for c in seen]
+                if warm:
+                    self._warm_seen_rungs(shape)
         with span("serve/rescore", n=n, b=b, k=k, rows=shortlist.rows,
                   rows_padded=shortlist.rows_padded):
             seen_tiles = self._seen_tiles(seen, shape)
@@ -977,7 +995,7 @@ class ServeEngine:
 
             def warm(take):
                 batch = self.stage(take, k, exclude_seen=exclude_seen,
-                                   min_seen_chunks=2)
+                                   warm=True)
                 compute(batch, batch)
 
             b = self.batch_quantum
@@ -1013,7 +1031,7 @@ class TopKBatch:
     """One batch of ``ServeEngine.topk`` between its two halves.
 
     ``ServeEngine.stage`` makes it; ``dispatch`` hands it to the device
-    (the jitted scatter once per piece of the cell list, then the scorer:
+    (the jitted scatter over the batch's cell list, then the scorer:
     asynchronous calls that return at once) and ``fetch`` waits for the
     answer and copies it to the host.  The fetch reads the batch alone and
     nothing of the engine (it leaves the batch's scan record there), so
@@ -1106,19 +1124,27 @@ def compute(dispatch: TopKBatch | None, fetch: TopKBatch | None):
     return None if fetch is None else fetch.result
 
 
-def _seen_chunks(sp, cells: np.ndarray, shape, min_chunks: int):
-    """The pieces of one batch's cell list, for ``ServeEngine._seen_tiles``
-    once uploaded.  Its ``serve/batch/seen_tiles`` span says what the
-    device will build ([tiles, b, width] int32), what the host built for
-    it (``bytes``), the real ``cells`` among them and how many scatter
-    programs (``chunks`` of ``capacity``) carry them."""
+def _seen_chunks(sp, cells: np.ndarray, shape, warm: bool = False):
+    """One batch's cell list as ``ServeEngine._seen_tiles`` takes it once
+    uploaded: one array, the list padded from its ``chunks`` pieces of
+    ``capacity`` cells up to the next rung of ``SEEN_PIECE_RUNGS``, so the
+    columns scattered stay under twice the cells past one piece; a list
+    past the top rung, and ``prewarm``'s (``warm``: one piece past it), as
+    several arrays of the top rung's size.  Its ``serve/batch/seen_tiles``
+    span says what the device will build ([tiles, b, width] int32), what
+    the host built for it (``bytes``), the real ``cells`` among them and
+    how many runs of the scatter program (``programs``) the batch will
+    cost."""
     nt, b, width = shape
     capacity = seen_cell_capacity(b)
-    chunks = chunk_seen_cells(cells, capacity, nt, min_chunks)
+    pieces = max(-(-cells.shape[1] // capacity),
+                 SEEN_PIECE_RUNGS[-1] + 1 if warm else 1)
+    rung = seen_piece_rung(pieces)
+    runs = chunk_seen_cells(cells, rung * capacity, nt, -(-pieces // rung))
     sp.set(tiles=nt, b=b, width=width, cells=cells.shape[1],
-           capacity=capacity, chunks=len(chunks),
-           bytes=sum(c.nbytes for c in chunks))
-    return chunks
+           capacity=capacity, chunks=pieces, programs=len(runs),
+           bytes=sum(c.nbytes for c in runs))
+    return runs
 
 
 # Trace counter (ISSUE 13): bumped once per TRACE of the serve program
@@ -1160,9 +1186,10 @@ def _seen_tiles_call(cells, seen_tiles, *, shape, tile_m):
 
 @functools.lru_cache(maxsize=1)
 def _seen_tiles_jit_fn():
-    """Jitted seen-rectangle scatter: per (B, W) bucket one program that
-    starts a rectangle and one that adds to the rectangle it is given
-    (donated, so no second 299 MB lives beside it)."""
+    """Jitted seen-rectangle scatter: per (B, W) bucket one program a rung
+    of ``SEEN_PIECE_RUNGS`` that starts a rectangle and, at the top rung,
+    one that adds to the rectangle it is given (donated, so no second
+    299 MB lives beside it)."""
     import jax
 
     return jax.jit(
@@ -1190,6 +1217,17 @@ def _set_rows_fn(sharding):
 
     return jax.jit(lambda data, rows, vals: data.at[rows].set(vals),
                    out_shardings=sharding)
+
+
+def _put(x, mesh):
+    """``x`` from the host onto the device, or (``u`` and the cell list are
+    all a batch sends there) onto every chip of ``mesh``."""
+    import jax
+    import jax.numpy as jnp
+
+    if mesh is None:
+        return jnp.asarray(x)
+    return jax.device_put(x, _replicated(mesh))
 
 
 @functools.lru_cache(maxsize=8)

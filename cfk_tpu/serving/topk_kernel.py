@@ -108,6 +108,13 @@ import numpy as np
 # to a multiple of it.
 _SEEN_CHUNK = 16
 
+# Pieces of ``seen_cell_capacity`` cells that one run of the rectangle's
+# scatter program takes (``seen_piece_rung``): a batch's whole cell list goes
+# into one run, padded up to the next rung, so that fill, scatter and layout
+# copy are paid once a batch and the columns scattered stay under twice the
+# cells; a list past the top rung takes the top program again, on top.
+SEEN_PIECE_RUNGS = (1, 2, 4, 8, 16)
+
 # Tiles a grid step may stream (``slab_tiles`` takes the largest that fits),
 # and what a call may ask of the v5e's 128 MiB of VMEM.
 _SLAB_LADDER = (16, 8, 4, 2, 1)
@@ -427,15 +434,25 @@ def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
 
 
 def seen_cell_capacity(batch: int) -> int:
-    """Cells one scatter program takes: a function of the padded batch
-    size alone, so the batch-size ladder ``ServeEngine.prewarm`` walks
-    covers every cell-list shape the data can produce."""
+    """Cells one piece of a batch's cell list holds: a function of the
+    padded batch size alone, so the batch-size ladder
+    ``ServeEngine.prewarm`` walks covers every cell-list shape the data
+    can produce."""
     return batch * _SEEN_CHUNK
+
+
+def seen_piece_rung(pieces: int) -> int:
+    """Pieces one run of the scatter program takes for a cell list of
+    ``pieces`` pieces: the first rung of ``SEEN_PIECE_RUNGS`` that holds
+    them all, the top one for a list past it (which then takes several
+    runs)."""
+    return next((r for r in SEEN_PIECE_RUNGS if r >= pieces),
+                SEEN_PIECE_RUNGS[-1])
 
 
 def chunk_seen_cells(cells, capacity: int, num_tiles: int,
                      min_chunks: int = 1):
-    """``cells`` [4, n] cut into [4, capacity] pieces, at least
+    """``cells`` [4, n] cut into [4, capacity] arrays, at least
     ``min_chunks`` of them.  The fill columns name tile ``num_tiles`` —
     out of range, so the scatter drops them."""
     n = cells.shape[1]
@@ -465,17 +482,21 @@ def scatter_seen_cells(cells, seen_tiles=None, *, shape, tile_m):
     """The [NT, B, W] exclusion rectangle, built where the kernel reads it,
     and which tiles of it hold a cell: a ``SeenTiles``.
 
-    ``cells`` is one [4, capacity] piece of ``chunk_seen_cells``.  Slot
+    ``cells`` is one [4, n] array of ``chunk_seen_cells``: the batch's
+    whole cell list, padded to a rung of ``SEEN_PIECE_RUNGS`` pieces (the
+    server's, ``engine._seen_chunks``), so one run of one program fills,
+    scatters and lays out the rectangle whatever the batch's cells.  Slot
     [t, b, w] is the w-th in-tile column of batch slot b's seen movies
     inside movie tile t, padded with ``tile_m`` (which no in-tile column
-    equals); hit t is 1 where some piece named tile t.  ``seen_tiles``
-    None starts from the all-padding rectangle; one that earlier pieces
-    were scattered into takes this one on top.  No ``indices_are_sorted``
-    / ``unique_indices``: the chip's compiler folds the three indices into
-    one and every dropped column into the same out-of-range value, which
-    is neither, and with the hints the v5e wrote a wrong rectangle
-    (PERF.md, PR 25).  Both scatters drop the same columns (those whose
-    tile is out of range), so no slot is written in a tile without a hit."""
+    equals); hit t is 1 where some column named tile t.  ``seen_tiles``
+    None starts from the all-padding rectangle; one that an earlier run
+    scattered into (a list past the top rung) takes this one on top.  No
+    ``indices_are_sorted`` / ``unique_indices``: the chip's compiler folds
+    the three indices into one and every dropped column into the same
+    out-of-range value, which is neither, and with the hints the v5e wrote
+    a wrong rectangle (PERF.md, PR 25).  Both scatters drop the same
+    columns (those whose tile is out of range), so no slot is written in a
+    tile without a hit."""
     if seen_tiles is None:
         seen_tiles = SeenTiles(jnp.full(shape, tile_m, jnp.int32),
                                jnp.zeros(shape[:1], jnp.int32))

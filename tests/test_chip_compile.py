@@ -402,6 +402,31 @@ def test_serve_scorer_amazon14_cell_shape(chip):
     assert "s32[18262]" in text
 
 
+@pytest.mark.parametrize("rung, add", [(1, False), (8, False), (16, True)],
+                         ids=["one_piece", "eight_pieces", "on_top_at_the_top"])
+def test_serve_seen_rectangle_amazon14_cell_shape(chip, rung, add):
+    """The exclusion rectangle's one program a batch at the one-chip cells'
+    shape ([18,262, 256, 16] int32, 299 MB), for a cell list padded to
+    ``rung`` pieces: it needs one temporary the rectangle's size beside its
+    result (the scatter works on a flat copy that is laid out for the scorer
+    at the end) and no more, whatever the rung; the program that adds to a
+    rectangle writes its result in the donated one's place."""
+    from cfk_tpu.serving.engine import _seen_tiles_jit_fn
+    from cfk_tpu.serving.topk_kernel import SeenTiles, seen_cell_capacity
+
+    shape = (18_262, 256, 16)
+    rect = 4 * shape[0] * shape[1] * shape[2]
+    seen = (SeenTiles(chip(shape, i32), chip(shape[:1], i32)) if add
+            else None)
+    mem = _seen_tiles_jit_fn().lower(
+        chip((4, rung * seen_cell_capacity(shape[1])), i32), seen,
+        shape=shape, tile_m=512).compile().memory_analysis()
+    assert rect <= mem.output_size_in_bytes < 1.01 * rect
+    assert mem.temp_size_in_bytes < 1.01 * rect
+    assert (mem.alias_size_in_bytes >= rect) if add else (
+        mem.alias_size_in_bytes == 0)
+
+
 @pytest.mark.parametrize("touched,width", [(256, 128), (8, 8)])
 def test_foldin_amazon14_stream_cell_shape(chip, as_tpu, touched, width):
     """The fold-in of ``amazon14-stream-r128.serve-foldin`` at the two ends
@@ -543,10 +568,12 @@ def test_serve_sharded_four_devices(topo, chip, as_tpu, m, k_top, b, dtype):
                    + nt // 4 * b * w * 4)
     # (a narrow batch's rectangle is padded to whole 128-lane registers)
     assert scorer.memory_analysis().argument_size_in_bytes < 1.5 * shard_bytes
-    for fresh in (True, False):
+    # a list of up to eight pieces in one run, and the top rung's program
+    # run again on its own result (``SEEN_PIECE_RUNGS``)
+    for fresh, rung in ((True, 8), (False, 16)):
         build = spmd._serve_seen_tiles_sharded_fn(
             mesh, (nt, b, w), tile_m, fresh)
-        ops = [on((4, 16 * b), i32, P())]
+        ops = [on((4, rung * 16 * b), i32, P())]
         if not fresh:
             ops.append(seen)
         mem = build.lower(*ops).compile().memory_analysis()

@@ -589,34 +589,80 @@ def _seen_problem(rng, users, movies, longest):
     return np.concatenate(seen), indptr
 
 
-@pytest.mark.parametrize("capacity", [None, 300])
-def test_seen_rectangle_built_on_the_chip_equals_host_oracle(capacity):
-    """The scatter the chip's compiler makes of ``scatter_seen_cells``
-    (dropped fill columns, the sorted and unique hints, a donated
-    rectangle for the second piece on) against numpy's, bit for bit."""
+def _chip_built_rectangle(monkeypatch, movies, indptr, rows, capacity, **kw):
+    """(the rectangle ``engine._seen_chunks`` + the engine's jitted scatter
+    build on the chip, the arrays handed over, numpy's) for one batch."""
     from cfk_tpu.serving.engine import _seen_tiles_jit_fn
     from cfk_tpu.serving.topk_kernel import (
         build_seen_tiles,
-        chunk_seen_cells,
         group_seen_cells,
         seen_cell_capacity,
     )
+    from tests.test_serving import (
+        assert_one_program_shape,
+        one_program_runs,
+    )
 
+    want = build_seen_tiles(movies, indptr, rows, **kw)
+    cells, shape = group_seen_cells(movies, indptr, rows, **kw)
+    capacity = capacity or seen_cell_capacity(len(rows))
+    runs, attrs = one_program_runs(monkeypatch, cells, shape, capacity)
+    assert_one_program_shape(runs, attrs, cells.shape[1], capacity)
+    got = None
+    for run in runs:
+        got = _seen_tiles_jit_fn()(jnp.asarray(run), got, shape=shape,
+                                   tile_m=kw["tile_m"])
+    return got, runs, want
+
+
+@pytest.mark.parametrize("capacity", [None, 300, 60],
+                         ids=["two_pieces", "five_pieces", "past_the_top"])
+def test_seen_rectangle_built_on_the_chip_equals_host_oracle(
+        capacity, monkeypatch):
+    """The scatter the chip's compiler makes of ``scatter_seen_cells``
+    (dropped fill columns, no sorted or unique hint; one run over the whole
+    list padded to a rung, a donated rectangle where the list passes the top
+    rung) against numpy's, bit for bit."""
     rng = np.random.default_rng(11)
     m, tile, b = 200_000, 512, 64
     movies, indptr = _seen_problem(rng, b, m + 5_000, 40)
     rows = rng.integers(0, b, size=b)  # users repeat within the batch
-    kw = dict(num_movies=m, tile_m=tile)
-    want = build_seen_tiles(movies, indptr, rows, **kw)
-    cells, shape = group_seen_cells(movies, indptr, rows, **kw)
-    chunks = chunk_seen_cells(cells, capacity or seen_cell_capacity(b),
-                              shape[0], 2)
-    assert len(chunks) == (2 if capacity is None else
-                           -(-cells.shape[1] // capacity)) >= 2
-    got = None
-    for chunk in chunks:
-        got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
-                                   tile_m=tile)
+    got, runs, want = _chip_built_rectangle(
+        monkeypatch, movies, indptr, rows, capacity, num_movies=m,
+        tile_m=tile)
+    assert len(runs) == (1 if capacity != 60 else 2)
+    np.testing.assert_array_equal(np.asarray(got.slots), want)
+    np.testing.assert_array_equal(np.asarray(got.hits),
+                                  (want != tile).any(axis=(1, 2)))
+
+
+@pytest.mark.parametrize("heavy, longest, rung, programs", [
+    (0, 0, 1, 1), (18, 1_300, 8, 1), (8, 10_000, 16, 2)],
+    ids=["the_control's_batch", "the_skew_cell's_batch", "past_the_top"])
+def test_seen_rectangle_at_the_skew_cell_shape_equals_host_oracle(
+        heavy, longest, rung, programs, monkeypatch):
+    """The one-program build at the stream cells' real shape, [18,262, 256,
+    16] int32 (299 MB): a batch of short lists (one piece, today's program),
+    a batch with eighteen follow-ups of users drawn by activity (~23 k
+    cells: six pieces in one run of the rung of eight, where the parent ran
+    its program six times) and one past the top rung (~78 k cells: the top
+    rung's program, whose scatter the compiler sorts, and again on its own
+    result), each against numpy's rectangle to the bit."""
+    rng = np.random.default_rng(42)
+    m, tile, b = 9_350_000, 512, 256
+    lists = [np.sort(rng.choice(m, size=int(rng.integers(0, 15)),
+                                replace=False)) for _ in range(b - heavy)]
+    lists += [np.sort(rng.choice(m, size=int(rng.integers(longest * 9 // 10,
+                                                         longest + 1)),
+                                 replace=False)) for _ in range(heavy)]
+    indptr = np.zeros(b + 1, np.int64)
+    indptr[1:] = np.cumsum([x.size for x in lists])
+    movies = np.concatenate(lists).astype(np.int32)
+    got, runs, want = _chip_built_rectangle(
+        monkeypatch, movies, indptr, rng.permutation(b), None, num_movies=m,
+        tile_m=tile)
+    assert want.shape == (18_262, 256, 16)
+    assert [r.shape for r in runs] == [(4, rung * 16 * b)] * programs
     np.testing.assert_array_equal(np.asarray(got.slots), want)
     np.testing.assert_array_equal(np.asarray(got.hits),
                                   (want != tile).any(axis=(1, 2)))
@@ -645,7 +691,10 @@ def test_serve_callers_same_answers_from_the_chip_built_rectangle(
         serve_mode="two_stage" if caller == "two_stage" else "exact",
     )
     rows = rng.integers(0, users, size=50)
-    for capacity in (engine_mod.seen_cell_capacity, lambda b: 512):
+    # one piece; a few (one run of a higher rung); past the top rung (the
+    # top rung's program run again on its own result)
+    for capacity in (engine_mod.seen_cell_capacity, lambda b: 512,
+                     lambda b: 48):
         with monkeypatch.context() as mp:
             mp.setattr(engine_mod, "seen_cell_capacity", capacity)
             vals, ids = eng.topk(rows, 10)
@@ -660,22 +709,24 @@ def test_serve_callers_same_answers_from_the_chip_built_rectangle(
         assert not set(got.tolist()) & set(mine.tolist())
 
 
-@pytest.mark.parametrize("capacity", [None, 512])
+@pytest.mark.parametrize("capacity", [None, 512, 48],
+                         ids=["two_pieces", "four_pieces", "past_the_top"])
 def test_four_chip_sharded_engine_equals_one_device(capacity, monkeypatch):
     """``ServeEngine(shards=4)`` on four real chips, at a size one chip
     holds too: the table lies a quarter on each chip, each chip's slice of
     the exclusion rectangle is the host oracle's, and ids and scores are the
-    one-device engine's bit for bit, the cell list in one piece or several."""
+    one-device engine's bit for bit, the cell list of one run of the shard
+    program or, past the top rung, of several."""
     if len(jax.devices()) < 4:
         pytest.skip("needs four chips (the chip tool's --chips 4)")
     from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
     from cfk_tpu.serving import engine as engine_mod
     from cfk_tpu.serving.topk_kernel import (
         build_seen_tiles,
-        chunk_seen_cells,
         group_seen_cells,
         seen_cell_capacity,
     )
+    from tests.test_serving import one_program_runs
 
     rng = np.random.default_rng(23)
     users, m, rank, tile, b = 300, 201_000, 128, 512, 64
@@ -696,12 +747,14 @@ def test_four_chip_sharded_engine_equals_one_device(capacity, monkeypatch):
     skw = dict(num_movies=m, tile_m=tile, num_tiles=nt)
     want = build_seen_tiles(movies, indptr, rows, **skw)
     cells, shape = group_seen_cells(movies, indptr, rows, **skw)
+    runs, attrs = one_program_runs(
+        monkeypatch, cells, shape, capacity or seen_cell_capacity(b))
+    assert attrs["programs"] == len(runs) == (1 if capacity != 48 else 3)
     got = None
-    for chunk in chunk_seen_cells(cells, capacity or seen_cell_capacity(b),
-                                  nt, 2):
+    for run in runs:
         got = serve_seen_tiles_sharded(
-            four.mesh, jax.device_put(chunk, engine_mod._replicated(four.mesh)),
-            got, shape=shape, tile_m=tile)
+            four.mesh, engine_mod._put(run, four.mesh), got, shape=shape,
+            tile_m=tile)
     for part, oracle in ((got.slots, want),
                          (got.hits, (want != tile).any(axis=(1, 2)))):
         for shard in part.addressable_shards:

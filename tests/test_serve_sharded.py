@@ -13,10 +13,15 @@ from cfk_tpu import telemetry
 from cfk_tpu.serving import engine as engine_mod
 from cfk_tpu.serving.topk_kernel import (
     build_seen_tiles,
-    chunk_seen_cells,
     group_seen_cells,
 )
 from tests.serve_reference import exact_topk_blocks, topk_gaps
+from tests.test_serving import (
+    PIECE_CASES,
+    assert_one_program_shape,
+    one_program_runs,
+    piece_case_lists,
+)
 
 RANK, TILE = 8, 16
 SHARDS = (1, 2, 4)
@@ -74,16 +79,20 @@ def _engine(uf, mf, lists, **kw):
         seen_movies=movies, seen_indptr=indptr, batch_quantum=8, **kw)
 
 
-@pytest.mark.parametrize("pieces", ["one_piece", "several_pieces"])
+@pytest.mark.parametrize("pieces", ["one_piece", "several_pieces",
+                                    "past_the_top_rung"])
 @pytest.mark.parametrize("shards", SHARDS)
 @pytest.mark.parametrize("case", CASES)
 def test_sharded_answers_equal_reference_and_one_device(
         case, shards, pieces, monkeypatch):
     uf, mf, lists, rows, k = _problem(case)
-    if pieces == "several_pieces":
+    if pieces != "one_piece":
+        # three pieces: one run of the rung of four; forty: the top rung's
+        # program run again on its own result
         cells = int(sum(len(lists[r]) for r in rows))
+        cut = {"several_pieces": 3, "past_the_top_rung": 40}[pieces]
         monkeypatch.setattr(engine_mod, "seen_cell_capacity",
-                            lambda b: max(-(-cells // 3), 1))
+                            lambda b: max(-(-cells // cut), 1))
     want_vals, want_ids = _engine(uf, mf, lists).topk(rows, k)
     vals, ids = _engine(uf, mf, lists, shards=shards).topk(rows, k)
     np.testing.assert_array_equal(ids, want_ids)
@@ -132,22 +141,32 @@ def test_table_is_placed_shard_by_shard_never_whole(shards, table_dtype,
     np.testing.assert_array_equal(vals, want_vals)
 
 
-@pytest.mark.parametrize("pieces", [1, 3])
+@pytest.mark.parametrize("name", PIECE_CASES)
 @pytest.mark.parametrize("shards", SHARDS)
-def test_each_chip_builds_its_slice_of_the_rectangle(shards, pieces):
+def test_each_chip_builds_its_slice_of_the_rectangle(shards, name,
+                                                     monkeypatch):
+    """The one-program build over a mesh: each chip's slice of the
+    rectangle and of its hits is the numpy oracle's to the bit however
+    many pieces the cell list holds (``tests/test_serving.py``'s cases: up
+    to the top rung one run of one shard program, past it the top rung's
+    run again on its own result), cells past ``num_movies`` dropped."""
     from cfk_tpu.parallel.mesh import make_mesh
     from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
 
-    _, mf, lists, rows, _ = _problem("ragged")
-    movies, indptr = _csr([lists[r] for r in rows] + [[]] * 3)
-    nt = -(-mf.shape[0] // (shards * TILE)) * shards
-    kw = dict(num_movies=mf.shape[0], tile_m=TILE, num_tiles=nt)
-    want = build_seen_tiles(movies, indptr, np.arange(16), **kw)
-    cells, shape = group_seen_cells(movies, indptr, np.arange(16), **kw)
+    n, warm = PIECE_CASES[name]
+    movies, indptr = _csr(piece_case_lists(n))
+    nt = -(-600 // (shards * TILE)) * shards
+    kw = dict(num_movies=600, tile_m=TILE, num_tiles=nt)
+    want = build_seen_tiles(movies, indptr, np.arange(8), **kw)
+    cells, shape = group_seen_cells(movies, indptr, np.arange(8), **kw)
+    assert cells.shape[1] == n
+    runs, attrs = one_program_runs(monkeypatch, cells, shape, 8, warm=warm)
+    if not warm:
+        assert_one_program_shape(runs, attrs, n, 8)
     mesh = make_mesh(shards)
     got = None
-    for chunk in chunk_seen_cells(cells, -(-cells.shape[1] // pieces), nt):
-        got = serve_seen_tiles_sharded(mesh, jnp.asarray(chunk), got,
+    for run in runs:
+        got = serve_seen_tiles_sharded(mesh, jnp.asarray(run), got,
                                        shape=shape, tile_m=TILE)
     assert got.slots.shape == want.shape and got.hits.shape == (nt,)
     # the rectangle and its hits (a tile that holds a cell) lie together
@@ -235,15 +254,27 @@ def test_shard_program_takes_the_operands_there_are(table_dtype, operands):
     assert seen == [operands, operands - 1]
 
 
-def test_prewarm_counts_the_shard_programs_and_closes_the_set():
+@pytest.mark.parametrize("pieces", [1, 3, 9, 17])
+def test_prewarm_counts_the_shard_programs_and_closes_the_set(pieces):
     uf, mf, lists, rows, k = _problem("ragged")
+    # one user whose list makes a batch of its rows hold `pieces` pieces of
+    # 16 x b cells at every batch size
+    lists = list(lists)
+    lists[7] = np.sort(np.random.default_rng(pieces).choice(
+        mf.shape[0], 16 * pieces - 8, replace=False))
     eng = _engine(uf, mf, lists, shards=4, tile_m=32)  # shapes of its own
     warm = eng.prewarm(8, max_batch=16)
-    # per rung: the scorer, the slice build, the build onto a donated slice
-    assert warm["programs"] == 2 and warm["new_traces"] == 6
+    # per batch size: the scorer, the slice build at each of the five rungs
+    # of pieces and the top rung's build onto a donated slice (all of them
+    # new in the first case, none in the others: the counter is the
+    # process's)
+    assert warm["programs"] == 2 and warm["new_traces"] in (0, 14)
     before = engine_mod.trace_count()
     eng.topk(rows, 8)
     eng.topk(rows[:5], 8)
+    for n in (8, 16):
+        vals, ids = eng.topk(np.full(n, 7), 8)
+        assert not set(ids.ravel().tolist()) & set(lists[7].tolist())
     assert engine_mod.trace_count() == before
 
 

@@ -120,7 +120,7 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     capacity = engine_mod.seen_cell_capacity(4)
     assert args["serve/batch/seen_tiles"] == {
         "tiles": 3, "b": 4, "width": 16, "cells": 12, "capacity": capacity,
-        "chunks": 1, "bytes": 4 * capacity * 4}
+        "chunks": 1, "programs": 1, "bytes": 4 * capacity * 4}
     # handed over: that piece and the [b, rank] float32 user batch
     assert args["serve/batch/upload"]["bytes"] == (4 * capacity * 4
                                                    + 4 * RANK * 4)
@@ -137,6 +137,29 @@ def test_one_step_emits_each_span_once(tracer, monkeypatch):
     assert spans["serve/poll"][0]["args"]["batch"] == 2
     assert spans["serve/batch"][0]["args"]["batch"] == 2
     assert spans["serve/poll"][0]["args"]["pending_after"] == 0
+
+
+@pytest.mark.parametrize("capacity, pieces, programs", [
+    (24, 1, 1), (12, 2, 1), (8, 3, 1), (5, 5, 1), (2, 12, 1), (1, 24, 2)])
+def test_seen_tiles_span_counts_the_runs_of_the_build_program(
+        capacity, pieces, programs, tracer, monkeypatch):
+    """``programs`` on ``serve/batch/seen_tiles`` is what the batch's
+    rectangle costs in runs of the scatter program: 1 for any list up to
+    the ladder's top rung of pieces (``chunks`` of ``capacity`` cells),
+    one more for every top rung's worth past it; ``bytes`` is the padded
+    list the host built and handed over."""
+    # eight users' three cells each against pieces of `capacity` cells
+    monkeypatch.setattr(engine_mod, "seen_cell_capacity", lambda b: capacity)
+    server, _ = _served(_engine(), range(8), max_batch=8)
+    tracer.clear()
+    assert server.step() == 8
+    (sp,) = _by_name(_serve_events(tracer))["serve/batch/seen_tiles"]
+    rung = engine_mod.seen_piece_rung(pieces)
+    assert sp["args"] == {
+        "tiles": 3, "b": 8, "width": 16, "cells": 24, "capacity": capacity,
+        "chunks": pieces, "programs": programs,
+        "bytes": programs * 4 * rung * capacity * 4}
+    assert programs == -(-pieces // engine_mod.SEEN_PIECE_RUNGS[-1])
 
 
 def test_under_a_backlog_each_step_has_one_compute_of_two_batches(tracer):

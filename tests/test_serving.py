@@ -617,10 +617,46 @@ SEEN_CASES = ("random", "all_empty", "one_user_many_times",
               "exactly_capacity", "wide")
 
 
+def one_program_runs(monkeypatch, cells, shape, capacity, *, warm=False):
+    """The batch's cell list as the server hands it to the device
+    (``engine._seen_chunks`` under a piece of ``capacity`` cells) and what
+    its ``serve/batch/seen_tiles`` span says of it."""
+    import types
+
+    from cfk_tpu.serving import engine as engine_mod
+
+    attrs = {}
+    with monkeypatch.context() as mp:
+        mp.setattr(engine_mod, "seen_cell_capacity", lambda b: capacity)
+        runs = engine_mod._seen_chunks(
+            types.SimpleNamespace(set=attrs.update), cells, shape, warm)
+    return runs, attrs
+
+
+def assert_one_program_shape(runs, attrs, cells: int, capacity: int):
+    """One array a rung of the ladder wide under the top rung, under twice
+    the cells past one piece; past the top, arrays of the top rung's."""
+    from cfk_tpu.serving.topk_kernel import SEEN_PIECE_RUNGS
+
+    top = SEEN_PIECE_RUNGS[-1]
+    pieces = max(-(-cells // capacity), 1)
+    assert attrs["cells"] == cells and attrs["capacity"] == capacity
+    assert attrs["programs"] == len(runs) == max(-(-pieces // top), 1)
+    width = runs[0].shape[1]
+    assert width // capacity in SEEN_PIECE_RUNGS and width % capacity == 0
+    for run in runs:
+        assert run.shape == (4, width) and run.dtype == np.int32
+    if pieces <= top:
+        assert attrs["chunks"] == pieces
+        assert width >= cells and (pieces == 1 or width < 2 * cells)
+    else:
+        assert width == top * capacity and len(runs) * width < 2 * cells
+
+
 @pytest.mark.parametrize("name", SEEN_CASES)
-def test_device_built_rectangle_bit_equals_host_oracle(name):
-    # the serve path's rectangle: host grouping, pieces of a fixed
-    # capacity, the engine's jitted scatter run once per piece
+def test_device_built_rectangle_bit_equals_host_oracle(name, monkeypatch):
+    # the serve path's rectangle: host grouping, the list padded to a rung
+    # of pieces of a fixed capacity, ONE run of the engine's jitted scatter
     from cfk_tpu.serving.engine import _seen_tiles_jit_fn
 
     lists, rows, m, tile, capacity, pieces, width = _seen_case(name)
@@ -630,19 +666,76 @@ def test_device_built_rectangle_bit_equals_host_oracle(name):
     cells, shape = group_seen_cells(movies, indptr, rows, **kw)
     assert shape == want.shape == (kw["num_tiles"], len(rows), width)
     assert cells.shape[1] == int((want != tile).sum())
-    chunks = chunk_seen_cells(cells, capacity, shape[0])
+    runs, attrs = one_program_runs(monkeypatch, cells, shape, capacity)
+    assert_one_program_shape(runs, attrs, cells.shape[1], capacity)
     if pieces is not None:
-        assert len(chunks) == pieces
-    got = None
-    for chunk in chunks:
-        assert chunk.shape == (4, capacity) and chunk.dtype == np.int32
-        got = _seen_tiles_jit_fn()(jnp.asarray(chunk), got, shape=shape,
-                                   tile_m=tile)
+        assert attrs["chunks"] == pieces
+    (run,) = runs
+    got = _seen_tiles_jit_fn()(jnp.asarray(run), None, shape=shape,
+                               tile_m=tile)
     assert got.slots.dtype == got.hits.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got.slots), want)
     # a tile is hit where the rectangle holds a cell, and nowhere else
     np.testing.assert_array_equal(np.asarray(got.hits),
                                   (want != tile).any(axis=(1, 2)))
+
+
+# pieces of eight cells a list is made to hold: none, one, every rung of the
+# ladder and between two, one past the top rung (the top program twice, the
+# second nearly empty), three times the top; a last piece one cell short,
+# full, and one cell into the next; prewarm's (an array of nothing but fill)
+PIECE_CASES = {
+    "no_cells": (0, False), "one_piece": (8, False),
+    "two_pieces": (16, False), "three_pieces": (24, False),
+    "four_pieces": (32, False), "five_pieces": (40, False),
+    "eight_pieces": (64, False), "sixteen_pieces": (128, False),
+    "top_rung_and_one": (136, False), "three_times_the_top": (384, False),
+    "last_piece_one_short": (31, False), "last_piece_one_over": (129, False),
+    "warm_few_cells": (5, True), "warm_past_the_top": (200, True),
+}
+
+
+def piece_case_lists(cells: int, users: int = 8, movies: int = 600):
+    """Seen lists of ``users`` users over ``movies`` movies with ``cells``
+    cells in all, and as many again at or past ``movies``, which the
+    grouping drops."""
+    rng = np.random.default_rng(cells)
+    sizes = np.full(users, cells // users)
+    sizes[:cells % users] += 1
+    return [np.concatenate([
+        np.sort(rng.choice(movies, size=int(n), replace=False)),
+        movies + np.arange(int(n))]) for n in sizes]
+
+
+@pytest.mark.parametrize("name", PIECE_CASES)
+def test_one_program_build_bit_equals_host_oracle(name, monkeypatch):
+    """However many pieces the batch's cell list holds, the rectangle is
+    ``build_seen_tiles``' to the bit: from one run of one program up to
+    the top rung, from the top rung's program run again on its own result
+    past it."""
+    from cfk_tpu.serving.engine import _seen_tiles_jit_fn
+    from cfk_tpu.serving.topk_kernel import SEEN_PIECE_RUNGS
+
+    n, warm = PIECE_CASES[name]
+    movies, indptr = _csr(piece_case_lists(n))
+    kw = dict(num_movies=600, tile_m=16, num_tiles=39)
+    want = build_seen_tiles(movies, indptr, np.arange(8), **kw)
+    cells, shape = group_seen_cells(movies, indptr, np.arange(8), **kw)
+    assert cells.shape[1] == n == int((want != 16).sum())
+    runs, attrs = one_program_runs(monkeypatch, cells, shape, 8, warm=warm)
+    if warm:  # both programs of the top rung, whatever the cells
+        top = SEEN_PIECE_RUNGS[-1]
+        assert [r.shape for r in runs] == [(4, top * 8)] * max(
+            2, -(-n // (top * 8)))
+    else:
+        assert_one_program_shape(runs, attrs, n, 8)
+    got = None
+    for run in runs:
+        got = _seen_tiles_jit_fn()(jnp.asarray(run), got, shape=shape,
+                                   tile_m=16)
+    np.testing.assert_array_equal(np.asarray(got.slots), want)
+    np.testing.assert_array_equal(np.asarray(got.hits),
+                                  (want != 16).any(axis=(1, 2)))
 
 
 @pytest.mark.parametrize("name", SEEN_CASES)
@@ -667,8 +760,11 @@ def test_engine_answers_equal_dense_oracle_through_device_rectangle(
         lambda *a: seen_chunks.append(real(*a)) or seen_chunks[-1])
     k = 6
     vals, ids = eng.topk(rows, k)
+    # one array, one run of the program, whatever the pieces
+    assert len(seen_chunks) == 1 and len(seen_chunks[0]) == 1
     if pieces is not None:
-        assert len(seen_chunks[0]) == pieces
+        assert seen_chunks[0][0].shape == (
+            4, engine_mod.seen_piece_rung(pieces) * capacity)
     seen = [np.asarray(lists[r], np.int64) for r in rows]
     seen = [x[x < m] for x in seen]
     ov, oi = _dense_oracle(uf[rows], mf, seen, k)
@@ -702,13 +798,16 @@ def host_built_seen_tiles(engine, chunks, shape, mesh=None):
     return jnp.asarray(rect)
 
 
+@pytest.mark.parametrize("pieces", [None, 3, 40],
+                         ids=["one_piece", "three_pieces", "past_the_top"])
 @pytest.mark.parametrize("caller", ["exact", "item_sharded", "two_stage"])
 def test_every_caller_serves_from_the_device_built_rectangle(
-        caller, rng, monkeypatch):
+        caller, pieces, rng, monkeypatch):
     # one grouping, one device builder: the one-device scan, the sharded
     # scan and the two-stage rescore give, bit for bit, the answers they
-    # give over a rectangle the host built from the same pieces — also for
-    # a batch in three pieces
+    # give over a rectangle the host built from the same cell list — also
+    # for a batch of three pieces (one run of the rung of four) and of forty
+    # (three runs of the top rung's program)
     from cfk_tpu.parallel.mesh import make_mesh
     from cfk_tpu.serving import engine as engine_mod
 
@@ -726,16 +825,27 @@ def test_every_caller_serves_from_the_device_built_rectangle(
         clusters=8, probe_clusters=4,
     )
     rows = rng.integers(0, users, size=13)
-    for capacity in (engine_mod.seen_cell_capacity,
-                     lambda b: -(-int(np.diff(indptr)[rows].sum()) // 3)):
-        with monkeypatch.context() as mp:
-            mp.setattr(engine_mod, "seen_cell_capacity", capacity)
-            vals, ids = eng.topk(rows, 5)
-            mp.setattr(engine_mod.ServeEngine, "_seen_tiles",
-                       host_built_seen_tiles)
-            want_vals, want_ids = eng.topk(rows, 5)
-        np.testing.assert_array_equal(ids, want_ids)
-        np.testing.assert_array_equal(vals, want_vals)
+    if pieces is not None:
+        cells = int(np.diff(indptr)[rows].sum())
+        monkeypatch.setattr(engine_mod, "seen_cell_capacity",
+                            lambda b: -(-cells // pieces))
+    programs = []
+    real = engine_mod._seen_chunks
+
+    def counting(*a):
+        runs = real(*a)
+        programs.append(len(runs))
+        return runs
+
+    monkeypatch.setattr(engine_mod, "_seen_chunks", counting)
+    vals, ids = eng.topk(rows, 5)
+    if caller != "two_stage":  # whose cell list is the shortlist's
+        assert programs == [{None: 1, 3: 1, 40: 3}[pieces]]
+    monkeypatch.setattr(engine_mod.ServeEngine, "_seen_tiles",
+                        host_built_seen_tiles)
+    want_vals, want_ids = eng.topk(rows, 5)
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(vals, want_vals)
     for row, got in zip(rows, ids):
         mine = movies_csr[indptr[row]: indptr[row + 1]]
         assert not set(got.tolist()) & set(mine.tolist())

@@ -237,18 +237,25 @@ def test_serve_engine_prewarm_pins_zero_new_traces():
     assert eng.trace_count - before == 1
 
 
-def test_serve_engine_prewarm_closes_the_seen_scatter_programs(monkeypatch):
+@pytest.mark.parametrize("pieces", [1, 3, 9, 17])
+def test_serve_engine_prewarm_closes_the_seen_scatter_programs(
+        pieces, monkeypatch):
     # the exclusion rectangle's programs are keyed by the padded batch size
-    # (the cell-list capacity is a function of it alone), so the ladder
-    # covers a batch of every rung AND a batch whose cells overflow one
-    # piece, twice over, whatever seen lists the sample happened to hold
+    # (a piece of the cell list is a function of it alone) and by the rung
+    # of pieces the list is padded to, so the ladder, walked over the rungs,
+    # covers a batch of every size AND a batch of one, three, nine pieces
+    # (rungs 1, 4, 16: one run of one program) or seventeen (the top rung's
+    # program, and again on its own result), whatever seen lists the sample
+    # happened to hold
     from cfk_tpu.serving import engine as engine_mod
 
     rng = np.random.default_rng(0)
-    users, movies = 40, 60
+    users, movies = 40, 400
     lists = [np.sort(rng.choice(movies, int(rng.integers(0, 5)),
                                 replace=False)) for _ in range(users)]
-    lists[7] = np.arange(movies)[::2]  # 30 cells: over a third of a piece
+    # a batch of b such rows holds b x (16 x pieces - 8) cells against
+    # pieces of 16 x b: `pieces` of them at every batch size
+    lists[7] = np.sort(rng.choice(movies, 16 * pieces - 8, replace=False))
     indptr = np.zeros(users + 1, np.int64)
     indptr[1:] = np.cumsum([x.size for x in lists])
     eng = engine_mod.ServeEngine(
@@ -261,24 +268,30 @@ def test_serve_engine_prewarm_closes_the_seen_scatter_programs(monkeypatch):
     # the sample holds none of the heavy user's rows
     warm = eng.prewarm(3, max_batch=16, user_rows=np.arange(20, 36))
     assert warm["programs"] == 3  # buckets 4, 8, 16
-    # per bucket at most: the scorer, the scatter that starts a rectangle
-    # and the one that adds to it (fewer where an earlier test of this
-    # process traced the same shapes: the counter is process-wide)
-    assert warm["new_traces"] <= 9
+    # per bucket at most: the scorer, the scatter that starts a rectangle at
+    # each of the five rungs and the top rung's that adds to one (fewer
+    # where an earlier test of this process traced the same shapes: the
+    # counter is process-wide)
+    assert warm["new_traces"] <= 21
     before = eng.trace_count
     chunks = []
     real = engine_mod._seen_chunks
     monkeypatch.setattr(
         engine_mod, "_seen_chunks",
         lambda *a: chunks.append(real(*a)) or chunks[-1])
-    for n in (3, 7, 13):  # a batch of every rung
+    for n in (3, 7, 13):  # a batch of every size
         eng.topk(np.arange(n), 3)
-    # 4 x 30 cells against a capacity of 64, 8 x 30 against 128, 16 x 30
-    # against 256, 3 x 30 against 64: two pieces each
     for n in (4, 8, 16, 3):
         vals, ids = eng.topk(np.full(n, 7), 3)
         assert not set(ids.ravel().tolist()) & set(lists[7].tolist())
-    assert [len(c) for c in chunks] == [1, 1, 1, 2, 2, 2, 2]
+    # ... and the last, three such rows padded to four, of fewer
+    held = [-(-n * lists[7].size // (16 * b))
+            for n, b in ((4, 4), (8, 8), (16, 16), (3, 4))]
+    assert held[:3] == [pieces] * 3
+    assert [len(c) for c in chunks] == [1, 1, 1] + [-(-p // 16) for p in held]
+    assert [c[0].shape[1] for c in chunks[3:]] == [
+        engine_mod.seen_piece_rung(p) * 16 * b
+        for p, b in zip(held, (4, 8, 16, 4))]
     assert eng.trace_count - before == 0
 
 
