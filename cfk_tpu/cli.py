@@ -889,18 +889,14 @@ def _serve_impl(args) -> int:
         num_users=ds.user_map.num_entities,
         num_movies=ds.movie_map.num_entities,
     )
-    engine = engine_from_model(
-        model, None if args.include_seen else ds,
-        table_dtype=args.table_dtype, tile_m=args.tile_m,
-        serve_mode=args.serve_mode, clusters=args.clusters or None,
-        probe_clusters=args.probe_clusters or None,
-    )
-    if engine.serve_mode == "two_stage":
-        _eprint(
-            f"two-stage retrieval: {engine.clusters} clusters, "
-            f"{engine.probe_clusters} probed per user "
-            "(exact scan remains the fault fallback)"
+
+    def build_engine():
+        return engine_from_model(
+            model, None if args.include_seen else ds,
+            table_dtype=args.table_dtype, tile_m=args.tile_m,
         )
+
+    engine = build_engine()
     # Trace/compile the pow2 batch-bucket set before traffic arrives
     # (ISSUE 13): the first real batch then pays zero traces.
     warm = engine.prewarm(args.k, max_batch=args.max_batch)
@@ -915,12 +911,7 @@ def _serve_impl(args) -> int:
         from cfk_tpu.serving import ServeFleet
 
         fleet = ServeFleet(
-            lambda i: engine if i == 0 else engine_from_model(
-                model, None if args.include_seen else ds,
-                table_dtype=args.table_dtype, tile_m=args.tile_m,
-                serve_mode=args.serve_mode, clusters=args.clusters or None,
-                probe_clusters=args.probe_clusters or None,
-            ),
+            lambda i: engine if i == 0 else build_engine(),
             transport, replicas=args.replicas, max_batch=args.max_batch,
             admission_max_queue=args.admission_queue or None,
             metrics_ports=args.metrics_port is not None,
@@ -1419,10 +1410,6 @@ def _plan_cmd(args) -> int:
         ici_group=args.ici_group,
         staging=None if args.staging == "auto" else args.staging,
         hot_rows=args.hot_rows,
-        serve_mode=(None if args.serve_mode == "auto"
-                    else args.serve_mode),
-        clusters=args.clusters,
-        probe_clusters=args.probe_clusters,
     )
     if args.device == "auto":
         device = DeviceSpec.detect()
@@ -1804,18 +1791,6 @@ def build_parser() -> argparse.ArgumentParser:
                     "has the times)")
     sv.add_argument("--tile-m", type=int, default=2048,
                     help="movie-axis tile rows streamed through VMEM")
-    sv.add_argument("--serve-mode", choices=["exact", "two_stage"],
-                    default="exact",
-                    help="retrieval mode (ISSUE 16): two_stage probes a "
-                    "k-means centroid index and exactly rescores only "
-                    "the probed clusters' rows — the exact scan stays "
-                    "the un-disableable fallback")
-    sv.add_argument("--clusters", type=int, default=0,
-                    help="two_stage k-means cluster count "
-                    "(0 = auto ~sqrt(movies))")
-    sv.add_argument("--probe-clusters", type=int, default=0,
-                    help="clusters probed per user (0 = auto at the "
-                    "0.95 modeled recall floor)")
     sv.add_argument("--max-batch", type=int, default=256,
                     help="max requests coalesced into one scoring batch")
     sv.add_argument("--replicas", type=int, default=1,
@@ -2019,19 +1994,6 @@ def build_parser() -> argparse.ArgumentParser:
                     "(batch quantum + table dtype from the table-scan "
                     "byte model)")
     pl.add_argument("--serve-k", type=int, default=100)
-    pl.add_argument("--serve-mode", default="auto",
-                    choices=["auto", "exact", "two_stage"],
-                    help="retrieval-mode pin of the serve plan "
-                    "(ISSUE 16): the byte model weighs the exact scan "
-                    "against centroid-probe + expected-shortlist bytes; "
-                    "a pinned two_stage whose modeled recall@K falls "
-                    "below the 0.95 floor raises at resolution")
-    pl.add_argument("--clusters", type=int, default=None, metavar="C",
-                    help="two_stage cluster-count pin (0 = exact-only)")
-    pl.add_argument("--probe-clusters", type=int, default=None,
-                    metavar="P",
-                    help="clusters-probed-per-user pin (~0.75*sqrt(C) "
-                    "reaches the recall floor)")
     # Constraint pins — 'auto' leaves the knob to the resolver; anything
     # else pins it exactly like the matching ALSConfig/train flag would.
     pl.add_argument("--layout", default="auto",

@@ -277,58 +277,17 @@ def train_iteration_cost(shape: ProblemShape, device: DeviceSpec,
     return PlanCost(seconds=total + extra, unit="s/iter", terms=terms)
 
 
-# Plan recall constraint of the two-stage serve mode (ISSUE 16): a
-# two_stage candidate whose MODELED recall@K falls below this floor is
-# never enumerated, and a pinned (clusters, probe_clusters) below it
-# raises at resolution.  The measured contract lives in bench/tests —
-# recall@K vs the exact oracle is a first-class column; this model only
-# gates what the resolver may promise.
-SERVE_MIN_RECALL = 0.95
-
-# Recall-curve steepness of the probe model below.  Calibrated so the IVF
-# rule of thumb (probe ≈ √clusters reaches high recall on clusterable
-# factor tables) sits just above the 0.95 floor: probe = 0.75·√clusters
-# models to 0.95, probe = √clusters to ~0.98.
-_RECALL_ALPHA = 4.0
-
-
-def estimated_recall(clusters: int, probe_clusters: int) -> float:
-    """Modeled recall@K of probing ``probe_clusters`` of ``clusters``.
-
-    ``1 − exp(−α·probe/√clusters)``: monotone up in the probe count, down
-    in the cluster count at a fixed probe — the classic IVF trade surface
-    (finer index → fewer bytes per probe but more probes for the same
-    recall).  Probing every cluster is exact coverage by construction."""
-    c = int(clusters)
-    if c <= 0:
-        return 1.0  # exact mode: no index, full scan
-    p = min(int(probe_clusters), c)
-    if p <= 0:
-        return 0.0
-    if p >= c:
-        return 1.0
-    return 1.0 - math.exp(-_RECALL_ALPHA * p / math.sqrt(c))
-
-
 def serve_batch_cost_for(shape: ProblemShape, device: DeviceSpec,
                          plan: ExecutionPlan) -> PlanCost:
     """One coalesced serve batch at the plan's quantum — reported per
     REQUEST-slot second so quanta are comparable: the table scan amortizes
-    over the batch, which is exactly the lever the quantum moves.
-
-    ``serve_mode="two_stage"`` prices the clustered path instead: the
-    centroid scan plus the EXPECTED batch-union shortlist gather
-    (``roofline.serve_batch_cost``) — so two_stage wins exactly where the
-    byte model says the centroids + shortlist undercut the full scan,
-    and loses where the batch-union approaches the table (large quanta
-    over a coarse index)."""
+    over the batch, which is exactly the lever the quantum moves."""
     from cfk_tpu.utils.roofline import serve_batch_cost
 
     b = plan.serve_batch_quantum
     cost = serve_batch_cost(
         shape.num_movies, shape.rank, b, shape.serve_k,
-        table_dtype=plan.table_dtype, serve_mode=plan.serve_mode,
-        clusters=plan.clusters, probe_clusters=plan.probe_clusters,
+        table_dtype=plan.table_dtype,
     )
     shards = max(shape.num_shards, 1)
     flops_s = cost.model_flops / shards / device.peak_flops
@@ -341,8 +300,7 @@ def serve_batch_cost_for(shape: ProblemShape, device: DeviceSpec,
     per_request = (batch_s + wait_s) / b
     terms = {
         "score_flops": flops_s,
-        ("shortlist_gather_bytes" if plan.serve_mode == "two_stage"
-         else "table_scan_bytes"): bytes_s,
+        "table_scan_bytes": bytes_s,
         "coalesce_wait": wait_s,
     }
     # Ranked PER REQUEST-SLOT: quanta are only comparable on what one

@@ -47,7 +47,6 @@ KERNEL_SLOTS = (
     "gram_solve_gather",  # both fusions
     "reg_solve",          # batched ridge+solve (the fused reg kernels)
     "topk",               # streaming score+top-K serve kernel
-    "topk_coarse",        # two-stage candidate stage (centroid probe)
 )
 
 
@@ -227,18 +226,6 @@ def _register_builtins() -> None:
 
     R.register("topk", "mosaic_tpu", _load_topk)
 
-    def _load_coarse():
-        from cfk_tpu.serving.twostage import _coarse_call
-
-        return _coarse_call
-
-    # The candidate stage is one XLA matmul + top_k on both backends (the
-    # exact rescore underneath it is the "topk" slot); registering it
-    # keeps the serve plan's kernel list complete — and "topk" remains
-    # the un-disableable fallback: forcing "topk_coarse" unavailable
-    # degrades the ENGINE to the exact scan, never to no serving.
-    R.register("topk_coarse", "mosaic_tpu", _load_coarse)
-
     # XLA-emulation twins — the same math through plain XLA ops (the
     # compat twins where one exists, the split/einsum formulations
     # otherwise).  Always feasible: this backend is the degradation floor.
@@ -275,13 +262,6 @@ def _register_builtins() -> None:
     R.register("reg_solve", "xla_emulation",
                _load_solve("dispatch_spd_solve"))
     R.register("topk", "xla_emulation", _load_emulate("emulate_topk_scores"))
-
-    def _load_coarse_emu():
-        from cfk_tpu.serving.twostage import _coarse_call
-
-        return _coarse_call
-
-    R.register("topk_coarse", "xla_emulation", _load_coarse_emu)
 
 
 _register_builtins()

@@ -44,8 +44,7 @@ _TRAIN_FIELDS = ("layout", "exchange", "chunk_elems", "fused_epilogue",
                  "in_kernel_gather", "overlap", "reg_solve_algo",
                  "table_dtype", "solver", "gram_backend", "offload_tier",
                  "ici_group", "staging", "hot_rows")
-_SERVE_FIELDS = ("table_dtype", "serve_batch_quantum", "serve_tile_m",
-                 "serve_mode", "clusters", "probe_clusters")
+_SERVE_FIELDS = ("table_dtype", "serve_batch_quantum", "serve_tile_m")
 
 
 def hard_conflict(shape: ProblemShape, pins: dict) -> str | None:
@@ -111,36 +110,6 @@ def hard_conflict(shape: ProblemShape, pins: dict) -> str | None:
         return (f"hot_rows={pins['hot_rows']} is a host_window-tier "
                 "knob (it cuts staged PCIe bytes); pinned "
                 "offload_tier='device' has no staging — unpin one side")
-    mode = pins.get("serve_mode")
-    if mode == "two_stage" and shape.kind != "serve":
-        return ("serve_mode='two_stage' is a serve-kind mode (the "
-                "clustered index exists only behind ServeEngine); "
-                "unpin it for a train resolve")
-    if mode == "exact" and (pins.get("clusters")
-                            or pins.get("probe_clusters")):
-        return (f"clusters={pins.get('clusters')}/probe_clusters="
-                f"{pins.get('probe_clusters')} are two_stage index knobs; "
-                "pinned serve_mode='exact' scans the full table — unpin "
-                "one side")
-    c_pin, p_pin = pins.get("clusters"), pins.get("probe_clusters")
-    if (c_pin is not None and p_pin is not None and c_pin > 0
-            and p_pin > c_pin):
-        return (f"probe_clusters={p_pin} exceeds clusters={c_pin} "
-                "(cannot probe more clusters than exist)")
-    if mode == "two_stage" and c_pin and p_pin:
-        from cfk_tpu.plan.cost import SERVE_MIN_RECALL, estimated_recall
-
-        est = estimated_recall(c_pin, p_pin)
-        if est < SERVE_MIN_RECALL:
-            # The recall constraint is a RESOLUTION-time raise (ISSUE
-            # 16): a pinned two_stage below the plan floor must never
-            # resolve — the measured contract (bench recall column)
-            # assumes no plan promises a sub-floor configuration.
-            return (f"serve_mode='two_stage' pinned at clusters={c_pin}, "
-                    f"probe_clusters={p_pin} models recall@K {est:.3f} "
-                    f"< the plan constraint {SERVE_MIN_RECALL} — raise "
-                    "probe_clusters (≈ 0.75·√clusters reaches the "
-                    "floor), coarsen the index, or unpin")
     return None
 
 
@@ -220,41 +189,6 @@ def _feasible(shape: ProblemShape, device: DeviceSpec, cand: dict,
         if shape.rank > 2 * PALLAS_MAX_RANK:
             return (f"rank {shape.rank} exceeds the pallas solver's "
                     f"blocked cap {2 * PALLAS_MAX_RANK}")
-    return None
-
-
-def _serve_feasible(shape: ProblemShape, cand: dict) -> str | None:
-    """Reason a serve-kind candidate cannot execute (ISSUE 16), or None.
-
-    Mirrors the engine's own gates: exact mode carries no index knobs
-    (refusing the duplicates keeps cost-identical candidates from
-    crowding autotune's measured top-N, the staging-axis rule), and a
-    two_stage candidate must clear BOTH the structural gates (a real
-    index, probe ≤ clusters, expected coverage ≥ K) and the plan recall
-    constraint — the resolver never enumerates a configuration the
-    recall model puts below ``cost.SERVE_MIN_RECALL``."""
-    from cfk_tpu.plan.cost import SERVE_MIN_RECALL, estimated_recall
-
-    mode = cand.get("serve_mode", "exact")
-    c = int(cand.get("clusters", 0) or 0)
-    p = int(cand.get("probe_clusters", 0) or 0)
-    if mode == "exact":
-        if c or p:
-            return "clusters/probe_clusters are two_stage index knobs"
-        return None
-    if c < 2:
-        return "two_stage needs a real index (clusters >= 2)"
-    if c > shape.num_movies:
-        return "more clusters than catalog rows"
-    if not 1 <= p <= c:
-        return "probe_clusters must be in [1, clusters]"
-    if shape.num_movies * p < shape.serve_k * c:
-        return ("expected probe coverage (M·probe/clusters) below K — "
-                "index too fine for this catalog")
-    est = estimated_recall(c, p)
-    if est < SERVE_MIN_RECALL:
-        return (f"modeled recall {est:.3f} below the plan constraint "
-                f"{SERVE_MIN_RECALL}")
     return None
 
 
@@ -446,13 +380,6 @@ def _assemble(shape: ProblemShape, cand: dict, pinned: frozenset,
                  and _registry.backend_available(moz)) else emu),
         ("topk", moz if _registry.backend_available(moz) else emu),
     )
-    if full["serve_mode"] == "two_stage":
-        # The candidate stage rides its own slot; "topk" above stays the
-        # un-disableable exact fallback (and the rescore executor).
-        kernels += (
-            ("topk_coarse", moz if _registry.backend_available(moz)
-             else emu),
-        )
     return ExecutionPlan(**full, kernels=kernels, pinned=pinned)
 
 
@@ -559,7 +486,9 @@ def _rank_plans(shape: ProblemShape, device: DeviceSpec,
     ranked = []
     for idx, values in enumerate(prod):
         cand = dict(zip(names, values))
-        reason = (_serve_feasible(shape, cand) if shape.kind == "serve"
+        # a serve-kind candidate (table dtype, batch quantum, tile rows)
+        # has no gate of its own
+        reason = (None if shape.kind == "serve"
                   else _feasible(shape, device, _with_defaults(cand)))
         if reason is not None:
             continue

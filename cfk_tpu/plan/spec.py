@@ -60,22 +60,6 @@ PLAN_FIELDS: dict[str, tuple] = {
     "gram_backend": ("pallas", "xla"),
     "serve_batch_quantum": (8, 16, 32, 64, 128, 256),
     "serve_tile_m": (512,),
-    # Two-stage clustered retrieval (ISSUE 16).  "exact" streams the full
-    # item table per batch (the PR 8 path — bit-identical, and the
-    # un-disableable fallback the engine degrades to on a corrupt or
-    # stale index); "two_stage" probes the k-means centroid index
-    # (serving.cluster) and rescores only the selected clusters' rows
-    # through the same kernel.  clusters/probe_clusters size the index
-    # (0/0 is exact mode's only value); a free serve_mode resolves
-    # through BOTH the cost byte model (centroid scan + expected
-    # short-list gather vs the full scan) and the recall model
-    # (cost.estimated_recall ≥ cost.SERVE_MIN_RECALL — candidates below
-    # the plan recall constraint are never enumerated).  Adding the
-    # fields rotates the autotune field-set digest: pre-two_stage
-    # winners carry no decision for them and must miss.
-    "serve_mode": ("exact", "two_stage"),
-    "clusters": (0, 256, 512, 1024, 2048, 4096),
-    "probe_clusters": (0, 8, 16, 32, 64, 128),
     # Out-of-core tier (ISSUE 11): "device" keeps both factor tables
     # HBM-resident (feasible ONLY while cfk_tpu.offload.budget's predicate
     # passes — the same PER-SHARD predicate the executor sizes windows
@@ -132,11 +116,10 @@ PLAN_FIELDSET_VERSION = 2
 # Fields whose pins are free-form positive ints (the candidate tuples
 # above are only the resolver's enumeration grid for UNPINNED fields).
 _NUMERIC_FIELDS = ("chunk_elems", "serve_batch_quantum", "serve_tile_m",
-                   "ici_group", "hot_rows", "clusters", "probe_clusters")
+                   "ici_group", "hot_rows")
 # Numeric fields where 0 is a legal pin (an explicit OFF, not "unset"):
-# hot_rows=0 pins the full-staging engine; clusters/probe_clusters=0 is
-# the exact serve mode's (only) value.
-_ZERO_OK_FIELDS = ("hot_rows", "clusters", "probe_clusters")
+# hot_rows=0 pins the full-staging engine.
+_ZERO_OK_FIELDS = ("hot_rows",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -287,9 +270,6 @@ class PlanConstraints:
     gram_backend: str | None = None
     serve_batch_quantum: int | None = None
     serve_tile_m: int | None = None
-    serve_mode: str | None = None
-    clusters: int | None = None
-    probe_clusters: int | None = None
     offload_tier: str | None = None
     ici_group: int | None = None
     staging: str | None = None
@@ -393,14 +373,6 @@ class ExecutionPlan:
     gram_backend: str
     serve_batch_quantum: int = 8
     serve_tile_m: int = 512
-    # Two-stage clustered retrieval (ISSUE 16): "exact" | "two_stage",
-    # with the k-means index size and per-user probe count (0/0 in exact
-    # mode).  Exact is the un-disableable fallback: the engine keeps the
-    # PR 8 scan path alive regardless of this field and degrades to it
-    # on index corruption or bounded-staleness overrun.
-    serve_mode: str = "exact"
-    clusters: int = 0
-    probe_clusters: int = 0
     # Out-of-core tier (ISSUE 11): "device" = HBM-resident factor tables,
     # "host_window" = host-RAM stores + device_put-pipelined windows
     # (cfk_tpu.offload) — gated by offload.budget's per-shard fit
@@ -463,12 +435,6 @@ class ExecutionPlan:
             tier += f"stage={self.staging} "
         if self.offload_tier == "host_window" and self.hot_rows:
             tier += f"hot={self.hot_rows} "
-        serve = f"serve_q={self.serve_batch_quantum}"
-        # Provenance must NAME the serve mode (ISSUE 16): a bench row's
-        # plan column says which retrieval path the row executed.
-        if self.serve_mode != "exact":
-            serve += (f" serve={self.serve_mode} c={self.clusters}"
-                      f" probe={self.probe_clusters}")
         return (f"{tier}{self.layout}/{self.exchange} "
                 f"chunk={self.chunk_elems} "
                 f"fused={'on' if self.fused_epilogue else 'off'} "
@@ -476,7 +442,7 @@ class ExecutionPlan:
                 f"overlap={'on' if self.overlap else 'off'} "
                 f"algo={self.reg_solve_algo} table={self.table_dtype} "
                 f"solver={self.solver} "
-                f"{serve} [{kb}]")
+                f"serve_q={self.serve_batch_quantum} [{kb}]")
 
     def as_dict(self) -> dict:
         d = self.knob_dict()

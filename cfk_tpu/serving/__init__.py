@@ -11,14 +11,6 @@ transport log into pow2-bucketed batches (``server``) over a live-updating
 commits (``engine``); and an open-loop generator measures QPS/p50/p99
 honestly (``loadgen``; PERF.md for what the chip measured).
 
-Two-stage clustered retrieval (ISSUE 16 / ROADMAP item 4) breaks the
-O(users × catalog) scan floor: a seeded k-means over the item factors
-(``cluster``) stores the table CLUSTER-MAJOR, a centroid probe picks
-top-probe clusters per user, and only the batch union of those clusters'
-rows is rescored EXACTLY through the same Pallas kernel (``twostage``) —
-recall@K vs the dense oracle is measured first-class and the exact scan
-stays the un-disableable fallback.
-
 The replicated fleet (ISSUE 18 / ROADMAP item 3, ``fleet``) puts N
 replicas behind the request log: user-keyed routing, admission control
 with explicit retriable rejections, versioned factor-delta shipping with
@@ -27,16 +19,10 @@ zero-downtime epoch rollover (background prewarm + single pointer flip),
 and kill/failover at the committed cursor (at-least-once re-serve).
 """
 
-from cfk_tpu.serving.cluster import (
-    ClusterIndex,
-    build_cluster_index,
-    kmeans_item_clusters,
-)
 from cfk_tpu.serving.engine import (
     ServeEngine,
     engine_from_model,
     pad_table,
-    plan_for_serving,
     row_reader,
 )
 from cfk_tpu.serving.fleet import (
@@ -48,12 +34,6 @@ from cfk_tpu.serving.fleet import (
     SnapshotStore,
     ensure_deltas_topic,
     table_crc,
-)
-from cfk_tpu.serving.twostage import (
-    Shortlist,
-    build_shortlist,
-    default_two_stage_params,
-    recall_at_k,
 )
 from cfk_tpu.serving.loadgen import (
     LoadReport,
@@ -78,16 +58,8 @@ from cfk_tpu.serving.topk_kernel import (
 __all__ = [
     "ServeEngine",
     "engine_from_model",
-    "plan_for_serving",
     "pad_table",
     "row_reader",
-    "ClusterIndex",
-    "build_cluster_index",
-    "kmeans_item_clusters",
-    "Shortlist",
-    "build_shortlist",
-    "default_two_stage_params",
-    "recall_at_k",
     "LoadReport",
     "run_open_loop",
     "warm_serve_programs",

@@ -46,20 +46,7 @@ user rows" and "[B, K] ids+scores":
   uploads and captures the table; ``compute`` hands one batch to the
   device and fetches another's answer, the same one for ``topk``, the one
   a step older for a request server under a backlog, which so keeps the
-  device busy under its own host work,
-- two-stage clustered retrieval (ISSUE 16, ``serve_mode="two_stage"``):
-  a k-means index over the item factors (``serving.cluster``), rebuilt
-  ATOMICALLY on every table swap, probed by a centroid stage
-  (``serve/candidate``) whose selected clusters' rows are rescored
-  exactly through the same kernel (``serve/rescore`` —
-  ``serving.twostage``).  The exact scan is the un-disableable fallback:
-  a corrupt index (NaN centroids, broken offsets, non-finite coarse
-  scores) or a staleness overrun degrades THIS engine to the exact path
-  bit-exactly — same table, same jitted program — records the plan
-  transition + flight-recorder event, and recovers two_stage at the
-  next table swap.  Per-row fold-in movie deltas update the clustered
-  table IN PLACE at their cluster-major position (staleness counted);
-  only a full snapshot swap re-clusters.
+  device busy under its own host work.
 """
 
 from __future__ import annotations
@@ -82,7 +69,7 @@ from cfk_tpu.serving.topk_kernel import (
     slab_tiles,
     topk_scores_counted,
 )
-from cfk_tpu.telemetry import dump_flight, record_event, span
+from cfk_tpu.telemetry import span
 from cfk_tpu.utils.search import csr_find
 
 
@@ -156,14 +143,6 @@ class ServeEngine:
         batch_quantum: int = 8,
         mesh=None,  # the caller's own; or
         shards: int | None = None,  # a mesh over the first `shards` devices
-        plan=None,  # cfk_tpu.plan.ExecutionPlan (serve knobs)
-        plan_provenance=None,
-        serve_mode: str | None = None,  # "exact" | "two_stage"
-        clusters: int | None = None,
-        probe_clusters: int | None = None,
-        cluster_seed: int = 0,
-        max_stale_fraction: float = 0.25,
-        metrics=None,  # telemetry.Metrics — recall/bytes-scanned gauges
     ) -> None:
         from cfk_tpu.config import enable_compile_cache
         from cfk_tpu.ops.quant import resolve_table_dtype
@@ -171,43 +150,8 @@ class ServeEngine:
         # Before the first compile: a restarted server replays its serve
         # programs from the persistent cache.
         enable_compile_cache()
-        # Opt-in plan consumption (cfk_tpu.plan): when a plan is given its
-        # serve knobs (batch quantum, movie tile rows, retrieval mode +
-        # index size, and — unless passed explicitly — the table dtype)
-        # configure the engine, and the provenance rides along for the
-        # bench rows.  No plan → the pre-planner defaults, unchanged.
-        self.plan = plan
-        self.plan_provenance = plan_provenance
-        if plan is not None:
-            if table_dtype is None:
-                table_dtype = plan.table_dtype
-            batch_quantum = plan.serve_batch_quantum
-            tile_m = plan.serve_tile_m
-            if serve_mode is None:
-                serve_mode = plan.serve_mode
-            if clusters is None and plan.clusters:
-                clusters = plan.clusters
-            if probe_clusters is None and plan.probe_clusters:
-                probe_clusters = plan.probe_clusters
-        self.serve_mode = serve_mode or "exact"
-        if self.serve_mode not in ("exact", "two_stage"):
-            raise ValueError(
-                f"serve_mode must be 'exact' or 'two_stage', "
-                f"got {self.serve_mode!r}"
-            )
         self.num_movies = int(num_movies)
         self.num_users = int(num_users)
-        if self.serve_mode == "two_stage":
-            from cfk_tpu.serving.twostage import default_two_stage_params
-
-            dc, dp = default_two_stage_params(self.num_movies)
-            clusters = int(clusters or dc)
-            probe_clusters = int(probe_clusters or dp)
-        self.clusters = int(clusters or 0)
-        self.probe_clusters = int(probe_clusters or 0)
-        self.cluster_seed = int(cluster_seed)
-        self.max_stale_fraction = float(max_stale_fraction)
-        self.metrics = metrics
         self.table_dtype = resolve_table_dtype(table_dtype)
         self.tile_m = int(tile_m)
         self.batch_quantum = int(batch_quantum)
@@ -220,13 +164,6 @@ class ServeEngine:
         self.mesh = mesh
         self._shards = 1 if mesh is None else int(mesh.devices.size)
         self._lock = threading.RLock()
-        # Two-stage state: (ClusterIndex, cluster-major quantized table,
-        # its scales, quantized centroids, centroid scales) — ONE tuple so
-        # every swap is a single atomic reference assignment, like _table.
-        self._cluster = None
-        self._two_stage_disabled = False
-        self.two_stage_fallbacks = 0
-        self.last_scan: dict = {}
         self._u_base = np.asarray(user_factors, np.float32)[:num_users]
         self._u_hot: dict[int, np.ndarray] = {}
         if (seen_movies is None) != (seen_indptr is None):
@@ -299,51 +236,10 @@ class ServeEngine:
         """Upload ``movie_factors`` — a [num_movies, k] array, or a callable
         ``(lo, hi)`` → rows [lo, hi) as float32 (``row_reader``) — as the
         live item table."""
-        import jax
-        import jax.numpy as jnp
-
-        from cfk_tpu.ops.quant import quantize_table
-
-        read = row_reader(movie_factors)
-        if self.serve_mode == "two_stage" and callable(movie_factors):
-            raise ValueError(
-                "serve_mode='two_stage' clusters the whole item table on "
-                "the host: pass it as an array, not as a row reader")
         # one atomic reference swap: a batch in flight keeps the table
         # it captured; the next batch sees the new one
-        self._table = self._upload(read, whole=not callable(movie_factors))
-        if self.serve_mode == "two_stage":
-            # Rebuild the cluster index with every swap (re-cluster ONLY
-            # here — fold-in deltas update rows in place).  Built off to
-            # the side, swapped as one reference: a batch in flight keeps
-            # the (index, table) pair it captured.
-            from cfk_tpu.serving.cluster import build_cluster_index
-
-            host = read(0, self.num_movies)
-            index = build_cluster_index(
-                host, min(self.clusters, max(host.shape[0], 1)),
-                seed=self.cluster_seed,
-            )
-            cpad = pad_table(host[index.perm], self.tile_m, 1)
-            cdata, cscale = quantize_table(
-                jnp.asarray(cpad), self.table_dtype
-            )
-            # the coarse stage scores the QUANTIZED centroid view — the
-            # same canonical ops.quant placement as the kernel's tiles
-            qc, qcs = quantize_table(
-                jnp.asarray(index.centroids), self.table_dtype
-            )
-            self._cluster = (
-                index,
-                jax.device_put(cdata),
-                None if cscale is None else jax.device_put(cscale),
-                jax.device_put(qc),
-                None if qcs is None else jax.device_put(qcs),
-            )
-            # a fresh index is healthy by construction — re-arm two_stage
-            # after any fault-driven degradation (the recovery half of the
-            # chaos contract)
-            self._two_stage_disabled = False
+        self._table = self._upload(row_reader(movie_factors),
+                                   whole=not callable(movie_factors))
 
     def _upload(self, read, *, whole: bool):
         """(data, scale) on the device, or row-sharded over the mesh, from
@@ -459,14 +355,12 @@ class ServeEngine:
         (a ``StreamSession(engine=...)`` keeps no copy of its own).  Other
         tables are refused: a fold-in solves float32 normal equations
         against whole rows on one device."""
-        if (self.mesh is not None or self.table_dtype != "float32"
-                or self.serve_mode != "exact"):
+        if self.mesh is not None or self.table_dtype != "float32":
             raise ValueError(
-                "a fold-in reads a float32 item table on one device, "
-                f"scanned exactly; this engine holds table_dtype="
-                f"{self.table_dtype!r} over {self._shards} device(s), "
-                f"serve_mode={self.serve_mode!r}: give the session a table "
-                "of its own (no engine=)")
+                "a fold-in reads a float32 item table on one device; this "
+                f"engine holds table_dtype={self.table_dtype!r} over "
+                f"{self._shards} device(s): give the session a table of "
+                "its own (no engine=)")
         with self._lock:
             return self._table[0]
 
@@ -501,9 +395,8 @@ class ServeEngine:
                                  int(event.get("num_users", self.num_users)))
             self.commit_ordinal = max(
                 self.commit_ordinal, int(event.get("stream_step", 0)))
-            # Item-side per-row deltas (ISSUE 16): a commit that ships
-            # re-solved MOVIE rows updates both table views in place —
-            # within each row's existing cluster — without re-clustering.
+            # Item-side per-row deltas: a commit that ships re-solved
+            # MOVIE rows updates the table in place.
             mrows = event.get("movie_rows")
             if mrows is not None and not event.get("retrain"):
                 self.apply_movie_deltas(mrows, event["movie_row_factors"])
@@ -519,15 +412,11 @@ class ServeEngine:
                 self.epoch += 1
 
     def apply_movie_deltas(self, rows, factors) -> int:
-        """Update item factor rows IN PLACE in both table views.
+        """Update item factor rows IN PLACE in the table.
 
-        The exact table updates at the global row; the cluster-major
-        table (when two_stage) at the row's EXISTING cluster position —
-        assignments and centroids intentionally go stale (recorded via
-        ``ClusterIndex.note_stale``; re-clustering happens only on a full
-        snapshot swap).  Quantization is per-row (``ops.quant``), so a
-        delta row's codes+scale are bit-identical to what a full-table
-        requantization would produce.  Returns the rows applied."""
+        Quantization is per-row (``ops.quant``), so a delta row's
+        codes+scale are bit-identical to what a full-table requantization
+        would produce.  Returns the rows applied."""
         import jax.numpy as jnp
 
         from cfk_tpu.ops.quant import quantize_rows_host, quantize_table
@@ -550,17 +439,6 @@ class ServeEngine:
             if scale is not None:
                 scale = set_rows(scale, rows, qs)
             self._table = (data, scale)
-            if self._cluster is not None:
-                index, ctable, cscale, qc, qcs = self._cluster
-                pos = index.positions_of(rows)
-                ctable = ctable.at[pos].set(qd.astype(ctable.dtype))
-                if cscale is not None:
-                    cscale = cscale.at[pos].set(qs)
-                index.note_stale(rows.size)
-                self._cluster = (index, ctable, cscale, qc, qcs)
-                if self.metrics is not None:
-                    self.metrics.gauge("serve/index_stale_rows",
-                                       index.stale_rows)
         return int(rows.size)
 
     # -- request path --------------------------------------------------------
@@ -640,29 +518,24 @@ class ServeEngine:
         return movies, indptr
 
     def topk(self, user_rows, k: int, *, exclude_seen: bool = True,
-             force_exact: bool = False, stamp: dict | None = None):
+             stamp: dict | None = None):
         """(scores [n, k] f32, movie rows [n, k] int32) for the requested
         user rows.  The batch is padded to the pow2 quantum (padding rows
         score with a zero factor vector and are sliced off), so request
         coalescing shares compiled programs across batch sizes.
-
-        ``force_exact`` skips the two-stage candidate path for this one
-        batch (same table, same masks, same jitted exact program) — the
-        dense oracle the recall@K measurements score against.
 
         The two halves of one ``TopKBatch``, back to back: ``stage`` and
         the hand-over to the device, then the fetch.  The request server
         runs the same halves one step apart (``compute``).  ``stamp``, a
         dict, receives the ``epoch`` and the commit ``ordinal`` the batch
         was staged against."""
-        batch = self.stage(user_rows, k, exclude_seen=exclude_seen,
-                           force_exact=force_exact)
+        batch = self.stage(user_rows, k, exclude_seen=exclude_seen)
         if stamp is not None:
             stamp.update(epoch=batch.epoch, ordinal=batch.ordinal)
         return compute(batch, batch)
 
     def stage(self, user_rows, k: int, *, exclude_seen: bool = True,
-              force_exact: bool = False, warm: bool = False) -> "TopKBatch":
+              warm: bool = False) -> "TopKBatch":
         """The host's part of ``topk``'s front half: gather the user rows,
         group the seen cells, upload both.  The ``TopKBatch`` it returns
         owns the table it will be scored against (captured under the lock
@@ -673,8 +546,7 @@ class ServeEngine:
         batch's cell list is padded past the top rung, so that it runs
         both of that rung's programs, and the lower rungs' run beside it
         (``_warm_seen_rungs``): every program the rectangle of such a
-        batch can take.  The two-stage route syncs with the host between
-        its stages: its batch comes back already answered."""
+        batch can take."""
         user_rows = np.asarray(user_rows, dtype=np.int64)
         n = user_rows.shape[0]
         if n == 0:
@@ -693,12 +565,11 @@ class ServeEngine:
         with span("serve/batch/assemble", n=n, b=b) as sp:
             with self._lock:
                 table, scale = self._table
-                cluster = self._cluster
                 epoch, ordinal = self.epoch, self.commit_ordinal
                 u = np.zeros((b, self._u_base.shape[1]), np.float32)
                 u[:n] = self._gather_users(user_rows)
                 seen = self._batch_seen(user_rows) if exclude_seen else None
-            seen_pad = None
+            movies = indptr_pad = None
             if seen is not None:
                 movies, indptr = seen
                 # padding slots carry EMPTY seen lists (repeat the last
@@ -708,21 +579,10 @@ class ServeEngine:
                 indptr_pad = np.concatenate(
                     [indptr, np.full(b - n, indptr[-1], np.int64)]
                 )
-                seen_pad = (movies, indptr_pad)
                 sp.set(seen_cells=len(movies))
-        if (self.serve_mode == "two_stage" and not force_exact
-                and not self._two_stage_disabled):
-            out = self._topk_two_stage(cluster, u, n, b, k, seen_pad, warm)
-            if out is not None:
-                return TopKBatch(self, n=n, k=k, epoch=epoch,
-                                 ordinal=ordinal, result=out)
-            # a detected fault fell through: the exact path below IS the
-            # un-disableable fallback — same table, same jitted program
-            # as serve_mode="exact", so the degraded answer is bit-exact
         tiles = table.shape[0] // self.tile_m
         seen = shape = None
-        if seen_pad is not None:
-            movies, indptr_pad = seen_pad
+        if movies is not None:
             with span("serve/batch/seen_tiles") as sp:
                 cells, shape = group_seen_cells(
                     movies, indptr_pad, np.arange(b),
@@ -750,7 +610,7 @@ class ServeEngine:
             u = _put(u, self.mesh)
             sp.set(bytes=nbytes)
         if warm and seen is not None:
-            self._warm_seen_rungs(shape, self.mesh)
+            self._warm_seen_rungs(shape)
         # what the fetch will say of the batch on ``serve/batch/compute``
         shard_tiles = tiles // self._shards
         slab = slab_tiles(shard_tiles, b, 0 if shape is None else shape[2],
@@ -773,34 +633,32 @@ class ServeEngine:
                             merge_candidates=self._shards * k)
         return TopKBatch(
             self, n=n, k=k, epoch=epoch, ordinal=ordinal, counters=counters,
-            operands=(u, table, scale, seen, shape),
-            scan=self._scan_record(mode="exact", b=b, k=k,
-                                   table_rows=table.shape[0]))
+            operands=(u, table, scale, seen, shape))
 
-    def _seen_tiles(self, chunks, shape, mesh=None):
+    def _seen_tiles(self, chunks, shape):
         """The [NT, B, W] exclusion rectangle on the device and which of
         its tiles hold a cell (a ``SeenTiles``), from the batch's uploaded
         cell list (``_seen_chunks``; None = no exclusion): one run of the
         scatter program, which starts from a fresh all-padding rectangle;
         a list past the ladder's top rung comes as several arrays and each
         further one runs the top rung's program on the rectangle so far.
-        Every caller — exact, item-sharded, two-stage rescore — gets its
-        rectangle here, from the one ``scatter_seen_cells``; over a mesh
-        each chip builds the tiles it scans
+        Every caller, on one device or over a mesh, gets its rectangle
+        here, from the one ``scatter_seen_cells``; over a mesh each chip
+        builds the tiles it scans
         (``parallel.spmd.serve_seen_tiles_sharded``)."""
-        if mesh is None:
+        if self.mesh is None:
             build = _seen_tiles_jit_fn()
         else:
             from cfk_tpu.parallel.spmd import serve_seen_tiles_sharded
 
-            build = functools.partial(serve_seen_tiles_sharded, mesh)
+            build = functools.partial(serve_seen_tiles_sharded, self.mesh)
         seen_tiles = None
         for cells in chunks or ():
             seen_tiles = build(cells, seen_tiles, shape=shape,
                                tile_m=self.tile_m)
         return seen_tiles
 
-    def _warm_seen_rungs(self, shape, mesh=None) -> None:
+    def _warm_seen_rungs(self, shape) -> None:
         """``prewarm``'s: the rectangle's program of every rung under the
         top, run once over an all-padding list and waited for, so that no
         two of their rectangles are alive at once.  The top rung's two
@@ -813,140 +671,7 @@ class ServeEngine:
         for rung in SEEN_PIECE_RUNGS[:-1]:
             (pad,) = chunk_seen_cells(none, rung * seen_cell_capacity(b), nt)
             jax.block_until_ready(
-                self._seen_tiles([_put(pad, mesh)], shape, mesh))
-
-    def _topk_two_stage(self, cluster, u, n, b, k, seen_pad, warm=False):
-        """One two-stage batch: centroid probe → batch-union shortlist →
-        exact rescore.  Returns ``(vals, ids)`` sliced to ``n``, or None
-        after recording a fault — the caller then takes the exact scan."""
-        import jax.numpy as jnp
-
-        from cfk_tpu.serving.twostage import (
-            build_shortlist,
-            coarse_jit_fn,
-            map_shortlist_ids,
-            rescore_jit_fn,
-            shortlist_seen_cells,
-        )
-
-        if cluster is None:
-            self._two_stage_fault("cluster index missing")
-            return None
-        index, ctable, cscale, qc, qcs = cluster
-        reason = index.quick_check()
-        if reason is not None:
-            self._two_stage_fault(reason)
-            return None
-        if index.stale_fraction > self.max_stale_fraction:
-            self._two_stage_fault(
-                f"index staleness {index.stale_fraction:.3f} over the "
-                f"{self.max_stale_fraction} bound (awaiting table swap)"
-            )
-            return None
-        probe = min(max(self.probe_clusters, 1), index.num_clusters)
-        with span("serve/candidate", n=n, b=b, probe=probe):
-            cvals, cids = coarse_jit_fn()(jnp.asarray(u), qc, qcs,
-                                          probe=probe)
-            if not np.isfinite(np.asarray(cvals)[:n]).all():
-                self._two_stage_fault("non-finite coarse scores")
-                return None
-            # union over the REAL rows only — padding slots carry a zero
-            # factor vector and would vote junk clusters into the gather
-            shortlist = build_shortlist(
-                index, np.asarray(cids)[:n].ravel(),
-                tile_m=self.tile_m, min_rows=k,
-            )
-            seen = shape = None
-            if seen_pad is not None:
-                movies, indptr_pad = seen_pad
-                with span("serve/batch/seen_tiles") as sp:
-                    cells, shape = shortlist_seen_cells(
-                        index, shortlist, movies, indptr_pad, b,
-                        tile_m=self.tile_m,
-                    )
-                    seen = _seen_chunks(sp, cells, shape, warm)
-                with span("serve/batch/upload",
-                          bytes=sum(c.nbytes for c in seen)):
-                    seen = [jnp.asarray(c) for c in seen]
-                if warm:
-                    self._warm_seen_rungs(shape)
-        with span("serve/rescore", n=n, b=b, k=k, rows=shortlist.rows,
-                  rows_padded=shortlist.rows_padded):
-            seen_tiles = self._seen_tiles(seen, shape)
-            vals, ids = rescore_jit_fn()(
-                jnp.asarray(u), jnp.asarray(shortlist.indices), ctable,
-                cscale, seen_tiles, np.int32(shortlist.offset),
-                k_top=k, tile_m=self.tile_m,
-            )
-            vals = np.asarray(vals)[:n]
-            ids = map_shortlist_ids(np.asarray(ids)[:n], shortlist)
-        self._publish_scan(self._scan_record(
-            mode="two_stage", b=b, k=k, shortlist=shortlist, probe=probe,
-            index=index))
-        return vals, ids
-
-    def _two_stage_fault(self, reason: str) -> None:
-        """Degrade to the exact scan until the next table swap.
-
-        The chaos contract (``chaos_lab two_stage_fallback``): the fault
-        is RECORDED (flight-recorder event + dump, plan transition,
-        fallback counter), the answer comes from the exact path
-        bit-exactly, and ``_set_table`` re-arms two_stage when a healthy
-        index is rebuilt."""
-        self._two_stage_disabled = True
-        self.two_stage_fallbacks += 1
-        record_event("serve", "two_stage_fault", reason=reason,
-                     fallbacks=self.two_stage_fallbacks)
-        dump_flight(f"two_stage_fallback: {reason}")
-        if self.plan_provenance is not None:
-            self.plan_provenance.record_transition(
-                "two_stage_fallback",
-                f"{reason}; exact scan until the next table swap "
-                "rebuilds the index",
-            )
-        if self.metrics is not None:
-            self.metrics.incr("serve/two_stage_fallbacks")
-
-    def _scan_record(self, *, mode, b, k, shortlist=None, probe=0,
-                     index=None, table_rows=None) -> dict:
-        """Per-batch scan accounting: the MEASURED byte traffic of the
-        executed mode (``utils.roofline.serve_batch_cost`` over the real
-        shortlist union for two_stage), as ``_publish_scan`` exposes it
-        once the batch is answered."""
-        from cfk_tpu.utils.roofline import serve_batch_cost
-
-        rank = int(self._u_base.shape[1])
-        if mode == "two_stage":
-            cost = serve_batch_cost(
-                self.num_movies, rank, b, k, table_dtype=self.table_dtype,
-                serve_mode="two_stage", clusters=index.num_clusters,
-                probe_clusters=probe,
-                shortlist_rows=shortlist.rows_padded,
-            )
-            return {
-                "serve_mode": "two_stage",
-                "clusters": index.num_clusters,
-                "probe_clusters": probe,
-                "shortlist_rows": shortlist.rows,
-                "shortlist_rows_padded": shortlist.rows_padded,
-                "index_stale_rows": index.stale_rows,
-                "bytes_scanned_per_batch": round(cost.hbm_bytes),
-            }
-        cost = serve_batch_cost(
-            self.num_movies, rank, b, k, table_dtype=self.table_dtype,
-            m_pad=table_rows,
-        )
-        return {
-            "serve_mode": "exact",
-            "bytes_scanned_per_batch": round(cost.hbm_bytes),
-        }
-
-    def _publish_scan(self, record: dict) -> None:
-        """``last_scan`` for the bench rows, and the metrics gauge."""
-        self.last_scan = record
-        if self.metrics is not None:
-            self.metrics.gauge("serve/bytes_scanned_per_batch",
-                               record["bytes_scanned_per_batch"])
+                self._seen_tiles([_put(pad, self.mesh)], shape))
 
     @property
     def trace_count(self) -> int:
@@ -973,11 +698,7 @@ class ServeEngine:
         Returns
         ``{"programs", "new_traces", "prewarm_s"}``; a later batch whose
         (padded size, seen width) bucket was covered here traces
-        nothing, which ``tests/test_staging.py`` pins.  In two_stage
-        mode each rung additionally traces the centroid probe and the
-        rescore at the shortlist width that rung's union produced —
-        pass a workload ``user_rows`` sample so those widths land in
-        the same pow2 buckets as live traffic."""
+        nothing, which ``tests/test_staging.py`` pins."""
         import time as _time
 
         with span("serve/prewarm", k=k, max_batch=max_batch):
@@ -993,11 +714,6 @@ class ServeEngine:
             before = trace_count()
             programs = 0
 
-            def warm(take):
-                batch = self.stage(take, k, exclude_seen=exclude_seen,
-                                   warm=True)
-                compute(batch, batch)
-
             b = self.batch_quantum
             while b <= top:
                 take = rows[: min(b, rows.size)]
@@ -1006,18 +722,10 @@ class ServeEngine:
                 # sample still traces the intended batch size
                 if take.size < b:
                     take = np.resize(take, b)
-                warm(take)
+                batch = self.stage(take, k, exclude_seen=exclude_seen,
+                                   warm=True)
+                compute(batch, batch)
                 programs += 1
-                if self.serve_mode == "two_stage" and rows.size > b:
-                    # a second, disjoint sample per rung: the shortlist
-                    # union width is data-dependent, so one sample warms
-                    # one pow2 width bucket — a second makes the
-                    # neighboring bucket resident when live unions
-                    # straddle a boundary
-                    alt = rows[b:2 * b]
-                    if alt.size < b:
-                        alt = np.resize(alt, b)
-                    warm(alt)
                 b *= 2
             self.prewarmed = True  # the /readyz gate flips here
             return {
@@ -1034,21 +742,19 @@ class TopKBatch:
     (the jitted scatter over the batch's cell list, then the scorer:
     asynchronous calls that return at once) and ``fetch`` waits for the
     answer and copies it to the host.  The fetch reads the batch alone and
-    nothing of the engine (it leaves the batch's scan record there), so
-    whatever the engine became in between (a commit, a delta, a table
-    swap, another engine in its server's place) the answer is that of the
-    table, the ``epoch`` and the commit ``ordinal`` the batch was staged
-    against.  A batch that needs no device (no rows; the two-stage route,
-    which syncs with the host between its stages) is made with its
-    ``result`` and both halves pass it through."""
+    nothing of the engine, so whatever the engine became in between (a
+    commit, a delta, a table swap, another engine in its server's place)
+    the answer is that of the table, the ``epoch`` and the commit
+    ``ordinal`` the batch was staged against.  The empty batch (no rows)
+    needs no device: it is made with its ``result`` and both halves pass
+    it through."""
 
     def __init__(self, engine, *, n, k, epoch, ordinal=0, counters=None,
-                 operands=None, scan=None, result=None) -> None:
+                 operands=None, result=None) -> None:
         self.engine = engine
         self.n, self.k = n, k
         self.epoch, self.ordinal = epoch, ordinal
         self.counters = counters
-        self.scan = scan
         self.result = result
         self._operands = operands
         self._out = None
@@ -1067,7 +773,7 @@ class TopKBatch:
     def dispatch(self) -> None:
         eng = self.engine
         u, table, scale, seen, shape = self._operands
-        seen_tiles = eng._seen_tiles(seen, shape, eng.mesh)
+        seen_tiles = eng._seen_tiles(seen, shape)
         if eng.mesh is not None:
             from cfk_tpu.parallel.spmd import serve_topk_sharded
 
@@ -1099,7 +805,6 @@ class TopKBatch:
         sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
                seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
                **self.counters)
-        self.engine._publish_scan(self.scan)
         self.result = vals[:self.n], ids[:self.n]
         return self.result
 
@@ -1110,8 +815,8 @@ def compute(dispatch: TopKBatch | None, fetch: TopKBatch | None):
     ``ServeEngine.topk`` passes one batch as both; the request server under
     a backlog passes the batch it has just polled and the one it handed
     over a step ago, whose scorer ran under the host's stages since.  The
-    span's counters are the fetched batch's.  Batches that came back from
-    ``stage`` already answered open no span."""
+    span's counters are the fetched batch's.  The empty batch, which
+    ``stage`` returns already answered, opens no span."""
     to_device = dispatch is not None and dispatch.result is None
     from_device = fetch is not None and fetch.result is None
     if to_device or from_device:
@@ -1156,13 +861,10 @@ _TRACES = [0]
 
 
 def trace_count() -> int:
-    """Traces of the serve programs this process — the exact scan and the
+    """Traces of the serve programs this process: the exact scan and the
     seen-rectangle scatter, on one device or as shard programs over a
-    mesh, plus (ISSUE 16) the two-stage coarse/rescore stages, so the
-    prewarm contract covers whichever mode the plan picked."""
-    from cfk_tpu.serving import twostage
-
-    return _TRACES[0] + twostage.trace_count()
+    mesh."""
+    return _TRACES[0]
 
 
 def note_trace() -> None:
@@ -1237,39 +939,11 @@ def _replicated(mesh):
     return NamedSharding(mesh, P())
 
 
-def plan_for_serving(num_users: int, num_movies: int, rank: int, *,
-                     k_top: int = 100, table_dtype: str | None = None,
-                     serve_mode: str | None = None,
-                     clusters: int | None = None,
-                     probe_clusters: int | None = None,
-                     mode: str = "model", cache_path: str | None = None):
-    """Resolve a serve-side ExecutionPlan: the batch quantum, table dtype
-    and (ISSUE 16) serve mode chosen from the scan/shortlist byte model
-    (``cost.serve_batch_cost_for``), with explicit knobs arriving as
-    pins — a pinned two_stage whose modeled recall@K falls below the
-    0.95 floor raises at resolution rather than serving bad answers.
-    Returns ``(plan, provenance)`` — hand both to
-    ``ServeEngine(plan=...)``."""
-    from cfk_tpu.plan import PlanConstraints, ProblemShape, plan
-
-    shape = ProblemShape(
-        num_users=num_users, num_movies=num_movies,
-        nnz=max(num_users, num_movies), rank=rank, kind="serve",
-        serve_k=k_top,
-    )
-    cons = PlanConstraints(table_dtype=table_dtype, serve_mode=serve_mode,
-                           clusters=clusters,
-                           probe_clusters=probe_clusters)
-    return plan(shape, None, cons, mode=mode, cache_path=cache_path)
-
-
 def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
-                      mesh=None, shards=None, batch_quantum=8, plan=None,
-                      plan_provenance=None, serve_mode=None, clusters=None,
-                      probe_clusters=None, metrics=None) -> ServeEngine:
+                      mesh=None, shards=None,
+                      batch_quantum=8) -> ServeEngine:
     """Build an engine from an ``ALSModel`` (+ optional dataset/index whose
-    ``coo_dense`` provides the exclude-seen lists).  ``plan`` (see
-    ``plan_for_serving``) optionally supplies the serve knobs."""
+    ``coo_dense`` provides the exclude-seen lists)."""
     seen_movies = seen_indptr = None
     if dataset is not None:
         coo = dataset.coo_dense
@@ -1294,7 +968,5 @@ def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
         num_users=model.num_users, num_movies=model.num_movies,
         seen_movies=seen_movies, seen_indptr=seen_indptr,
         table_dtype=table_dtype, tile_m=tile_m, mesh=mesh, shards=shards,
-        batch_quantum=batch_quantum, plan=plan,
-        plan_provenance=plan_provenance, serve_mode=serve_mode,
-        clusters=clusters, probe_clusters=probe_clusters, metrics=metrics,
+        batch_quantum=batch_quantum,
     )

@@ -532,9 +532,7 @@ class FleetReplica:
                     seen_movies=old._seen_movies,
                     seen_indptr=old._seen_indptr,
                     table_dtype=old.table_dtype, tile_m=old.tile_m,
-                    batch_quantum=old.batch_quantum,
-                    serve_mode=old.serve_mode,
-                    metrics=self.metrics,
+                    batch_quantum=old.batch_quantum, mesh=old.mesh,
                 )
                 eng.epoch = snap["epoch"]
                 for row, f in snap["overlay"].items():

@@ -545,7 +545,7 @@ def slab_tiles(num_tiles, batch, seen_width, rank, table_dtype, *,
     ahead of their gates (``_topk_kernel``), and a grid step costs a
     little whatever its height (0.06 µs: PERF.md section 7, row 17), so
     the step is as tall as the budget and the table allow: the ladder's
-    top at a serve cell's 18,262 tiles, NT's rung at a shortlist's
+    top at a serve cell's 18,262 tiles, NT's rung at a table of a
     handful, 1 where a wide rectangle leaves room for one tile only.  A
     function of the shapes alone; ``tile_m`` stays the tile of the fold,
     of the gate, of the rectangle and of every count."""

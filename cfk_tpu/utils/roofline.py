@@ -231,60 +231,18 @@ class ServeBatchCost:
         return max(self.flops_bound_s(peak), self.bytes_bound_s(bandwidth))
 
 
-def expected_shortlist_rows(num_movies: int, batch: int, clusters: int,
-                            probe_clusters: int) -> float:
-    """Expected batch-union shortlist rows of the two-stage path.
-
-    Each user probes ``probe`` of ``clusters`` clusters; the rescore
-    gathers the BATCH-UNION, so the expected covered-cluster fraction is
-    ``1 − (1 − probe/clusters)^batch`` under the independence prior (the
-    model's conservative default — correlated traffic, the common case
-    under zipf user popularity, only shrinks the union).  This is the
-    model-side estimate; the bench charges the MEASURED union instead."""
-    c = max(int(clusters), 1)
-    p = min(max(int(probe_clusters), 1), c)
-    frac = 1.0 - (1.0 - p / c) ** max(int(batch), 1)
-    return float(num_movies) * frac
-
-
 def serve_batch_cost(num_movies: int, rank: int, batch: int, k_top: int,
                      *, table_dtype: str | None = None,
-                     m_pad: int | None = None,
-                     serve_mode: str = "exact",
-                     clusters: int = 0, probe_clusters: int = 0,
-                     shortlist_rows: float | None = None) -> ServeBatchCost:
+                     m_pad: int | None = None) -> ServeBatchCost:
     """Model cost of one [batch, k_top] top-K scoring batch.
 
     ``m_pad`` is the padded table row count actually scanned (tile/shard
     padding scans too — charge what the kernel reads); the per-row bytes
     follow the table dtype exactly like the gather floor
     (``table_gather_bytes_per_row`` — int8 is charged codes PLUS the
-    per-row f32 scale, never a flat 1 B/row).
-
-    ``serve_mode="two_stage"`` (ISSUE 16) swaps the full table scan for
-    the clustered path's traffic: the [clusters, k] centroid scan (same
-    dtype as the table — the coarse stage scores the quantized view) plus
-    the gathered shortlist rows (``shortlist_rows`` when MEASURED —
-    bench/engine pass the real union — else the expected batch-union,
-    ``expected_shortlist_rows``) at table-row bytes plus 4 B/row of
-    gather indices.  That byte swap IS the lever the planner prices:
-    two_stage wins exactly where centroids + shortlist undercut the scan.
-    """
+    per-row f32 scale, never a flat 1 B/row)."""
     row_bytes = table_gather_bytes_per_row(rank, table_dtype)
     io_bytes = batch * rank * 4.0 + batch * k_top * 8.0
-    if serve_mode == "two_stage":
-        if clusters <= 0:
-            raise ValueError("two_stage cost needs clusters >= 1")
-        sl_rows = (float(shortlist_rows) if shortlist_rows is not None
-                   else expected_shortlist_rows(num_movies, batch, clusters,
-                                                probe_clusters))
-        centroid_bytes = clusters * row_bytes
-        shortlist_bytes = sl_rows * (row_bytes + 4.0)  # + int32 gather idx
-        flops = 2.0 * batch * (clusters + sl_rows) * rank
-        return ServeBatchCost(
-            model_flops=flops,
-            hbm_bytes=centroid_bytes + shortlist_bytes + io_bytes,
-        )
     rows = float(m_pad if m_pad is not None else num_movies)
     flops = 2.0 * batch * rows * rank
     table_bytes = rows * row_bytes
@@ -303,10 +261,7 @@ def serve_roofline_row(cost: ServeBatchCost, s_per_batch: float,
         "device_kind": device_kind,
         "serve_batch_tflops": round(cost.model_flops / 1e12, 6),
         "serve_batch_mb": round(cost.hbm_bytes / 1e6, 3),
-        # The EXECUTED mode's per-batch HBM traffic (ISSUE 16): for exact
-        # rows this is the table scan + io; for two_stage rows the caller
-        # builds the cost from the MEASURED shortlist union, so the byte
-        # column is what the batch actually moved, not the model's guess.
+        # the batch's HBM traffic: the table scan + io
         "bytes_scanned_per_batch": round(cost.hbm_bytes),
     }
     pk = _peaks_or_none(device_kind)

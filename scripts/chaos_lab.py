@@ -1776,79 +1776,6 @@ def scenario_serve_under_foldin() -> dict:
     }
 
 
-def scenario_two_stage_fallback() -> dict:
-    """ISSUE 16: a corrupted two-stage cluster index must never corrupt
-    answers.  NaN-poison the centroid table under a serving engine.
-    Contract: (1) DETECTED — the per-batch index health probe trips
-    before any shortlist is scored; (2) DEGRADED BIT-EXACTLY — the
-    faulted request and every request until recovery is answered by the
-    exact scan, bit-identical to a pure-exact engine on the same
-    factors; (3) RECORDED — a flight dump and a plan-provenance
-    transition name the fault; (4) RECOVERED — the next full table swap
-    (a retrain commit through the live-update listener) rebuilds the
-    index and two_stage resumes at its recall floor."""
-    from cfk_tpu.plan.cost import SERVE_MIN_RECALL
-    from cfk_tpu.serving import ServeEngine, plan_for_serving, recall_at_k
-
-    rng = np.random.default_rng(7)
-    users, movies, rank, k = 96, 1024, 16, 5
-    uf = rng.standard_normal((users, rank)).astype(np.float32) * 0.3
-    mf = rng.standard_normal((movies, rank)).astype(np.float32) * 0.3
-    # the pinned two_stage plan resolves through the cost model (a pin
-    # below the recall floor would raise here instead of serving badly)
-    plan_, prov = plan_for_serving(
-        users, movies, rank, k_top=k, serve_mode="two_stage",
-        clusters=256, probe_clusters=32,
-    )
-    eng = ServeEngine(uf, mf, num_users=users, num_movies=movies,
-                      plan=plan_, plan_provenance=prov)
-    exact = ServeEngine(uf, mf, num_users=users, num_movies=movies,
-                        table_dtype=eng.table_dtype, tile_m=eng.tile_m,
-                        batch_quantum=eng.batch_quantum, serve_mode="exact")
-    rows = np.arange(8)
-    eng.topk(rows, k)
-    healthy_mode = eng.last_scan.get("serve_mode")
-    # inject: NaN-poison the centroid table the coarse stage scores
-    eng._cluster[0].centroids[5, :] = np.nan
-    fv, fi = eng.topk(rows, k)  # the faulted request
-    ev, ei = exact.topk(rows, k)
-    bit_exact = (np.array_equal(np.asarray(fv), np.asarray(ev))
-                 and np.array_equal(np.asarray(fi), np.asarray(ei)))
-    detected = bool(eng.two_stage_fallbacks == 1
-                    and eng.last_scan.get("serve_mode") == "exact")
-    transition = (prov.transitions[-1]["reason"]
-                  if prov.transitions else None)
-    # degraded steady state: still exact, no re-fire of the fault path
-    eng.topk(rows, k)
-    degraded_stable = bool(eng.two_stage_fallbacks == 1
-                           and eng.last_scan.get("serve_mode") == "exact")
-    # recovery: a retrain commit swaps the table and rebuilds the index
-    mf2 = mf + rng.standard_normal(mf.shape).astype(np.float32) * 0.01
-    eng.on_commit({"retrain": True, "user_factors": uf,
-                   "movie_factors": mf2})
-    pv, pi = eng.topk(rows, k)
-    post_mode = eng.last_scan.get("serve_mode")
-    _, oracle = eng.topk(rows, k, force_exact=True)
-    post_recall = float(recall_at_k(np.asarray(pi), np.asarray(oracle)))
-    recovered = bool(post_mode == "two_stage"
-                     and not eng._two_stage_disabled
-                     and post_recall >= SERVE_MIN_RECALL)
-    return {
-        "scenario": "two_stage_fallback",
-        "fault_fired": healthy_mode == "two_stage",
-        "detected": detected,
-        "recovered": recovered,
-        "fallbacks": int(eng.two_stage_fallbacks),
-        "fallback_bit_exact": bit_exact,
-        "degraded_stable": degraded_stable,
-        "provenance_transition": transition,
-        "post_recovery_recall": round(post_recall, 4),
-        "ok": bool(healthy_mode == "two_stage" and detected and bit_exact
-                   and degraded_stable and recovered
-                   and transition == "two_stage_fallback"),
-    }
-
-
 def _fleet_fixture(replicas, transport=None, seed=0, users=48, movies=64,
                    rank=6, **fleet_kw):
     """(fleet, publisher, broker, (u, m), oracle_engine) — a prewarmed
@@ -2104,7 +2031,6 @@ SCENARIOS = {
     "serve_replica_kill": scenario_serve_replica_kill,
     "serve_delta_gap": scenario_serve_delta_gap,
     "serve_rollover": scenario_serve_rollover,
-    "two_stage_fallback": scenario_two_stage_fallback,
     "plan_fallback": scenario_plan_fallback,
     "offload_window": scenario_offload_window,
     "offload_window_sharded": scenario_offload_window_sharded,
@@ -2142,7 +2068,6 @@ FLIGHT_EXPECT = {
     "serve_replica_kill": ("replica_kill", "failover"),
     "serve_delta_gap": ("delta_gap", "resync"),
     "serve_rollover": ("rollover_begin", "rollover_flip"),
-    "two_stage_fallback": ("two_stage_fault",),
     "plan_fallback": ("health_trip", "nonfinite"),
     "offload_window": ("health_trip",),
     "offload_window_sharded": ("health_trip",),

@@ -42,12 +42,12 @@ def _engine(u, m, **kw):
     return ServeEngine(u, m, num_users=U, num_movies=M, tile_m=16, **kw)
 
 
-def _wired(replicas=1, seed=0, **fleet_kw):
+def _wired(replicas=1, seed=0, shards=None, **fleet_kw):
     """(fleet, publisher, broker, (u, m)) with the store seeded."""
     u, m = _factors(seed)
     broker = InMemoryBroker()
-    fleet = ServeFleet(lambda i: _engine(u, m), broker, replicas=replicas,
-                       **fleet_kw)
+    fleet = ServeFleet(lambda i: _engine(u, m, shards=shards), broker,
+                       replicas=replicas, **fleet_kw)
     fleet.seed_store(u, m, num_users=U)
     pub = DeltaPublisher(broker, fleet.store)
     return fleet, pub, broker, (u, m)
@@ -209,10 +209,12 @@ def test_duplicate_delta_delivery_is_idempotent():
 # -- rollover ----------------------------------------------------------------
 
 
-def test_rollover_flips_epoch_and_applies_deferred_deltas():
-    fleet, pub, broker, (u, m) = _wired()
+@pytest.mark.parametrize("shards", [None, 2], ids=["one_device", "shards2"])
+def test_rollover_flips_epoch_and_applies_deferred_deltas(shards):
+    fleet, pub, broker, (u, m) = _wired(shards=shards)
     rng = np.random.default_rng(7)
     replica = fleet.replicas[0]
+    placed = replica.engine._table[0].sharding
     pub.on_commit(_commit(rng, [1]))
     replica.pump()
     assert replica.engine.epoch == 0
@@ -229,6 +231,10 @@ def test_rollover_flips_epoch_and_applies_deferred_deltas():
         time.sleep(0.01)
     assert replica.rollovers == 1
     assert replica.engine.epoch == 1
+    # the new epoch's engine lies where the old one lay: a row-sharded
+    # replica does not roll over onto one device
+    assert replica.engine._table[0].sharding == placed
+    assert len(placed.device_set) == (shards or 1)
     replica.pump()  # drain anything the flip left pending
     assert replica.applied_seq == 3
     # the deferred commit landed on the NEW engine

@@ -668,13 +668,13 @@ def test_seen_rectangle_at_the_skew_cell_shape_equals_host_oracle(
                                   (want != tile).any(axis=(1, 2)))
 
 
-@pytest.mark.parametrize("caller", ["exact", "one_device_mesh", "two_stage"])
+@pytest.mark.parametrize("caller", ["exact", "one_device_mesh"])
 def test_serve_callers_same_answers_from_the_chip_built_rectangle(
         caller, monkeypatch):
-    """Each caller of the device-built rectangle — the one-device scan, the
-    item-sharded scan (a mesh of this one chip), the two-stage rescore —
-    answers as it did over a rectangle built on the host and uploaded
-    whole: ids and scores bit for bit, one piece or several."""
+    """Each caller of the device-built rectangle — the one-device scan and
+    the item-sharded scan (a mesh of this one chip) — answers as it did
+    over a rectangle built on the host and uploaded whole: ids and scores
+    bit for bit, one piece or several."""
     from cfk_tpu.parallel.mesh import make_mesh
     from cfk_tpu.serving import engine as engine_mod
     from tests.test_serving import host_built_seen_tiles
@@ -688,7 +688,6 @@ def test_serve_callers_same_answers_from_the_chip_built_rectangle(
         uf, mf, num_users=users, num_movies=m, seen_movies=movies,
         seen_indptr=indptr, tile_m=tile,
         mesh=make_mesh(1) if caller == "one_device_mesh" else None,
-        serve_mode="two_stage" if caller == "two_stage" else "exact",
     )
     rows = rng.integers(0, users, size=50)
     # one piece; a few (one run of a higher rung); past the top rung (the

@@ -144,69 +144,6 @@ def test_serve_plan_prefers_quantized_table_and_big_quanta():
     assert pinned.table_dtype == "float32"
 
 
-# -- serve mode (ISSUE 16): two_stage through the byte model ----------------
-
-_SERVE_SH = ProblemShape(num_users=162_541, num_movies=59_047, nnz=59_047,
-                         rank=128, kind="serve", serve_k=100)
-
-
-def test_serve_mode_resolves_through_cost_model():
-    from cfk_tpu.plan.cost import SERVE_MIN_RECALL, estimated_recall
-
-    # small coalesced batches: the expected batch-union shortlist is far
-    # under the catalog, so the byte model picks two_stage
-    small_q, prov = plan(_SERVE_SH, CPU, PlanConstraints(
-        serve_batch_quantum=8))
-    assert small_q.serve_mode == "two_stage"
-    assert small_q.clusters >= 2
-    assert 1 <= small_q.probe_clusters <= small_q.clusters
-    assert (estimated_recall(small_q.clusters, small_q.probe_clusters)
-            >= SERVE_MIN_RECALL)
-    # provenance names the mode, and the coarse kernel slot is planned
-    assert "serve=two_stage" in prov.summary()
-    assert "topk_coarse" in dict(small_q.kernels)
-    # huge batches amortize the scan — the union approaches the catalog
-    # and exact wins; its summary is byte-identical to pre-ISSUE-16
-    big_q, prov2 = plan(_SERVE_SH, CPU, PlanConstraints(
-        serve_batch_quantum=256))
-    assert big_q.serve_mode == "exact"
-    assert big_q.clusters == 0 and big_q.probe_clusters == 0
-    assert "serve=" not in prov2.summary()
-    assert "topk_coarse" not in dict(big_q.kernels)
-
-
-def test_serve_mode_pins_and_recall_floor_conflicts():
-    # pinned exact forbids cluster knobs
-    with pytest.raises(PlanConstraintError, match="exact"):
-        plan(_SERVE_SH, CPU, PlanConstraints(serve_mode="exact",
-                                             clusters=1024))
-    # probing more clusters than exist is unsatisfiable
-    with pytest.raises(PlanConstraintError, match="probe"):
-        plan(_SERVE_SH, CPU, PlanConstraints(serve_mode="two_stage",
-                                             clusters=256,
-                                             probe_clusters=512))
-    # a pinned two_stage below the modeled recall floor raises AT
-    # RESOLUTION, naming the recall — it must never serve bad answers
-    with pytest.raises(PlanConstraintError, match="recall"):
-        plan(_SERVE_SH, CPU, PlanConstraints(serve_mode="two_stage",
-                                             clusters=4096,
-                                             probe_clusters=8))
-    # two_stage on a TRAINING shape is meaningless
-    with pytest.raises(PlanConstraintError, match="serve"):
-        plan(_shape(), CPU, PlanConstraints(serve_mode="two_stage"))
-
-
-def test_serve_mode_pinned_exact_matches_pre_issue16_plan():
-    free, _ = plan(_SERVE_SH, CPU, PlanConstraints(
-        serve_batch_quantum=256))
-    pinned, _ = plan(_SERVE_SH, CPU, PlanConstraints(
-        serve_batch_quantum=256, serve_mode="exact"))
-    # pinning what the model already chose changes nothing (bit-identical
-    # plan — the PR 8 serve behavior is reachable and unchanged)
-    assert pinned.serve_mode == "exact"
-    assert dataclasses.replace(pinned, pinned=free.pinned) == free
-
-
 # -- bit-identical execution ------------------------------------------------
 
 def _tiny_ds(layout):

@@ -287,7 +287,6 @@ def test_another_engine_in_the_servers_place_answers_only_the_next_batch():
     for i, rid in enumerate(ids[4:]):
         assert got[rid].epoch == 1
         np.testing.assert_array_equal(got[rid].movie_rows, want_new[1][i, :K])
-    assert old.last_scan and new.last_scan  # each published its own batch's
 
 
 # -- a failure in one half --------------------------------------------------
@@ -351,20 +350,3 @@ def test_two_batches_staged_before_either_is_fetched(route):
         np.testing.assert_array_equal(got[1], want[1])
     assert (a.n, a.counters["b"], b.n, b.counters["b"]) == (3, 4, 5, 8)
     assert a.epoch == b.epoch == 0
-
-
-def test_a_two_stage_batch_comes_back_from_stage_already_answered():
-    uf, mf = _factors()
-    eng = engine_mod.ServeEngine(
-        uf, mf, num_users=USERS, num_movies=MOVIES, tile_m=16,
-        batch_quantum=4, serve_mode="two_stage", clusters=4,
-        probe_clusters=4)
-    batch = eng.stage(np.arange(6), 8)
-    assert batch.result is not None and not batch.on_device
-    assert engine_mod.compute(batch, batch) is batch.result
-    want = eng.topk(np.arange(6), 8)
-    np.testing.assert_array_equal(batch.result[1], want[1])
-    # through the server, under a backlog, like any other batch
-    server, client, ids = _wired(eng, range(8))
-    assert [server.step() for _ in range(3)] == [0, 4, 4]
-    assert sorted(_by_id(client)) == sorted(ids)

@@ -250,13 +250,6 @@ def test_deltas_and_a_swap_keep_the_rule_and_the_placement(shards,
         np.testing.assert_array_equal(got, want)
 
 
-def test_two_stage_needs_the_table_whole():
-    uf, mf, lists, _ = _problem(8)
-    with pytest.raises(ValueError, match="two_stage.*array"):
-        _engine(uf, lambda lo, hi: mf[lo:hi], lists, num_movies=1000,
-                serve_mode="two_stage", clusters=8, probe_clusters=2)
-
-
 def test_host_quantizer_is_the_rule_on_awkward_rows():
     """Ties at .5 round to even, the largest magnitude maps to ±127, a
     zero row keeps scale 1, and the pieces of the thread pool join up."""

@@ -4,6 +4,7 @@ the no-dense-score-matrix memory bound, multi-shard == single-shard, the
 request server round trip, and the hot-user cache's fold-in freshness."""
 
 import functools
+import re
 import warnings
 
 import numpy as np
@@ -785,7 +786,7 @@ def test_engine_answers_equal_dense_oracle_through_device_rectangle(
     np.testing.assert_array_equal(ids, np.asarray(ki)[: len(rows)])
 
 
-def host_built_seen_tiles(engine, chunks, shape, mesh=None):
+def host_built_seen_tiles(engine, chunks, shape):
     """``ServeEngine._seen_tiles`` as the serve path had it before the
     device built the rectangle: numpy fills it, the whole of it is
     uploaded."""
@@ -800,12 +801,12 @@ def host_built_seen_tiles(engine, chunks, shape, mesh=None):
 
 @pytest.mark.parametrize("pieces", [None, 3, 40],
                          ids=["one_piece", "three_pieces", "past_the_top"])
-@pytest.mark.parametrize("caller", ["exact", "item_sharded", "two_stage"])
+@pytest.mark.parametrize("caller", ["exact", "item_sharded"])
 def test_every_caller_serves_from_the_device_built_rectangle(
         caller, pieces, rng, monkeypatch):
-    # one grouping, one device builder: the one-device scan, the sharded
-    # scan and the two-stage rescore give, bit for bit, the answers they
-    # give over a rectangle the host built from the same cell list — also
+    # one grouping, one device builder: the one-device scan and the sharded
+    # scan give, bit for bit, the answers they give over a rectangle the
+    # host built from the same cell list — also
     # for a batch of three pieces (one run of the rung of four) and of forty
     # (three runs of the top rung's program)
     from cfk_tpu.parallel.mesh import make_mesh
@@ -821,8 +822,6 @@ def test_every_caller_serves_from_the_device_built_rectangle(
         uf, mf, num_users=users, num_movies=movies, seen_movies=movies_csr,
         seen_indptr=indptr, tile_m=16, batch_quantum=8,
         mesh=make_mesh(2) if caller == "item_sharded" else None,
-        serve_mode="two_stage" if caller == "two_stage" else "exact",
-        clusters=8, probe_clusters=4,
     )
     rows = rng.integers(0, users, size=13)
     if pieces is not None:
@@ -839,8 +838,7 @@ def test_every_caller_serves_from_the_device_built_rectangle(
 
     monkeypatch.setattr(engine_mod, "_seen_chunks", counting)
     vals, ids = eng.topk(rows, 5)
-    if caller != "two_stage":  # whose cell list is the shortlist's
-        assert programs == [{None: 1, 3: 1, 40: 3}[pieces]]
+    assert programs == [{None: 1, 3: 1, 40: 3}[pieces]]
     monkeypatch.setattr(engine_mod.ServeEngine, "_seen_tiles",
                         host_built_seen_tiles)
     want_vals, want_ids = eng.topk(rows, 5)
@@ -849,6 +847,33 @@ def test_every_caller_serves_from_the_device_built_rectangle(
     for row, got in zip(rows, ids):
         mine = movies_csr[indptr[row]: indptr[row + 1]]
         assert not set(got.tolist()) & set(mine.tolist())
+
+
+def test_int8_engine_bit_identical_to_kernel(rng):
+    # the engine's int8 answers are the kernel's own over the same table,
+    # quantized and assembled by hand
+    from cfk_tpu.ops.quant import quantize_table
+    from cfk_tpu.serving import ServeEngine, pad_table
+
+    users, movies, rank, per_user = 48, 512, 16, 6
+    uf = rng.standard_normal((users, rank)).astype(np.float32)
+    mf = rng.standard_normal((movies, rank)).astype(np.float32)
+    sm = np.sort(rng.integers(0, movies, size=(users, per_user)),
+                 axis=1).astype(np.int32).ravel()
+    si = np.arange(users + 1, dtype=np.int64) * per_user
+    eng = ServeEngine(
+        uf, mf, num_users=users, num_movies=movies, seen_movies=sm,
+        seen_indptr=si, table_dtype="int8", tile_m=64, batch_quantum=8)
+    vals, ids = eng.topk(np.arange(8), 10)
+    data, scale = quantize_table(jnp.asarray(pad_table(mf, 64, 1)), "int8")
+    st = build_seen_tiles(sm, si[:9], np.arange(8), num_movies=movies,
+                          tile_m=64, num_tiles=data.shape[0] // 64)
+    ev, ei = topk_scores_pallas(
+        jnp.asarray(uf[:8]), data, scale, jnp.asarray(st), k_top=10,
+        num_movies=movies, tile_m=64,
+    )
+    np.testing.assert_array_equal(vals, np.asarray(ev))
+    np.testing.assert_array_equal(ids, np.asarray(ei))
 
 
 def _tiny_model(seed=0):
@@ -1537,3 +1562,80 @@ def test_ordinal_and_read_your_writes_with_a_batch_in_flight(tmp_path):
     seen = [early[rid].ordinal for rid in first + extra + [late_req]]
     assert seen == sorted(seen) and set(seen) == {0, 1}
     assert not s.session.in_flight and s.session.backlog() == 0
+
+
+# -- the names the benchmark finds the programs by --------------------------
+# A device trace names an XLA module after its jitted entry, and the
+# benchmark's readers find the serve and fold-in programs by those names
+# alone: renamed, a per-layer metric reads ``null`` on the ledger and
+# nothing else notices.  Each entry lowered at toy size; the shapes are no
+# other test's, so no other test's trace count sees these.
+
+def _lowered_program(entry):
+    from jax import ShapeDtypeStruct as S
+
+    from cfk_tpu.parallel import spmd
+    from cfk_tpu.parallel.mesh import make_mesh
+    from cfk_tpu.serving import engine as engine_mod
+    from cfk_tpu.serving.topk_kernel import SeenTiles
+    from cfk_tpu.streaming import foldin
+
+    f32, i32 = jnp.float32, jnp.int32
+    b, k, tile, nt, w, k_top = 8, 24, 16, 6, 16, 7
+    m, e = nt * tile, 8
+    seen = SeenTiles(S((nt, b, w), i32), S((nt,), i32))
+    fold = dict(lam=0.0625, solver="cholesky", reg_solve_algo="auto")
+    if entry == "_topk_call":
+        return engine_mod._topk_jit_fn().lower(
+            S((b, k), f32), S((m, k), f32), None, seen, k_top=k_top,
+            num_movies=m - 3, tile_m=tile)
+    if entry == "_seen_tiles_call":
+        return engine_mod._seen_tiles_jit_fn().lower(
+            S((4, 32), i32), None, shape=(nt, b, w), tile_m=tile)
+    if entry == "_topk_shard_call":
+        return spmd._serve_topk_sharded_fn(
+            make_mesh(2), m // 2, False, True, k_top, m - 3, tile,
+        ).lower(S((b, k), f32), S((m, k), f32), seen)
+    if entry == "_seen_tiles_shard_call":
+        return spmd._serve_seen_tiles_sharded_fn(
+            make_mesh(2), (nt, b, w), tile, True).lower(S((4, 32), i32))
+    if entry == "_padded_fold":
+        return foldin._padded_fold.lower(
+            S((m, k), f32), S((e, 8), i32), S((e, 8), f32), S((e, 8), f32),
+            S((e,), f32), np.int32(3), np.float32(1e3), **fold)
+    if entry == "_cells_fold_gram":
+        return foldin._cells_fold_gram.lower(
+            S((m, k), f32), S((64, 2 * foldin.CHUNK + 2), i32),
+            S((e, k, k), f32), S((e, k), f32))
+    assert entry == "_cells_fold_solve"
+    return foldin._cells_fold_solve.lower(
+        S((e, k, k), f32), S((e, k), f32), S((e,), f32), np.int32(3),
+        np.float32(1e3), **fold)
+
+
+@pytest.mark.parametrize("entry", [
+    # benchmarks/layer_metrics/serve_seen_device_ms.py (SCORE_PROGRAM) and
+    # benchmarks/harness/shard_trace.py (SCORER: the Mosaic call's name)
+    "_topk_call",
+    # benchmarks/layer_metrics/serve_seen_device_ms.py (BUILD_PROGRAM)
+    "_seen_tiles_call",
+    # benchmarks/harness/shard_trace.py (SCORE_PROGRAM, and SCORER for the
+    # call inside it, which the named scope names)
+    "_topk_shard_call",
+    # benchmarks/harness/shard_trace.py (BUILD_PROGRAM)
+    "_seen_tiles_shard_call",
+    # benchmarks/layer_metrics/foldin_device_ms.py (PROGRAM) and
+    # benchmarks/harness/foldin_modules.py (FOLD_MODULES)
+    "_padded_fold",
+    # benchmarks/harness/foldin_modules.py (FOLD_MODULES: ``_cells_fold``)
+    "_cells_fold_gram",
+    "_cells_fold_solve",
+])
+def test_programs_carry_the_names_the_benchmark_reads(entry):
+    text = _lowered_program(entry).as_text(debug_info=True)
+    assert re.search(rf"module @jit_{entry}\b", text), text[:200]
+    if entry == "_topk_shard_call":
+        # the scorer inside the shard program runs under a scope of the
+        # same name: its custom call reads ``_topk_shard_call.<n>`` in a
+        # device trace, not ``shard_map.<n>``
+        assert re.search(r'"_topk_shard_call/', text)
