@@ -53,8 +53,35 @@ def _gram_compute_dtype(fixed_factors):
     return jnp.float32, "highest"
 
 
+def gather_rows(fixed, neighbor_idx: jax.Array) -> jax.Array:
+    """Rows ``neighbor_idx`` of the side held fixed.  ``fixed`` is the
+    [F, k] factor array, gathered as it is (a trainer's table: its dtype is
+    the Gram's compute dtype, ``_gram_compute_dtype``), or the pair a
+    serving engine holds its item table as (``ServeEngine.fold_table``):
+    ``(data [F, k], scale [F] float32 or None)``.  A pair's rows come back
+    in float32, whatever it stores: ``data[idx]`` upcast, times the row's
+    scale where there is one (an int8 table's code x scale, one float32
+    rounding: the dequantized view the scorer's epilogue computes).  Codes
+    and scales are gathered by the same indices; no float32 copy of the
+    table, or of a block of it, is made beside the gathered rows."""
+    if not isinstance(fixed, tuple):
+        return fixed[neighbor_idx]
+    data, scale = fixed
+    rows = data[neighbor_idx].astype(jnp.float32)
+    if scale is None:
+        return rows
+    return rows * scale[neighbor_idx][..., None]
+
+
+def table_parts(fixed) -> tuple:
+    """(data [F, k], scale [F] or None) of a fixed side in either form
+    ``gather_rows`` takes: what a caller reads the rank, the stored dtype
+    and a gathered row's bytes from."""
+    return fixed if isinstance(fixed, tuple) else (fixed, None)
+
+
 def gather_gram(
-    fixed_factors: jax.Array,  # [F, k] factors of the side held fixed
+    fixed_factors,  # [F, k] factors of the side held fixed, or (data, scale)
     neighbor_idx: jax.Array,  # [E, P] int32
     rating: jax.Array,  # [E, P] float32 (0 at padding)
     mask: jax.Array,  # [E, P] float32 (1 = real)
@@ -63,9 +90,12 @@ def gather_gram(
 
     Returns (A [E, k, k], b [E, k]).  The gather + einsum pair is what XLA
     tiles onto the MXU; padding rows contribute zero via the mask.
+    ``fixed_factors`` in either form ``gather_rows`` takes: an engine's pair
+    gives float32 rows and so the ``HIGHEST`` contraction, whatever the
+    table stores.
     """
-    ct, prec = _gram_compute_dtype(fixed_factors)
-    gathered = fixed_factors[neighbor_idx]  # [E, P, k]
+    gathered = gather_rows(fixed_factors, neighbor_idx)  # [E, P, k]
+    ct, prec = _gram_compute_dtype(gathered)
     gm = gathered.astype(ct) * mask[..., None].astype(ct)
     a = jnp.einsum(
         "epk,epl->ekl", gm, gm,
@@ -564,7 +594,7 @@ def _solve_chunk(
 
 
 def als_half_step(
-    fixed_factors: jax.Array,  # [F, k]
+    fixed_factors,  # [F, k], or an engine's (data, scale): ``gather_rows``
     neighbor_idx: jax.Array,  # [E, P]
     rating: jax.Array,  # [E, P]
     mask: jax.Array,  # [E, P]
@@ -605,7 +635,7 @@ def als_half_step(
          reshape(count)),
         n_chunks, overlap=overlap,
     )
-    return out.reshape(e + pad, fixed_factors.shape[-1])[:e]
+    return out.reshape(e + pad, table_parts(fixed_factors)[0].shape[-1])[:e]
 
 
 def _ragged_gram_ddn():

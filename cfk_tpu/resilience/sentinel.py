@@ -102,17 +102,25 @@ def probe_word(u: jax.Array, m: jax.Array, norm_limit: float) -> jax.Array:
             | side_word(m, norm_limit, NONFINITE_M, NORM_M))
 
 
-def side_word(x: jax.Array, norm_limit, nonfinite_bit: int, norm_bit: int,
+def side_word(x, norm_limit, nonfinite_bit: int, norm_bit: int,
               rows=None) -> jax.Array:
     """One side of ``probe_word``: the two bits of one factor array, over
     all of its rows or over its first ``rows`` (a traced count: a padded
-    solve's trailing rows are nobody's factors)."""
+    solve's trailing rows are nobody's factors).  ``x`` may be the pair a
+    serving engine holds its table as, ``(data, scale or None)``: the rows
+    probed are ``data x scale``, their norms taken from the codes' own
+    sums (one pass over the table as stored, no float32 copy of it)."""
+    x, scale = x if isinstance(x, tuple) else (x, None)
     xf = x.astype(jnp.float32)
     if rows is not None:
         xf = jnp.where(jnp.arange(xf.shape[0])[:, None] < rows, xf, 0.0)
     limit_sq = jnp.asarray(norm_limit, jnp.float32) ** 2
     finite = jnp.all(jnp.isfinite(xf))
-    norm_sq = jnp.max(jnp.sum(jnp.square(xf), axis=-1))
+    row_sq = jnp.sum(jnp.square(xf), axis=-1)
+    if scale is not None:
+        finite &= jnp.all(jnp.isfinite(scale))
+        row_sq = row_sq * jnp.square(scale)
+    norm_sq = jnp.max(row_sq)
     w = jnp.where(finite, jnp.int32(0), jnp.int32(nonfinite_bit))
     return w | jnp.where(norm_sq > limit_sq, jnp.int32(norm_bit), jnp.int32(0))
 

@@ -350,19 +350,26 @@ class ServeEngine:
         return int(self._table[0].shape[0])
 
     def fold_table(self):
-        """The item table as a fold-in gathers from it: the [M_pad, k]
-        float32 array on the one device, the very buffer the scorer scans
-        (a ``StreamSession(engine=...)`` keeps no copy of its own).  Other
-        tables are refused: a fold-in solves float32 normal equations
-        against whole rows on one device."""
-        if self.mesh is not None or self.table_dtype != "float32":
+        """The item table as a fold-in gathers from it: ``(data, scale)`` as
+        this engine holds it on its one device, the very buffers the scorer
+        scans (a ``StreamSession(engine=...)`` keeps no copy of its own and
+        makes none): [M_pad, k] float32 or bfloat16 rows with ``scale``
+        None, or int8 codes with the [M_pad] float32 scale of each row.
+        The same tuple until the table is swapped.  A fold-in dequantizes
+        the rows it gathers (``ops.solve.gather_rows``) and solves float32
+        normal equations against them: the table a user's row is solved
+        against is the table the user is scored against.  A table
+        row-sharded over a mesh is refused: a fold-in gathers whole rows
+        on one device."""
+        if self.mesh is not None:
             raise ValueError(
-                "a fold-in reads a float32 item table on one device; this "
-                f"engine holds table_dtype={self.table_dtype!r} over "
-                f"{self._shards} device(s): give the session a table of "
-                "its own (no engine=)")
+                "a fold-in gathers whole item rows on one device; this "
+                f"engine's table (table_dtype={self.table_dtype!r}) is "
+                f"row-sharded over {self._shards} devices, and a gather "
+                "that crosses shards is the half of ROADMAP R8 (a) still "
+                "open: give the session a table of its own (no engine=)")
         with self._lock:
-            return self._table[0]
+            return self._table
 
     def user_base(self) -> np.ndarray:
         """The base user table as the engine holds it (by reference, never

@@ -31,6 +31,17 @@ The programs are a fixed set (``CHUNK``, ``SLABS`` and the entity buckets
 bound every shape), whatever the longest list: ``StreamSession.prewarm``
 runs each once and no list can outgrow them.
 
+The fixed movie factors (``movie_factors`` / ``fixed`` below) are one
+[M, k] device array, or the pair a serving engine holds its table as on
+one device (``ServeEngine.fold_table``): ``(data, scale)``, float32 or
+bfloat16 rows with ``scale`` None, int8 codes with a float32 scale a row.
+Both routes gather from it where it lies (``ops.solve.gather_rows``: codes
+and scales by the same indices, dequantized in float32 among the gathered
+rows, code x scale: the view the engine's scorer scores against), so the
+normal equations are float32 and ``HIGHEST`` whatever the table stores and
+nothing the size of the table, or of a block of it, is ever made.  A
+float32 pair lowers to the program the plain array lowers to.
+
 Out-of-core sessions (``offload_tier='host_window'``) stage the batch's
 touched item rows as one window and run the same two routes against it
 (``fold_in_rows_windowed``: the one caller is ``StreamSession._dispatch``,
@@ -62,7 +73,7 @@ import numpy as np
 from jax import lax
 
 from cfk_tpu.ops.solve import (
-    als_half_step, gather_gram, regularized_solve, solve_route)
+    als_half_step, gather_gram, regularized_solve, solve_route, table_parts)
 from cfk_tpu.resilience import sentinel as _sentinel
 from cfk_tpu.telemetry import span
 
@@ -183,7 +194,8 @@ def fold_in_rows(
     solved float32 rows ``[len(neighbor_data), k]`` in the same order.
     """
     if not len(neighbor_data):
-        return np.zeros((0, movie_factors.shape[-1]), np.float32)
+        return np.zeros((0, table_parts(movie_factors)[0].shape[-1]),
+                        np.float32)
     return fold_in_dispatch(
         movie_factors, neighbor_data, lam=lam, solver=solver,
         pad_multiple=pad_multiple, reg_solve_algo=reg_solve_algo,
@@ -273,26 +285,34 @@ class FoldIn:
     ``route`` says which programs ran; ``cells`` is the sum of the touched
     users' lists and ``padded_cells`` what the route's layout really
     gathers and multiplies; ``chunks`` the chunk rows of the cells route (0
-    on the padded one).  ``entities`` x ``width`` is ``_padded_fold``'s
-    rectangle, ``gather_bytes`` the item rows that program gathers for it,
-    ``operand_bytes`` what went up for it: all 0 where it did not run."""
+    on the padded one).  ``gather_bytes`` is what the route's programs
+    gather from the table as it is stored (``table_dtype``): a row of
+    ``rank`` elements a padded cell, and its float32 scale where the table
+    has one.  ``entities`` x ``width`` is ``_padded_fold``'s rectangle and
+    ``operand_bytes`` what went up for it: 0 where it did not run."""
 
-    def __init__(self, out, touched: int, rank: int, *, route: str,
+    def __init__(self, out, touched: int, fixed, *, route: str,
                  cells: int, padded_cells: int, chunks: int = 0,
                  entities: int = 0, width: int = 0,
                  operand_bytes: int = 0) -> None:
+        data, scale = table_parts(fixed)
         self._out = out
-        self.touched, self.rank, self.route = touched, rank, route
+        self.touched, self.route = touched, route
+        self.rank = int(data.shape[-1])
         self.cells, self.padded_cells, self.chunks = cells, padded_cells, chunks
         self.entities, self.width = entities, width
-        self.gather_bytes = entities * width * rank * 4
+        self.table_dtype = str(data.dtype)
+        self.gather_bytes = padded_cells * (
+            self.rank * data.dtype.itemsize + (0 if scale is None else 4))
         self.operand_bytes = operand_bytes
 
     def counts(self) -> dict:
         """What a span says of the fold-in's work."""
         return dict(route=self.route, cells=self.cells,
                     padded_cells=self.padded_cells, chunks=self.chunks,
-                    entities=self.entities, width=self.width)
+                    entities=self.entities, width=self.width,
+                    gather_bytes=self.gather_bytes,
+                    table_dtype=self.table_dtype)
 
     def fetch(self) -> tuple[np.ndarray, int]:
         """(rows [touched, k] float32, the user side's health word)."""
@@ -325,7 +345,6 @@ def fold_in_dispatch(
     route, which a session pins to the most users a micro-batch can touch
     so that the route's programs are one set."""
     t = len(neighbor_data)
-    rank = int(movie_factors.shape[-1])
     static = dict(lam=float(lam), solver=solver, reg_solve_algo=reg_solve_algo)
     if fold_route(neighbor_data) == "padded":
         with span("stream/batch/upload") as sp:
@@ -334,12 +353,12 @@ def fold_in_dispatch(
             e, p = host[0].shape
             nbytes = sum(o.nbytes for o in host)
             sp.set(entities=e, width=p, bytes=nbytes)
-        fold = FoldIn(None, t, rank, route="padded",
+        fold = FoldIn(None, t, movie_factors, route="padded",
                       cells=int(host[3].sum()), padded_cells=e * p,
                       entities=e, width=p, operand_bytes=nbytes)
         with span("stream/batch/solve", touched=t,
-                  gather_bytes=fold.gather_bytes,
-                  solve_route=solve_route(solver, rank), **fold.counts()), \
+                  solve_route=solve_route(solver, fold.rank),
+                  **fold.counts()), \
                 span("stream/batch/solve/dispatch"):
             fold._out = _padded_fold(
                 movie_factors, *operands, np.int32(t),
@@ -353,12 +372,12 @@ def fold_in_dispatch(
         count = jnp.asarray(count)
         chunks = sum(s.shape[0] for s in slabs)
         sp.set(chunks=chunks, slabs=len(slabs), bytes=nbytes)
-    fold = FoldIn(None, t, rank, route="cells", cells=cells,
+    fold = FoldIn(None, t, movie_factors, route="cells", cells=cells,
                   padded_cells=chunks * CHUNK, chunks=chunks)
-    with span("stream/batch/solve", touched=t, gather_bytes=0,
-              solve_route=solve_route(solver, rank), **fold.counts()), \
+    with span("stream/batch/solve", touched=t,
+              solve_route=solve_route(solver, fold.rank), **fold.counts()), \
             span("stream/batch/solve/dispatch"):
-        acc = _zero_systems(e, rank)
+        acc = _zero_systems(e, fold.rank)
         for slab in slabs:
             acc = _cells_fold_gram(movie_factors, slab, *acc)
         fold._out = _cells_fold_solve(

@@ -75,6 +75,7 @@ import zlib
 
 import numpy as np
 
+from cfk_tpu.ops.solve import table_parts
 from cfk_tpu.resilience import sentinel as _sentinel
 from cfk_tpu.resilience.loop import drain_checkpoints, save_checkpoint
 from cfk_tpu.resilience.policy import Overrides, RecoveryPolicy, policy_from_config
@@ -347,10 +348,12 @@ class StreamSession:
 
     ``dataset`` is a ``Dataset`` or a ``StreamState`` (``from_csr``: a
     deployment that holds its ratings as a CSR and could never build
-    blocks of them).  ``engine`` (a ``ServeEngine`` with a float32 table on
-    one device) makes the session fold in against the table the engine
-    serves — one item table on the device, one user base on the host, both
-    owned by the engine and absent from this store's snapshots — and
+    blocks of them).  ``engine`` (a ``ServeEngine`` with its table on one
+    device, float32, bfloat16 or int8 codes and scales) makes the session
+    fold in against the table the engine serves, as the engine holds it
+    (``ServeEngine.fold_table``: gathered rows are dequantized, the solve
+    is float32) — one item table on the device, one user base on the host,
+    both owned by the engine and absent from this store's snapshots — and
     subscribes the engine to the commits.  ``listeners`` are subscribed
     before a resume publishes what the engine lacks.
     """
@@ -422,7 +425,9 @@ class StreamSession:
                     "and the session from the new model")
             if jnp_dtype(config.dtype) != np.float32:
                 raise ValueError(
-                    f"the engine's table is float32, config.dtype is "
+                    "a fold-in solves float32 normal equations, whatever "
+                    "the engine's table stores (its rows are dequantized "
+                    f"as they are gathered): config.dtype is "
                     f"{config.dtype!r}")
             engine.fold_table()  # refuses, in words, a table no fold-in reads
         self._overrides = Overrides(
@@ -629,6 +634,15 @@ class StreamSession:
                 f"stream checkpoint has rank {meta.get('rank')}, config "
                 f"wants {self.config.rank}"
             )
+        solved_on = meta.get("table_dtype", "float32")  # older stores
+        if self._engine is not None and solved_on != self._table_dtype():
+            raise ValueError(
+                f"this store's units were solved against a {solved_on} item "
+                f"table; the engine serves a {self._table_dtype()} one: "
+                "batches replayed onto it would re-solve to other bits "
+                "(streaming/foldin.py's determinism contract): start the "
+                "engine with the table the stream was started on, or give "
+                "the stream a new directory")
         if int(meta.get("base_users", -1)) != self.state.num_base_users:
             raise ValueError(
                 "stream checkpoint was committed against a base dataset "
@@ -767,7 +781,15 @@ class StreamSession:
     @property
     def movie_factors(self):
         if self._engine is not None:
-            return self._engine.fold_table()
+            data, scale = self._engine.fold_table()
+            if scale is not None or data.dtype != np.float32:
+                raise ValueError(
+                    "this session folds in against its engine's table as "
+                    f"the engine holds it ({self._engine.table_dtype}): "
+                    "there is no float32 item table to hand out, and the "
+                    "session makes none (dequantizing it is the caller's "
+                    "choice, from ServeEngine.fold_table())")
+            return data
         if self._offload:
             return self._m_store.as_array()
         return self._m
@@ -776,7 +798,9 @@ class StreamSession:
         """Current live factors as an ``ALSModel`` (serving view).  An
         offload session returns host arrays (materializing the store is
         the caller's choice — the session itself never holds the full
-        movie table on device)."""
+        movie table on device).  An ``ALSModel`` is float32: on an engine
+        whose table is quantized this refuses in words
+        (``movie_factors``) and dequantizes nothing."""
         import jax.numpy as jnp
 
         from cfk_tpu.models.als import ALSModel
@@ -805,8 +829,10 @@ class StreamSession:
         return bool(self._in_flight or self._unpublished)
 
     def _fixed(self):
-        """The item table a fold-in gathers from."""
-        fixed = self.movie_factors
+        """The item table a fold-in gathers from: the session's own array,
+        or its engine's table as the engine holds it, ``(data, scale)``."""
+        fixed = (self._engine.fold_table() if self._engine is not None
+                 else self.movie_factors)
         if fixed is None:
             raise ValueError(
                 "this session was resumed without the engine that owns its "
@@ -970,7 +996,7 @@ class StreamSession:
         before = trace_count()
         programs = 0
         fixed = self._fixed()
-        num_m = int(fixed.shape[0])
+        num_m = int(table_parts(fixed)[0].shape[0])
         if self.health is not None:
             self._table_word(fixed)  # the fixed side's probe, once a table
 
@@ -1023,6 +1049,9 @@ class StreamSession:
             "seq_high": int(self.state.applied_seq_high),
             "base_users": self.state.num_base_users,
             "users": self.state.num_users,
+            # what the fixed side stores: rows solved against another
+            # table's rounding are other bits (``_try_resume`` refuses)
+            "table_dtype": self._table_dtype(),
             # poison ranges whose offsets are consumed but whose writes
             # must never be re-applied — crash replay skips them
             "quarantined": list(self.quarantined),
@@ -1039,6 +1068,11 @@ class StreamSession:
         if note:
             meta["note"] = note
         return meta
+
+    def _table_dtype(self) -> str:
+        """The dtype the fold-in's fixed side is stored in."""
+        return str(self._engine.table_dtype if self._engine is not None
+                   else jnp_dtype(self.config.dtype))
 
     def _save(self, users, movies, meta: dict, note: str | None, *,
               arrays: dict, wait: bool = False) -> int:
@@ -1487,8 +1521,8 @@ class StreamSession:
                touched=len(rows), new_users=summary["new_users"],
                commit_bytes=commit_bytes)
         if fold is not None:
-            sp.set(rank=fold.rank, gather_bytes=fold.gather_bytes,
-                   operand_bytes=fold.operand_bytes, **fold.counts())
+            sp.set(rank=fold.rank, operand_bytes=fold.operand_bytes,
+                   **fold.counts())
         summary["stream_step"] = self.stream_step
         if (self.stream.retrain_every is not None
                 and self.stream_step % self.stream.retrain_every == 0):

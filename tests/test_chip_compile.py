@@ -488,6 +488,66 @@ def test_foldin_cells_route_amazon14_skew_cell_shape(chip, as_tpu, slab):
     assert "Cholesky" not in text and "triangular-solve" not in text.lower()
 
 
+@pytest.mark.parametrize("program", ["rectangle-256x128", "rectangle-8x8",
+                                     "slab-4096", "slab-64", "probe"])
+def test_foldin_amazon23_int8_stream_cell_shape(chip, as_tpu, program):
+    """The fold-in of ``amazon23-stream-r128-int8.serve-foldin-skew-int8``
+    against the table as the engine holds it, 48,190,464 int8 codes and a
+    float32 scale a row (ISSUE 47): both ends of the rectangle's grid, the
+    largest and the smallest slab of the cells route, and the sentinel's
+    probe of the fixed side.  The codes and the scales are gathered where
+    they lie, by the same indices, and dequantized among the gathered rows:
+    nothing the size of the table, or of a float32 block of it, is made (a
+    relayout of the codes would be a 6.17 GB temporary).  Prints the gather
+    ops the chip's compiler makes (``-s`` shows them)."""
+    import re
+    import time
+
+    from cfk_tpu.streaming import foldin, session
+
+    m, k, e = 48_190_464, 128, 256
+    fixed = (chip((m, k), i8), chip((m,), f32))
+    t0 = time.perf_counter()
+    if program == "probe":
+        compiled = session._side_word_fn().lower(
+            fixed, chip((), f32)).compile()
+        # one float32 a row, the rows' squared norms: 193 MB, once a table
+        name, temp = "side_word", 4 * m + (1 << 20)
+    elif program.startswith("rectangle"):
+        touched, width = map(int, program.split("-")[1].split("x"))
+        rect = lambda dt: chip((touched, width), dt)
+        compiled = foldin._padded_fold.lower(
+            fixed, rect(jnp.int32), rect(f32), rect(f32),
+            chip((touched,), f32), chip((), jnp.int32), chip((), f32),
+            lam=LAM, solver="cholesky", reg_solve_algo=None).compile()
+        name, temp = "jit__padded_fold", 1 << 28
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        slab = int(program.split("-")[1])
+        compiled = foldin._cells_fold_gram.lower(
+            fixed, chip((slab, 2 * foldin.CHUNK + 2), i32),
+            chip((e, k, k), f32), chip((e, k), f32)).compile()
+        # the slab's codes (67 MB), their float32 rows and the chunk Grams
+        name, temp = "jit__cells_fold_gram", 1 << 30
+    assert time.perf_counter() - t0 < 20.0
+    text = compiled.as_text()
+    assert name in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp
+    # the table goes in as it is held and comes out nowhere
+    assert m * (k + 4) <= mem.argument_size_in_bytes < m * (k + 4) + (1 << 28)
+    if program != "probe":  # (its convert is fused into the reduction)
+        assert f"f32[{m},{k}]" not in text
+    gathers = [ln.strip() for ln in text.splitlines()
+               if re.search(r"= \S+ gather\(", ln)]
+    print(f"\n{program}: temp {mem.temp_size_in_bytes:,} B; gathers:")
+    for ln in gathers:
+        print("   ", ln[:160])
+    if program != "probe":
+        assert any(ln.split("= ")[1].startswith("s8[") for ln in gathers)
+        assert any(ln.split("= ")[1].startswith("f32[") for ln in gathers)
+
+
 @pytest.mark.parametrize("k,e", [(128, 2561), (64, 333), (8, 8)])
 def test_cholesky_lanes(chip, k, e):
     """The lane-batched Cholesky alone, at the widths the half-steps name
