@@ -93,7 +93,7 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
                          tile_m, row_offset=0):
     """XLA twin of ``serving.topk_kernel.topk_scores_counted``: (scores,
     movie rows, [selection rounds, tiles that ran one, exclusion chunks,
-    tiles that ran one]).
+    tiles that ran one, tiles completed]).
 
     Scans the SAME per-tile fold the kernel body runs
     (``serving.topk_kernel._score_tile_fold`` — one shared function, the
@@ -103,7 +103,10 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     fold's branch with or without the masks by the same per-tile scalar
     (``SeenTiles.hits``) as the kernel does — so kernel and twin are
     BIT-IDENTICAL on this route, counts included (``tests/test_serving.py``
-    pins it).  Crucially the scan's
+    pins it).  Where the fold defers passes (an int8 table) the tiles it
+    completes depend on the K-th scores its first gate reads, and the
+    kernel reads them once a group of tiles: the scan carries that row and
+    renews it at the same tiles.  Crucially the scan's
     per-step block is [B, tile_m]: no [B, num_movies] score matrix is ever
     materialized here either (the emulation-path memory check in the tests
     compiles this and bounds its temp memory below B·M·4 bytes).
@@ -131,11 +134,17 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         # row; the hits are the kernel's second scalar-prefetch operand
         seen, hits = jnp.swapaxes(seen_tiles.slots, 1, 2), seen_tiles.hits
         width = seen.shape[1]
+    # the tiles the kernel scores ahead of their gates (its P): a deferring
+    # fold's first gate reads the K-th scores as of the group's start
+    group = min(topk_kernel._GROUP_TILES, topk_kernel.slab_tiles(
+        nt, b, width, table.shape[1], table.dtype, tile_m=tile_m,
+        k_top=k_top))
     carry0 = jax.tree.map(
         lambda z: match_varying(z, table),
         (jnp.full((k_top, b), -jnp.inf, jnp.float32),
          jnp.full((k_top, b), -1, jnp.int32),
-         jnp.zeros(4, jnp.int32)),
+         jnp.zeros(topk_kernel.NUM_COUNTS, jnp.int32),
+         jnp.full((1, b), -jnp.inf, jnp.float32)),
     )
 
     off = jnp.asarray(row_offset, jnp.int32)
@@ -143,18 +152,20 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     def step(carry, i):
         idx = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
         seen_i = None if seen is None else idx(seen)
+        gate_kth = jnp.where(i % group == 0, carry[0][k_top - 1:], carry[3])
         v, ids, counts = topk_kernel._score_tile_fold(
-            lambda: (carry[0], carry[1], u, idx(tbl),
-                     None if sc is None else idx(sc)[:, None]),
+            carry[0], carry[1], u, idx(tbl),
+            None if sc is None else idx(sc)[:, None],
             None if seen is None else (
                 lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
             ),
             width, None if seen is None else idx(hits), off + i * tile_m,
             tile_m=tile_m, num_movies=num_movies, k_top=k_top,
+            gate_kth=gate_kth,
         )
-        return (v, ids, carry[2] + jnp.stack(counts)), None
+        return (v, ids, carry[2] + jnp.stack(counts), gate_kth), None
 
-    (vals, ids, counts), _ = lax.scan(
+    (vals, ids, counts, _), _ = lax.scan(
         step, carry0, jnp.arange(nt, dtype=jnp.int32))
     return vals.T, ids.T, counts
 

@@ -1512,9 +1512,9 @@ def serve_topk_sharded(
     tile_m: int = 512,
 ):
     """Item-axis sharded score+top-K: (scores [B, K], movie rows [B, K],
-    counts [shards, 4] — each shard's selection rounds and exclusion chunks
-    and the tiles that ran any of either, ``topk_scores_counted``'s, left
-    on their chips unsummed).
+    counts [shards, 5] — each shard's selection rounds and exclusion chunks,
+    the tiles that ran any of either and the tiles it completed,
+    ``topk_scores_counted``'s, left on their chips unsummed).
 
     The serving analog of the half-steps' exchange, with the direction
     reversed: the ITEM table is row-sharded over the mesh, the [B, k]

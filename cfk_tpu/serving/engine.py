@@ -58,6 +58,7 @@ import threading
 import numpy as np
 
 from cfk_tpu.serving.topk_kernel import (
+    NUM_COUNTS,
     SEEN_PIECE_RUNGS,
     _pow2_ceil,
     chunk_seen_cells,
@@ -803,15 +804,17 @@ class TopKBatch:
         (vals, ids, counts), self._out = self._out, None
         with span("serve/batch/compute/fetch") as fetch:
             vals, ids = np.asarray(vals), np.asarray(ids)
-            # a [4] row a shard: the host adds them, no collective
-            counts = np.asarray(counts).reshape(-1, 4).sum(axis=0)
+            # a row a shard: the host adds them, no collective
+            counts = np.asarray(counts).reshape(-1, NUM_COUNTS).sum(axis=0)
             fetch.set(bytes=vals.nbytes + ids.nbytes)
         # what the data made this batch cost, over every tile scanned
         # (all shards'): selection rounds run and tiles that ran any,
-        # exclusion chunks run and tiles that ran any
+        # exclusion chunks run and tiles that ran any, tiles on which
+        # every one of ``score_passes`` ran (an int8 tile behind a shut
+        # first gate runs one, and no mask)
         sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
                seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
-               **self.counters)
+               completed_tiles=int(counts[4]), **self.counters)
         self.result = vals[:self.n], ids[:self.n]
         return self.result
 

@@ -37,7 +37,17 @@ along sublanes, the forms Mosaic lowers:
   product exact in the float32 accumulator, and one float32 multiply of
   the [T, B] block by the row's scale — float32 arithmetic on the
   dequantized view at half the six passes a float32 tile takes at
-  ``Precision.HIGHEST`` (PERF.md section 6, PR 35).  The scales reach the
+  ``Precision.HIGHEST`` (PERF.md section 6, PR 35).  **Only pass 0 runs
+  on every int8 tile.**  What passes 1 and 2 can add to a row is bounded
+  by the codes' range and the two low pieces' 1-norms (a [1, B] row made
+  once a call, times the tile's largest scale: ``pass_slack``,
+  ``_bound_max``, where the proof is), so pass 0's maximum plus that
+  bound decides a first gate that is open wherever the exact gate would
+  be; nine tiles in ten of a serve cell's hold no row that can enter any
+  user's top-K, and they cost one pass, no mask and no round.  A tile
+  whose first gate opens is completed, (P2 + P1) + P0 times the scale, to
+  the bit of three passes run together, and folded like any other
+  (``deferred_passes``; PERF.md section 6, PR 48).  The scales reach the
   kernel lane-dense, one [1, T] row a tile of a [NT, 1, T] view of the
   [M_pad] vector (a bitcast), and are turned to columns in register: a
   [M_pad, 1] operand is padded to 128 lanes a row in HBM, 4.8 GB copied
@@ -73,9 +83,10 @@ along sublanes, the forms Mosaic lowers:
   (``lax.top_k`` has no Mosaic lowering; neither has ``dynamic_slice`` on
   values).  A stream in no particular order changes a user's top-K about
   K/i times in tile i, so most tiles cost the gating pass and no round;
-  the kernel counts the rounds it ran and the tiles that ran any, and
-  likewise the exclusion chunks (``topk_scores_counted``;
-  ``ServeEngine.topk`` puts them on its compute span).
+  the kernel counts the rounds it ran and the tiles that ran any,
+  likewise the exclusion chunks, and the tiles it completed
+  (``topk_scores_counted``; ``ServeEngine.topk`` puts them on its compute
+  span).
 
 The merge step (``_score_tile_fold``) is ONE function shared by the Mosaic
 kernel body and the XLA twin (``compat.emulate_topk_counted`` scans it over
@@ -84,7 +95,7 @@ counts included — the same twin discipline as the Gram kernels.  The
 kernel compiles for the v5e at f32/bf16/int8 and under the 2×2 shard_map
 (``tests/test_chip_compile.py``) and matches the twin on the chip
 (``tests/test_pallas_tpu.py``); what it costs there is in PERF.md
-(sections 5 and 6, PRs 27, 31, 35 and 38; section 7, row 17).
+(sections 5 and 6, PRs 27, 31, 35, 38 and 48; section 7, row 17).
 """
 
 from __future__ import annotations
@@ -122,6 +133,9 @@ _VMEM_CAP = 110 << 20
 # Tiles of a slab scored in straight-line code ahead of their gates (a
 # rung of the ladder: it divides every G at or above it).
 _GROUP_TILES = 8
+# What a call counts (``_tile_counts``): selection rounds and tiles that ran
+# any, exclusion chunks and tiles that ran any, tiles completed.
+NUM_COUNTS = 5
 
 
 def _pow2_ceil(x: int, floor: int = 1) -> int:
@@ -141,26 +155,45 @@ def serve_compute_dtype(table_dtype):
     batch: half at bf16, a quarter plus 4 B a row at int8.  At
     ``Precision.HIGHEST`` the scorer is bound by the MXU's passes, six over
     a float32 tile; an int8 code is exact in bfloat16, so an int8 tile
-    needs three (``score_passes``, ``split_bf16x3``; PERF.md sections 5
-    and 6 hold what each costs on the chip).  The two control tests patch
-    this function to ``(bfloat16, None)`` for int8: the fold then runs the
-    arithmetic under the stated one, the dequantized tile and ``u`` each
-    rounded to bfloat16, in one pass."""
+    needs three (``score_passes``, ``split_bf16x3``), and costs ONE unless
+    the first of them cannot rule the tile out (``deferred_passes``,
+    ``_bound_max``): the answer is the three-pass one on every input
+    (PERF.md sections 5 and 6 hold what each costs on the chip).  The two
+    control tests patch this function to ``(bfloat16, None)`` for int8: the
+    fold then runs the arithmetic under the stated one, the dequantized
+    tile and ``u`` each rounded to bfloat16, in one pass on every tile,
+    with nothing deferred."""
     if table_dtype == jnp.bfloat16:
         return jnp.bfloat16, None
     return jnp.float32, lax.Precision.HIGHEST
 
 
 def score_passes(table_dtype) -> int:
-    """bfloat16 MXU passes the fold runs over one tile of such a table: 1
-    where the compute dtype is bfloat16, else the pieces of a float32
-    operand pair that can be non-zero — six of a float32 tile's nine at
-    ``Precision.HIGHEST``, three for an int8 tile, whose codes have one
-    piece.  What ``ServeEngine`` puts on ``serve/batch/compute``."""
+    """bfloat16 MXU passes the fold runs over one COMPLETED tile of such a
+    table: 1 where the compute dtype is bfloat16, else the pieces of a
+    float32 operand pair that can be non-zero — six of a float32 tile's
+    nine at ``Precision.HIGHEST``, three for an int8 tile, whose codes
+    have one piece.  A float32 or bfloat16 tile is always completed; an
+    int8 tile runs the first of its three on every tile and the other two
+    (``deferred_passes``) only where its first gate opens, so a call costs
+    ``tiles + 2 x completed_tiles`` passes.  What ``ServeEngine`` puts on
+    ``serve/batch/compute``, beside the count of completed tiles."""
     ct, _ = serve_compute_dtype(table_dtype)
     if ct == jnp.bfloat16:
         return 1
     return 3 if table_dtype == jnp.int8 else 6
+
+
+def deferred_passes(table_dtype) -> tuple[int, ...]:
+    """Which of a tile's ``score_passes`` wait behind its first gate: the
+    pieces of ``u`` whose pass runs only on a tile that pass 0 could not
+    rule out (``_bound_max``).  Passes 1 and 2 of an int8 tile scored in
+    float32 arithmetic; none of a float32 or bfloat16 tile's, whose one
+    ``dot_general`` cannot be split, and none under the controls' one-pass
+    bfloat16 arithmetic, which has no second pass to defer.  Read at trace
+    time from the table's dtype: no knob."""
+    ct, _ = serve_compute_dtype(table_dtype)
+    return (1, 2) if table_dtype == jnp.int8 and ct == jnp.float32 else ()
 
 
 def split_bf16x3(u):
@@ -179,11 +212,35 @@ def split_bf16x3(u):
     return jnp.stack(pieces)
 
 
+# What an int8 code's magnitude cannot pass (−128 is a code too, though
+# ``ops.quant`` writes none).
+_CODE_MAX = 128.0
+
+
+def pass_slack(pieces):
+    """The [1, B] float32 row L that ``_bound_max`` adds, times a tile's
+    largest scale, to the tile's pass-0 maximum: per user, ``_CODE_MAX`` x
+    (1 + rho) x (||u1||_1 + ||u2||_1 + 2^-20 ||u0||_1) over the three
+    bfloat16 ``pieces`` [3, B, k] of ``u`` as the fold reads them, rho =
+    (2 k + 32) 2^-24.  Made once a call."""
+    k = pieces.shape[-1]
+    n0, n1, n2 = jnp.sum(jnp.abs(pieces.astype(jnp.float32)), axis=-1)
+    rho = (2 * k + 32) * 2.0 ** -24
+    return (_CODE_MAX * (1 + rho) * ((n1 + n2) + 2.0 ** -20 * n0))[None, :]
+
+
 def resident_operand(u, table_dtype):
     """What the fold reads as ``u`` for a table of this dtype, made once a
-    call: the [B, k] batch itself, or for int8 codes its three bfloat16
-    pieces (``split_bf16x3``), [3, B, k]."""
-    return split_bf16x3(u) if table_dtype == jnp.int8 else u
+    call: the [B, k] batch itself; for int8 codes its three bfloat16
+    pieces (``split_bf16x3``), [3, B, k]; and where passes are deferred
+    (``deferred_passes``) the pieces with the first gate's slack row,
+    ``(pieces, pass_slack(pieces))``."""
+    if table_dtype != jnp.int8:
+        return u
+    pieces = split_bf16x3(u)
+    if deferred_passes(table_dtype):
+        return pieces, pass_slack(pieces)
+    return pieces
 
 
 def _times_row_scale(x, scale):
@@ -198,32 +255,82 @@ def _times_row_scale(x, scale):
         axis=1)
 
 
+_DOT_DIMS = (((1,), (1,)), ((), ()))
+
+
+def _code_pass(pieces, codes, piece):
+    """One bfloat16 MXU pass: the raw [T, B] float32 sums of ``codes``
+    [T, k] (bfloat16: an int8 code is exact there) against piece ``piece``
+    of ``u``.  Every product is exact in the float32 accumulator."""
+    return jax.lax.dot_general(
+        codes, pieces[piece], dimension_numbers=_DOT_DIMS,
+        preferred_element_type=jnp.float32)
+
+
+def _complete_scores(p0, pieces, tile, scale):
+    """The exact [T, B] block of an int8 tile from pass 0's raw sums
+    ``p0``: the two deferred passes, the three added low to high, (P2 +
+    P1) + P0, then the block times the row's scale."""
+    codes = tile.astype(jnp.bfloat16)
+    return _times_row_scale(
+        (_code_pass(pieces, codes, 2) + _code_pass(pieces, codes, 1)) + p0,
+        scale)
+
+
+def _bound_max(p0, scale, factor, slack):
+    """[1, B]: per user a number no smaller than the maximum of the exact
+    block ``_complete_scores(p0, ...)`` would hold, from pass 0 alone —
+    the maximum of ``p0`` times the row's scale, plus ``factor`` [1, 1]
+    (the largest |scale| among the tile's rows) times the call's slack
+    row (``pass_slack``).
+
+    Why it bounds.  Model: round to nearest, eps = 2^-24, |fl(x) − x| <=
+    eps |x|; finite operands; no result in the subnormal range (the chip
+    flushes those: scores under 2^-100 are outside the argument).  For row
+    j and user b let c be the row's codes, |c_i| <= 128, s its scale, and
+    u = u0 + u1 + u2 the pieces.  The MXU's sum for piece p adds exact
+    products in float32 in some order: |Pp| <= 128 g ||up||_1 with g = (1
+    + eps)^(k−1), whatever the order.  ``_complete_scores`` is E = fl(fl(fl(P2
+    + P1) + P0) s), the exact block; this function's is S = fl(P0 s).
+      D = fl(P2 + P1):  |D| <= (1 + eps)(|P1| + |P2|)
+                            <= 128 g (1 + eps)(||u1||_1 + ||u2||_1)
+      A = fl(D + P0):   |A − P0| <= |D| + eps |D + P0|
+                                 <= (1 + eps)|D| + eps |P0|
+      |E − S| <= |s| |A − P0| + eps |s| (|A| + |P0|)
+              <= |s| ((1 + eps)|A − P0| + 2 eps |P0|)
+              <= |s| ((1 + eps)^2 |D| + (3 eps + eps^2)|P0|)
+              <= |s| 128 g ((1 + eps)^3 (||u1||_1 + ||u2||_1)
+                            + 4 eps ||u0||_1)  =: |s| Lam_b,
+    for a scale of either sign (rounding is odd), so over the tile's rows
+    max_j E_j <= max_j S_j + f Lam_b, f = max_j |s_j|.  A maximum is exact.
+    What is computed here is G = fl(M + fl(f L)) with M = max_j S_j, |M|
+    <= f 128 g (1 + eps) ||u0||_1:
+      G >= M + f L (1 − eps)^2 − eps f 128 g (1 + eps) ||u0||_1,
+    which is >= M + f Lam_b once L >= 128 g ((1 + eps)^3 (||u1||_1 +
+    ||u2||_1) + (5 eps + eps^2) ||u0||_1) / (1 − eps)^2.  ``pass_slack``'s
+    L is 128 (1 + rho)(n1 + n2 + 16 eps n0) with the norms n summed in
+    float32 (each >= (1 − eps)^(k−1) of the true one) and four roundings
+    of its own: (1 + rho) with rho = (2 k + 32) eps covers g (1 − eps)^-(k−1)
+    <= 1 + 2 k eps and the dozen single roundings (second-order terms k^2
+    eps^2 stay under them for every rank below 2^14).  So G
+    >= max_j E_j, and with the masks (which only lower a maximum) and a
+    stale K-th score (which only rises): **where some user's exact,
+    masked maximum is strictly above its K-th score, so is its G** — the
+    gate on G may open where the exact gate is shut, never the other way.
+    On the benchmark's tables f L is ~2e-3 against K-th scores near 0.6."""
+    return _tile_max(_times_row_scale(p0, scale)) + factor * slack
+
+
 def _tile_scores(u, tile, scale):
-    """The [T, B] float32 score block of one tile, movie-major: ``tile``
-    [T, k] (f32 / bf16 / int8) against ``u`` [B, k] — for an int8 tile its
-    three bfloat16 pieces [3, B, k] (``resident_operand``) and ``scale``
-    f32 [T, 1] or [T, L] with the row's scale in every column
-    (``_times_row_scale``)."""
+    """The [T, B] float32 score block of one tile whose passes are not
+    deferred, movie-major: ``tile`` [T, k] (f32 / bf16) against ``u``
+    [B, k] in one ``dot_general``.  An int8 tile comes here only under the
+    controls' one-pass arithmetic (``serve_compute_dtype`` patched): the
+    dequantized tile and piece 0 of ``u`` [3, B, k], each rounded to the
+    compute dtype; ``scale`` f32 [T, 1] or [T, L] with the row's scale in
+    every column (``_times_row_scale``)."""
     ct, prec = serve_compute_dtype(tile.dtype)
-    dims = (((1,), (1,)), ((), ()))
-    if tile.dtype == jnp.int8 and ct == jnp.float32:
-        # A code is exact in bfloat16 and the row's scale comes out of
-        # the sum: three bfloat16 passes against the pieces of u, every
-        # product exact in the float32 accumulator, added low to high,
-        # then the [T, B] block times the scale.  Float32 arithmetic
-        # on the dequantized view, at half HIGHEST's six passes.
-        codes = tile.astype(jnp.bfloat16)
-
-        def one_pass(piece):
-            return jax.lax.dot_general(
-                codes, u[piece], dimension_numbers=dims,
-                preferred_element_type=jnp.float32)
-
-        return _times_row_scale(
-            (one_pass(2) + one_pass(1)) + one_pass(0), scale)  # [T, B]
     if tile.dtype == jnp.int8:
-        # the controls' one pass (``serve_compute_dtype`` patched):
-        # the dequantized tile and u, each rounded to ``ct``
         tile_f = _times_row_scale(tile.astype(jnp.float32),
                                   scale).astype(ct)
         u = u[0]
@@ -231,7 +338,7 @@ def _tile_scores(u, tile, scale):
         tile_f = tile.astype(ct)
     return jax.lax.dot_general(
         tile_f, u.astype(ct),
-        dimension_numbers=dims,
+        dimension_numbers=_DOT_DIMS,
         preferred_element_type=jnp.float32,
         precision=prec,
     )  # [T, B]
@@ -297,25 +404,30 @@ def _select_round(sc, cv, ci, ms, tile_base):
     return sc, cv, ci, _tile_max(sc)
 
 
-def _tile_counts(rounds, hit, seen_width):
-    """What one tile adds to the four counts: the selection rounds it ran
+def _tile_counts(rounds, hit, seen_width, completed):
+    """What one tile adds to the five counts: the selection rounds it ran
     and whether it ran any, the exclusion chunks of ``_SEEN_CHUNK`` slots
-    it ran (its whole width, or none) and whether it ran any."""
+    it ran (its whole width, or none) and whether it ran any (``hit``: 0
+    for a tile whose masks did not run), and whether every one of its
+    ``score_passes`` ran (``completed``)."""
     return (rounds, (rounds > 0).astype(jnp.int32),
-            hit * (seen_width // _SEEN_CHUNK), hit)
+            hit * (seen_width // _SEEN_CHUNK), hit, completed)
 
 
-def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
-                     tile_m, num_movies, k_top):
+def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
+                     seen_hit, tile_base, *, tile_m, num_movies, k_top,
+                     gate_kth):
     """Fold one movie tile into the running top-K carry: ``(carry_v,
-    carry_i, counts)``, ``counts`` four int32 scalars — the selection
+    carry_i, counts)``, ``counts`` five int32 scalars — the selection
     rounds this tile needed (0 for most tiles) and whether it ran any, the
     exclusion chunks of ``_SEEN_CHUNK`` slots it ran (its whole width, or
-    none) and whether it ran any: what ``topk_scores_counted`` adds up.
+    none) and whether it ran any, and whether the tile was completed
+    (every pass run): what ``topk_scores_counted`` adds up.
 
     The per-tile math as one function of one tile: what the XLA twin scans
     (``compat.emulate_topk_counted``).  The Mosaic kernel body runs the same
-    pieces (``_tile_scores``, ``_mask_scores``, ``_entrant``,
+    pieces (``_tile_scores``, ``_code_pass``, ``_bound_max``,
+    ``_complete_scores``, ``_mask_scores``, ``_entrant``,
     ``_select_round``, ``_tile_counts``) on the same tiles in the same
     order, the score blocks of a few tiles ahead of their gates
     (``_topk_kernel``).  Everything is MOVIE-MAJOR ([T, B] scores,
@@ -325,11 +437,11 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     reduction this selection needs that Mosaic lowers (``lax.top_k``,
     ``dynamic_slice`` on values and lane-offset slices do not).
 
-    ``read()`` → (carry_v [K, B] f32, carry_i [K, B] int32 (−1 empty),
-    u [B, k] — for an int8 tile its three bfloat16 pieces [3, B, k]
-    (``resident_operand``) — tile [T, k] (f32/bf16/int8), scale f32 [T, 1]
-    or [T, L] with the row's scale in every column (``_times_row_scale``),
-    or None),
+    ``carry_v`` [K, B] f32, ``carry_i`` [K, B] int32 (−1 empty), ``u`` as
+    ``resident_operand`` makes it — [B, k]; for an int8 tile the three
+    bfloat16 pieces [3, B, k] with the slack row, ``(pieces, slack)`` —
+    ``tile`` [T, k] (f32/bf16/int8), ``scale`` f32 [T, 1] or [T, L] with
+    the row's scale in every column (``_times_row_scale``), or None,
     ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j < the
     static ``seen_width`` (T = padding), ``seen_hit`` scalar int32 =
     whether any of this tile's slots holds a cell (``SeenTiles.hits``) —
@@ -341,6 +453,22 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     tile — nine in ten of a serve cell's — runs neither, and loses
     nothing: its slot rows are all T, which no row equals, and none of its
     rows is padding.
+
+    **An int8 tile** (``deferred_passes``) runs pass 0 alone, and
+    everything else only behind its first gate: some user's
+    ``_bound_max`` — pass 0's maximum plus a proven bound of what passes
+    1 and 2 can add — strictly above ``gate_kth`` [1, B], the K-th scores
+    the gate reads: the kernel reads them as of the tile's group's start,
+    and the twin hands the same row in (no fold without deferred passes
+    reads it).  Where
+    that gate is shut no row of the tile can enter any top-K
+    (``_bound_max``'s argument) and the tile costs nothing more: no
+    passes 1 and 2, no masks (a mask only lowers a maximum), no rounds.
+    Where it is open the block is completed to the bit of three passes
+    on every tile (``_complete_scores``), masked if the tile is hit or
+    the table's last, and folded as any other: the result and the
+    selection's counts do not depend on the gate, the exclusion counts
+    say what ran.
 
     The carry is SORTED: scores descending, equal scores by ascending
     global row, empty slots (−inf / −1) at the tail — so its last row is
@@ -359,13 +487,12 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
     """
     t = tile_m
     hit = jnp.int32(0) if seen_row is None else seen_hit
+    # a tile without a cell that ends inside the table needs no mask;
+    # without exclusion the padding compare is not worth a branch
+    needs_mask = (True if seen_row is None
+                  else (hit > 0) | (tile_base + t > num_movies))
 
-    def fold(masked):
-        carry_v, carry_i, u, tile, scale = read()
-        scores = _tile_scores(u, tile, scale)
-        if masked:
-            scores = _mask_scores(scores, seen_row, seen_width, tile_base,
-                                  num_movies)
+    def rounds_on(scores, carry_v, carry_i):
         # Under shard_map's own tracing (the twin's sharded route) the
         # loop state varies over the mesh like the tile's scores do.
         rounds = match_varying(jnp.int32(0), scores)
@@ -375,14 +502,46 @@ def _score_tile_fold(read, seen_row, seen_width, seen_hit, tile_base, *,
             (scores, carry_v, carry_i, _tile_max(scores), rounds))
         return carry_v, carry_i, rounds
 
-    if seen_row is None:
-        # nothing to skip but the padding compare: not worth a branch
-        carry_v, carry_i, rounds = fold(True)
+    def masked_scores(scores):
+        return _mask_scores(scores, seen_row, seen_width, tile_base,
+                            num_movies)
+
+    def fold(masked):
+        scores = _tile_scores(u, tile, scale)
+        return rounds_on(masked_scores(scores) if masked else scores,
+                         carry_v, carry_i)
+
+    def fold_deferred():
+        pieces, slack = u
+        p0 = _code_pass(pieces, tile.astype(jnp.bfloat16), 0)
+        factor = jnp.max(jnp.abs(scale), axis=0, keepdims=True)[:, :1]
+        opened = _entrant(_bound_max(p0, scale, factor, slack), gate_kth)
+
+        def turn():
+            scores = _complete_scores(p0, pieces, tile, scale)
+            if needs_mask is True:
+                scores = masked_scores(scores)
+            else:
+                scores = lax.cond(needs_mask, masked_scores, lambda sc: sc,
+                                  scores)
+            return rounds_on(scores, carry_v, carry_i)
+
+        cv, ci, rounds = lax.cond(
+            opened, turn,
+            lambda: (carry_v, carry_i, match_varying(jnp.int32(0), p0)))
+        opened = opened.astype(jnp.int32)
+        return cv, ci, rounds, hit * opened, opened
+
+    if deferred_passes(tile.dtype):
+        carry_v, carry_i, rounds, hit, completed = fold_deferred()
     else:
-        carry_v, carry_i, rounds = lax.cond(
-            (hit > 0) | (tile_base + t > num_movies),
-            lambda: fold(True), lambda: fold(False))
-    return carry_v, carry_i, _tile_counts(rounds, hit, seen_width)
+        completed = jnp.int32(1)
+        if needs_mask is True:
+            carry_v, carry_i, rounds = fold(True)
+        else:
+            carry_v, carry_i, rounds = lax.cond(
+                needs_mask, lambda: fold(True), lambda: fold(False))
+    return carry_v, carry_i, _tile_counts(rounds, hit, seen_width, completed)
 
 
 def group_seen_cells(seen_movies, seen_indptr, batch_rows, *, num_movies,
@@ -572,7 +731,7 @@ def _vmem_bytes(g, batch, seen_width, rank, table_dtype, *, tile_m, k_top):
 
 
 def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
-                 with_seen, with_scale):
+                 with_seen, with_scale, defers):
     """Grid step i: fold the slab of movie tiles [i·G, min((i+1)·G, NT))
     into the resident [K, B] carry, a group of P tiles at a time.
 
@@ -599,6 +758,18 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     clipped at the table's end and the tiles past NT are scored (whatever
     the buffers hold) and never folded, masked or counted.
 
+    Where passes are deferred (``defers``: an int8 table, whose ``u`` is
+    the three pieces and whose slack row ``slack_ref`` [1, B] follows it)
+    the straight-line block runs pass 0 alone: ``sc_ref`` takes its raw
+    sums, and the first gate is read from ``_bound_max``, which may open
+    where the exact gate is shut and never the other way by the argument
+    written there.  A tile whose first gate is open is completed in its
+    turn from the slab still in VMEM (``_complete_scores``: passes 2 and 1
+    and the sums of pass 0 in scratch, to the bit of three passes run
+    together), masked if it is hit or the table's last, and given its
+    rounds; one whose gate is shut gets nothing more, no pass, no mask, no
+    round.  The completion is traced once a program, inside the turn.
+
     The carry is two VMEM scratch blocks: step 0 initializes them, every
     tile merges into them, the last step copies the final state to the
     (constant-index, resident) output blocks.  ``off_ref`` (scalar-
@@ -607,15 +778,18 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     global movie rows [off + n·T, off + (n+1)·T).  With exclusion a second
     scalar-prefetched operand follows it, ``hits_ref`` ([NT] int32,
     ``SeenTiles.hits``): whether tile n holds a cell to mask.
-    ``counts_ref`` (SMEM, [4] int32) accumulates the selection rounds run,
+    ``counts_ref`` (SMEM, [5] int32) accumulates the selection rounds run,
     the tiles that ran at least one, the exclusion chunks run
     (``_SEEN_CHUNK`` slots each, the rectangle's whole width on a tile
-    that is hit) and the tiles that ran them — tiles of T rows, whatever G
+    whose masks ran) and the tiles that ran them, and the tiles completed
+    (every tile where no pass is deferred) — tiles of T rows, whatever G
     and P are.
     """
     refs = list(refs)
     hits_ref = refs.pop(0) if with_seen else None
-    u_ref, tbl_ref = refs.pop(0), refs.pop(0)
+    u_ref = refs.pop(0)
+    slack_ref = refs.pop(0) if defers else None
+    tbl_ref = refs.pop(0)
     scale_ref = refs.pop(0) if with_scale else None
     seen_ref = refs.pop(0) if with_seen else None
     (vals_ref, ids_ref, counts_ref, cv_ref, ci_ref, sc_ref, ms_ref,
@@ -633,11 +807,14 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
         # rows, PERF.md section 6, PR 32)
         return jnp.broadcast_to(scale_ref[s], (128, t)).T
 
+    def tile_rows(s):
+        return tbl_ref[pl.ds(pl.multiple_of(s * t, t), t), :]
+
     @pl.when(i == 0)
     def _():
         cv_ref[...] = jnp.full((k_top, b), -jnp.inf, jnp.float32)
         ci_ref[...] = jnp.full((k_top, b), -1, jnp.int32)
-        for j in range(4):
+        for j in range(NUM_COUNTS):
             counts_ref[j] = 0
 
     def rounds_of(j, tile_base):
@@ -678,9 +855,10 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
         tile_base = off_ref[0] + n * t
         there = True if nt % g == 0 else n < nt
         hit = hits_ref[jnp.minimum(n, nt - 1)] if with_seen else jnp.int32(0)
+        opened = there & (gate_ref[j] > 0)
+        needs_mask = there & ((hit > 0) | (tile_base + t > num_movies))
 
-        @pl.when(there & ((hit > 0) | (tile_base + t > num_movies)))
-        def _():
+        def mask():
             sc = _mask_scores(
                 sc_ref[j],
                 (lambda w: seen_ref[s, pl.ds(w, 1), :]) if with_seen
@@ -688,11 +866,22 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
             sc_ref[j] = sc
             ms_ref[j] = _tile_max(sc)
 
-        rounds = lax.cond(there & (gate_ref[j] > 0),
-                          lambda: rounds_of(j, tile_base),
-                          lambda: jnp.int32(0))
-        tile_counts = _tile_counts(rounds, jnp.where(there, hit, 0),
-                                   seen_width)
+        def turn():
+            if defers:
+                sc = _complete_scores(sc_ref[j], u_ref[...], tile_rows(s),
+                                      scale_rows(s))
+                sc_ref[j] = sc
+                ms_ref[j] = _tile_max(sc)
+                pl.when(needs_mask)(mask)
+            return rounds_of(j, tile_base)
+
+        if not defers:
+            pl.when(needs_mask)(mask)
+        rounds = lax.cond(opened, turn, lambda: jnp.int32(0))
+        # the tiles whose masks ran and whose every pass ran: behind the
+        # first gate where passes are deferred, else all there are
+        ran = jnp.asarray(opened if defers else there).astype(jnp.int32)
+        tile_counts = _tile_counts(rounds, hit * ran, seen_width, ran)
         return tuple(c + d for c, d in zip(counts, tile_counts))
 
     def fold_group(q, counts):
@@ -701,12 +890,20 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
 
         def score_tile(j, _):  # nothing here reads what a tile before wrote
             s = first + j
-            sc = _tile_scores(u_ref[...],
-                              tbl_ref[pl.ds(pl.multiple_of(s * t, t), t), :],
-                              scale_rows(s) if with_scale else None)
+            if defers:
+                # pass 0 alone: its raw sums, and what the exact block's
+                # maximum cannot pass
+                sc = _code_pass(u_ref[...],
+                                tile_rows(s).astype(jnp.bfloat16), 0)
+                factor = jnp.max(jnp.abs(scale_ref[s]), axis=1,
+                                 keepdims=True)
+                ms = _bound_max(sc, scale_rows(s), factor, slack_ref[...])
+            else:
+                sc = _tile_scores(u_ref[...], tile_rows(s),
+                                  scale_rows(s) if with_scale else None)
+                ms = _tile_max(sc)
+                ms_ref[j] = ms
             sc_ref[j] = sc
-            ms = _tile_max(sc)
-            ms_ref[j] = ms
             gate_ref[j] = _entrant(ms, kth).astype(jnp.int32)
             return _
 
@@ -716,7 +913,8 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
         return lax.fori_loop(0, p, functools.partial(fold_tile, first=first),
                              counts)
 
-    counts = lax.fori_loop(0, g // p, fold_group, (jnp.int32(0),) * 4)
+    counts = lax.fori_loop(0, g // p, fold_group,
+                           (jnp.int32(0),) * NUM_COUNTS)
     for j, n in enumerate(counts):
         counts_ref[j] += n
 
@@ -758,11 +956,14 @@ def topk_scores_pallas(
 def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
                         tile_m=512, row_offset=0, interpret=None):
     """``topk_scores_pallas`` and what the data made it cost: ``(scores,
-    movie rows, counts)``, counts [4] int32 = selection rounds run over the
+    movie rows, counts)``, counts [5] int32 = selection rounds run over the
     table's tiles, tiles that ran at least one (of ``M_pad / tile_m``),
     exclusion chunks run (``_SEEN_CHUNK`` compares each: the rectangle's
-    width on every tile that holds a cell) and tiles that ran them.  What
-    ``ServeEngine.topk`` puts on its compute span.  ``seen_tiles`` is a
+    width on every tile that holds a cell — on an int8 table, on those of
+    them whose first gate opened) and tiles that ran them, and tiles
+    completed (every pass run: all of them, but on an int8 table those
+    whose first gate opened).  What ``ServeEngine.topk`` puts on its
+    compute span.  ``seen_tiles`` is a
     ``SeenTiles`` (``scatter_seen_cells``) or a bare [NT, B, W] rectangle
     (``as_seen_tiles``)."""
     b, k = u.shape
@@ -805,8 +1006,11 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
     # u is the resident operand: what the fold needs of it is made here,
-    # once a call (an int8 table's three bfloat16 pieces)
+    # once a call (an int8 table's three bfloat16 pieces, and the slack
+    # row of the gate its deferred passes wait behind)
+    defers = bool(deferred_passes(table.dtype))
     u = resident_operand(u, table.dtype)
+    u, slack = u if defers else (u, None)
     seen_width = 0 if seen_tiles is None else slots.shape[2]
     # G tiles a grid step, from the shapes (``slab_tiles``)
     g = slab_tiles(nt, b, seen_width, k, table.dtype, tile_m=tile_m,
@@ -816,12 +1020,14 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     # row offset and, with exclusion, the tiles' hits.  Where G does not
     # divide NT the last step's blocks reach past the arrays and are
     # clipped; the kernel folds the tiles there are.
-    in_specs = [
-        pl.BlockSpec(u.shape, lambda i, *_: (0,) * u.ndim),  # u: resident
-        pl.BlockSpec((g * tile_m, k), lambda i, *_: (i, 0)),  # table: slabs
-    ]
+    in_specs = [pl.BlockSpec(u.shape, lambda i, *_: (0,) * u.ndim)]
+    ops = [u]  # resident
+    if defers:
+        in_specs.append(pl.BlockSpec(slack.shape, lambda i, *_: (0, 0)))
+        ops.append(slack)
+    in_specs.append(pl.BlockSpec((g * tile_m, k), lambda i, *_: (i, 0)))
+    ops.append(table)  # streamed in slabs
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
-    ops = [u, table]
     if scale is not None:
         # lane-dense: [NT, 1, T] is the [M_pad] vector itself in HBM, where
         # [M_pad, 1] would be padded to 128 lanes a row
@@ -869,11 +1075,11 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         functools.partial(
             _topk_kernel, t=tile_m, g=g, p=p, nt=nt, k_top=k_top,
             num_movies=num_movies, b=b, with_seen=seen_tiles is not None,
-            with_scale=scale is not None,
+            with_scale=scale is not None, defers=defers,
         ),
         grid_spec=grid_spec,
         out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32),
-                   mk((4,), jnp.int32)),
+                   mk((NUM_COUNTS,), jnp.int32)),
         interpret=bool(interpret),
         **kwargs,
     )(*prefetch, *ops)

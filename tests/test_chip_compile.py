@@ -377,6 +377,21 @@ def test_serve_scorer_slab_of_the_cells(chip, dtype, m, g_want, ragged):
     _compile_scorer(chip, dtype, b=256, k_top=16, w=16, m=m)
 
 
+@pytest.mark.parametrize("b", [128, 256])
+def test_serve_scorer_int8_cells_deferred_passes(chip, b):
+    """The int8 cells' call at the two batch buckets a saturated window
+    fills (48.19 M x 128 codes, 94,122 tiles, 16 slots): pass 0 and the
+    first gate's bound in the straight-line block (a lane reduction of the
+    tile's [1, 512] row of scales, a [1, 1] factor times the [1, B] slack
+    row), passes 2 and 1 from the slab still in VMEM inside the tile's
+    turn, the masks nested behind that gate: one Mosaic call, the slack
+    row made outside it, and nothing the size of the table beside it."""
+    compiled = _compile_scorer(chip, i8, b=b, k_top=16, w=16, m=48_190_000)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "s32[94122]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("g", [1, 2, 4, 8, 16])
 @pytest.mark.parametrize("dtype", [f32, i8])
 def test_serve_scorer_every_rung_of_the_slab_ladder(chip, monkeypatch, dtype,

@@ -455,8 +455,13 @@ def _topk_compiled_against_twin(table_dtype, order, cells, m):
                                         **kw)
     v_t, i_t, n_t = emulate_topk_counted(u, data, scale, st, **kw)
     v_c, i_c, v_t, i_t = map(np.asarray, (v_c, i_c, v_t, i_t))
-    assert np.asarray(n_c)[2:].tolist() == chunks
-    assert np.asarray(n_t)[2:].tolist() == chunks
+    for n in map(np.asarray, (n_c, n_t)):
+        # an int8 tile's masks wait behind its first gate with passes 1
+        # and 2: of the hit tiles, those that gate let through
+        assert n[2] == n[3] * (st.shape[2] // 16) and n[3] <= hit
+        assert n[1] <= n[4] <= nt
+        if table_dtype != "int8" or n[4] == nt:
+            assert n[2:].tolist() == chunks + [nt]
     tol = 2e-2 if table_dtype == "bfloat16" else 2e-3
     np.testing.assert_allclose(v_c, v_t, rtol=tol, atol=tol)
     assert (np.diff(v_c, axis=1) <= 0).all()  # descending
@@ -538,6 +543,109 @@ def test_topk_int8_at_a_size_where_the_scale_layout_shows():
     want = np.einsum("bjk,bk->bj", deq.astype(np.float64),
                      u_host.astype(np.float64))
     assert np.abs(v_c - want).max() <= 2e-6
+
+
+def _device_table(key, m_pad, tile, rank, dtype):
+    """[m_pad, rank] made on the device, a few tiles at a time, in place:
+    int8 codes uniform in ±127 (what a uniform ±0.175 table quantizes to),
+    or uniform ±0.175 floats."""
+    nt = m_pad // tile
+    d = max(x for x in range(1, 65) if nt % x == 0)
+
+    def block(k):
+        if dtype == jnp.int8:
+            return jax.random.randint(k, (tile * d, rank), -127, 128, jnp.int8)
+        return ((jax.random.uniform(k, (tile * d, rank), jnp.float32) - 0.5)
+                * 0.35).astype(dtype)
+
+    return jax.jit(lambda: jax.lax.fori_loop(
+        0, nt // d,
+        lambda i, buf: jax.lax.dynamic_update_slice(
+            buf, block(jax.random.fold_in(key, i)), (i * tile * d, 0)),
+        jnp.zeros((m_pad, rank), dtype)))()
+
+
+def _device_seen(key, nt, b, tile, hit_share):
+    """A ``SeenTiles`` made on the device: ``hit_share`` of the tiles hold
+    one cell of ~3 of the batch's rows each, W = 16."""
+    from cfk_tpu.serving.topk_kernel import SeenTiles
+
+    k1, k2, k3 = jax.random.split(key, 3)
+    hits = (jax.random.uniform(k1, (nt,)) < hit_share).astype(jnp.int32)
+    row = jax.random.randint(k2, (nt, b), 0, tile, jnp.int32)
+    who = jax.random.uniform(k3, (nt, b)) < 3.0 / b
+    slots = jax.jit(lambda: jnp.full((nt, b, 16), tile, jnp.int32).at[
+        :, :, 0].set(jnp.where((hits[:, None] > 0) & who, row, tile)))()
+    return SeenTiles(slots, hits)
+
+
+@pytest.mark.parametrize("b", [128, 256])
+def test_topk_int8_cell_shape_matches_twin_with_its_counts(b):
+    """The int8 cells' call (48.19 M x 128 codes, 94,122 tiles, K 16, W 16,
+    a fifth of the tiles hit) compiled against the twin scanning the same
+    tiles: the ids, the scores to float32 round-off, and all five counts —
+    the tiles completed among them, a tenth to a fifth of the table's,
+    which is what passes 1 and 2, the masks and the rounds now cost."""
+    from cfk_tpu.compat import emulate_topk_counted
+    from cfk_tpu.serving.topk_kernel import topk_scores_counted
+
+    m, k, k_top, tile = 48_190_000, 128, 16, 512
+    nt = -(-m // tile)
+    key = jax.random.PRNGKey(48)
+    data = _device_table(key, nt * tile, tile, k, jnp.int8)
+    scale = jax.random.uniform(jax.random.fold_in(key, 1), (nt * tile,),
+                               jnp.float32, 0.170, 0.175) / 127.0
+    u = (jax.random.uniform(jax.random.fold_in(key, 2), (b, k), jnp.float32)
+         - 0.5) * 0.35
+    st = _device_seen(jax.random.fold_in(key, 3), nt, b, tile, 0.21)
+    kw = dict(k_top=k_top, num_movies=m, tile_m=tile)
+    v_c, i_c, n_c = map(np.asarray, jax.jit(
+        lambda *a: topk_scores_counted(*a, interpret=False, **kw))(
+            u, data, scale, st))
+    v_t, i_t, n_t = map(np.asarray, jax.jit(
+        lambda *a: emulate_topk_counted(*a, **kw))(u, data, scale, st))
+    assert n_c.tolist() == n_t.tolist()
+    rounds, select, chunks, hit, completed = n_c.tolist()
+    assert select <= completed <= 0.25 * nt
+    assert 0 < chunks == hit <= completed
+    assert hit < 0.3 * int(np.asarray(st.hits).sum())
+    np.testing.assert_array_equal(i_c, i_t)
+    np.testing.assert_allclose(v_c, v_t, rtol=2e-6, atol=2e-6)
+    assert (np.diff(v_c, axis=1) <= 0).all() and (i_c < m).all()
+
+
+def test_float32_scorer_alone_takes_what_it_took():
+    """The float32 body defers nothing and is traced as before: alone at
+    the one-chip cell's size (9.35 M x 128, B 256, K 16, 8 % of the tiles
+    hit) a call takes what the parent's took in the same call of the chip
+    tool (PERF.md section 6, PR 48), within the room two runs of one body
+    differ by."""
+    import time
+
+    from cfk_tpu.serving.topk_kernel import topk_scores_counted
+
+    m, k, b, k_top, tile = 9_350_000, 128, 256, 16, 512
+    nt = -(-m // tile)
+    key = jax.random.PRNGKey(48)
+    data = _device_table(key, nt * tile, tile, k, jnp.float32)
+    u = (jax.random.uniform(jax.random.fold_in(key, 2), (b, k), jnp.float32)
+         - 0.5) * 0.35
+    st = _device_seen(jax.random.fold_in(key, 3), nt, b, tile, 0.08)
+    fn = jax.jit(lambda *a: topk_scores_counted(
+        *a, k_top=k_top, num_movies=m, tile_m=tile, interpret=False))
+    out = jax.block_until_ready(fn(u, data, None, st))
+    t0 = time.perf_counter()
+    for _ in range(20):
+        out = fn(u, data, None, st)
+    jax.block_until_ready(out)
+    ms = (time.perf_counter() - t0) / 20 * 1e3
+    print(f"float32 scorer alone: {ms:.2f} ms a call")
+    assert np.asarray(out[2])[4] == nt  # every tile completed
+    assert ms <= _F32_ALONE_MS * 1.02, ms
+
+
+# the parent's body on this data, same call of the chip tool (PR 48)
+_F32_ALONE_MS = 28.7
 
 
 def test_sliced_upload_on_the_chip_is_the_whole_table_quantizer(monkeypatch):

@@ -7,7 +7,11 @@ budget going up in slices where it fits quantized and refused in words where
 it does not; the spans.  And the arithmetic of an int8 tile's score block
 (ISSUE 35): the three bfloat16 pieces of ``u`` sum to it bit for bit, every
 code is exact in bfloat16, the three-pass block sits where a float32 sum
-can, and each piece is needed."""
+can, and each piece is needed.  And the gate its passes 1 and 2 wait behind
+(ISSUE 48): the bound that decides it never hides an entrant, the fold that
+defers them answers bit for bit what a fold that completes every tile
+answers, kernel and twin alike with all five counts, float tables defer
+nothing."""
 
 import jax
 import jax.numpy as jnp
@@ -154,9 +158,20 @@ def test_one_pass_bfloat16_control_fails_the_stated_limits(monkeypatch):
         topk_kernel, "serve_compute_dtype",
         lambda dtype: (jnp.bfloat16, None) if dtype == jnp.int8
         else real(dtype))
+    # the control has no second pass to defer: every tile is completed
+    assert topk_kernel.deferred_passes(jnp.dtype("int8")) == ()
+    assert topk_kernel.score_passes(jnp.dtype("int8")) == 1
     # jax keeps traces by function and shapes: another tile height (the
     # scores do not depend on it) makes the patched fold a trace of its own
-    control = _engine(uf, mf, lists, tile_m=256).topk(rows, k)
+    tracer = telemetry.configure()
+    try:
+        control = _engine(uf, mf, lists, tile_m=256).topk(rows, k)
+        (compute,) = [e["args"] for e in tracer.events()
+                      if e.get("ph") == "X"
+                      and e["name"] == "serve/batch/compute"]
+    finally:
+        telemetry.shutdown(write=False)
+    assert compute["completed_tiles"] == compute["tiles"]
     seen = [lists[r] for r in rows]
     best, _, at = quantized_topk(uf[rows], mf, seen, k, sound[1])
     assert topk_gaps(sound[0], best, at) <= (RANK_GAP, SCORE_ERR)
@@ -388,3 +403,229 @@ def test_compute_span_says_how_many_passes_a_tile_takes(table_dtype, passes,
     assert compute["table_dtype"] == table_dtype
     assert compute["score_passes"] == passes
     assert compute.get("shards") == shards
+    # the tiles every one of those passes ran on: all of a float table's,
+    # of an int8 table's those whose first gate opened (all shards')
+    assert compute["select_tiles"] <= compute["completed_tiles"]
+    assert compute["seen_hit_tiles"] <= compute["completed_tiles"]
+    if table_dtype == "int8":
+        assert compute["completed_tiles"] <= compute["tiles"]
+    else:
+        assert compute["completed_tiles"] == compute["tiles"]
+
+
+# -- passes 1 and 2 behind a gate that pass 0 decides (ISSUE 48) -------------
+
+_HALF = 1.0 + 2.0 ** -8  # the midpoint between two bfloat16 neighbours
+
+
+def _bound_case(name, t=64, k=128, b=16):
+    """(u [b, k], codes [t, k] int8, scales [t]) built against the bound:
+    what passes 1 and 2 add to row 0 is as large as the operands allow."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    u = ((rng.random((b, k), dtype=np.float32) - 0.5) * 0.35)
+    codes = rng.integers(-127, 128, (t, k), dtype=np.int8)
+    scales = rng.uniform(1e-3, 2e-3, t).astype(np.float32)
+    if name in ("residual", "residual_neg", "both_extremes"):
+        # every entry a hair under a bfloat16 midpoint: piece 0 rounds
+        # down and the residual is the largest a float32 can leave
+        mag = np.nextafter(np.float32(_HALF), np.float32(0)) * (
+            2.0 ** rng.integers(-6, 3, (b, k))).astype(np.float32)
+        u = (mag * rng.choice([-1.0, 1.0], (b, k))).astype(np.float32)
+    if name == "code_min":
+        codes[:] = -128  # a code the quantizer never writes
+        u = -np.abs(u)
+    hi = np.asarray(jnp.asarray(u).astype(jnp.bfloat16).astype(jnp.float32))
+    # row r < b: codes of the largest magnitude, each with the sign of
+    # user r's residual, so passes 1 and 2 add all they can for that user
+    sign = np.where(u - hi >= 0, 1, -1).astype(np.int8)
+    if name != "code_min":
+        codes[:b] = 127 * sign
+    if name == "residual_neg":
+        codes[:b] = -127 * sign  # and take away all they can
+    if name in ("scale_extremes", "both_extremes"):
+        scales[:] = np.float32(2.0 ** -60)
+        scales[:b:2] = np.float32(2.0 ** 60)  # 2^120 apart in one tile
+    if name == "scale_top_row_elsewhere":
+        # the largest scale sits on a row that scores low
+        scales[:] = np.float32(1e-3)
+        scales[-1] = np.float32(0.5)
+        codes[-1] = 0
+    if name == "negative_scale":
+        scales[::3] *= -1
+    return u, codes, scales
+
+
+BOUND_CASES = ("weights", "residual", "residual_neg", "code_min",
+               "scale_extremes", "both_extremes", "scale_top_row_elsewhere",
+               "negative_scale")
+
+
+@pytest.mark.parametrize("jitted", [False, True])
+@pytest.mark.parametrize("case", BOUND_CASES)
+def test_the_first_gate_never_hides_an_entrant(case, jitted):
+    """``_bound_max`` from pass 0 alone is no smaller than the maximum of
+    the completed block, for every user, on inputs built against it; so a
+    K-th score a hair under the exact maximum leaves the gate open."""
+    u, codes, scales = _bound_case(case)
+
+    def both(u, codes, scales):
+        pieces, slack = topk_kernel.resident_operand(u, jnp.int8)
+        scale = scales[:, None]
+        p0 = topk_kernel._code_pass(pieces, codes.astype(jnp.bfloat16), 0)
+        factor = jnp.max(jnp.abs(scale), axis=0, keepdims=True)
+        bound = topk_kernel._bound_max(p0, scale, factor, slack)
+        exact = topk_kernel._complete_scores(p0, pieces, codes, scale)
+        first = topk_kernel._tile_max(
+            topk_kernel._times_row_scale(p0, scale))
+        return bound, exact, first
+
+    bound, exact, first = map(np.asarray, (jax.jit(both) if jitted else both)(
+        jnp.asarray(u), jnp.asarray(codes), jnp.asarray(scales)))
+    top = exact.max(axis=0)
+    assert np.isfinite(bound).all() and np.isfinite(exact).all()
+    assert (bound[0] >= top).all()
+    hair_under = np.nextafter(top, np.float32(-np.inf))[None]
+    assert bool(topk_kernel._entrant(jnp.asarray(bound),
+                                     jnp.asarray(hair_under)))
+    assert (bound[0] > hair_under[0]).all()  # user by user, not just one
+    if case.startswith("residual") or case == "both_extremes":
+        # the case is worth its name: pass 0 alone is off by a good part
+        # of the slack the bound allows for
+        off = np.abs(top - first[0]).max()
+        assert off >= 0.2 * (bound[0] - first[0]).min() > 0
+    # in float64 the three passes are the dequantized dot: the bound
+    # holds against that too, up to the block's own float32 rounding
+    deq = codes.astype(np.float64) * scales.astype(np.float64)[:, None]
+    real = (deq @ u.astype(np.float64).T).max(axis=0)
+    assert (bound[0] >= real - 1e-5 * np.abs(real)).all()
+
+
+def _defer_problem(data_case, b, exclusion, nt=35, t=16, k=16, k_top=10):
+    """(u, codes, scales, seen rectangle or None, num_movies): 2 G + 3
+    tiles, so the last grid step is a ragged slab of three, the last tile
+    reaching past ``num_movies``."""
+    rng = np.random.default_rng(b + len(data_case) + len(exclusion))
+    m = nt * t - 5
+    u = ((rng.random((b, k), dtype=np.float32) - 0.5) * 0.35)
+    mf = ((rng.random((nt * t, k), dtype=np.float32) - 0.5) * 0.35)
+    if data_case == "ties":
+        mf[:] = mf[:7][rng.integers(0, 7, nt * t)]  # seven distinct rows
+    if data_case == "residual":
+        u, _, _ = _bound_case("residual", t=b, k=k, b=b)
+        u = u * np.float32(0.01)
+    # later tiles score lower, so that their gates shut for a full batch
+    mf *= (0.85 ** (np.arange(nt * t) // t)).astype(np.float32)[:, None]
+    mf[m:] = 10.0  # padding rows would win if the mask let them
+    codes, scales = quantize_rows(mf)
+    if exclusion == "none" and data_case != "few":
+        return u, codes, scales, None, m
+    if data_case == "few":
+        # user i keeps i % (K + 1) candidates: from none to exactly K
+        lists = [np.sort(rng.permutation(m)[i % (k_top + 1):])
+                 for i in range(b)]
+    elif exclusion == "sparse":
+        lists = [np.sort(rng.choice(m, int(rng.integers(0, 4)), False))
+                 for _ in range(b)]
+    else:  # every tile holds a cell of some user: the tile's best row
+        lists = [np.zeros(0, np.int64) for _ in range(b)]
+        best = (mf[:m] @ u[0]).reshape(-1)
+        lists[0] = np.sort(np.asarray(
+            [j * t + int(np.argmax(best[j * t:min((j + 1) * t, m)]))
+             for j in range(nt)]))
+        lists[1 % b] = np.union1d(lists[1 % b], rng.choice(m, 5, False))
+    movies, indptr = _csr([x.astype(np.int32) for x in lists])
+    st = topk_kernel.build_seen_tiles(
+        movies, indptr, np.arange(b), num_movies=m, tile_m=t)
+    return u, codes, scales, jnp.asarray(st), m
+
+
+@pytest.mark.parametrize("data_case", ["ragged", "ties", "few", "residual"])
+@pytest.mark.parametrize("exclusion", ["none", "sparse", "every_tile"])
+@pytest.mark.parametrize("b", [8, 128, 256])
+def test_deferred_fold_is_the_completed_fold_bit_for_bit(
+        b, exclusion, data_case, monkeypatch):
+    """Kernel (interpret path) and twin with passes 1 and 2 behind the
+    first gate, against the same fold with that gate held open on every
+    tile — three passes, the masks and the exact gate everywhere: the
+    parent's arithmetic.  Scores, ids and the selection's counts to the
+    bit; the exclusion counts and the completed tiles no larger."""
+    from cfk_tpu.compat import emulate_topk_counted
+
+    k_top, t = 10, 16
+    u, codes, scales, st, m = _defer_problem(data_case, b, exclusion)
+    args = (jnp.asarray(u), jnp.asarray(codes), jnp.asarray(scales), st)
+    kw = dict(k_top=k_top, num_movies=m, tile_m=t)
+    routes = (topk_kernel.topk_scores_counted, emulate_topk_counted)
+    deferred = [tuple(map(np.asarray, fn(*args, **kw))) for fn in routes]
+    monkeypatch.setattr(
+        topk_kernel, "_bound_max",
+        lambda p0, scale, factor, slack: jnp.full(
+            (1, p0.shape[1]), jnp.inf, jnp.float32))
+    completed = [tuple(map(np.asarray, fn(*args, **kw))) for fn in routes]
+    nt = codes.shape[0] // t
+    for got, want in zip(deferred, completed):
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+        assert got[0].tobytes() == want[0].tobytes()
+        assert got[2][:2].tolist() == want[2][:2].tolist()
+        assert (got[2][2:] <= want[2][2:]).all()
+        assert want[2][4] == nt
+        assert got[2][1] <= got[2][4]  # a tile that ran a round was completed
+        assert got[2][3] <= got[2][4]  # and so was one that ran a mask
+    # kernel == twin with all five counts, either way
+    for kernel, twin in (deferred, completed):
+        for x, y in zip(kernel, twin):
+            np.testing.assert_array_equal(x, y)
+    if data_case in ("ragged", "residual"):
+        assert deferred[0][2][4] < nt  # the gate did shut somewhere
+    # and the answer is the dequantized table's, by the plain reference
+    if data_case == "ragged" and exclusion == "none":
+        mf = codes[:m].astype(np.float32) * scales[:m, None]
+        sc = mf.astype(np.float64) @ u.astype(np.float64).T
+        want_ids = np.argsort(-sc, axis=0, kind="stable")[:k_top].T
+        assert (deferred[0][1] == want_ids).mean() > 0.99
+
+
+def _dots(jaxpr):
+    """``dot_general`` equations in a jaxpr, sub-jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _dots(sub)
+    return n
+
+
+@pytest.mark.parametrize("route", ["kernel", "twin"])
+@pytest.mark.parametrize("table_dtype,dots", [
+    ("float32", 1), ("bfloat16", 1), ("int8", 3)])
+def test_float_tables_defer_nothing(table_dtype, dots, route):
+    """A float32 or bfloat16 tile is one ``dot_general``, in the fold's
+    masked and unmasked branch each, and every tile is completed; an int8
+    tile is pass 0 and, behind the gate, passes 2 and 1."""
+    from cfk_tpu.compat import emulate_topk_counted
+
+    uf, mf, lists, _ = _problem(48, m=35 * TILE - 5, users=8)
+    data, scale = quantize_table(jnp.asarray(engine_mod.pad_table(mf, TILE)),
+                                 table_dtype)
+    movies, indptr = _csr(lists[:8])
+    st = jnp.asarray(topk_kernel.build_seen_tiles(
+        movies, indptr, np.arange(8), num_movies=mf.shape[0], tile_m=TILE))
+    fn = (topk_kernel.topk_scores_counted if route == "kernel"
+          else emulate_topk_counted)
+    call = lambda u, st: fn(u, data, scale, st, k_top=5,
+                            num_movies=mf.shape[0], tile_m=TILE)
+    assert topk_kernel.deferred_passes(data.dtype) == (
+        (1, 2) if table_dtype == "int8" else ())
+    counts = np.asarray(call(jnp.asarray(uf[:8]), st)[2])
+    if table_dtype == "int8":
+        assert counts[1] <= counts[4] < 35
+    else:
+        assert counts[4] == 35
+    for seen, branches in ((None, 1), (st, 2)):
+        n = _dots(jax.make_jaxpr(call)(jnp.asarray(uf[:8]), seen).jaxpr)
+        # the kernel masks a float tile in place, the twin's fold has a
+        # branch with the masks and one without; the int8 completion is
+        # traced once on either route
+        want = dots * (branches if route == "twin" and dots == 1 else 1)
+        assert n == want, (n, want)

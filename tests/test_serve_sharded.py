@@ -308,6 +308,8 @@ def test_spans_of_a_sharded_engine():
     # and the gated exclusion's: chunks run, tiles that ran any
     assert 1 <= compute["seen_hit_tiles"] <= compute["tiles"]
     assert compute["seen_hit_tiles"] <= compute["seen_chunks"]
+    # a float32 table completes every tile of every shard
+    assert compute["completed_tiles"] == compute["tiles"]
     # what the scorer streams for the batch, all four shards'
     assert compute["table_dtype"] == "float32"
     assert compute["scan_bytes"] == 1024 * RANK * 4
@@ -329,7 +331,8 @@ def test_spans_of_a_sharded_engine():
     assert "shard_cells" not in events["serve/batch/seen_tiles"]["args"]
     assert set(events["serve/batch/compute"]["args"]) == {
         "n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
-        "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes",
+        "seen_hit_tiles", "completed_tiles", "tiles", "table_dtype",
+        "scan_bytes",
         "score_passes", "slab_tiles", "grid_steps"}
     assert {x: events["serve/batch/compute"]["args"][x]
             for x in ("seen_chunks", "seen_hit_tiles")} == {

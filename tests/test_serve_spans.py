@@ -201,7 +201,8 @@ def test_under_a_backlog_each_step_has_one_compute_of_two_batches(tracer):
     # the third polls three rows (padded to four) and answers batch two
     assert steps[2]["serve/batch/assemble"][0]["args"]["n"] == 3
     keys = {"n", "b", "k", "select_rounds", "select_tiles", "seen_chunks",
-            "seen_hit_tiles", "tiles", "table_dtype", "scan_bytes",
+            "seen_hit_tiles", "completed_tiles", "tiles", "table_dtype",
+            "scan_bytes",
             "score_passes", "slab_tiles", "grid_steps"}
     for spans, n in zip(steps[1:], (4, 4, 3)):
         compute = spans["serve/batch/compute"][0]["args"]

@@ -304,17 +304,18 @@ def test_gated_fold_equals_stable_sort_oracle(order, table_dtype, b, k_top):
 @pytest.mark.parametrize("order", ["ascending", "descending", "equal"])
 def test_selection_counts_are_what_the_order_implies(order, k_top):
     """[rounds run, tiles that ran any, exclusion chunks run, tiles that
-    ran any] with no cell in the rectangle: ascending scores make every
-    tile replace the whole carry (min(K, real rows) rounds each),
-    descending or equal ones only fill it (K rounds over the first
-    ceil(K / T) tiles, then every gate stays shut), no tile runs an
-    exclusion chunk — and kernel and twin count alike."""
+    ran any, tiles completed] with no cell in the rectangle: ascending
+    scores make every tile replace the whole carry (min(K, real rows)
+    rounds each), descending or equal ones only fill it (K rounds over the
+    first ceil(K / T) tiles, then every gate stays shut), no tile runs an
+    exclusion chunk, a float32 table completes all five tiles — and kernel
+    and twin count alike."""
     (kernel, twin), _ = _gate_run(order, "float32", 8, k_top, exclude=False)
     real = [16, 16, 16, 16, 6]  # rows of each tile below num_movies
     want = ([sum(min(k_top, r) for r in real), 5] if order == "ascending"
             else [k_top, -(-k_top // 16)])  # the tiles that fill the carry
-    assert kernel[2].tolist() == want + [0, 0]
-    assert twin[2].tolist() == want + [0, 0]
+    assert kernel[2].tolist() == want + [0, 0, 5]
+    assert twin[2].tolist() == want + [0, 0, 5]
 
 
 # -- the gated masks: a tile runs the exclusion chunks it holds --------------
@@ -373,8 +374,9 @@ def test_gated_masks_equal_oracle_and_full_width_run(case, table_dtype):
     a cell or cross ``num_movies``, against the numpy stable-sort oracle
     and against a run told that every tile holds one — to the bit — with
     the hits made from the cell list (``scatter_seen_cells``, the
-    server's) and by reading a bare rectangle; the four counts are what
-    the cell lists imply."""
+    server's) and by reading a bare rectangle; the counts are what the
+    cell lists imply — on an int8 table, whose masks wait with passes 1
+    and 2 behind the first gate, of the hit tiles that gate let through."""
     from cfk_tpu.ops.quant import dequantize_table, quantize_table
     from cfk_tpu.serving.topk_kernel import SeenTiles, scatter_seen_cells
 
@@ -414,12 +416,22 @@ def test_gated_masks_equal_oracle_and_full_width_run(case, table_dtype):
             np.testing.assert_array_equal(got_v, want_v)
         np.testing.assert_array_equal(kernel[2], twin[2])
     counts = {name: kernel[2].tolist() for name, (kernel, _) in runs.items()}
-    assert counts["cell_list"][2:] == _mask_counts_implied(seen)
+    chunks = _MASK_WIDTH // 16
+    if table_dtype == "int8":
+        # a mask runs on a hit tile that was completed, and only there
+        for c in counts.values():
+            assert c[2] == c[3] * chunks and c[1] <= c[4] <= _MASK_NT
+        assert counts["cell_list"][3] <= _mask_counts_implied(seen)[1]
+        assert counts["every_tile"][3] == counts["every_tile"][4]
+    else:
+        assert counts["cell_list"][2:] == _mask_counts_implied(seen) + [
+            _MASK_NT]
+        assert counts["every_tile"][2:] == [_MASK_NT * chunks, _MASK_NT,
+                                            _MASK_NT]
     assert counts["bare"] == counts["cell_list"]
-    assert counts["every_tile"][2:] == [
-        _MASK_NT * (_MASK_WIDTH // 16), _MASK_NT]
     # the same rounds on the same scores: only the masks differ
     assert counts["every_tile"][:2] == counts["cell_list"][:2]
+    assert counts["every_tile"][4] == counts["cell_list"][4]
 
 
 # -- a grid step streams a slab of G tiles and folds them one by one ---------
@@ -441,7 +453,7 @@ def _slab_num_tiles(which):
 def test_slab_kernel_equals_twin_to_the_bit(which, table_dtype, exclude):
     """The kernel on the interpret path, G tiles a grid step and the last
     step ragged, against the twin that scans tile by tile: scores, ids and
-    all four counts to the bit, at NT on both sides of the ladder's top.
+    all five counts to the bit, at NT on both sides of the ladder's top.
     The table's last tile reaches past ``num_movies``, holds user 0's best
     row (an entrant in the last slab's last tile) and user 1's best row,
     which user 1 has rated (a hit there)."""
@@ -482,10 +494,15 @@ def test_slab_kernel_equals_twin_to_the_bit(which, table_dtype, exclude):
     if exclude:
         assert not any(np.isin(ids[i], seen[i]).any() for i in range(b))
         hit = np.unique(np.concatenate(seen) // t).size
-        assert counts[2:].tolist() == [hit, hit]  # W = 16: a chunk a tile
+        # W = 16: a chunk a tile that is hit (and, int8, completed)
+        assert counts[2] == counts[3] <= hit
+        assert counts[3] == hit or table_dtype == "int8"
     else:
-        assert counts[2:].tolist() == [0, 0]
+        assert counts[2:4].tolist() == [0, 0]
     assert 1 <= counts[1] <= nt and counts[1] <= counts[0]
+    # every pass ran on every tile, but on an int8 table's behind a shut gate
+    assert counts[1] <= counts[4] <= nt
+    assert counts[4] == nt or table_dtype == "int8"
 
 
 def test_slab_tiles_fits_the_table_and_the_budget():
