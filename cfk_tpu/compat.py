@@ -103,10 +103,10 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     fold's branch with or without the masks by the same per-tile scalar
     (``SeenTiles.hits``) as the kernel does — so kernel and twin are
     BIT-IDENTICAL on this route, counts included (``tests/test_serving.py``
-    pins it).  Where the fold defers passes (an int8 table) the tiles it
-    completes depend on the K-th scores its first gate reads, and the
-    kernel reads them once a group of tiles: the scan carries that row and
-    renews it at the same tiles.  Crucially the scan's
+    pins it).  Where the fold defers passes (an int8 or a float32 table)
+    the tiles it completes depend on the K-th scores its first gate reads,
+    and the kernel reads them once a group of tiles: the scan carries that
+    row and renews it at the same tiles.  Crucially the scan's
     per-step block is [B, tile_m]: no [B, num_movies] score matrix is ever
     materialized here either (the emulation-path memory check in the tests
     compiles this and bounds its temp memory below B·M·4 bytes).

@@ -634,7 +634,8 @@ class ServeEngine:
             # the table as it is held, and its scales
             table_dtype=self.table_dtype,
             scan_bytes=table.nbytes + (0 if scale is None else scale.nbytes),
-            # the MXU passes the fold runs over each tile of such a table
+            # the MXU passes the fold runs over a completed tile of such
+            # a table (one on every other: ``completed_tiles``)
             score_passes=score_passes(table.dtype))
         if self.mesh is not None:
             counters.update(shards=self._shards,
@@ -810,8 +811,8 @@ class TopKBatch:
         # what the data made this batch cost, over every tile scanned
         # (all shards'): selection rounds run and tiles that ran any,
         # exclusion chunks run and tiles that ran any, tiles on which
-        # every one of ``score_passes`` ran (an int8 tile behind a shut
-        # first gate runs one, and no mask)
+        # every one of ``score_passes`` ran (an int8 or a float32 tile
+        # behind a shut first gate runs one, and no mask)
         sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
                seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
                completed_tiles=int(counts[4]), **self.counters)

@@ -29,7 +29,18 @@ Per tile, everything MOVIE-MAJOR — [T, B] scores, [K, B] carry — so that
 every dynamic index is a single-sublane ref row and every reduction runs
 along sublanes, the forms Mosaic lowers:
 
-- score block  S = tile · Uᵀ on the MXU (f32 accumulation).  An int8
+- score block  S = tile · Uᵀ on the MXU (f32 accumulation).  A float32
+  tile's block is one ``dot_general`` at ``Precision.HIGHEST``, six
+  bfloat16 passes, and **runs only behind a first gate that one bfloat16
+  pass decides**: the tile and ``u`` each rounded to bfloat16 (``u``'s
+  once a call), their block's per-user maximum, plus a proven bound of
+  how far the exact block can lie above it, from the tile's largest
+  |entry| and two 1-norms of ``u`` made once a call (``_pass0_bound``,
+  where the proof is; ``float_slack``).  Six tiles in ten of a serve
+  cell's hold no row that can enter any user's top-K: they cost that one
+  pass, 64 |.| and 64 maxima, no block, no mask and no round; a tile
+  whose first gate opens runs the block as before, to the bit, the pass
+  it was gated by a seventh (PERF.md section 6, PR 50).  An int8
   tile is not dequantized at all: a code is an integer in ±127, exact in
   bfloat16, and the row's scale comes out of the sum, so the block is
   three bfloat16 passes of the codes against the three bfloat16 pieces of
@@ -95,7 +106,7 @@ counts included — the same twin discipline as the Gram kernels.  The
 kernel compiles for the v5e at f32/bf16/int8 and under the 2×2 shard_map
 (``tests/test_chip_compile.py``) and matches the twin on the chip
 (``tests/test_pallas_tpu.py``); what it costs there is in PERF.md
-(sections 5 and 6, PRs 27, 31, 35, 38 and 48; section 7, row 17).
+(sections 5 and 6, PRs 27, 31, 35, 38, 48 and 50; section 7, row 17).
 """
 
 from __future__ import annotations
@@ -154,11 +165,13 @@ def serve_compute_dtype(table_dtype):
     scale).  What a narrower table buys is BYTES, held and scanned per
     batch: half at bf16, a quarter plus 4 B a row at int8.  At
     ``Precision.HIGHEST`` the scorer is bound by the MXU's passes, six over
-    a float32 tile; an int8 code is exact in bfloat16, so an int8 tile
-    needs three (``score_passes``, ``split_bf16x3``), and costs ONE unless
-    the first of them cannot rule the tile out (``deferred_passes``,
-    ``_bound_max``): the answer is the three-pass one on every input
-    (PERF.md sections 5 and 6 hold what each costs on the chip).  The two
+    a float32 tile's block; an int8 code is exact in bfloat16, so an int8
+    tile needs three (``score_passes``, ``split_bf16x3``).  Either costs
+    ONE pass unless that pass cannot rule the tile out (``deferred_passes``:
+    ``_bound_max`` for int8, ``_pass0_bound`` for float32, whose first pass
+    is a seventh beside the block's six: 1, or 1 + 6): the answer is the
+    three-pass and the six-pass one on every input (PERF.md sections 5 and
+    6 hold what each costs on the chip).  The two
     control tests patch this function to ``(bfloat16, None)`` for int8: the
     fold then runs the arithmetic under the stated one, the dequantized
     tile and ``u`` each rounded to bfloat16, in one pass on every tile,
@@ -170,30 +183,36 @@ def serve_compute_dtype(table_dtype):
 
 def score_passes(table_dtype) -> int:
     """bfloat16 MXU passes the fold runs over one COMPLETED tile of such a
-    table: 1 where the compute dtype is bfloat16, else the pieces of a
-    float32 operand pair that can be non-zero — six of a float32 tile's
-    nine at ``Precision.HIGHEST``, three for an int8 tile, whose codes
-    have one piece.  A float32 or bfloat16 tile is always completed; an
-    int8 tile runs the first of its three on every tile and the other two
-    (``deferred_passes``) only where its first gate opens, so a call costs
-    ``tiles + 2 x completed_tiles`` passes.  What ``ServeEngine`` puts on
+    table: 1 where the compute dtype is bfloat16; three for an int8 tile,
+    whose codes have one piece against the three of ``u``; seven for a
+    float32 tile: the one bfloat16 pass its first gate is read from, and
+    behind that gate the ``dot_general`` at ``Precision.HIGHEST``, which
+    runs six of the nine piece products of a float32 operand pair and
+    cannot be split, so the first pass is not one of its six.  A bfloat16
+    tile is always completed; an int8 or a float32 tile runs pass 0 on
+    every tile and the others (``deferred_passes``) only where its first
+    gate opens, so a call costs ``tiles + (score_passes - 1) x
+    completed_tiles`` passes.  What ``ServeEngine`` puts on
     ``serve/batch/compute``, beside the count of completed tiles."""
-    ct, _ = serve_compute_dtype(table_dtype)
-    if ct == jnp.bfloat16:
-        return 1
-    return 3 if table_dtype == jnp.int8 else 6
+    return 1 + len(deferred_passes(table_dtype))
 
 
 def deferred_passes(table_dtype) -> tuple[int, ...]:
     """Which of a tile's ``score_passes`` wait behind its first gate: the
-    pieces of ``u`` whose pass runs only on a tile that pass 0 could not
-    rule out (``_bound_max``).  Passes 1 and 2 of an int8 tile scored in
-    float32 arithmetic; none of a float32 or bfloat16 tile's, whose one
-    ``dot_general`` cannot be split, and none under the controls' one-pass
+    passes that run only on a tile that pass 0 could not rule out.  Passes
+    1 and 2 of an int8 tile scored in float32 arithmetic (the two low
+    pieces of ``u``: ``_bound_max``); passes 1 to 6 of a float32 tile, the
+    whole block at ``Precision.HIGHEST`` (``_pass0_bound``: pass 0 there is
+    the tile and ``u`` each rounded to bfloat16); none of a bfloat16
+    tile's, which has one pass, and none under the controls' one-pass
     bfloat16 arithmetic, which has no second pass to defer.  Read at trace
     time from the table's dtype: no knob."""
     ct, _ = serve_compute_dtype(table_dtype)
-    return (1, 2) if table_dtype == jnp.int8 and ct == jnp.float32 else ()
+    if ct != jnp.float32:
+        return ()
+    if table_dtype == jnp.int8:
+        return (1, 2)
+    return (1, 2, 3, 4, 5, 6) if table_dtype == jnp.float32 else ()
 
 
 def split_bf16x3(u):
@@ -229,14 +248,41 @@ def pass_slack(pieces):
     return (_CODE_MAX * (1 + rho) * ((n1 + n2) + 2.0 ** -20 * n0))[None, :]
 
 
+def float_slack(u, u0):
+    """The two [1, B] float32 rows, [2, 1, B], that ``_pass0_bound`` adds to a
+    float32 tile's pass-0 maximum, the first times h, half the spacing of
+    bfloat16 numbers at the tile's largest |entry| a, the second times a:
+    per user (1 + rho) ||u||_1 and (1 + rho) ((1 + 2^-8) ||u - u0||_1 +
+    (10 k + 8) 2^-24 ||u||_1), ``u0`` [B, k] being ``u`` rounded to
+    bfloat16 as pass 0 reads it (the difference is exact in float32), rho
+    = (2 k + 32) 2^-24.  Made once a call."""
+    k = u.shape[-1]
+    eps = 2.0 ** -24
+    n_u = jnp.sum(jnp.abs(u), axis=-1)
+    n_d = jnp.sum(jnp.abs(u - u0.astype(jnp.float32)), axis=-1)
+    grow = 1 + (2 * k + 32) * eps
+    return jnp.stack([
+        grow * n_u,
+        grow * ((1 + 2.0 ** -8) * n_d + ((10 * k + 8) * eps) * n_u)],
+    )[:, None, :]
+
+
 def resident_operand(u, table_dtype):
     """What the fold reads as ``u`` for a table of this dtype, made once a
     call: the [B, k] batch itself; for int8 codes its three bfloat16
     pieces (``split_bf16x3``), [3, B, k]; and where passes are deferred
-    (``deferred_passes``) the pieces with the first gate's slack row,
-    ``(pieces, pass_slack(pieces))``."""
+    (``deferred_passes``) a tuple that ends in the first gate's slack row:
+    ``(pieces, pass_slack(pieces))`` for int8 codes, ``(u, u0,
+    float_slack(u, u0))`` for a float32 table, ``u`` in float32 for the
+    block and ``u0`` its first bfloat16 piece for pass 0."""
     if table_dtype != jnp.int8:
-        return u
+        if not deferred_passes(table_dtype):
+            return u
+        u = u.astype(jnp.float32)
+        # piece 0 of ``split_bf16x3``, by the rounding XLA may not drop
+        u0 = lax.reduce_precision(
+            u, exponent_bits=8, mantissa_bits=7).astype(jnp.bfloat16)
+        return u, u0, float_slack(u, u0)
     pieces = split_bf16x3(u)
     if deferred_passes(table_dtype):
         return pieces, pass_slack(pieces)
@@ -258,13 +304,19 @@ def _times_row_scale(x, scale):
 _DOT_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _code_pass(pieces, codes, piece):
-    """One bfloat16 MXU pass: the raw [T, B] float32 sums of ``codes``
-    [T, k] (bfloat16: an int8 code is exact there) against piece ``piece``
-    of ``u``.  Every product is exact in the float32 accumulator."""
+def _bf16_pass(rows, piece):
+    """One bfloat16 MXU pass: the raw [T, B] float32 sums of ``rows``
+    [T, k] against ``piece`` [B, k], both bfloat16.  Every product is
+    exact in the float32 accumulator."""
     return jax.lax.dot_general(
-        codes, pieces[piece], dimension_numbers=_DOT_DIMS,
+        rows, piece, dimension_numbers=_DOT_DIMS,
         preferred_element_type=jnp.float32)
+
+
+def _code_pass(pieces, codes, piece):
+    """``_bf16_pass`` of ``codes`` [T, k] (bfloat16: an int8 code is exact
+    there) against piece ``piece`` of ``u``."""
+    return _bf16_pass(codes, pieces[piece])
 
 
 def _complete_scores(p0, pieces, tile, scale):
@@ -321,10 +373,66 @@ def _bound_max(p0, scale, factor, slack):
     return _tile_max(_times_row_scale(p0, scale)) + factor * slack
 
 
+def _pass0_bound(tile, u0, slack):
+    """[1, B]: per user a number no smaller than the maximum of the exact
+    block ``_tile_scores(u, tile)`` of a float32 ``tile`` [T, k], from ONE
+    bfloat16 pass — the maximum of P0 = bf(tile) . ``u0`` (both operands
+    rounded to bfloat16, float32 accumulation) plus what the call's two
+    slack rows (``float_slack``: ``slack`` [2, 1, B], an array or the
+    kernel's ref) make of the tile's largest |entry|.
+
+    Why it bounds.  The model of ``_bound_max``: round to nearest, eps =
+    2^-24, finite operands, nothing in the subnormal range; 2 < k < 2^12.
+    For row v of the tile and user u let v0 = bf(v), u0 = bf(u), entry by
+    entry, a = max |v_i| over the tile and h = 2^(floor(log2 a) - 8), half
+    the spacing of bfloat16 numbers at a: |v_i - v0_i| <= h for every
+    entry (the spacing does not shrink as a magnitude grows), h <= 2^-8 a,
+    |v0_i| <= (1 + 2^-8) a.
+      v.u - v0.u0 = (v - v0).u + v0.(u - u0), so
+      |v.u - v0.u0| <= h ||u||_1 + (1 + 2^-8) a ||u - u0||_1.
+    P0 adds the k exact products v0_i u0_i in float32 in some order:
+      |P0 - v0.u0| <= k eps (1 + 2^-8)^2 a ||u||_1 <= 1.01 k eps a ||u||_1.
+    The exact block's entry E is ``dot_general`` at ``Precision.HIGHEST``:
+    a float32 sum, in some order, of k products v_i u_i each rounded once,
+    or of the 6 k exact products of the operands' bfloat16 pieces with the
+    three smallest kinds left out (v1 u2, v2 u1, v2 u2: under 2.1 eps |v_i
+    u_i| together; the six kept are under 1.017 |v_i u_i| in magnitude):
+      |E - v.u| <= (6.11 k + 2.1) eps a ||u||_1 <= 8 k eps a ||u||_1.
+    Together, for every row, |E - P0| <= h ||u||_1 + a Lam_b, Lam_b = (1 +
+    2^-8) ||u - u0||_1 + 9.01 k eps ||u||_1, so max_j E_j <= max_j P0_j +
+    h ||u||_1 + a Lam_b; a maximum is exact, and so are a and h (a's
+    exponent bits, times 2^-8).  What is computed here is G = fl(M + fl(fl(h
+    N) + fl(a L))), M = max_j P0_j, |M| <= 1.01 a ||u||_1, N and L the
+    slack rows:
+      G >= M + (h N + a L)(1 - eps)^3 - 1.01 eps a ||u||_1
+        >= M + h ||u||_1 + a Lam_b
+    once N >= ||u||_1 / (1 - eps)^3 and L >= (Lam_b + 1.01 eps ||u||_1) /
+    (1 - eps)^3.  ``float_slack``'s L has (10 k + 8) eps where that has
+    (9.01 k + 1.01), and both rows have their norms summed in float32
+    (each >= (1 - eps)^(k-1) of the true one; u - u0 is exact) and a few
+    roundings of their own, all inside (1 + rho), rho = (2 k + 32) eps.
+    So G >= max_j E_j, and as in ``_bound_max`` the masks only lower a
+    maximum and a stale K-th score only rises: **the gate on G may open
+    where the exact gate is shut, never the other way.**  What the form
+    costs and what else was weighed (2^-8 a for h; the tile's largest row
+    norm against 2-norms of u; norms kept as device state): PERF.md
+    section 6, PR 50.  On the benchmark's tables h ||u||_1 + a L is ~8e-3
+    against K-th scores near 0.53."""
+    p0 = _bf16_pass(tile.astype(jnp.bfloat16), u0)
+    # a down the lanes first: its exponent is then read in one register
+    reach = jnp.max(jnp.abs(tile), axis=0, keepdims=True)  # [1, k]
+    half = lax.bitcast_convert_type(
+        lax.bitcast_convert_type(reach, jnp.int32) & 0x7F800000,
+        jnp.float32) * 2.0 ** -8
+    reach, half = (jnp.max(x, axis=1, keepdims=True) for x in (reach, half))
+    return _tile_max(p0) + (half * slack[0] + reach * slack[1])
+
+
 def _tile_scores(u, tile, scale):
-    """The [T, B] float32 score block of one tile whose passes are not
-    deferred, movie-major: ``tile`` [T, k] (f32 / bf16) against ``u``
-    [B, k] in one ``dot_general``.  An int8 tile comes here only under the
+    """The [T, B] float32 score block of one tile in one ``dot_general``,
+    movie-major: ``tile`` [T, k] (f32 / bf16) against ``u`` [B, k] — a
+    bfloat16 tile's one pass, a float32 tile's exact block behind its
+    first gate.  An int8 tile comes here only under the
     controls' one-pass arithmetic (``serve_compute_dtype`` patched): the
     dequantized tile and piece 0 of ``u`` [3, B, k], each rounded to the
     compute dtype; ``scale`` f32 [T, 1] or [T, L] with the row's scale in
@@ -427,7 +535,7 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     The per-tile math as one function of one tile: what the XLA twin scans
     (``compat.emulate_topk_counted``).  The Mosaic kernel body runs the same
     pieces (``_tile_scores``, ``_code_pass``, ``_bound_max``,
-    ``_complete_scores``, ``_mask_scores``, ``_entrant``,
+    ``_pass0_bound``, ``_complete_scores``, ``_mask_scores``, ``_entrant``,
     ``_select_round``, ``_tile_counts``) on the same tiles in the same
     order, the score blocks of a few tiles ahead of their gates
     (``_topk_kernel``).  Everything is MOVIE-MAJOR ([T, B] scores,
@@ -439,7 +547,8 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
 
     ``carry_v`` [K, B] f32, ``carry_i`` [K, B] int32 (−1 empty), ``u`` as
     ``resident_operand`` makes it — [B, k]; for an int8 tile the three
-    bfloat16 pieces [3, B, k] with the slack row, ``(pieces, slack)`` —
+    bfloat16 pieces [3, B, k] with the slack row, ``(pieces, slack)``; for
+    a float32 tile ``(u, u0, slack)`` —
     ``tile`` [T, k] (f32/bf16/int8), ``scale`` f32 [T, 1] or [T, L] with
     the row's scale in every column (``_times_row_scale``), or None,
     ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot j < the
@@ -454,21 +563,23 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     nothing: its slot rows are all T, which no row equals, and none of its
     rows is padding.
 
-    **An int8 tile** (``deferred_passes``) runs pass 0 alone, and
-    everything else only behind its first gate: some user's
-    ``_bound_max`` — pass 0's maximum plus a proven bound of what passes
-    1 and 2 can add — strictly above ``gate_kth`` [1, B], the K-th scores
-    the gate reads: the kernel reads them as of the tile's group's start,
-    and the twin hands the same row in (no fold without deferred passes
-    reads it).  Where
-    that gate is shut no row of the tile can enter any top-K
-    (``_bound_max``'s argument) and the tile costs nothing more: no
-    passes 1 and 2, no masks (a mask only lowers a maximum), no rounds.
-    Where it is open the block is completed to the bit of three passes
-    on every tile (``_complete_scores``), masked if the tile is hit or
-    the table's last, and folded as any other: the result and the
-    selection's counts do not depend on the gate, the exclusion counts
-    say what ran.
+    **An int8 or a float32 tile** (``deferred_passes``) runs pass 0
+    alone, and everything else only behind its first gate: some user's
+    bound — pass 0's maximum plus a proven bound of what the exact block
+    can hold above it: ``_bound_max`` for int8 codes (what passes 1 and 2
+    can add), ``_pass0_bound`` for a float32 tile (how far one bfloat16
+    pass can lie under the block at ``Precision.HIGHEST``) — strictly
+    above ``gate_kth`` [1, B], the K-th scores the gate reads: the kernel
+    reads them as of the tile's group's start, and the twin hands the same
+    row in (no fold without deferred passes reads it).  Where that gate
+    is shut no row of the tile can enter any top-K (the bounds' argument)
+    and the tile costs nothing more: no further pass, no masks (a mask
+    only lowers a maximum), no rounds.  Where it is open the block is the
+    exact one to the bit — an int8 tile's completed from pass 0's sums
+    (``_complete_scores``), a float32 tile's the one ``dot_general`` it
+    always was (``_tile_scores``) — masked if the tile is hit or the
+    table's last, and folded as any other: the result and the selection's
+    counts do not depend on the gate, the exclusion counts say what ran.
 
     The carry is SORTED: scores descending, equal scores by ascending
     global row, empty slots (−inf / −1) at the tail — so its last row is
@@ -512,13 +623,20 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
                          carry_v, carry_i)
 
     def fold_deferred():
-        pieces, slack = u
-        p0 = _code_pass(pieces, tile.astype(jnp.bfloat16), 0)
-        factor = jnp.max(jnp.abs(scale), axis=0, keepdims=True)[:, :1]
-        opened = _entrant(_bound_max(p0, scale, factor, slack), gate_kth)
+        if tile.dtype == jnp.int8:
+            pieces, slack = u
+            p0 = _code_pass(pieces, tile.astype(jnp.bfloat16), 0)
+            factor = jnp.max(jnp.abs(scale), axis=0, keepdims=True)[:, :1]
+            bound = _bound_max(p0, scale, factor, slack)
+            complete = lambda: _complete_scores(p0, pieces, tile, scale)
+        else:
+            u_full, u0, slack = u
+            bound = _pass0_bound(tile, u0, slack)
+            complete = lambda: _tile_scores(u_full, tile, scale)
+        opened = _entrant(bound, gate_kth)
 
         def turn():
-            scores = _complete_scores(p0, pieces, tile, scale)
+            scores = complete()
             if needs_mask is True:
                 scores = masked_scores(scores)
             else:
@@ -528,7 +646,7 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
 
         cv, ci, rounds = lax.cond(
             opened, turn,
-            lambda: (carry_v, carry_i, match_varying(jnp.int32(0), p0)))
+            lambda: (carry_v, carry_i, match_varying(jnp.int32(0), bound)))
         opened = opened.astype(jnp.int32)
         return cv, ci, rounds, hit * opened, opened
 
@@ -731,7 +849,7 @@ def _vmem_bytes(g, batch, seen_width, rank, table_dtype, *, tile_m, k_top):
 
 
 def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
-                 with_seen, with_scale, defers):
+                 with_seen, with_scale, resident):
     """Grid step i: fold the slab of movie tiles [i·G, min((i+1)·G, NT))
     into the resident [K, B] carry, a group of P tiles at a time.
 
@@ -758,17 +876,22 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     clipped at the table's end and the tiles past NT are scored (whatever
     the buffers hold) and never folded, masked or counted.
 
-    Where passes are deferred (``defers``: an int8 table, whose ``u`` is
-    the three pieces and whose slack row ``slack_ref`` [1, B] follows it)
-    the straight-line block runs pass 0 alone: ``sc_ref`` takes its raw
-    sums, and the first gate is read from ``_bound_max``, which may open
-    where the exact gate is shut and never the other way by the argument
-    written there.  A tile whose first gate is open is completed in its
-    turn from the slab still in VMEM (``_complete_scores``: passes 2 and 1
-    and the sums of pass 0 in scratch, to the bit of three passes run
-    together), masked if it is hit or the table's last, and given its
-    rounds; one whose gate is shut gets nothing more, no pass, no mask, no
-    round.  The completion is traced once a program, inside the turn.
+    Where passes are deferred (``resident`` > 1: ``resident_operand``'s
+    parts are the first operands, ``u`` and then what pass 0 reads — an
+    int8 table's slack row [1, B] behind the three pieces, a float32
+    table's ``u0`` and its two slack rows [2, 1, B] behind ``u``) the
+    straight-line block runs pass 0 alone and the first gate is read from
+    ``_bound_max`` / ``_pass0_bound``, which may open where the exact gate
+    is shut and never the other way by the arguments written there.  An
+    int8 tile's raw sums go to ``sc_ref``; a float32 tile's are dropped
+    (its block cannot use them).  A tile whose first gate is open is
+    completed in its turn from the slab still in VMEM (``_complete_scores``:
+    passes 2 and 1 and the sums of pass 0 in scratch, to the bit of three
+    passes run together; a float32 tile: ``_tile_scores``, the one
+    ``dot_general`` of the body without a first gate), masked if it is hit
+    or the table's last, and given its rounds; one whose gate is shut gets
+    nothing more, no pass, no mask, no round.  The completion is traced
+    once a program, inside the turn.
 
     The carry is two VMEM scratch blocks: step 0 initializes them, every
     tile merges into them, the last step copies the final state to the
@@ -787,8 +910,10 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     """
     refs = list(refs)
     hits_ref = refs.pop(0) if with_seen else None
-    u_ref = refs.pop(0)
-    slack_ref = refs.pop(0) if defers else None
+    # ``resident_operand``'s parts: u, and where passes are deferred what
+    # pass 0 reads beside it and the first gate's slack
+    u_ref, *first_refs = (refs.pop(0) for _ in range(resident))
+    defers = bool(first_refs)
     tbl_ref = refs.pop(0)
     scale_ref = refs.pop(0) if with_scale else None
     seen_ref = refs.pop(0) if with_seen else None
@@ -868,8 +993,11 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
 
         def turn():
             if defers:
-                sc = _complete_scores(sc_ref[j], u_ref[...], tile_rows(s),
-                                      scale_rows(s))
+                if with_scale:
+                    sc = _complete_scores(sc_ref[j], u_ref[...],
+                                          tile_rows(s), scale_rows(s))
+                else:
+                    sc = _tile_scores(u_ref[...], tile_rows(s), None)
                 sc_ref[j] = sc
                 ms_ref[j] = _tile_max(sc)
                 pl.when(needs_mask)(mask)
@@ -890,20 +1018,27 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
 
         def score_tile(j, _):  # nothing here reads what a tile before wrote
             s = first + j
-            if defers:
+            if defers and with_scale:
                 # pass 0 alone: its raw sums, and what the exact block's
                 # maximum cannot pass
+                slack_ref, = first_refs
                 sc = _code_pass(u_ref[...],
                                 tile_rows(s).astype(jnp.bfloat16), 0)
                 factor = jnp.max(jnp.abs(scale_ref[s]), axis=1,
                                  keepdims=True)
                 ms = _bound_max(sc, scale_rows(s), factor, slack_ref[...])
+                sc_ref[j] = sc
+            elif defers:
+                # a float32 tile's one bfloat16 pass: nothing of it is
+                # kept, the block behind the gate cannot use its sums
+                u0_ref, slack_ref = first_refs
+                ms = _pass0_bound(tile_rows(s), u0_ref[...], slack_ref)
             else:
                 sc = _tile_scores(u_ref[...], tile_rows(s),
                                   scale_rows(s) if with_scale else None)
                 ms = _tile_max(sc)
                 ms_ref[j] = ms
-            sc_ref[j] = sc
+                sc_ref[j] = sc
             gate_ref[j] = _entrant(ms, kth).astype(jnp.int32)
             return _
 
@@ -959,11 +1094,11 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     movie rows, counts)``, counts [5] int32 = selection rounds run over the
     table's tiles, tiles that ran at least one (of ``M_pad / tile_m``),
     exclusion chunks run (``_SEEN_CHUNK`` compares each: the rectangle's
-    width on every tile that holds a cell — on an int8 table, on those of
-    them whose first gate opened) and tiles that ran them, and tiles
-    completed (every pass run: all of them, but on an int8 table those
-    whose first gate opened).  What ``ServeEngine.topk`` puts on its
-    compute span.  ``seen_tiles`` is a
+    width on every tile that holds a cell — on an int8 or a float32
+    table, on those of them whose first gate opened) and tiles that ran
+    them, and tiles completed (every pass run: all of a bfloat16 table's,
+    of an int8 or a float32 table's those whose first gate opened).  What
+    ``ServeEngine.topk`` puts on its compute span.  ``seen_tiles`` is a
     ``SeenTiles`` (``scatter_seen_cells``) or a bare [NT, B, W] rectangle
     (``as_seen_tiles``)."""
     b, k = u.shape
@@ -1006,11 +1141,12 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             num_movies=num_movies, tile_m=tile_m, row_offset=row_offset,
         )
     # u is the resident operand: what the fold needs of it is made here,
-    # once a call (an int8 table's three bfloat16 pieces, and the slack
-    # row of the gate its deferred passes wait behind)
-    defers = bool(deferred_passes(table.dtype))
+    # once a call (an int8 table's three bfloat16 pieces, a float32
+    # table's first piece, and the slack of the gate the deferred passes
+    # wait behind)
     u = resident_operand(u, table.dtype)
-    u, slack = u if defers else (u, None)
+    ops = list(u) if isinstance(u, tuple) else [u]  # resident
+    resident = len(ops)
     seen_width = 0 if seen_tiles is None else slots.shape[2]
     # G tiles a grid step, from the shapes (``slab_tiles``)
     g = slab_tiles(nt, b, seen_width, k, table.dtype, tile_m=tile_m,
@@ -1020,11 +1156,8 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     # row offset and, with exclusion, the tiles' hits.  Where G does not
     # divide NT the last step's blocks reach past the arrays and are
     # clipped; the kernel folds the tiles there are.
-    in_specs = [pl.BlockSpec(u.shape, lambda i, *_: (0,) * u.ndim)]
-    ops = [u]  # resident
-    if defers:
-        in_specs.append(pl.BlockSpec(slack.shape, lambda i, *_: (0, 0)))
-        ops.append(slack)
+    in_specs = [pl.BlockSpec(r.shape, lambda i, *_, n=r.ndim: (0,) * n)
+                for r in ops]
     in_specs.append(pl.BlockSpec((g * tile_m, k), lambda i, *_: (i, 0)))
     ops.append(table)  # streamed in slabs
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
@@ -1075,7 +1208,7 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         functools.partial(
             _topk_kernel, t=tile_m, g=g, p=p, nt=nt, k_top=k_top,
             num_movies=num_movies, b=b, with_seen=seen_tiles is not None,
-            with_scale=scale is not None, defers=defers,
+            with_scale=scale is not None, resident=resident,
         ),
         grid_spec=grid_spec,
         out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32),

@@ -392,6 +392,24 @@ def test_serve_scorer_int8_cells_deferred_passes(chip, b):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("b", [128, 256])
+def test_serve_scorer_float32_cells_first_gate(chip, b):
+    """The float32 cells' call at the two batch buckets a saturated window
+    fills (9.35 M x 128 rows, 18,262 tiles, 16 slots): one bfloat16 pass
+    and the first gate's bound in the straight-line block (the tile turned
+    to bfloat16, its largest |entry| down the sublanes and across the
+    lanes, that entry's exponent bits, two [1, 1] factors times the two
+    [1, B] slack rows), the block at ``Precision.HIGHEST`` from the slab
+    still in VMEM inside the tile's turn, the masks nested behind that
+    gate: one Mosaic call, ``u``'s bfloat16 piece and the slack rows made
+    outside it, and nothing the size of the table beside it."""
+    compiled = _compile_scorer(chip, f32, b=b, k_top=16, w=16, m=9_350_000)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "s32[18262]" in text
+    assert f"bf16[{b},128]" in text and f"f32[2,1,{b}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("g", [1, 2, 4, 8, 16])
 @pytest.mark.parametrize("dtype", [f32, i8])
 def test_serve_scorer_every_rung_of_the_slab_ladder(chip, monkeypatch, dtype,

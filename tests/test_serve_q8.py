@@ -386,7 +386,7 @@ def test_three_passes_are_a_float32_sum_and_each_piece_is_needed(
 
 @pytest.mark.parametrize("shards", [None, 2])
 @pytest.mark.parametrize("table_dtype,passes", [
-    ("int8", 3), ("float32", 6), ("bfloat16", 1)])
+    ("int8", 3), ("float32", 7), ("bfloat16", 1)])
 def test_compute_span_says_how_many_passes_a_tile_takes(table_dtype, passes,
                                                         shards):
     uf, mf, lists, rows = _problem(10)
@@ -403,14 +403,15 @@ def test_compute_span_says_how_many_passes_a_tile_takes(table_dtype, passes,
     assert compute["table_dtype"] == table_dtype
     assert compute["score_passes"] == passes
     assert compute.get("shards") == shards
-    # the tiles every one of those passes ran on: all of a float table's,
-    # of an int8 table's those whose first gate opened (all shards')
+    # the tiles every one of those passes ran on: all of a bfloat16
+    # table's, of an int8 or a float32 table's those whose first gate
+    # opened (all shards')
     assert compute["select_tiles"] <= compute["completed_tiles"]
     assert compute["seen_hit_tiles"] <= compute["completed_tiles"]
-    if table_dtype == "int8":
-        assert compute["completed_tiles"] <= compute["tiles"]
-    else:
+    if table_dtype == "bfloat16":
         assert compute["completed_tiles"] == compute["tiles"]
+    else:
+        assert compute["completed_tiles"] <= compute["tiles"]
 
 
 # -- passes 1 and 2 behind a gate that pass 0 decides (ISSUE 48) -------------
@@ -598,11 +599,12 @@ def _dots(jaxpr):
 
 @pytest.mark.parametrize("route", ["kernel", "twin"])
 @pytest.mark.parametrize("table_dtype,dots", [
-    ("float32", 1), ("bfloat16", 1), ("int8", 3)])
-def test_float_tables_defer_nothing(table_dtype, dots, route):
-    """A float32 or bfloat16 tile is one ``dot_general``, in the fold's
-    masked and unmasked branch each, and every tile is completed; an int8
-    tile is pass 0 and, behind the gate, passes 2 and 1."""
+    ("float32", 2), ("bfloat16", 1), ("int8", 3)])
+def test_which_tables_defer_what(table_dtype, dots, route):
+    """A bfloat16 tile is one ``dot_general``, in the fold's masked and
+    unmasked branch each, and every tile is completed; an int8 tile is
+    pass 0 and, behind the gate, passes 2 and 1; a float32 tile (since
+    ISSUE 50) its one bfloat16 pass and, behind the gate, the block."""
     from cfk_tpu.compat import emulate_topk_counted
 
     uf, mf, lists, _ = _problem(48, m=35 * TILE - 5, users=8)
@@ -615,13 +617,14 @@ def test_float_tables_defer_nothing(table_dtype, dots, route):
           else emulate_topk_counted)
     call = lambda u, st: fn(u, data, scale, st, k_top=5,
                             num_movies=mf.shape[0], tile_m=TILE)
-    assert topk_kernel.deferred_passes(data.dtype) == (
-        (1, 2) if table_dtype == "int8" else ())
+    assert topk_kernel.deferred_passes(data.dtype) == {
+        "int8": (1, 2), "float32": (1, 2, 3, 4, 5, 6), "bfloat16": ()}[
+            table_dtype]
     counts = np.asarray(call(jnp.asarray(uf[:8]), st)[2])
-    if table_dtype == "int8":
-        assert counts[1] <= counts[4] < 35
-    else:
+    if table_dtype == "bfloat16":
         assert counts[4] == 35
+    else:
+        assert counts[1] <= counts[4] < 35
     for seen, branches in ((None, 1), (st, 2)):
         n = _dots(jax.make_jaxpr(call)(jnp.asarray(uf[:8]), seen).jaxpr)
         # the kernel masks a float tile in place, the twin's fold has a

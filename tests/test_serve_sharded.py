@@ -308,8 +308,11 @@ def test_spans_of_a_sharded_engine():
     # and the gated exclusion's: chunks run, tiles that ran any
     assert 1 <= compute["seen_hit_tiles"] <= compute["tiles"]
     assert compute["seen_hit_tiles"] <= compute["seen_chunks"]
-    # a float32 table completes every tile of every shard
-    assert compute["completed_tiles"] == compute["tiles"]
+    # a float32 table completes the tiles whose first gate opened, shard
+    # by shard: at least those that ran a round or a mask
+    assert (max(compute["select_tiles"], compute["seen_hit_tiles"])
+            <= compute["completed_tiles"] <= compute["tiles"])
+    assert compute["score_passes"] == 7
     # what the scorer streams for the batch, all four shards'
     assert compute["table_dtype"] == "float32"
     assert compute["scan_bytes"] == 1024 * RANK * 4
@@ -334,9 +337,12 @@ def test_spans_of_a_sharded_engine():
         "seen_hit_tiles", "completed_tiles", "tiles", "table_dtype",
         "scan_bytes",
         "score_passes", "slab_tiles", "grid_steps"}
-    assert {x: events["serve/batch/compute"]["args"][x]
-            for x in ("seen_chunks", "seen_hit_tiles")} == {
-        x: compute[x] for x in ("seen_chunks", "seen_hit_tiles")}
+    # a shard's first gate reads its own K-th scores, which lie no higher
+    # than one device's at the same tile: it opens, and masks, no fewer
+    one = events["serve/batch/compute"]["args"]
+    for x in ("seen_chunks", "seen_hit_tiles", "completed_tiles"):
+        assert 1 <= one[x] <= compute[x]
+    assert one["completed_tiles"] < one["tiles"]
     # 63 tiles on one device: three steps of 16 and a ragged one of 15
     assert events["serve/batch/compute"]["args"]["tiles"] == 63
     assert events["serve/batch/compute"]["args"]["slab_tiles"] == 16
@@ -374,6 +380,61 @@ def test_slab_counts_on_the_span_over_shards(case, shards):
     assert compute["grid_steps"] == shards * -(-per // g)
     assert compute["tiles"] / compute["grid_steps"] <= g
     assert compute["seen_hit_tiles"] <= compute["tiles"]
+
+
+@pytest.mark.parametrize("shards", SHARDS)
+@pytest.mark.parametrize("data_case", ["lattice", "late_ulp"])
+def test_float_gate_over_shards_answers_as_one_device(data_case, shards):
+    """A float32 table's first gate (ISSUE 50) shard by shard: each shard
+    reads its own K-th scores and its own tiles' largest entries, no
+    collective is added, and the merged answer is the one-device engine's
+    and the dense float32 oracle's to the bit — on a table whose later
+    tiles score lower, so that gates shut on every shard, and on one whose
+    only entrant past the first rows lies one ulp over the K-th score in
+    the last shard, where pass 0 cannot see it."""
+    from tests.test_serve_f32_gate import _dense_oracle, _lattice
+
+    rng = np.random.default_rng(50 + shards)
+    users, m, k = 24, 64 * TILE - 5, 9
+    uf, mf = _lattice(rng, (users, 16)), _lattice(rng, (m, 16))
+    if data_case == "lattice":
+        mf *= (2.0 ** -(np.arange(m) // (4 * TILE) % 4)).astype(
+            np.float32)[:, None]
+    else:
+        mf[:], uf[:] = 0.0, 0.0
+        mf[:, 0], uf[:, 0] = 0.5, 1.0
+        mf[:, 1], uf[:, 1] = 512 / 1024.0, 2.0 ** -14
+        mf[60 * TILE + 3, 1] = 513 / 1024.0
+    lists = [np.sort(rng.choice(m, int(rng.integers(0, 30)), replace=False))
+             for _ in range(users)]
+    rows = rng.integers(0, users, size=13)
+    want_v, want_i = _dense_oracle(uf[rows], mf, [lists[r] for r in rows], m,
+                                   k)
+    computes = []
+    for n in (None, shards):
+        tracer = telemetry.configure()
+        try:
+            vals, ids = _engine(uf, mf, lists, shards=n).topk(rows, k)
+            computes.append(next(e["args"] for e in tracer.events()
+                                 if e["name"] == "serve/batch/compute"))
+        finally:
+            telemetry.shutdown(write=False)
+        np.testing.assert_array_equal(ids, want_i)
+        np.testing.assert_array_equal(vals, want_v)
+    one, over = computes
+    assert one["score_passes"] == over["score_passes"] == 7
+    for c in (one, over):
+        assert (max(c["select_tiles"], c["seen_hit_tiles"])
+                <= c["completed_tiles"] <= c["tiles"] == 64)
+    # a shard's K-th scores lie no higher than one device's at the same
+    # tile, so its gate opens no fewer
+    assert one["completed_tiles"] <= over["completed_tiles"]
+    if data_case == "lattice":
+        assert over["completed_tiles"] < 64
+    else:
+        late = 60 * TILE + 3
+        assert all(late in row for row, r in zip(ids, rows)
+                   if late not in lists[r])
 
 
 @pytest.mark.parametrize("shards", SHARDS)
