@@ -842,6 +842,13 @@ def _serve(args) -> int:
     instead: N replicas behind the request log (one partition each,
     user-keyed routing), each with its own /metrics + /readyz and
     optional admission control (``--admission-queue``).
+
+    ``--item-departments FILE`` (one int an item row: text, or ``.npy``)
+    gives every item its department: the table is laid out by department, a
+    request that names one (``ServeClient.request(user, k, department=)``)
+    is answered with the exact top-K among that department's items, and a
+    ``--broker``'s requests topic is keyed by department.  Without the
+    file the server serves as it did.
     """
     with _telemetry_session(args):
         return _serve_impl(args)
@@ -890,10 +897,18 @@ def _serve_impl(args) -> int:
         num_movies=ds.movie_map.num_entities,
     )
 
+    departments = None
+    if args.item_departments:
+        departments = (np.load(args.item_departments)
+                       if args.item_departments.endswith(".npy")
+                       else np.loadtxt(args.item_departments, dtype=np.int64,
+                                       ndmin=1))
+
     def build_engine():
         return engine_from_model(
             model, None if args.include_seen else ds,
             table_dtype=args.table_dtype, tile_m=args.tile_m,
+            item_department=departments,
         )
 
     engine = build_engine()
@@ -952,6 +967,8 @@ def _serve_impl(args) -> int:
         ensure_serve_topics(
             transport, request_partitions=args.request_partitions,
             response_partitions=args.response_partitions,
+            departments=(None if departments is None
+                         else int(departments.max()) + 1),
         )
         session, new_session = None, None
         if args.stream_dir:
@@ -1011,7 +1028,7 @@ def _serve_impl(args) -> int:
         import json
 
         fleet = _fleet(transport).start()
-        client = ServeClient(transport, route_by_user=True)
+        client = ServeClient(transport, route="user")
         pool = zipf_user_rows(
             ds.user_map.num_entities, args.loadgen_requests, seed=args.seed
         )
@@ -1802,6 +1819,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="fleet admission-control queue depth per poll "
                     "(0 = unbounded); backlog beyond it is answered "
                     "with explicit RETRIABLE rejections, never dropped")
+    sv.add_argument("--item-departments", default=None, metavar="FILE",
+                    help="one int an item row (text, or .npy): the item's "
+                         "department; a request may then name a department "
+                         "and is answered among its items alone")
     sv.add_argument("--request-partitions", type=int, default=1)
     sv.add_argument("--response-partitions", type=int, default=1)
     sv.add_argument("--stream-dir", default=None, metavar="DIR",

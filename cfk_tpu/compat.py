@@ -90,7 +90,7 @@ def emulate_topk_scores(u, table, scale, seen_tiles, *, k_top, num_movies,
 
 
 def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
-                         tile_m, row_offset=0):
+                         tile_m, row_offset=0, rows=None, grid_tiles=None):
     """XLA twin of ``serving.topk_kernel.topk_scores_counted``: (scores,
     movie rows, [selection rounds, tiles that ran one, exclusion chunks,
     tiles that ran one, tiles completed]).
@@ -110,6 +110,12 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     per-step block is [B, tile_m]: no [B, num_movies] score matrix is ever
     materialized here either (the emulation-path memory check in the tests
     compiles this and bounds its temp memory below B·M·4 bytes).
+
+    A ranged scan (``rows`` = ``(row_lo, row_hi)``, ``grid_tiles``: see
+    ``topk_scores_counted``) scans the ``grid_tiles`` tiles the kernel's
+    grid runs, from the first tile of the range's first slab, and folds
+    those that hold a row of the range, the range's bounds in the masks'
+    compares: the kernel's tiles, order, gates and counts.
     """
     import jax.numpy as jnp
 
@@ -136,9 +142,10 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         width = seen.shape[1]
     # the tiles the kernel scores ahead of their gates (its P): a deferring
     # fold's first gate reads the K-th scores as of the group's start
-    group = min(topk_kernel._GROUP_TILES, topk_kernel.slab_tiles(
+    slab = topk_kernel.slab_tiles(
         nt, b, width, table.shape[1], table.dtype, tile_m=tile_m,
-        k_top=k_top))
+        k_top=k_top)
+    group = min(topk_kernel._GROUP_TILES, slab)
     carry0 = jax.tree.map(
         lambda z: match_varying(z, table),
         (jnp.full((k_top, b), -jnp.inf, jnp.float32),
@@ -148,25 +155,47 @@ def emulate_topk_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     )
 
     off = jnp.asarray(row_offset, jnp.int32)
+    if rows is not None:
+        row_lo, row_hi = (jnp.asarray(r, jnp.int32) for r in rows)
+        # the table's tile of the rectangle's (and the scan's) tile 0
+        first = topk_kernel.range_slabs(row_lo, row_hi, slab, tile_m)[0] * slab
+        # the range's bounds take the table's end's place in the masks
+        bounds = {"num_movies": row_hi, "row_lo": row_lo}
+    else:
+        bounds = {"num_movies": num_movies}
 
     def step(carry, i):
-        idx = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-        seen_i = None if seen is None else idx(seen)
+        # ``i`` is the tile's place in the scan, ``n`` in the table
+        n = i if rows is None else jnp.minimum(first + i, nt - 1)
+        at = lambda a, j: lax.dynamic_index_in_dim(a, j, 0, keepdims=False)
+        seen_i = None if seen is None else at(seen, i)
         gate_kth = jnp.where(i % group == 0, carry[0][k_top - 1:], carry[3])
-        v, ids, counts = topk_kernel._score_tile_fold(
-            carry[0], carry[1], u, idx(tbl),
-            None if sc is None else idx(sc)[:, None],
-            None if seen is None else (
-                lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
-            ),
-            width, None if seen is None else idx(hits), off + i * tile_m,
-            tile_m=tile_m, num_movies=num_movies, k_top=k_top,
-            gate_kth=gate_kth,
-        )
-        return (v, ids, carry[2] + jnp.stack(counts), gate_kth), None
+        tile_base = off + (i if rows is None else first + i) * tile_m
+
+        def fold():
+            v, ids, counts = topk_kernel._score_tile_fold(
+                carry[0], carry[1], u, at(tbl, n),
+                None if sc is None else at(sc, n)[:, None],
+                None if seen is None else (
+                    lambda j: lax.dynamic_slice_in_dim(seen_i, j, 1, 0)
+                ),
+                width, None if seen is None else at(hits, i), tile_base,
+                tile_m=tile_m, k_top=k_top, gate_kth=gate_kth, **bounds,
+            )
+            return v, ids, carry[2] + jnp.stack(counts)
+
+        if rows is None:
+            out = fold()
+        else:
+            # a tile that holds no row of the range is not there
+            out = lax.cond(
+                (tile_base + tile_m > row_lo) & (tile_base < row_hi),
+                fold, lambda: carry[:3])
+        return (*out, gate_kth), None
 
     (vals, ids, counts, _), _ = lax.scan(
-        step, carry0, jnp.arange(nt, dtype=jnp.int32))
+        step, carry0,
+        jnp.arange(nt if rows is None else grid_tiles, dtype=jnp.int32))
     return vals.T, ids.T, counts
 
 

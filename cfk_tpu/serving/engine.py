@@ -42,6 +42,17 @@ user rows" and "[B, K] ids+scores":
   section 6, PR 42).  A list past the top rung runs the top program
   again on its own result, so ``prewarm``'s batch-size ladder, walked
   over the rungs, closes the program set whatever the data,
+- departments (``item_department=``, one int an item row): the table is
+  laid out sorted by department, a stable permutation of the item rows
+  (none where they come sorted), so that a department is one range of
+  rows, ``department_range``; the seen lists are mapped into the layout
+  once at load and every answer is given in the caller's item rows.  A
+  batch that names a department (``stage(..., department=)``) is scored
+  over that range alone: the exclusion rectangle is built over the range's
+  tiles, padded to a rung of a short ladder of lengths (powers of two of
+  the scorer's slabs), and the scorer's grid runs that rung
+  (``topk_scores_counted(rows=, grid_tiles=)``); a batch that names none
+  scans the whole table as before, in the same engine,
 - a batch in two halves (``TopKBatch``): ``stage`` gathers, groups and
   uploads and captures the table; ``compute`` hands one batch to the
   device and fetches another's answer, the same one for ``topk``, the one
@@ -67,6 +78,8 @@ from cfk_tpu.serving.topk_kernel import (
     score_passes,
     seen_cell_capacity,
     seen_piece_rung,
+    range_slabs,
+    range_tiles,
     slab_tiles,
     topk_scores_counted,
 )
@@ -144,6 +157,7 @@ class ServeEngine:
         batch_quantum: int = 8,
         mesh=None,  # the caller's own; or
         shards: int | None = None,  # a mesh over the first `shards` devices
+        item_department=None,  # [num_movies] ints: each item row's
     ) -> None:
         from cfk_tpu.config import enable_compile_cache
         from cfk_tpu.ops.quant import resolve_table_dtype
@@ -171,13 +185,20 @@ class ServeEngine:
             raise ValueError(
                 "pass both of seen_movies/seen_indptr or neither"
             )
-        self._seen_movies = (
-            None if seen_movies is None
-            else np.asarray(seen_movies, np.int32)
-        )
+        # The table's layout: row r of it is item row ``_to_item[r]``, item
+        # row i lies at ``_to_layout[i]`` (both None: the item order), and
+        # department d is the layout's rows ``_ranges[d]``.
+        self._to_item = self._to_layout = None
+        self._ranges: dict[int, tuple[int, int]] = {}
+        if item_department is not None:
+            self._lay_out(item_department)
         self._seen_indptr = (
             None if seen_indptr is None
             else np.asarray(seen_indptr, np.int64)
+        )
+        self._seen_movies = (
+            None if seen_movies is None
+            else self._seen_in_layout(np.asarray(seen_movies, np.int32))
         )
         # row -> the items the stream added to the user's list, ascending,
         # none of them in the base slice (``_extend_seen``)
@@ -231,16 +252,86 @@ class ServeEngine:
             if epoch is not None:
                 self.epoch = int(epoch)
 
+    # -- departments ---------------------------------------------------------
+
+    def _lay_out(self, item_department) -> None:
+        """The layout by department: a stable sort of the item rows by their
+        department, the identity (no permutation is kept, no row moves)
+        where they come sorted, which is looked at, not assumed."""
+        dept = np.asarray(item_department)
+        if (dept.shape != (self.num_movies,)
+                or not np.issubdtype(dept.dtype, np.integer)
+                or (dept.size and dept.min() < 0)):
+            raise ValueError(
+                "item_department is one non-negative int an item row, "
+                f"[{self.num_movies}]; got {dept.dtype} {dept.shape}")
+        if self.mesh is not None:
+            raise ValueError(
+                "departments are ranges of one device's table: over a mesh "
+                f"of {self._shards} devices a range crosses shards, each of "
+                "which would scan its own piece of it (ROADMAP Queue 2: "
+                "not built); serve departments from one chip, or the "
+                "sharded table without them")
+        if dept.size > 1 and np.any(dept[1:] < dept[:-1]):
+            self._to_item = np.argsort(dept, kind="stable").astype(np.int32)
+            self._to_layout = np.empty_like(self._to_item)
+            self._to_layout[self._to_item] = np.arange(
+                dept.size, dtype=np.int32)
+            dept = dept[self._to_item]
+        ids, starts = np.unique(dept, return_index=True)
+        ends = np.append(starts[1:], dept.size)
+        self._ranges = {int(d): (int(lo), int(hi))
+                        for d, lo, hi in zip(ids, starts, ends)}
+
+    def _seen_in_layout(self, seen_movies):
+        """The seen lists' item rows as layout rows, ascending within each
+        user again: once, at load."""
+        if self._to_layout is None:
+            return seen_movies
+        rows = self._to_layout[seen_movies].astype(np.int64)
+        owner = np.repeat(np.arange(self._seen_indptr.size - 1),
+                          np.diff(self._seen_indptr))
+        # one sort of (user, layout row) keys puts every list in order
+        rows += owner * self.num_movies
+        rows.sort()
+        rows -= owner * self.num_movies
+        return rows.astype(np.int32)
+
+    @property
+    def departments(self) -> tuple[int, ...]:
+        """The departments this engine can restrict an answer to."""
+        return tuple(self._ranges)
+
+    def department_range(self, department: int) -> tuple[int, int]:
+        """Rows ``[lo, hi)`` of the table's layout that hold the
+        department's items."""
+        try:
+            return self._ranges[int(department)]
+        except KeyError:
+            raise ValueError(
+                f"no department {department}: this engine "
+                + (f"holds departments {sorted(self._ranges)}"
+                   if self._ranges else "was given no item_department")
+            ) from None
+
     # -- table ---------------------------------------------------------------
 
     def _set_table(self, movie_factors) -> None:
         """Upload ``movie_factors`` — a [num_movies, k] array, or a callable
         ``(lo, hi)`` → rows [lo, hi) as float32 (``row_reader``) — as the
-        live item table."""
+        live item table, its rows in the layout's order."""
+        read = row_reader(movie_factors)
+        if self._to_item is not None:
+            if callable(movie_factors):
+                raise ValueError(
+                    "a row reader hands the table over in item order, and "
+                    "these departments are not sorted by item row: give "
+                    "the factors as an array, or item_department sorted")
+            read = lambda lo, hi: np.asarray(
+                movie_factors[self._to_item[lo:hi]], np.float32)
         # one atomic reference swap: a batch in flight keeps the table
         # it captured; the next batch sees the new one
-        self._table = self._upload(row_reader(movie_factors),
-                                   whole=not callable(movie_factors))
+        self._table = self._upload(read, whole=not callable(movie_factors))
 
     def _upload(self, read, *, whole: bool):
         """(data, scale) on the device, or row-sharded over the mesh, from
@@ -369,6 +460,13 @@ class ServeEngine:
                 f"row-sharded over {self._shards} devices, and a gather "
                 "that crosses shards is the half of ROADMAP R8 (a) still "
                 "open: give the session a table of its own (no engine=)")
+        if self._to_item is not None:
+            raise ValueError(
+                "a fold-in gathers item rows by their item number, and this "
+                "engine's table is laid out by department (item_department "
+                "came unsorted, so row r of it is not item r): give the "
+                "departments sorted by item row, or the session a table of "
+                "its own (no engine=)")
         with self._lock:
             return self._table
 
@@ -435,6 +533,8 @@ class ServeEngine:
         rows, f = rows[keep], f[keep]
         if rows.size == 0:
             return 0
+        if self._to_layout is not None:
+            rows = self._to_layout[rows]
         if self.table_dtype == "int8":
             # where the table's own codes were made (``_upload``)
             qd, qs = map(jnp.asarray, quantize_rows_host(f))
@@ -470,6 +570,11 @@ class ServeEngine:
         kept in order here, once a commit, so a batch reads it as it
         stands."""
         cells = np.asarray(list(cells), np.int64).reshape(-1, 2)
+        if self._to_layout is not None and cells.shape[0]:
+            # the lists are kept in the layout's rows; an item the table
+            # does not hold stays past its end, where the scorer drops it
+            known = cells[:, 1] < self.num_movies
+            cells[known, 1] = self._to_layout[cells[known, 1]]
         if self._seen_movies is not None and cells.shape[0]:
             cells = cells[csr_find(self._seen_indptr, self._seen_movies,
                                    cells[:, 0], cells[:, 1]) < 0]
@@ -526,24 +631,28 @@ class ServeEngine:
         return movies, indptr
 
     def topk(self, user_rows, k: int, *, exclude_seen: bool = True,
-             stamp: dict | None = None):
+             stamp: dict | None = None, department: int | None = None):
         """(scores [n, k] f32, movie rows [n, k] int32) for the requested
-        user rows.  The batch is padded to the pow2 quantum (padding rows
-        score with a zero factor vector and are sliced off), so request
-        coalescing shares compiled programs across batch sizes.
+        user rows, over the whole catalogue or, with ``department``, over
+        that department's items alone.  The batch is padded to the pow2
+        quantum (padding rows score with a zero factor vector and are
+        sliced off), so request coalescing shares compiled programs across
+        batch sizes.
 
         The two halves of one ``TopKBatch``, back to back: ``stage`` and
         the hand-over to the device, then the fetch.  The request server
         runs the same halves one step apart (``compute``).  ``stamp``, a
         dict, receives the ``epoch`` and the commit ``ordinal`` the batch
         was staged against."""
-        batch = self.stage(user_rows, k, exclude_seen=exclude_seen)
+        batch = self.stage(user_rows, k, exclude_seen=exclude_seen,
+                           department=department)
         if stamp is not None:
             stamp.update(epoch=batch.epoch, ordinal=batch.ordinal)
         return compute(batch, batch)
 
     def stage(self, user_rows, k: int, *, exclude_seen: bool = True,
-              warm: bool = False) -> "TopKBatch":
+              warm: bool = False,
+              department: int | None = None) -> "TopKBatch":
         """The host's part of ``topk``'s front half: gather the user rows,
         group the seen cells, upload both.  The ``TopKBatch`` it returns
         owns the table it will be scored against (captured under the lock
@@ -554,9 +663,22 @@ class ServeEngine:
         batch's cell list is padded past the top rung, so that it runs
         both of that rung's programs, and the lower rungs' run beside it
         (``_warm_seen_rungs``): every program the rectangle of such a
-        batch can take."""
+        batch can take.
+
+        ``department`` makes it a ranged batch: the one scan it shares is
+        of the department's rows (``department_range``).  Only the cells of
+        its users' lists that lie in the range are grouped, the rectangle
+        covers the range's slabs padded to their rung (``_range_rung``),
+        tile 0 the first tile of the range's first slab, and the scorer is
+        handed the range's rows and the rung.  Such a rectangle is small,
+        so its cell list goes up a piece a run of the scatter program (the
+        program that starts a rectangle, then the one that adds to it): two
+        programs a (batch size, rung) where the whole table's ladder has
+        six."""
         user_rows = np.asarray(user_rows, dtype=np.int64)
         n = user_rows.shape[0]
+        rows = None if department is None else self.department_range(
+            department)
         if n == 0:
             return TopKBatch(self, n=0, k=k, epoch=self.epoch,
                              ordinal=self.commit_ordinal, result=(
@@ -584,12 +706,19 @@ class ServeEngine:
                 # indptr entry), not user 0's — aliasing the heaviest user
                 # into every pad slot would inflate the seen-rectangle
                 # width for rows whose output is sliced off anyway
+                if rows is not None:
+                    # the cells of the range alone: each list is ascending,
+                    # so what is kept of it is too
+                    keep = (movies >= rows[0]) & (movies < rows[1])
+                    indptr = np.concatenate(
+                        ([0], np.cumsum(keep)))[indptr]
+                    movies = movies[keep]
                 indptr_pad = np.concatenate(
                     [indptr, np.full(b - n, indptr[-1], np.int64)]
                 )
                 sp.set(seen_cells=len(movies))
         tiles = table.shape[0] // self.tile_m
-        seen = shape = None
+        seen = shape = grid_tiles = None
         if movies is not None:
             with span("serve/batch/seen_tiles") as sp:
                 cells, shape = group_seen_cells(
@@ -597,7 +726,14 @@ class ServeEngine:
                     num_movies=self.num_movies,
                     tile_m=self.tile_m, num_tiles=tiles,
                 )
-                seen = _seen_chunks(sp, cells, shape, warm)
+                if rows is not None:
+                    # the rectangle of the range's rung, from the first
+                    # tile of its first slab
+                    first, grid_tiles = self._range_grid(
+                        rows, b, shape[2], table, k)
+                    cells[0] -= first
+                    shape = (grid_tiles,) + shape[1:]
+                seen = _seen_chunks(sp, cells, shape, warm, rows is not None)
                 if self.mesh is not None:
                     # how many of the cells each chip keeps for its slice
                     sp.set(shard_cells=np.bincount(
@@ -617,7 +753,7 @@ class ServeEngine:
                 seen = [_put(c, self.mesh) for c in seen]
             u = _put(u, self.mesh)
             sp.set(bytes=nbytes)
-        if warm and seen is not None:
+        if warm and seen is not None and rows is None:
             self._warm_seen_rungs(shape)
         # what the fetch will say of the batch on ``serve/batch/compute``
         shard_tiles = tiles // self._shards
@@ -640,9 +776,31 @@ class ServeEngine:
         if self.mesh is not None:
             counters.update(shards=self._shards,
                             merge_candidates=self._shards * k)
+        if rows is not None:
+            if grid_tiles is None:  # no exclusion: no rectangle
+                _, grid_tiles = self._range_grid(rows, b, 0, table, k)
+            # the tiles that hold a row of the range are those scanned;
+            # the grid runs its rung's, the rest shut
+            scanned = range_tiles(*rows, self.tile_m)
+            counters.update(
+                department=int(department), range_rows=rows[1] - rows[0],
+                tiles=scanned, grid_tiles=grid_tiles,
+                grid_steps=grid_tiles // slab,
+                scan_bytes=scanned * (counters["scan_bytes"] // tiles))
         return TopKBatch(
             self, n=n, k=k, epoch=epoch, ordinal=ordinal, counters=counters,
-            operands=(u, table, scale, seen, shape))
+            operands=(u, table, scale, seen, shape, rows, grid_tiles))
+
+    def _range_grid(self, rows, b, seen_width, table, k):
+        """(the table's tile that a ranged batch's rectangle starts at, the
+        tiles its grid runs): the range's first slab, and its slabs padded
+        to their rung, at the slab the scorer will choose for these shapes
+        (``slab_tiles``)."""
+        g = slab_tiles(table.shape[0] // self.tile_m, b, seen_width,
+                       table.shape[1], table.dtype, tile_m=self.tile_m,
+                       k_top=k)
+        first, last = range_slabs(rows[0], rows[1], g, self.tile_m)
+        return first * g, _range_rung(int(last) - first + 1) * g
 
     def _seen_tiles(self, chunks, shape):
         """The [NT, B, W] exclusion rectangle on the device and which of
@@ -690,7 +848,8 @@ class ServeEngine:
         return trace_count()
 
     def prewarm(self, k: int, *, max_batch: int | None = None,
-                user_rows=None, exclude_seen: bool = True) -> dict:
+                user_rows=None, exclude_seen: bool = True,
+                departments=None) -> dict:
         """Trace (and compile) the pow2 batch-bucket program set up
         front (ISSUE 13), so the first REAL request batch after attach
         pays zero traces — the cold-process counterpart of the pow2
@@ -707,7 +866,11 @@ class ServeEngine:
         Returns
         ``{"programs", "new_traces", "prewarm_s"}``; a later batch whose
         (padded size, seen width) bucket was covered here traces
-        nothing, which ``tests/test_staging.py`` pins."""
+        nothing, which ``tests/test_staging.py`` pins.  An engine that
+        was given departments also runs, at each size, a ranged batch of
+        every rung that the ranges of ``departments`` take (all it holds
+        where none are named; a deployment whose traffic names a few warms
+        those)."""
         import time as _time
 
         with span("serve/prewarm", k=k, max_batch=max_batch):
@@ -735,6 +898,18 @@ class ServeEngine:
                                    warm=True)
                 compute(batch, batch)
                 programs += 1
+                # an engine that was given departments: the ranged
+                # programs of this batch size, one a rung that some
+                # department's range takes (and a rectangle's width)
+                warmed = set()
+                for department in (self._ranges if departments is None
+                                   else departments):
+                    batch = self.stage(take, k, exclude_seen=exclude_seen,
+                                       warm=True, department=department)
+                    if batch.statics not in warmed:
+                        warmed.add(batch.statics)
+                        compute(batch, batch)
+                        programs += 1
                 b *= 2
             self.prewarmed = True  # the /readyz gate flips here
             return {
@@ -769,6 +944,12 @@ class TopKBatch:
         self._out = None
 
     @property
+    def statics(self):
+        """What, beside the batch's padded size and K, chooses its programs:
+        the rectangle's shape and a ranged batch's rung."""
+        return self._operands[4], self._operands[6]
+
+    @property
     def on_device(self) -> bool:
         """Handed to the device and not fetched yet."""
         return self._out is not None
@@ -781,9 +962,17 @@ class TopKBatch:
 
     def dispatch(self) -> None:
         eng = self.engine
-        u, table, scale, seen, shape = self._operands
+        u, table, scale, seen, shape, rows, grid_tiles = self._operands
         seen_tiles = eng._seen_tiles(seen, shape)
-        if eng.mesh is not None:
+        if rows is not None:
+            # the same entry, the range's rows as two scalars and its rung
+            # as a static: a family of programs beside the whole table's
+            out = _topk_jit_fn()(
+                u, table, scale, seen_tiles, np.asarray(rows, np.int32),
+                k_top=self.k, num_movies=eng.num_movies, tile_m=eng.tile_m,
+                grid_tiles=grid_tiles,
+            )
+        elif eng.mesh is not None:
             from cfk_tpu.parallel.spmd import serve_topk_sharded
 
             out = serve_topk_sharded(
@@ -816,7 +1005,13 @@ class TopKBatch:
         sp.set(select_rounds=int(counts[0]), select_tiles=int(counts[1]),
                seen_chunks=int(counts[2]), seen_hit_tiles=int(counts[3]),
                completed_tiles=int(counts[4]), **self.counters)
-        self.result = vals[:self.n], ids[:self.n]
+        vals, ids = vals[:self.n], ids[:self.n]
+        to_item = self.engine._to_item
+        if to_item is not None:
+            # the layout's rows back to the caller's item rows (an empty
+            # slot stays -1)
+            ids = np.where(ids >= 0, to_item[np.maximum(ids, 0)], ids)
+        self.result = vals, ids
         return self.result
 
 
@@ -840,22 +1035,25 @@ def compute(dispatch: TopKBatch | None, fetch: TopKBatch | None):
     return None if fetch is None else fetch.result
 
 
-def _seen_chunks(sp, cells: np.ndarray, shape, warm: bool = False):
+def _seen_chunks(sp, cells: np.ndarray, shape, warm: bool = False,
+                 ranged: bool = False):
     """One batch's cell list as ``ServeEngine._seen_tiles`` takes it once
     uploaded: one array, the list padded from its ``chunks`` pieces of
     ``capacity`` cells up to the next rung of ``SEEN_PIECE_RUNGS``, so the
     columns scattered stay under twice the cells past one piece; a list
     past the top rung, and ``prewarm``'s (``warm``: one piece past it), as
-    several arrays of the top rung's size.  Its ``serve/batch/seen_tiles``
+    several arrays of the top rung's size.  A ``ranged`` batch's list, the
+    cells of one department, takes the ladder's first rung alone: a piece
+    an array (``prewarm``'s: two).  Its ``serve/batch/seen_tiles``
     span says what the device will build ([tiles, b, width] int32), what
     the host built for it (``bytes``), the real ``cells`` among them and
     how many runs of the scatter program (``programs``) the batch will
     cost."""
     nt, b, width = shape
     capacity = seen_cell_capacity(b)
-    pieces = max(-(-cells.shape[1] // capacity),
-                 SEEN_PIECE_RUNGS[-1] + 1 if warm else 1)
-    rung = seen_piece_rung(pieces)
+    top = SEEN_PIECE_RUNGS[0 if ranged else -1]
+    pieces = max(-(-cells.shape[1] // capacity), top + 1 if warm else 1)
+    rung = min(seen_piece_rung(pieces), top)
     runs = chunk_seen_cells(cells, rung * capacity, nt, -(-pieces // rung))
     sp.set(tiles=nt, b=b, width=width, cells=cells.shape[1],
            capacity=capacity, chunks=pieces, programs=len(runs),
@@ -884,12 +1082,23 @@ def note_trace() -> None:
     _TRACES[0] += 1
 
 
-def _topk_call(u, table, scale, seen_tiles, *, k_top, num_movies, tile_m):
+def _topk_call(u, table, scale, seen_tiles, rows=None, *, k_top, num_movies,
+               tile_m, grid_tiles=None):
     _TRACES[0] += 1
     return topk_scores_counted(
         u, table, scale, seen_tiles, k_top=k_top, num_movies=num_movies,
-        tile_m=tile_m,
+        tile_m=tile_m, rows=rows, grid_tiles=grid_tiles,
     )
+
+
+def _range_rung(slabs: int) -> int:
+    """The slabs a ranged scan's grid runs for a range that lies in
+    ``slabs`` of them: the next power of two.  Ranges of every length then
+    share a dozen programs a batch size (a program costs ~0.1 s of every
+    warm start, seconds of a cold one: PERF.md section 7, row 25), and what
+    the padding costs is a step shut by one scalar compare and a rectangle
+    under twice the range's (PERF.md section 6, PR 52)."""
+    return _pow2_ceil(slabs)
 
 
 def _seen_tiles_call(cells, seen_tiles, *, shape, tile_m):
@@ -918,7 +1127,8 @@ def _topk_jit_fn():
     import jax
 
     return jax.jit(
-        _topk_call, static_argnames=("k_top", "num_movies", "tile_m")
+        _topk_call,
+        static_argnames=("k_top", "num_movies", "tile_m", "grid_tiles"),
     )
 
 
@@ -951,10 +1161,11 @@ def _replicated(mesh):
 
 
 def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
-                      mesh=None, shards=None,
-                      batch_quantum=8) -> ServeEngine:
+                      mesh=None, shards=None, batch_quantum=8,
+                      item_department=None) -> ServeEngine:
     """Build an engine from an ``ALSModel`` (+ optional dataset/index whose
-    ``coo_dense`` provides the exclude-seen lists)."""
+    ``coo_dense`` provides the exclude-seen lists; + optional
+    ``item_department``, one int a dense item row)."""
     seen_movies = seen_indptr = None
     if dataset is not None:
         coo = dataset.coo_dense
@@ -979,5 +1190,5 @@ def engine_from_model(model, dataset=None, *, table_dtype=None, tile_m=512,
         num_users=model.num_users, num_movies=model.num_movies,
         seen_movies=seen_movies, seen_indptr=seen_indptr,
         table_dtype=table_dtype, tile_m=tile_m, mesh=mesh, shards=shards,
-        batch_quantum=batch_quantum,
+        batch_quantum=batch_quantum, item_department=item_department,
     )

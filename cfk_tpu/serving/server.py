@@ -16,6 +16,18 @@ natural batch size self-tunes — a busy server finds more requests pending
 per poll, amortizing the per-batch dispatch over more queries, which is
 what makes the QPS-vs-latency trade measurable (PERF.md §4).
 
+A request may name a department (``ScoreRequest.department``): its answer
+is the exact top-K among that department's items, a scan of the
+department's range of the engine's table.  One batch shares one scan, so a
+batch holds requests of ONE department (or of none): a step polls one
+partition, the one whose head has waited longest, and takes the run of
+records at its head that name the same department.  The log does the
+grouping where the topic is keyed by department (``ensure_serve_topics(
+departments=)``, ``ServeClient(route="department")``: partition 0 for
+requests that name none, partition 1 + d for department d), and every run
+is then a full batch under a backlog; over any other partitioning the runs
+are as long as the log happens to make them, and the answers the same.
+
 Under a backlog the server keeps one batch in flight on the device
 (``RecommendServer.step``): the batch just polled is staged and handed
 over, then the batch handed over a step ago is fetched and answered, so
@@ -51,7 +63,7 @@ import time
 import numpy as np
 
 from cfk_tpu.serving.engine import TopKBatch, compute
-from cfk_tpu.serving.topk_kernel import _pow2_ceil
+from cfk_tpu.serving.topk_kernel import _pow2_ceil, range_tiles
 from cfk_tpu.telemetry import get_tracer, record_event, span
 from cfk_tpu.transport.serdes import (
     ScoreRequest,
@@ -69,15 +81,43 @@ RESPONSES_TOPIC = "serve-responses"
 def ensure_serve_topics(transport, *, requests_topic: str = REQUESTS_TOPIC,
                         responses_topic: str = RESPONSES_TOPIC,
                         request_partitions: int = 1,
-                        response_partitions: int = 1) -> None:
+                        response_partitions: int = 1,
+                        departments: int | None = None) -> None:
     """Create the serve topics if absent (existing ones keep their own
-    partition counts, like the updates topic)."""
+    partition counts, like the updates topic).  ``departments`` keys the
+    requests topic by department: one partition for each of departments 0
+    to ``departments - 1`` behind partition 0, which takes the requests
+    that name none (``department_partition``)."""
+    if departments is not None:
+        request_partitions = max(request_partitions, int(departments) + 1)
     for name, parts in ((requests_topic, request_partitions),
                         (responses_topic, response_partitions)):
         try:
             transport.num_partitions(name)
         except KeyError:
             transport.create_topic(name, parts)
+
+
+def department_partition(department: int | None, partitions: int) -> int:
+    """The partition of a requests topic keyed by department that takes a
+    request naming ``department``: 0 for none, 1 + d for department d.  A
+    topic with no such partition is not keyed by this department: refused."""
+    if department is None:
+        return 0
+    if not 0 <= int(department) < partitions - 1:
+        raise ValueError(
+            f"department {department} has no partition on a requests topic "
+            f"of {partitions}: a topic keyed by department holds one for "
+            "each department and one for none "
+            "(ensure_serve_topics(departments=))")
+    return 1 + int(department)
+
+
+def _waiting_since(record) -> float:
+    """When ``record`` reached the head of the line, on
+    ``time.perf_counter``: when the log appended it where the log says
+    (``Record.appended``), else now, when this server first sees it."""
+    return record.appended or time.perf_counter()
 
 
 class RecommendServer:
@@ -144,6 +184,10 @@ class RecommendServer:
         own = (range(nparts) if partitions is None
                else [int(p) for p in partitions])
         self._cursors = {p: 0 for p in own}
+        # partition -> when the record at its cursor was appended (where
+        # the log says; else when this server first saw it there), for the
+        # partitions that hold one: ``_next_partition``
+        self._heads: dict[int, float] = {}
         # Committed cursors move only AFTER a batch's responses are
         # produced and flushed — the failover handoff point: a survivor
         # adopting a dead replica's partition resumes here, re-serving
@@ -188,6 +232,7 @@ class RecommendServer:
         p = int(partition)
         self._cursors[p] = int(cursor)
         self.committed_cursors[p] = int(cursor)
+        self._heads.pop(p, None)
 
     def close(self) -> None:
         """Release the /metrics endpoint (idempotent)."""
@@ -202,31 +247,69 @@ class RecommendServer:
         self.close()
 
     def _poll_requests(self) -> list[ScoreRequest]:
-        """Everything currently pending, up to ``max_batch``, in
-        (partition, offset) order — the same deterministic order the
-        streaming consumer uses."""
+        """One batch: the run of records at the head of ONE partition that
+        name the same department (or none), up to ``max_batch``, in offset
+        order.  One batch shares one scan, so it never mixes departments;
+        the first record that names another stays in the log, the head of
+        the next run.  The partition is the one whose head has waited
+        longest (``_next_partition``), so under a backlog no partition
+        starves another whatever each holds."""
+        p = self._next_partition()
+        if p is None:
+            return []
         out: list[ScoreRequest] = []
-        for p in sorted(self._cursors):
-            if len(out) >= self.max_batch:
-                break
-            take = self.max_batch - len(out)
-            got = 0
-            for rec in self.transport.consume(
-                self.requests_topic, p, self._cursors[p]
-            ):
-                got += 1  # cursor advances past the frame either way: a
-                # malformed frame must be SKIPPED, not re-read forever —
-                # re-raising before the cursor moved would wedge every
-                # restart on the same poison offset
-                try:
-                    out.append(decode_score_request(rec.value))
-                except ValueError:
-                    self.malformed_requests += 1
-                    self.metrics.incr("serve_malformed_requests")
-                if got >= take:
+        got = 0
+        head = None  # the record the cursor will stand at, where seen
+        records = iter(self.transport.consume(
+            self.requests_topic, p, self._cursors[p]))
+        for rec in records:
+            try:
+                req = decode_score_request(rec.value)
+            except ValueError:
+                self.malformed_requests += 1
+                self.metrics.incr("serve_malformed_requests")
+            else:
+                if out and req.department != out[0].department:
+                    head = rec
                     break
-            self._cursors[p] += got
+                out.append(req)
+            got += 1  # cursor advances past the frame either way: a
+            # malformed frame must be SKIPPED, not re-read forever —
+            # re-raising before the cursor moved would wedge every
+            # restart on the same poison offset
+            if got >= self.max_batch:
+                if len(self._cursors) > 1:
+                    head = next(records, None)
+                break
+        self._cursors[p] += got
+        self._heads.pop(p, None)
+        if head is not None:
+            self._heads[p] = _waiting_since(head)
         return out
+
+    def _next_partition(self) -> int | None:
+        """The partition to poll: of those that hold a record, the one
+        whose head has waited longest: by the time the log appended it
+        where the log says (``Record.appended``), else by when this server
+        first saw it at the head.  A server of one partition asks nothing
+        of the log here."""
+        if len(self._cursors) == 1:
+            return next(iter(self._cursors))
+        best = None
+        for p, cursor in self._cursors.items():
+            since = self._heads.get(p)
+            if since is None:
+                if self.transport.end_offset(self.requests_topic,
+                                             p) <= cursor:
+                    continue
+                head = next(iter(self.transport.consume(
+                    self.requests_topic, p, cursor)), None)
+                if head is None:
+                    continue
+                since = self._heads[p] = _waiting_since(head)
+            if best is None or since < best[0]:
+                best = (since, p)
+        return None if best is None else best[1]
 
     def _pending(self) -> int:
         """Requests produced to this server's partitions and not yet
@@ -305,6 +388,14 @@ class RecommendServer:
                 sp.set(requests=len(reqs),
                        malformed=self.malformed_requests - malformed,
                        pending_after=self._pending())
+                if reqs and reqs[0].department is not None:
+                    # what waits in each partition of a topic keyed by
+                    # department (partition p: department p - 1)
+                    sp.set(department=reqs[0].department,
+                           pending_by_department={
+                               p - 1: self.transport.end_offset(
+                                   self.requests_topic, p) - cursor
+                               for p, cursor in self._cursors.items() if p})
         # a fuzzed frame can decode into a request whose reply_partition
         # doesn't exist — unanswerable (there is no partition to refuse
         # it on), so it is counted and dropped BEFORE admission rather
@@ -331,14 +422,25 @@ class RecommendServer:
         if overlapped:
             self.metrics.incr("serve_batches_overlapped")
         with span("serve/batch", requests=len(reqs), shed=len(shed),
-                  batch=ordinal, overlapped=overlapped):
+                  batch=ordinal, overlapped=overlapped) as sp:
             polled = (self._validate(reqs, shed, cursors)
                       if reqs or shed else None)
+            # a batch that names a department is scored over its range
+            # alone; one that names none takes no such argument
+            ranged = {}
+            if polled is not None and polled.department is not None:
+                ranged["department"] = polled.department
+                lo, hi = self.engine.department_range(polled.department)
+                sp.set(department=polled.department, range_rows=hi - lo,
+                       range_tiles=range_tiles(lo, hi, self.engine.tile_m))
+                self.metrics.incr(
+                    f"serve_dept_batches_{polled.department}")
             if in_flight is None and self._pending() == 0:
                 # straight through: engine.topk is both halves of the
                 # batch back to back, under the spans of one
                 stamp: dict = {}
-                answer = (self.engine.topk(polled.rows, polled.k, stamp=stamp)
+                answer = (self.engine.topk(polled.rows, polled.k, stamp=stamp,
+                                           **ranged)
                           if polled.valid else None)
                 polled.ordinal = stamp.get("ordinal", polled.ordinal)
                 return self._respond(polled, answer)
@@ -346,7 +448,8 @@ class RecommendServer:
                 if polled is not None and polled.valid:
                     # the assemble, seen_tiles and upload spans: the
                     # host's side of the polled batch's timeline
-                    polled.handle = self.engine.stage(polled.rows, polled.k)
+                    polled.handle = self.engine.stage(
+                        polled.rows, polled.k, **ranged)
                     polled.epoch = polled.handle.epoch
                     polled.ordinal = polled.handle.ordinal
                 # one serve/batch/compute: the polled batch's dispatch,
@@ -415,15 +518,30 @@ class RecommendServer:
         with span("serve/batch/validate", requests=len(reqs)):
             valid: list[ScoreRequest] = []
             errors: list[tuple[ScoreRequest, str]] = []
+            # the most a request may ask for: the catalogue's items, or
+            # those of the department the batch's requests name (all the
+            # same one: ``_poll_requests``); a department this engine does
+            # not hold is refused in words, never answered over the whole
+            department = reqs[0].department if reqs else None
+            most, unknown = engine.num_movies, ""
+            if department is not None:
+                try:
+                    lo, hi = engine.department_range(department)
+                    most = hi - lo
+                except (AttributeError, ValueError) as e:
+                    unknown = str(e) or f"no department {department}"
             for r in reqs:
-                if (0 <= r.user < engine.num_users
-                        and 1 <= r.k <= engine.num_movies):
+                if unknown:
+                    errors.append((r, unknown))
+                elif 0 <= r.user < engine.num_users and 1 <= r.k <= most:
                     valid.append(r)
                 else:
                     errors.append((r, (
                         f"user row {r.user} out of range "
                         f"[0, {engine.num_users}) or k {r.k} "
-                        f"outside [1, {engine.num_movies}]")))
+                        f"outside [1, {most}]")))
+            if not valid:
+                department = None  # nothing to scan
         rows = k_pad = None
         if valid:
             k_pad = _pow2_ceil(
@@ -433,7 +551,7 @@ class RecommendServer:
             k_pad = min(k_pad, engine.num_movies)
             rows = np.asarray([r.user for r in valid], np.int64)
         return _Polled(valid, errors, shed, rows, k_pad, epoch, staleness,
-                       cursors, t0)
+                       cursors, t0, department=department)
 
     def _respond(self, batch: "_Polled", answer) -> int:
         """Produce and flush ``batch``'s responses, then commit its read
@@ -669,6 +787,7 @@ class _Polled:
     t0: float
     handle: TopKBatch | None = None  # staged on the engine, under a backlog
     ordinal: int = 0  # the stream commit its rows and seen lists are as of
+    department: int | None = None  # the one its requests name, if any
 
     @property
     def on_device(self) -> bool:
@@ -689,7 +808,7 @@ class ServeClient:
         reply_partition: int = 0,
         requests_topic: str = REQUESTS_TOPIC,
         responses_topic: str = RESPONSES_TOPIC,
-        route_by_user: bool = False,
+        route: str = "req",
         metrics=None,
     ) -> None:
         import os
@@ -699,12 +818,20 @@ class ServeClient:
         self.responses_topic = responses_topic
         self.reply_partition = int(reply_partition)
         self._req_parts = transport.num_partitions(requests_topic)
-        # Fleet routing (ISSUE 18): user-keyed partitioning pins every
-        # request for a user onto ONE replica's partition (user % N — the
-        # PureModPartitioner rule), so a user's answers come from a single
-        # hot-row overlay; the default req_id spread stays for standalone
-        # servers, where any partition reaches the one server anyway.
-        self.route_by_user = bool(route_by_user)
+        # Which partition of the requests topic takes a request.  "req":
+        # the req_id's spread, for standalone servers, where any partition
+        # reaches the one server anyway.  "user" (fleet routing, ISSUE 18):
+        # user % N (the PureModPartitioner rule) pins every request for a
+        # user onto ONE replica's partition, so a user's answers come from
+        # a single hot-row overlay.  "department": a topic keyed by
+        # department (``ensure_serve_topics(departments=)``), every request
+        # on its department's partition (``department_partition``), so that
+        # the log hands the server batches of one department.
+        if route not in ("req", "user", "department"):
+            raise ValueError(
+                f"route={route!r}: requests are routed by 'req', 'user' or "
+                "'department'")
+        self.route = route
         self.metrics = metrics
         # req_ids start at a random 40-bit base: the response partition is
         # supposed to be one-per-client, but if two clients DO share one
@@ -720,20 +847,27 @@ class ServeClient:
         self.retries = 0
         self.rejections = 0
 
-    def request(self, user: int, k: int) -> int:
-        """Send one query; returns its req_id (the response's echo key)."""
+    def request(self, user: int, k: int,
+                department: int | None = None) -> int:
+        """Send one query; returns its req_id (the response's echo key).
+        ``department`` restricts the answer to that department's items."""
         req_id = self._next_req
         if self._send is None:
             self._send = (time.perf_counter_ns()
                           if get_tracer() is not None else 0, req_id)
         self._next_req += 1
-        part = (int(user) if self.route_by_user else req_id) % self._req_parts
+        if self.route == "department":
+            part = department_partition(department, self._req_parts)
+        else:
+            part = (int(user) if self.route == "user"
+                    else req_id) % self._req_parts
         self.transport.produce(
             self.requests_topic,
             key=int(user) % (1 << 31),
             value=encode_score_request(ScoreRequest(
                 req_id=req_id, user=int(user), k=int(k),
                 reply_partition=self.reply_partition,
+                department=None if department is None else int(department),
             )),
             partition=part,
         )
@@ -781,7 +915,8 @@ class ServeClient:
     def ask(self, users, k: int, *, server=None, timeout_s: float = 30.0,
             poll_wait_s: float = 0.002, retries: int = 3,
             backoff_base: float = 0.02, rng=None,
-            sleep=time.sleep) -> dict[int, ScoreResponse]:
+            sleep=time.sleep,
+            department: int | None = None) -> dict[int, ScoreResponse]:
         """Blocking convenience: send, then poll until every response is
         back — driving ``server.step()`` inline when one is given (the
         single-threaded test mode; with a live server thread/process pass
@@ -800,7 +935,7 @@ class ServeClient:
         from cfk_tpu.resilience.retry import backoff_delays
 
         self.flush()
-        ids = [self.request(int(u), k) for u in users]
+        ids = [self.request(int(u), k, department) for u in users]
         self.flush()
         user_of = {rid: int(u) for rid, u in zip(ids, users)}
         alias: dict[int, int] = {}  # re-sent req_id -> original req_id
@@ -848,7 +983,7 @@ class ServeClient:
                 break
             sleep(next(delays))
             for orig in sorted(missing):
-                new_id = self.request(user_of[orig], k)
+                new_id = self.request(user_of[orig], k, department)
                 alias[new_id] = orig
                 self.retries += 1
                 if self.metrics is not None:

@@ -452,15 +452,20 @@ def _tile_scores(u, tile, scale):
     )  # [T, B]
 
 
-def _mask_scores(scores, seen_row, seen_width, tile_base, num_movies):
+def _mask_scores(scores, seen_row, seen_width, tile_base, num_movies,
+                 row_lo=None):
     """``scores`` [T, B] with −inf on the rows at or past ``num_movies``
-    and, with exclusion, on each user's seen rows: ``seen_row(j)`` → [1, B]
-    int32 in-tile rows of exclusion slot j < the static ``seen_width`` (T =
-    padding), or None."""
+    (of a ranged scan: the range's end) and under ``row_lo`` (a ranged
+    scan's start; None: no such bound) and, with exclusion, on each user's
+    seen rows: ``seen_row(j)`` → [1, B] int32 in-tile rows of exclusion slot
+    j < the static ``seen_width`` (T = padding), or None."""
     t, b = scores.shape
     row = lax.broadcasted_iota(jnp.int32, (t, b), 0)  # in-tile row
     neg = jnp.float32(-jnp.inf)
-    scores = jnp.where(tile_base + row < num_movies, scores, neg)
+    inside = tile_base + row < num_movies
+    if row_lo is not None:
+        inside &= tile_base + row >= row_lo
+    scores = jnp.where(inside, scores, neg)
     if seen_row is None:
         return scores
 
@@ -524,7 +529,7 @@ def _tile_counts(rounds, hit, seen_width, completed):
 
 def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
                      seen_hit, tile_base, *, tile_m, num_movies, k_top,
-                     gate_kth):
+                     gate_kth, row_lo=None):
     """Fold one movie tile into the running top-K carry: ``(carry_v,
     carry_i, counts)``, ``counts`` five int32 scalars — the selection
     rounds this tile needed (0 for most tiles) and whether it ran any, the
@@ -561,7 +566,11 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     past ``num_movies`` (the table's last) runs both masks, every other
     tile — nine in ten of a serve cell's — runs neither, and loses
     nothing: its slot rows are all T, which no row equals, and none of its
-    rows is padding.
+    rows is padding.  In a ranged scan (``row_lo`` given: a traced scalar,
+    and ``num_movies`` then the range's end, traced too) the tile that
+    holds the range's first row and the one that holds its last run the
+    masks as the table's last tile does: rows outside ``[row_lo,
+    num_movies)`` can enter no top-K.
 
     **An int8 or a float32 tile** (``deferred_passes``) runs pass 0
     alone, and everything else only behind its first gate: some user's
@@ -602,6 +611,8 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
     # without exclusion the padding compare is not worth a branch
     needs_mask = (True if seen_row is None
                   else (hit > 0) | (tile_base + t > num_movies))
+    if row_lo is not None and needs_mask is not True:
+        needs_mask |= tile_base < row_lo
 
     def rounds_on(scores, carry_v, carry_i):
         # Under shard_map's own tracing (the twin's sharded route) the
@@ -615,7 +626,7 @@ def _score_tile_fold(carry_v, carry_i, u, tile, scale, seen_row, seen_width,
 
     def masked_scores(scores):
         return _mask_scores(scores, seen_row, seen_width, tile_base,
-                            num_movies)
+                            num_movies, row_lo)
 
     def fold(masked):
         scores = _tile_scores(u, tile, scale)
@@ -849,7 +860,7 @@ def _vmem_bytes(g, batch, seen_width, rank, table_dtype, *, tile_m, k_top):
 
 
 def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
-                 with_seen, with_scale, resident):
+                 with_seen, with_scale, resident, ranged=False):
     """Grid step i: fold the slab of movie tiles [i·G, min((i+1)·G, NT))
     into the resident [K, B] carry, a group of P tiles at a time.
 
@@ -907,9 +918,25 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     whose masks ran) and the tiles that ran them, and the tiles completed
     (every tile where no pass is deferred) — tiles of T rows, whatever G
     and P are.
+
+    A RANGED scan (``ranged``: the last scalar-prefetched operand is
+    ``span_ref`` [4] int32 = the range's first and last slab of the table
+    and its rows ``[row_lo, row_hi)``) runs a grid of its rung's steps over
+    the slabs that hold the range: the index maps add ``span_ref[0]`` to
+    the step (``topk_scores_counted``), so step i streams table slab
+    ``span_ref[0] + i`` and rectangle slab i (the rectangle and the hits of
+    such a call cover the rung's tiles only, tile 0 = the first tile of the
+    range's first slab).  A step past the range's last slab does nothing: a
+    scalar compare shuts it whole, and its blocks are the last slab's
+    again, which the pipeline does not fetch twice.  Within the range's
+    slabs a tile is folded, masked and counted only if it holds a row of
+    the range, and the two tiles at the range's ends are masked by row as
+    the table's last tile is: ``row_hi`` takes ``num_movies``' place in
+    every compare, and rows under ``row_lo`` are shut the same way.
     """
     refs = list(refs)
     hits_ref = refs.pop(0) if with_seen else None
+    span_ref = refs.pop(0) if ranged else None
     # ``resident_operand``'s parts: u, and where passes are deferred what
     # pass 0 reads beside it and the first gate's slack
     u_ref, *first_refs = (refs.pop(0) for _ in range(resident))
@@ -921,6 +948,10 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
      gate_ref) = refs
     i = pl.program_id(0)
     seen_width = seen_ref.shape[1] if with_seen else 0
+    if ranged:
+        row_lo, row_hi = span_ref[2], span_ref[3]
+    else:
+        row_lo, row_hi = None, num_movies
 
     def scale_rows(s):
         # the tile's scales are one lane-dense [1, T] row; the score block
@@ -977,17 +1008,28 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
     def fold_tile(j, counts, *, first):
         s = first + j
         n = i * g + s  # the tile's place in the table (or the shard)
-        tile_base = off_ref[0] + n * t
-        there = True if nt % g == 0 else n < nt
-        hit = hits_ref[jnp.minimum(n, nt - 1)] if with_seen else jnp.int32(0)
+        if ranged:
+            # n is its place in the rectangle; the table's tile is that
+            # many past the first tile of the range's first slab
+            tile_base = off_ref[0] + (span_ref[0] * g + n) * t
+            there = (tile_base + t > row_lo) & (tile_base < row_hi)
+            hit = hits_ref[n] if with_seen else jnp.int32(0)
+            edge = (tile_base + t > row_hi) | (tile_base < row_lo)
+        else:
+            tile_base = off_ref[0] + n * t
+            there = True if nt % g == 0 else n < nt
+            hit = (hits_ref[jnp.minimum(n, nt - 1)] if with_seen
+                   else jnp.int32(0))
+            edge = None
         opened = there & (gate_ref[j] > 0)
-        needs_mask = there & ((hit > 0) | (tile_base + t > num_movies))
+        needs_mask = there & ((hit > 0) | (
+            tile_base + t > num_movies if edge is None else edge))
 
         def mask():
             sc = _mask_scores(
                 sc_ref[j],
                 (lambda w: seen_ref[s, pl.ds(w, 1), :]) if with_seen
-                else None, seen_width, tile_base, num_movies)
+                else None, seen_width, tile_base, row_hi, row_lo)
             sc_ref[j] = sc
             ms_ref[j] = _tile_max(sc)
 
@@ -1048,10 +1090,16 @@ def _topk_kernel(off_ref, *refs, t, g, p, nt, k_top, num_movies, b,
         return lax.fori_loop(0, p, functools.partial(fold_tile, first=first),
                              counts)
 
-    counts = lax.fori_loop(0, g // p, fold_group,
-                           (jnp.int32(0),) * NUM_COUNTS)
-    for j, n in enumerate(counts):
-        counts_ref[j] += n
+    def fold_slab():
+        counts = lax.fori_loop(0, g // p, fold_group,
+                               (jnp.int32(0),) * NUM_COUNTS)
+        for j, n in enumerate(counts):
+            counts_ref[j] += n
+
+    if ranged:
+        pl.when(i <= span_ref[1] - span_ref[0])(fold_slab)
+    else:
+        fold_slab()
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -1088,8 +1136,23 @@ def topk_scores_pallas(
     )[:2]
 
 
+def range_slabs(row_lo, row_hi, g, tile_m):
+    """(first, last) slab of ``g`` tiles of ``tile_m`` rows that a scan of
+    rows ``[row_lo, row_hi)`` streams: python ints or traced scalars.  An
+    empty range keeps its first slab (every tile of it is then shut)."""
+    first, last = row_lo // (g * tile_m), (row_hi - 1) // (g * tile_m)
+    return first, (max if isinstance(last, int) else jnp.maximum)(last, first)
+
+
+def range_tiles(row_lo: int, row_hi: int, tile_m: int) -> int:
+    """The tiles of ``tile_m`` rows that hold a row of ``[row_lo, row_hi)``:
+    what a ranged scan folds and counts."""
+    return -(-row_hi // tile_m) - row_lo // tile_m
+
+
 def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
-                        tile_m=512, row_offset=0, interpret=None):
+                        tile_m=512, row_offset=0, interpret=None,
+                        rows=None, grid_tiles=None):
     """``topk_scores_pallas`` and what the data made it cost: ``(scores,
     movie rows, counts)``, counts [5] int32 = selection rounds run over the
     table's tiles, tiles that ran at least one (of ``M_pad / tile_m``),
@@ -1100,7 +1163,21 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     of an int8 or a float32 table's those whose first gate opened).  What
     ``ServeEngine.topk`` puts on its compute span.  ``seen_tiles`` is a
     ``SeenTiles`` (``scatter_seen_cells``) or a bare [NT, B, W] rectangle
-    (``as_seen_tiles``)."""
+    (``as_seen_tiles``).
+
+    ``rows`` = ``(row_lo, row_hi)`` (int32 scalars, traced or not) makes it
+    a RANGED scan: the exact top-K among the table's rows ``[row_lo,
+    row_hi)`` alone, ``row_hi <= num_movies``; every other row can enter no
+    top-K and, but for the rest of the two slabs at the range's ends, is
+    never read.  ``grid_tiles`` (static, a multiple of ``slab_tiles``'
+    G) is then the tiles the grid runs, at least those of the slabs that
+    hold the range (``range_slabs``): the rung of a short ladder, so that
+    ranges of many lengths share a program; ``seen_tiles`` covers those
+    ``grid_tiles`` tiles only, tile 0 = the first tile of the range's first
+    slab, and the counts are over the tiles that hold a row of the range.
+    The rows are this table's own: no ``row_offset``, no mesh.  Without
+    ``rows`` the call lowers to the program it lowered to before there were
+    ranges."""
     b, k = u.shape
     m_pad = table.shape[0]
     if m_pad % tile_m != 0:
@@ -1111,13 +1188,21 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     if not 1 <= k_top:
         raise ValueError(f"k_top must be >= 1, got {k_top}")
     nt = m_pad // tile_m
+    ranged = rows is not None
+    if ranged and (grid_tiles is None or typeof_vma(table)):
+        raise ValueError(
+            "a ranged scan takes grid_tiles, and the rows of one device's "
+            "own table: over a mesh every shard would need its own piece "
+            "of the range (not built)")
     seen_tiles = as_seen_tiles(seen_tiles, tile_m)
     if seen_tiles is not None:
         slots, hits = seen_tiles
-        if slots.shape[:2] != (nt, b) or hits.shape != (nt,):
+        # a ranged scan's rectangle covers the tiles its grid runs
+        snt = grid_tiles if ranged else nt
+        if slots.shape[:2] != (snt, b) or hits.shape != (snt,):
             raise ValueError(
                 f"seen_tiles shapes {slots.shape}, {hits.shape} != "
-                f"({nt}, {b}, W), ({nt},)"
+                f"({snt}, {b}, W), ({snt},)"
             )
         if slots.shape[2] % _SEEN_CHUNK != 0:
             raise ValueError(
@@ -1156,23 +1241,40 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
     # row offset and, with exclusion, the tiles' hits.  Where G does not
     # divide NT the last step's blocks reach past the arrays and are
     # clipped; the kernel folds the tiles there are.
+    if ranged:
+        if grid_tiles % g:
+            raise ValueError(
+                f"grid_tiles {grid_tiles} is no multiple of the slab's {g} "
+                "tiles (slab_tiles)")
+        # the last prefetch ref is the span (first slab, last slab, row_lo,
+        # row_hi): a step streams its slab of the range, and past the
+        # range's last slab that one again, which costs no fetch
+        slab = lambda i, pre: jnp.minimum(pre[-1][0] + i, pre[-1][1])
+        own = lambda i, pre: jnp.minimum(i, pre[-1][1] - pre[-1][0])
+    else:
+        slab = own = lambda i, pre: i
     in_specs = [pl.BlockSpec(r.shape, lambda i, *_, n=r.ndim: (0,) * n)
                 for r in ops]
-    in_specs.append(pl.BlockSpec((g * tile_m, k), lambda i, *_: (i, 0)))
+    in_specs.append(
+        pl.BlockSpec((g * tile_m, k), lambda i, *pre: (slab(i, pre), 0)))
     ops.append(table)  # streamed in slabs
     prefetch = [jnp.asarray(row_offset, jnp.int32).reshape(1)]
     if scale is not None:
         # lane-dense: [NT, 1, T] is the [M_pad] vector itself in HBM, where
         # [M_pad, 1] would be padded to 128 lanes a row
-        in_specs.append(pl.BlockSpec((g, 1, tile_m), lambda i, *_: (i, 0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (g, 1, tile_m), lambda i, *pre: (slab(i, pre), 0, 0)))
         ops.append(scale.astype(jnp.float32).reshape(nt, 1, tile_m))
     if seen_tiles is not None:
         prefetch.append(hits)
         # slot-major for the kernel: one exclusion slot = one [1, B] row
-        in_specs.append(
-            pl.BlockSpec((g, seen_width, b), lambda i, *_: (i, 0, 0))
-        )
+        in_specs.append(pl.BlockSpec(
+            (g, seen_width, b), lambda i, *pre: (own(i, pre), 0, 0)))
         ops.append(jnp.swapaxes(slots, 1, 2))
+    if ranged:
+        row_lo, row_hi = (jnp.asarray(r, jnp.int32) for r in rows)
+        prefetch.append(jnp.stack(
+            [*range_slabs(row_lo, row_hi, g, tile_m), row_lo, row_hi]))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -1184,7 +1286,7 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(pl.cdiv(nt, g),),
+        grid=(grid_tiles // g if ranged else pl.cdiv(nt, g),),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((k_top, b), lambda i, *_: (0, 0)),
@@ -1209,6 +1311,7 @@ def topk_scores_counted(u, table, scale, seen_tiles, *, k_top, num_movies,
             _topk_kernel, t=tile_m, g=g, p=p, nt=nt, k_top=k_top,
             num_movies=num_movies, b=b, with_seen=seen_tiles is not None,
             with_scale=scale is not None, resident=resident,
+            ranged=ranged,
         ),
         grid_spec=grid_spec,
         out_shape=(mk((k_top, b), jnp.float32), mk((k_top, b), jnp.int32),

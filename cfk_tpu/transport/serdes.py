@@ -95,39 +95,61 @@ def decode_rating_update(data: bytes) -> RatingUpdate:
     return RatingUpdate(seq=seq, user=user, movie=movie, rating=rating)
 
 
-# ScoreRequest: int64 req_id | int64 user | int32 k | int32 reply_partition.
-# The serving path's query frame (ISSUE 8): ``user`` is a user id in the
-# server's id space (dense row for the in-process engine; the CLI resolves
-# raw ids before producing), ``k`` the requested top-K, ``reply_partition``
-# the response-topic partition this client consumes (one partition per
-# client, so responses need no broker-side routing beyond the partition).
+# ScoreRequest: int64 req_id | int64 user | int32 k | int32 reply_partition
+# [| int32 department].  The serving path's query frame (ISSUE 8): ``user``
+# is a user id in the server's id space (dense row for the in-process
+# engine; the CLI resolves raw ids before producing), ``k`` the requested
+# top-K, ``reply_partition`` the response-topic partition this client
+# consumes (one partition per client, so responses need no broker-side
+# routing beyond the partition).  The frame is versioned by its length: the
+# 24-byte frame names no department ("the whole catalogue", all a server
+# before PR 52 knew), the 28-byte frame ends in the department whose items
+# alone may be recommended.  A request without one is written as the
+# 24-byte frame, so what an older server reads has not changed.
 _SCORE_REQUEST = struct.Struct(">qqii")
+_SCORE_REQUEST_DEPT = struct.Struct(">qqiii")
 
 
 @dataclasses.dataclass(frozen=True)
 class ScoreRequest:
     """One top-K query in flight: ``req_id`` is client-assigned and echoed
-    on the response — the client's latency clock and dedup key."""
+    on the response — the client's latency clock and dedup key.
+    ``department`` (a non-negative id, or None) restricts the answer to
+    that department's items; it is echoed on nothing."""
 
     req_id: int
     user: int
     k: int
     reply_partition: int = 0
+    department: int | None = None
 
 
 def encode_score_request(msg: ScoreRequest) -> bytes:
-    return _SCORE_REQUEST.pack(msg.req_id, msg.user, msg.k,
-                               msg.reply_partition)
+    if msg.department is None:
+        return _SCORE_REQUEST.pack(msg.req_id, msg.user, msg.k,
+                                   msg.reply_partition)
+    if msg.department < 0:
+        raise ValueError(
+            f"a department is a non-negative id, got {msg.department}")
+    return _SCORE_REQUEST_DEPT.pack(msg.req_id, msg.user, msg.k,
+                                    msg.reply_partition, msg.department)
 
 
 def decode_score_request(data: bytes) -> ScoreRequest:
-    if len(data) != _SCORE_REQUEST.size:
+    if len(data) == _SCORE_REQUEST.size:
+        req_id, user, k, reply = _SCORE_REQUEST.unpack(data)
+        return ScoreRequest(req_id=req_id, user=user, k=k,
+                            reply_partition=reply)
+    if len(data) != _SCORE_REQUEST_DEPT.size:
         raise ValueError(
-            f"ScoreRequest frame must be {_SCORE_REQUEST.size} bytes, "
-            f"got {len(data)}"
+            f"ScoreRequest frame must be {_SCORE_REQUEST.size} or "
+            f"{_SCORE_REQUEST_DEPT.size} bytes, got {len(data)}"
         )
-    req_id, user, k, reply = _SCORE_REQUEST.unpack(data)
-    return ScoreRequest(req_id=req_id, user=user, k=k, reply_partition=reply)
+    req_id, user, k, reply, dept = _SCORE_REQUEST_DEPT.unpack(data)
+    if dept < 0:
+        raise ValueError(f"corrupt ScoreRequest frame: department {dept}")
+    return ScoreRequest(req_id=req_id, user=user, k=k, reply_partition=reply,
+                        department=dept)
 
 
 # ScoreResponse header: int64 req_id | int32 n | uint16 error_len |

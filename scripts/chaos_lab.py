@@ -1817,7 +1817,7 @@ def scenario_serve_replica_kill() -> dict:
 
     fleet, pub, broker, (u, m), oracle = _fleet_fixture(replicas=2)
     k = 5
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     answered = []
     timeouts = 0
     fleet.start()
@@ -1948,7 +1948,7 @@ def scenario_serve_rollover() -> dict:
     oracle1 = ServeEngine(u2, m2, num_users=u.shape[0],
                           num_movies=m.shape[0], tile_m=16)
     k = 5
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     answered = []
     timeouts = 0
     fleet.start()

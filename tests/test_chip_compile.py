@@ -410,6 +410,41 @@ def test_serve_scorer_float32_cells_first_gate(chip, b):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("dtype, grid_tiles, b", [
+    (f32, 512, 256),  # Movies: 407 tiles in 26 slabs, the rung of 32
+    (f32, 8192, 256),  # Books: 4,629 tiles in 290 slabs, the rung of 512
+    (f32, 512, 8),  # the smallest batch bucket
+    (i8, 512, 256), (bf16, 512, 256),  # the bodies share the fold
+])
+def test_serve_scorer_dept_ranged(chip, dtype, grid_tiles, b):
+    """A department's scan of the one-chip catalogue (9.35 M x 128 rows):
+    the range's two rows ride in as a third scalar-prefetch operand beside
+    the row offset and the hits, every block's index map adds the range's
+    first slab to the grid step (and holds at its last), the grid runs the
+    rung's steps, the rectangle and the hits cover the rung's tiles only:
+    one Mosaic call, and nothing the size of the table beside it."""
+    from cfk_tpu.serving.topk_kernel import SeenTiles, topk_scores_counted
+
+    k, tile_m, m = 128, 512, 9_350_000
+    m_pad = -(-m // tile_m) * tile_m
+    scale = [chip((m_pad,), f32)] if dtype == i8 else []
+
+    def fn(u, tbl, seen, rows, *sc):
+        return topk_scores_counted(
+            u, tbl, sc[0] if sc else None, seen, k_top=16, num_movies=m,
+            tile_m=tile_m, interpret=False, rows=rows,
+            grid_tiles=grid_tiles)
+
+    compiled = _compile(
+        fn, chip((b, k), f32), chip((m_pad, k), dtype),
+        SeenTiles(chip((grid_tiles, b, 16), i32), chip((grid_tiles,), i32)),
+        chip((2,), i32), *scale)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"s32[{grid_tiles}]" in text and "s32[18262]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("g", [1, 2, 4, 8, 16])
 @pytest.mark.parametrize("dtype", [f32, i8])
 def test_serve_scorer_every_rung_of_the_slab_ladder(chip, monkeypatch, dtype,

@@ -350,7 +350,7 @@ def test_client_retry_exhaustion_raises_timeout():
 
 def test_fleet_user_keyed_routing_partitions_traffic():
     fleet, pub, broker, _ = _wired(replicas=2)
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     for user in range(8):
         client.request(user, 3)
     client.flush()
@@ -367,7 +367,7 @@ def test_fleet_kill_failover_answers_every_accepted_request():
     fleet, pub, broker, _ = _wired(replicas=2)
     fleet.prewarm(3, max_batch=8)
     fleet.start()
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     try:
         got = client.ask(list(range(16)), 3, timeout_s=20)
         assert len(got) == 16
@@ -387,7 +387,7 @@ def test_failover_reserves_uncommitted_requests_at_least_once():
     # (committed cursor did not): the survivor must re-serve from the
     # COMMITTED cursor, so the request is answered, not lost
     fleet, pub, broker, _ = _wired(replicas=2)
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     victim, heir = fleet.replicas
     rid = client.request(0, 3)  # user 0 -> partition 0 (victim)
     client.flush()
@@ -407,7 +407,7 @@ def test_a_replica_killed_with_a_batch_in_flight_is_reserved_by_the_heir():
     exactly past the batch whose responses were flushed, and the heir
     serves everything after it, the batch in flight included."""
     fleet, pub, broker, _ = _wired(replicas=2, max_batch=4)
-    client = ServeClient(broker, route_by_user=True)
+    client = ServeClient(broker, route="user")
     victim, heir = fleet.replicas
     ids = [client.request(2 * i, 3) for i in range(10)]  # all to partition 0
     client.flush()

@@ -1664,3 +1664,345 @@ def test_programs_carry_the_names_the_benchmark_reads(entry):
         # same name: its custom call reads ``_topk_shard_call.<n>`` in a
         # device trace, not ``shard_map.<n>``
         assert re.search(r'"_topk_shard_call/', text)
+
+
+# -- departments on the request path (PR 52) ----------------------------------
+
+def test_request_frames_are_versioned_by_length():
+    """A frame without a department is the 24 bytes it always was and an old
+    frame decodes as 'no department'; a department rides in 4 more."""
+    import struct
+
+    from cfk_tpu.transport.serdes import (
+        ScoreRequest,
+        decode_score_request,
+        encode_score_request,
+    )
+
+    old = struct.pack(">qqii", 7, 123, 10, 3)  # what a client of PR 51 sends
+    assert decode_score_request(old) == ScoreRequest(
+        req_id=7, user=123, k=10, reply_partition=3, department=None)
+    assert encode_score_request(ScoreRequest(7, 123, 10, 3)) == old
+    req = ScoreRequest(req_id=7, user=123, k=10, reply_partition=3,
+                       department=5)
+    frame = encode_score_request(req)
+    assert len(frame) == 28 and frame[:24] == old
+    assert decode_score_request(frame) == req
+    assert decode_score_request(encode_score_request(
+        ScoreRequest(1, 2, 3, department=0))).department == 0
+    with pytest.raises(ValueError, match="non-negative"):
+        encode_score_request(ScoreRequest(1, 2, 3, department=-1))
+    with pytest.raises(ValueError, match="department"):
+        decode_score_request(struct.pack(">qqiii", 7, 123, 10, 3, -2))
+    for n in (23, 25, 27, 29):
+        with pytest.raises(ValueError, match="24 or 28"):
+            decode_score_request(b"\x00" * n)
+
+
+class _CountingBroker:
+    """An ``InMemoryBroker`` that counts the reads of each requests
+    partition."""
+
+    def __init__(self):
+        from cfk_tpu.transport import InMemoryBroker
+
+        self._b = InMemoryBroker()
+        self.reads: dict[int, int] = {}
+
+    def consume(self, topic, partition, start_offset=0):
+        if topic == "serve-requests":
+            self.reads[partition] = self.reads.get(partition, 0) + 1
+        return self._b.consume(topic, partition, start_offset)
+
+    def __getattr__(self, name):
+        return getattr(self._b, name)
+
+
+def _dept_serving(*, departments=3, max_batch=4, route=True, broker=None,
+                  seed=3):
+    """(engine, server, client, department of each item) over a small
+    catalogue whose departments come sorted."""
+    from cfk_tpu.serving import (
+        RecommendServer, ServeClient, ServeEngine, ensure_serve_topics)
+    from cfk_tpu.transport import InMemoryBroker
+
+    rng = np.random.default_rng(seed)
+    users, items, rank = 30, 200, 8
+    dept = np.sort(rng.integers(0, departments, size=items))
+    eng = ServeEngine(
+        rng.standard_normal((users, rank)).astype(np.float32),
+        rng.standard_normal((items, rank)).astype(np.float32),
+        num_users=users, num_movies=items, tile_m=16, item_department=dept)
+    broker = broker if broker is not None else InMemoryBroker()
+    ensure_serve_topics(broker, departments=departments if route else None)
+    server = RecommendServer(eng, broker, max_batch=max_batch)
+    client = ServeClient(broker, route="department" if route else "req")
+    return eng, server, client, dept
+
+
+def _drive(server, client):
+    """Step until nothing is answered any more: {req_id: response}, and the
+    answers each step returned together."""
+    got, steps = {}, []
+    idle = 0
+    while idle < 3:
+        served = server.step()
+        new = client.poll_responses()
+        got.update({r.req_id: r for r in new})
+        if new:
+            steps.append([r.req_id for r in new])
+        idle = 0 if served or new else idle + 1
+    return got, steps
+
+
+@pytest.mark.parametrize("route", [True, False],
+                         ids=["keyed_by_department", "one_partition"])
+def test_a_batch_never_mixes_departments(route):
+    """Requests of three departments and of none, interleaved: every step's
+    answers name one department, on a topic keyed by department (full
+    batches) and on one partition (the runs the log holds); every answer is
+    of the department asked for."""
+    eng, server, client, dept = _dept_serving(route=route)
+    asked = {}
+    for i in range(36):
+        d = (None, 0, 1, 2)[(i // 2) % 4] if not route else (None, 0, 1, 2)[i % 4]
+        asked[client.request(i % 30, 3, d)] = d
+    client.flush()
+    got, steps = _drive(server, client)
+    assert set(got) == set(asked) and not any(r.error for r in got.values())
+    for step in steps:
+        assert len({asked[rid] for rid in step}) == 1
+    for rid, r in got.items():
+        if asked[rid] is not None:
+            assert (dept[r.movie_rows] == asked[rid]).all()
+    if route:  # the log did the grouping: full batches
+        # nine requests of each: 4 + 4 + 1, never topped up from another
+        assert max(map(len, steps)) == 4 and server.batches == 12
+    else:  # runs of two
+        assert max(map(len, steps)) == 2
+    # a whole-catalogue answer is what an engine without departments gives
+    whole = [rid for rid, d in asked.items() if d is None]
+    users = [i for i, rid in enumerate(asked) if asked[rid] is None]
+    vals, ids = eng.topk(np.asarray(users) % 30, 3)
+    for j, rid in enumerate(whole):
+        np.testing.assert_array_equal(got[rid].movie_rows, ids[j])
+
+
+def test_a_topic_without_the_departments_partition_refuses_the_request():
+    """Keyed by department means a partition for each and one for none:
+    nothing wraps onto another department's."""
+    from cfk_tpu.serving import ServeClient
+    from cfk_tpu.serving.server import department_partition
+
+    assert department_partition(None, 1) == 0
+    assert [department_partition(d, 4) for d in range(3)] == [1, 2, 3]
+    for department, partitions in ((3, 4), (0, 1), (-1, 4)):
+        with pytest.raises(ValueError, match="has no partition"):
+            department_partition(department, partitions)
+    _, _, client, _ = _dept_serving(departments=3)
+    with pytest.raises(ValueError, match="department 3 has no partition"):
+        client.request(0, 5, department=3)
+    with pytest.raises(ValueError, match="'req', 'user' or 'department'"):
+        ServeClient(client.transport, route="item")
+
+
+def test_the_longest_waiting_department_is_served_first():
+    eng, server, client, _ = _dept_serving()
+    order = [2, 0, None, 1]  # the order their first requests arrived in
+    asked = {}
+    for d in order:
+        asked[client.request(1, 3, d)] = d
+    for d in (1, None, 0, 2) * 2:
+        asked[client.request(2, 3, d)] = d
+    client.flush()
+    _, steps = _drive(server, client)
+    assert [asked[s[0]] for s in steps] == order
+    assert all(len(s) == 3 for s in steps)
+
+
+def test_a_department_never_asked_costs_nothing():
+    broker = _CountingBroker()
+    eng, server, client, _ = _dept_serving(broker=broker)
+    for i in range(10):
+        client.request(i, 3, 1)
+    client.flush()
+    got, _ = _drive(server, client)
+    assert len(got) == 10
+    # partition 2 (department 1) alone was ever read: no poll, no batch and
+    # no program for the others
+    assert set(broker.reads) == {2}
+    assert server.metrics.counters["serve_dept_batches_1"] == server.batches
+    assert "serve_dept_batches_0" not in server.metrics.counters
+
+
+def test_an_unknown_department_is_refused_in_words_not_served_whole():
+    from cfk_tpu.serving import (
+        RecommendServer, ServeClient, ServeEngine, ensure_serve_topics)
+    from cfk_tpu.transport import InMemoryBroker
+
+    # (a topic not keyed by department: on a keyed one the client has no
+    # partition to send department 9 to)
+    eng, server, client, _ = _dept_serving(route=False)
+    rid = client.request(1, 3, 9)
+    big = client.request(1, 150, 0)  # more than department 0 holds
+    client.flush()
+    got, _ = _drive(server, client)
+    assert "no department 9" in got[rid].error and not got[rid].retriable
+    assert "outside [1, " in got[big].error
+    # an engine that was given no departments says so
+    plain = ServeEngine(np.zeros((4, 8), np.float32),
+                        np.ones((40, 8), np.float32), num_users=4,
+                        num_movies=40, tile_m=16)
+    broker = InMemoryBroker()
+    ensure_serve_topics(broker)
+    server, client = RecommendServer(plain, broker), ServeClient(broker)
+    rid, ok = client.request(1, 3, 0), client.request(1, 3)
+    client.flush()
+    got, _ = _drive(server, client)
+    assert "no item_department" in got[rid].error and not got[ok].error
+
+
+def test_cursors_committed_per_partition_survive_a_restart():
+    """At least once, as before departments: a server that dies with a batch
+    in flight leaves its cursor uncommitted, and its heir, adopting every
+    partition at its committed cursor, answers that batch again and the
+    rest, none twice but the uncommitted one."""
+    from cfk_tpu.serving import RecommendServer
+
+    eng, server, client, _ = _dept_serving(max_batch=2)
+    asked = {}
+    for i in range(12):
+        asked[client.request(i, 3, i % 3)] = i % 3
+    client.flush()
+    answered = {}
+    for _ in range(3):  # two batches answered, a third in flight
+        server.step()
+        answered.update({r.req_id: r for r in client.poll_responses()})
+    assert len(answered) == 4 and server._in_flight is not None
+    committed = dict(server.committed_cursors)
+    assert sum(committed.values()) == 4 and sum(server._cursors.values()) == 6
+    heir = RecommendServer(eng, server.transport, max_batch=2)
+    for p, cursor in committed.items():
+        heir.adopt_partition(p, cursor)
+    again, _ = _drive(heir, client)
+    assert set(again) | set(answered) == set(asked)
+    assert not set(again) & set(answered)  # what was committed is not re-served
+    assert sum(heir.committed_cursors.values()) == 12
+
+
+def test_two_partitions_under_a_backlog_take_turns():
+    """The repair for servers without departments too: a batch is one
+    partition's, the one whose head has waited longest, so partition 0
+    cannot starve partition 1 whatever it holds."""
+    from cfk_tpu.serving import (
+        RecommendServer, ServeClient, ServeEngine, ensure_serve_topics)
+    from cfk_tpu.transport import InMemoryBroker
+
+    rng = np.random.default_rng(0)
+    eng = ServeEngine(rng.standard_normal((8, 8)).astype(np.float32),
+                      rng.standard_normal((60, 8)).astype(np.float32),
+                      num_users=8, num_movies=60, tile_m=16)
+    broker = InMemoryBroker()
+    ensure_serve_topics(broker, request_partitions=2)
+    server = RecommendServer(eng, broker, max_batch=4)
+    client = ServeClient(broker, route="user")
+    part_of = {}
+    for i in range(40):  # partition 0 holds 32 requests, partition 1 eight
+        user = 0 if i % 5 else 1
+        part_of[client.request(user, 3)] = user % 2
+    client.flush()
+    _, steps = _drive(server, client)
+    served = [part_of[s[0]] for s in steps]
+    assert all(len({part_of[r] for r in s}) == 1 for s in steps)
+    # by the arrival of each batch's head: partition 1's first request came
+    # first, its fifth after partition 0's sixteenth.  The parent filled
+    # every batch from partition 0 until that was empty: [0] * 8 + [1] * 2
+    assert served == [1, 0, 0, 0, 0, 1, 0, 0, 0, 0]
+
+
+def test_two_ranged_batches_in_flight_scan_their_own_ranges():
+    """The overlap of PR 33 under departments: batch n + 1 (another
+    department) is staged and handed over while batch n is on the device."""
+    from cfk_tpu import telemetry
+
+    eng, server, client, dept = _dept_serving(max_batch=4)
+    asked = {}
+    for i in range(24):
+        asked[client.request(i, 3, i % 3)] = i % 3
+    client.flush()
+    tracer = telemetry.configure(None)
+    try:
+        got, steps = _drive(server, client)
+        events = tracer.events()
+    finally:
+        telemetry.shutdown(write=False)
+    assert len(got) == 24 and server.metrics.counters[
+        "serve_batches_overlapped"] >= 4
+    for rid, r in got.items():
+        assert (dept[r.movie_rows] == asked[rid]).all()
+    batches = [e["args"] for e in events if e.get("name") == "serve/batch"
+               and "department" in e.get("args", {})]
+    assert len(batches) == 6 and all(
+        b["range_rows"] == int((dept == b["department"]).sum())
+        and b["range_tiles"] >= 1 for b in batches)
+    polls = [e["args"] for e in events if e.get("name") == "serve/poll"
+             and "department" in e.get("args", {})]
+    assert polls and set(polls[0]["pending_by_department"]) == {0, 1, 2}
+    computes = [e["args"] for e in events
+                if e.get("name") == "serve/batch/compute"
+                and "tiles" in e.get("args", {})]  # those that fetched
+    assert len(computes) == 6
+    assert all(c["grid_tiles"] >= c["tiles"] for c in computes)
+
+
+@pytest.mark.parametrize("kind", ["text", "npy"])
+def test_cli_serve_takes_the_departments_from_a_file(tmp_path, capsys,
+                                                     monkeypatch, kind):
+    """``cfk_tpu serve --item-departments FILE``: one int an item row, read
+    with numpy; the engine it builds was given them (and prewarms their
+    rungs), and without the file the command serves as it did."""
+    import json
+
+    from cfk_tpu.cli import main
+    from cfk_tpu.serving import engine as engine_mod
+    from cfk_tpu.transport.checkpoint import CheckpointManager
+
+    ds, model = _tiny_model()
+    csv = tmp_path / "ratings.csv"
+    coo = ds.coo_dense
+    with open(csv, "w") as f:
+        f.write("userId,movieId,rating,timestamp\n")
+        for u, m, r in zip(ds.user_map.raw_ids[coo.user_raw],
+                           ds.movie_map.raw_ids[coo.movie_raw],
+                           coo.rating):
+            f.write(f"{u},{m},{r},0\n")
+    ck = tmp_path / "ck"
+    ck.mkdir()
+    mgr = CheckpointManager(str(ck))
+    mgr.save(3, model.user_factors, model.movie_factors,
+             meta={"model": "als", "rank": 4, "num_shards": 1})
+    mgr.wait_pending()
+    dept = np.arange(model.num_movies) % 3  # not sorted: a permuted layout
+    path = tmp_path / ("dept.npy" if kind == "npy" else "dept.txt")
+    np.save(path, dept) if kind == "npy" else np.savetxt(path, dept, fmt="%d")
+    built = []
+    real = engine_mod.ServeEngine.__init__
+
+    def spy(self, *a, **kw):
+        real(self, *a, **kw)
+        built.append(self)
+
+    monkeypatch.setattr(engine_mod.ServeEngine, "__init__", spy)
+    rc = main([
+        "serve", "--data", str(csv), "--format", "movielens",
+        "--checkpoint-dir", str(ck), "--tile-m", "16", "-k", "5",
+        "--loadgen-qps", "500", "--loadgen-requests", "16",
+        "--item-departments", str(path),
+    ])
+    assert rc == 0
+    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert row["answered"] == 16
+    (eng,) = built
+    assert sorted(eng.departments) == [0, 1, 2] and eng._to_item is not None
+    vals, ids = eng.topk(np.arange(4), 3, department=2)
+    assert (dept[ids] == 2).all()
